@@ -1,0 +1,980 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the served coprocessor path, end to end, on the chip.
+
+The quickest proof that the system still starts on a TPU and that the
+device path — not one of its fallbacks — is what answers.  Run from the
+root of a checkout:
+
+    python3 chip_smoke.py                  # needs a TPU; exit 0 = every check held
+    python3 chip_smoke.py --allow-cpu --rows 65536     # CPU dry run, labelled so
+
+Phase A — the served path at BASELINE config 6's size.  The README's
+entry points (``python -m tikv_tpu.server pd`` / ``tikv --with-device``)
+run as child processes; 10,485,760 seeded rows of ``int_table(2)`` load
+through ImportSST; hash-agg, simple agg, selection, TopN and a plan-IR
+join are answered over gRPC at fresh timestamps and compared with plain
+numpy; one acknowledged ``txn_write`` must be in the next answer via the
+delta/patch path.  Every response is held to ``backend=device``, no
+``degraded`` label, no ``host_exec`` span, and the compile class its plan
+is meant to take on a TPU.  The table sits in ONE region, as in every
+bench rig (multi-region fan-out is ROADMAP R1).
+
+Phase B — the north-star shape at full size: 104,857,600 rows, GROUP BY
+1024 groups + COUNT/SUM through ``DeviceRunner().handle_request``
+against ``np.bincount``.
+
+On a four-chip host Phase A runs on the whole 2x2 mesh (per-device feed
+residency asserted) and adds a placement leg (four small tables spread
+over slices).
+
+One process holds the chip at a time: this parent never imports JAX (it
+asserts so before exiting) and runs its children one after another.
+Any failed check, child exit code or exception exits non-zero naming
+the check.  A run that held every check on the chip ends its stdout with
+two JSON lines: the summary (versions, rows, seconds, compile classes,
+compile-cache counts — SMOKE READINGS of one run, compile included where
+labelled, not benchmark results), then the result line
+``{"ok": true, "device": {"platform", "kind", "count"}}`` with the device
+as JAX reports it.  A dry run prints the summary (``"dry_run": true``)
+and no result line; a failed run prints neither.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import signal
+import socket
+import subprocess
+import sys
+import tempfile
+import time
+import urllib.request
+from importlib import metadata
+
+import numpy as np
+
+ROWS_A = 10 * (1 << 20)         # BASELINE config 6
+ROWS_B = 100 * (1 << 20)        # BASELINE config 4
+GROUPS = 1024
+BUILD_ROWS = 1024               # join build side
+PLACEMENT_ROWS = 1 << 18        # per table, placement leg (4 chips)
+TABLE_ID = 9900
+DEFAULT_ROW_THRESHOLD = 131072  # etc/config-template.toml
+LOAD_CHUNK = 1 << 20
+SEL_FLOOR = 960                 # c1 >= 960: 2% of [-1000, 1000)
+JOIN_FLOOR = 800                # c1 > 800: ~10% of the probe side
+TOPN_LIMIT = 1000
+
+# The compile class each plan is meant to take on a TPU
+# (device_dispatch span attr == flight-recorder entry).  The hash-agg
+# and the simple agg are the fused Pallas kernel, never its XLA
+# stand-ins (hash_twolevel / hash_scatter / simple).
+CLASS_PALLAS = {"pallas_hash"}
+CLASS_SELECTION = {"scan_sel_mask", "scan_sel_index", "scan_sel_compact"}
+CLASS_TOPN = {"topn"}
+CLASS_JOIN = {"join_build", "join_probe"}
+
+FORBIDDEN_LOG_LINES = ("pallas hash kernel disabled", "degrading to host")
+
+TOML = """\
+# chip_smoke.py — cut from etc/config-template.toml
+[server]
+addr = "127.0.0.1:{kv_port}"
+status-addr = "127.0.0.1:{status_port}"
+
+[raftstore]
+# one region holds every table (the bench rigs' setting): this run
+# proves the request path, not the split machinery
+region-split-size-mb = 1048576
+region-max-size-mb = 1048576
+
+[coprocessor]
+device-row-threshold = {threshold}
+{extra}
+"""
+
+
+class SmokeFailure(Exception):
+    """A named check did not hold."""
+
+
+T0 = time.monotonic()
+
+
+def log(msg: str) -> None:
+    print(f"[chip_smoke +{time.monotonic() - T0:7.1f}s] {msg}",
+          file=sys.stderr, flush=True)
+
+
+class Checks:
+    """Every assertion of the run, by name.  A check that the dry run
+    cannot make is recorded as skipped — never as passed."""
+
+    def __init__(self, dry_run: bool):
+        self.dry_run = dry_run
+        self.passed: list = []
+        self.skipped: list = []
+
+    def require(self, name: str, ok: bool, detail="") -> None:
+        """``detail`` may be a callable, built only on failure."""
+        if not ok:
+            raise SmokeFailure(
+                f"{name}: {detail() if callable(detail) else detail}")
+        self.passed.append(name)
+
+    def on_chip(self, name: str, ok: bool, detail="") -> None:
+        """A platform / kernel-class assertion: only a TPU run can make
+        it."""
+        if self.dry_run:
+            self.skipped.append(name)
+        else:
+            self.require(name, ok, detail)
+
+
+# ------------------------------------------------------------ children
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+class Child:
+    """One child process with its stdout/stderr in files."""
+
+    def __init__(self, name: str, argv: list, workdir: str):
+        self.name = name
+        self.out_path = os.path.join(workdir, f"{name}.out")
+        self.err_path = os.path.join(workdir, f"{name}.err")
+        self._out = open(self.out_path, "wb")
+        self._err = open(self.err_path, "wb")
+        self.proc = subprocess.Popen(
+            argv, stdout=self._out, stderr=self._err,
+            cwd=os.path.dirname(os.path.abspath(__file__)))
+
+    def read(self, which: str = "out") -> str:
+        path = self.out_path if which == "out" else self.err_path
+        with open(path, "rb") as f:
+            return f.read().decode(errors="replace")
+
+    def alive(self) -> bool:
+        return self.proc.poll() is None
+
+    def wait(self, timeout: float) -> int:
+        """Wait for the child's own exit; returns the exit code."""
+        try:
+            return self.proc.wait(timeout=timeout)
+        except subprocess.TimeoutExpired:
+            self.proc.kill()
+            self.proc.wait()
+            raise SmokeFailure(
+                f"{self.name}: did not exit within {timeout}s")
+        finally:
+            self._close()
+
+    def terminate(self, timeout: float = 60.0) -> int:
+        """SIGTERM → wait; returns the exit code."""
+        if self.alive():
+            self.proc.send_signal(signal.SIGTERM)
+        return self.wait(timeout)
+
+    def kill(self) -> None:
+        if self.alive():
+            self.proc.kill()
+            self.proc.wait()
+        self._close()
+
+    def _close(self) -> None:
+        self._out.close()
+        self._err.close()
+
+    def tail(self, n: int = 30) -> str:
+        return "\n".join(self.read("err").splitlines()[-n:])
+
+
+def wait_for(what: str, pred, child: Child, timeout: float):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if not child.alive():
+            raise SmokeFailure(
+                f"{what}: {child.name} exited rc={child.proc.returncode}"
+                f"\n{child.tail()}")
+        got = pred()
+        if got:
+            return got
+        time.sleep(0.2)
+    raise SmokeFailure(f"{what}: not within {timeout}s\n{child.tail()}")
+
+
+def listening(port: int) -> bool:
+    try:
+        socket.create_connection(("127.0.0.1", port), 0.2).close()
+        return True
+    except OSError:
+        return False
+
+
+def http_json(port: int, path: str) -> dict:
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}{path}", timeout=30) as r:
+        return json.loads(r.read())
+
+
+# ------------------------------------------------------------ the data
+
+
+def table_data(seed: int, stream: int, n: int):
+    """(c0, c1) of one seeded ``int_table(2)``: c0 in [0, GROUPS),
+    c1 in [-1000, 1000); handles are 0..n-1."""
+    rng = np.random.default_rng([seed, stream])
+    return (rng.integers(0, GROUPS, n).astype(np.int64),
+            rng.integers(-1000, 1000, n).astype(np.int64))
+
+
+def build_data(seed: int):
+    """Join build side: c0 a permutation of [0, BUILD_ROWS) (the join
+    key), c1 the group it maps to (64 groups)."""
+    rng = np.random.default_rng([seed, 99])
+    return (rng.permutation(BUILD_ROWS).astype(np.int64),
+            rng.integers(0, 64, BUILD_ROWS).astype(np.int64))
+
+
+def ref_hash_agg(c0, c1) -> list:
+    """Sorted [count, sum, key] rows, numpy only."""
+    cnt = np.bincount(c0, minlength=GROUPS)
+    sm = np.zeros(GROUPS, np.int64)
+    np.add.at(sm, c0, c1)
+    return sorted([int(cnt[g]), int(sm[g]), g]
+                  for g in range(GROUPS) if cnt[g])
+
+
+# ---------------------------------------------------------- phase A
+
+
+class ServedLeg:
+    """One pd + tikv --with-device pair and the client driving it."""
+
+    def __init__(self, args, checks: Checks, workdir: str, name: str,
+                 extra_toml: str = ""):
+        self.args, self.checks, self.name = args, checks, name
+        self.pd_port, self.kv_port = free_port(), free_port()
+        self.status_port = free_port()
+        toml = os.path.join(workdir, f"{name}.toml")
+        threshold = min(DEFAULT_ROW_THRESHOLD, max(64, args.rows // 4))
+        with open(toml, "w") as f:
+            f.write(TOML.format(kv_port=self.kv_port,
+                                status_port=self.status_port,
+                                threshold=threshold, extra=extra_toml))
+        py = [sys.executable, "-m", "tikv_tpu.server"]
+        self.pd = Child(f"{name}-pd", py + [
+            "pd", "--addr", f"127.0.0.1:{self.pd_port}"], workdir)
+        self.kv = None
+        try:
+            wait_for("pd listening", lambda: listening(self.pd_port),
+                     self.pd, 60)
+            self.kv = Child(f"{name}-tikv", py + [
+                "tikv", "--addr", f"127.0.0.1:{self.kv_port}",
+                "--pd", f"127.0.0.1:{self.pd_port}", "--with-device",
+                "--config", toml,
+                "--status-addr", f"127.0.0.1:{self.status_port}"],
+                workdir)
+            self.device = self._startup_line()
+        except BaseException:
+            self.kill()
+            raise
+
+    def _startup_line(self) -> dict:
+        """Parse the store's ``device runner:`` start-up line and hold
+        it to the platform check before anything is loaded."""
+        def line():
+            for ln in self.kv.read().splitlines():
+                if ln.startswith("device runner: "):
+                    return ln
+            return None
+        try:
+            ln = wait_for("device runner start-up line", line, self.kv, 300)
+        except SmokeFailure as e:
+            raise SmokeFailure(f"platform check: store did not bring up "
+                               f"a device runner — {e}")
+        log(ln)
+        m = re.fullmatch(r"device runner: platform=(\S+) "
+                         r"device_kind='(.*)' n_devices=(\d+) mesh=(\S+)",
+                         ln.strip())
+        if m is None:
+            raise SmokeFailure(f"platform check: cannot parse {ln!r}")
+        dev = {"platform": m[1], "kind": m[2], "count": int(m[3]),
+               "mesh": m[4]}
+        if dev["platform"] != "tpu" and not self.args.allow_cpu:
+            raise SmokeFailure(
+                f"platform check: the store serves on "
+                f"{dev['platform']!r}, not a TPU (pass --allow-cpu for "
+                f"a dry run)")
+        self.checks.on_chip("platform==tpu", dev["platform"] == "tpu")
+        wait_for("tikv listening",
+                 lambda: listening(self.kv_port) and
+                 listening(self.status_port), self.kv, 120)
+        return dev
+
+    # -- client side --
+
+    def connect(self):
+        from tikv_tpu.server import TxnClient
+        self.client = TxnClient(f"127.0.0.1:{self.pd_port}")
+        stores = wait_for("store registered with PD",
+                          lambda: self.client.pd.stores(), self.kv, 60)
+        self.store_id = stores[0].id
+        return self.client
+
+    def load(self, table, handles, c0, c1) -> float:
+        """ImportSST bulk load (bench.py _bulk_load shape): native SST
+        encode, chunked upload, raft ingest, import mode on/off."""
+        from tikv_tpu.codec.keys import table_record_key
+        from tikv_tpu.sst_importer import fast_mvcc_table_sst
+        c = self.client
+        t0 = time.perf_counter()
+        c.import_switch_mode(self.store_id, True)
+        for s in range(0, len(handles), LOAD_CHUNK):
+            hs = handles[s:s + LOAD_CHUNK]
+            blob = fast_mvcc_table_sst(
+                table.table_id, hs,
+                [(2, c0[s:s + LOAD_CHUNK], None),
+                 (3, c1[s:s + LOAD_CHUNK], None)], commit_ts=c.tso())
+            c.ingest_sst(blob, table_record_key(table.table_id,
+                                                int(hs[0])),
+                         chunk=2 << 20, timeout=300)
+        c.import_switch_mode(self.store_id, False)
+        return time.perf_counter() - t0
+
+    def request(self, name: str, send, classes, backend="device",
+                spans=()) -> dict:
+        """Send one coprocessor request, fetch its trace, and hold the
+        response to the device path.  → {"resp", "wall_s", "classes"}."""
+        ck = self.checks
+        t0 = time.perf_counter()
+        resp = send()
+        wall = time.perf_counter() - t0
+        td = resp.get("time_detail", {})
+        labels = td.get("labels", {})
+        trace = http_json(self.status_port,
+                          f"/debug/trace/{resp['trace_id']}")
+        names = [s["name"] for s in trace["spans"]]
+        ck.require(f"{name}: backend", resp["backend"] == backend,
+                   f"{resp['backend']!r}, labels={labels}")
+        ck.require(f"{name}: not degraded",
+                   "degraded" not in labels and
+                   "degraded" not in trace["labels"],
+                   f"labels={labels} trace={trace['labels']}")
+        ck.require(f"{name}: no host_exec span",
+                   "host_exec" not in names, f"spans={names}")
+        for want in spans:
+            ck.require(f"{name}: {want} span", want in names,
+                       f"spans={names}")
+        got = sorted({s["attrs"]["compile_class"]
+                      for s in trace["spans"]
+                      if s["name"] == "device_dispatch"
+                      and "compile_class" in s.get("attrs", {})})
+        ck.on_chip(f"{name}: compile class",
+                   bool(got) and set(got) <= classes,
+                   f"{got}, want a subset of {sorted(classes)}; store "
+                   f"warnings: {self.warnings()}")
+        top = sorted(trace["breakdown_ms"].items(),
+                     key=lambda kv: -kv[1])[:5]
+        log(f"{name}: {wall * 1e3:.1f} ms classes={got} "
+            f"breakdown_ms={dict(top)} labels={labels}")
+        return {"resp": resp, "wall_s": wall, "classes": got,
+                "labels": labels, "spans": names,
+                "phases_ms": td.get("phases_ms", {})}
+
+    def warnings(self) -> list:
+        """The store's recent WARNING-level lines (why a kernel was
+        refused is there, not in the response)."""
+        return [ln[:600] for ln in self.kv.read("err").splitlines()
+                if "disabled" in ln or "degrading" in ln or
+                "failure" in ln or "Error" in ln][-6:]
+
+    # -- teardown --
+
+    def health_checks(self) -> dict:
+        ck, name = self.checks, self.name
+        health = http_json(self.status_port, "/health")
+        index = http_json(self.status_port, "/debug/trace")
+        mesh = health["device_mesh"]
+        ck.on_chip(f"{name}: /health device_mesh.platform",
+                   mesh["platform"] == "tpu", mesh)
+        ck.require(f"{name}: mesh covers every device",
+                   mesh["n_devices"] == self.device["count"],
+                   f"{mesh} vs start-up {self.device}")
+        bad = [s for s in health["device_health"]["slices"]
+               if s.get("state") not in (None, "healthy")]
+        ck.require(f"{name}: no quarantined slice", not bad, bad)
+        fr = index["flight_recorder"]
+        ck.require(f"{name}: flight-recorder faults == 0",
+                   fr["faults"] == 0, fr)
+        pinned = health["fastpath"]["pinned_readback"]
+        log(f"{name}: fastpath.pinned_readback={pinned}")
+        return {"pinned_readback": pinned,
+                "flight_recorder": {k: fr[k] for k in
+                                    ("launches", "first_launches",
+                                     "faults")},
+                "hbm": health["device_state"]["hbm"],
+                "compile_cache": health["compile_cache"],
+                "mesh": mesh}
+
+    def stop(self) -> None:
+        """SIGTERM both servers; both must exit 0 with a clean log."""
+        ck, name = self.checks, self.name
+        rc_kv = self.kv.terminate(120)
+        rc_pd = self.pd.terminate(60)
+        ck.require(f"{name}: tikv exit code 0", rc_kv == 0,
+                   f"rc={rc_kv}\n{self.kv.tail()}")
+        ck.require(f"{name}: pd exit code 0", rc_pd == 0,
+                   f"rc={rc_pd}\n{self.pd.tail()}")
+        logs = self.kv.read("err") + self.kv.read("out")
+        for needle in FORBIDDEN_LOG_LINES:
+            ck.require(f"{name}: log has no {needle!r}",
+                       needle not in logs,
+                       [ln for ln in logs.splitlines() if needle in ln][:3])
+
+    def kill(self) -> None:
+        for ch in (self.kv, self.pd):
+            if ch is not None:
+                ch.kill()
+
+
+def served_leg(args, checks: Checks, workdir: str) -> dict:
+    """Phase A's main leg: single chip (1x1) or the whole 2x2 mesh."""
+    from tikv_tpu.codec.keys import table_record_range
+    from tikv_tpu.copr import plan_ir as pir
+    from tikv_tpu.copr.dag import (
+        AggExprDesc,
+        AggregationDesc,
+        TableScanDesc,
+    )
+    from tikv_tpu.datatype import EvalType
+    from tikv_tpu.executors.ranges import KeyRange
+    from tikv_tpu.expr import Expr
+    from tikv_tpu.testing.dag import DagSelect
+    from tikv_tpu.testing.fixture import encode_table_row, int_table
+
+    n = args.rows
+    leg = ServedLeg(args, checks, workdir, "served")
+    try:
+        c = leg.connect()
+        whole_mesh = leg.device["count"] > 1
+        table = int_table(2, table_id=TABLE_ID)
+        build_t = int_table(2, table_id=TABLE_ID + 1)
+        c0, c1 = table_data(args.seed, 0, n)
+        b0, b1 = build_data(args.seed)
+        handles = np.arange(n, dtype=np.int64)
+        load_s = leg.load(table, handles, c0, c1)
+        leg.load(build_t, np.arange(BUILD_ROWS, dtype=np.int64), b0, b1)
+        log(f"loaded {n} rows in {load_s:.1f}s")
+
+        def select():
+            # fresh builder per request: DagSelect mutates
+            return DagSelect.from_table(table, ["id", "c0", "c1"])
+
+        def hash_agg():
+            s = select()
+            dag = s.aggregate(
+                [s.col("c0")],
+                [("count_star", None), ("sum", s.col("c1"))]
+            ).build(start_ts=c.tso())
+            return c.coprocessor(dag, timeout=900)
+
+        def same_rows(name, got, want):
+            got, want = sorted(got), sorted(want)
+            checks.require(f"{name}: equals numpy reference", got == want,
+                           lambda: f"{len(got)} rows vs {len(want)}; "
+                                   f"first {got[:2]} vs {want[:2]}")
+
+        # -- hash-agg: one cold, then warm --
+        want = ref_hash_agg(c0, c1)
+        cold = leg.request("hash_agg cold", hash_agg, CLASS_PALLAS)
+        same_rows("hash_agg cold", cold["resp"]["rows"], want)
+        # the device MVCC resolver is single-device by design
+        # (device/mvcc.py): a whole-mesh runner's cold build is the
+        # native C++ rung, and says so
+        want_cold = ("native", "upload") if whole_mesh else \
+            ("device", "device_resolve")
+        checks.require(
+            "hash_agg cold: cold_build / device_feed rung",
+            (cold["labels"].get("cold_build"),
+             cold["labels"].get("device_feed")) == want_cold,
+            f"{cold['labels']}, want cold_build/device_feed={want_cold}")
+        warm = []
+        for i in range(6):
+            w = leg.request(f"hash_agg warm {i}", hash_agg, CLASS_PALLAS)
+            same_rows(f"hash_agg warm {i}", w["resp"]["rows"], want)
+            checks.require(f"hash_agg warm {i}: device_feed=hit",
+                           w["labels"].get("device_feed") == "hit",
+                           w["labels"])
+            warm.append(w)
+
+        # -- simple agg --
+        def simple_agg():
+            s = select()
+            dag = s.aggregate([], [
+                ("sum", s.col("c1")), ("count", s.col("c1")),
+                ("avg", s.col("c1"))]).build(start_ts=c.tso())
+            return c.coprocessor(dag, timeout=900)
+
+        total = int(c1.sum())
+        simple = []
+        for i in range(2):
+            simple.append(leg.request(f"simple_agg {i}", simple_agg,
+                                      CLASS_PALLAS))
+            (row,) = simple[-1]["resp"]["rows"]
+            checks.require(
+                f"simple_agg {i}: equals numpy reference",
+                row[0] == total and row[1] == n and
+                abs(row[2] - total / n) <= 1e-9 * max(1.0, abs(total / n)),
+                f"{row} vs {(total, n, total / n)}")
+
+        # -- selection (2%: a unary gRPC response is capped at 4 MB by
+        #    the client's default, ~350k rows of this table) --
+        def selection():
+            s = select()
+            dag = s.where(s.col("c1") >= SEL_FLOOR).build(
+                start_ts=c.tso())
+            return c.coprocessor(dag, timeout=900)
+
+        hit = np.nonzero(c1 >= SEL_FLOOR)[0]
+        want_sel = [[int(h), int(c0[h]), int(c1[h])] for h in hit]
+        sel = []
+        for i in range(3):
+            sel.append(leg.request(f"selection {i}", selection,
+                                   CLASS_SELECTION))
+            same_rows(f"selection {i}", sel[-1]["resp"]["rows"], want_sel)
+
+        # -- TopN LIMIT 1000 (ties break by scan position) --
+        def topn():
+            s = select()
+            dag = s.order_by(s.col("c1"), desc=True,
+                             limit=TOPN_LIMIT).build(start_ts=c.tso())
+            return c.coprocessor(dag, timeout=900)
+
+        order = np.argsort(-c1, kind="stable")[:TOPN_LIMIT]
+        want_top = [[int(h), int(c0[h]), int(c1[h])] for h in order]
+        top = []
+        for i in range(2):
+            top.append(leg.request(f"topn {i}", topn, CLASS_TOPN))
+            got_top = top[-1]["resp"]["rows"]
+            checks.require(f"topn {i}: equals numpy reference",
+                           got_top == want_top,
+                           f"{got_top[:2]} vs {want_top[:2]}")
+
+        # -- plan-IR join: scan+select (probe) ⋈ build → host group-by --
+        def scan_node(t):
+            s, e = table_record_range(t.table_id)
+            return pir.ScanNode(
+                TableScanDesc(t.table_id, tuple(
+                    t.column_info(col.name) for col in t.columns)),
+                (KeyRange(s, e),))
+
+        def join(force):
+            probe = pir.SelectNode(scan_node(table), (
+                Expr.column(2, EvalType.INT) >
+                Expr.const(JOIN_FLOOR, EvalType.INT),))
+            plan = pir.AggNode(
+                pir.JoinNode(probe, scan_node(build_t), 1, 1),
+                AggregationDesc(
+                    (Expr.column(5, EvalType.INT),),    # build c1
+                    (AggExprDesc("count_star", None),
+                     AggExprDesc("sum", Expr.column(2, EvalType.INT))),
+                    False))
+            return c.coprocessor_plan(
+                pir.PlanRequest(plan, start_ts=c.tso()),
+                force_backend=force, timeout=900)
+
+        m = c1 > JOIN_FLOOR
+        group_of = np.empty(BUILD_ROWS, np.int64)
+        group_of[b0] = b1                       # join key → build c1
+        w = group_of[c0[m]]
+        jc = np.bincount(w, minlength=64)
+        js = np.zeros(64, np.int64)
+        np.add.at(js, w, c1[m])
+        want_join = [[int(jc[g]), int(js[g]), g]
+                     for g in range(64) if jc[g]]
+        # a whole-mesh runner without placement has no single-chip
+        # joiner: its joins are host joins by design (plan_ir._model),
+        # so there only exactness is held
+        if whole_mesh:
+            jn = {"wall_s": 0.0, "classes": "host join (whole mesh)"}
+            t0 = time.perf_counter()
+            same_rows("join (host, whole mesh)", join(None)["rows"],
+                      want_join)
+            jn["wall_s"] = time.perf_counter() - t0
+        else:
+            # unforced first — the request a user sends; if the
+            # fragment router kept the join on the host (its choice is
+            # a finding, not a check) the device join is forced once
+            jn = leg.request("join", lambda: join(None), CLASS_JOIN,
+                             backend="plan")
+            same_rows("join", jn["resp"]["rows"], want_join)
+            if "join_probe" not in jn["spans"]:
+                jn = leg.request("join forced device",
+                                 lambda: join("device"), CLASS_JOIN,
+                                 backend="plan", spans=("join_probe",))
+                same_rows("join forced device", jn["resp"]["rows"],
+                          want_join)
+        health = http_json(leg.status_port, "/health")
+        routed = health["plan_ir"]["router"]["decisions"]
+
+        # -- one acknowledged write must be in the next answer --
+        key, value = encode_table_row(table, n, {"c0": 7, "c1": 123})
+        c.txn_write([("put", key, value)])
+        c0w = np.append(c0, 7)
+        c1w = np.append(c1, 123)
+        post = leg.request("hash_agg after write", hash_agg, CLASS_PALLAS)
+        same_rows("hash_agg after write", post["resp"]["rows"],
+                  ref_hash_agg(c0w, c1w))
+        checks.require(
+            "hash_agg after write: copr_cache=delta, device_feed=patch",
+            post["labels"].get("copr_cache") == "delta" and
+            post["labels"].get("device_feed") == "patch", post["labels"])
+
+        rollup = leg.health_checks()
+        by_dev = rollup["hbm"]["resident_bytes_by_device"]
+        checks.require(
+            "feed resident on every mesh device",
+            len(by_dev) == leg.device["count"] and
+            all(b > 0 for b in by_dev.values()), by_dev)
+        leg.stop()
+    except BaseException:
+        leg.kill()
+        raise
+    walls = sorted(w["wall_s"] for w in warm)
+
+    def family(reqs) -> dict:
+        """Compile classes seen + first (cold: compile, feed upload)
+        and last (warm) wall seconds of one query family."""
+        return {"classes": sorted({k for r in reqs for k in r["classes"]}),
+                "first_s": round(reqs[0]["wall_s"], 3),
+                "last_s": round(reqs[-1]["wall_s"], 3)}
+
+    return {
+        "leg": "whole_mesh" if whole_mesh else "single_chip",
+        "device": leg.device, "rows": n, "load_s": round(load_s, 2),
+        "cold_s": round(cold["wall_s"], 3),
+        "cold_labels": cold["labels"],
+        "cold_phases_ms": cold["phases_ms"],
+        "warm_p50_s": walls[len(walls) // 2], "warm_n": len(walls),
+        "warm_phases_ms": warm[-1]["phases_ms"],
+        "queries": {
+            "hash_agg": family([cold] + warm),
+            "simple_agg": family(simple),
+            "selection": {**family(sel), "rows": len(want_sel),
+                          "routing": sel[-1]["labels"].get("routing")},
+            "topn": family(top),
+            "join": {"classes": jn["classes"],
+                     "first_s": round(jn["wall_s"], 3), "router": routed},
+            "after_write": {**family([post]),
+                            "device_feed":
+                            post["labels"].get("device_feed")}},
+        "resident_bytes_by_device": by_dev,
+        "pinned_readback": rollup["pinned_readback"],
+        "flight_recorder": rollup["flight_recorder"],
+        "compile_cache": rollup["compile_cache"],
+    }
+
+
+def placement_leg(args, checks: Checks, workdir: str) -> dict:
+    """Four chips only: ``device-placement = true`` and four small
+    tables — anchors must spread over more than one slice, answers
+    exact, each slice's kernel the fused one."""
+    from tikv_tpu.testing.dag import DagSelect
+    from tikv_tpu.testing.fixture import int_table
+
+    n = min(PLACEMENT_ROWS, args.rows)
+    leg = ServedLeg(args, checks, workdir, "placement",
+                    extra_toml="device-placement = true")
+    try:
+        c = leg.connect()
+        handles = np.arange(n, dtype=np.int64)
+        tables = []
+        for i in range(4):
+            t = int_table(2, table_id=TABLE_ID + 10 + i)
+            c0, c1 = table_data(args.seed, 10 + i, n)
+            leg.load(t, handles, c0, c1)
+            tables.append((t, c0, c1))
+        for rnd in range(2):        # cold, then warm
+            for i, (t, c0, c1) in enumerate(tables):
+                def agg(t=t):
+                    s = DagSelect.from_table(t, ["id", "c0", "c1"])
+                    dag = s.aggregate(
+                        [s.col("c0")],
+                        [("count_star", None), ("sum", s.col("c1"))]
+                    ).build(start_ts=c.tso())
+                    return c.coprocessor(dag, timeout=900)
+                r = leg.request(f"placement table {i} round {rnd}", agg,
+                                CLASS_PALLAS)
+                checks.require(
+                    f"placement table {i} round {rnd}: equals numpy "
+                    f"reference",
+                    sorted(r["resp"]["rows"]) == ref_hash_agg(c0, c1))
+        rollup = leg.health_checks()
+        placed = [s["placed_anchors"]
+                  for s in rollup["mesh"]["placement"]["slices"]]
+        checks.require("placement: anchors on more than one slice",
+                       sum(1 for k in placed if k > 0) > 1, placed)
+        by_dev = rollup["hbm"]["resident_bytes_by_device"]
+        checks.require("placement: feeds resident on more than one device",
+                       sum(1 for b in by_dev.values() if b > 0) > 1, by_dev)
+        leg.stop()
+    except BaseException:
+        leg.kill()
+        raise
+    return {"leg": "placement", "rows_per_table": n,
+            "placed_anchors": placed,
+            "resident_bytes_by_device": by_dev}
+
+
+# ---------------------------------------------------------- phase B
+
+
+def phase_b_child(args) -> int:
+    """Runs in its own process (the only one holding the chip): the
+    config-4 shape through ``DeviceRunner().handle_request``.  Prints
+    one JSON line for the parent."""
+    import jax
+
+    from tikv_tpu.datatype import Column, EvalType, FieldType
+    from tikv_tpu.device import DeviceRunner
+    from tikv_tpu.executors.columnar import ColumnarTable
+    from tikv_tpu.testing.dag import DagSelect
+    from tikv_tpu.testing.fixture import Table, TableColumn
+    from tikv_tpu.utils import tracker
+
+    checks = Checks(args.allow_cpu)
+    dev0 = jax.devices()[0]
+    device = {"platform": dev0.platform, "kind": dev0.device_kind,
+              "count": len(jax.devices())}
+    if dev0.platform != "tpu" and not args.allow_cpu:
+        raise SmokeFailure(f"platform check: jax.devices()[0].platform "
+                           f"is {dev0.platform!r}, not 'tpu'")
+    checks.on_chip("phase B: platform==tpu", dev0.platform == "tpu")
+
+    n = args.rows
+    t0 = time.perf_counter()
+    k, v = table_data(args.seed, 1, n)
+    table = Table(99, (
+        TableColumn("id", 1, FieldType.long(not_null=True),
+                    is_pk_handle=True),
+        TableColumn("k", 2, FieldType.long()),
+        TableColumn("v", 3, FieldType.long())))
+    ones = np.ones(n, np.bool_)
+    snap = ColumnarTable.from_arrays(
+        table, np.arange(n, dtype=np.int64),
+        {"k": Column(EvalType.INT, k, ones),
+         "v": Column(EvalType.INT, v, ones)})
+    want = ref_hash_agg(k, v)
+    build_s = time.perf_counter() - t0
+    log(f"phase B: built {n} rows + reference in {build_s:.1f}s")
+
+    runner = DeviceRunner()
+
+    def one(name, snap=snap, want=want):
+        s = DagSelect.from_table(table, ["id", "k", "v"])
+        dag = s.aggregate([s.col("k")], [("count_star", None),
+                                        ("sum", s.col("v"))]).build()
+        tr, tok = tracker.install()
+        try:
+            t0 = time.perf_counter()
+            result = runner.handle_request(dag, snap)
+            wall = time.perf_counter() - t0
+        finally:
+            tracker.uninstall(tok)
+        td = tr.time_detail()
+        labels = td.get("labels", {})
+        checks.require(f"phase B {name}: not degraded",
+                       "degraded" not in labels and
+                       "host_exec" not in td["phases_ms"], td)
+        rows = [list(r) for r in result.rows()]
+        checks.require(f"phase B {name}: equals numpy reference",
+                       sorted(rows) == want,
+                       f"{len(rows)} rows; {sorted(rows)[:2]} vs {want[:2]}")
+        log(f"phase B {name}: {wall * 1e3:.1f} ms "
+            f"phases_ms={td['phases_ms']}")
+        return wall, td["phases_ms"]
+
+    cold_s, _ = one("cold")
+    warm = [one(f"warm {i}") for i in range(3)]
+
+    # the kernel's sparse slot mode (BASELINE 4s: 1k distinct keys
+    # drawn from [0, 2^62)), with a ragged last block
+    ns = min(n, ROWS_A) + 4097
+    rng = np.random.default_rng([args.seed, 2])
+    domain = np.unique(rng.integers(0, 1 << 62, 1000, dtype=np.int64))
+    slot = rng.integers(0, domain.size, ns)
+    vs = rng.integers(-1000, 1000, ns).astype(np.int64)
+    sparse_snap = ColumnarTable.from_arrays(
+        table, np.arange(ns, dtype=np.int64),
+        {"k": Column(EvalType.INT, domain[slot], np.ones(ns, np.bool_)),
+         "v": Column(EvalType.INT, vs, np.ones(ns, np.bool_))})
+    cnt = np.bincount(slot, minlength=domain.size)
+    sm = np.zeros(domain.size, np.int64)
+    np.add.at(sm, slot, vs)
+    sparse_want = sorted([int(cnt[i]), int(sm[i]), int(domain[i])]
+                         for i in range(domain.size) if cnt[i])
+    sparse_s = [one(f"sparse {i}", sparse_snap, sparse_want)[0]
+                for i in range(2)]
+    fr = runner.flight_recorder
+    classes = sorted({e["compile_class"] for e in fr.items()})
+    checks.on_chip("phase B: every launch is pallas_hash",
+                   set(classes) == CLASS_PALLAS, classes)
+    checks.require("phase B: flight-recorder faults == 0",
+                   fr.stats()["faults"] == 0, fr.stats())
+    disabled = [str(key)[:120] for key, val in
+                runner._kernel_cache.items()
+                if key[0] == "hashpl" and val is False]
+    checks.require("phase B: no cache-disabled pallas plan",
+                   not disabled, disabled)
+    by_dev = runner.hbm_stats()["resident_bytes_by_device"]
+    checks.require("phase B: feed resident on every device",
+                   len(by_dev) == device["count"] and
+                   all(b > 0 for b in by_dev.values()), by_dev)
+    walls = sorted(w for w, _ in warm)
+    print(json.dumps({
+        "device": device, "rows": n, "build_s": round(build_s, 2),
+        "cold_s": round(cold_s, 3),
+        "warm_p50_s": walls[len(walls) // 2], "warm_n": len(walls),
+        "warm_phases_ms": warm[-1][1], "classes": classes,
+        "sparse": {"rows": ns, "first_s": round(sparse_s[0], 3),
+                   "last_s": round(sparse_s[1], 3)},
+        "resident_bytes_by_device": by_dev,
+        "compile_cache": runner.compile_cache_stats(),
+        "versions": {"jax": jax.__version__},
+        "passed": checks.passed, "skipped": checks.skipped},
+        separators=(",", ":")), flush=True)
+    return 0
+
+
+def phase_b(args, checks: Checks, workdir: str) -> dict:
+    argv = [sys.executable, os.path.abspath(__file__), "--child",
+            "phase_b", "--seed", str(args.seed), "--rows",
+            str(args.rows if args.allow_cpu else ROWS_B)]
+    if args.allow_cpu:
+        argv.append("--allow-cpu")
+    child = Child("phase_b", argv, workdir)
+    rc = child.wait(900)
+    sys.stderr.write(child.read("err"))
+    checks.require("phase B: child exit code 0", rc == 0,
+                   f"rc={rc}\n{child.tail()}")
+    out = json.loads(child.read().strip().splitlines()[-1])
+    checks.passed += out.pop("passed")
+    checks.skipped += out.pop("skipped")
+    return out
+
+
+# -------------------------------------------------------------- main
+
+
+def cache_entries(path: str) -> int:
+    try:
+        return sum(1 for f in os.listdir(path) if not f.endswith("-atime"))
+    except FileNotFoundError:
+        return 0
+
+
+def version_of(dist: str) -> str:
+    try:
+        return metadata.version(dist)
+    except metadata.PackageNotFoundError:
+        return "absent"
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="dry run: skip the platform and kernel-class "
+                         "checks and say so in the summary")
+    ap.add_argument("--rows", type=int, default=None,
+                    help="rows per phase (dry run only)")
+    ap.add_argument("--child", choices=["phase_b"], help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.rows is not None and not args.allow_cpu and not args.child:
+        ap.error("--rows cuts the run to a dry-run size: it needs "
+                 "--allow-cpu")
+    if args.rows is None:
+        args.rows = ROWS_A
+    if args.child == "phase_b":
+        return phase_b_child(args)
+
+    try:
+        import tikv_tpu
+        from tikv_tpu import native
+    except ImportError as e:
+        raise SmokeFailure(f"checkout check: chip_smoke.py drives the "
+                           f"tikv_tpu package beside it — {e}")
+    checks = Checks(args.allow_cpu)
+    # a store that has silently lost its C++ loader is a failure, not a
+    # slow run
+    for fn in ("mvcc_build_columnar", "build_mvcc_sst",
+               "mvcc_parse_planes"):
+        checks.require(f"native.{fn} built",
+                       getattr(native, fn) is not None,
+                       "g++ build of native/fastbuild.cpp failed")
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(tikv_tpu.__file__))), ".jax_cache")
+    cache_before = cache_entries(cache_dir)
+
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
+        legs = [served_leg(args, checks, workdir)]
+        device = legs[0]["device"]
+        if device["count"] == 4:
+            legs.append(placement_leg(args, checks, workdir))
+        b = phase_b(args, checks, workdir)
+    checks.require(
+        "phase B saw the device phase A saw",
+        (b["device"]["platform"], b["device"]["kind"],
+         b["device"]["count"]) ==
+        (device["platform"], device["kind"], device["count"]),
+        f"{b['device']} vs {device}")
+    served_cache = legs[0]["compile_cache"]["dir"]
+    checks.on_chip("compile cache placed where expected",
+                   served_cache == cache_dir == b["compile_cache"]["dir"],
+                   f"store {served_cache!r}, phase B "
+                   f"{b['compile_cache']['dir']!r}, want {cache_dir!r}")
+    checks.require("parent never imported jax",
+                   "jax" not in sys.modules)
+
+    summary = {
+        "smoke_readings_not_benchmark_results": True,
+        "platform": device["platform"], "device_kind": device["kind"],
+        "n_devices": device["count"], "mesh": device["mesh"],
+        "versions": {"jax": b["versions"]["jax"],
+                     "jaxlib": version_of("jaxlib"),
+                     "libtpu": version_of("libtpu")},
+        "seed": args.seed,
+        "phase_a": legs, "phase_b": b,
+        "compile_cache": {"dir": cache_dir, "entries_before": cache_before,
+                          "entries_after": cache_entries(cache_dir)},
+        "checks_passed": len(checks.passed),
+        "checks_skipped": checks.skipped,
+    }
+    if args.allow_cpu:
+        summary["dry_run"] = True
+    summary["claim"] = None
+    print(json.dumps(summary, separators=(",", ":")), flush=True)
+    # The result line — last, and only from a run that held every check
+    # on an accelerator, with the device as phase B's child read it from
+    # JAX (checked equal to the store's above).  A dry run ends on its
+    # labelled summary: it is not a result.
+    if not args.allow_cpu:
+        print(json.dumps({"ok": True, "device": b["device"]}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    try:
+        sys.exit(main())
+    except SmokeFailure as e:
+        print(f"chip_smoke: FAILED — {e}", file=sys.stderr, flush=True)
+        sys.exit(1)

@@ -497,3 +497,20 @@ def test_partial_range_hash_agg_tile_detection():
     # sanity: the subset differs from the full-region answer
     full = sorted(runner.handle_request(dag, snap).rows())
     assert got != full
+
+
+def test_pad_rows_keeps_compile_class_on_first_append_at_exact_fill():
+    """A bulk load that exactly fills its blocks (power-of-two row
+    counts) must pad like its first append does, at EVERY bucket-grid
+    granularity — else one written row re-uploads the feed and
+    recompiles its kernels (seen on a 2x2 TPU mesh: ten 2^20-row
+    blocks, grid one block wide)."""
+    import jax
+
+    from tikv_tpu.parallel import make_mesh
+    r = DeviceRunner(mesh=make_mesh(jax.devices()[:1]))
+    unit = r._feed_unit()
+    for blocks in (9, 10, 15, 16, 40, 100, 400):
+        n = blocks * unit
+        assert r._pad_rows(n) == r._pad_rows(n + 1), blocks
+        assert r._pad_rows(n) >= n + 1

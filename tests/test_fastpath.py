@@ -584,8 +584,9 @@ def test_pipeline_close_feeds_drained_device():
 
 
 def test_pinned_stager_disabled_on_cpu_default():
-    """CPU jax has no pinned_host space: the stager probes once,
-    disables itself, and readback is byte-identical."""
+    """The CPU backend cannot run the host-placement program (JAX
+    0.9.0: no annotate_device_placement there): the stager probes
+    once, disables itself, and readback is byte-identical."""
     import jax.numpy as jnp
 
     from tikv_tpu.device.runner import _PinnedStager

@@ -6,9 +6,10 @@ row-sharded feeds, per-shard partial aggregation with the psum /
 all-to-all tree-reduce, shard-concatenable selection routing, sharded
 delta patching — runs against the REAL shard_map lowering and is
 asserted bit-identical to the single-device and host backends.  The
-fused Pallas rung needs real TPU lowering and is exercised by the
-MULTICHIP artifact harness (__graft_entry__.dryrun_multichip) on
-hardware; these tests pin the semantics every rung must agree on.
+fused Pallas rung needs real TPU lowering: its sharded wrap is pinned
+in interpret mode by tests/test_pallas_hash_interpret.py and on
+hardware by chip_smoke.py; these tests pin the semantics every rung
+must agree on.
 """
 
 from __future__ import annotations
@@ -433,6 +434,12 @@ def test_placement_spreads_anchors_and_rebalances():
     # 9 anchors over 8 slices: every slice gets at least one
     assert st["places"] == 9
     assert all(sl["placed_anchors"] >= 1 for sl in st["slices"]), st
+    # ...and the bytes live where the bookkeeping says: every chip
+    # holds a feed, not all nine the process default device (a single-
+    # device runner's uncommitted uploads land there unless pinned)
+    by_dev = runner.hbm_stats()["resident_bytes_by_device"]
+    assert sorted(by_dev) == [d.id for d in jax.devices()] and \
+        all(by_dev.values()), by_dev
     # two anchors share one slice (the tie-break slice); heat the one
     # that was placed FIRST, then rebalance: the COLD co-tenant moves
     doubled = max(range(8),

@@ -67,8 +67,7 @@ class RaftstoreConfig:
 @dataclass
 class CoprocessorConfig:
     # device routing crossover — rationale at
-    # copr/endpoint.py Endpoint.DEFAULT_DEVICE_ROW_THRESHOLD; raise to
-    # ~2^22 for tunneled (high-RTT) device transports
+    # copr/endpoint.py Endpoint.DEFAULT_DEVICE_ROW_THRESHOLD
     device_row_threshold: int = 131072
     region_cache_capacity: int = 8
     # paged response budget (endpoint.rs paging)

@@ -58,6 +58,7 @@ dispatch for failpoint-driven per-fragment host degrade.
 
 from __future__ import annotations
 
+import functools
 import threading
 import weakref
 from collections import OrderedDict
@@ -82,6 +83,17 @@ _MAX_ENTRIES = 64
 _DEFAULT_CACHE_BYTES = 1 << 28
 
 _DEVICE_KEY_ETS = (EvalType.INT,)
+
+
+def _on_runner_device(method):
+    """Run a joiner entry point where its runner's chip is (a
+    placement slice's uploads and launches must not land on the
+    process default device — runner._device_scope)."""
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        with self._runner._device_scope():
+            return method(self, *args, **kwargs)
+    return wrapped
 
 
 class JoinDeviceUnavailable(Exception):
@@ -339,6 +351,7 @@ class DeviceJoiner:
         return join_supported(probe_scan, probe_conds, left_key,
                               build_scan, right_key)
 
+    @_on_runner_device
     def join(self, probe_scan, probe_ranges, probe_storage, probe_conds,
              left_key: int, build_scan, build_ranges, build_storage,
              right_key: int) -> Optional[tuple]:
@@ -476,6 +489,7 @@ class DeviceJoiner:
 
     # ------------------------------------------------------------- sort
 
+    @_on_runner_device
     def sort_perm(self, keys: Sequence[np.ndarray], n: int) -> np.ndarray:
         """Stable composed argsort on device → host permutation (the
         sort fragment's ONLY D2H payload); padding rows are pushed
@@ -505,6 +519,7 @@ class DeviceJoiner:
 
     # ----------------------------------------------------------- window
 
+    @_on_runner_device
     def window(self, batch, node: WindowNode):
         """Device window fragment over a host batch: keys/args upload,
         one dispatch sorts + segmented-scans, the host gathers the

@@ -29,12 +29,6 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 
-try:                                    # varying-manual-axes typing
-    _pvary = lax.pvary
-except AttributeError:                  # jax 0.4.x: replication implicit
-    def _pvary(x, axes):
-        return x
-
 BLOCK_ROWS = 1 << 16
 
 
@@ -243,8 +237,9 @@ def matmul_groupby(idx, L8, Lf, slots: int, block: int = BLOCK_ROWS,
     Sf: (Pf, slots) float64 | None).
 
     ``vary_axes``: when called inside shard_map, the mesh axis names — the
-    scan carry must be marked device-varying (lax.pvary) to match the body
-    output's varying-manual-axes type."""
+    scan carry must be marked device-varying (``lax.pcast(...,
+    to="varying")``) to match the body output's varying-manual-axes
+    type."""
     import math
     G = slot_pad(slots)
     n = idx.shape[0]
@@ -267,7 +262,7 @@ def matmul_groupby(idx, L8, Lf, slots: int, block: int = BLOCK_ROWS,
         xs = (idx_b, l8_b)
         carry = (jnp.zeros((p8, G), jnp.int64), None)
     if vary_axes:
-        carry = tuple(None if c is None else _pvary(c, vary_axes)
+        carry = tuple(None if c is None else lax.pcast(c, vary_axes, to="varying")
                       for c in carry)
 
     def body(carry, xs):

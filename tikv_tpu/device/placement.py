@@ -354,6 +354,11 @@ class SlicePlacer:
             m.DEVICE_FAILOVER_COUNTER.labels("failover").inc()
         if rebalance:
             self.rebalance()
+            # the balance step may have moved THIS anchor: serving it
+            # from the slice picked above would re-upload a copy the
+            # pin no longer points at (and no drain ever finds)
+            with self._mu:
+                idx = self._placed.get(key, idx)
         return self._slices[idx]
 
     def owner(self, anchor):

@@ -66,11 +66,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax >= 0.5 top-level alias
-    _shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..datatype import device_const_dtype
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef, RpnConst, RpnExpression
@@ -264,7 +259,7 @@ def build_mask_kernel(sel_rpns, null_flags, n_pad: int, n_flat: int,
 
     if mesh is None:
         return jax.jit(local_fn)
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(),) * (1 + n_params) + (P(ROW_AXES),) * n_flat,
         out_specs=(P(), P(ROW_AXES), P(ROW_AXES))))
@@ -348,7 +343,7 @@ def build_index_kernel(n_pad: int, k_cap: int, mesh=None):
 
     if mesh is None:
         return jax.jit(local_fn)
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(ROW_AXES),),
         out_specs=(P(ROW_AXES), P())))

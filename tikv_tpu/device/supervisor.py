@@ -54,6 +54,7 @@ lifecycle teardown) without paying the accelerator runtime import.
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 import weakref
@@ -88,19 +89,21 @@ def host_plane_digest(arr: np.ndarray, n: int) -> int:
         return int((u * (2 * idx + 1)).sum(dtype=np.uint64))
 
 
-def _bucket_nbytes(bucket: dict) -> int:
-    """Device bytes held by one anchor's cache bucket: feed planes plus
+def _bucket_arrays(bucket: dict):
+    """Device arrays held by one anchor's cache bucket: feed planes plus
     cached sparse-slot columns inside request memos."""
-    total = 0
-    for v in bucket.values():
+    for v in list(bucket.values()):     # status threads race inserts
         if not isinstance(v, dict):
             continue
-        for a in v.get("flat", ()):
-            total += int(getattr(a, "nbytes", 0))
+        yield from v.get("flat", ())
         ss = v.get("sparse_slots")
         if ss is not None:
-            total += int(getattr(ss[3], "nbytes", 0))
-    return total
+            yield ss[3]
+
+
+def _bucket_nbytes(bucket: dict) -> int:
+    return sum(int(getattr(a, "nbytes", 0))
+               for a in _bucket_arrays(bucket))
 
 
 # ----------------------------------------------------- flight recorder
@@ -894,6 +897,24 @@ class FeedArena:
                 t = ResourceTagFactory.tenant(e.owner_tag)
                 out[t] = out.get(t, 0) + e.nbytes
             return out
+
+    def resident_bytes_by_device(self) -> dict:
+        """Resident bytes per PHYSICAL device id, read from where each
+        plane's shards actually live (not from the accounting) — the
+        check that a "sharded" feed is not sitting whole on device 0."""
+        with self._mu:
+            buckets = [e.bucket for e in self._entries.values()]
+        out: dict = {}
+        for b in buckets:
+            for a in _bucket_arrays(b):
+                sharding = getattr(a, "sharding", None)
+                if sharding is None:
+                    continue
+                per_dev = a.dtype.itemsize * math.prod(
+                    sharding.shard_shape(a.shape))
+                for d in sharding.device_set:
+                    out[d.id] = out.get(d.id, 0) + per_dev
+        return out
 
     def items(self) -> list:
         """Snapshot of (anchor, bucket) pairs with live anchors — the

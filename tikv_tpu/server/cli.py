@@ -103,11 +103,19 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     if args.cmd == "pd":
+        import signal
+        import threading
+
         from .pd_server import PdServer
         server = PdServer(args.addr)
         print(f"pd listening on {args.addr}", flush=True)
         server.start()
-        server.wait()
+        # graceful shutdown on SIGTERM/SIGINT, like the store below
+        stop = threading.Event()
+        signal.signal(signal.SIGTERM, lambda *_: stop.set())
+        signal.signal(signal.SIGINT, lambda *_: stop.set())
+        stop.wait()
+        server.stop()
         return 0
 
     if args.cmd == "tikv":
@@ -141,6 +149,25 @@ def main(argv=None) -> int:
                     flight_recorder_depth=cc.flight_recorder_depth)
             else:
                 device_runner = DeviceRunner()
+            import os
+
+            import jax
+            ms = device_runner.mesh_stats()
+            print(f"device runner: platform={ms['platform']} "
+                  f"device_kind={ms['device_kind']!r} "
+                  f"n_devices={len(jax.devices())} "
+                  f"mesh={'x'.join(str(v) for v in ms['shape'].values())}",
+                  flush=True)
+            if ms["platform"] == "cpu" and "cpu" not in os.environ.get(
+                    "JAX_PLATFORMS", "").split(","):
+                # --with-device asked for an accelerator and JAX found
+                # none: refuse to serve every "device" request from
+                # XLA's CPU backend unless the operator chose it
+                print("--with-device found no accelerator (JAX fell "
+                      "back to cpu); set JAX_PLATFORMS=cpu to serve the "
+                      "device path on the CPU backend deliberately",
+                      file=sys.stderr, flush=True)
+                return 1
         if args.status_addr and config is not None:
             config.server.status_addr = args.status_addr
         node = Node(args.addr, RemotePdClient(args.pd),
@@ -158,7 +185,7 @@ def main(argv=None) -> int:
             attach,
         )
         events = ServiceEventChannel()
-        attach(events, server)
+        dispatcher = attach(events, server)
 
         def _on_signal(signum, _frame):
             print(f"received signal {signum}; shutting down", flush=True)
@@ -172,6 +199,12 @@ def main(argv=None) -> int:
         print(f"tikv store {node.store_id} listening on {args.addr}",
               flush=True)
         server.wait()
+        # wait() returns as soon as the gRPC server begins stopping;
+        # the dispatcher thread is still inside server.stop() (node
+        # teardown, pool joins).  Returning before it finishes lets
+        # interpreter finalization race the teardown — with device
+        # state live that aborts the process instead of exiting 0.
+        dispatcher.join()
         return 0
 
     # ctl

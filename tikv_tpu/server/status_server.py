@@ -113,6 +113,11 @@ class StatusServer:
                         # late-materialized selection: routing-decision
                         # counts + per-plan observed-selectivity EWMAs
                         body["device_selection"] = dr.selection_stats()
+                    if dr is not None and \
+                            hasattr(dr, "compile_cache_stats"):
+                        # persistent XLA compile cache: where it lives
+                        # and this process's requests/hits/writes
+                        body["compile_cache"] = dr.compile_cache_stats()
                     if dr is not None and hasattr(dr, "mesh_stats"):
                         # multi-chip rollup: mesh shape (incl. any
                         # coprocessor.mesh_shape override), and when

@@ -1,4 +1,4 @@
-"""MVCC error taxonomy.
+"""MVCC error kinds.
 
 Reference: src/storage/mvcc/mod.rs ErrorInner variants (KeyIsLocked,
 WriteConflict, TxnLockNotFound, Committed, AlreadyExist,
