@@ -74,6 +74,7 @@ from ..datatype import EvalType
 from ..expr import build_rpn
 from ..expr.eval import eval_rpn
 from ..utils.failpoint import fail_point
+from .kernels import named_program
 
 _I64 = np.iinfo(np.int64)
 
@@ -282,7 +283,7 @@ class DeviceJoiner:
                     [jnp.zeros(1, jnp.int64),
                      jnp.cumsum(svs.astype(jnp.int64))])
                 return sk, perm.astype(jnp.int32), prefix
-            return jax.jit(fn)
+            return jax.jit(named_program(fn, "join_build"))
         return self._kern(("join_build", n_pad), build)
 
     def _probe_kernel(self, np_probe: int, np_build: int, k_cap: int,
@@ -324,7 +325,7 @@ class DeviceJoiner:
                 pi = jnp.where(ok_pair, probe_of, -1).astype(jnp.int32)
                 bi = jnp.where(ok_pair, bidx, -1).astype(jnp.int32)
                 return pi, bi, total
-            return jax.jit(fn)
+            return jax.jit(named_program(fn, "join_probe"))
         return self._kern(("join_probe", np_probe, np_build, k_cap,
                            null_like_sig, n_params), build)
 
@@ -505,7 +506,7 @@ class DeviceJoiner:
                 for k in list(ks)[::-1] + [pad_key]:
                     perm = perm[jnp.argsort(k[perm])]
                 return perm.astype(jnp.int32)
-            return jax.jit(fn)
+            return jax.jit(named_program(fn, "sort_perm"))
         kfn = self._kern(("sort", n_pad, dts), build)
         with self._runner._dispatch_phase("sort_perm",
                                           key=("sort", n_pad, dts)):
@@ -615,7 +616,7 @@ class DeviceJoiner:
                                               jnp.zeros((), v.dtype)))
                         outs.append(valid)
                 return tuple(outs)
-            return jax.jit(fn)
+            return jax.jit(named_program(fn, "window"))
         kfn = self._kern(("window",) + sig, build)
         args = [self._pad_plane(np.asarray(k), n_pad)
                 for k in part_keys + order_keys]

@@ -21,15 +21,31 @@ each aggregate appends its own validity plane and value planes.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 BLOCK_ROWS = 1 << 16
+
+
+def named_program(fn, klass: str):
+    """``fn`` under the name of its flight-recorder compile class, for
+    ``jax.jit``: the XLA module is then ``jit_<klass>`` (a profile's
+    'XLA Modules' line tells selection from TopN, and a module maps to
+    a ``compile_class`` by eye) and its ops trace under
+    ``jax.named_scope(klass)``."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        with jax.named_scope(klass):
+            return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = klass
+    return program
 
 
 def slot_pad(slots: int) -> int:

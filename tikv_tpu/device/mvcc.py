@@ -521,9 +521,9 @@ class DeviceMvccResolver:
         with self._mu:
             fn = self._dus_fn
             if fn is None:
-                def _upd(a, u, i):
+                def h2d_stream_patch(a, u, i):
                     return lax.dynamic_update_slice(a, u, (i,))
-                fn = self._dus_fn = jax.jit(_upd)
+                fn = self._dus_fn = jax.jit(h2d_stream_patch)
         return fn(arr, update, jnp.asarray(lo, jnp.int32))
 
     # -- the resolve + gather kernel --------------------------------------
@@ -545,8 +545,8 @@ class DeviceMvccResolver:
         import jax
         import jax.numpy as jnp
 
-        def resolve(read_ts, n_out, commit_ts, wtype, seg_id, handles,
-                    *planes):
+        def mvcc_resolve(read_ts, n_out, commit_ts, wtype, seg_id,
+                         handles, *planes):
             i32 = jnp.int32
             elig = (commit_ts <= read_ts) & (wtype <= WT_DELETE)
             score = jnp.where(elig, commit_ts, jnp.int64(0))
@@ -572,7 +572,7 @@ class DeviceMvccResolver:
                     outs.append(planes[s[1]][idx] & live)
             return tuple(outs)
 
-        fn = jax.jit(resolve)
+        fn = jax.jit(mvcc_resolve)
         with self._mu:
             self._kernels[key] = fn
         return fn

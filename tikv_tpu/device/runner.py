@@ -97,6 +97,7 @@ from ..ops.agg import (
     simple_agg_tile,
 )
 from ..parallel import ROW_AXES, make_mesh, num_shards, row_sharding
+from .kernels import named_program
 
 _BIG = np.iinfo(np.int64).max
 
@@ -386,7 +387,8 @@ class _PinnedStager:
         try:
             from jax.sharding import SingleDeviceSharding
             out = SingleDeviceSharding(dev, memory_kind=self.memory_kind)
-            fn = jax.jit(lambda a: a, out_shardings=out)
+            fn = jax.jit(named_program(lambda a: a, "pinned_stage"),
+                         out_shardings=out)
             fn(x)                   # probe: compiles + runs once
             self.enabled = True
         except Exception as e:  # noqa: BLE001 — placement unsupported
@@ -693,6 +695,12 @@ class DeviceRunner:
         # importing the package has no process-global side effect.)
         jax.config.update("jax_enable_x64", True)
         _place_compile_cache()
+        # the request spans that are work on some thread go to the JAX
+        # profiler as ``copr:<span>`` annotations, on the device trace's
+        # clock (utils/trace.py itself imports no JAX; an annotation
+        # outside a profiler session costs one flag test)
+        from ..utils import trace
+        trace.set_annotator(jax.profiler.TraceAnnotation)
         self._mesh = mesh if mesh is not None else make_mesh()
         self._max_hash_capacity = max_hash_capacity
         self._max_topn_limit = max_topn_limit
@@ -1881,11 +1889,11 @@ class DeviceRunner:
         span, exactly like the single-device path."""
         fn = self._kernel_cache.get("feed_patch_fn")
         if fn is None:
-            def _upd(a, u, i):
+            def feed_patch(a, u, i):
                 return lax.dynamic_update_slice(a, u, (i,))
-            fn = self._kernel_cache["feed_patch_fn"] = jax.jit(_upd) \
-                if self._single else \
-                jax.jit(_upd, out_shardings=self._row_sharding)
+            fn = self._kernel_cache["feed_patch_fn"] = \
+                jax.jit(feed_patch) if self._single else \
+                jax.jit(feed_patch, out_shardings=self._row_sharding)
         return fn(arr, update, jnp.asarray(lo, jnp.int32))
 
     # ------------------------------------- device-state supervision
@@ -2069,7 +2077,7 @@ class DeviceRunner:
                     return lax.bitcast_convert_type(x, _udt) \
                         .astype(jnp.uint64)
 
-            def kern(x, lo_arr, hi_arr):
+            def feed_digest(x, lo_arr, hi_arr):
                 iota = jnp.arange(n_pad, dtype=jnp.uint64)
                 w = 2 * iota + 1
                 sel = (iota >= lo_arr.astype(jnp.uint64)) & \
@@ -2077,7 +2085,7 @@ class DeviceRunner:
                 return jnp.sum(jnp.where(sel, to_bits(x) * w,
                                          jnp.uint64(0)))
 
-            fn = self._kernel_cache[key] = jax.jit(kern)
+            fn = self._kernel_cache[key] = jax.jit(feed_digest)
         return fn
 
     def device_digest(self, arr, n: int):
@@ -2260,7 +2268,8 @@ class DeviceRunner:
                     iota = jnp.arange(n_pad_child)
                     return jnp.where(iota < n_child, y,
                                      jnp.zeros((), y.dtype))
-            fn = self._kernel_cache[key] = jax.jit(kern)
+            fn = self._kernel_cache[key] = jax.jit(
+                named_program(kern, "device_split"))
         return fn
 
     def split_resident_feeds(self, spec) -> str:
@@ -2599,8 +2608,9 @@ class DeviceRunner:
 
         return local_fn
 
-    def _wrap_mega(self, local_fn, carry_example, n_flat: int,
-                   ys_specs=None):
+    def _wrap_mega(self, klass: str, local_fn, carry_example,
+                   n_flat: int, ys_specs=None):
+        local_fn = named_program(local_fn, klass)
         if self._single:
             return jax.jit(local_fn)
         cs = self._carry_specs(carry_example)
@@ -2957,6 +2967,7 @@ class DeviceRunner:
             o1 = jnp.take_along_axis(ok.reshape(nseg, seglen), ki1, axis=1)
             return gidx, m1.reshape(-1)[sel], o1.reshape(-1)[sel]
 
+        local_fn = named_program(local_fn, "topn")
         if self._single:
             return jax.jit(local_fn)
         return jax.jit(jax.shard_map(
@@ -3045,7 +3056,19 @@ class DeviceRunner:
                     x.copy_to_host_async()
                 except Exception:   # pragma: no cover - CPU arrays
                     pass
-            fetched = [np.asarray(x) for x in leaves]
+            # span-only children: device_wait is the program not
+            # finished yet (with the pinned stager its last step IS the
+            # copy into pinned host memory), d2h_copy the transfer +
+            # sync np.asarray still pays after it.  A blocking call
+            # drops the GIL, and getting it back cost ~0.6 ms a read on
+            # the v5e host (PERF.md, PR 25): so none for leaves that
+            # are ready, which is_ready() says without blocking
+            with tracker.span("device_wait"):
+                if not all(x.is_ready() for x in leaves
+                           if hasattr(x, "is_ready")):
+                    jax.block_until_ready(leaves)
+            with tracker.span("d2h_copy"):
+                fetched = [np.asarray(x) for x in leaves]
             # RU metering: the MEASURED transfer payload, charged once
             # per physical D2H (a group's shared fetch splits across
             # its members through the captured group context)
@@ -3734,6 +3757,7 @@ class DeviceRunner:
                                    lambda: self._init_agg_carry(plan, None))
         kern = self._shard_kernel(
             key, lambda: self._wrap_mega(
+                "simple",
                 self._mega(self._build_simple_body(plan, n_cols),
                            self._finalize_psum_summed(),
                            feed["null_flags"], feed["n_pad"], chunk),
@@ -3927,6 +3951,7 @@ class DeviceRunner:
                 []))
             kern = self._shard_kernel(
                 key, lambda: self._wrap_mega(
+                    "hash_twolevel",
                     self._mega(self._build_hash_twolevel_body(
                         plan, n_cols, capacity, layouts, LO, HI, pf,
                         sparse=sparse),
@@ -3969,6 +3994,7 @@ class DeviceRunner:
             carry = self._cached_carry(key, build_scatter_carry)
             kern = self._shard_kernel(
                 key, lambda: self._wrap_mega(
+                    "hash_scatter",
                     self._mega(self._build_hash_scatter_body(
                         plan, n_cols, capacity, sparse=sparse,
                         stack_pad=slots_m - slots),
@@ -4036,7 +4062,7 @@ class DeviceRunner:
         int32 partial pairs psum over both mesh axes.  check_vma is
         off: pallas_call's out_shape carries no varying-axes type, and
         the psum makes the output replicated by construction."""
-        def local_fn(n_arr, base_arr, *cols_local):
+        def pallas_hash_sharded(n_arr, base_arr, *cols_local):
             start = self._shard_index() * n_local_pad
             row_hi = jnp.clip(n_arr - start, 0, n_local_pad)
             packed = run(jnp.asarray(0, jnp.int32), row_hi, base_arr,
@@ -4044,7 +4070,7 @@ class DeviceRunner:
             return lax.psum(packed, ROW_AXES)
 
         return jax.jit(jax.shard_map(
-            local_fn, mesh=self._mesh,
+            pallas_hash_sharded, mesh=self._mesh,
             in_specs=(P(), P()) + (P(ROW_AXES),) * n_in,
             out_specs=P(), check_vma=False))
 
@@ -4622,7 +4648,7 @@ class _AnalyzeKernels:
                 bits, ranks + 1,
                 jnp.stack([n_valid, distinct])])
 
-        return jax.jit(kern)
+        return jax.jit(named_program(kern, "analyze_column"))
 
 
 def _analyze_on_device(runner, dag, storage, n_buckets: int):

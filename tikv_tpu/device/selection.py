@@ -70,6 +70,7 @@ from ..datatype import device_const_dtype
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef, RpnConst, RpnExpression
 from ..parallel import ROW_AXES, num_shards
+from .kernels import named_program
 
 ROUTE_MASK = "mask"
 ROUTE_INDEX = "index"
@@ -257,6 +258,7 @@ def build_mask_kernel(sel_rpns, null_flags, n_pad: int, n_flat: int,
             count = lax.psum(count, ROW_AXES)
         return count, jnp.packbits(mask), mask
 
+    local_fn = named_program(local_fn, "scan_sel_mask")
     if mesh is None:
         return jax.jit(local_fn)
     return jax.jit(jax.shard_map(
@@ -312,7 +314,7 @@ def build_batched_mask_kernel(sel_rpns, null_flags, n_pad: int,
 
         return jax.vmap(one)(*params)
 
-    return jax.jit(local_fn)
+    return jax.jit(named_program(local_fn, "scan_sel_batched"))
 
 
 def build_index_kernel(n_pad: int, k_cap: int, mesh=None):
@@ -341,6 +343,7 @@ def build_index_kernel(n_pad: int, k_cap: int, mesh=None):
             ovf = lax.psum(ovf, ROW_AXES)
         return gidx, ovf
 
+    local_fn = named_program(local_fn, "scan_sel_index")
     if mesh is None:
         return jax.jit(local_fn)
     return jax.jit(jax.shard_map(
@@ -369,7 +372,7 @@ def build_compact_kernel(n_pad: int, k_cap: int, null_flags):
         ovf = (jnp.sum(mask, dtype=jnp.int64) > k_cap).astype(jnp.int64)
         return tuple(outs), ovf
 
-    return jax.jit(fn)
+    return jax.jit(named_program(fn, "scan_sel_compact"))
 
 
 def index_capacity(k_hint: float, n_local: int) -> int:

@@ -326,7 +326,7 @@ class _Member:
 
 class _Group:
     __slots__ = ("key", "members", "close_at", "window_close_at",
-                 "closed")
+                 "closed", "t_closed_ns")
 
     def __init__(self, key, close_at: float):
         self.key = key
@@ -334,6 +334,9 @@ class _Group:
         self.close_at = close_at            # only ever tightens
         self.window_close_at = close_at     # the untightened window
         self.closed = False
+        # when _close_locked queued it for the dispatcher (0: a
+        # shutdown-time group dispatched inline, never queued)
+        self.t_closed_ns = 0
 
 
 class RequestCoalescer:
@@ -572,6 +575,7 @@ class RequestCoalescer:
         if g.closed:
             return
         g.closed = True
+        g.t_closed_ns = time.perf_counter_ns()
         if self._open.get(g.key) is g:
             del self._open[g.key]
         self._ready.append(g)
@@ -624,9 +628,14 @@ class RequestCoalescer:
         """The hot loop: stage closed groups' launches back-to-back;
         when nothing is staged or unresolved, feed the oldest open
         group early instead of idling (module/init rationale)."""
+        from ..utils import tracker
         while True:
             g = None
-            with self._cv:
+            # the launcher's two states, accounted in the aggregate:
+            # dispatcher_idle (here, nothing ready) and group_dispatch
+            # (_dispatch).  Idle most of a window, the device starves
+            # because requests are elsewhere; busy, staging is the queue
+            with tracker.timed("dispatcher_idle"), self._cv:
                 while not self._ready:
                     if self._shutdown:
                         return
@@ -648,11 +657,25 @@ class RequestCoalescer:
     # ---------------------------------------------------------- dispatch
 
     def _dispatch(self, group: _Group) -> None:
+        """Stage one closed group's launch, on whichever thread runs it
+        (the dispatcher; a submitter or close() at shutdown)."""
+        from ..utils import tracker
+        t_begin_ns = time.perf_counter_ns()
+        lead = group.members[0].tracker if group.members else None
+        with tracker.timed("group_dispatch",
+                           lead.trace_id if lead is not None else None):
+            self._stage(group, t_begin_ns)
+
+    def _stage(self, group: _Group, t_begin_ns: int) -> None:
         from ..device.runner import (
             DeferredResult,
             _BatchUnavailable,
         )
         members = group.members
+        # a member's coalesce_wait splits at these two instants: submit
+        # → closed is the collection window, closed → begin the wait
+        # for this (one) dispatcher, the rest the shared staging below
+        t_closed_ns = group.t_closed_ns or t_begin_ns
         # resource control (resource_control.py): stacked-group
         # membership is chosen by deficit-weighted fair queuing over
         # the parked members' groups instead of FIFO — one tenant's
@@ -748,7 +771,7 @@ class RequestCoalescer:
                 tracker.uninstall(lead_tok)
                 lead_tok = None
             self.router.note_launch(time.perf_counter() - t0, size)
-            self._solo_fallback(members)
+            self._solo_fallback(members, t_closed_ns, t_begin_ns)
             return
         finally:
             if lead_tok is not None:
@@ -763,12 +786,13 @@ class RequestCoalescer:
                     mtr.link_from("group_dispatch", span_tr.trace_id,
                                   gsp.span_id, occupancy=size, lane=i)
         self.router.note_launch(time.perf_counter() - t0, size)
-        t_dispatch_ns = time.perf_counter_ns()
+        t_staged_ns = time.perf_counter_ns()
         for m, resolve in zip(members, resolvers):
-            self._complete(m, resolve,
-                           t_dispatch_ns - m.t_submit_ns)
+            self._complete(m, resolve, t_closed_ns, t_begin_ns,
+                           t_staged_ns)
 
-    def _solo_fallback(self, members) -> None:
+    def _solo_fallback(self, members, t_closed_ns: int,
+                       t_begin_ns: int) -> None:
         from ..device.runner import DeferredResult
         from ..resource_metering import GLOBAL_RECORDER, region_of
         with self._mu:
@@ -798,7 +822,7 @@ class RequestCoalescer:
                 resolve = d.result
             else:
                 resolve = (lambda r=d: r)
-            self._complete(m, resolve, t_ns - m.t_submit_ns)
+            self._complete(m, resolve, t_closed_ns, t_begin_ns, t_ns)
 
     def _defer_members(self, key, members) -> None:
         """Re-park DWFQ-deferred members into ``key``'s next
@@ -846,7 +870,8 @@ class RequestCoalescer:
         if inline is not None:
             self._dispatch(inline)
 
-    def _complete(self, m: _Member, resolve, wait_ns: int) -> None:
+    def _complete(self, m: _Member, resolve, t_closed_ns: int,
+                  t_begin_ns: int, t_staged_ns: int) -> None:
         """Hand the member's resolution (shared fetch join + its own
         host gather) to the completion pool; its result lands on the
         member's future for CopDeferred.wait()."""
@@ -857,10 +882,17 @@ class RequestCoalescer:
             tok = tracker.adopt(m.tracker) if m.tracker is not None \
                 else None
             try:
-                # the time a request spent parked in the collection
-                # window, split out of generic queue time so the
-                # batched-path p99 can be decomposed from the artifact
-                tracker.add_phase("coalesce_wait", max(0, wait_ns))
+                # submit → launch staged, split out of generic queue
+                # time so the batched-path p99 can be decomposed from
+                # the artifact; its span-only children say where: the
+                # collection window, then the wait for the dispatcher
+                # (what is left is the shared staging)
+                sp = tracker.add_phase("coalesce_wait",
+                                       t_staged_ns - m.t_submit_ns)
+                tracker.add_span("coalesce_window", m.t_submit_ns,
+                                 t_closed_ns, sp)
+                tracker.add_span("dispatch_queue_wait", t_closed_ns,
+                                 t_begin_ns, sp)
                 # group_fetch_wait: this member's join of the group's
                 # shared (memoized) fetch — for the first joiner it
                 # nests the real d2h_wait/host_materialize spans, for

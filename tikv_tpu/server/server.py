@@ -8,14 +8,46 @@ msgpack bodies (wire.py).
 
 from __future__ import annotations
 
+import time
 from concurrent import futures
 from typing import Optional
 
 import grpc
 
+from ..utils import tracker
 from . import wire
 from .node import Node
 from .service import KvService
+
+
+class _HandlerPool(futures.ThreadPoolExecutor):
+    """The gRPC handler pool, stamping when gRPC hands it each call:
+    ``rpc_accept_wait`` runs from that stamp to the request's
+    ``tracker.install`` (the pool's queue, the message receive, the
+    wait for the GIL)."""
+
+    def submit(self, fn, /, *args, **kwargs):
+        return super().submit(_stamped, time.perf_counter_ns(), fn,
+                              *args, **kwargs)
+
+
+def _stamped(submit_ns: int, fn, *args, **kwargs):
+    tracker.rpc_task_begin(submit_ns)
+    try:
+        return fn(*args, **kwargs)
+    finally:
+        tracker.rpc_task_end()
+
+
+def _replying(pack):
+    """A unary response serializer that closes ``rpc_reply`` (opened
+    where ``_seal_traced`` froze the trace) when the pack returns."""
+    def serialize(resp):
+        try:
+            return pack(resp)
+        finally:
+            tracker.reply_done()
+    return serialize
 
 
 class _GenericHandler(grpc.GenericRpcHandler):
@@ -66,14 +98,14 @@ class _GenericHandler(grpc.GenericRpcHandler):
                 return fn(method, raw)
             return grpc.unary_unary_rpc_method_handler(
                 raw_unary, request_deserializer=lambda b: b,
-                response_serializer=wire.pack_response)
+                response_serializer=_replying(wire.pack_response))
 
         def unary(req: dict, ctx) -> dict:
             return self._dispatch(method, req)
 
         return grpc.unary_unary_rpc_method_handler(
             unary, request_deserializer=wire.unpack,
-            response_serializer=wire.pack)
+            response_serializer=_replying(wire.pack))
 
 
 class TikvServer:
@@ -88,7 +120,7 @@ class TikvServer:
         # workers — grpc's stop() alone leaves them parked on the work
         # queue until the executor is garbage collected, which leaks a
         # thread per in-process server cycle (chaos restarts, tests)
-        self._pool = futures.ThreadPoolExecutor(max_workers=max_workers)
+        self._pool = _HandlerPool(max_workers=max_workers)
         self._server = grpc.server(self._pool)
         self._server.add_generic_rpc_handlers((
             _GenericHandler(

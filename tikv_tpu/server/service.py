@@ -114,6 +114,7 @@ class KvService:
         sampled = tid is not None or sample >= 1.0 or \
             (sample > 0.0 and random.random() < sample)
         tr, tok = tracker.install(trace_id=tid, sampled=sampled)
+        tracker.note_accept(tr)
         try:
             resp = self._dispatch_rpc(method, fn, req, prio)
         finally:
@@ -207,6 +208,7 @@ class KvService:
         sampled = tid is not None or sample >= 1.0 or \
             (sample > 0.0 and random.random() < sample)
         tr, tok = tracker.install(trace_id=tid, sampled=sampled)
+        tracker.note_accept(tr)
         try:
             env, result = self._fastpath_dispatch(
                 fp, ent, storage, consts, start_ts, deadline_ms)
@@ -485,6 +487,9 @@ class KvService:
         must be debuggable from the response alone), fire the
         slow-query log, and hand the trace to the retention buffer."""
         tr.finish()
+        # rpc_reply runs from here to the response serializer's return
+        # (server.py): what the store still does for a sealed request
+        tracker.reply_begin(tr)
         # RU accounting seal: the trace (and through it the slow-query
         # line and /debug/trace/<id>) answers "who paid for this" —
         # resource_group was labeled at admission, the RU total

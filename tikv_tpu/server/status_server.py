@@ -14,6 +14,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from ..utils import trace as trace_mod
 from ..utils.metrics import REGISTRY
 
 
@@ -211,6 +212,12 @@ class StatusServer:
                             if dr is not None else None
                         if fr is not None:
                             tracing["flight_recorder"] = fr.stats()
+                        # cumulative per-span wall/cpu and the
+                        # process's clocks: two samples difference
+                        # into per-request means and window shares
+                        tracing["phases"] = \
+                            trace_mod.AGGREGATE.snapshot()
+                        tracing["process"] = trace_mod.process_clock()
                         body["tracing"] = tracing
                     # device-aware RU metering rollup: live knobs +
                     # cost-model weights (all online-updatable), tag

@@ -47,12 +47,16 @@ the buffer) as Chrome trace-event JSON that loads in Perfetto.
 from __future__ import annotations
 
 import contextvars
+import gc
 import os
+import random
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from contextlib import contextmanager
 from typing import Optional
+
+from .trace_vocab import SPAN_VOCABULARY
 
 # (trace, ambient parent span) — the span new phases nest under
 _current: contextvars.ContextVar = contextvars.ContextVar(
@@ -69,10 +73,15 @@ def new_trace_id() -> str:
 class Span:
     """One timed operation in a trace.  ``t1 is None`` while open.
     ``links``: follows-from references into OTHER traces
-    ({trace_id, span_id}) — causal predecessors that are not parents."""
+    ({trace_id, span_id}) — causal predecessors that are not parents.
+    ``cpu_ns``: the opening thread's CPU time between open and close
+    (``phase``/``span`` where the clock was taken, :func:`_takes_cpu` —
+    a retroactive span is a wait and carries none).  For a span that
+    blocks on nothing, wall − cpu is time the thread was runnable and
+    not running: the GIL, plus preemption."""
 
     __slots__ = ("name", "span_id", "parent_id", "t0", "t1", "tid",
-                 "attrs", "links")
+                 "attrs", "links", "cpu_ns")
 
     def __init__(self, name: str, span_id: int, parent_id,
                  t0: int, tid: int):
@@ -84,6 +93,7 @@ class Span:
         self.tid = tid
         self.attrs: Optional[dict] = None
         self.links: Optional[list] = None
+        self.cpu_ns: Optional[int] = None
 
     def to_dict(self, base_ns: int, end_ns: int) -> dict:
         t1 = self.t1 if self.t1 is not None else end_ns
@@ -91,6 +101,8 @@ class Span:
              "parent_id": self.parent_id,
              "start_us": round((self.t0 - base_ns) / 1e3, 1),
              "dur_us": round(max(0, t1 - self.t0) / 1e3, 1)}
+        if self.cpu_ns is not None:
+            d["cpu_us"] = round(self.cpu_ns / 1e3, 1)
         if self.attrs:
             d["attrs"] = dict(self.attrs)
         if self.links:
@@ -106,13 +118,16 @@ class Tracker:
     the upgrade; the span tree is what's new.
     """
 
-    __slots__ = ("trace_id", "sampled", "t0", "wall_t0", "t1",
-                 "wait_ns", "phases", "scan_rows", "scan_bytes",
+    __slots__ = ("trace_id", "sampled", "cpu_all", "t0", "wall_t0",
+                 "t1", "wait_ns", "phases", "scan_rows", "scan_bytes",
                  "labels", "_mu", "_next_id", "spans", "root",
                  "meter_ctx", "ru")
 
     def __init__(self, trace_id: Optional[str] = None,
                  sampled: bool = True):
+        # a trace the caller asked for by id takes the thread CPU clock
+        # on every span; the others on a sample of them (_takes_cpu)
+        self.cpu_all = trace_id is not None
         self.trace_id = trace_id or new_trace_id()
         self.sampled = sampled
         self.t0 = time.perf_counter_ns()
@@ -197,6 +212,7 @@ class Tracker:
         clamped so export/breakdown see a closed tree."""
         if self.t1 is None:
             self.t1 = time.perf_counter_ns()
+            AGGREGATE.add(ROOT_SPAN_NAME, self.t1 - self.t0)
         with self._mu:
             for sp in self.spans:
                 if sp.t1 is None:
@@ -318,6 +334,222 @@ class Tracker:
         }
 
 
+# ----------------------------------------------------------- aggregate
+
+class SpanAggregate:
+    """Cumulative totals per span name since process start, fed by
+    every ``phase`` / ``span`` / ``add_phase`` / ``add_span`` /
+    ``timed`` call, sampled or not, and by the intervals no tracker
+    holds (``rpc_accept_wait``, ``rpc_reply``, ``gc_pause``).  Any two
+    snapshots difference into a per-span mean (Δwall_ms / Δcount) or a
+    share of the window (Δwall_ms / Δclock_ms, :func:`process_clock`).
+    ``cpu_ms`` and ``offcpu_ms`` (wall − cpu) are sums over the
+    ``cpu_samples`` spans of the row that took the thread CPU clock
+    (:func:`_takes_cpu`), so a mean is Δcpu_ms / Δcpu_samples; a row of
+    waits reads 0 in all three.  Sums, not span by span, because one
+    reading is itself a sample where the clock ticks coarsely (10 ms
+    under gVisor), and every counter only adds, so two snapshots
+    difference."""
+
+    def __init__(self, names=()):
+        # re-entrant: an allocation under the lock can start a
+        # collection, whose ``gc_pause`` callback adds on this thread
+        self._mu = threading.RLock()
+        # name -> [count, wall_ns, cpu samples, their cpu_ns, their wall_ns]
+        self._rows: dict[str, list] = {n: [0, 0, 0, 0, 0] for n in names}
+
+    def add(self, name: str, wall_ns: int,
+            cpu_ns: Optional[int] = None) -> None:
+        with self._mu:
+            row = self._rows.get(name)
+            if row is None:
+                row = self._rows[name] = [0, 0, 0, 0, 0]
+            row[0] += 1
+            row[1] += wall_ns
+            if cpu_ns is not None:
+                row[2] += 1
+                row[3] += cpu_ns
+                row[4] += wall_ns
+
+    def snapshot(self) -> dict:
+        with self._mu:
+            rows = {n: tuple(r) for n, r in self._rows.items()}
+        return {n: {"count": count, "wall_ms": round(wall / 1e6, 3),
+                    "cpu_samples": samples,
+                    "cpu_ms": round(cpu_s / 1e6, 3),
+                    "offcpu_ms": round((wall_s - cpu_s) / 1e6, 3)}
+                for n, (count, wall, samples, cpu_s, wall_s)
+                in rows.items()}
+
+
+# one table a process: a row, zeroed, for every registered name from the
+# start, so a path that has not run yet reads 0 and never goes missing
+AGGREGATE = SpanAggregate(SPAN_VOCABULARY)
+_CLOCK_T0 = time.perf_counter()
+_CPU_T0 = time.process_time()
+
+# One span in 2**_CPU_SAMPLE_BITS takes the thread CPU clock, drawn at
+# random so that no span name is always or never drawn.  Taken on every
+# span (~3,400 readings/s) it cost the served path 7% of its rate on the
+# benchmark's machine, where one reading is a 5.8 us call into gVisor's
+# kernel and ticks in 10 ms (PERF.md, PR 25); the aggregate needs sums,
+# and a trace asked for by id (Tracker.cpu_all) still gets every span.
+_CPU_SAMPLE_BITS = 4
+
+
+def _takes_cpu(tr: Optional[Tracker] = None) -> bool:
+    return (tr is not None and tr.cpu_all) or \
+        random.getrandbits(_CPU_SAMPLE_BITS) == 0
+
+
+def process_clock() -> dict:
+    """The window's denominators: wall and CPU (every thread, XLA's
+    too) of the process since this module was imported."""
+    return {"clock_ms": round((time.perf_counter() - _CLOCK_T0) * 1e3, 3),
+            "cpu_ms": round((time.process_time() - _CPU_T0) * 1e3, 3)}
+
+
+@contextmanager
+def timed(name: str, trace_id: Optional[str] = None):
+    """A thread's own state outside any request (the coalescer's
+    dispatcher: ``dispatcher_idle``, ``group_dispatch``): wall and CPU
+    into the aggregate, and an annotation where the name is listed."""
+    ann = _annotation(name, trace_id)
+    c0 = time.thread_time_ns() if _takes_cpu() else None
+    t0 = time.perf_counter_ns()
+    try:
+        yield
+    finally:
+        AGGREGATE.add(name, time.perf_counter_ns() - t0, None
+                      if c0 is None else time.thread_time_ns() - c0)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+
+_gc_t0 = 0
+
+
+def _on_gc(phase_: str, _info: dict) -> None:
+    # collections do not nest and start/stop run on the collecting
+    # thread: one module slot is enough
+    global _gc_t0
+    if phase_ == "start":
+        _gc_t0 = time.perf_counter_ns()
+    elif _gc_t0:
+        AGGREGATE.add("gc_pause", time.perf_counter_ns() - _gc_t0)
+        _gc_t0 = 0
+
+
+def watch_gc() -> None:
+    """Account Python's collector in the aggregate (``gc_pause``:
+    count, wall).  Idempotent; the node calls it when it is built."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+# ---------------------------------------------- profiler annotations
+
+# Spans that are WORK on some thread, emitted as ``copr:<name>`` through
+# the annotator so they land in the device profile on its clock.
+# Umbrellas and parked waits (rpc, fastpath, copr_handler, admission,
+# await_deferred, group_fetch_wait, coalesce_wait, read_pool_wait) stay
+# out: they cover every idle gap of the device and explain none.
+ANNOTATED = frozenset({
+    "dispatcher_idle", "group_dispatch", "d2h_wait", "host_materialize",
+    "plan_decode", "snapshot", "columnar_cache", "device_dispatch",
+    "feed_patch", "feed_upload", "delta_apply", "resp_serialize",
+    "rpc_reply"})
+_ANNOTATION_NAMES = {n: f"copr:{n}" for n in ANNOTATED}
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """``factory(name, **kwargs)`` → a context manager, or None for
+    off (the default).  The device layer hands in
+    ``jax.profiler.TraceAnnotation``; this module imports no JAX."""
+    global _annotator
+    _annotator = factory
+
+
+def _annotation(name: str, trace_id: Optional[str]):
+    """An ENTERED annotation for ``name``, or None: the caller exits it."""
+    if _annotator is None:
+        return None
+    label = _ANNOTATION_NAMES.get(name)
+    if label is None:
+        return None
+    ann = _annotator(label, trace_id=trace_id) if trace_id \
+        else _annotator(label)
+    ann.__enter__()
+    return ann
+
+
+# ------------------------------------------- outside the root span
+
+class _RpcEnvelope(threading.local):
+    """What one handler-pool task knows of its RPC outside the root
+    span: when gRPC handed it to the pool, and the sealed trace whose
+    reply is still to be serialized."""
+    submit_ns = None    # pool submit, consumed by the first install
+    reply = None        # (t_finish_ns, cpu0_ns | None, annotation)
+    armed = False       # inside a pool task: a serializer will follow
+
+
+_rpc = _RpcEnvelope()
+
+
+def rpc_task_begin(submit_ns: int) -> None:
+    """The handler pool's wrapper, on the worker thread, before gRPC's
+    task runs."""
+    _rpc.submit_ns = submit_ns
+    _rpc.reply = None
+    _rpc.armed = True
+
+
+def rpc_task_end() -> None:
+    reply_done()
+    _rpc.submit_ns = None
+    _rpc.armed = False
+
+
+def note_accept(tr: Tracker) -> None:
+    """``rpc_accept_wait``: pool submit → this tracker's install (the
+    pool's queue, the message receive, the wait for the GIL).  An
+    attribute of the root span and a row of the aggregate; the root
+    span and ``total_rpc_wall_ms`` do not move."""
+    t = _rpc.submit_ns
+    if t is None:
+        return
+    _rpc.submit_ns = None       # a streamed task's later requests: none
+    wait = max(0, tr.t0 - t)
+    AGGREGATE.add("rpc_accept_wait", wait)
+    tr.annotate_span(tr.root, rpc_accept_wait_us=round(wait / 1e3, 1))
+
+
+def reply_begin(tr: Tracker) -> None:
+    """``rpc_reply`` opens where the trace was sealed
+    (``Tracker.finish``); :func:`reply_done` closes it when the
+    response serializer returns.  Aggregate only: the reply has left."""
+    if not _rpc.armed or tr.t1 is None:
+        return
+    reply_done()
+    _rpc.reply = (tr.t1,
+                  time.thread_time_ns() if _takes_cpu(tr) else None,
+                  _annotation("rpc_reply", tr.trace_id))
+
+
+def reply_done() -> None:
+    got = _rpc.reply
+    if got is None:
+        return
+    _rpc.reply = None
+    t_finish, c0, ann = got
+    AGGREGATE.add("rpc_reply", time.perf_counter_ns() - t_finish, None
+                  if c0 is None else time.thread_time_ns() - c0)
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
 # ------------------------------------------------------------- context
 
 def install(trace_id: Optional[str] = None, sampled: bool = True
@@ -354,66 +586,104 @@ def current_span() -> Optional[Span]:
     return got[1] if got is not None else None
 
 
-@contextmanager
-def phase(name: str):
+class _Scoped:
+    """``phase`` / ``span`` as a context manager: opens and closes on
+    ONE thread, so the thread's CPU time can be taken at both ends
+    beside the wall.  A class, not a generator: a request opens a dozen
+    of these under a saturated GIL."""
+
+    __slots__ = ("name", "in_phases", "tr", "sp", "tok", "ann", "c0",
+                 "t0")
+
+    def __init__(self, name: str, in_phases: bool):
+        self.name = name
+        self.in_phases = in_phases
+        self.tr = None
+
+    def __enter__(self) -> Optional[Tracker]:
+        got = _current.get()
+        if got is None:
+            return None
+        tr, parent = got
+        self.tr = tr
+        self.ann = _annotation(self.name, tr.trace_id)
+        self.c0 = time.thread_time_ns() if _takes_cpu(tr) else None
+        self.t0 = time.perf_counter_ns()
+        self.sp = sp = tr.begin(self.name, parent, self.t0) \
+            if tr.sampled else None
+        self.tok = _current.set((tr, sp)) if sp is not None else None
+        return tr
+
+    def __exit__(self, *_exc) -> bool:
+        tr = self.tr
+        if tr is None:
+            return False
+        t1 = time.perf_counter_ns()
+        cpu = None if self.c0 is None else time.thread_time_ns() - self.c0
+        if self.tok is not None:
+            _current.reset(self.tok)
+        if self.sp is not None:
+            self.sp.cpu_ns = cpu
+            tr.end(self.sp, t1)
+        if self.in_phases:
+            tr.add(self.name, t1 - self.t0)
+        AGGREGATE.add(self.name, t1 - self.t0, cpu)
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        return False
+
+
+def phase(name: str) -> _Scoped:
     """Attribute the enclosed wall time to ``name`` on the active
     tracker (no-op without one): accumulates into ``phases_ms`` AND —
-    when sampled — opens a nesting child span of the ambient span."""
-    got = _current.get()
-    if got is None:
-        yield None
-        return
-    tr, parent = got
-    t0 = time.perf_counter_ns()
-    sp = tr.begin(name, parent, t0) if tr.sampled else None
-    tok = _current.set((tr, sp)) if sp is not None else None
-    try:
-        yield tr
-    finally:
-        t1 = time.perf_counter_ns()
-        if tok is not None:
-            _current.reset(tok)
-        tr.end(sp, t1)
-        tr.add(name, t1 - t0)
+    when sampled — opens a nesting child span of the ambient span.
+    Sampled or not, the wall and (:func:`_takes_cpu`) the thread's CPU
+    time also feed the process-wide :data:`AGGREGATE`."""
+    return _Scoped(name, True)
 
 
-@contextmanager
-def span(name: str):
+def span(name: str) -> _Scoped:
     """Span-ONLY timing: records a child span but does NOT accumulate
     into ``phases_ms`` — for umbrella intervals that other phases
     decompose (``await_deferred`` over the completion-side spans,
     ``group_fetch_wait`` over the shared d2h), so the flat phase dict
     keeps its historical non-overlapping-sum-≤-total invariant."""
-    got = _current.get()
-    if got is None:
-        yield None
-        return
-    tr, parent = got
-    if not tr.sampled:
-        yield tr
-        return
-    sp = tr.begin(name, parent)
-    tok = _current.set((tr, sp))
-    try:
-        yield tr
-    finally:
-        _current.reset(tok)
-        tr.end(sp)
+    return _Scoped(name, False)
 
 
-def add_phase(name: str, ns: int) -> None:
+def add_phase(name: str, ns: int) -> Optional[Span]:
     """Retroactive attribution: ``ns`` of wall ENDING NOW (the interval
-    was measured on a thread that had no tracker context)."""
+    was measured on a thread that had no tracker context).  → the span,
+    for :func:`add_span` children (None when unsampled)."""
     got = _current.get()
     if got is None:
-        return
+        return None
     tr, parent = got
     ns = max(0, int(ns))
     tr.add(name, ns)
+    AGGREGATE.add(name, ns)
+    if not tr.sampled:
+        return None
+    now = time.perf_counter_ns()
+    sp = tr.begin(name, parent, now - ns)
+    tr.end(sp, now)
+    return sp
+
+
+def add_span(name: str, t0_ns: int, t1_ns: int,
+             parent: Optional[Span] = None) -> None:
+    """Retroactive span-ONLY child with both ends given (a wait that
+    was over before this thread held the tracker): in the tree and the
+    aggregate, not in ``phases_ms``."""
+    got = _current.get()
+    if got is None:
+        return
+    tr, ambient = got
+    t1_ns = max(t0_ns, t1_ns)
+    AGGREGATE.add(name, t1_ns - t0_ns)
     if tr.sampled:
-        now = time.perf_counter_ns()
-        sp = tr.begin(name, parent, now - ns)
-        tr.end(sp, now)
+        tr.end(tr.begin(name, parent if parent is not None else ambient,
+                        t0_ns), t1_ns)
 
 
 def add_wait(ns: int) -> None:
@@ -422,6 +692,8 @@ def add_wait(ns: int) -> None:
         return
     tr, parent = got
     tr.add_wait(ns)
+    if ns > 0:
+        AGGREGATE.add("read_pool_wait", int(ns))
     if tr.sampled and ns > 0:
         now = time.perf_counter_ns()
         sp = tr.begin("read_pool_wait", parent, now - int(ns))

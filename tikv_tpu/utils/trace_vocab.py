@@ -1,12 +1,14 @@
 """Registered span-name vocabulary for the causal tracing subsystem.
 
-Every ``tracker.phase(...)`` / ``add_phase(...)`` / ``begin(...)`` /
-``link_from(...)`` span name used anywhere in ``tikv_tpu/`` MUST appear
-here (tests/test_trace.py scans the source tree both ways, like the
+Every ``tracker.phase(...)`` / ``add_phase(...)`` / ``add_span(...)`` /
+``timed(...)`` / ``begin(...)`` / ``link_from(...)`` span name used
+anywhere in ``tikv_tpu/`` (and each ``AGGREGATE.add(...)`` row of
+utils/trace.py) MUST appear here (tests/test_trace.py scans the source tree both ways, like the
 failpoint inventory): a typo'd phase label fails CI instead of silently
 forking the latency breakdown into two names no dashboard ever joins.
 The descriptions double as the README's span-vocabulary table — keep
-them one line each.
+them one line each.  Every name also has a row, zeroed from process
+start, in the ``/health`` ``tracing.phases`` aggregate (utils/trace.py).
 """
 
 from __future__ import annotations
@@ -14,6 +16,13 @@ from __future__ import annotations
 SPAN_VOCABULARY: dict[str, str] = {
     # -- request envelope (server/service.py, utils/trace.py) --
     "rpc": "root span: the whole RPC from admission to response",
+    "rpc_accept_wait": "before the root span: gRPC's hand-off to the "
+                       "handler pool → tracker install (pool queue, "
+                       "message receive, wait for the GIL); aggregate "
+                       "row + root-span attribute rpc_accept_wait_us",
+    "rpc_reply": "after the root span: trace sealed → response "
+                 "serializer returned (seal tail, encode_response, "
+                 "gRPC's hand-off, wire pack); aggregate row only",
     "untracked": "synthesized residual: root wall no child span covers",
     "admission": "umbrella: deadline/resource gating + class keying",
     "plan_decode": "wire → DAGRequest decode (compile-class keying)",
@@ -45,14 +54,27 @@ SPAN_VOCABULARY: dict[str, str] = {
     "host_materialize": "host finalize: fetched tree → SelectResult",
     # -- async serving stack --
     "completion_queue_wait": "wait for a completion-pool worker slot",
-    "coalesce_wait": "time parked in a coalescer collection window",
+    "coalesce_wait": "coalescer submit → the group's launch staged: "
+                     "collection window + wait for the one dispatcher "
+                     "thread + the shared launch staging",
+    "coalesce_window": "span-only child of coalesce_wait: submit → the "
+                       "member's group closed (the collection window)",
+    "dispatch_queue_wait": "span-only child of coalesce_wait: group "
+                           "closed → the dispatcher began staging it",
     "group_dispatch": "shared dispatch of one coalesced group "
-                      "(follows-from linked into every member trace)",
+                      "(follows-from linked into every member trace); "
+                      "its aggregate row is the dispatcher thread busy",
+    "dispatcher_idle": "dispatcher thread parked with no closed group "
+                       "to stage (aggregate row only)",
     "group_fetch_wait": "member resolution joining the group's shared "
                         "(memoized) fetch",
     # -- device backend (device/runner.py) --
     "device_dispatch": "kernel launch enqueue (flight-recorder attrs)",
     "d2h_wait": "device→host transfer + sync wait",
+    "device_wait": "span-only child of d2h_wait: block_until_ready on "
+                   "the result leaves (the program has not finished)",
+    "d2h_copy": "span-only child of d2h_wait: np.asarray of the leaves "
+                "(transfer + sync left after the program finished)",
     "feed_upload": "cold H2D upload of the columnar feed",
     "feed_patch": "delta-dirty span patch of a resident feed",
     "shard_merge": "host-side merge of per-shard partial agg states",
@@ -82,4 +104,7 @@ SPAN_VOCABULARY: dict[str, str] = {
     "mvcc_resolve": "device segmented-argmax MVCC version resolution",
     "stream_take": "cold-stream handoff wait at build time",
     "h2d_stream": "streaming per-chunk H2D upload during the load",
+    # -- the process (utils/trace.py) --
+    "gc_pause": "one run of Python's cyclic collector, from "
+                "gc.callbacks (aggregate row only: count, wall)",
 }

@@ -26,6 +26,7 @@ from .trace import (      # noqa: F401 — re-exported compat surface
     Tracker,
     add_phase,
     add_scan,
+    add_span,
     add_wait,
     adopt,
     annotate,
@@ -33,8 +34,14 @@ from .trace import (      # noqa: F401 — re-exported compat surface
     current_span,
     install,
     label,
+    note_accept,
     phase,
+    reply_begin,
+    reply_done,
+    rpc_task_begin,
+    rpc_task_end,
     span,
+    timed,
     to_chrome,
     uninstall,
 )
