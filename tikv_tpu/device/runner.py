@@ -267,6 +267,9 @@ class _Plan:
     sel_params: Optional[tuple] = None
     # lazy const-blind stat key (runner._sel_keys)
     sel_stat_key: Optional[tuple] = None
+    # lazy (result FieldTypes, container dtypes) of ``specs``
+    # (runner._agg_out)
+    agg_out: Optional[tuple] = None
 
 
 def _sum_parts(parts):
@@ -275,6 +278,19 @@ def _sum_parts(parts):
     for p in parts[1:]:
         packed = packed + np.asarray(p)
     return packed
+
+
+def _hash_columns(specs, agg_out, merged, base, capacity, slot_keys):
+    """Merged hash-agg state → result Columns (aggregates, then the
+    key), wrapped straight around ``finalize_hash``'s planes: no Python
+    value is made per group between the fetched accumulator and the
+    wire encoder.  ``agg_out``: ``DeviceRunner._agg_out`` of the plan."""
+    (keys, key_valid), planes = finalize_hash(
+        specs, merged, base, capacity, slot_keys=slot_keys)
+    cols = [Column(ft.eval_type, vals.astype(dt, copy=False), ok)
+            for ft, dt, (vals, ok) in zip(*agg_out, planes)]
+    cols.append(Column(EvalType.INT, keys, key_valid))
+    return cols
 
 
 class _GuardedMeta:
@@ -443,8 +459,11 @@ HOST_STAGER = _PinnedStager()
 class _Pending:
     """A dispatched device request: output pytree still on device plus
     the host finalize that turns the fetched numpy tree into a
-    SelectResult.  Leaves are staged to pinned host memory at
-    construction when the backend supports it (:class:`_PinnedStager`)
+    SelectResult (the ``host_materialize`` phase; for a hash
+    aggregation the accumulator's unpack and ``_hash_columns``, array
+    calls only, whatever the group count).  Leaves are staged to
+    pinned host memory at construction when the backend supports it
+    (:class:`_PinnedStager`)
     and ``copy_to_host_async`` is issued for every leaf, so the D2H
     transfer streams while the caller decides when (and on which
     thread) to block — the seam the async serving path pipelines on.
@@ -3048,7 +3067,10 @@ class DeviceRunner:
         # the old monolithic "device_fetch" phase is split so a warm
         # p50 can be attributed from the artifact alone: "d2h_wait" is
         # the transfer + sync (here), "host_materialize" is the host
-        # finalize that follows (_finish)
+        # finalize that follows (_finish): fetched planes -> result
+        # Columns.  For a hash aggregation that is numpy over the KBs
+        # of accumulator, with no Python value made per group; for a
+        # selection it is the host gather of the selected rows
         with tracker.phase("d2h_wait"):
             leaves, treedef = jax.tree.flatten(tree)
             for x in leaves:
@@ -3701,6 +3723,24 @@ class DeviceRunner:
             out.append(flag)
         return out
 
+    @staticmethod
+    def _agg_out(plan) -> tuple:
+        """(result FieldType, container dtype) lists of ``plan.specs``,
+        resolved once per cached plan.  The dtype is the one
+        ``Column.from_list`` gives the eval type, uint64 where the field
+        type is unsigned (BIT kinds)."""
+        out = plan.agg_out
+        if out is None:
+            from ..executors.aggregation import _agg_ret_ft
+            fts = [_agg_ret_ft(spec.kind,
+                               spec.eval_type if spec.kind not in
+                               ("count", "count_star") else None)
+                   for spec in plan.specs]
+            out = plan.agg_out = (fts, [
+                np.dtype(np.uint64) if ft.is_unsigned
+                else ft.eval_type.np_dtype for ft in fts])
+        return out
+
     def _simple_result(self, dag, plan, merged):
         finals = finalize_simple(plan.specs, merged)
         from ..executors.aggregation import _agg_ret_ft
@@ -3894,20 +3934,12 @@ class DeviceRunner:
 
         slot_keys = sparse_keys[0] if sparse else None
 
+        agg_out = self._agg_out(plan)
+        schema = agg_out[0] + [FieldType.long()]
+
         def hash_result(merged):
-            keys, results = finalize_hash(plan.specs, merged, base,
-                                          capacity, slot_keys=slot_keys)
-            from ..executors.aggregation import _agg_ret_ft
-            schema, cols = [], []
-            for spec, vals in zip(plan.specs, results):
-                ft = _agg_ret_ft(spec.kind,
-                                 spec.eval_type if spec.kind not in
-                                 ("count", "count_star") else None)
-                schema.append(ft)
-                cols.append(Column.from_list(ft.eval_type, vals))
-            schema.append(FieldType.long())
-            cols.append(Column.from_list(EvalType.INT, keys))
-            return self._result(dag, schema, cols)
+            return self._result(dag, list(schema), _hash_columns(
+                plan.specs, agg_out, merged, base, capacity, slot_keys))
 
         got = None
         if layouts is not None:

@@ -69,6 +69,7 @@ def _bit_ufunc(kind: str):
 
 
 _U64 = 0xFFFFFFFFFFFFFFFF
+_I64_MAX = (1 << 63) - 1
 
 
 def _bit_int64(values):
@@ -433,56 +434,78 @@ def merge_hash_states(xp, specs, a: dict, b: dict) -> dict:
 
 def finalize_hash(specs, state: dict, base: int, capacity: int,
                   slot_keys=None):
-    """Produce (group_keys, per-spec result columns) for present groups.
+    """Present groups of a hash-agg state as numpy planes.
 
     Groups are emitted in ascending key order (deterministic), NULL group
     last — matches what the reference's tests canonicalize to.
     ``slot_keys``: sparse recode — per-slot key values (sorted distinct
     keys) instead of the dense ``slot + base`` arithmetic.
-    Returns (keys: list[Optional[int]], results: list[list]).
+    Returns ``((keys, key_valid), planes)``: the int64 key plane with its
+    validity (False on the NULL group), and one ``(values, validity)``
+    pair per spec, in the state's own dtype (BIT kinds: uint64, AVG and
+    the VAR kinds: float64).  A NULL result holds 0 under a False
+    validity, the ``Column`` contract, so the caller wraps the planes
+    without walking them.  No per-group Python runs here except
+    ``_finalize_var``, whose float operation order is the host's.
     """
     present = np.asarray(state["present"])
-    slots = np.nonzero(present[:capacity])[0]
-    has_null = bool(present[capacity])
+    sel = np.flatnonzero(present[:capacity])
     if slot_keys is not None:
-        keys: list[Optional[int]] = [int(slot_keys[s]) for s in slots]
+        keys = np.asarray(slot_keys)[sel]
+    elif base + capacity > _I64_MAX:
+        keys = sel.astype(np.uint64) + np.uint64(base)
     else:
-        keys = [int(s) + base for s in slots]
-    all_slots = list(slots)
-    if has_null:
-        keys.append(None)
-        all_slots.append(capacity)
-    sel = np.asarray(all_slots, dtype=np.int64)
+        keys = sel + np.int64(base)
+    if not (keys.dtype == np.uint64 and (keys > _I64_MAX).any()):
+        # an unsigned key domain keeps uint64 only where a key needs it
+        # (``Column.from_list``'s container rule)
+        keys = keys.astype(np.int64, copy=False)
+    key_valid = np.ones(len(sel), dtype=np.bool_)
+    if present[capacity]:
+        sel = np.append(sel, capacity)
+        keys = np.append(keys, keys.dtype.type(0))
+        key_valid = np.append(key_valid, False)
 
-    results = []
+    def all_valid():
+        return np.ones(len(sel), dtype=np.bool_)
+
+    def nullable(values, counts):
+        valid = counts > 0
+        return np.where(valid, values, 0), valid
+
+    planes = []
     for spec, s in zip(specs, state["states"]):
         if spec.kind in ("count", "count_star"):
-            results.append([int(x) for x in np.asarray(s["count"])[sel]])
+            planes.append((np.asarray(s["count"])[sel], all_valid()))
         elif spec.kind == "sum":
-            sums = np.asarray(s["sum"])[sel]
-            nn = np.asarray(s["nonnull"])[sel]
-            results.append([None if c == 0 else sums[i].item()
-                            for i, c in enumerate(nn)])
+            planes.append(nullable(np.asarray(s["sum"])[sel],
+                                   np.asarray(s["nonnull"])[sel]))
         elif spec.kind == "avg":
-            sums = np.asarray(s["sum"])[sel]
             cnt = np.asarray(s["count"])[sel]
-            results.append([None if c == 0 else float(sums[i]) / int(c)
-                            for i, c in enumerate(cnt)])
+            valid = cnt > 0
+            planes.append((np.divide(
+                np.asarray(s["sum"])[sel].astype(np.float64),
+                cnt.astype(np.float64),
+                out=np.zeros(len(sel), dtype=np.float64), where=valid),
+                valid))
         elif spec.kind in ("min", "max"):
-            vals = np.asarray(s[spec.kind])[sel]
-            nn = np.asarray(s["nonnull"])[sel]
-            results.append([None if c == 0 else vals[i].item()
-                            for i, c in enumerate(nn)])
+            planes.append(nullable(np.asarray(s[spec.kind])[sel],
+                                   np.asarray(s["nonnull"])[sel]))
         elif spec.kind in VAR_KINDS:
             sums = np.asarray(s["sum"])[sel]
             sqs = np.asarray(s["sumsq"])[sel]
             cnt = np.asarray(s["count"])[sel]
-            results.append([_finalize_var(spec.kind, float(sums[i]),
-                                          float(sqs[i]), int(c))
-                            for i, c in enumerate(cnt)])
+            out = [_finalize_var(spec.kind, float(sums[i]),
+                                 float(sqs[i]), int(c))
+                   for i, c in enumerate(cnt)]
+            planes.append((
+                np.array([0.0 if v is None else v for v in out],
+                         dtype=np.float64),
+                np.array([v is not None for v in out], dtype=np.bool_)))
         elif spec.kind in BIT_KINDS:
-            results.append([int(x) & _U64
-                            for x in np.asarray(s["bits"])[sel]])
+            # two's complement reinterpretation == ``& 2**64 - 1``
+            planes.append((np.asarray(s["bits"])[sel].astype(np.uint64),
+                           all_valid()))
         else:
             raise ValueError(f"finalize_hash: {spec.kind} unsupported here")
-    return keys, results
+    return (keys, key_valid), planes

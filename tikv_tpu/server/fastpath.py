@@ -1040,7 +1040,10 @@ def _column_list(c) -> list:
     """One result column → a Python value list at C speed:
     ``ndarray.tolist()`` (one call) + a vectorized NULL punch-through,
     instead of the per-element ``Column.get`` walk ``enc_rows`` pays
-    (an isinstance + validity probe + ``.item()`` per cell)."""
+    (an isinstance + validity probe + ``.item()`` per cell).  A device
+    hash aggregation's columns are the finalize's own planes
+    (``runner._hash_columns``), so this call is the first and only
+    place its answer becomes Python values: msgpack wants them."""
     import numpy as np
     vals = c.values.tolist()
     validity = c.validity
