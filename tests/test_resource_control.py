@@ -636,6 +636,11 @@ def test_coalescer_fairness_flood_defers_throttled_never_late(runner):
         warm = ep.handle(CopRequest(REQ_TYPE_DAG, _sel_dag(table, 0),
                                     resource_group="warm"))
         assert warm.backend == "device"
+        # the warm launch's wall is a compile: folded into the router's
+        # launch EWMA it prices a solo dispatch behind six parked bg
+        # members above fg's budget, and fg then goes solo instead of
+        # riding the window whenever its thread arrives last
+        coal.router.launch_ewma = coal.router.LAUNCH_SEED_S
         GLOBAL_CONTROLLER.configure(
             enabled=True,
             groups={"fg": {"share": 1000.0, "priority": "high"},

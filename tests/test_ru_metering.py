@@ -604,7 +604,12 @@ def test_e2e_hot_regions_visible_at_pd(rig):
         while time.monotonic() < deadline:
             GLOBAL_RECORDER.roll_window()
             got = rig["pd_client"].hot_regions(topk=4)
-            if got.get("regions") and got.get("tenants"):
+            # the first report PD merges can be the window that closed
+            # under the tests before this one: their tags fill the top
+            # four, so wait for a window that holds this tenant's reads
+            if got.get("regions") and any(
+                    e["tag"] == "hot-tenant"
+                    for e in got.get("tenants") or ()):
                 break
             c.coprocessor(_agg_dag(rig, c.tso()), timeout=60,
                           resource_group="hot-tenant")

@@ -159,22 +159,8 @@ class Endpoint:
         # capability decision — a TypeError raised INSIDE a run must
         # degrade, not silently re-execute the request)
         self._runner_deferred: Optional[bool] = None
-        # request-level mesh attribution: device-routed requests carry
-        # a "mesh" tracker label ("RxT" shape, or "RxT+placement") so
-        # the multichip bench and /status TimeDetails can tell sharded
-        # serving from single-chip without reaching into the runner
-        self._mesh_label: Optional[str] = None
-        if device_runner is not None and \
-                hasattr(device_runner, "mesh_stats"):
-            try:
-                ms = device_runner.mesh_stats()
-                shape = ms.get("shape", {})
-                self._mesh_label = "x".join(
-                    str(v) for v in shape.values()) or None
-                if self._mesh_label and "placement" in ms:
-                    self._mesh_label += "+placement"
-            except Exception:   # noqa: BLE001 — attribution only
-                self._mesh_label = None
+        # (a device-served request's "mesh" label is set by the runner
+        # that launched it: device/runner.py _dispatch_phase)
 
     def close(self) -> None:
         """Release the coalescer's dispatcher and the completion
@@ -382,8 +368,6 @@ class Endpoint:
             set_region(region_of(storage))
             backend = self._pick_backend(req, storage)
             tracker.label("backend", backend)
-            if backend == "device" and self._mesh_label is not None:
-                tracker.label("mesh", self._mesh_label)
 
             def host_exec():
                 from ..executors.runner import BatchExecutorsRunner
@@ -536,8 +520,6 @@ class Endpoint:
         with GLOBAL_RECORDER.attach(tag):
             set_region(region_of(storage))
             tracker.label("backend", "device")
-            if self._mesh_label is not None:
-                tracker.label("mesh", self._mesh_label)
             _dl_check("device_dispatch")
             coal = self.coalescer
             if coal is not None:

@@ -499,7 +499,7 @@ class DeferredResult:
     """
 
     __slots__ = ("_runner", "_pending", "_dag", "_storage", "_mu",
-                 "_memo", "small", "_pin_anchor", "_meter_ctx")
+                 "_memo", "small", "_pin_anchor", "_meter_ctx", "_mesh")
 
     def __init__(self, runner, pending: _Pending, dag, storage,
                  pin_anchor=None):
@@ -518,9 +518,15 @@ class DeferredResult:
         # matter which completion worker runs the fetch
         from .. import resource_metering as rm
         self._meter_ctx = rm.current_context()
+        # the mesh the launch ran on: every request that joins this
+        # result (a coalesced group's members resolve on their own
+        # trackers) is labelled with it; None once a rescue re-served
+        # the request elsewhere (that launch labelled its own tracker)
+        self._mesh = runner._mesh_desc
 
     def result(self):
         from .. import resource_metering as rm
+        from ..utils import tracker
         with self._mu:
             if self._memo is None:
                 try:
@@ -536,8 +542,11 @@ class DeferredResult:
                             pass
                         self._pin_anchor = None
             kind, val = self._memo
+            mesh = self._mesh
         if kind == "err":
             raise val
+        if mesh is not None:
+            tracker.label("mesh", mesh)
         return val
 
     def __del__(self):
@@ -561,6 +570,7 @@ class DeferredResult:
             # the host rung.  The pin release in result()'s finally is
             # untouched either way: exactly-once, never doubled.
             self._runner._note_slice_fault("fetch")
+            self._mesh = None
             rescued = self._runner._rescue(self._dag, self._storage)
             if rescued is not None:
                 return rescued
@@ -675,6 +685,7 @@ class _BatchedSelectionGroup:
             raise       # the endpoint's per-member host degrade applies
         dag, storage = self._members[i]
         runner = self._runner
+        tracker.label("mesh", runner._mesh_desc)
         plan = runner._analyze(dag)
         k = int(counts[i])
         runner._sel_observe(runner._sel_keys(dag, plan),
@@ -786,6 +797,10 @@ class DeviceRunner:
         # a chip is quarantined; None = full mesh healthy
         self._degraded: Optional[tuple] = None
         self._degrade_mu = threading.Lock()
+        # times whole-mesh serving was re-minted on a submesh (monotone;
+        # /health device_mesh): a run that saw one did not stay on the
+        # mesh it was configured with
+        self._submesh_rebuilds = 0
         # keyed by const-SENSITIVE plan_key: rotating constants mint a
         # fresh analysis each, so the cache is bounded (FIFO) — the
         # const-blind kernel caches below are what keep compile classes
@@ -1088,6 +1103,7 @@ class DeviceRunner:
                 # in-flight dispatches keep their own buffer references
                 self._arena.drop_all(reason="failover")
                 self._degraded = (key, sub)
+                self._submesh_rebuilds += 1
                 m.DEVICE_FAILOVER_COUNTER.labels("mesh_downsize").inc()
             return self._degraded[1]
 
@@ -1137,17 +1153,27 @@ class DeviceRunner:
         except Exception:   # noqa: BLE001 — rescue is best-effort;
             return None     # the host rung follows
 
+    def _live_mesh(self) -> tuple:
+        """(runner, dead slices) that whole-mesh plans are served by
+        right now: the degraded submesh runner while a slice is
+        quarantined, self and () otherwise.  The one place /health's
+        ``device_mesh`` and ``device_health`` blocks read it from."""
+        with self._degrade_mu:
+            if self._degraded is not None:
+                dead, sub = self._degraded
+                return sub, tuple(sorted(dead))
+        return self, ()
+
     def failure_domain_stats(self) -> dict:
         """Per-slice health + degrade rollup (/health device_health)."""
         out: dict = {"n_slices": len(self._slice_indices),
                      "slices": self._board.stats()
                      if self._board is not None else []}
-        with self._degrade_mu:
-            if self._degraded is not None:
-                dead, sub = self._degraded
-                out["degraded"] = {
-                    "dead_slices": sorted(dead),
-                    "healthy_devices": num_shards(sub._mesh)}
+        live, dead = self._live_mesh()
+        if live is not self:
+            out["degraded"] = {
+                "dead_slices": list(dead),
+                "healthy_devices": num_shards(live._mesh)}
         return out
 
     def close(self) -> None:
@@ -1170,14 +1196,28 @@ class DeviceRunner:
             self._board.reset()
 
     def mesh_stats(self) -> dict:
-        """Mesh shape + placement rollup for /health."""
+        """Mesh rollup for /health ``device_mesh``: the configured
+        shape, the mesh whole-mesh plans are served on right now
+        (``live``: a submesh while a slice is quarantined), what ran on
+        it (``sharded_launches``: launches over every device of the
+        configured mesh, beside the flight recorder's ``launches``;
+        ``submesh_rebuilds``; both monotone), the resident bytes of the
+        live mesh's fullest shard, and the placement rollup."""
         shape = dict(zip(ROW_AXES,
                          (int(s) for s in self._mesh.devices.shape)))
         dev0 = self._mesh.devices.flat[0]
+        live, _dead = self._live_mesh()
         out = {"shape": shape,
                "n_devices": num_shards(self._mesh),
                "platform": dev0.platform,
-               "device_kind": dev0.device_kind}
+               "device_kind": dev0.device_kind,
+               "live": {"shape": live._mesh_desc,
+                        "n_devices": num_shards(live._mesh)},
+               "sharded_launches": self.flight_recorder.sharded_launches,
+               "submesh_rebuilds": self._submesh_rebuilds,
+               "feed_bytes_per_shard": max(
+                   live._arena.resident_bytes_by_device().values(),
+                   default=0)}
         if self._placer is not None:
             out["placement"] = self._placer.stats()
         return out
@@ -1649,6 +1689,7 @@ class DeviceRunner:
         on-device row mask (synthesized from iota < n), saving the HBM
         footprint and H2D bandwidth of an all-true mask.
         """
+        from ..utils import tracker
         n_pad = self._pad_rows(n)
         flat, flags = [], []
 
@@ -1663,9 +1704,15 @@ class DeviceRunner:
                 p = np.zeros(n_pad, dtype=arr.dtype)
                 p[:n] = arr
                 return jnp.asarray(p)
-            p = np.zeros(n_pad, dtype=dtype)
-            p[:n] = arr
-            return jax.device_put(p, self._row_sharding)
+            # a sharded cold build, span by span: the host's padded
+            # copy, then handing one slice to each shard (the put is
+            # not waited for: the next plane's pad overlaps it, and the
+            # first launch waits for what is left)
+            with tracker.span("feed_host_pad"):
+                p = np.zeros(n_pad, dtype=dtype)
+                p[:n] = arr
+            with tracker.span("feed_shard_put"):
+                return jax.device_put(p, self._row_sharding)
 
         from .supervisor import host_plane_digest
         digests = [] if self.scrub_digests else None
@@ -3001,8 +3048,9 @@ class DeviceRunner:
         """Every kernel launch site runs under this: the
         ``device_dispatch`` tracker span, plus one flight-recorder
         entry (launch wall, compile class, first-launch flag, mesh
-        shape, slice id, arena-pinned bytes) annotated onto the span —
-        the trace carries the launch's black-box record inline.
+        shape and ``shards``, slice id, arena-pinned bytes) annotated
+        onto the span — the trace carries the launch's black-box record
+        inline — and the request's ``mesh`` label.
 
         ``key`` refines the compile class (n_pad bucket / kernel cache
         key) so the ``first_launch`` flag distinguishes a real
@@ -3026,6 +3074,10 @@ class DeviceRunner:
                 # launch splits by occupancy share across member tags
                 # (resource_metering.charge_launch site resolution)
                 rm.charge_launch(wall_s)
+                # the request's ``mesh`` label is the mesh THIS launch
+                # ran on: a placement slice or a degraded submesh runner
+                # states its own shape, not the node's configured one
+                tracker.label("mesh", self._mesh_desc)
                 if rec is not None:
                     entry = rec.note(
                         klass=klass, key=key,
@@ -3034,7 +3086,8 @@ class DeviceRunner:
                         slice_id=self._slice_indices[0]
                         if len(self._slice_indices) == 1 else None,
                         pinned_bytes=self._arena.pinned_bytes(),
-                        ok=ok)
+                        ok=ok, shards=num_shards(self._mesh),
+                        whole_mesh=self._failover_parent is None)
                     tracker.annotate(**entry)
 
     # -- packed device→host readback (one transfer, one sync) --

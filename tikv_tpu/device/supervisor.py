@@ -116,8 +116,9 @@ class FlightRecorder:
     operator (or the /debug/trace surface) reads after a latency spike:
     per-launch wall, compile class, whether this launch was the class's
     FIRST (compile-vs-cached — the difference between a 0.6ms warm
-    enqueue and a multi-second XLA compile), mesh shape, slice id for
-    placement-routed launches, and arena-pinned bytes at dispatch.
+    enqueue and a multi-second XLA compile), the shape and device count
+    of the mesh the launch ran on, slice id for placement-routed
+    launches, and arena-pinned bytes at dispatch.
 
     One recorder per PHYSICAL runner: placement slices and degraded
     submesh sub-runners share their parent's ring (their entries carry
@@ -136,6 +137,10 @@ class FlightRecorder:
         self.launches = 0
         self.first_launches = 0
         self.faults = 0
+        # launches that ran on every device of the physical runner's
+        # configured mesh (not on a placement slice, not on a degraded
+        # submesh): what /health device_mesh reports beside ``launches``
+        self.sharded_launches = 0
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -144,7 +149,8 @@ class FlightRecorder:
 
     def note(self, klass: str, key=None, wall_s: float = 0.0,
              mesh: str = "", slice_id=None, pinned_bytes: int = 0,
-             ok: bool = True) -> dict:
+             ok: bool = True, shards: int = 1,
+             whole_mesh: bool = False) -> dict:
         ck = (klass, key)
         with self._mu:
             first = ck not in self._seen
@@ -158,11 +164,14 @@ class FlightRecorder:
                 self.first_launches += 1
             if not ok:
                 self.faults += 1
+            if whole_mesh:
+                self.sharded_launches += 1
             entry = {"t_unix_s": round(time.time(), 6),
                      "launch_ms": round(wall_s * 1e3, 3),
                      "compile_class": klass,
                      "first_launch": first,
                      "mesh": mesh,
+                     "shards": int(shards),
                      "slice": slice_id,
                      "pinned_bytes": int(pinned_bytes),
                      "ok": ok}
@@ -187,6 +196,7 @@ class FlightRecorder:
                     "launches": self.launches,
                     "first_launches": self.first_launches,
                     "faults": self.faults,
+                    "sharded_launches": self.sharded_launches,
                     "wall_s_total": self.wall_s_total}
 
 

@@ -30,7 +30,14 @@ The default shape comes from ``_factor2`` (as square as possible; note a
 PRIME device count necessarily degenerates to ``(1, n)`` — every row
 block then rides the ``tile`` axis).  Deployments pin an explicit shape
 via ``coprocessor.mesh_shape`` ("2x4"), parsed by ``parse_mesh_shape``
-and surfaced in ``/health``.
+and surfaced in ``/health`` (``device_mesh``: the configured shape, the
+live one, and what was launched on it).
+
+No rate or latency of this path is quoted here: ``PERF.md`` has every
+measured number with its origin, and the scale-up mode is the
+benchmark's four-chip cell (``python3 benchmark/run.py --workload
+agg-mesh4-closed8 ...`` on a four-chip host, deployment TOML
+``benchmark/configs/int3-10m-mesh4.toml``).
 
 A configured device is NOT assumed healthy forever: the failure-domain
 supervisor (device/supervisor.py) scores each slice and quarantines a

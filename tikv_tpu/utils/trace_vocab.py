@@ -76,6 +76,12 @@ SPAN_VOCABULARY: dict[str, str] = {
     "d2h_copy": "span-only child of d2h_wait: np.asarray of the leaves "
                 "(transfer + sync left after the program finished)",
     "feed_upload": "cold H2D upload of the columnar feed",
+    "feed_host_pad": "span-only child of feed_upload on a sharded "
+                     "mesh: the host's padded copy of one plane",
+    "feed_shard_put": "span-only child of feed_upload on a sharded "
+                      "mesh: one plane handed to device_put on the row "
+                      "sharding, a slice to each shard (not waited for: "
+                      "the first launch waits for the transfer)",
     "feed_patch": "delta-dirty span patch of a resident feed",
     "shard_merge": "host-side merge of per-shard partial agg states",
     "mesh_rebuild": "elastic degrade: re-mint serving on a submesh",
