@@ -302,10 +302,15 @@ class ServedLeg:
                                f"a device runner — {e}")
         log(ln)
         m = re.fullmatch(r"device runner: platform=(\S+) "
-                         r"device_kind='(.*)' n_devices=(\d+) mesh=(\S+)",
-                         ln.strip())
+                         r"device_kind='(.*)' n_devices=(\d+) mesh=(\S+) "
+                         r"native_finalize=(yes|no)", ln.strip())
         if m is None:
             raise SmokeFailure(f"platform check: cannot parse {ln!r}")
+        # the store says itself whether its hash-agg finalize is the one
+        # native call or the numpy chain (no silent fallback)
+        self.checks.require("store says native_finalize=yes", m[5] == "yes",
+                            "the store's extension lacks "
+                            "hash_finalize_packed")
         dev = {"platform": m[1], "kind": m[2], "count": int(m[3]),
                "mesh": m[4]}
         if dev["platform"] != "tpu" and not self.args.allow_cpu:
@@ -408,6 +413,10 @@ class ServedLeg:
         ck.require(f"{name}: mesh covers every device",
                    mesh["n_devices"] == self.device["count"],
                    f"{mesh} vs start-up {self.device}")
+        fin = mesh["finalize"]
+        ck.on_chip(f"{name}: every Pallas hash accumulator finalized by "
+                   f"the native call", fin["native"] > 0 and
+                   fin["numpy"] == 0, fin)
         bad = [s for s in health["device_health"]["slices"]
                if s.get("state") not in (None, "healthy")]
         ck.require(f"{name}: no quarantined slice", not bad, bad)
@@ -913,10 +922,11 @@ def main() -> int:
         raise SmokeFailure(f"checkout check: chip_smoke.py drives the "
                            f"tikv_tpu package beside it — {e}")
     checks = Checks(args.allow_cpu)
-    # a store that has silently lost its C++ loader is a failure, not a
+    # a store that has silently lost its C++ loader, or the native
+    # hash-agg finalize that shares its extension, is a failure, not a
     # slow run
     for fn in ("mvcc_build_columnar", "build_mvcc_sst",
-               "mvcc_parse_planes"):
+               "mvcc_parse_planes", "hash_finalize_packed"):
         checks.require(f"native.{fn} built",
                        getattr(native, fn) is not None,
                        "g++ build of native/fastbuild.cpp failed")

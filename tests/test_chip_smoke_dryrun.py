@@ -62,4 +62,9 @@ def test_with_device_store_refuses_an_unasked_for_cpu_fallback():
         env=env, cwd=ROOT, capture_output=True, text=True, timeout=300)
     assert r.returncode != 0
     assert "device runner: platform=cpu" in r.stdout, r.stdout
+    # the same line says whether the hash-agg finalize is the native
+    # call (no silent fallback to the numpy chain)
+    from tikv_tpu import native
+    built = "yes" if native.hash_finalize_packed is not None else "no"
+    assert f" native_finalize={built}\n" in r.stdout, r.stdout
     assert "found no accelerator" in r.stderr, r.stderr[-2000:]

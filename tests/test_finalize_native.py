@@ -1,0 +1,365 @@
+"""The hash aggregation's finalize after a Pallas launch is ONE native call.
+
+``runner.finalize_packed`` hands the fetched ``(2, HI, W)`` int32
+accumulator parts to ``native.hash_finalize_packed``
+(native/fastbuild.cpp), which holds the GIL from entry to return, where
+the numpy chain (``_sum_parts`` → ``_pallas_states`` → ``finalize_hash``)
+made ~24 array calls and dropped the GIL around each.  The chain stays
+in the tree as the fallback and is the oracle here: the native planes
+must be array-equal in values, validity, dtype and length, and the reply
+the same bytes, for every aggregate, key mode, NULL shape, grid shape
+and width the Pallas hash path can produce.  What the native call cannot
+serve must reach the chain unchanged, and the runner must count which of
+the two ran.
+
+Off a TPU ``_try_pallas`` returns None, so the accumulators here are
+synthetic: plane sums drawn at random, packed as the kernel packs them.
+tests/test_pallas_hash_interpret.py drives the same code from a real
+(interpreted) launch.
+"""
+
+import re
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+
+from tikv_tpu import native
+from tikv_tpu.datatype import EvalType, FieldType
+from tikv_tpu.datatype.column import ColumnBatch
+from tikv_tpu.device import DeviceRunner
+from tikv_tpu.device import runner as runner_mod
+from tikv_tpu.device.kernels import PlaneLayout, build_layouts
+from tikv_tpu.executors.runner import SelectResult
+from tikv_tpu.ops.agg import AggSpec, finalize_hash
+from tikv_tpu.parallel import make_mesh
+from tikv_tpu.server import fastpath
+
+needs_native = pytest.mark.skipif(
+    native.hash_finalize_packed is None,
+    reason="native/fastbuild.cpp did not build here (no g++?): the "
+           "native finalize cannot be compared with the numpy chain")
+
+I64_MAX = (1 << 63) - 1
+
+
+# ------------------------------------------------- a synthetic accumulator
+
+def make_case(aggs=(("count_star", 0), ("sum", 2)), *, mode="dense",
+              base=0, capacity=1024, groups=700, null_group=False,
+              zero_nonnull=False, parts=1, tight=False, big=False,
+              ok_is_mask=False, LO=32, seed=0):
+    return dict(aggs=aggs, mode=mode, base=base, capacity=capacity,
+                groups=groups, null_group=null_group,
+                zero_nonnull=zero_nonnull, parts=parts, tight=tight,
+                big=big, ok_is_mask=ok_is_mask, LO=LO, seed=seed)
+
+
+def split(rng, total, n_parts):
+    """``total`` (int64, inside int32) as ``n_parts`` int32 addends."""
+    out = [rng.integers(-1 << 20, 1 << 20, total.shape)
+           for _ in range(n_parts - 1)]
+    out.append(total - sum(out))
+    assert all(np.abs(p).max(initial=0) < 1 << 31 for p in out)
+    return [p.astype(np.int32) for p in out]
+
+
+def accumulator(case):
+    """→ (parts, LO, p8, layouts, specs, slots, base, capacity,
+    slot_keys): what ``_run_hash`` hands ``finalize_packed`` after a
+    Pallas launch, with plane sums drawn at random."""
+    rng = np.random.default_rng(case["seed"])
+    capacity, LO = case["capacity"], case["LO"]
+    specs = [AggSpec(kind, i, EvalType.INT)
+             for i, (kind, _nb) in enumerate(case["aggs"])]
+    layouts, p8, pf = build_layouts(
+        specs, [False] * len(specs), [nb for _k, nb in case["aggs"]],
+        [case["ok_is_mask"]] * len(specs))
+    assert pf == 0
+    slots = capacity + 2                        # + NULL + scrap
+    # the kernel's tight grid: no scrap row, and no NULL row for a key
+    # that cannot be NULL, so HI·LO may be under ``slots``
+    grid = capacity if case["tight"] else slots
+    HI = -(-grid // LO)
+    if case["tight"]:
+        assert HI * LO < slots and not case["null_group"]
+
+    slot_keys = None
+    key_slots = capacity
+    if case["mode"] == "sparse":
+        # sorted distinct keys, fewer than ``capacity`` of them
+        slot_keys = np.unique(rng.integers(-1 << 62, 1 << 62,
+                                           max(case["groups"], 1) + 7))
+        key_slots = len(slot_keys)
+    present = np.zeros(HI * LO, np.bool_)
+    present[rng.choice(key_slots, case["groups"], replace=False)] = True
+    if case["null_group"]:
+        present[capacity] = True
+    if not case["tight"]:
+        present[capacity + 1] = True            # the scrap slot: ignored
+
+    cmax = 1 << 37 if case["big"] else 1 << 12
+    S8 = np.zeros((p8, HI * LO), np.int64)
+    S8[0] = np.where(present, rng.integers(1, cmax, HI * LO), 0)
+    zeroed = np.flatnonzero(present)[:3] if case["zero_nonnull"] else []
+    for lay in layouts:
+        if lay.kind == "count_star":
+            continue
+        if lay.ok_plane != 0:
+            ok = rng.integers(0, S8[0] + 1)     # 0 ≤ ok ≤ rows
+            ok[zeroed] = 0
+            S8[lay.ok_plane] = ok
+        ok = S8[lay.ok_plane]
+        for p in lay.byte_planes:               # Σ (byte − 128) over ok
+            S8[p] = rng.integers(-128 * ok, 127 * ok + 1)
+    # (p8, HI·LO) planes → the packed (HI, p8·LO) layout → an int32
+    # pair with lo + (hi << 16) == the sum, lo not held under 2^16
+    packed = S8.reshape(p8, HI, LO).transpose(1, 0, 2).reshape(HI, p8 * LO)
+    carry = rng.integers(0, 4, packed.shape)
+    lo = (packed & 0xFFFF) + (carry << 16)
+    hi = (packed >> 16) - carry
+    assert np.array_equal(lo + (hi << 16), packed)
+    parts = [np.stack(pair) for pair in zip(split(rng, lo, case["parts"]),
+                                            split(rng, hi, case["parts"]))]
+    return (parts, LO, p8, layouts, specs, slots, case["base"], capacity,
+            slot_keys)
+
+
+def plan_of(specs):
+    return runner_mod._Plan(scan=None, kind="hash_agg", used_cols=[],
+                            specs=list(specs))
+
+
+def numpy_chain(parts, LO, p8, layouts, specs, slots, base, capacity,
+                slot_keys):
+    """The finalize as it stood before the native call: the oracle."""
+    present, states = DeviceRunner._pallas_states(
+        runner_mod._sum_parts(parts), LO, p8, layouts, specs, slots)
+    merged = {"present": present, "overflow": False, "states": states}
+    return runner_mod._hash_columns(
+        DeviceRunner._agg_out(plan_of(specs)),
+        finalize_hash(specs, merged, base, capacity, slot_keys=slot_keys))
+
+
+def wire_bytes(specs, cols):
+    schema = DeviceRunner._agg_out(plan_of(specs))[0] + [FieldType.long()]
+    return fastpath.encode_response(
+        {"backend": "device", "trace_id": "t"},
+        SelectResult(ColumnBatch(schema, list(cols)), []))
+
+
+def assert_same_columns(new, old):
+    assert len(new) == len(old)
+    for got, want in zip(new, old):
+        assert got.eval_type is want.eval_type
+        assert got.values.dtype == want.values.dtype
+        assert got.validity.dtype == want.validity.dtype == np.bool_
+        assert len(got.values) == len(want.values) == len(got.validity)
+        assert np.array_equal(got.validity, want.validity)
+        assert np.array_equal(got.values, want.values)
+        # the Column contract: a harmless 0 under a False validity
+        assert not got.values[~got.validity].any()
+
+
+ALL_FOUR = (("count_star", 0), ("count", 0), ("sum", 2), ("avg", 3))
+
+CASES = {
+    # each kind alone, then together; own validity planes and aliased
+    "count_star": make_case((("count_star", 0),)),
+    "count": make_case((("count", 0),), zero_nonnull=True),
+    "sum": make_case((("sum", 2),), zero_nonnull=True),
+    "avg": make_case((("avg", 2),), zero_nonnull=True),
+    "together": make_case(ALL_FOUR, zero_nonnull=True, null_group=True),
+    "together-ok-is-mask": make_case(ALL_FOUR, ok_is_mask=True),
+    "the-cells-plan": make_case((("count", 0), ("sum", 2)),
+                                ok_is_mask=True, tight=True),
+    # keys
+    "negative-base": make_case(base=-(1 << 40), null_group=True),
+    "base-at-int64-min": make_case(base=-(1 << 63)),
+    "base-under-int64-max": make_case(base=I64_MAX - 1024),
+    "sparse": make_case(ALL_FOUR, mode="sparse", zero_nonnull=True),
+    "sparse-null-group": make_case(mode="sparse", null_group=True,
+                                   groups=1000),
+    # NULL shapes
+    "null-group-only": make_case(groups=0, null_group=True),
+    "null-group-whose-sum-is-null": make_case(
+        (("sum", 1), ("avg", 1)), groups=0, null_group=True,
+        zero_nonnull=True),
+    "no-group-at-all": make_case(ALL_FOUR, groups=0),
+    "no-group-at-all-sparse": make_case(mode="sparse", groups=0),
+    # parts and grid
+    "three-parts": make_case(ALL_FOUR, parts=3, null_group=True),
+    "three-parts-sparse-big": make_case(ALL_FOUR, mode="sparse", parts=3,
+                                        big=True),
+    "grid-under-slots": make_case(ALL_FOUR, tight=True, groups=1024),
+    "grid-under-slots-three-parts": make_case(tight=True, parts=3),
+    "LO-8": make_case(ALL_FOUR, LO=8, capacity=64, groups=40,
+                      null_group=True),
+    # widths: negative sums come out of the bias term
+    **{f"nb-{nb}": make_case((("sum", nb), ("avg", nb)), null_group=True,
+                             zero_nonnull=True, seed=nb)
+       for nb in (1, 2, 3, 4, 8)},
+    "nb-mixed": make_case((("sum", 1), ("sum", 8), ("avg", 4), ("sum", 3))),
+    # cells past 2^32: the ``hi << 16`` carry; nb 8 wraps int64 as numpy
+    **{f"past-2^32-nb-{nb}": make_case((("count", 0), ("sum", nb),
+                                        ("avg", nb)), big=True, seed=nb)
+       for nb in (2, 4, 8)},
+    # group counts
+    "one-group": make_case(ALL_FOUR, groups=1),
+    "1024-groups": make_case(ALL_FOUR, groups=1024, null_group=True),
+    "65536-groups": make_case(ALL_FOUR, capacity=65536, groups=65536,
+                              null_group=True, zero_nonnull=True),
+    "65536-groups-sparse-three-parts": make_case(
+        mode="sparse", capacity=65536, groups=65529, parts=3),
+}
+
+
+@needs_native
+@pytest.mark.parametrize("name", CASES)
+def test_native_planes_equal_the_numpy_chain(name):
+    case = CASES[name]
+    args = accumulator(case)
+    specs = args[4]
+    want = numpy_chain(*args)
+    finalized, was_native = runner_mod.finalize_packed(*args)
+    assert was_native
+    got = runner_mod._hash_columns(
+        DeviceRunner._agg_out(plan_of(specs)), finalized)
+    assert_same_columns(got, want)
+    assert wire_bytes(specs, got) == wire_bytes(specs, want)
+    # the oracle is not vacuous: every group the case planted is there
+    n_groups = case["groups"] + case["null_group"]
+    assert len(want[-1].values) == n_groups
+    assert int((~want[-1].validity).sum()) == case["null_group"]
+    if case["zero_nonnull"] and n_groups:
+        # a group none of whose arguments was non-NULL: COUNT 0, SUM and
+        # AVG NULL
+        for col, (kind, _nb) in zip(want, case["aggs"]):
+            if kind == "count":
+                assert col.validity.all() and (col.values == 0).any()
+            elif kind in ("sum", "avg"):
+                assert not col.validity.all()
+    if any(nb for _k, nb in case["aggs"]) and n_groups > 10:
+        sums = [c for c, (k, _nb) in zip(want, case["aggs"]) if k == "sum"]
+        assert all((c.values < 0).any() for c in sums)
+        if case["big"]:
+            assert all((np.abs(c.values) > 1 << 32).any() for c in sums)
+
+
+@needs_native
+def test_the_native_finalize_never_lets_go_of_the_gil():
+    """It is one call because nothing inside it hands the GIL on."""
+    src = Path(native.__file__).with_name("fastbuild.cpp").read_text()
+    body = re.search(r"\nPyObject\* hash_finalize_packed\(.*?\n}\n", src,
+                     re.S).group(0)
+    assert "return PyLong_FromSsize_t(k);" in body
+    assert "ALLOW_THREADS" not in body
+
+
+# -------------------------------------------------- what it must decline
+
+@pytest.fixture(scope="module")
+def runner():
+    return DeviceRunner(mesh=make_mesh(jax.devices()[:1]))
+
+
+def outcome(fn, *args):
+    try:
+        return "columns", fn(*args)
+    except Exception as e:                      # the chain's own error
+        return "raised", type(e)
+
+
+def f32_layout(args):
+    """A real SUM rides a float plane (never on the Pallas path:
+    ``pallas_hash.supported`` wants pf == 0)."""
+    layouts, _p8, pf = build_layouts(args[4], [False, True], [0, 0], None)
+    assert pf == 1 and layouts[1].f32_plane == 0
+    return args[:3] + (layouts,) + args[4:]
+
+
+def unknown_kind(args):
+    lay = args[3][1]
+    strange = PlaneLayout("min", ok_plane=lay.ok_plane,
+                          byte_planes=lay.byte_planes, nb=lay.nb)
+    specs = [args[4][0], AggSpec("min", 1, EvalType.INT)]
+    return args[:3] + ([args[3][0], strange], specs) + args[5:]
+
+
+def uint64_domain(args):
+    return args[:6] + (I64_MAX - 5,) + args[7:]         # base
+
+
+def uint64_slot_keys(args):
+    keys = np.arange(args[7], dtype=np.uint64) + np.uint64(I64_MAX - 5)
+    return args[:8] + (keys,)
+
+
+def int64_parts(args):
+    return ([p.astype(np.int64) for p in args[0]],) + args[1:]
+
+
+def strided_parts(args):
+    wide = [np.repeat(p, 2, axis=2) for p in args[0]]
+    return ([w[:, :, ::2] for w in wide],) + args[1:]
+
+
+DECLINED = {
+    "f32-plane": f32_layout,
+    "kind-outside-the-four": unknown_kind,
+    "uint64-key-domain": uint64_domain,
+    "uint64-slot-keys": uint64_slot_keys,
+    "int64-parts": int64_parts,
+    "parts-not-contiguous": strided_parts,
+    "extension-absent": lambda args: args,
+}
+
+
+@pytest.mark.parametrize("name", DECLINED)
+def test_what_the_native_call_declines_takes_the_numpy_chain(
+        name, runner, monkeypatch):
+    args = DECLINED[name](accumulator(
+        make_case((("count_star", 0), ("sum", 2)), null_group=True,
+                  parts=2, seed=5)))
+    if name == "extension-absent":
+        monkeypatch.setattr(native, "hash_finalize_packed", None)
+    else:
+        def never(*_a):
+            raise AssertionError(f"{name}: handed to the native call")
+        monkeypatch.setattr(native, "hash_finalize_packed", never)
+    want = outcome(numpy_chain, *args)
+    before = runner.mesh_stats()["finalize"]
+    (parts, LO, p8, layouts, specs, slots, base, capacity, slot_keys) = args
+    got = outcome(runner._packed_columns, plan_of(specs), parts, LO, p8,
+                  layouts, slots, base, capacity, slot_keys)
+    after = runner.mesh_stats()["finalize"]
+    assert got[0] == want[0]
+    if want[0] == "columns":
+        assert_same_columns(got[1], want[1])
+        assert len(got[1][-1].values) == 701
+        if "uint64" in name:
+            assert got[1][-1].values.dtype == np.uint64
+        assert wire_bytes(specs, got[1]) == wire_bytes(specs, want[1])
+    else:
+        # never on the Pallas path: the chain fails as it did before
+        assert name in ("f32-plane", "kind-outside-the-four")
+        assert got[1] is want[1]
+    # counted once a finalize, as what it was
+    assert after["native"] == before["native"]
+    assert after["numpy"] - before["numpy"] == (want[0] == "columns")
+    assert after["native_available"] is (name != "extension-absent")
+
+
+@needs_native
+def test_the_runner_counts_a_native_finalize(runner):
+    args = accumulator(make_case(ALL_FOUR, mode="sparse", parts=3))
+    (parts, LO, p8, layouts, specs, slots, base, capacity, slot_keys) = args
+    before = runner.mesh_stats()["finalize"]
+    cols = runner._packed_columns(plan_of(specs), parts, LO, p8, layouts,
+                                  slots, base, capacity, slot_keys)
+    assert_same_columns(cols, numpy_chain(*args))
+    after = runner.mesh_stats()["finalize"]
+    assert after == {"native": before["native"] + 1,
+                     "numpy": before["numpy"], "native_available": True}

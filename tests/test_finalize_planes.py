@@ -117,8 +117,8 @@ def new_hash_result(specs, merged, base, capacity, slot_keys=None):
                             specs=list(specs))
     agg_out = DeviceRunner._agg_out(plan)
     assert DeviceRunner._agg_out(plan) is agg_out    # once per plan
-    cols = runner_mod._hash_columns(specs, agg_out, merged, base,
-                                    capacity, slot_keys)
+    cols = runner_mod._hash_columns(agg_out, finalize_hash(
+        specs, merged, base, capacity, slot_keys=slot_keys))
     return agg_out[0] + [FieldType.long()], cols
 
 

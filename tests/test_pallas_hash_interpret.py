@@ -21,6 +21,7 @@ import pytest
 
 import jax
 
+from tikv_tpu import native
 from tikv_tpu.datatype import Column, EvalType, FieldType
 from tikv_tpu.device import DeviceRunner, pallas_hash
 from tikv_tpu.executors.columnar import ColumnarTable
@@ -73,6 +74,18 @@ def _served_by_pallas(runner: DeviceRunner) -> None:
     assert not disabled, disabled
 
 
+def _finalized_natively(runner: DeviceRunner) -> None:
+    """Both requests' accumulators (the cold build's, fetched in line,
+    and the warm launch's parts) became planes in the one native call
+    (native/fastbuild.cpp ``hash_finalize_packed``) where the extension
+    built, and in the numpy chain where it did not: counted either way,
+    once a finalize (the only tier-1 path through ``from_packed``)."""
+    built = native.hash_finalize_packed is not None
+    assert runner.mesh_stats()["finalize"] == {
+        "native": 2 if built else 0, "numpy": 0 if built else 2,
+        "native_available": built}
+
+
 def _group_rows(result) -> dict:
     return {r[-1]: tuple(r[:-1]) for r in result.rows()}
 
@@ -108,6 +121,7 @@ def test_dense_mode_matches_numpy(interpret, n_devices):
     assert _group_rows(runner.handle_request(dag(), snap)) == want
     assert _group_rows(runner.handle_request(dag(), snap)) == want
     _served_by_pallas(runner)
+    _finalized_natively(runner)
     if n_devices == 1:
         feed_pad = {f["n_pad"] for b in (e.bucket for e in
                     runner._arena._entries.values())
@@ -134,6 +148,7 @@ def test_sparse_mode_matches_numpy(interpret, n_devices):
     assert _group_rows(runner.handle_request(dag(), snap)) == want
     assert _group_rows(runner.handle_request(dag(), snap)) == want
     _served_by_pallas(runner)
+    _finalized_natively(runner)
 
 
 @pytest.mark.parametrize("n_devices", [1, 4])

@@ -75,6 +75,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import native
 from ..copr.dag import (
     AggregationDesc,
     DAGRequest,
@@ -90,6 +91,7 @@ from ..expr import build_rpn
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef, RpnConst, RpnExpression, RpnFnCall
 from ..ops.agg import (
+    _I64_MAX,
     AggSpec,
     finalize_hash,
     finalize_simple,
@@ -280,17 +282,85 @@ def _sum_parts(parts):
     return packed
 
 
-def _hash_columns(specs, agg_out, merged, base, capacity, slot_keys):
-    """Merged hash-agg state → result Columns (aggregates, then the
-    key), wrapped straight around ``finalize_hash``'s planes: no Python
-    value is made per group between the fetched accumulator and the
-    wire encoder.  ``agg_out``: ``DeviceRunner._agg_out`` of the plan."""
-    (keys, key_valid), planes = finalize_hash(
-        specs, merged, base, capacity, slot_keys=slot_keys)
+def _hash_columns(agg_out, finalized):
+    """Finalized hash-agg planes → result Columns (aggregates, then the
+    key): the ONE place where planes become Columns for every hash body,
+    whether ``ops.agg.finalize_hash`` or the native call of
+    ``finalize_packed`` made them.  No Python value is made per group
+    between the fetched accumulator and the wire encoder.  ``agg_out``:
+    ``DeviceRunner._agg_out`` of the plan; ``finalized``:
+    ``finalize_hash``'s ``((keys, key_valid), planes)``."""
+    (keys, key_valid), planes = finalized
     cols = [Column(ft.eval_type, vals.astype(dt, copy=False), ok)
             for ft, dt, (vals, ok) in zip(*agg_out, planes)]
     cols.append(Column(EvalType.INT, keys, key_valid))
     return cols
+
+
+# kernels.PlaneLayout kinds the native finalize serves, by the code
+# native/fastbuild.cpp knows them by (``FinKind``)
+_NATIVE_FINALIZE_KINDS = {"count_star": 0, "count": 1, "sum": 2, "avg": 3}
+
+
+def _native_layout_desc(layouts):
+    """``layouts`` flattened for ``native.hash_finalize_packed`` — per
+    spec: kind code, ``ok_plane``, ``nb``, then the ``nb`` byte-plane
+    indices — or None where one is outside what that call serves (a
+    float plane, a kind outside the four)."""
+    flat = []
+    for lay in layouts:
+        code = _NATIVE_FINALIZE_KINDS.get(lay.kind)
+        if code is None or lay.f32_plane is not None:
+            return None
+        flat += (code, lay.ok_plane or 0, lay.nb, *lay.byte_planes)
+    return np.array(flat, np.int64)
+
+
+def finalize_packed(parts, LO, p8, layouts, specs, slots, base, capacity,
+                    slot_keys):
+    """The fetched Pallas hash accumulator → ``finalize_hash``'s planes.
+
+    ``parts``: one (2, HI, p8·LO) int32 pair per tile (one on a
+    whole-feed launch and on a mesh); they add.  Returns
+    ``(((keys, key_valid), planes), native)``.
+
+    Where the extension built and the input is what the Pallas hash
+    path produces — int32 parts, integer layouts of COUNT / SUM / AVG,
+    a key domain inside int64 — this is ONE call into
+    ``native.hash_finalize_packed``, which holds the GIL from entry to
+    return: the numpy chain below makes ~24 array calls on planes of
+    1k-4k elements, numpy drops the GIL around each, and on a serving
+    store every drop queues behind ~10 runnable threads (PERF.md
+    section 6, PRs 26 and 28).  The planes are views of buffers sized
+    ``capacity + 1`` (``np.empty`` and a slice drop no GIL).  What it
+    adapts to is in its input: anything else takes the numpy chain
+    (``_sum_parts`` → ``_pallas_states`` → ``finalize_hash``), the same
+    bytes, kept as the fallback and as the oracle of
+    tests/test_finalize_native.py.  ``native`` says which ran; the
+    caller counts it (``/health`` ``device_mesh.finalize``).
+    """
+    call = native.hash_finalize_packed
+    keys_fit_int64 = slot_keys.dtype == np.int64 if slot_keys is not None \
+        else base + capacity <= _I64_MAX
+    desc = None
+    if call is not None and keys_fit_int64 and all(
+            p.dtype == np.int32 and p.flags.c_contiguous for p in parts):
+        desc = _native_layout_desc(layouts)
+    if desc is not None:
+        n = capacity + 1                # + the NULL slot
+        keys = np.empty(n, np.int64)
+        key_valid = np.empty(n, np.bool_)
+        outs = [(np.empty(n, np.float64 if lay.kind == "avg" else np.int64),
+                 np.empty(n, np.bool_)) for lay in layouts]
+        k = call(parts, LO, p8, capacity, 0 if slot_keys is not None
+                 else base, slot_keys, desc, keys, key_valid, outs)
+        return ((keys[:k], key_valid[:k]),
+                [(vals[:k], ok[:k]) for vals, ok in outs]), True
+    present, states = DeviceRunner._pallas_states(
+        _sum_parts(parts), LO, p8, layouts, specs, slots)
+    return finalize_hash(
+        specs, {"present": present, "overflow": False, "states": states},
+        base, capacity, slot_keys=slot_keys), False
 
 
 class _GuardedMeta:
@@ -460,8 +530,11 @@ class _Pending:
     """A dispatched device request: output pytree still on device plus
     the host finalize that turns the fetched numpy tree into a
     SelectResult (the ``host_materialize`` phase; for a hash
-    aggregation the accumulator's unpack and ``_hash_columns``, array
-    calls only, whatever the group count).  Leaves are staged to
+    aggregation after a Pallas launch ``finalize_packed``: ONE native
+    call from the fetched accumulator parts to the result planes, GIL
+    held throughout, then ``_hash_columns``'s wrap; the numpy chain
+    where that call cannot serve, and ``finalize_hash`` for the XLA
+    bodies' states).  Leaves are staged to
     pinned host memory at construction when the backend supports it
     (:class:`_PinnedStager`)
     and ``copy_to_host_async`` is issued for every leaf, so the D2H
@@ -1201,8 +1274,10 @@ class DeviceRunner:
         (``live``: a submesh while a slice is quarantined), what ran on
         it (``sharded_launches``: launches over every device of the
         configured mesh, beside the flight recorder's ``launches``;
-        ``submesh_rebuilds``; both monotone), the resident bytes of the
-        live mesh's fullest shard, and the placement rollup."""
+        ``submesh_rebuilds``; ``finalize``: Pallas hash accumulators
+        finalized by the one native call or by the numpy chain, and
+        whether the extension built; all monotone), the resident bytes
+        of the live mesh's fullest shard, and the placement rollup."""
         shape = dict(zip(ROW_AXES,
                          (int(s) for s in self._mesh.devices.shape)))
         dev0 = self._mesh.devices.flat[0]
@@ -1214,6 +1289,10 @@ class DeviceRunner:
                "live": {"shape": live._mesh_desc,
                         "n_devices": num_shards(live._mesh)},
                "sharded_launches": self.flight_recorder.sharded_launches,
+               "finalize": {
+                   **self.flight_recorder.finalize_counts(),
+                   "native_available":
+                       native.hash_finalize_packed is not None},
                "submesh_rebuilds": self._submesh_rebuilds,
                "feed_bytes_per_shard": max(
                    live._arena.resident_bytes_by_device().values(),
@@ -3121,9 +3200,12 @@ class DeviceRunner:
         # p50 can be attributed from the artifact alone: "d2h_wait" is
         # the transfer + sync (here), "host_materialize" is the host
         # finalize that follows (_finish): fetched planes -> result
-        # Columns.  For a hash aggregation that is numpy over the KBs
-        # of accumulator, with no Python value made per group; for a
-        # selection it is the host gather of the selected rows
+        # Columns.  For a hash aggregation off the Pallas kernel that is
+        # one native call over the KBs of accumulator, which never
+        # lets go of the GIL (finalize_packed; numpy over the same KBs
+        # for the XLA bodies, no Python value made per group either
+        # way); for a selection it is the host gather of the selected
+        # rows
         with tracker.phase("d2h_wait"):
             leaves, treedef = jax.tree.flatten(tree)
             for x in leaves:
@@ -3992,7 +4074,8 @@ class DeviceRunner:
 
         def hash_result(merged):
             return self._result(dag, list(schema), _hash_columns(
-                plan.specs, agg_out, merged, base, capacity, slot_keys))
+                agg_out, finalize_hash(plan.specs, merged, base, capacity,
+                                       slot_keys=slot_keys)))
 
         got = None
         if layouts is not None:
@@ -4013,16 +4096,14 @@ class DeviceRunner:
         if got is not None:
             kind, payload, pl_LO = got
 
-            def from_packed(packed):
-                present, states = self._pallas_states(
-                    packed, pl_LO, p8, layouts, plan.specs, slots)
-                return hash_result({"present": present,
-                                    "overflow": False, "states": states})
+            def from_packed(parts):
+                return self._result(dag, list(schema), self._packed_columns(
+                    plan, parts, pl_LO, p8, layouts, slots, base, capacity,
+                    slot_keys))
 
             if kind == "sync":
-                return from_packed(payload)
-            return _Pending(payload,
-                            lambda parts: from_packed(_sum_parts(parts)))
+                return from_packed([payload])
+            return _Pending(payload, from_packed)
         elif layouts is not None and twolevel_lo(p8, pf) is not None:
             LO, HI = twolevel_dims(slots, p8, pf)
             chunk = self._pick_chunk(feed["n_pad"], self._feed_unit())
@@ -4120,6 +4201,19 @@ class DeviceRunner:
                 k = -(-blocks // (1 << s))
             blocks = k << s
         return max(1, blocks)
+
+    def _packed_columns(self, plan, parts, LO, p8, layouts, slots, base,
+                        capacity, slot_keys):
+        """The hash aggregation's finalize after a Pallas launch:
+        ``finalize_packed`` (one native call where it can, the numpy
+        chain where it cannot), counted once on the physical runner's
+        flight recorder as what it was (``mesh_stats`` ``finalize``),
+        then ``_hash_columns``."""
+        finalized, was_native = finalize_packed(
+            parts, LO, p8, layouts, plan.specs, slots, base, capacity,
+            slot_keys)
+        self.flight_recorder.note_finalize(was_native)
+        return _hash_columns(self._agg_out(plan), finalized)
 
     @staticmethod
     def _pallas_states(packed, LO, p8, layouts, specs, slots):

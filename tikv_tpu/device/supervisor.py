@@ -141,6 +141,11 @@ class FlightRecorder:
         # configured mesh (not on a placement slice, not on a degraded
         # submesh): what /health device_mesh reports beside ``launches``
         self.sharded_launches = 0
+        # Pallas hash accumulators finalized (device/runner.py
+        # finalize_packed), by what ran: the one native call that holds
+        # the GIL, or the numpy chain it falls back to
+        self.finalize_native = 0
+        self.finalize_numpy = 0
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -177,6 +182,18 @@ class FlightRecorder:
                      "ok": ok}
             self._ring.append(entry)
         return entry
+
+    def note_finalize(self, native: bool) -> None:
+        with self._mu:
+            if native:
+                self.finalize_native += 1
+            else:
+                self.finalize_numpy += 1
+
+    def finalize_counts(self) -> dict:
+        with self._mu:
+            return {"native": self.finalize_native,
+                    "numpy": self.finalize_numpy}
 
     def set_depth(self, depth: int) -> None:
         """Online-resize the ring, keeping the newest tail."""

@@ -1,14 +1,20 @@
 """Native (C++) runtime components, compiled on first import.
 
 The reference's hot loops live in C++/Rust (RocksDB iterators, the row
-codec, tidb_query's decode paths); here the equivalent data-loader —
-the MVCC→columnar builder feeding both the host pipeline and the TPU
-device feed — is a CPython extension (fastbuild.cpp).
+codec, tidb_query's decode paths); here two of them are one CPython
+extension (fastbuild.cpp): the data-loader — the MVCC→columnar builder
+feeding both the host pipeline and the TPU device feed — and, on the
+serving path, the hash aggregation's host finalize, which turns the
+fetched Pallas accumulator into result planes in one call that holds
+the GIL throughout (``hash_finalize_packed``).
 
 The build is hermetic and optional: g++ compiles the module into
 ``_build/`` keyed by source hash (one compile per source change, ~2s);
-any failure leaves ``mvcc_build_columnar = None`` and callers use the
-interpreted fallback, so the framework never hard-requires a compiler.
+any failure leaves every export below ``None`` and callers use their
+interpreted or numpy fallback, so the framework never hard-requires a
+compiler.  No fallback is silent: the cold build labels itself
+``cold_build=native|interpreted``, and the finalize counts itself on
+``/health`` ``device_mesh.finalize``.
 """
 
 from __future__ import annotations
@@ -68,3 +74,8 @@ build_mvcc_sst = getattr(_mod, "build_mvcc_sst", None)
 # with a spare core on the build path, where yielding on a single-CPU
 # box just hands the core to background tick threads)
 mvcc_parse_planes = getattr(_mod, "mvcc_parse_planes", None)
+# hash-agg host finalize: fetched (2, HI, W) int32 accumulator parts →
+# key / value / validity planes in caller-made buffers, GIL held from
+# entry to return (device/runner.py finalize_packed, which keeps the
+# numpy chain as the fallback and the tests' oracle)
+hash_finalize_packed = getattr(_mod, "hash_finalize_packed", None)

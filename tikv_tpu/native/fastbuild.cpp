@@ -28,6 +28,7 @@
 
 #include <cstdint>
 #include <cstring>
+#include <deque>
 #include <string>
 #include <vector>
 
@@ -1087,6 +1088,272 @@ PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
   return PyBytes_FromStringAndSize(out.data(), (Py_ssize_t)out.size());
 }
 
+/* ---- hash-agg finalize: the fetched Pallas accumulator -> result planes
+ *
+ * What device/runner.py's numpy chain does in ~24 array calls
+ * (_sum_parts, pallas_hash.unpack_to_int64, kernels.twolevel_unpack,
+ * kernels.states_from_matmul, ops/agg.py finalize_hash), in one pass
+ * over the slots.  numpy drops the GIL around every inner loop of more
+ * than a few hundred elements and a serving store has ~10 runnable
+ * threads queued for it, so the chain's cost was the hand-offs, not
+ * the arithmetic (PERF.md section 6, PR 26 and PR 28).  This function
+ * never writes Py_BEGIN_ALLOW_THREADS: it holds the GIL from entry to
+ * return.
+ *
+ * Kept in lockstep with that chain, which stays as the fallback and
+ * as the oracle of tests/test_finalize_native.py:
+ *  - a part is a (2, HI, p8*LO) int32 pair (lo, hi); parts add;
+ *    value = lo + (hi << 16), exact in int64
+ *  - slot s lives at row s / LO, lane p*LO + s % LO of plane p; slots
+ *    past HI*LO read zero (the tight grid drops the NULL/scrap rows)
+ *  - int SUM = sum_k plane[bp_k] << 8k + ok * bias_offset(nb), two's
+ *    complement wrap-around as numpy's int64
+ *  - a group is a slot whose plane-0 (row mask) count is > 0, emitted
+ *    in ascending slot order; slot ``capacity`` is the NULL key, last
+ *  - COUNT always valid; SUM valid where ok > 0, else 0; AVG
+ *    double(sum) / double(count) where count > 0, else 0.0
+ */
+
+/* every buffer the call holds, released on any way out */
+struct Views {
+  std::deque<Py_buffer> held;
+  ~Views() {
+    for (auto& b : held) PyBuffer_Release(&b);
+  }
+  /* C-contiguous buffer of ``itemsize``-byte items whose struct format
+   * code is one of ``codes``; nullptr with an exception set otherwise */
+  Py_buffer* get(PyObject* o, bool writable, Py_ssize_t itemsize,
+                 const char* codes, const char* what) {
+    held.emplace_back();
+    int flags = PyBUF_C_CONTIGUOUS | PyBUF_FORMAT |
+                (writable ? PyBUF_WRITABLE : 0);
+    if (PyObject_GetBuffer(o, &held.back(), flags) < 0) {
+      held.pop_back();
+      return nullptr;
+    }
+    Py_buffer* b = &held.back();
+    const char* f = b->format ? b->format : "B";
+    if (*f == '@' || *f == '=' || *f == '<') f++;
+    if (b->itemsize != itemsize || !*f || f[1] || !strchr(codes, *f)) {
+      PyErr_Format(PyExc_TypeError,
+                   "hash_finalize_packed: %s has format %s, itemsize %zd",
+                   what, b->format ? b->format : "(none)", b->itemsize);
+      return nullptr;
+    }
+    return b;
+  }
+};
+
+enum FinKind : int64_t { FIN_COUNT_STAR = 0, FIN_COUNT = 1, FIN_SUM = 2,
+                         FIN_AVG = 3 };
+
+struct FinSpec {
+  int64_t kind, ok_plane, nb;
+  const int64_t* byte_planes;
+  uint64_t bias;
+  void* values;       /* int64, or double for AVG */
+  uint8_t* validity;
+};
+
+PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
+  PyObject *parts_o, *slot_keys_o, *desc_o, *keys_o, *key_valid_o, *outs_o;
+  Py_ssize_t LO, p8, capacity;
+  long long base;
+  if (!PyArg_ParseTuple(args, "OnnnLOOOOO", &parts_o, &LO, &p8, &capacity,
+                        &base, &slot_keys_o, &desc_o, &keys_o, &key_valid_o,
+                        &outs_o))
+    return nullptr;
+  if (LO <= 0 || p8 <= 0 || capacity < 0) {
+    PyErr_SetString(PyExc_ValueError,
+                    "hash_finalize_packed: LO, p8 > 0 and capacity >= 0");
+    return nullptr;
+  }
+  const Py_ssize_t n_out = capacity + 1;    /* + the NULL slot */
+  const Py_ssize_t W = p8 * LO;
+  Views views;
+
+  /* the parts: (2, HI, W) int32 each, one HI for all */
+  PyObject* parts = PySequence_Fast(parts_o, "parts not a sequence");
+  if (!parts) return nullptr;
+  struct Drop {
+    PyObject* o;
+    ~Drop() { Py_XDECREF(o); }
+  } drop_parts{parts};
+  const Py_ssize_t n_parts = PySequence_Fast_GET_SIZE(parts);
+  if (n_parts < 1) {
+    PyErr_SetString(PyExc_ValueError, "hash_finalize_packed: no parts");
+    return nullptr;
+  }
+  std::vector<const int32_t*> part_lo(n_parts);
+  Py_ssize_t HI = -1;
+  for (Py_ssize_t i = 0; i < n_parts; i++) {
+    Py_buffer* b = views.get(PySequence_Fast_GET_ITEM(parts, i), false, 4,
+                             "il", "a part");
+    if (!b) return nullptr;
+    if (b->len % (2 * W * 4) != 0 || (HI >= 0 && b->len != 2 * HI * W * 4)) {
+      PyErr_SetString(PyExc_ValueError,
+                      "hash_finalize_packed: a part is not (2, HI, p8*LO)");
+      return nullptr;
+    }
+    HI = b->len / (2 * W * 4);
+    part_lo[i] = static_cast<const int32_t*>(b->buf);
+  }
+  const Py_ssize_t plane_hi = HI * W;       /* lo pair -> hi pair */
+  const Py_ssize_t have = HI * LO;          /* slots the grid holds */
+
+  /* sparse recode: per-slot key values, int64 */
+  const int64_t* slot_keys = nullptr;
+  Py_ssize_t n_keys = 0;
+  if (slot_keys_o != Py_None) {
+    Py_buffer* b = views.get(slot_keys_o, false, 8, "lq", "slot_keys");
+    if (!b) return nullptr;
+    slot_keys = static_cast<const int64_t*>(b->buf);
+    n_keys = b->len / 8;
+  }
+
+  /* outputs: capacity + 1 entries each */
+  Py_buffer* kb = views.get(keys_o, true, 8, "lq", "the key plane");
+  if (!kb) return nullptr;
+  Py_buffer* kvb = views.get(key_valid_o, true, 1, "?Bb", "the key validity");
+  if (!kvb) return nullptr;
+  if (kb->len < n_out * 8 || kvb->len < n_out) {
+    PyErr_SetString(PyExc_ValueError,
+                    "hash_finalize_packed: key planes shorter than capacity+1");
+    return nullptr;
+  }
+  int64_t* keys = static_cast<int64_t*>(kb->buf);
+  uint8_t* key_valid = static_cast<uint8_t*>(kvb->buf);
+
+  /* layouts: per spec (kind, ok_plane, nb, nb byte-plane indices) */
+  Py_buffer* db = views.get(desc_o, false, 8, "lq", "the layout description");
+  if (!db) return nullptr;
+  const int64_t* desc = static_cast<const int64_t*>(db->buf);
+  const Py_ssize_t n_desc = db->len / 8;
+  PyObject* outs = PySequence_Fast(outs_o, "outs not a sequence");
+  if (!outs) return nullptr;
+  Drop drop_outs{outs};
+  const Py_ssize_t n_specs = PySequence_Fast_GET_SIZE(outs);
+  std::vector<FinSpec> specs(n_specs);
+  Py_ssize_t at = 0;
+  for (Py_ssize_t i = 0; i < n_specs; i++) {
+    FinSpec& sp = specs[i];
+    if (at + 3 > n_desc) {
+      PyErr_SetString(PyExc_ValueError,
+                      "hash_finalize_packed: fewer layouts than outputs");
+      return nullptr;
+    }
+    sp.kind = desc[at];
+    sp.ok_plane = desc[at + 1];
+    sp.nb = desc[at + 2];
+    sp.byte_planes = desc + at + 3;
+    bool summed = sp.kind == FIN_SUM || sp.kind == FIN_AVG;
+    if (sp.kind < FIN_COUNT_STAR || sp.kind > FIN_AVG || sp.ok_plane < 0 ||
+        sp.ok_plane >= p8 || (summed ? sp.nb < 1 || sp.nb > 8 : sp.nb != 0) ||
+        at + 3 + sp.nb > n_desc) {
+      PyErr_SetString(PyExc_ValueError,
+                      "hash_finalize_packed: bad layout description");
+      return nullptr;
+    }
+    for (int64_t k = 0; k < sp.nb; k++)
+      if (sp.byte_planes[k] < 0 || sp.byte_planes[k] >= p8) {
+        PyErr_SetString(PyExc_ValueError,
+                        "hash_finalize_packed: byte plane outside p8");
+        return nullptr;
+      }
+    at += 3 + sp.nb;
+    /* kernels.bias_offset: 128 * sum_k 2^(8k) - 2^(8nb-1) */
+    sp.bias = 0;
+    if (summed) {
+      for (int64_t k = 0; k < sp.nb; k++) sp.bias += 128ULL << (8 * k);
+      sp.bias -= 1ULL << (8 * sp.nb - 1);
+    }
+    PyObject* pair = PySequence_Fast_GET_ITEM(outs, i);
+    if (!PyTuple_Check(pair) || PyTuple_GET_SIZE(pair) != 2) {
+      PyErr_SetString(PyExc_TypeError,
+                      "hash_finalize_packed: an output is (values, validity)");
+      return nullptr;
+    }
+    Py_buffer* vb = views.get(PyTuple_GET_ITEM(pair, 0), true, 8,
+                              sp.kind == FIN_AVG ? "d" : "lq",
+                              "a value plane");
+    if (!vb) return nullptr;
+    Py_buffer* ob = views.get(PyTuple_GET_ITEM(pair, 1), true, 1, "?Bb",
+                              "a validity plane");
+    if (!ob) return nullptr;
+    if (vb->len < n_out * 8 || ob->len < n_out) {
+      PyErr_SetString(PyExc_ValueError,
+                      "hash_finalize_packed: output shorter than capacity+1");
+      return nullptr;
+    }
+    sp.values = vb->buf;
+    sp.validity = static_cast<uint8_t*>(ob->buf);
+  }
+  if (at != n_desc) {
+    PyErr_SetString(PyExc_ValueError,
+                    "hash_finalize_packed: more layouts than outputs");
+    return nullptr;
+  }
+
+  /* the one pass.  Arithmetic is unsigned where numpy's int64 wraps. */
+  Py_ssize_t k = 0;
+  const Py_ssize_t last = capacity < have ? capacity : have - 1;
+  for (Py_ssize_t s = 0; s <= last; s++) {
+    const Py_ssize_t cell = (s / LO) * W + s % LO;
+    auto plane = [&](int64_t p) -> uint64_t {
+      const Py_ssize_t j = cell + p * LO;
+      uint64_t v = 0;
+      for (Py_ssize_t i = 0; i < n_parts; i++)
+        v += (uint64_t)((int64_t)part_lo[i][j] +
+                        (int64_t)part_lo[i][plane_hi + j] * 65536);
+      return v;
+    };
+    const int64_t mask_count = (int64_t)plane(0);
+    if (mask_count <= 0) continue;
+    if (s == capacity) {
+      keys[k] = 0;
+      key_valid[k] = 0;
+    } else {
+      if (slot_keys) {
+        if (s >= n_keys) {
+          PyErr_Format(PyExc_IndexError,
+                       "hash_finalize_packed: slot %zd is present but "
+                       "slot_keys has %zd entries", s, n_keys);
+          return nullptr;
+        }
+        keys[k] = slot_keys[s];
+      } else {
+        keys[k] = (int64_t)((uint64_t)s + (uint64_t)base);
+      }
+      key_valid[k] = 1;
+    }
+    for (FinSpec& sp : specs) {
+      if (sp.kind == FIN_COUNT_STAR) {
+        static_cast<int64_t*>(sp.values)[k] = mask_count;
+        sp.validity[k] = 1;
+        continue;
+      }
+      const uint64_t ok = plane(sp.ok_plane);
+      if (sp.kind == FIN_COUNT) {
+        static_cast<int64_t*>(sp.values)[k] = (int64_t)ok;
+        sp.validity[k] = 1;
+        continue;
+      }
+      uint64_t total = ok * sp.bias;
+      for (int64_t b = 0; b < sp.nb; b++)
+        total += plane(sp.byte_planes[b]) << (8 * b);
+      const bool valid = (int64_t)ok > 0;
+      sp.validity[k] = valid;
+      if (sp.kind == FIN_SUM)
+        static_cast<int64_t*>(sp.values)[k] = valid ? (int64_t)total : 0;
+      else
+        static_cast<double*>(sp.values)[k] =
+            valid ? (double)(int64_t)total / (double)(int64_t)ok : 0.0;
+    }
+    k++;
+  }
+  return PyLong_FromSsize_t(k);
+}
+
 PyMethodDef methods[] = {
     {"mvcc_build_columnar", mvcc_build, METH_VARARGS,
      "One-pass MVCC resolve + row decode into columnar buffers.\n"
@@ -1101,6 +1368,11 @@ PyMethodDef methods[] = {
      "Bulk pre-timestamped MVCC SST (v2 container) from int64/float64\n"
      "column buffers: (table_id, handles_bytes, col_ids, col_kinds,\n"
      "col_bufs, col_valid, commit_ts, start_ts) -> bytes"},
+    {"hash_finalize_packed", hash_finalize_packed, METH_VARARGS,
+     "Fetched Pallas hash-agg accumulator -> result planes, in one call\n"
+     "that holds the GIL throughout: (parts, LO, p8, capacity, base,\n"
+     "slot_keys | None, layout_desc, keys_out, key_valid_out,\n"
+     "[(values_out, validity_out), ...]) -> group count"},
     {nullptr, nullptr, 0, nullptr}};
 
 PyModuleDef moddef = {PyModuleDef_HEAD_INIT, "_fastbuild",

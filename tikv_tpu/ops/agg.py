@@ -447,6 +447,15 @@ def finalize_hash(specs, state: dict, base: int, capacity: int,
     validity, the ``Column`` contract, so the caller wraps the planes
     without walking them.  No per-group Python runs here except
     ``_finalize_var``, whose float operation order is the host's.
+
+    Two callers in device/runner.py: the XLA hash bodies (two-level,
+    scatter) finalize their states here, and ``finalize_packed`` does
+    for a Pallas accumulator the native call cannot serve, or in a
+    process without the extension.  That native call
+    (native/fastbuild.cpp ``hash_finalize_packed``) gives this
+    function's planes for COUNT / SUM / AVG over an int64 key domain:
+    change the contract in both, tests/test_finalize_native.py holds
+    them equal.
     """
     present = np.asarray(state["present"])
     sel = np.flatnonzero(present[:capacity])

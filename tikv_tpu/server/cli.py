@@ -156,7 +156,9 @@ def main(argv=None) -> int:
             print(f"device runner: platform={ms['platform']} "
                   f"device_kind={ms['device_kind']!r} "
                   f"n_devices={len(jax.devices())} "
-                  f"mesh={'x'.join(str(v) for v in ms['shape'].values())}",
+                  f"mesh={'x'.join(str(v) for v in ms['shape'].values())} "
+                  f"native_finalize="
+                  f"{'yes' if ms['finalize']['native_available'] else 'no'}",
                   flush=True)
             if ms["platform"] == "cpu" and "cpu" not in os.environ.get(
                     "JAX_PLATFORMS", "").split(","):
