@@ -335,8 +335,8 @@ class ServedLeg:
         return self.client
 
     def load(self, table, handles, c0, c1) -> float:
-        """ImportSST bulk load (bench.py _bulk_load shape): native SST
-        encode, chunked upload, raft ingest, import mode on/off."""
+        """ImportSST bulk load (benchmark/tables/int_table.py's shape):
+        native SST encode, chunked upload, raft ingest, import mode."""
         from tikv_tpu.codec.keys import table_record_key
         from tikv_tpu.sst_importer import fast_mvcc_table_sst
         c = self.client

@@ -35,3 +35,77 @@ def test_manifest_declares_the_files_own_entry(name):
     cells = {w["name"]: w for w in manifest["workloads"]}
     assert set(declared[0]["workloads"]) <= set(cells)
     assert declared[0]["moves"] in {m["name"] for m in manifest["end_to_end"]}
+
+
+# ------------------------------------------------ names the benchmark reads
+#
+# The benchmark finds the program by name: a traffic file's
+# ``forbidden_classes`` are flight-recorder compile classes (what
+# ``_dispatch_phase`` is given), and ``mesh.program_ms`` matches an XLA
+# module, which is ``jit_`` + the name of the Python function that was
+# jitted.  A rename in tikv_tpu/device/ would make a check or a metric
+# read nothing, in silence.
+
+DEVICE = os.path.join(ROOT, "tikv_tpu", "device")
+
+
+@functools.lru_cache(maxsize=None)
+def device_sources() -> dict:
+    import ast
+    out = {}
+    for path in sorted(glob.glob(os.path.join(DEVICE, "*.py"))):
+        with open(path) as f:
+            out[os.path.basename(path)] = ast.parse(f.read())
+    return out
+
+
+def names_the_benchmark_reads() -> list:
+    names = set()
+    for path in glob.glob(os.path.join(ROOT, "benchmark", "traffic",
+                                       "*.json")):
+        with open(path) as f:
+            names |= set(json.load(f).get("forbidden_classes", ()))
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "mesh.program_ms.json")) as f:
+        for module in json.load(f)["args"]["match"]:
+            assert module.startswith("jit_"), module
+            names.add(module[len("jit_"):])
+    return sorted(names)
+
+
+@pytest.mark.parametrize("name", names_the_benchmark_reads())
+def test_the_device_layer_has_the_name_the_benchmark_reads(name):
+    import ast
+    classes, functions = set(), set()
+    for tree in device_sources().values():
+        for node in ast.walk(tree):
+            if isinstance(node, ast.FunctionDef):
+                functions.add(node.name)
+            elif isinstance(node, ast.Call) and node.args and \
+                    getattr(node.func, "attr", None) == "_dispatch_phase" \
+                    and isinstance(node.args[0], ast.Constant):
+                classes.add(node.args[0].value)
+    assert name in classes | functions, (sorted(classes), name)
+
+
+# the operator modules of device/ and what they share: the runner
+# imports them, never the other way, at module level or inside a function
+OPERATORS = ("aggregate.py", "join.py", "mvcc.py", "request.py",
+             "selection.py")
+
+
+@pytest.mark.parametrize("module", OPERATORS)
+def test_no_operator_module_imports_the_runner(module):
+    import ast
+    for node in ast.walk(device_sources()[module]):
+        if isinstance(node, ast.ImportFrom):
+            imported = [node.module or ""] + [a.name for a in node.names]
+        elif isinstance(node, ast.Import):
+            imported = [a.name for a in node.names]
+        else:
+            continue
+        # (executors/runner.py is the host pipeline's, another module)
+        assert not any("runner" in dotted.split(".") and
+                       "executors" not in dotted.split(".")
+                       for dotted in imported), \
+            (module, node.lineno, imported)

@@ -1,6 +1,6 @@
 """Microsecond warm path: compiled request fast path + back-to-back
 dispatcher + pinned D2H staging (server/fastpath.py, coalescer
-pipeline, runner._PinnedStager).
+pipeline, request._PinnedStager).
 
 Covers: wire-template codec units (every msgpack int width, floats,
 structural-mismatch safety); randomized fast-vs-full-decode parity
@@ -589,7 +589,7 @@ def test_pinned_stager_disabled_on_cpu_default():
     once, disables itself, and readback is byte-identical."""
     import jax.numpy as jnp
 
-    from tikv_tpu.device.runner import _PinnedStager
+    from tikv_tpu.device.request import _PinnedStager
     st = _PinnedStager()            # default pinned_host
     x = jnp.arange(512, dtype=jnp.int32)
     tree = st.stage({"x": x})
@@ -604,7 +604,7 @@ def test_pinned_stager_mechanics_on_host_space():
     identical to the direct readback."""
     import jax.numpy as jnp
 
-    from tikv_tpu.device.runner import _PinnedStager
+    from tikv_tpu.device.request import _PinnedStager
     st = _PinnedStager(memory_kind="unpinned_host")
     x = jnp.arange(1024, dtype=jnp.int64) * 3
     y = jnp.linspace(0.0, 1.0, 256)

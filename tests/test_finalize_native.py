@@ -1,6 +1,6 @@
 """The hash aggregation's finalize after a Pallas launch is ONE native call.
 
-``runner.finalize_packed`` hands the fetched ``(2, HI, W)`` int32
+``aggregate.finalize_packed`` hands the fetched ``(2, HI, W)`` int32
 accumulator parts to ``native.hash_finalize_packed``
 (native/fastbuild.cpp), which holds the GIL from entry to return, where
 the numpy chain (``_sum_parts`` → ``_pallas_states`` → ``finalize_hash``)
@@ -12,7 +12,7 @@ and width the Pallas hash path can produce.  What the native call cannot
 serve must reach the chain unchanged, and the runner must count which of
 the two ran.
 
-Off a TPU ``_try_pallas`` returns None, so the accumulators here are
+Off a TPU no plan reaches ``_try_pallas``, so the accumulators here are
 synthetic: plane sums drawn at random, packed as the kernel packs them.
 tests/test_pallas_hash_interpret.py drives the same code from a real
 (interpreted) launch.
@@ -30,7 +30,9 @@ from tikv_tpu import native
 from tikv_tpu.datatype import EvalType, FieldType
 from tikv_tpu.datatype.column import ColumnBatch
 from tikv_tpu.device import DeviceRunner
-from tikv_tpu.device import runner as runner_mod
+from tikv_tpu.device import aggregate as agg_mod
+from tikv_tpu.device.aggregate import DeviceAggregator
+from tikv_tpu.device.request import _Plan
 from tikv_tpu.device.kernels import PlaneLayout, build_layouts
 from tikv_tpu.executors.runner import SelectResult
 from tikv_tpu.ops.agg import AggSpec, finalize_hash
@@ -68,7 +70,7 @@ def split(rng, total, n_parts):
 
 def accumulator(case):
     """→ (parts, LO, p8, layouts, specs, slots, base, capacity,
-    slot_keys): what ``_run_hash`` hands ``finalize_packed`` after a
+    slot_keys): what ``run_hash`` hands ``finalize_packed`` after a
     Pallas launch, with plane sums drawn at random."""
     rng = np.random.default_rng(case["seed"])
     capacity, LO = case["capacity"], case["LO"]
@@ -128,23 +130,23 @@ def accumulator(case):
 
 
 def plan_of(specs):
-    return runner_mod._Plan(scan=None, kind="hash_agg", used_cols=[],
+    return _Plan(scan=None, kind="hash_agg", used_cols=[],
                             specs=list(specs))
 
 
 def numpy_chain(parts, LO, p8, layouts, specs, slots, base, capacity,
                 slot_keys):
     """The finalize as it stood before the native call: the oracle."""
-    present, states = DeviceRunner._pallas_states(
-        runner_mod._sum_parts(parts), LO, p8, layouts, specs, slots)
+    present, states = agg_mod._pallas_states(
+        agg_mod._sum_parts(parts), LO, p8, layouts, specs, slots)
     merged = {"present": present, "overflow": False, "states": states}
-    return runner_mod._hash_columns(
-        DeviceRunner._agg_out(plan_of(specs)),
+    return agg_mod._hash_columns(
+        DeviceAggregator._agg_out(plan_of(specs)),
         finalize_hash(specs, merged, base, capacity, slot_keys=slot_keys))
 
 
 def wire_bytes(specs, cols):
-    schema = DeviceRunner._agg_out(plan_of(specs))[0] + [FieldType.long()]
+    schema = DeviceAggregator._agg_out(plan_of(specs))[0] + [FieldType.long()]
     return fastpath.encode_response(
         {"backend": "device", "trace_id": "t"},
         SelectResult(ColumnBatch(schema, list(cols)), []))
@@ -223,10 +225,10 @@ def test_native_planes_equal_the_numpy_chain(name):
     args = accumulator(case)
     specs = args[4]
     want = numpy_chain(*args)
-    finalized, was_native = runner_mod.finalize_packed(*args)
+    finalized, was_native = agg_mod.finalize_packed(*args)
     assert was_native
-    got = runner_mod._hash_columns(
-        DeviceRunner._agg_out(plan_of(specs)), finalized)
+    got = agg_mod._hash_columns(
+        DeviceAggregator._agg_out(plan_of(specs)), finalized)
     assert_same_columns(got, want)
     assert wire_bytes(specs, got) == wire_bytes(specs, want)
     # the oracle is not vacuous: every group the case planted is there
@@ -332,7 +334,7 @@ def test_what_the_native_call_declines_takes_the_numpy_chain(
     want = outcome(numpy_chain, *args)
     before = runner.mesh_stats()["finalize"]
     (parts, LO, p8, layouts, specs, slots, base, capacity, slot_keys) = args
-    got = outcome(runner._packed_columns, plan_of(specs), parts, LO, p8,
+    got = outcome(runner._aggregator._packed_columns, plan_of(specs), parts, LO, p8,
                   layouts, slots, base, capacity, slot_keys)
     after = runner.mesh_stats()["finalize"]
     assert got[0] == want[0]
@@ -357,7 +359,7 @@ def test_the_runner_counts_a_native_finalize(runner):
     args = accumulator(make_case(ALL_FOUR, mode="sparse", parts=3))
     (parts, LO, p8, layouts, specs, slots, base, capacity, slot_keys) = args
     before = runner.mesh_stats()["finalize"]
-    cols = runner._packed_columns(plan_of(specs), parts, LO, p8, layouts,
+    cols = runner._aggregator._packed_columns(plan_of(specs), parts, LO, p8, layouts,
                                   slots, base, capacity, slot_keys)
     assert_same_columns(cols, numpy_chain(*args))
     after = runner.mesh_stats()["finalize"]

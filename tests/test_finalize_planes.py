@@ -16,7 +16,9 @@ import pytest
 
 from tikv_tpu.datatype import Column, EvalType, FieldType
 from tikv_tpu.device import DeviceRunner
-from tikv_tpu.device import runner as runner_mod
+from tikv_tpu.device import aggregate as agg_mod
+from tikv_tpu.device.aggregate import DeviceAggregator
+from tikv_tpu.device.request import _Plan
 from tikv_tpu.executors.columnar import ColumnarTable
 from tikv_tpu.executors.runner import BatchExecutorsRunner, SelectResult
 from tikv_tpu.datatype.column import ColumnBatch
@@ -34,8 +36,9 @@ from tikv_tpu.testing.fixture import Table, TableColumn
 
 
 # --------------------------------------------------------------- the oracle
-# ops/agg.py's finalize_hash and runner.py's hash_result as they stood
-# before the planes (PR 25's tree), unchanged.
+# ops/agg.py's finalize_hash and the runner's hash_result (today
+# device/aggregate.py's) as they stood before the planes (PR 25's
+# tree), unchanged.
 
 def oracle_finalize_hash(specs, state: dict, base: int, capacity: int,
                          slot_keys=None):
@@ -113,11 +116,11 @@ def oracle_hash_result(specs, merged, base, capacity, slot_keys=None):
 # ------------------------------------------------------------ the new path
 
 def new_hash_result(specs, merged, base, capacity, slot_keys=None):
-    plan = runner_mod._Plan(scan=None, kind="hash_agg", used_cols=[],
+    plan = _Plan(scan=None, kind="hash_agg", used_cols=[],
                             specs=list(specs))
-    agg_out = DeviceRunner._agg_out(plan)
-    assert DeviceRunner._agg_out(plan) is agg_out    # once per plan
-    cols = runner_mod._hash_columns(agg_out, finalize_hash(
+    agg_out = DeviceAggregator._agg_out(plan)
+    assert DeviceAggregator._agg_out(plan) is agg_out    # once per plan
+    cols = agg_mod._hash_columns(agg_out, finalize_hash(
         specs, merged, base, capacity, slot_keys=slot_keys))
     return agg_out[0] + [FieldType.long()], cols
 
@@ -253,7 +256,7 @@ def test_planes_equal_the_list_oracle(kind, mode, shape):
 @pytest.mark.parametrize("domain", ["sparse_above", "sparse_below",
                                     "dense_straddle"])
 def test_unsigned_key_domain_keeps_the_oracles_container(domain):
-    """A uint64 key column (runner._sparse_slots keeps its dtype): the
+    """A uint64 key column (aggregate.py ``_sparse_slots`` keeps its dtype): the
     key plane is uint64 exactly where a present key is >= 2**63."""
     spec, merged = make_state("count", 7, null_group=True,
                               zero_group=False, empty=False)

@@ -177,8 +177,8 @@ def test_sums_served_in_bfloat16_are_caught(store, kind, params, keys,
                                             monkeypatch):
     """The control on the mesh: the runner's finalize hands on its
     sums rounded to bfloat16, the next precision down."""
-    from tikv_tpu.device import runner as runner_mod
-    sound = runner_mod._hash_columns
+    from tikv_tpu.device import aggregate as agg_mod
+    sound = agg_mod._hash_columns
     to_bf16 = byname.load("requests", "hash_agg").to_bf16
 
     def lower_precision(*args, **kw):
@@ -186,7 +186,7 @@ def test_sums_served_in_bfloat16_are_caught(store, kind, params, keys,
         cols[1] = Column(cols[1].eval_type, to_bf16(cols[1].values),
                          cols[1].validity)
         return cols
-    monkeypatch.setattr(runner_mod, "_hash_columns", lower_precision)
+    monkeypatch.setattr(agg_mod, "_hash_columns", lower_precision)
     ctx = store.ctxs[keys]
     records = [served(store, kind, params, keys) for _ in range(3)]
     checks = dict((n, v) for n, v, _lim in kind.check(

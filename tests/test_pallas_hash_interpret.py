@@ -42,7 +42,7 @@ def interpret(monkeypatch):
 
 def _runner(n_devices: int) -> DeviceRunner:
     r = DeviceRunner(mesh=make_mesh(jax.devices()[:n_devices]))
-    r._is_tpu = True            # lift the CPU gate in _try_pallas
+    r._is_tpu = True            # lift the CPU gate (aggregate.agg_bodies)
     r._block_local = BLOCK      # feeds pad to whole (patched) blocks
     return r
 

@@ -75,6 +75,7 @@ from ..expr import build_rpn
 from ..expr.eval import eval_rpn
 from ..utils.failpoint import fail_point
 from .kernels import named_program
+from .request import _remap_rpn, _rpn_col_indices, _rpn_device_safe
 
 _I64 = np.iinfo(np.int64)
 
@@ -113,7 +114,6 @@ def join_supported(probe_scan, probe_conds, left_key: int,
     executor checks this BEFORE recording co-location affinity, so
     join pairs that can never be device-served don't earn score-blind
     placement pins."""
-    from .runner import _rpn_device_safe
     from ..copr.dag import TableScanDesc
     for scan, key in ((probe_scan, left_key), (build_scan, right_key)):
         if not isinstance(scan, TableScanDesc) or scan.desc:
@@ -286,8 +286,8 @@ class DeviceJoiner:
             return jax.jit(named_program(fn, "join_build"))
         return self._kern(("join_build", n_pad), build)
 
-    def _probe_kernel(self, np_probe: int, np_build: int, k_cap: int,
-                      rpns, null_like_sig, n_params: int):
+    def _probe_side_kernel(self, np_probe: int, np_build: int,
+                           k_cap: int, rpns, null_like_sig, n_params: int):
         def build():
             def fn(n_scalar, sk, perm, prefix, pkeys, pvalid, *args):
                 params = args[:n_params]
@@ -394,7 +394,6 @@ class DeviceJoiner:
                 self.build_cache_hits += 1
         # ---- probe side: key + fused predicate planes ----
         rpns = [build_rpn(c) for c in probe_conds]
-        from .runner import _remap_rpn, _rpn_col_indices
         used = sorted({i for r in rpns
                        for i in _rpn_col_indices(r)})
         panchor, pver = self._anchor_version(probe_storage)
@@ -448,9 +447,9 @@ class DeviceJoiner:
         for attempt in range(3):
             kkey = ("join_probe", pent["n_pad"], ent["n_pad"], k_cap,
                     rpn_sig, len(param_vals))
-            kfn = self._probe_kernel(pent["n_pad"], ent["n_pad"], k_cap,
-                                     param_rpns, rpn_sig,
-                                     len(param_vals))
+            kfn = self._probe_side_kernel(
+                pent["n_pad"], ent["n_pad"], k_cap, param_rpns, rpn_sig,
+                len(param_vals))
             with tracker.phase("join_probe"):
                 with self._runner._dispatch_phase("join_probe",
                                                   key=kkey):

@@ -10,10 +10,9 @@ per-group COUNT/SUM via ``dot_general`` against a one-hot slot matrix:
 - integer values are **byte-split** into int8 planes (biased to [-128,127])
   so the whole aggregation is exact int8×int8→int32 MXU work, widened to
   int64 between blocks: sum(v) = Σ_k 2^(8k)·S_k + count·BIAS_OFFSET;
-- real values ride a separate f32 matmul, accumulated in f64 across blocks;
-- rows are processed in ``lax.scan`` blocks so the transient one-hot
-  (block × slots) stays small and int32 partials cannot overflow
-  (block ≤ 2^16 rows × |int8| ≤ 127 < 2^23).
+- real values ride a separate f32 matmul, accumulated in f64 across
+  blocks (the callers' ``lax.scan`` steps: int32 partials cannot
+  overflow while a block stays ≤ 2^23 rows).
 
 Plane layout: plane 0 is always the row mask (→ present + count_star);
 each aggregate appends its own validity plane and value planes.
@@ -31,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-BLOCK_ROWS = 1 << 16
-
 
 def named_program(fn, klass: str):
     """``fn`` under the name of its flight-recorder compile class, for
@@ -46,11 +43,6 @@ def named_program(fn, klass: str):
             return fn(*args, **kwargs)
     program.__name__ = program.__qualname__ = klass
     return program
-
-
-def slot_pad(slots: int) -> int:
-    """Round the one-hot width up to the MXU lane count."""
-    return ((slots + 127) // 128) * 128
 
 
 def int_planes_needed(vmin: int, vmax: int) -> int:
@@ -192,7 +184,7 @@ def twolevel_dims(slots: int, p8: int, pf: int) -> tuple:
 def twolevel_partial(idx, L8, Lf, LO: int, HI: int):
     """Factorized one-hot group-by over ONE row block: slot = hi·LO + lo.
 
-    The straight one-hot matmul (matmul_groupby) materializes an
+    A straight one-hot matmul (this module's first body) materializes an
     (block, slots) one-hot operand — both its VPU generation cost and its
     MXU contraction width scale with ``slots`` (≈1152 lanes for 1k
     groups). Factorizing the slot id as hi·LO+lo turns the aggregation
@@ -245,61 +237,6 @@ def twolevel_unpack(S2, n_planes: int, LO: int, slots: int, xp=jnp):
     S = xp.transpose(S2.reshape(HI, n_planes, LO), (1, 0, 2)) \
         .reshape(n_planes, HI * LO)
     return S[:, :slots]
-
-
-def matmul_groupby(idx, L8, Lf, slots: int, block: int = BLOCK_ROWS,
-                   vary_axes: tuple = ()):
-    """Blocked one-hot matmuls: → (S8: (P8, slots) int64,
-    Sf: (Pf, slots) float64 | None).
-
-    ``vary_axes``: when called inside shard_map, the mesh axis names — the
-    scan carry must be marked device-varying (``lax.pcast(...,
-    to="varying")``) to match the body output's varying-manual-axes
-    type."""
-    import math
-    G = slot_pad(slots)
-    n = idx.shape[0]
-    # the block length must divide n (lax.scan over equal blocks); chunk
-    # sizes are powers of two in practice, so this stays == BLOCK_ROWS
-    block = math.gcd(n, min(block, n))
-    nblk = n // block
-    p8 = L8.shape[0]
-    iota = jnp.arange(G, dtype=jnp.int32)
-
-    idx_b = idx.reshape(nblk, block)
-    l8_b = L8.reshape(p8, nblk, block).transpose(1, 0, 2)
-    if Lf is not None:
-        pf = Lf.shape[0]
-        lf_b = Lf.reshape(pf, nblk, block).transpose(1, 0, 2)
-        xs = (idx_b, l8_b, lf_b)
-        carry = (jnp.zeros((p8, G), jnp.int64),
-                 jnp.zeros((pf, G), jnp.float64))
-    else:
-        xs = (idx_b, l8_b)
-        carry = (jnp.zeros((p8, G), jnp.int64), None)
-    if vary_axes:
-        carry = tuple(None if c is None else lax.pcast(c, vary_axes, to="varying")
-                      for c in carry)
-
-    def body(carry, xs):
-        c8, cf = carry
-        if Lf is not None:
-            i_b, l8, lf = xs
-        else:
-            i_b, l8 = xs
-        onehot8 = (i_b[:, None] == iota[None, :]).astype(jnp.int8)
-        prod8 = lax.dot_general(l8, onehot8, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.int32)
-        c8 = c8 + prod8.astype(jnp.int64)
-        if Lf is not None:
-            onehotf = (i_b[:, None] == iota[None, :]).astype(jnp.float32)
-            prodf = lax.dot_general(lf, onehotf, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            cf = cf + prodf.astype(jnp.float64)
-        return (c8, cf), None
-
-    (S8, Sf), _ = lax.scan(body, carry, xs)
-    return S8[:, :slots], (None if Sf is None else Sf[:, :slots])
 
 
 def states_from_matmul(layouts, specs, S8, Sf, xp=jnp):

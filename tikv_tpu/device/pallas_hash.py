@@ -22,22 +22,21 @@ is the default body for every aggregation shape that qualifies):
   expression evaluates in-kernel and ``key - base`` indexes the grid
   directly (BASELINE config 4).
 - ``sparse`` — arbitrary int64 key domains: the host dictionary-encodes
-  the keys once per snapshot (runner._sparse_slots) and the dense slot
+  the keys once per snapshot (aggregate.py _sparse_slots) and the dense slot
   ids ride as ONE extra int32 input column, so the kernel never touches
   the (Mosaic-unsupported) int64 key values (config 4s).  Columns the
   kernel does not evaluate (the raw key) stay out of its input set, so
   their dtype/NULLability cannot disqualify the plan.
 - ``simple`` — no GROUP BY: a single-slot grid (every masked row aims at
-  slot 0), which turns SUM/COUNT/AVG over 50M rows into one fused
-  HBM pass (config 3).
+  slot 0), which turns SUM/COUNT/AVG into one fused HBM pass
+  (config 3).
 
-Design (r5 — all choices measured on v5e at 100M rows):
+Design (r5: each choice was swept on a v5e; the kernel's time today and
+its share of the HBM roofline are in PERF.md section 5, ledger-cited):
 
-- **The MXU contraction is the binding constraint, not HBM.**  Pure-dot
-  probes (operand generation stripped to ~2 VPU ops/cell) run
-  9.4-15 G rows/s depending on output shape; streaming reads alone hit
-  ~800 GB/s.  An exact scatter-by-matmul consumes one int8 K-element per
-  row, so kernel time ~= rows / dot-rate regardless of byte width.
+- **The MXU contraction is the binding constraint, not HBM.**  An exact
+  scatter-by-matmul consumes one int8 K-element per row, so kernel time
+  ~= rows / dot-rate regardless of byte width.
 - **Tight slot grid.**  Rows with no destination (row-mask off,
   predicate false, key out of range) point their one-hot column at a
   sentinel ``hi`` row that does not exist (``idx = HI*LO``): the column
@@ -159,7 +158,7 @@ def n_slots(plan, capacity: int, mode: str = MODE_DENSE) -> int:
     if mode == MODE_SIMPLE:
         return 1
     if mode == MODE_SPARSE:
-        # the slot encoding (runner._sparse_slots) reserves slot
+        # the slot encoding (aggregate.py _sparse_slots) reserves slot
         # ``capacity`` for NULL keys; whether a given snapshot has any
         # is data-dependent, so the slot is always materialized
         return capacity + 1
@@ -179,7 +178,8 @@ def supported(plan, feed, dtypes, pf: int, capacity: int,
     ``n_shards > 1``: the sharded mesh runs this same kernel PER SHARD
     under shard_map — each shard's grid covers its local feed slice,
     so the padded feed must split into whole BLOCKs per shard; the
-    per-shard packed partials psum on ICI (runner._try_pallas).
+    per-shard packed partials psum on ICI (aggregate.py
+    _pallas_sharded_wrap).
     """
     if pf != 0:
         return False

@@ -1,0 +1,247 @@
+"""What a device request is made of, for the runner and its operators.
+
+The runner (device/runner.py) analyses a DAG into a ``_Plan``, hands
+it to an operator module (device/aggregate.py, device/selection.py,
+device/join.py), and gets back a ``_Pending``: the launched program's
+output still on the device plus the host finalize for it.  Any of them
+raises ``_FallbackToHost`` when a runtime property (not the plan)
+forces the host path.  These types, the stager a ``_Pending`` lands
+its leaves through, and the three pure RPN helpers plan analysis
+shares with the join live here, below both sides: ``runner.py``
+imports its operators, and no operator module imports the runner.
+"""
+
+from __future__ import annotations
+
+import threading
+from dataclasses import dataclass, field
+from typing import Optional, Sequence
+
+import jax
+
+from ..copr.dag import TableScanDesc
+from ..datatype import EvalType
+from ..expr.rpn import RpnColumnRef, RpnConst, RpnExpression, RpnFnCall
+from .kernels import named_program
+
+
+class _FallbackToHost(Exception):
+    """Raised when a runtime property (not the plan) forces the host path."""
+
+
+#  DATETIME (packed u64 core — the bit layout is order-preserving) and
+#  DURATION (i64 ns) are device-native dense columns: comparisons, topN
+#  and min/max/count ride the same kernels as INT.  Years >= 8192 pack
+#  above 2^63 and would corrupt the int64 carries — the feed guard
+#  routes such columns to host.
+_DEVICE_ETS = (EvalType.INT, EvalType.REAL, EvalType.DATETIME,
+               EvalType.DURATION)
+
+
+def _rpn_col_indices(rpn: RpnExpression) -> set:
+    return {n.col_idx for n in rpn.nodes if isinstance(n, RpnColumnRef)}
+
+
+def _remap_rpn(rpn: RpnExpression, mapping: dict) -> RpnExpression:
+    nodes = []
+    for n in rpn.nodes:
+        if isinstance(n, RpnColumnRef):
+            nodes.append(RpnColumnRef(mapping[n.col_idx], n.eval_type))
+        else:
+            nodes.append(n)
+    return RpnExpression(tuple(nodes))
+
+
+def _rpn_device_safe(rpn: RpnExpression, scan_ets: Sequence[EvalType]) -> bool:
+    for n in rpn.nodes:
+        if isinstance(n, RpnConst):
+            if n.value is not None and not isinstance(n.value, (int, float, bool)):
+                return False
+        elif isinstance(n, RpnColumnRef):
+            if n.col_idx >= len(scan_ets) or scan_ets[n.col_idx] not in _DEVICE_ETS:
+                return False
+        elif isinstance(n, RpnFnCall):
+            if n.meta.ret not in _DEVICE_ETS:
+                return False
+            if not n.meta.device_safe:
+                # raw-numpy sig bodies (time extractors, string/json
+                # families) crash on jit tracers — only pure-xp sigs
+                # may enter a device plan; everything else runs host
+                return False
+    return True
+
+
+@dataclass
+class _Plan:
+    """Analyzed device plan (rpns remapped onto ``used_cols`` positions)."""
+
+    scan: TableScanDesc
+    kind: str                        # scan | simple_agg | hash_agg | topn
+    used_cols: list                  # original scan column offsets shipped to device
+    sel_rpns: list = field(default_factory=list)
+    specs: list = field(default_factory=list)        # AggSpec per agg
+    agg_rpns: list = field(default_factory=list)     # RpnExpression | None
+    key_rpn: Optional[RpnExpression] = None
+    order_rpn: Optional[RpnExpression] = None
+    order_desc: bool = False
+    limit: int = 0
+    # scan_sel only: every scan column rides the feed in a lossless
+    # device dtype, so the compact route may materialize the output on
+    # device (selection.py routing matrix)
+    compact_ok: bool = False
+    # lazy (param_rpns, values, dtypes) from selection.split_params
+    sel_params: Optional[tuple] = None
+    # lazy const-blind stat key (runner._sel_keys)
+    sel_stat_key: Optional[tuple] = None
+    # lazy (result FieldTypes, container dtypes) of ``specs``
+    # (aggregate.py DeviceAggregator._agg_out)
+    agg_out: Optional[tuple] = None
+
+
+class _PinnedStager:
+    """Pre-registered pinned-host D2H landing buffers.
+
+    On TPU the blocking half of a readback is ``np.asarray(x)``: the
+    runtime allocates fresh host memory and synchronously drains the
+    transfer into it, per request.  This stager instead appends a
+    jitted identity program with ``out_shardings`` pinned to the
+    device's ``pinned_host`` memory space to the DISPATCH stream: the
+    device→host copy executes asynchronously as part of the launch
+    train, lands in runtime-managed pinned (page-locked) host buffers,
+    and the later ``np.asarray`` at fetch time reads settled host
+    memory instead of paying the sync round trip.  One staging program
+    is compiled per (shape, dtype, device) — shapes are already
+    pow2/9-8-geometric capacity buckets (``_pad_rows``), so the
+    registration set is bounded exactly like the feed compile classes.
+
+    Probed once per shape class: a backend that cannot run the
+    placement program disables the stager and the readback path is
+    unchanged.  (The CPU backend of JAX 0.9.0 lists ``pinned_host`` and
+    ``unpinned_host`` on its devices but has no
+    ``annotate_device_placement`` implementation, so the probe fails
+    there: ``probed: true, enabled: false``.)  Sharded leaves pass
+    through.
+    """
+
+    _MAX_CLASSES = 256
+
+    def __init__(self, memory_kind: str = "pinned_host"):
+        # "pinned_host" on TPU; tests pass "unpinned_host" to drive the
+        # mechanics wherever the backend can run the placement program
+        self.memory_kind = memory_kind
+        self._mu = threading.Lock()
+        self._fns: dict = {}        # class key -> jitted fn | None
+        self.enabled: Optional[bool] = None     # None = unprobed
+        self.probe_error = ""       # why the probe disabled the stager
+        self.staged = 0
+        self.staged_bytes = 0
+        self.classes = 0
+
+    def _fn_for(self, x):
+        try:
+            sharding = x.sharding
+            devices = getattr(sharding, "_device_assignment", None) or \
+                tuple(sharding.device_set)
+            if len(devices) != 1:
+                return None         # sharded leaf: leave to GSPMD
+            dev = devices[0]
+            key = (x.shape, str(x.dtype), dev.id)
+        except Exception:   # noqa: BLE001 — not a jax array
+            return None
+        with self._mu:
+            if key in self._fns:
+                return self._fns[key]
+            if len(self._fns) >= self._MAX_CLASSES:
+                # registration full: pass the leaf through rather than
+                # compiling (and immediately forgetting) a staging
+                # program per request — the cap is a backstop far above
+                # the bucketed shape population, so hitting it means a
+                # shape explosion, not a workload to optimize
+                return None
+        fn = None
+        try:
+            from jax.sharding import SingleDeviceSharding
+            out = SingleDeviceSharding(dev, memory_kind=self.memory_kind)
+            fn = jax.jit(named_program(lambda a: a, "pinned_stage"),
+                         out_shardings=out)
+            fn(x)                   # probe: compiles + runs once
+            self.enabled = True
+        except Exception as e:  # noqa: BLE001 — placement unsupported
+            fn = None
+            if self.enabled is None:
+                self.enabled = False
+                self.probe_error = f"{type(e).__name__}: {e}"[:200]
+        with self._mu:
+            if len(self._fns) < self._MAX_CLASSES:
+                self._fns[key] = fn
+            if fn is not None:
+                self.classes += 1
+        return fn
+
+    def stage(self, tree):
+        """Stage every single-device leaf of ``tree`` to pinned host
+        memory; leaves that cannot stage pass through untouched."""
+        if self.enabled is False:
+            return tree
+
+        def one(x):
+            fn = self._fn_for(x)
+            if fn is None:
+                return x
+            try:
+                y = fn(x)
+            except Exception:   # noqa: BLE001 — degrade to direct D2H
+                return x
+            with self._mu:
+                self.staged += 1
+                self.staged_bytes += int(getattr(x, "nbytes", 0))
+            return y
+
+        return jax.tree.map(one, tree)
+
+    def stats(self) -> dict:
+        with self._mu:
+            return {"enabled": bool(self.enabled),
+                    "probed": self.enabled is not None,
+                    "probe_error": self.probe_error,
+                    "staged": self.staged,
+                    "staged_bytes": self.staged_bytes,
+                    "classes": self.classes}
+
+
+# process-wide: pinned host memory is a per-device runtime resource,
+# and the jit cache keys on the concrete device — safe to share across
+# runners (slice sub-runners included)
+HOST_STAGER = _PinnedStager()
+
+
+class _Pending:
+    """A dispatched device request: output pytree still on device plus
+    the host finalize that turns the fetched numpy tree into a
+    SelectResult (the ``host_materialize`` phase; for a hash
+    aggregation after a Pallas launch ``finalize_packed``: ONE native
+    call from the fetched accumulator parts to the result planes, GIL
+    held throughout, then ``_hash_columns``'s wrap; the numpy chain
+    where that call cannot serve, and ``finalize_hash`` for the XLA
+    bodies' states).  Leaves are staged to
+    pinned host memory at construction when the backend supports it
+    (:class:`_PinnedStager`)
+    and ``copy_to_host_async`` is issued for every leaf, so the D2H
+    transfer streams while the caller decides when (and on which
+    thread) to block — the seam the async serving path pipelines on.
+    ``small``: the fetch is KBs (agg states), so a completion pool may
+    prioritize it over bulk candidate readbacks.
+    """
+
+    __slots__ = ("tree", "finalize", "small")
+
+    def __init__(self, tree, finalize, small: bool = True):
+        tree = HOST_STAGER.stage(tree)
+        self.tree = tree
+        self.finalize = finalize
+        self.small = small
+        for x in jax.tree.leaves(tree):
+            try:
+                x.copy_to_host_async()
+            except Exception:   # pragma: no cover - CPU arrays
+                pass

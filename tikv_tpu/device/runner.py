@@ -15,18 +15,10 @@ computation per plan over the whole HBM-resident feed:
   aggregation carry on device (RpnExpression evaluation, the filter
   mask, and the aggregate kernels all trace into the same jit, so XLA
   fuses selection into the aggregation's HBM pass);
-- group-by COUNT/SUM runs on the MXU as a *factorized* one-hot matmul
-  (slot = hi·LO+lo, kernels.twolevel_partial) with exact int8 byte-split
-  arithmetic — ~8× the straight one-hot matmul, which itself is ~10×
-  XLA's scatter lowering on TPU;
-- cross-shard merging happens ONCE after the scan, as a partial-agg →
-  tree-reduce split on the interconnect (the TiDB partial-at-TiKV /
-  final-at-TiDB architecture mapped onto mesh axes): psum for the
-  count/sum/nonnull fields — TiKV's psum-mergeable partial aggregate
-  states, tidb_query_aggr — and an all-to-all by key bucket for the
-  order-sensitive hash-agg min/max slots (_finalize_hash_bucket_merge);
-  simple-agg min/max/first come back as a per-shard (S,) stack for a
-  scalar host reduce;
+- the aggregation itself (which kernel body serves a plan, the MXU
+  group-by, the cross-shard merge on the interconnect, the finalize) is
+  one operator module, device/aggregate.py, as selection, join and MVCC
+  resolution are (selection.py, join.py, mvcc.py);
 - the result returns in ONE packed uint8 buffer with the D2H transfer
   started asynchronously (r2's per-array readback paid 3+ blocking
   syncs per request; the co-located cost of one sync is ~1-2 ms —
@@ -39,7 +31,7 @@ first-class backend, not a degraded
 one: feeds upload row-sharded and delta-PATCH in place
 (GSPMD-partitioned dynamic_update_slice, _dus), the fused Pallas kernel
 runs as per-shard partial grids psum-merged on ICI
-(_pallas_sharded_wrap), selection mask/index routing is
+(aggregate.py _pallas_sharded_wrap), selection mask/index routing is
 shard-concatenable, and hot regions optionally pin to single-device
 slices via the placement loop (device/placement.py) so a
 many-small-regions mix scales OUT while a single big feed scales UP.
@@ -65,8 +57,7 @@ import os
 import threading
 import time
 from contextlib import contextmanager, nullcontext
-from dataclasses import dataclass, field
-from typing import Optional, Sequence
+from typing import Optional
 
 import numpy as np
 
@@ -80,28 +71,29 @@ from ..copr.dag import (
     AggregationDesc,
     DAGRequest,
     IndexScanDesc,
-    LimitDesc,
     SelectionDesc,
     TableScanDesc,
     TopNDesc,
 )
-from ..datatype import Column, ColumnBatch, EvalType, FieldType
+from ..datatype import Column, ColumnBatch, EvalType
 from ..datatype.tile import _device_dtype
 from ..expr import build_rpn
 from ..expr.eval import eval_rpn
-from ..expr.rpn import RpnColumnRef, RpnConst, RpnExpression, RpnFnCall
-from ..ops.agg import (
-    _I64_MAX,
-    AggSpec,
-    finalize_hash,
-    finalize_simple,
-    hash_agg_tile,
-    simple_agg_tile,
-)
+from ..expr.rpn import RpnColumnRef, RpnExpression
+from ..ops.agg import AggSpec
 from ..parallel import ROW_AXES, make_mesh, num_shards, row_sharding
+from .aggregate import DeviceAggregator
 from .kernels import named_program
-
-_BIG = np.iinfo(np.int64).max
+from .request import (
+    _DEVICE_ETS,
+    HOST_STAGER,
+    _FallbackToHost,
+    _Pending,
+    _Plan,
+    _remap_rpn,
+    _rpn_col_indices,
+    _rpn_device_safe,
+)
 
 # same-width unsigned views for bit-exact digest/corruption bitcasts
 _UINT_BY_ITEMSIZE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
@@ -110,12 +102,6 @@ _UINT_BY_ITEMSIZE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
 # scan-block granularity per kernel kind (rows per lax.scan step; the
 # feed pads to a multiple of _FEED_UNIT per shard so any of these divide)
 _FEED_BLOCK = 1 << 15
-_CHUNK_AGG = 1 << 20
-_CHUNK_TOPN = 1 << 23
-
-
-class _FallbackToHost(Exception):
-    """Raised when a runtime property (not the plan) forces the host path."""
 
 
 # persistent compile-cache traffic of THIS process, counted from JAX's
@@ -195,172 +181,13 @@ def _fp_degrade(name: str) -> None:
     from ..utils.failpoint import fail_point
     if fail_point(name) is not None:
         raise _FallbackToHost(name)
-#  DATETIME (packed u64 core — the bit layout is order-preserving) and
-#  DURATION (i64 ns) are device-native dense columns: comparisons, topN
-#  and min/max/count ride the same kernels as INT.  Years >= 8192 pack
-#  above 2^63 and would corrupt the int64 carries — the feed guard
-#  routes such columns to host.
-_DEVICE_ETS = (EvalType.INT, EvalType.REAL, EvalType.DATETIME,
-               EvalType.DURATION)
+
+
 _TIME_ETS = (EvalType.DATETIME, EvalType.DURATION)
 
 # TopN sort-key sentinels (float64 keys; any real data is far inside these)
-_EXCLUDED_ASC = 1e308
 _EXCLUDED_DESC = -1e308
 _NULL_KEY = -1e307          # MySQL: NULL sorts below every value
-
-
-def _next_pow2(n: int) -> int:
-    return 1 << max(0, (n - 1).bit_length())
-
-
-def _rpn_col_indices(rpn: RpnExpression) -> set:
-    return {n.col_idx for n in rpn.nodes if isinstance(n, RpnColumnRef)}
-
-
-def _remap_rpn(rpn: RpnExpression, mapping: dict) -> RpnExpression:
-    nodes = []
-    for n in rpn.nodes:
-        if isinstance(n, RpnColumnRef):
-            nodes.append(RpnColumnRef(mapping[n.col_idx], n.eval_type))
-        else:
-            nodes.append(n)
-    return RpnExpression(tuple(nodes))
-
-
-def _rpn_device_safe(rpn: RpnExpression, scan_ets: Sequence[EvalType]) -> bool:
-    for n in rpn.nodes:
-        if isinstance(n, RpnConst):
-            if n.value is not None and not isinstance(n.value, (int, float, bool)):
-                return False
-        elif isinstance(n, RpnColumnRef):
-            if n.col_idx >= len(scan_ets) or scan_ets[n.col_idx] not in _DEVICE_ETS:
-                return False
-        elif isinstance(n, RpnFnCall):
-            if n.meta.ret not in _DEVICE_ETS:
-                return False
-            if not n.meta.device_safe:
-                # raw-numpy sig bodies (time extractors, string/json
-                # families) crash on jit tracers — only pure-xp sigs
-                # may enter a device plan; everything else runs host
-                return False
-    return True
-
-
-@dataclass
-class _Plan:
-    """Analyzed device plan (rpns remapped onto ``used_cols`` positions)."""
-
-    scan: TableScanDesc
-    kind: str                        # scan | simple_agg | hash_agg | topn
-    used_cols: list                  # original scan column offsets shipped to device
-    sel_rpns: list = field(default_factory=list)
-    specs: list = field(default_factory=list)        # AggSpec per agg
-    agg_rpns: list = field(default_factory=list)     # RpnExpression | None
-    key_rpn: Optional[RpnExpression] = None
-    order_rpn: Optional[RpnExpression] = None
-    order_desc: bool = False
-    limit: int = 0
-    # scan_sel only: every scan column rides the feed in a lossless
-    # device dtype, so the compact route may materialize the output on
-    # device (selection.py routing matrix)
-    compact_ok: bool = False
-    # lazy (param_rpns, values, dtypes) from selection.split_params
-    sel_params: Optional[tuple] = None
-    # lazy const-blind stat key (runner._sel_keys)
-    sel_stat_key: Optional[tuple] = None
-    # lazy (result FieldTypes, container dtypes) of ``specs``
-    # (runner._agg_out)
-    agg_out: Optional[tuple] = None
-
-
-def _sum_parts(parts):
-    """Merge per-tile packed partials (psum-partial semantics)."""
-    packed = np.asarray(parts[0])
-    for p in parts[1:]:
-        packed = packed + np.asarray(p)
-    return packed
-
-
-def _hash_columns(agg_out, finalized):
-    """Finalized hash-agg planes → result Columns (aggregates, then the
-    key): the ONE place where planes become Columns for every hash body,
-    whether ``ops.agg.finalize_hash`` or the native call of
-    ``finalize_packed`` made them.  No Python value is made per group
-    between the fetched accumulator and the wire encoder.  ``agg_out``:
-    ``DeviceRunner._agg_out`` of the plan; ``finalized``:
-    ``finalize_hash``'s ``((keys, key_valid), planes)``."""
-    (keys, key_valid), planes = finalized
-    cols = [Column(ft.eval_type, vals.astype(dt, copy=False), ok)
-            for ft, dt, (vals, ok) in zip(*agg_out, planes)]
-    cols.append(Column(EvalType.INT, keys, key_valid))
-    return cols
-
-
-# kernels.PlaneLayout kinds the native finalize serves, by the code
-# native/fastbuild.cpp knows them by (``FinKind``)
-_NATIVE_FINALIZE_KINDS = {"count_star": 0, "count": 1, "sum": 2, "avg": 3}
-
-
-def _native_layout_desc(layouts):
-    """``layouts`` flattened for ``native.hash_finalize_packed`` — per
-    spec: kind code, ``ok_plane``, ``nb``, then the ``nb`` byte-plane
-    indices — or None where one is outside what that call serves (a
-    float plane, a kind outside the four)."""
-    flat = []
-    for lay in layouts:
-        code = _NATIVE_FINALIZE_KINDS.get(lay.kind)
-        if code is None or lay.f32_plane is not None:
-            return None
-        flat += (code, lay.ok_plane or 0, lay.nb, *lay.byte_planes)
-    return np.array(flat, np.int64)
-
-
-def finalize_packed(parts, LO, p8, layouts, specs, slots, base, capacity,
-                    slot_keys):
-    """The fetched Pallas hash accumulator → ``finalize_hash``'s planes.
-
-    ``parts``: one (2, HI, p8·LO) int32 pair per tile (one on a
-    whole-feed launch and on a mesh); they add.  Returns
-    ``(((keys, key_valid), planes), native)``.
-
-    Where the extension built and the input is what the Pallas hash
-    path produces — int32 parts, integer layouts of COUNT / SUM / AVG,
-    a key domain inside int64 — this is ONE call into
-    ``native.hash_finalize_packed``, which holds the GIL from entry to
-    return: the numpy chain below makes ~24 array calls on planes of
-    1k-4k elements, numpy drops the GIL around each, and on a serving
-    store every drop queues behind ~10 runnable threads (PERF.md
-    section 6, PRs 26 and 28).  The planes are views of buffers sized
-    ``capacity + 1`` (``np.empty`` and a slice drop no GIL).  What it
-    adapts to is in its input: anything else takes the numpy chain
-    (``_sum_parts`` → ``_pallas_states`` → ``finalize_hash``), the same
-    bytes, kept as the fallback and as the oracle of
-    tests/test_finalize_native.py.  ``native`` says which ran; the
-    caller counts it (``/health`` ``device_mesh.finalize``).
-    """
-    call = native.hash_finalize_packed
-    keys_fit_int64 = slot_keys.dtype == np.int64 if slot_keys is not None \
-        else base + capacity <= _I64_MAX
-    desc = None
-    if call is not None and keys_fit_int64 and all(
-            p.dtype == np.int32 and p.flags.c_contiguous for p in parts):
-        desc = _native_layout_desc(layouts)
-    if desc is not None:
-        n = capacity + 1                # + the NULL slot
-        keys = np.empty(n, np.int64)
-        key_valid = np.empty(n, np.bool_)
-        outs = [(np.empty(n, np.float64 if lay.kind == "avg" else np.int64),
-                 np.empty(n, np.bool_)) for lay in layouts]
-        k = call(parts, LO, p8, capacity, 0 if slot_keys is not None
-                 else base, slot_keys, desc, keys, key_valid, outs)
-        return ((keys[:k], key_valid[:k]),
-                [(vals[:k], ok[:k]) for vals, ok in outs]), True
-    present, states = DeviceRunner._pallas_states(
-        _sum_parts(parts), LO, p8, layouts, specs, slots)
-    return finalize_hash(
-        specs, {"present": present, "overflow": False, "states": states},
-        base, capacity, slot_keys=slot_keys), False
 
 
 class _GuardedMeta:
@@ -407,155 +234,6 @@ class _GuardedMeta:
             return got
         self[k] = v
         return v
-
-
-class _PinnedStager:
-    """Pre-registered pinned-host D2H landing buffers.
-
-    On TPU the blocking half of a readback is ``np.asarray(x)``: the
-    runtime allocates fresh host memory and synchronously drains the
-    transfer into it, per request.  This stager instead appends a
-    jitted identity program with ``out_shardings`` pinned to the
-    device's ``pinned_host`` memory space to the DISPATCH stream: the
-    device→host copy executes asynchronously as part of the launch
-    train, lands in runtime-managed pinned (page-locked) host buffers,
-    and the later ``np.asarray`` at fetch time reads settled host
-    memory instead of paying the sync round trip.  One staging program
-    is compiled per (shape, dtype, device) — shapes are already
-    pow2/9-8-geometric capacity buckets (``_pad_rows``), so the
-    registration set is bounded exactly like the feed compile classes.
-
-    Probed once per shape class: a backend that cannot run the
-    placement program disables the stager and the readback path is
-    unchanged.  (The CPU backend of JAX 0.9.0 lists ``pinned_host`` and
-    ``unpinned_host`` on its devices but has no
-    ``annotate_device_placement`` implementation, so the probe fails
-    there: ``probed: true, enabled: false``.)  Sharded leaves pass
-    through.
-    """
-
-    _MAX_CLASSES = 256
-
-    def __init__(self, memory_kind: str = "pinned_host"):
-        # "pinned_host" on TPU; tests pass "unpinned_host" to drive the
-        # mechanics wherever the backend can run the placement program
-        self.memory_kind = memory_kind
-        self._mu = threading.Lock()
-        self._fns: dict = {}        # class key -> jitted fn | None
-        self.enabled: Optional[bool] = None     # None = unprobed
-        self.probe_error = ""       # why the probe disabled the stager
-        self.staged = 0
-        self.staged_bytes = 0
-        self.classes = 0
-
-    def _fn_for(self, x):
-        try:
-            sharding = x.sharding
-            devices = getattr(sharding, "_device_assignment", None) or \
-                tuple(sharding.device_set)
-            if len(devices) != 1:
-                return None         # sharded leaf: leave to GSPMD
-            dev = devices[0]
-            key = (x.shape, str(x.dtype), dev.id)
-        except Exception:   # noqa: BLE001 — not a jax array
-            return None
-        with self._mu:
-            if key in self._fns:
-                return self._fns[key]
-            if len(self._fns) >= self._MAX_CLASSES:
-                # registration full: pass the leaf through rather than
-                # compiling (and immediately forgetting) a staging
-                # program per request — the cap is a backstop far above
-                # the bucketed shape population, so hitting it means a
-                # shape explosion, not a workload to optimize
-                return None
-        fn = None
-        try:
-            from jax.sharding import SingleDeviceSharding
-            out = SingleDeviceSharding(dev, memory_kind=self.memory_kind)
-            fn = jax.jit(named_program(lambda a: a, "pinned_stage"),
-                         out_shardings=out)
-            fn(x)                   # probe: compiles + runs once
-            self.enabled = True
-        except Exception as e:  # noqa: BLE001 — placement unsupported
-            fn = None
-            if self.enabled is None:
-                self.enabled = False
-                self.probe_error = f"{type(e).__name__}: {e}"[:200]
-        with self._mu:
-            if len(self._fns) < self._MAX_CLASSES:
-                self._fns[key] = fn
-            if fn is not None:
-                self.classes += 1
-        return fn
-
-    def stage(self, tree):
-        """Stage every single-device leaf of ``tree`` to pinned host
-        memory; leaves that cannot stage pass through untouched."""
-        if self.enabled is False:
-            return tree
-
-        def one(x):
-            fn = self._fn_for(x)
-            if fn is None:
-                return x
-            try:
-                y = fn(x)
-            except Exception:   # noqa: BLE001 — degrade to direct D2H
-                return x
-            with self._mu:
-                self.staged += 1
-                self.staged_bytes += int(getattr(x, "nbytes", 0))
-            return y
-
-        return jax.tree.map(one, tree)
-
-    def stats(self) -> dict:
-        with self._mu:
-            return {"enabled": bool(self.enabled),
-                    "probed": self.enabled is not None,
-                    "probe_error": self.probe_error,
-                    "staged": self.staged,
-                    "staged_bytes": self.staged_bytes,
-                    "classes": self.classes}
-
-
-# process-wide: pinned host memory is a per-device runtime resource,
-# and the jit cache keys on the concrete device — safe to share across
-# runners (slice sub-runners included)
-HOST_STAGER = _PinnedStager()
-
-
-class _Pending:
-    """A dispatched device request: output pytree still on device plus
-    the host finalize that turns the fetched numpy tree into a
-    SelectResult (the ``host_materialize`` phase; for a hash
-    aggregation after a Pallas launch ``finalize_packed``: ONE native
-    call from the fetched accumulator parts to the result planes, GIL
-    held throughout, then ``_hash_columns``'s wrap; the numpy chain
-    where that call cannot serve, and ``finalize_hash`` for the XLA
-    bodies' states).  Leaves are staged to
-    pinned host memory at construction when the backend supports it
-    (:class:`_PinnedStager`)
-    and ``copy_to_host_async`` is issued for every leaf, so the D2H
-    transfer streams while the caller decides when (and on which
-    thread) to block — the seam the async serving path pipelines on.
-    ``small``: the fetch is KBs (agg states), so a completion pool may
-    prioritize it over bulk candidate readbacks.
-    """
-
-    __slots__ = ("tree", "finalize", "small")
-
-    def __init__(self, tree, finalize, small: bool = True):
-        tree = HOST_STAGER.stage(tree)
-        self.tree = tree
-        self.finalize = finalize
-        self.small = small
-        for x in jax.tree.leaves(tree):
-            try:
-                x.copy_to_host_async()
-            except Exception:   # pragma: no cover - CPU arrays
-                pass
 
 
 class DeferredResult:
@@ -881,6 +559,9 @@ class DeviceRunner:
         self._plan_cache: dict = {}
         self._plan_cache_max = 4096
         self._kernel_cache: dict = {}
+        # the aggregation operator (device/aggregate.py), over this
+        # runner's feeds, caches and dispatch span
+        self._aggregator = DeviceAggregator(self)
         # dispatch serialization: two threads launching multi-device
         # executables concurrently can interleave their per-device
         # enqueues and deadlock the mesh (launch-order inversion), and
@@ -900,9 +581,6 @@ class DeviceRunner:
         self._sel_mu = threading.Lock()
         self._sel_stats: "OrderedDict" = OrderedDict()
         self._sel_route_counts: dict = {}
-        # single-slot probe seam (probe_scan_kernel): last selection
-        # dispatch's (plan_key, kernel key, params, n)
-        self._selmask_last: Optional[tuple] = None
         # HBM-resident feed cache — the TPU-native analog of TiKV's
         # in-memory region cache engine (components/
         # region_cache_memory_engine: RangeCacheMemoryEngine layered over
@@ -1727,8 +1405,8 @@ class DeviceRunner:
         # logarithmically and taxes ONLY the cache key, never the
         # computed extent: blocks past the live rows skip their MXU /
         # aggregation work (pl.when dead-block guard in pallas_hash,
-        # lax.cond guard in _mega's scan step), so the ≤12.5% padding
-        # costs DMA + grid steps, not kernel time.
+        # lax.cond guard in aggregate.py _scan_program's step), so the
+        # ≤12.5% padding costs DMA + grid steps, not kernel time.
         if not self._chunk_override and blocks > 8:
             # one ROW of growth headroom BEFORE bucketing: it only
             # moves sizes whose live rows exactly fill their last block
@@ -2576,17 +2254,6 @@ class DeviceRunner:
                str(dtype))
         return self._scalar_cache_get(key, v, dtype)
 
-    def _cached_carry(self, cache_key, build):
-        """Device-resident initial carry, uploaded once per kernel key.
-        Kernels never donate their inputs, so the same zero/identity
-        buffers are safe to reuse across requests."""
-        key = ("carry0",) + cache_key
-        carry = self._kernel_cache.get(key)
-        if carry is None:
-            carry = self._put_carry(build())
-            self._kernel_cache[key] = carry
-        return carry
-
     def _eval_masked(self, plan: _Plan, pairs, n_local, row_mask):
         mask = row_mask
         for rpn in plan.sel_rpns:
@@ -2603,410 +2270,6 @@ class DeviceRunner:
 
     def _psum(self, x):
         return x if self._single else lax.psum(x, ROW_AXES)
-
-    # -- cross-shard merges --
-    #
-    # Only Sum all-reduces are emitted (no pmin/pmax): the dominant
-    # state fields (count/sum/nonnull — every config in BASELINE.md)
-    # merge with one post-scan psum on ICI, while order-sensitive
-    # fields (min/max/first-pos) come back per-shard — a
-    # (n_shards, slots) stack, KBs — and reduce on host (simple agg) or
-    # through the all-to-all bucket merge (hash agg).
-
-    @staticmethod
-    def _merge_stacked(specs, summed_states, stacked_states) -> list:
-        """Host-side: reduce the per-shard stacks into one state per spec."""
-        out = []
-        for spec, sm, st in zip(specs, summed_states, stacked_states):
-            d = {k: np.asarray(v) for k, v in sm.items()}
-            if spec.kind == "min":
-                d["min"] = np.min(np.asarray(st["min"]), axis=0)
-            elif spec.kind == "max":
-                d["max"] = np.max(np.asarray(st["max"]), axis=0)
-            elif spec.kind == "first":
-                pos = np.asarray(st["pos"])
-                if "value" in st:       # simple agg: scalar per shard
-                    i = int(np.argmin(pos))
-                    d["pos"] = pos[i]
-                    d["value"] = np.asarray(st["value"])[i]
-                else:                   # hash agg: (n_shards, slots)
-                    d["pos"] = np.min(pos, axis=0)
-            out.append(d)
-        return out
-
-    def _canon_state(self, s: dict) -> dict:
-        """Cast state leaves to carry dtypes (int64 / float64)."""
-        return {k: (v.astype(jnp.float64) if v.dtype.kind == "f"
-                    else v.astype(jnp.int64)) for k, v in s.items()}
-
-    @staticmethod
-    def _merge_summed(carry: dict, new: dict) -> dict:
-        return {k: carry[k] + new[k] for k in carry}
-
-    @staticmethod
-    def _merge_stacked_dict(carry: dict, new: dict) -> dict:
-        d = {}
-        if "pos" in carry and "value" in carry:     # FIRST (simple agg)
-            take_new = new["pos"] < carry["pos"]
-            d["pos"] = jnp.where(take_new, new["pos"], carry["pos"])
-            d["value"] = jnp.where(take_new, new["value"], carry["value"])
-            return d
-        for k in carry:
-            if k == "min" or k == "pos":
-                d[k] = jnp.minimum(carry[k], new[k])
-            elif k == "max":
-                d[k] = jnp.maximum(carry[k], new[k])
-            else:   # pragma: no cover
-                raise ValueError(k)
-        return d
-
-    def _split_new_state(self, s: dict):
-        """→ (summed fields, per-shard stacked fields shaped [1, ...])."""
-        summed, stacked = {}, {}
-        for k, v in s.items():
-            if k in ("count", "sum", "nonnull", "sumsq"):
-                summed[k] = v
-            else:
-                stacked[k] = v[None] if getattr(v, "ndim", 0) else \
-                    jnp.reshape(v, (1,))
-        return summed, stacked
-
-    def _carry_specs(self, carry):
-        """shard_map in/out specs matching a carry pytree: stacked leaves
-        (leading shard axis) are P(ROW_AXES); everything else replicated."""
-        summedlike, stackedlike = carry
-        return (jax.tree.map(lambda _: P(), summedlike),
-                jax.tree.map(lambda _: P(ROW_AXES), stackedlike))
-
-    # -- the single-dispatch scan wrapper --
-    #
-    # Every request is ONE jit call: body(carry, aux, base, *cols, row_mask)
-    # folds one scan block; lax.scan drives it across the whole feed; the
-    # finalize hook (cross-shard psum of the summed subtree) runs once
-    # after the scan.  r2 dispatched one jit per 2^23-row chunk — enqueues
-    # are cheap but the per-chunk carries defeated XLA's scheduling and
-    # every chunk paid its own blocking sync.
-
-    def _mega(self, body, finalize, null_flags, n_pad: int, chunk: int,
-              emits: bool = False):
-        S = self._nshards()
-        n_local_total = n_pad // S
-        chunk_local = chunk // S
-        nblk = n_pad // chunk
-
-        def local_fn(carry, n_scalar, aux, *flat):
-            if not self._single:
-                # the replicated summed subtree becomes device-varying as
-                # soon as local rows fold in; the scan carry type must be
-                # varying from step 0
-                summed0, stacked0 = carry
-                carry = (jax.tree.map(
-                    lambda x: lax.pcast(x, ROW_AXES, to="varying"),
-                    summed0), stacked0)
-            base0 = self._shard_index() * n_local_total
-            xs = tuple(a.reshape(nblk, chunk_local) for a in flat)
-            steps = jnp.arange(nblk, dtype=jnp.int64)
-            # the ragged-tail mask comes from an iota compare (int32 when
-            # rows fit — int64 is pair-emulated on TPU), so it costs no
-            # HBM reads
-            idt = jnp.int32 if n_pad <= np.iinfo(np.int32).max else jnp.int64
-            iota = jnp.arange(chunk_local, dtype=idt)
-
-            def step(c, x):
-                s_i = x[0]
-                cols = x[1:]
-                base = base0 + s_i * chunk_local
-
-                def live(c):
-                    row_mask = (base.astype(idt) + iota) < \
-                        n_scalar.astype(idt)
-                    args = []
-                    fi = 0
-                    for has_nulls in null_flags:
-                        v = cols[fi]
-                        fi += 1
-                        if has_nulls:
-                            m = cols[fi]
-                            fi += 1
-                        else:
-                            m = row_mask
-                        args.append(v)
-                        args.append(m)
-                    out = body(c, aux, base, *args, row_mask)
-                    if emits:
-                        return out
-                    return out, None
-
-                def dead(c):
-                    # block entirely past the live rows (bucketed feed
-                    # padding): an all-masked body invocation is a
-                    # carry no-op by construction, so skip its HBM pass
-                    ys = jnp.zeros((chunk_local,), jnp.bool_) \
-                        if emits else None
-                    return c, ys
-
-                return lax.cond(base < n_scalar, live, dead, c)
-
-            carry, ys = lax.scan(step, carry, (steps,) + xs)
-            carry = finalize(carry)
-            return (carry, ys) if emits else carry
-
-        return local_fn
-
-    def _wrap_mega(self, klass: str, local_fn, carry_example,
-                   n_flat: int, ys_specs=None):
-        local_fn = named_program(local_fn, klass)
-        if self._single:
-            return jax.jit(local_fn)
-        cs = self._carry_specs(carry_example)
-        out_specs = (cs, ys_specs) if ys_specs is not None else cs
-        return jax.jit(jax.shard_map(
-            local_fn, mesh=self._mesh,
-            in_specs=(cs, P(), P()) + (P(ROW_AXES),) * n_flat,
-            out_specs=out_specs))
-
-    # -- carry initialization (host → device once per request) --
-
-    def _put_carry(self, carry):
-        """Place a (summed, stacked) carry pytree built from numpy."""
-        if self._single:
-            return jax.tree.map(jnp.asarray, carry)
-        summed, stacked = carry
-        repl = self._repl
-        rows = self._row_sharding
-        return (jax.tree.map(lambda x: jax.device_put(x, repl), summed),
-                jax.tree.map(lambda x: jax.device_put(x, rows), stacked))
-
-    def _init_agg_carry(self, plan: _Plan, slots: Optional[int],
-                        stacked_slots: Optional[int] = None):
-        """Zero/identity states for the scatter-path carries.
-
-        ``slots=None`` → simple agg (scalar states); else hash agg
-        arrays.  ``stacked_slots`` widens only the per-shard stacked
-        leaves (min/max/first) — the sharded tree-reduce pads their
-        slot axis to a multiple of the shard count so the all-to-all
-        bucket exchange splits it evenly.
-        """
-        S = self._nshards()
-        shape = () if slots is None else (slots,)
-        sshape = (S,) if slots is None else \
-            (S, slots if stacked_slots is None else stacked_slots)
-        summed, stacked = [], []
-        for spec, rpn in zip(plan.specs, plan.agg_rpns):
-            is_real = rpn is not None and rpn.ret_type is EvalType.REAL
-            sm, st = {}, {}
-            if spec.kind in ("count", "count_star"):
-                sm["count"] = np.zeros(shape, np.int64)
-            elif spec.kind == "sum":
-                sm["sum"] = np.zeros(shape, np.float64 if is_real else np.int64)
-                sm["nonnull"] = np.zeros(shape, np.int64)
-            elif spec.kind == "avg":
-                sm["sum"] = np.zeros(shape, np.float64 if is_real else np.int64)
-                sm["count"] = np.zeros(shape, np.int64)
-            elif spec.kind in ("min", "max"):
-                ident = (np.inf if spec.kind == "min" else -np.inf) \
-                    if is_real else \
-                    (np.iinfo(np.int64).max if spec.kind == "min"
-                     else np.iinfo(np.int64).min)
-                st[spec.kind] = np.full(
-                    sshape, ident, np.float64 if is_real else np.int64)
-                sm["nonnull"] = np.zeros(shape, np.int64)
-            elif spec.kind == "first":
-                st["pos"] = np.full(sshape, _BIG, np.int64)
-                st["value"] = np.zeros(
-                    sshape, np.float64 if is_real else np.int64)
-            elif spec.kind in ("var_pop", "var_samp", "stddev_pop",
-                               "stddev_samp"):
-                sm["sum"] = np.zeros(shape, np.float64)
-                sm["sumsq"] = np.zeros(shape, np.float64)
-                sm["count"] = np.zeros(shape, np.int64)
-            summed.append(sm)
-            stacked.append(st)
-        return summed, stacked
-
-    def _finalize_psum_summed(self):
-        """Post-scan cross-shard merge: psum every summed leaf."""
-        def fin(carry):
-            summed, stacked = carry
-            return jax.tree.map(self._psum, summed), stacked
-        return fin
-
-    def _finalize_hash_bucket_merge(self):
-        """Sharded hash-agg tree-reduce, entirely on the interconnect:
-        psum the mergeable (count/sum/nonnull/present) fields, and
-        merge the order-sensitive stacked fields (min/max) with an
-        ALL-TO-ALL BY KEY BUCKET — each shard sends bucket ``j`` of
-        its local (1, slots_m) partial to shard ``j``, reduces the
-        (S, slots_m/S) pile it receives, and returns its merged bucket.
-        This is the TiDB partial-at-TiKV / final-at-TiDB split mapped
-        onto mesh axes: the runtime here lowers only Sum all-reduce
-        (no pmin/pmax), but an all-to-all is a pure permutation, so
-        the min/max merge that used to ship a (S, slots) stack over
-        D2H for a host reduce now crosses ICI once and ships (slots,)."""
-        def fin(carry):
-            summed, stacked = carry
-            summed = jax.tree.map(self._psum, summed)
-            out_st = []
-            for st in stacked:
-                d = {}
-                for k, v in st.items():
-                    b = lax.all_to_all(v, ROW_AXES, split_axis=1,
-                                       concat_axis=0, tiled=True)
-                    red = jnp.max if k == "max" else jnp.min
-                    d[k] = red(b, axis=0, keepdims=True)
-                out_st.append(d)
-            return summed, out_st
-        return fin
-
-    @staticmethod
-    def _pad_stacked(st: dict, pad: int) -> dict:
-        """Pad a new stacked state's slot axis with the merge identity
-        (min/pos → +big, max → -big) so it folds into the widened
-        sharded carry without perturbing any real slot."""
-        if not pad:
-            return st
-        out = {}
-        for k, v in st.items():
-            if v.dtype.kind == "f":
-                fill = -jnp.inf if k == "max" else jnp.inf
-            else:
-                fill = np.iinfo(np.int64).min if k == "max" \
-                    else np.iinfo(np.int64).max
-            out[k] = jnp.pad(v, ((0, 0), (0, pad)),
-                             constant_values=fill)
-        return out
-
-    @staticmethod
-    def _merge_bucketed(specs, summed_states, stacked_states,
-                        slots: int) -> list:
-        """Host-side unpack after the device bucket merge: the fetched
-        stacked leaves are (S, slots_m/S) — shard j's row IS bucket j,
-        already cross-shard reduced — so the merged per-slot vector is
-        just the row-major flatten, trimmed of the all-to-all pad."""
-        out = []
-        for spec, sm, st in zip(specs, summed_states, stacked_states):
-            d = {k: np.asarray(v) for k, v in sm.items()}
-            for k, v in st.items():
-                d[k] = np.asarray(v).reshape(-1)[:slots]
-            out.append(d)
-        return out
-
-    # -- kernel bodies --
-
-    def _build_simple_body(self, plan: _Plan, n_cols: int):
-        specs = plan.specs
-
-        def body(carry, aux, base, *flat):
-            summed_c, stacked_c = carry
-            row_mask = flat[-1]
-            pairs = [(flat[2 * i], flat[2 * i + 1]) for i in range(n_cols)]
-            n_local = row_mask.shape[0]
-            mask = self._eval_masked(plan, pairs, n_local, row_mask)
-            cols = []
-            for r in plan.agg_rpns:
-                if r is None:
-                    cols.append((jnp.zeros((n_local,), jnp.int32), mask))
-                else:
-                    v, ok = eval_rpn(r, pairs, n_local, jnp)
-                    cols.append((v, ok & mask))
-            n_valid = jnp.sum(mask, dtype="int64")
-            states = simple_agg_tile(jnp, specs, cols, n_valid_rows=n_valid)
-            out_sm, out_st = [], []
-            for spec, s, cs, cst in zip(specs, states, summed_c, stacked_c):
-                s = self._canon_state(s)
-                if spec.kind == "first":
-                    # globalize positions; host picks the cross-shard argmin
-                    s["pos"] = jnp.where(s["pos"] == _BIG, _BIG,
-                                         s["pos"] + base)
-                sm, st = self._split_new_state(s)
-                out_sm.append(self._merge_summed(cs, sm))
-                out_st.append(self._merge_stacked_dict(cst, st)
-                              if st else cst)
-            return out_sm, out_st
-
-        return body
-
-    def _build_hash_scatter_body(self, plan: _Plan, n_cols: int,
-                                 capacity: int, sparse: bool = False,
-                                 stack_pad: int = 0):
-        specs = plan.specs
-        n_pairs = n_cols + (1 if sparse else 0)
-
-        def body(carry, aux, base, *flat):
-            (summed_c, present_c, overflow_c), stacked_c = carry
-            row_mask = flat[-1]
-            pairs = [(flat[2 * i], flat[2 * i + 1])
-                     for i in range(n_pairs)]
-            n_local = row_mask.shape[0]
-            mask = self._eval_masked(plan, pairs, n_local, row_mask)
-            cols = []
-            for r in plan.agg_rpns:
-                if r is None:
-                    cols.append((jnp.zeros((n_local,), jnp.int32), mask))
-                else:
-                    cols.append(eval_rpn(r, pairs, n_local, jnp))
-            if sparse:
-                # precomputed slot ids ride as the trailing column
-                key_pair = (jnp.zeros((n_local,), jnp.int32), mask)
-                tile_base = ("precomp", pairs[n_cols][0])
-            else:
-                key_pair = eval_rpn(plan.key_rpn, pairs, n_local, jnp)
-                tile_base = aux
-            st = hash_agg_tile(jnp, specs, key_pair, cols, capacity,
-                               tile_base, row_mask=mask)
-            present = present_c + st["present"].astype(jnp.int64)
-            overflow = overflow_c + st["overflow"].astype(jnp.int64)
-            out_sm, out_st = [], []
-            for spec, s, cs, cst in zip(specs, st["states"], summed_c,
-                                        stacked_c):
-                sm, stk = self._split_new_state(self._canon_state(s))
-                stk = self._pad_stacked(stk, stack_pad)
-                out_sm.append(self._merge_summed(cs, sm))
-                out_st.append(self._merge_stacked_dict(cst, stk)
-                              if stk else cst)
-            return (out_sm, present, overflow), out_st
-
-        return body
-
-    def _build_hash_twolevel_body(self, plan: _Plan, n_cols: int,
-                                  capacity: int, layouts, LO: int, HI: int,
-                                  pf: int, sparse: bool = False):
-        from .kernels import make_planes, slot_index, twolevel_partial
-        specs = plan.specs
-        n_pairs = n_cols + (1 if sparse else 0)
-
-        def body(carry, aux, base, *flat):
-            (S8_c, Sf_c, ovf_c), _unused = carry
-            row_mask = flat[-1]
-            pairs = [(flat[2 * i], flat[2 * i + 1])
-                     for i in range(n_pairs)]
-            n_local = row_mask.shape[0]
-            mask = self._eval_masked(plan, pairs, n_local, row_mask)
-            cols = []
-            for r in plan.agg_rpns:
-                if r is None:
-                    cols.append((jnp.zeros((n_local,), jnp.int32), mask))
-                else:
-                    cols.append(eval_rpn(r, pairs, n_local, jnp))
-            if sparse:
-                # precomputed slot ids (trailing column); only the
-                # request's selection/row mask is applied here
-                scrap = capacity + 1
-                idx = jnp.where(mask, pairs[n_cols][0].astype(jnp.int32),
-                                scrap)
-                overflow = jnp.zeros((), jnp.bool_)
-            else:
-                key_pair = eval_rpn(plan.key_rpn, pairs, n_local, jnp)
-                idx, overflow = slot_index(key_pair, capacity, aux, mask)
-            L8, Lf = make_planes(layouts, specs, cols, mask)
-            S2_8, S2_f = twolevel_partial(idx, L8, Lf, LO, HI)
-            S8_c = S8_c + S2_8.astype(jnp.int64)
-            if S2_f is not None:
-                Sf_c = Sf_c + S2_f.astype(jnp.float64)
-            ovf_c = ovf_c + overflow.astype(jnp.int64)
-            return (S8_c, Sf_c, ovf_c), _unused
-
-        return body
 
     def _topn_sort_key(self, plan: _Plan, v, ok, mask):
         """Map the order expression to one descending-top_k sort key.
@@ -3202,10 +2465,10 @@ class DeviceRunner:
         # finalize that follows (_finish): fetched planes -> result
         # Columns.  For a hash aggregation off the Pallas kernel that is
         # one native call over the KBs of accumulator, which never
-        # lets go of the GIL (finalize_packed; numpy over the same KBs
-        # for the XLA bodies, no Python value made per group either
-        # way); for a selection it is the host gather of the selected
-        # rows
+        # lets go of the GIL (aggregate.finalize_packed; numpy over the
+        # same KBs for the XLA bodies, no Python value made per group
+        # either way); for a selection it is the host gather of the
+        # selected rows
         with tracker.phase("d2h_wait"):
             leaves, treedef = jax.tree.flatten(tree)
             for x in leaves:
@@ -3544,12 +2807,12 @@ class DeviceRunner:
                 # request keeps them request-local
                 gmeta = _GuardedMeta(meta, memo_fresh)
                 if plan.kind == "simple_agg":
-                    result = self._run_simple(dag, plan, host_cols, dtypes,
-                                              n, feed, gmeta)
+                    result = self._aggregator.run_simple(
+                        dag, plan, host_cols, dtypes, n, feed, gmeta)
                 elif plan.kind == "hash_agg":
-                    result = self._run_hash(dag, plan, host_cols, dtypes,
-                                            n, feed, gmeta,
-                                            tile_spans=tile_spans)
+                    result = self._aggregator.run_hash(
+                        dag, plan, host_cols, dtypes, n, feed, gmeta,
+                        tile_spans=tile_spans)
                 elif plan.kind == "topn":
                     result = self._run_topn(dag, plan, host_cols, dtypes,
                                             n, get_batch, feed)
@@ -3634,103 +2897,6 @@ class DeviceRunner:
                 [b.schema[i] for i in dag.output_offsets],
                 [b.columns[i] for i in dag.output_offsets])
         return result
-
-    def probe_kernel(self, dag, storage, launches: int = 32):
-        """Diagnostic: amortized kernel-only ms/pass for a cached Pallas
-        plan.  Dispatches ``launches`` back-to-back kernels and blocks
-        once on the last (in-order stream), so the transport round-trip
-        is paid once: per-launch ≈ true device time when kernel >>
-        dispatch.  → {"kernel_ms", "launches"} or None when the plan has
-        no cached Pallas kernel (XLA path / host fallback).
-
-        Exists for bench.py's phase decomposition (VERDICT r4 #2: a
-        perf artifact must attribute kernel vs transport); not a serving
-        path."""
-        import time as _time
-        self.handle_request(dag, storage)       # warm: feed + kernel
-        entry = None
-        for key, val in self._kernel_cache.items():
-            if isinstance(key, tuple) and key and key[0] == "hashpl" \
-                    and isinstance(val, dict) and "runs" in val:
-                # sharded entries wrap their grid in shard_map; the
-                # launch-train probe times the raw single-device runs
-                if key[1] == dag.plan_key():
-                    entry = val
-        if entry is None:
-            return None
-        runs_by_nb = entry["runs"]
-        run = runs_by_nb[max(runs_by_nb)]      # the full-feed span
-        meta = self._request_meta(storage, (dag.plan_key(), dag.ranges))
-        if "n_rows" not in meta:
-            return None
-        # simple-agg plans have no key bounds; their kernels ignore base
-        base = meta["hash_bounds"][0] if "hash_bounds" in meta else 0
-        n = meta["n_rows"]
-        feed = None
-        cache = self._arena.bucket(self._feed_anchor(storage),
-                                   create=False)
-        for k, v in (cache or {}).items():
-            if isinstance(v, dict) and "flat" in v:
-                feed = v
-        if feed is None:
-            return None
-        cols = tuple(feed["flat"][j] for j in entry["col_sel"])
-        if entry["mode"] == "sparse":
-            got = meta.get("sparse_slots")
-            if got is None:
-                return None
-            cols += (got[3],)
-        out = run(0, n, base, 0, cols)
-        np.asarray(out)                         # sync
-        t0 = _time.perf_counter()
-        outs = [run(0, n, base, 0, cols)
-                for _ in range(launches)]
-        outs[-1].block_until_ready()
-        per = (_time.perf_counter() - t0) / launches
-        return {"kernel_ms": round(per * 1e3, 3), "launches": launches}
-
-    def probe_scan_kernel(self, dag, storage, launches: int = 32):
-        """Diagnostic twin of :meth:`probe_kernel` for the selection /
-        scan mask kernel: amortized kernel-only ms per full-feed
-        predicate pass via an RTT-amortized launch train, plus the feed
-        bytes the pass streams (→ bench's kernel_feed_gbps for configs
-        1-2).  → {"kernel_ms", "launches", "feed_bytes"} or None when
-        the plan has no cached selection kernel."""
-        import time as _time
-        self.handle_request(dag, storage)       # warm: feed + kernel
-        entry = getattr(self, "_selmask_last", None)
-        if entry is None or entry[0] != dag.plan_key():
-            return None
-        _pkey, skey, params, n = entry
-        kern = self._kernel_cache.get(skey)
-        plan = self._analyze(dag)
-        meta = self._request_meta(storage, (dag.plan_key(), dag.ranges))
-        dts = meta.get("dtypes")
-        if kern is None or plan is None or dts is None:
-            return None
-        # THIS plan's feed, by its exact cache key — another plan over
-        # the same snapshot may have a different column set, and timing
-        # the wrong planes would silently corrupt the attribution
-        feed_key = (tuple(plan.scan.columns[ci].col_id
-                          for ci in plan.used_cols), tuple(dts),
-                    dag.ranges)
-        cache = self._arena.bucket(self._feed_anchor(storage),
-                                   create=False)
-        feed = (cache or {}).get(feed_key)
-        if feed is None:
-            return None
-        pvals = tuple(self._cached_param(v, dt) for v, dt in params)
-        n_arr = self._cached_scalar(n, jnp.int64)
-        out = kern(n_arr, *pvals, *feed["flat"])
-        jax.block_until_ready(out)              # compile + sync
-        t0 = _time.perf_counter()
-        outs = [kern(n_arr, *pvals, *feed["flat"])
-                for _ in range(launches)]
-        jax.block_until_ready(outs[-1])
-        per = (_time.perf_counter() - t0) / launches
-        feed_bytes = int(sum(a.nbytes for a in feed["flat"]))
-        return {"kernel_ms": round(per * 1e3, 3), "launches": launches,
-                "feed_bytes": feed_bytes}
 
     def _request_meta(self, storage, meta_key) -> dict:
         """Snapshot-lifetime memo for host-derived request constants
@@ -3841,642 +3007,6 @@ class DeviceRunner:
         return (kind, dag.plan_key(), feed["null_flags"], feed["n_pad"],
                 chunk) + extra
 
-    # -- analyze (tp=104) --
-
-    # -- simple agg --
-
-    def _arg_ok_is_mask(self, plan, feed) -> list:
-        """Per-agg flag: the arg's validity provably equals the row mask
-        (bare NOT NULL column ref), so its plane aliases the mask plane."""
-        out = []
-        for r in plan.agg_rpns:
-            flag = False
-            if r is not None and len(r.nodes) == 1 and \
-                    isinstance(r.nodes[0], RpnColumnRef):
-                ci = r.nodes[0].col_idx
-                flag = not feed["null_flags"][ci]
-            out.append(flag)
-        return out
-
-    @staticmethod
-    def _agg_out(plan) -> tuple:
-        """(result FieldType, container dtype) lists of ``plan.specs``,
-        resolved once per cached plan.  The dtype is the one
-        ``Column.from_list`` gives the eval type, uint64 where the field
-        type is unsigned (BIT kinds)."""
-        out = plan.agg_out
-        if out is None:
-            from ..executors.aggregation import _agg_ret_ft
-            fts = [_agg_ret_ft(spec.kind,
-                               spec.eval_type if spec.kind not in
-                               ("count", "count_star") else None)
-                   for spec in plan.specs]
-            out = plan.agg_out = (fts, [
-                np.dtype(np.uint64) if ft.is_unsigned
-                else ft.eval_type.np_dtype for ft in fts])
-        return out
-
-    def _simple_result(self, dag, plan, merged):
-        finals = finalize_simple(plan.specs, merged)
-        from ..executors.aggregation import _agg_ret_ft
-        schema, cols = [], []
-        for spec, val in zip(plan.specs, finals):
-            ft = _agg_ret_ft(spec.kind, spec.eval_type if spec.kind not in
-                             ("count", "count_star") else None)
-            schema.append(ft)
-            cols.append(Column.from_list(ft.eval_type, [val]))
-        return self._result(dag, schema, cols)
-
-    def _run_simple(self, dag, plan, host_cols, dtypes, n, feed, meta):
-        from ..utils import tracker as _tracker
-        # the fused Pallas kernel serves simple aggregations too (r6):
-        # a single-slot grid turns SUM/COUNT/AVG into one direct-index
-        # pass — the XLA scan's per-step and fusion-boundary costs
-        # (pallas_hash.py module doc) taxed config 3 the same way they
-        # taxed config 4
-        from .kernels import build_layouts, matmul_supported
-        if matmul_supported(plan.specs):
-            arg_nbytes = meta.get("simple_arg_nbytes") \
-                if meta is not None else None
-            if arg_nbytes is None:
-                arg_nbytes = self._arg_nbytes(plan, host_cols(), n)
-                if meta is not None:
-                    meta["simple_arg_nbytes"] = arg_nbytes
-            arg_is_real = [r is not None and r.ret_type is EvalType.REAL
-                           for r in plan.agg_rpns]
-            arg_ok_is_mask = self._arg_ok_is_mask(plan, feed)
-            layouts, p8, pf = build_layouts(plan.specs, arg_is_real,
-                                            arg_nbytes, arg_ok_is_mask)
-            got = self._try_pallas(dag, plan, feed, dtypes, n, 0, 1,
-                                   layouts, p8, pf, arg_nbytes,
-                                   arg_ok_is_mask, mode="simple")
-            if got is not None:
-                kind, payload, LO = got
-
-                def from_packed(packed):
-                    _present, states = self._pallas_states(
-                        packed, LO, p8, layouts, plan.specs, 1)
-                    merged = [{k: np.asarray(v).reshape(-1)[0]
-                               for k, v in s.items()} for s in states]
-                    return self._simple_result(dag, plan, merged)
-
-                if kind == "sync":
-                    return from_packed(payload)
-                return _Pending(payload,
-                                lambda parts: from_packed(_sum_parts(parts)))
-
-        chunk = self._pick_chunk(feed["n_pad"], _CHUNK_AGG)
-        n_cols = len(plan.used_cols)
-        key = self._kern_key("simple", dag, feed, chunk, tuple(dtypes))
-        carry = self._cached_carry(key,
-                                   lambda: self._init_agg_carry(plan, None))
-        kern = self._shard_kernel(
-            key, lambda: self._wrap_mega(
-                "simple",
-                self._mega(self._build_simple_body(plan, n_cols),
-                           self._finalize_psum_summed(),
-                           feed["null_flags"], feed["n_pad"], chunk),
-                carry, len(feed["flat"])))
-        with self._dispatch_phase("simple", key):
-            carry = kern(carry, self._cached_scalar(n, jnp.int64),
-                         self._cached_scalar(0, jnp.int64),
-                         *feed["flat"])
-
-        def fin(fetched):
-            summed, stacked = fetched
-            if not self._single:
-                # summed fields already psum-merged on ICI; only the
-                # per-shard (S,) min/max/first scalars reduce here
-                with _tracker.phase("shard_merge"):
-                    merged = self._merge_stacked(plan.specs, summed,
-                                                 stacked)
-            else:
-                merged = self._merge_stacked(plan.specs, summed,
-                                             stacked)
-            return self._simple_result(dag, plan, merged)
-
-        return _Pending(carry, fin)
-
-    # -- hash agg --
-
-    def _sparse_slots(self, plan, host_cols, n, feed, meta):
-        """Host recode of a sparse GROUP BY key into dense slot ids.
-
-        A sparse int64 key domain (user ids, hashes) cannot
-        direct-index into [0, capacity).  Ranking on device was tried
-        and measured: ``searchsorted``/gather per row lowers to
-        scalar-gather loops on TPU (~120× slower than the dense MXU
-        path).  The TPU-shaped answer is dictionary encoding OUTSIDE
-        the kernel — exactly how BYTES columns reach devices — so the
-        recode runs once per snapshot on host (np.unique's sort is the
-        C path) and the slot column is cached in HBM next to the feed;
-        warm requests then run the identical one-hot MXU kernel as the
-        dense case.  Reference analog: fast_hash_aggr_executor.rs keys
-        its specialised hashmap once per scan, not per batch.
-
-        Returns (uniq_np, nd, capacity, slot device array) or None when
-        the distinct count exceeds the sparse budget.
-        """
-        if "sparse_slots" in meta:
-            return meta["sparse_slots"]
-        kv, km = eval_rpn(plan.key_rpn, host_cols(), n, np)
-        kv = np.broadcast_to(kv, (n,))
-        km = np.broadcast_to(km, (n,))
-        valid = kv[km] if not km.all() else kv
-        got = None
-        if valid.size:
-            # keep the key dtype: casting a uint64 domain to int64 would
-            # wrap keys >= 2^63 and emit wrong group values
-            uniq, inv = np.unique(valid, return_inverse=True)
-            nd = len(uniq)
-            if nd <= self._max_hash_capacity:
-                capacity = max(1024, _next_pow2(nd))
-                idx = np.full(n, capacity, np.int32)       # NULL slot
-                if km.all():
-                    idx[:] = inv.astype(np.int32)
-                else:
-                    idx[km] = inv.astype(np.int32)
-                n_pad = feed["n_pad"]
-                padded = np.full(n_pad, capacity + 1, np.int32)  # scrap
-                padded[:n] = idx
-                dev = jnp.asarray(padded) if self._single else \
-                    jax.device_put(padded, self._row_sharding)
-                got = (uniq, nd, capacity, dev)
-        meta["sparse_slots"] = got
-        return got
-
-    def _run_hash(self, dag, plan, host_cols, dtypes, n, feed, meta,
-                  tile_spans=None):
-        from ..utils import tracker as _tracker
-        from .kernels import (
-            build_layouts,
-            matmul_supported,
-            states_from_matmul,
-            twolevel_dims,
-            twolevel_lo,
-            twolevel_unpack,
-        )
-        if "hash_bounds" in meta:
-            base, span, arg_nbytes = meta["hash_bounds"]
-        else:
-            kv, km = eval_rpn(plan.key_rpn, host_cols(), n, np)
-            kv = np.broadcast_to(kv, (n,))
-            km = np.broadcast_to(km, (n,))
-            valid_keys = kv[km]
-            if valid_keys.size:
-                base = int(valid_keys.min())
-                span = int(valid_keys.max()) - base + 1
-            else:
-                base, span = 0, 1
-            arg_nbytes = self._arg_nbytes(plan, host_cols(), n)
-            meta["hash_bounds"] = (base, span, arg_nbytes)
-            meta.setdefault("n_rows", n)
-        sparse_keys = None          # (uniq_np, slot device array)
-        if span > self._max_hash_capacity:
-            # sparse key domain: direct indexing can't span it, but the
-            # DISTINCT count may still be small — dictionary-encode the
-            # key once per snapshot and feed dense slot ids (the
-            # reference's fast_hash_aggr_executor.rs handles arbitrary
-            # int keys with a hashmap, runner.rs:293-318)
-            got = self._sparse_slots(plan, host_cols, n, feed, meta)
-            if got is None:
-                raise _FallbackToHost(f"hash key span {span}")
-            uniq_np, nd, capacity, slots_dev = got
-            sparse_keys = (uniq_np, slots_dev)
-        else:
-            capacity = max(1024, _next_pow2(span))
-        slots = capacity + 2
-        arg_is_real = [r is not None and r.ret_type is EvalType.REAL
-                       for r in plan.agg_rpns]
-        # a bare reference to a NOT NULL column has validity ≡ row mask —
-        # alias its plane to the mask plane instead of duplicating it
-        # through the matmul (cuts config-4's W operand 4→3 planes)
-        arg_ok_is_mask = self._arg_ok_is_mask(plan, feed)
-        layouts = p8 = pf = None
-        if matmul_supported(plan.specs):
-            layouts, p8, pf = build_layouts(plan.specs, arg_is_real,
-                                            arg_nbytes, arg_ok_is_mask)
-        sparse = sparse_keys is not None
-        # the sparse slot column rides the sharded flat inputs like any
-        # other column (one extra all-valid pair after the scan columns)
-        kern_flat = feed["flat"] + (sparse_keys[1],) if sparse \
-            else feed["flat"]
-        kern_null_flags = feed["null_flags"] + (False,) if sparse \
-            else feed["null_flags"]
-        aux_arr = self._cached_scalar(base, jnp.int64)
-        n_arr = self._cached_scalar(n, jnp.int64)
-        n_cols = len(plan.used_cols)
-
-        slot_keys = sparse_keys[0] if sparse else None
-
-        agg_out = self._agg_out(plan)
-        schema = agg_out[0] + [FieldType.long()]
-
-        def hash_result(merged):
-            return self._result(dag, list(schema), _hash_columns(
-                agg_out, finalize_hash(plan.specs, merged, base, capacity,
-                                       slot_keys=slot_keys)))
-
-        got = None
-        if layouts is not None:
-            # the fused direct-index kernel is the default body for
-            # both dense and (dictionary-encoded) sparse key domains —
-            # the slot column rides as one extra int32 kernel input
-            got = self._try_pallas(dag, plan, feed, dtypes, n, base,
-                                   capacity, layouts, p8, pf,
-                                   arg_nbytes, arg_ok_is_mask,
-                                   mode="sparse" if sparse else "dense",
-                                   spans=tile_spans,
-                                   slots_dev=sparse_keys[1] if sparse
-                                   else None)
-        if got is None and tile_spans is not None:
-            # bucket tiles exist only on the fused-kernel path; the
-            # host pipeline serves the original ranged request instead
-            raise _FallbackToHost("bucket tiles need the pallas kernel")
-        if got is not None:
-            kind, payload, pl_LO = got
-
-            def from_packed(parts):
-                return self._result(dag, list(schema), self._packed_columns(
-                    plan, parts, pl_LO, p8, layouts, slots, base, capacity,
-                    slot_keys))
-
-            if kind == "sync":
-                return from_packed([payload])
-            return _Pending(payload, from_packed)
-        elif layouts is not None and twolevel_lo(p8, pf) is not None:
-            LO, HI = twolevel_dims(slots, p8, pf)
-            chunk = self._pick_chunk(feed["n_pad"], self._feed_unit())
-            key = self._kern_key("hash2l", dag, feed, chunk, tuple(dtypes),
-                                 capacity, arg_nbytes,
-                                 tuple(arg_ok_is_mask), sparse)
-            carry = self._cached_carry(key, lambda: (
-                (np.zeros((HI, p8 * LO), np.int64),
-                 np.zeros((HI, max(pf, 1) * LO), np.float64),
-                 np.zeros((), np.int64)),
-                []))
-            kern = self._shard_kernel(
-                key, lambda: self._wrap_mega(
-                    "hash_twolevel",
-                    self._mega(self._build_hash_twolevel_body(
-                        plan, n_cols, capacity, layouts, LO, HI, pf,
-                        sparse=sparse),
-                        self._finalize_psum_summed(),
-                        kern_null_flags, feed["n_pad"], chunk),
-                    carry, len(kern_flat)))
-            with self._dispatch_phase("hash_twolevel", key):
-                carry = kern(carry, n_arr, aux_arr, *kern_flat)
-
-            def fin_twolevel(fetched):
-                (S8p, Sfp, ovf), _ = fetched
-                assert int(ovf) == 0, "hash agg key range overflow"
-                S8 = twolevel_unpack(S8p, p8, LO, slots, xp=np)
-                Sf = twolevel_unpack(Sfp, pf, LO, slots, xp=np) \
-                    if pf else None
-                present, states = states_from_matmul(layouts, plan.specs,
-                                                     S8, Sf, xp=np)
-                return hash_result({"present": present, "overflow": False,
-                                    "states": states})
-
-            return _Pending(carry, fin_twolevel)
-        else:
-            chunk = self._pick_chunk(feed["n_pad"], _CHUNK_AGG)
-            key = self._kern_key("hashsc", dag, feed, chunk, tuple(dtypes),
-                                 capacity, sparse)
-            # sharded: the order-sensitive stacked states (min/max)
-            # tree-reduce on device via the all-to-all bucket merge —
-            # the slot axis pads to a shard multiple so buckets split
-            # evenly, and D2H shrinks from (S, slots) to (slots,)
-            S = self._nshards()
-            bucket_merge = not self._single
-            slots_m = -(-slots // S) * S if bucket_merge else slots
-
-            def build_scatter_carry():
-                sm_init, st_init = self._init_agg_carry(
-                    plan, slots, stacked_slots=slots_m)
-                return ((sm_init, np.zeros(slots, np.int64),
-                         np.zeros((), np.int64)), st_init)
-
-            carry = self._cached_carry(key, build_scatter_carry)
-            kern = self._shard_kernel(
-                key, lambda: self._wrap_mega(
-                    "hash_scatter",
-                    self._mega(self._build_hash_scatter_body(
-                        plan, n_cols, capacity, sparse=sparse,
-                        stack_pad=slots_m - slots),
-                        self._finalize_hash_bucket_merge()
-                        if bucket_merge else
-                        self._finalize_psum_summed(),
-                        kern_null_flags, feed["n_pad"], chunk),
-                    carry, len(kern_flat)))
-            with self._dispatch_phase("hash_scatter", key):
-                carry = kern(carry, n_arr, aux_arr, *kern_flat)
-
-            def fin_scatter(fetched):
-                (summed, present_counts, ovf), stacked = fetched
-                assert int(ovf) == 0, "hash agg key range overflow"
-                if bucket_merge:
-                    with _tracker.phase("shard_merge"):
-                        states = self._merge_bucketed(
-                            plan.specs, summed, stacked, slots)
-                else:
-                    states = self._merge_stacked(plan.specs, summed,
-                                                 stacked)
-                return hash_result({
-                    "present": present_counts > 0,
-                    "overflow": False,
-                    "states": states,
-                })
-
-            return _Pending(carry, fin_scatter)
-
-    def _bucket_blocks(self, blocks: int) -> int:
-        """Round a grid span up to a 4-significant-bit block count —
-        the compile-class grid shared with _pad_rows."""
-        if blocks > 8:
-            s = blocks.bit_length() - 4
-            k = -(-blocks // (1 << s))
-            if k > 15:
-                s += 1
-                k = -(-blocks // (1 << s))
-            blocks = k << s
-        return max(1, blocks)
-
-    def _packed_columns(self, plan, parts, LO, p8, layouts, slots, base,
-                        capacity, slot_keys):
-        """The hash aggregation's finalize after a Pallas launch:
-        ``finalize_packed`` (one native call where it can, the numpy
-        chain where it cannot), counted once on the physical runner's
-        flight recorder as what it was (``mesh_stats`` ``finalize``),
-        then ``_hash_columns``."""
-        finalized, was_native = finalize_packed(
-            parts, LO, p8, layouts, plan.specs, slots, base, capacity,
-            slot_keys)
-        self.flight_recorder.note_finalize(was_native)
-        return _hash_columns(self._agg_out(plan), finalized)
-
-    @staticmethod
-    def _pallas_states(packed, LO, p8, layouts, specs, slots):
-        """Packed (2, HI, p8*LO) accumulator pair → (present, states).
-
-        The tight slot grid (no scrap slot; NULL slot only when the key
-        may be NULL) may hold fewer than ``slots`` rows: the dropped
-        slots are zero by construction (nothing ever scatters there),
-        so zero-pad back to the shared layout.
-        """
-        from . import pallas_hash
-        from .kernels import states_from_matmul, twolevel_unpack
-        S = pallas_hash.unpack_to_int64(packed)
-        have = min(slots, S.shape[0] * LO)
-        S8 = twolevel_unpack(S, p8, LO, have, xp=np)
-        if have < slots:
-            S8 = np.pad(S8, ((0, 0), (0, slots - have)))
-        return states_from_matmul(layouts, specs, S8, None, xp=np)
-
-    def _pallas_sharded_wrap(self, run, n_in: int, n_local_pad: int):
-        """shard_map wrapper for the fused kernel: each shard runs one
-        grid over its LOCAL feed slice (row bounds traced from the
-        shard index — the kernel's dead-block guard masks the ragged
-        tail shard exactly as it masks bucket padding), then the packed
-        int32 partial pairs psum over both mesh axes.  check_vma is
-        off: pallas_call's out_shape carries no varying-axes type, and
-        the psum makes the output replicated by construction."""
-        def pallas_hash_sharded(n_arr, base_arr, *cols_local):
-            start = self._shard_index() * n_local_pad
-            row_hi = jnp.clip(n_arr - start, 0, n_local_pad)
-            packed = run(jnp.asarray(0, jnp.int32), row_hi, base_arr,
-                         jnp.asarray(0, jnp.int32), cols_local)
-            return lax.psum(packed, ROW_AXES)
-
-        return jax.jit(jax.shard_map(
-            pallas_hash_sharded, mesh=self._mesh,
-            in_specs=(P(), P()) + (P(ROW_AXES),) * n_in,
-            out_specs=P(), check_vma=False))
-
-    def _try_pallas(self, dag, plan, feed, dtypes, n, base, capacity,
-                    layouts, p8, pf, arg_nbytes, arg_ok_is_mask,
-                    mode="dense", spans=None, slots_dev=None):
-        """Fused Pallas fast path for the direct-index aggregation
-        (dense / sparse-slot / simple modes — pallas_hash module doc).
-
-        ``spans``: row intervals to aggregate (bucket tiles); None =
-        the whole feed, dispatched over the ENTIRE padded grid so the
-        compile class is exactly the feed-shape cache key — the
-        dead-block guard makes the bucketed padding cost DMA only.
-        Span tiles keep bucketed block counts for compile-class reuse
-        (block offset via prefetch scalar); the packed partials ADD —
-        psum-partial merge semantics.
-
-        Returns None when the plan/feed/platform is outside the
-        kernel's envelope (the caller then runs an XLA path), else
-        ``(kind, payload, LO)``:
-
-        - ``("sync",  packed np.ndarray, LO)`` — first build: compile +
-          validate ran synchronously so Mosaic rejections fall back.
-        - ``("parts", [device arrays], LO)`` — warm dispatch; the
-          caller fetches and ``_sum_parts``-merges them (possibly on a
-          completion thread — the async serving path).
-
-        A build or compile failure is cached so the fallback is taken
-        once per plan, not per request.
-
-        SHARDED meshes ride the same kernel as per-shard partials
-        (partial-at-shard / final-on-ICI — the TiDB split): shard_map
-        runs one grid over each shard's local feed slice with traced
-        row bounds from the shard index, and the packed int32 partial
-        pairs — exact sums by construction — psum across both mesh
-        axes before ONE replicated (2, HI, W) result crosses D2H.  Any
-        build/lowering failure falls back to the sharded XLA paths
-        exactly like the single-device case.
-        """
-        from . import pallas_hash
-        if not self._is_tpu:
-            return None     # Mosaic kernels need real TPU lowering
-        if not pallas_hash.supported(plan, feed, dtypes, pf, capacity,
-                                     self._nshards(), mode):
-            return None
-        if not self._single and spans is not None:
-            return None     # bucket tiles are a single-device shape
-        sparse = mode == pallas_hash.MODE_SPARSE
-        B = pallas_hash.BLOCK
-        total_blocks = feed["n_pad"] // B
-        tiles = []          # (row_lo, row_hi, blk0, span_blocks)
-        if spans is None:
-            tiles.append((0, n, 0, total_blocks))
-        else:
-            for lo, hi in spans:
-                hi = min(hi, n)
-                if hi <= lo:
-                    continue
-                blk0 = lo // B
-                nb = self._bucket_blocks(-(-hi // B) - blk0)
-                nb = min(nb, total_blocks)
-                if blk0 + nb > total_blocks:
-                    blk0 = total_blocks - nb  # shift left; rows mask exact
-                tiles.append((lo, hi, blk0, nb))
-            if not tiles:
-                return None
-
-        # kernel input selection: only columns the kernel evaluates
-        # (int32, non-null ⇒ one flat entry each) plus the sparse slot
-        # column; everything else (e.g. the raw int64 sparse key) stays
-        # host/XLA-side
-        kset = set(pallas_hash.kernel_col_ids(plan, mode))
-        col_sel, col_map, fi = [], [], 0
-        for i, has_nulls in enumerate(feed["null_flags"]):
-            if i in kset:
-                col_map.append(len(col_sel))
-                col_sel.append(fi)
-            else:
-                col_map.append(-1)
-            fi += 2 if has_nulls else 1
-        col_sel, col_map = tuple(col_sel), tuple(col_map)
-        cols = tuple(feed["flat"][j] for j in col_sel)
-        if sparse:
-            cols += (slots_dev,)
-
-        def dispatch(runs_by_nb):
-            packed = None
-            for lo, hi, blk0, nb in tiles:
-                part = np.asarray(
-                    runs_by_nb[nb](lo, hi, base, blk0, cols))
-                packed = part if packed is None else packed + part
-            return packed
-
-        key = ("hashpl", dag.plan_key(), mode,
-               tuple(sorted({t[3] for t in tiles})), tuple(dtypes),
-               capacity, arg_nbytes, tuple(arg_ok_is_mask),
-               self._nshards())
-        entry = self._kernel_cache.get(key)
-        if entry is False:
-            return None
-        if entry is None:
-            try:
-                # the first build is a launch like any other: its
-                # compile wall and class land in the flight recorder
-                # (first_launch=True), and a rejected build counts as
-                # a recorder fault before the XLA fallback serves
-                with self._dispatch_phase("pallas_hash", key):
-                    if not self._single:
-                        # per-shard partial grids + psum tree-reduce:
-                        # one shard_map launch, one replicated packed
-                        # result
-                        S = self._nshards()
-                        run, LO, HI = pallas_hash.build(
-                            plan, layouts, p8, capacity,
-                            feed["n_pad"] // (S * B), col_map, mode=mode)
-                        wrapped = self._pallas_sharded_wrap(
-                            run, len(cols), feed["n_pad"] // S)
-                        # compile + validate now so Mosaic/shard_map
-                        # rejections fall back to the sharded XLA paths
-                        packed = np.asarray(wrapped(
-                            self._cached_scalar(n, jnp.int64),
-                            self._cached_scalar(base, jnp.int64), *cols))
-                        entry = {"sharded": wrapped, "LO": LO,
-                                 "col_sel": col_sel, "mode": mode}
-                    else:
-                        runs_by_nb = {}
-                        LO = None
-                        for nb in sorted({t[3] for t in tiles}):
-                            run, LO, HI = pallas_hash.build(
-                                plan, layouts, p8, capacity, nb, col_map,
-                                mode=mode)
-                            runs_by_nb[nb] = run
-                        # compile + validate now so Mosaic rejections
-                        # fall back
-                        packed = dispatch(runs_by_nb)
-                        entry = {"runs": runs_by_nb, "LO": LO,
-                                 "col_sel": col_sel, "mode": mode}
-            except Exception as e:
-                # never silently: a swallowed genuine bug here would
-                # disguise itself as the slower XLA path
-                import logging
-                # cache-disable deterministic build/lowering rejections
-                # (Mosaic/compile errors) immediately; a transient runtime
-                # failure (device OOM, runtime hiccup) falls back without
-                # poisoning the cache — but only a few times, so a
-                # deterministic failure dressed as transient can't re-pay
-                # the build+compile cost on every request forever
-                name = type(e).__name__
-                transient = isinstance(e, (OSError, TimeoutError)) or \
-                    "RESOURCE_EXHAUSTED" in str(e) or \
-                    name in ("XlaRuntimeError", "InternalError") and \
-                    "Mosaic" not in str(e)
-                tries = self._kernel_cache.get(("hashpl_tries", key), 0) + 1
-                self._kernel_cache[("hashpl_tries", key)] = tries
-                if transient and tries < 3:
-                    logging.getLogger(__name__).warning(
-                        "pallas hash kernel transient failure for plan %r "
-                        "(attempt %d/3, falling back once): %s: %s",
-                        key[1], tries, name, e)
-                else:
-                    logging.getLogger(__name__).warning(
-                        "pallas hash kernel disabled (cached) for plan "
-                        "%r: %s: %s", key[1], name, e)
-                    self._kernel_cache[key] = False
-                return None
-            self._kernel_cache[key] = entry
-            # success clears the transient strike count — three isolated
-            # hiccups over a process lifetime must not kill the fast path
-            self._kernel_cache.pop(("hashpl_tries", key), None)
-            return ("sync", packed, entry["LO"])
-        LO = entry["LO"]
-        try:
-            with self._dispatch_phase("pallas_hash", key):
-                if "sharded" in entry:
-                    parts = [entry["sharded"](
-                        self._cached_scalar(n, jnp.int64),
-                        self._cached_scalar(base, jnp.int64), *cols)]
-                else:
-                    runs_by_nb = entry["runs"]
-                    parts = [runs_by_nb[nb](lo, hi, base, blk0, cols)
-                             for lo, hi, blk0, nb in tiles]
-            self._kernel_cache.pop(("hashpl_tries", key), None)
-        except Exception as e:
-            # a transient DISPATCH failure on a cached kernel must fall
-            # back to the XLA path for THIS request, same as the
-            # build-time path — not fail the coprocessor request.  (A
-            # failure surfacing later, at the possibly-deferred fetch,
-            # degrades to the host pipeline via the DeferredResult /
-            # endpoint contract instead.)
-            import logging
-            logging.getLogger(__name__).warning(
-                "pallas hash kernel runtime failure for cached plan "
-                "%r (falling back once): %s: %s",
-                key[1], type(e).__name__, e)
-            tries = self._kernel_cache.get(("hashpl_tries", key), 0) + 1
-            self._kernel_cache[("hashpl_tries", key)] = tries
-            if tries >= 3:
-                self._kernel_cache[key] = False
-            return None
-        return ("parts", parts, LO)
-
-    def _arg_nbytes(self, plan: _Plan, host_cols, n: int) -> tuple:
-        """Byte-plane count per aggregate arg for the MXU int path.
-
-        Plain column refs use the column's actual value range (host
-        min/max, vectorized); computed expressions use the device dtype
-        width (int arithmetic wraps in-dtype on device — documented
-        deviation, expr/functions.py)."""
-        from .kernels import int_planes_needed
-        out = []
-        for r in plan.agg_rpns:
-            if r is None or r.ret_type is EvalType.REAL:
-                out.append(0)
-                continue
-            nodes = r.nodes
-            if len(nodes) == 1 and isinstance(nodes[0], RpnColumnRef):
-                v, ok = host_cols[nodes[0].col_idx]
-                if v.size:
-                    out.append(int_planes_needed(int(v.min()), int(v.max())))
-                else:
-                    out.append(1)
-            else:
-                widths = [host_cols[i][0].dtype.itemsize
-                          for i in _rpn_col_indices(r)] or [4]
-                out.append(max(widths))
-        return tuple(out)
-
     # -- selection (late materialization: predicate on device, COMPACT
     #    selection vector over D2H, alive-mask-aware host gather) --
 
@@ -4513,7 +3043,6 @@ class DeviceRunner:
         from ..utils import tracker as _tracker
         n_pad = feed["n_pad"]
         n_local = n_pad // self._nshards()
-        pkey = dag.plan_key()
         stat_keys = self._sel_keys(dag, plan)
 
         if plan.sel_params is None:
@@ -4566,11 +3095,6 @@ class DeviceRunner:
         with self._dispatch_phase("scan_sel_mask", skey):
             count_dev, packed_dev, mask_dev = kern(
                 self._cached_scalar(n, jnp.int64), *params, *feed["flat"])
-        # bench attribution seam (probe_scan_kernel launch train): ONE
-        # slot, not a per-plan-key cache entry — const-inclusive keys
-        # would grow the kernel cache per distinct threshold forever
-        self._selmask_last = (pkey, skey,
-                              tuple(zip(param_vals, param_dts)), n)
 
         pred = self._sel_predict(stat_keys)
         if pred is None:

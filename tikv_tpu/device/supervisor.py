@@ -141,7 +141,7 @@ class FlightRecorder:
         # configured mesh (not on a placement slice, not on a degraded
         # submesh): what /health device_mesh reports beside ``launches``
         self.sharded_launches = 0
-        # Pallas hash accumulators finalized (device/runner.py
+        # Pallas hash accumulators finalized (device/aggregate.py
         # finalize_packed), by what ran: the one native call that holds
         # the GIL, or the numpy chain it falls back to
         self.finalize_native = 0

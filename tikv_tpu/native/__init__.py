@@ -76,6 +76,6 @@ build_mvcc_sst = getattr(_mod, "build_mvcc_sst", None)
 mvcc_parse_planes = getattr(_mod, "mvcc_parse_planes", None)
 # hash-agg host finalize: fetched (2, HI, W) int32 accumulator parts →
 # key / value / validity planes in caller-made buffers, GIL held from
-# entry to return (device/runner.py finalize_packed, which keeps the
+# entry to return (device/aggregate.py finalize_packed, which keeps the
 # numpy chain as the fallback and the tests' oracle)
 hash_finalize_packed = getattr(_mod, "hash_finalize_packed", None)

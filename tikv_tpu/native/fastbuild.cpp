@@ -1090,7 +1090,7 @@ PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
 
 /* ---- hash-agg finalize: the fetched Pallas accumulator -> result planes
  *
- * What device/runner.py's numpy chain does in ~24 array calls
+ * What device/aggregate.py's numpy chain does in ~24 array calls
  * (_sum_parts, pallas_hash.unpack_to_int64, kernels.twolevel_unpack,
  * kernels.states_from_matmul, ops/agg.py finalize_hash), in one pass
  * over the slots.  numpy drops the GIL around every inner loop of more

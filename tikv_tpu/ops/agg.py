@@ -327,7 +327,7 @@ def hash_agg_tile(xp, specs: Sequence[AggSpec], key: tuple,
         # sparse recode: base = ("precomp", idx) — the slot per row was
         # already computed (rank among distinct keys, NULLs at the NULL
         # slot); only the request's row/selection mask is applied here
-        # (device/runner.py _run_hash sparse path)
+        # (device/aggregate.py run_hash sparse path)
         idx = xp.where(row_mask, base[1].astype("int32"), scrap)
         overflow = xp.zeros((), dtype=bool) if xp is not np else False
     else:
@@ -448,7 +448,7 @@ def finalize_hash(specs, state: dict, base: int, capacity: int,
     without walking them.  No per-group Python runs here except
     ``_finalize_var``, whose float operation order is the host's.
 
-    Two callers in device/runner.py: the XLA hash bodies (two-level,
+    Two callers in device/aggregate.py: the XLA hash bodies (two-level,
     scatter) finalize their states here, and ``finalize_packed`` does
     for a Pallas accumulator the native call cannot serve, or in a
     process without the extension.  That native call

@@ -3,9 +3,8 @@
 The heavy-traffic serving subsystem (ROADMAP "Heavy-traffic serving"):
 thousands of concurrent small coprocessor queries each paid their own
 device dispatch, their own D2H sync, and their own trip through the
-read pool, even though config 4p proves the hardware amortizes those
-fixed costs across in-flight work (~6.1B rows/s pipelined vs ~1B
-single-stream).  The accelerator's economics are BATCH economics
+read pool, though the hardware amortizes those fixed costs across
+in-flight work.  The accelerator's economics are BATCH economics
 (Jouppi et al., PAPERS.md): a launch plus a transfer sync is a fixed
 tax, so the unit of dispatch must be a *group* of requests, exactly as
 MonetDB/X100 made the unit of interpretation a vector of tuples

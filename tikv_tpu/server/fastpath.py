@@ -1042,7 +1042,7 @@ def _column_list(c) -> list:
     instead of the per-element ``Column.get`` walk ``enc_rows`` pays
     (an isinstance + validity probe + ``.item()`` per cell).  A device
     hash aggregation's columns are the finalize's own planes
-    (``runner._hash_columns``), so this call is the first and only
+    (``device/aggregate.py _hash_columns``), so this call is the first and only
     place its answer becomes Python values: msgpack wants them."""
     import numpy as np
     vals = c.values.tolist()
