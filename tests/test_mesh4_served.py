@@ -8,6 +8,7 @@ add up to the unsharded reference), and what the deployment's spans and
 counters say: the ``mesh`` label of the runner that launched, ``/health``
 ``device_mesh``'s counts, and both after a submesh rebuild."""
 
+import contextlib
 import json
 import os
 import sys
@@ -19,6 +20,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from tikv_tpu.config import TikvConfig
 from tikv_tpu.datatype import Column, EvalType
@@ -303,3 +305,188 @@ def test_a_submesh_rebuild_changes_the_label_and_the_count(store, kind,
     now = runner.mesh_stats()
     assert now["live"] == {"shape": "2x2", "n_devices": 4}
     assert now["sharded_launches"] > mid["sharded_launches"]
+
+
+# ------------------------- the cached scalars lie where the program wants
+
+
+def replicated_over(arr, runner) -> bool:
+    """Committed, with the sharding every sharded program of ``runner``
+    declares for a scalar (``P()`` over its mesh), one copy a device."""
+    return (arr.committed
+            and arr.sharding.is_equivalent_to(runner._repl, arr.ndim)
+            and arr.devices() == set(runner._mesh.devices.flat)
+            and len(arr.addressable_shards) == runner._nshards())
+
+
+def cached_values(runner) -> dict:
+    return {"scalar": runner._cached_scalar(ROWS, jnp.int64),
+            "int param": runner._cached_param(960, jnp.int32),
+            "float param": runner._cached_param(0.5, jnp.float32)}
+
+
+def test_a_mesh_runners_cached_scalars_are_replicated_over_its_mesh(store):
+    """What ``_cached_scalar`` / ``_cached_param`` hand a sharded
+    program lies on all four devices, committed with ``runner._repl``:
+    the jitted call takes it as it lies; the value and dtype are what
+    was asked for, and the second look-up is the same array."""
+    runner = store.runner
+    assert len(set(runner._mesh.devices.flat)) == 4
+    for name, arr in cached_values(runner).items():
+        assert replicated_over(arr, runner), (name, arr.sharding)
+    got = cached_values(runner)
+    assert (int(got["scalar"]), str(got["scalar"].dtype)) == (ROWS, "int64")
+    assert (int(got["int param"]), str(got["int param"].dtype)) == \
+        (960, "int32")
+    assert (float(got["float param"]), str(got["float param"].dtype)) == \
+        (0.5, "float32")
+    assert all(a is b for a, b in zip(got.values(),
+                                      cached_values(runner).values()))
+
+
+@pytest.mark.parametrize("device", [0, 2])
+def test_a_one_device_runners_cached_scalars_stay_on_its_device(device):
+    """The one-chip cells' runner and a placement slice make theirs as
+    before: uncommitted, on the single device they run on (a slice's
+    requests run under its ``_device_scope``)."""
+    one = DeviceRunner(mesh=make_mesh(jax.devices()[device:device + 1]),
+                       chunk_rows=1 << 12)
+    assert one._single
+    with one._device_scope():
+        got = cached_values(one)
+    for name, arr in got.items():
+        assert arr.devices() == {jax.devices()[device]}, name
+        assert not arr.committed, name
+        assert len(arr.addressable_shards) == 1, name
+    assert one.mesh_stats()["scalar_cache"] == {"hits": 0, "uploads": 3}
+
+
+@contextlib.contextmanager
+def launches_guarded():
+    """Every launch of every runner, on whatever thread serves it, runs
+    with device-to-device and host-to-device transfers disallowed
+    around the jitted call (JAX's guards are per thread, so they are
+    set inside ``_dispatch_phase``, where the request's thread is).
+    → the compile classes launched so."""
+    inner = DeviceRunner._dispatch_phase
+    launched = []
+
+    @contextlib.contextmanager
+    def guarded(self, klass, key=None):
+        with inner(self, klass, key), \
+                jax.transfer_guard_device_to_device("disallow"), \
+                jax.transfer_guard_host_to_device("disallow"):
+            launched.append(klass)
+            yield
+    DeviceRunner._dispatch_phase = guarded
+    try:
+        yield launched
+    finally:
+        DeviceRunner._dispatch_phase = inner
+
+
+@contextlib.contextmanager
+def slice_one_tripped(store, kind, params):
+    """Slice 1 dead until the block ends (the way
+    ``test_a_submesh_rebuild_changes_the_label_and_the_count`` trips
+    it); inside, whole-mesh reads are served by the 1x2 sub-runner."""
+    ctx = store.ctxs["dense"]
+    runner = store.runner
+    failpoint.cfg("device::slice_dead", "return(1)")
+    try:
+        deadline = time.monotonic() + 20
+        while not runner._board.quarantined_set():
+            assert time.monotonic() < deadline, runner._board.stats()
+            kind.send(ctx, store.client,
+                      kind.prepare(ctx, store.client, params))
+        yield
+    finally:
+        failpoint.teardown()
+        end = time.monotonic() + 5
+        while runner._board.quarantined_set() and time.monotonic() < end:
+            runner.probe_quarantined()
+            time.sleep(0.02)
+        assert not runner._board.quarantined_set()
+        runner._degraded_target()       # the full mesh takes over again
+
+
+def test_a_degraded_sub_runner_replicates_over_its_own_two_devices(
+        store, kind, params):
+    """The 1x2 submesh runner is a ``DeviceRunner`` of its own: its
+    cache and its ``_repl`` are its own, over the two devices it
+    serves on, nothing shared with the parent's four; only the counts
+    go to the parent's ``/health`` block, as its launches do."""
+    runner = store.runner
+    reference = kind.reference(store.ctxs["dense"], params)
+    with slice_one_tripped(store, kind, params):
+        assert served(store, kind, params, "dense")["labels"]["mesh"] == "1x2"
+        sub, dead = runner._live_mesh()
+        assert sub is not runner and dead == (1,)
+        assert sub._scalar_cache is not runner._scalar_cache
+        assert sub.flight_recorder is runner.flight_recorder
+        assert len(sub._scalar_cache) >= 2      # the read's n and base
+        two = set(sub._mesh.devices.flat)
+        assert len(two) == 2 and two < set(runner._mesh.devices.flat)
+        for name, arr in {**cached_values(sub),
+                          **dict(sub._scalar_cache)}.items():
+            assert replicated_over(arr, sub), (name, arr.sharding)
+            assert arr.devices() == two, name
+        before = runner.mesh_stats()["scalar_cache"]
+        with launches_guarded() as launched:
+            warm = served(store, kind, params, "dense")
+        after = runner.mesh_stats()["scalar_cache"]
+        assert launched and warm["answer"] == reference.tobytes()
+        assert warm["labels"]["mesh"] == "1x2"
+        assert after["uploads"] == before["uploads"]
+        assert after["hits"] >= before["hits"] + 2
+    for name, arr in cached_values(runner).items():
+        assert replicated_over(arr, runner), name
+
+
+def test_the_guards_refuse_a_scalar_that_lies_on_one_device(store):
+    """The control for the test below: handed a scalar on one device,
+    as ``_cached_scalar`` made it before, a sharded program's call is
+    refused by the device-to-device guard on this backend too."""
+    from jax.sharding import PartitionSpec as P
+    runner = store.runner
+    prog = jax.jit(jax.shard_map(
+        lambda n, x: x + n, mesh=runner._mesh,
+        in_specs=(P(), P()), out_specs=P(), check_vma=False))
+    x = runner._cached_scalar(1, jnp.int64)
+    assert int(prog(runner._cached_scalar(ROWS, jnp.int64), x)) == ROWS + 1
+    with jax.transfer_guard_device_to_device("disallow"), \
+            jax.transfer_guard_host_to_device("disallow"):
+        assert int(prog(runner._cached_scalar(ROWS, jnp.int64), x)) == \
+            ROWS + 1
+        with pytest.raises(Exception, match="[Dd]isallowed"):
+            prog(jnp.asarray(ROWS, jnp.int64), x)
+
+
+@pytest.mark.parametrize("keys", sorted(KEYS))
+def test_a_warm_whole_mesh_read_moves_nothing_to_or_between_devices(
+        store, kind, params, keys):
+    """After one warm-up read, served reads launch with every argument
+    where the program wants it: no transfer to a device or between
+    devices is allowed around the launch and none is asked for, the
+    answers are the reference's, the cache uploads nothing and is hit
+    at least twice a launch."""
+    ctx = store.ctxs[keys]
+    runner = store.runner
+    served(store, kind, params, keys)
+    before = runner.mesh_stats()
+    launches0 = runner.flight_recorder.stats()["launches"]
+    with launches_guarded() as launched:
+        records = [served(store, kind, params, keys) for _ in range(3)]
+    after = runner.mesh_stats()
+    launches = runner.flight_recorder.stats()["launches"] - launches0
+    assert launches == len(launched) == 3
+    assert failing(kind.check(ctx, records, params,
+                              kind.reference(ctx, params))) == []
+    assert {r["labels"]["mesh"] for r in records} == {"2x2"}
+    assert "host_exec" not in {p for r in records for p in r["phases_ms"]}
+    assert after["sharded_launches"] - before["sharded_launches"] == 3
+    assert after["scalar_cache"]["uploads"] == \
+        before["scalar_cache"]["uploads"]
+    assert after["scalar_cache"]["hits"] >= \
+        before["scalar_cache"]["hits"] + 2 * launches
+    assert runner.flight_recorder.stats()["faults"] == 0

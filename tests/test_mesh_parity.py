@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from tikv_tpu.datatype import Column, EvalType, FieldType
 from tikv_tpu.device import DeviceRunner
@@ -478,3 +479,100 @@ def test_mesh_stats_rollup():
     assert "placement" in ms and len(ms["placement"]["slices"]) == 8
     from tikv_tpu.utils.metrics import DEVICE_MESH_SHARDS
     assert DEVICE_MESH_SHARDS.value == 8
+
+
+# ------------------------------------- the scalar cache on a mesh (PR 30)
+#
+# Every sharded launch site takes its scalars (row count, key base,
+# predicate constants) from the runner's one cache and declares them
+# ``P()``: on a mesh the cache commits them replicated over the
+# runner's devices, so the jitted call takes them as they lie.
+
+
+@pytest.fixture(scope="module")
+def r4():
+    return DeviceRunner(mesh=make_mesh(jax.devices()[:4],
+                                       shape=parse_mesh_shape("2x2")),
+                        chunk_rows=4 * 64)
+
+
+def _plan_of(kind, table):
+    sel = DagSelect.from_table(table, ["id", "k", "v"])
+    if kind == "hash_twolevel":
+        return sel.aggregate([sel.col("k")], [
+            ("count_star", None), ("sum", sel.col("v"))]).build()
+    if kind == "hash_scatter":
+        return sel.aggregate([sel.col("k")], [
+            ("count_star", None), ("min", sel.col("v")),
+            ("max", sel.col("v"))]).build()
+    if kind == "simple":
+        return sel.aggregate([], [
+            ("count_star", None), ("sum", sel.col("v")),
+            ("avg", sel.col("v"))]).build()
+    if kind == "topn":
+        return sel.order_by(sel.col("v"), desc=True, limit=37).build()
+    assert kind == "scan_sel_mask"
+    return sel.where(sel.col("v") < -49_000).build()
+
+
+@pytest.mark.parametrize("kind", ["hash_twolevel", "hash_scatter", "simple",
+                                  "topn", "scan_sel_mask"])
+def test_replicated_scalars_serve_every_sharded_launch_site(kind, r4, r1):
+    """The XLA stand-in bodies, TopN and a selection on a 2x2 mesh
+    answer as one device and the host do; the class launched is the
+    one named; whatever the request cached is replicated over the four
+    devices; and a warm request launches with transfers to a device
+    and between devices disallowed (the fetch is outside the guards,
+    as it is outside the dispatch lock)."""
+    table = _table()
+    snap = _snap(table, 7000, 41, key_hi=300)
+    want = _rows(BatchExecutorsRunner(_plan_of(kind, table),
+                                      snap).handle_request())
+    if kind == "topn":      # ties order freely: compare the sort key
+        want = [r[-1] for r in want]
+
+    def rows(result):
+        got = _rows(result)
+        return [r[-1] for r in got] if kind == "topn" else got
+    assert rows(r1.handle_request(_plan_of(kind, table), snap)) == want
+    assert rows(r4.handle_request(_plan_of(kind, table), snap)) == want
+    before = r4.mesh_stats()["scalar_cache"]
+    with jax.transfer_guard_device_to_device("disallow"), \
+            jax.transfer_guard_host_to_device("disallow"):
+        pending = r4.handle_request(_plan_of(kind, table), snap,
+                                    deferred=True)
+    assert rows(pending.result()) == want
+    after = r4.mesh_stats()["scalar_cache"]
+    assert after["uploads"] == before["uploads"]
+    assert after["hits"] > before["hits"]
+    assert r4.flight_recorder.items()[-1]["compile_class"] == kind
+    assert r4.flight_recorder.items()[-1]["shards"] == 4
+    four = set(r4._mesh.devices.flat)
+    assert r4._scalar_cache
+    for key, arr in r4._scalar_cache.items():
+        assert arr.committed and arr.devices() == four, key
+        assert arr.sharding.is_equivalent_to(r4._repl, arr.ndim), key
+
+
+def test_scalar_cache_lru_evicts_at_256_on_a_mesh(r4):
+    """The bound a live server needs under writes (a new row count a
+    snapshot) holds on a mesh: 256 values stay, the oldest go first,
+    and a value that went is put again, replicated."""
+    base = 1 << 40                  # values no other test asks for
+    for i in range(300):
+        r4._cached_scalar(base + i, jnp.int64)
+    cache = r4._scalar_cache
+    assert len(cache) == 256
+    key = lambda i: (base + i, str(jnp.int64))     # noqa: E731
+    assert key(0) not in cache and key(43) not in cache
+    assert key(44) in cache and key(299) in cache
+    before = r4.mesh_stats()["scalar_cache"]
+    again = r4._cached_scalar(base, jnp.int64)
+    kept = r4._cached_scalar(base + 299, jnp.int64)
+    after = r4.mesh_stats()["scalar_cache"]
+    assert (after["uploads"], after["hits"]) == \
+        (before["uploads"] + 1, before["hits"] + 1)
+    assert len(cache) == 256 and key(44) not in cache
+    assert int(again) == base and int(kept) == base + 299
+    assert again.devices() == set(r4._mesh.devices.flat)
+    assert again.sharding.is_equivalent_to(r4._repl, 0)

@@ -169,3 +169,45 @@ def test_simple_mode_matches_numpy(interpret, n_devices):
         assert row[0] == int(vv.sum()) and row[1] == len(vv), row
         assert row[2] == int(vv.sum()) / len(vv), row
     _served_by_pallas(runner)
+
+
+@pytest.mark.parametrize("keys", ["dense", "sparse"])
+def test_a_warm_sharded_launch_takes_its_arguments_as_they_lie(interpret,
+                                                               keys):
+    """The benchmark's four-chip launch site (``_try_pallas``'s
+    ``launch`` of the ``shard_map`` wrap): warm, it hands the jitted
+    program the row count and the key base from the runner's cache,
+    committed replicated over the mesh, beside the row-sharded feed,
+    so the call passes with transfers to a device and between devices
+    disallowed and the cache uploads nothing (on the tree before PR 30
+    the device-to-device guard refused the two scalars' re-lay)."""
+    rng = np.random.default_rng(6)
+    if keys == "dense":
+        k = rng.integers(0, 1024, N_ROWS).astype(np.int64)
+    else:
+        domain = rng.integers(0, 1 << 62, 1000, dtype=np.int64)
+        k = domain[rng.integers(0, domain.size, N_ROWS)]
+    table, snap, v = _snapshot(N_ROWS, k, seed=6 if keys == "dense" else 7)
+    runner = _runner(4)
+
+    def dag():
+        sel = DagSelect.from_table(table, ["id", "k", "v"])
+        return sel.aggregate(
+            [sel.col("k")],
+            [("count_star", None), ("sum", sel.col("v"))]).build()
+
+    want = _want_groups(k, v, np.ones(N_ROWS, np.bool_))
+    assert _group_rows(runner.handle_request(dag(), snap)) == want
+    before = runner.mesh_stats()["scalar_cache"]
+    with jax.transfer_guard_device_to_device("disallow"), \
+            jax.transfer_guard_host_to_device("disallow"):
+        pending = runner.handle_request(dag(), snap, deferred=True)
+    assert _group_rows(pending.result()) == want
+    _served_by_pallas(runner)
+    after = runner.mesh_stats()["scalar_cache"]
+    assert after["uploads"] == before["uploads"]
+    assert after["hits"] >= before["hits"] + 2
+    four = set(runner._mesh.devices.flat)
+    for key, arr in runner._scalar_cache.items():
+        assert arr.committed and arr.devices() == four, key
+        assert arr.sharding.is_equivalent_to(runner._repl, arr.ndim), key

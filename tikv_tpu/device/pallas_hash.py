@@ -390,11 +390,15 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
             # domain's minimum (up to 2^62) does not fit the int32
             # prefetch scalars (numpy 2 raises instead of wrapping)
             base = 0
-        # the scalar tuple is constant per (feed, tile): cache it so a
-        # warm request issues no scalar H2D (co-located cost: not
-        # measured).  Traced scalars (the sharded per-shard path:
-        # row bounds depend on lax.axis_index) stack instead of
-        # caching — inside shard_map there is no H2D to save.
+        # the scalar tuple is constant per (feed, tile): cache it, on
+        # the device the kernel runs on, so a warm request issues no
+        # scalar H2D and the jitted call takes it as it lies (what an
+        # argument that lies elsewhere costs a launch was measured on
+        # four chips: 1.2 ms each, PERF.md section 6, PR 30).  Traced
+        # scalars (the sharded per-shard path: row bounds depend on
+        # lax.axis_index) stack instead of caching — inside shard_map
+        # there is no H2D to save; theirs are the runner's cached
+        # scalars, replicated over its mesh (DeviceRunner._cached_scalar).
         if isinstance(row_lo, (int, np.integer)):
             key = (row_lo, int(row_hi), int(base), int(blk0))
             scal = scal_cache.get(key)

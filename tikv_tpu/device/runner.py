@@ -489,7 +489,14 @@ class DeviceRunner:
         self._repl = NamedSharding(self._mesh, P())
         # Single-device: plain jit + uncommitted arrays.  A 1-device
         # mesh gains nothing from explicit NamedSharding transfers and
-        # shard_map wrappers (their dispatch cost: not measured).
+        # shard_map wrappers.  On more than one device whatever a
+        # sharded program takes has to lie where the program declares
+        # it BEFORE the call: an uncommitted array on one chip is
+        # re-laid by JAX's Python argument path on every launch, under
+        # the dispatch lock (the four-chip trace: 1.2 ms an argument;
+        # PERF.md sections 3 and 6, PR 30).  ``_repl`` is where the
+        # cached scalars go (_scalar_cache_get), ``_row_sharding``
+        # where the feeds do.
         self._single = num_shards(self._mesh) == 1
         dev0 = self._mesh.devices.flat[0]
         self._pin_device = dev0 \
@@ -954,8 +961,12 @@ class DeviceRunner:
         configured mesh, beside the flight recorder's ``launches``;
         ``submesh_rebuilds``; ``finalize``: Pallas hash accumulators
         finalized by the one native call or by the numpy chain, and
-        whether the extension built; all monotone), the resident bytes
-        of the live mesh's fullest shard, and the placement rollup."""
+        whether the extension built; ``scalar_cache``: look-ups of the
+        cached device scalars that found the value on the device
+        (``hits``) or had to put it there (``uploads``), sub-runners'
+        included: a warm launch only hits; all monotone), the resident
+        bytes of the live mesh's fullest shard, and the placement
+        rollup."""
         shape = dict(zip(ROW_AXES,
                          (int(s) for s in self._mesh.devices.shape)))
         dev0 = self._mesh.devices.flat[0]
@@ -971,6 +982,7 @@ class DeviceRunner:
                    **self.flight_recorder.finalize_counts(),
                    "native_available":
                        native.hash_finalize_packed is not None},
+               "scalar_cache": self.flight_recorder.scalar_counts(),
                "submesh_rebuilds": self._submesh_rebuilds,
                "feed_bytes_per_shard": max(
                    live._arena.resident_bytes_by_device().values(),
@@ -2229,8 +2241,15 @@ class DeviceRunner:
     def _scalar_cache_get(self, key, v, dtype):
         cache = self._scalar_cache
         arr = cache.get(key)
+        self.flight_recorder.note_scalar(hit=arr is not None)
         if arr is None:
-            arr = jnp.asarray(v, dtype)
+            if self._single:
+                arr = jnp.asarray(v, dtype)
+            else:
+                # committed where every sharded program declares its
+                # scalars, ``P()`` over THIS runner's mesh: the jitted
+                # call then takes the array as it lies
+                arr = jax.device_put(np.asarray(v, dtype), self._repl)
             cache[key] = arr
             while len(cache) > 256:
                 cache.popitem(last=False)
@@ -2240,10 +2259,17 @@ class DeviceRunner:
 
     def _cached_scalar(self, v, dtype):
         """Device-resident scalar, uploaded once per value, so a warm
-        request issues no scalar H2D (its co-located cost: not
-        measured).  LRU-bounded: row counts vary per snapshot, so
-        unbounded caching would leak one device buffer per distinct n
-        on a live server."""
+        request issues no scalar H2D; on a mesh it is committed
+        replicated over this runner's devices (``_repl``), which is
+        how every sharded program declares it, so a warm launch moves
+        nothing between chips either.  Measured on four chips
+        (PERF.md sections 3 and 6, PR 30): a scalar cached on one chip
+        went through ``shard_args`` → ``DevicePutWithSharding`` on
+        every launch, 1.2 ms each and two a launch, 2.4 of the 3.6 ms
+        a launch held the dispatch lock.  Counted on /health
+        ``device_mesh.scalar_cache``.  LRU-bounded: row counts vary
+        per snapshot, so unbounded caching would leak one device
+        buffer per distinct n on a live server."""
         return self._scalar_cache_get((int(v), str(dtype)), v, dtype)
 
     def _cached_param(self, v, dtype):

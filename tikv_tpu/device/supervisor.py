@@ -146,6 +146,11 @@ class FlightRecorder:
         # the GIL, or the numpy chain it falls back to
         self.finalize_native = 0
         self.finalize_numpy = 0
+        # look-ups of the runners' cached device scalars
+        # (DeviceRunner._scalar_cache_get), by whether the value was
+        # already on the device: a warm launch only hits
+        self.scalar_hits = 0
+        self.scalar_uploads = 0
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -189,6 +194,18 @@ class FlightRecorder:
                 self.finalize_native += 1
             else:
                 self.finalize_numpy += 1
+
+    def note_scalar(self, hit: bool) -> None:
+        with self._mu:
+            if hit:
+                self.scalar_hits += 1
+            else:
+                self.scalar_uploads += 1
+
+    def scalar_counts(self) -> dict:
+        with self._mu:
+            return {"hits": self.scalar_hits,
+                    "uploads": self.scalar_uploads}
 
     def finalize_counts(self) -> dict:
         with self._mu:
