@@ -1,0 +1,556 @@
+"""The table in upstream's regions (benchmark configuration
+``int3-10m-regions96``) at a small size on the CPU: ``int_table`` data
+from a seed, pre-split and loaded by the cell's own table kind into a
+store built as ``benchmark/rig.py`` builds it, read by
+``TxnClient.coprocessor_fanout`` through the cell's own request kind.
+The fanned-out answer against the numpy reference, the control, the
+share test (the regions' partials, each computed alone on its rows, add
+up to the whole table's), a split landing between the cut and the send,
+what the summary of a read carries, the layout wait's refusal, and the
+whole flow of ``benchmark/loadgen.py`` as a child process (``run.py
+--dry-run-cpu`` cannot rehearse this cell: its threshold of rows // 4
+sends every region to the host)."""
+
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+import types
+import urllib.request
+
+import numpy as np
+import pytest
+
+import jax
+
+from tikv_tpu.codec.keys import table_record_key, table_record_range
+from tikv_tpu.config import TikvConfig
+from tikv_tpu.device import DeviceRunner
+from tikv_tpu.parallel import make_mesh
+from tikv_tpu.server import wire
+from tikv_tpu.utils import failpoint
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+if BENCH not in sys.path:       # the table and request kinds import ``byname``
+    sys.path.append(BENCH)
+
+import byname  # noqa: E402
+
+ROWS = 20000
+SEED = 2600000027           # the driver's seeds are this large
+THRESHOLD = 256             # a toy region must still route to the device
+SPLIT_MB = 1                # ... and the split checker must still size it
+KEYS = {
+    "dense": {"dist": "uniform_dense", "groups": 1024},
+    "sparse": {"dist": "uniform_sparse", "groups": 1024,
+               "domain_bits": 62},
+}
+# one table for each test that changes its table's layout
+TABLE_IDS = {"dense": 9903, "sparse": 9904, "split": 9905, "tiny": 9906,
+             "unsettled": 9907, "loadgen": 9908}
+CELL = "agg-regions96-closed4"
+
+
+def load_config() -> dict:
+    with open(os.path.join(BENCH, "configs", "int3-10m-regions96.json")) as f:
+        return json.load(f)
+
+
+def table_spec(name: str) -> dict:
+    spec = json.loads(json.dumps(load_config()["table"]))
+    spec["table_id"] = TABLE_IDS[name]
+    spec["columns"]["c0"] = KEYS.get(name, KEYS["dense"])
+    return spec
+
+
+N = load_config()["table"]["regions"]
+
+
+@pytest.fixture(scope="module")
+def kind():
+    return byname.load("requests", "hash_agg_regions")
+
+
+@pytest.fixture(scope="module")
+def table_kind():
+    return byname.load("tables", "int_table_presplit")
+
+
+@pytest.fixture(scope="module")
+def params():
+    with open(os.path.join(BENCH, "traffic", f"{CELL}.json")) as f:
+        return json.load(f)["kinds"]["hash_agg_regions"]["params"]
+
+
+@pytest.fixture(scope="module")
+def store(table_kind):
+    """The store as ``benchmark/rig.py`` builds it from the
+    configuration's TOML (one chip, the status server beside it), with PD
+    in process; the region size limit is cut with the table, so that the
+    split checker sizes a region of a few thousand rows as it sizes one
+    of 96 MiB."""
+    pytest.importorskip("grpc")
+    from tikv_tpu.raftstore.metapb import Store
+    from tikv_tpu.server import (
+        Node, PdServer, RemotePdClient, TikvServer, TxnClient,
+    )
+    config = TikvConfig.from_file(os.path.join(ROOT, load_config()["toml"]))
+    assert config.raftstore.region_split_size_mb == \
+        load_config()["table"]["region_split_size_mb"] == 96
+    config.raftstore.region_split_size_mb = SPLIT_MB
+    config.coprocessor.device_row_threshold = THRESHOLD
+    runner = DeviceRunner(mesh=make_mesh(jax.devices()[:1]),
+                          chunk_rows=1 << 12)
+    pd_server = PdServer("127.0.0.1:0")
+    pd_server.start()
+    pd_addr = f"127.0.0.1:{pd_server.port}"
+    node = Node("127.0.0.1:0", RemotePdClient(pd_addr),
+                device_runner=runner, config=config)
+    srv = TikvServer(node, status_addr="127.0.0.1:0")
+    node.addr = f"127.0.0.1:{srv.port}"
+    node.pd.put_store(Store(node.store_id, node.addr))
+    srv.start()
+    client = TxnClient(pd_addr)
+    ctxs = {}
+    for name in ("dense", "sparse", "split", "tiny"):
+        spec = table_spec(name)
+        table = table_kind.fixture(spec)
+        cols = table_kind.make(spec, SEED, ROWS)
+        table_kind.load(client, node.store_id, table, cols)
+        ctxs[name] = types.SimpleNamespace(table=table, rows=ROWS,
+                                           cols=cols)
+    # the client's fan-out workers are made as its fan-outs need them
+    # and kept: all of them before the first test, for conftest's
+    # thread-leak guard
+    gate = threading.Barrier(16)
+    for _ in range(15):
+        client._fanout_executor(15).submit(gate.wait)
+    gate.wait()
+    yield types.SimpleNamespace(
+        node=node, runner=runner, client=client, pd_addr=pd_addr,
+        ctxs=ctxs, TxnClient=TxnClient,
+        status_port=srv.status_server.port)
+    client.close()
+    srv.stop()
+    pd_server.stop()
+
+
+def read(store, kind, params, name, client=None) -> tuple:
+    """One read as ``benchmark/loadgen.py request()`` records it →
+    (record, reply)."""
+    client = client or store.client
+    ctx = store.ctxs[name]
+    resp = kind.send(ctx, client, kind.prepare(ctx, client, params))
+    td = resp.get("time_detail", {})
+    labels, phases = td.get("labels", {}), td.get("phases_ms", {})
+    rec = {"labels": labels, "phases_ms": phases,
+           "trace_id": resp.get("trace_id"),
+           "ok": resp.get("backend") == "device" and
+           "degraded" not in labels and "host_exec" not in phases}
+    if rec["ok"]:
+        rec["answer"] = kind.digest(ctx, resp, params)
+    return rec, resp
+
+
+def failing(checks) -> list:
+    return [name for name, value, limit in checks if value > limit]
+
+
+def health(store) -> dict:
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{store.status_port}/health", timeout=30) as r:
+        return json.loads(r.read())
+
+
+# ------------------------------------------------- the files of the cell
+
+
+def test_the_cells_files_agree_on_the_layout(table_kind, params):
+    config = load_config()
+    with open(os.path.join(BENCH, "traffic", f"{CELL}.json")) as f:
+        traffic = json.load(f)
+    rows = config["table"]["rows"]
+    assert params["regions"] == N == \
+        len(table_kind.boundaries(rows, N)) + 1
+    assert params["concurrency"] == 15
+    assert traffic["main_kernel"]["rows_per_launch"] == -(-rows // N)
+    # N is what the store's own estimate of the loaded table gives at
+    # the split size, and every region reads under it
+    measured = config["measured"]
+    assert N == -(-measured["table_bytes"] // (96 << 20))
+    assert max(measured["region_bytes"]) < 96 << 20
+    assert len(measured["region_bytes"]) == N
+    with open(os.path.join(BENCH, "configs", "int3-10m.json")) as f:
+        base = json.load(f)
+    for key in ("isolation", "exactness", "freshness", "durability"):
+        assert config["guarantees"][key] == base["guarantees"][key]
+    assert "layout" in config["guarantees"]
+    assert sorted(config["reduced"]) == ["replicas", "rows"]
+    assert {k: v for k, v in config["table"].items()
+            if k in ("rows", "columns")} == \
+        {k: v for k, v in base["table"].items() if k in ("rows", "columns")}
+
+
+def test_presplit_layout_is_what_the_store_holds(store, table_kind):
+    """PD lists N regions over each table, cut at the row boundaries,
+    each led and sized under the limit by the store's split checker."""
+    from tikv_tpu.storage.txn_types import encode_key
+    for name in ("dense", "sparse"):
+        ctx = store.ctxs[name]
+        got = table_kind.table_regions(store.client, ctx.table)
+        assert len(got) == N and all(ld is not None for _r, ld in got)
+        cuts = [encode_key(table_record_key(ctx.table.table_id, h))
+                for h in table_kind.boundaries(ROWS, N)]
+        assert [r.start_key for r, _ld in got[1:]] == cuts
+        assert [r.end_key for r, _ld in got[:-1]] == cuts
+        sizes = table_kind.store_sizes(store.client, store.node.store_id,
+                                       {r.id for r, _ld in got})
+        assert len(sizes) == N
+        assert all(0 < s < SPLIT_MB << 20 for s in sizes.values()), sizes
+
+
+# ------------------------------------------------- served path vs reference
+
+
+@pytest.mark.parametrize("name", ["dense", "sparse"])
+def test_fanned_out_answers_equal_the_numpy_reference(store, kind, params,
+                                                      name):
+    """Every answer of four concurrent closed-loop sessions equals the
+    whole-table reference exactly, each read answered by N cop tasks,
+    every one on the device path."""
+    ctx = store.ctxs[name]
+    records, errors = [], []
+
+    def session():
+        client = store.TxnClient(store.pd_addr)
+        try:
+            for _ in range(3):
+                records.append(read(store, kind, params, name, client)[0])
+        except Exception as e:      # noqa: BLE001 — reported below
+            errors.append(e)
+        finally:
+            client.close()
+
+    threads = [threading.Thread(target=session) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors, errors
+    assert len(records) == 12 and all(r["ok"] for r in records), \
+        [r["labels"] for r in records if not r["ok"]]
+    checks = kind.check(ctx, records, params, kind.reference(ctx, params))
+    assert checks == [("hash_agg.wrong_answers", 0, 0),
+                      ("regions.reads_off_the_layout", 0, 0)]
+    assert not any(r.get("wrong") for r in records)
+    assert {r["labels"]["cop_tasks"] for r in records} == {str(N)}
+    want = kind.reference(ctx, params)
+    assert len(want) == 1024
+    assert np.frombuffer(records[0]["answer"], np.int64).reshape(-1, 3) \
+        .tolist() == want.tolist()
+
+
+@pytest.mark.parametrize("name", ["dense", "sparse"])
+def test_the_control_is_caught(store, kind, params, name):
+    """Sums served in bfloat16 (``benchmark/control.py``'s record: an
+    answer alone) fail the cell by the answer, not by the layout."""
+    ctx = store.ctxs[name]
+    served = {"answer": kind.reference(ctx, params, approx=True).tobytes()}
+    checks = kind.check(ctx, [served], params, kind.reference(ctx, params))
+    assert failing(checks) == ["hash_agg.wrong_answers"]
+    assert served["wrong"] is True
+
+
+@pytest.mark.parametrize("name", ["dense", "sparse"])
+def test_share_test_partials_add_up_to_the_whole(store, kind, table_kind,
+                                                 params, name):
+    """Each region's partial, computed alone over its rows in numpy, is
+    what its cop task served (the replies come in range order); merged,
+    they are the whole-table reference."""
+    ctx = store.ctxs[name]
+    edges = [0] + table_kind.boundaries(ROWS, N) + [ROWS]
+    alone = []
+    for lo, hi in zip(edges, edges[1:]):
+        part = types.SimpleNamespace(
+            rows=hi - lo, cols={c: v[lo:hi] for c, v in ctx.cols.items()})
+        alone.append(kind.reference(part, params))
+    assert kind.merge(alone).tolist() == \
+        kind.reference(ctx, params).tolist()
+    _rec, resp = read(store, kind, params, name)
+    assert resp["tasks"] == len(resp["responses"]) == N
+    for task, want in zip(resp["responses"], alone):
+        got = np.array(task["rows"], dtype=np.int64).reshape(-1, 3)
+        assert got[np.argsort(got[:, 2], kind="stable")].tolist() == \
+            want.tolist()
+
+
+# ------------------------------------------------- what a read's summary says
+
+
+def test_summary_is_shaped_like_one_reply(store, kind, params):
+    before = health(store)["coprocessor"]["requests_served"]
+    rec, resp = read(store, kind, params, "dense")
+    assert rec["ok"]
+    assert resp["backend"] == "device" and resp["tasks"] == N
+    td = resp["time_detail"]
+    for phase in ("fanout_cut", "fanout_tasks", "fanout_straggler",
+                  "fanout_task", "device_dispatch"):
+        assert phase in td["phases_ms"], (phase, td["phases_ms"])
+    assert td["phases_ms"]["fanout_tasks"] >= \
+        td["phases_ms"]["fanout_task"] > 0
+    assert td["phases_ms"]["fanout_straggler"] >= 0
+    assert td["total_rpc_wall_ms"] > 0
+    assert td["labels"]["cop_tasks"] == str(N)
+    assert "fanout_retries" not in td["labels"]
+    # the summary's trace is the critical task's: one the store's buffer
+    # retains, with the launch and its compile class in it
+    # (loadgen.py probe() fetches it and takes a 404 for a crash)
+    ids = {r["trace_id"] for r in resp["responses"]}
+    assert len(ids) == N and resp["trace_id"] in ids
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{store.status_port}/debug/trace/"
+            f"{resp['trace_id']}", timeout=30) as r:
+        trace = json.loads(r.read())
+    launches = [s for s in trace["spans"] if s["name"] == "device_dispatch"]
+    assert launches and "compile_class" in launches[0]["attrs"]
+    assert health(store)["coprocessor"]["requests_served"] == before + N
+    from tikv_tpu.utils.metrics import GRPC_MSG_COUNTER
+    assert health(store)["coprocessor"]["requests_served"] == sum(
+        GRPC_MSG_COUNTER.labels("Coprocessor", st).value
+        for st in ("ok", "err"))
+
+
+def test_warm_tasks_take_the_compiled_fast_path(store, kind, params):
+    """A task's region context is fixed bytes of its wire template: the
+    repeats of a region's task hit the fast path as a one-region read
+    does, a class a region."""
+    for _ in range(3):
+        read(store, kind, params, "dense")
+    fp = store.node.fastpath.stats()
+    before = fp["hit"]
+    rec, _resp = read(store, kind, params, "dense")
+    assert rec["ok"] and rec["labels"].get("fastpath") == "hit"
+    assert store.node.fastpath.stats()["hit"] == before + N
+
+
+def test_a_task_on_the_host_fails_the_whole_read(store, kind, params):
+    """One task that the runner's host rung served shows in the summary
+    (label ``degraded``, phase ``host_exec``), so ``loadgen.py
+    request()`` does not count the read."""
+    read(store, kind, params, "dense")
+    failpoint.cfg("device::before_dispatch", "1*return->off")
+    try:
+        rec, resp = read(store, kind, params, "dense")
+    finally:
+        failpoint.teardown()
+    assert not rec["ok"]
+    assert "degraded" in rec["labels"] and "host_exec" in rec["phases_ms"]
+    # the answer is still exact: the host rung serves the same rows
+    ctx = store.ctxs["dense"]
+    assert kind.digest(ctx, resp, params) == \
+        kind.reference(ctx, params).tobytes()
+
+
+def test_a_stale_region_context_is_refused(store, kind, params):
+    """The store serves a task only from the region and epoch it was
+    cut for; without a context it serves as it always did."""
+    ctx = store.ctxs["dense"]
+    dag, _c = kind.prepare(ctx, store.client, params)
+    region, leader = store.client._lookup_region(dag.ranges[0].start)
+    req = {"tp": 103, "dag": wire.enc_dag(dag), "force_backend": None,
+           "paging_size": 0, "resume_token": None,
+           "resource_group": "default", "request_source": ""}
+    stale = dict(req, context={"region_id": region.id,
+                               "version": region.epoch.version + 1})
+    with pytest.raises(wire.RemoteError) as e:
+        store.client._store_call(leader.store_id, "Coprocessor", stale, 30)
+    assert e.value.kind == "epoch_not_match"
+    assert e.value.err["current"]["id"] == region.id
+    other = dict(req, context={"region_id": region.id + 1000,
+                               "version": region.epoch.version})
+    with pytest.raises(wire.RemoteError) as e:
+        store.client._store_call(leader.store_id, "Coprocessor", other, 30)
+    assert e.value.kind == "epoch_not_match"
+    ok = dict(req, context=wire.enc_region_ctx(region))
+    assert "rows" in store.client._store_call(
+        leader.store_id, "Coprocessor", ok, 30)
+    assert "rows" in store.client._store_call(
+        leader.store_id, "Coprocessor", req, 30)
+
+
+# ------------------------------------------------- a split under a read
+
+
+def test_split_between_cut_and_send_is_recut_and_exact(store, kind, params):
+    """A region splits after the client cached its bounds: the task cut
+    for the old epoch is refused (``epoch_not_match``), its ranges are
+    cut again, the answer is exact and the read took N + 1 tasks, which
+    the cell's check then fails: the layout is part of the result."""
+    ctx = store.ctxs["split"]
+    stale = store.TxnClient(store.pd_addr)
+    rec, _resp = read(store, kind, params, "split", stale)
+    assert rec["ok"] and rec["labels"]["cop_tasks"] == str(N)
+    # another client splits the third region in its middle
+    per = -(-ROWS // N)
+    store.client.split(table_record_key(ctx.table.table_id,
+                                        2 * per + per // 2))
+    rec, resp = read(store, kind, params, "split", stale)
+    assert rec["ok"], rec
+    assert resp["tasks"] == N + 1
+    assert rec["labels"]["cop_tasks"] == str(N + 1)
+    assert rec["labels"]["fanout_retries"] == "1"
+    want = kind.reference(ctx, params)
+    assert rec["answer"] == want.tobytes()
+    checks = kind.check(ctx, [rec], params, want)
+    assert checks == [("hash_agg.wrong_answers", 0, 0),
+                      ("regions.reads_off_the_layout", 1, 0)]
+    assert rec["wrong"] is True
+    # the client has learned the new layout: no task is refused again
+    rec, _resp = read(store, kind, params, "split", stale)
+    stale.close()
+    assert rec["labels"]["cop_tasks"] == str(N + 1)
+    assert "fanout_retries" not in rec["labels"]
+
+
+def test_a_region_under_the_row_threshold_fails_the_read(store, kind,
+                                                         params):
+    """Every region must clear ``device-row-threshold``: a task whose
+    region holds fewer rows is served by the host pipeline, and the
+    summary's backend is then not ``device``."""
+    ctx = store.ctxs["tiny"]
+    store.client.split(table_record_key(ctx.table.table_id,
+                                        ROWS - THRESHOLD // 2))
+    rec, resp = read(store, kind, params, "tiny")
+    assert resp["tasks"] == N + 1
+    assert resp["backend"] == "host" and not rec["ok"]
+    assert [r["backend"] for r in resp["responses"]] == \
+        ["device"] * N + ["host"]
+    assert kind.digest(ctx, resp, params) == \
+        kind.reference(ctx, params).tobytes()
+
+
+def test_locked_key_is_the_callers(store, kind, params):
+    """``key_is_locked`` rises to the caller as from ``coprocessor``."""
+    from tikv_tpu.testing.fixture import encode_table_row
+    ctx = store.ctxs["dense"]
+    key, value = encode_table_row(ctx.table, 7, {"c0": 1, "c1": 1})
+    client = store.client
+    start_ts = client.tso()
+    client._call_leader(key, "KvPrewrite", {
+        "mutations": [{"op": "put", "key": key, "value": value}],
+        "primary": key, "start_version": start_ts})
+    try:
+        with pytest.raises(wire.RemoteError) as e:
+            read(store, kind, params, "dense")
+        assert e.value.kind == "key_is_locked"
+    finally:
+        client._call_leader(key, "KvBatchRollback", {
+            "keys": [key], "start_version": start_ts})
+    rec, _resp = read(store, kind, params, "dense")
+    assert rec["ok"]
+
+
+# ------------------------------------------------- the layout wait
+
+
+def test_layout_wait_raises_with_the_sizes(store, table_kind, monkeypatch):
+    """A region that stays over the limit the layout was cut for ends
+    the load, loudly and inside its bound, with the sizes it saw."""
+    spec = table_spec("unsettled")
+    spec["region_split_size_mb"] = 0.05     # ~52 KB: under a region's size
+    table = table_kind.fixture(spec)
+    cols = table_kind.make(spec, SEED, ROWS)
+    monkeypatch.setattr(table_kind, "LAYOUT_WAIT_S", 2.0)
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError) as e:
+        table_kind.load(store.client, store.node.store_id, table, cols)
+    assert time.monotonic() - t0 < 60
+    said = str(e.value)
+    assert "split checker" in said and "sizes" in said and \
+        "limit_bytes" in said, said
+
+
+def test_a_store_without_the_size_estimate_fails_at_once(store, table_kind,
+                                                         monkeypatch):
+    """The parent commit's Status lists no ``approximate_size``: the load
+    says so before it splits or ingests anything."""
+    real = store.client.status
+
+    def old_status(store_id):
+        st = real(store_id)
+        for r in st["regions"]:
+            del r["approximate_size"]
+        return st
+    monkeypatch.setattr(store.client, "status", old_status)
+    spec = table_spec("unsettled")
+    table = table_kind.fixture(spec)
+    regions = len(real(store.node.store_id)["regions"])
+    with pytest.raises(RuntimeError, match="approximate_size"):
+        table_kind.load(store.client, store.node.store_id, table,
+                        table_kind.make(spec, SEED, 1000))
+    assert len(real(store.node.store_id)["regions"]) == regions
+
+
+# ------------------------------------------------- loadgen.py, as run.py runs it
+
+
+def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
+    """``benchmark/loadgen.py`` itself, as a child with the ``warm`` /
+    ``go`` / ``done`` hand-shake of ``run.py``, over the cell's own
+    traffic file and its configuration (the table's id apart): the table
+    kind's load, the first read, the probes (each fetches its trace),
+    the warm rounds, a window of one second, the check."""
+    config = load_config()
+    config["table"]["table_id"] = TABLE_IDS["loadgen"]
+    config_file = tmp_path / "config.json"
+    config_file.write_text(json.dumps(config))
+    out = tmp_path / "result.json"
+    spec_file = tmp_path / "spec.json"
+    spec_file.write_text(json.dumps({
+        "pd_addr": store.pd_addr, "status_port": store.status_port,
+        "seed": SEED, "seconds": 1, "rows": ROWS,
+        "config_file": str(config_file),
+        "traffic_file": os.path.join(BENCH, "traffic", f"{CELL}.json"),
+        "out": str(out), "on_tpu": False}))
+    child = subprocess.Popen(
+        [sys.executable, os.path.join(BENCH, "loadgen.py"), str(spec_file)],
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True, cwd=ROOT,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    try:
+        timer = threading.Timer(240, child.kill)
+        timer.start()
+        try:
+            first = child.stdout.readline()
+            assert first.startswith("warm "), (first, child.poll())
+            warm = json.loads(first[len("warm "):])
+            assert warm["failed"] == 0, warm
+            child.stdin.write("go\n")
+            child.stdin.flush()
+            assert child.stdout.readline().strip() == "done"
+            assert child.wait(timeout=60) == 0
+        finally:
+            timer.cancel()
+    finally:
+        if child.poll() is None:
+            child.kill()
+            child.wait()
+        child.stdin.close()
+        child.stdout.close()
+    result = json.loads(out.read_text())
+    assert result["warm_failed"] == 0
+    assert result["checks"] == [["hash_agg.wrong_answers", 0, 0],
+                                ["regions.reads_off_the_layout", 0, 0]]
+    assert result["records"] and all(r["ok"] for r in result["records"]), \
+        [r["why"] for r in result["records"] if not r["ok"]][:3]
+    assert all(r["ok"] for r in result["last"])
+    assert all(r["labels"]["cop_tasks"] == str(N)
+               for r in result["records"])
+    go, end = result["counters_go"], result["counters_end"]
+    tasks = end["health"]["coprocessor"]["requests_served"] - \
+        go["health"]["coprocessor"]["requests_served"]
+    assert tasks >= N * len(result["records"])
+    assert end["flight_recorder"]["launches"] > \
+        go["flight_recorder"]["launches"]

@@ -62,6 +62,12 @@ class CopRequest:
     # dict here and the endpoint/node fill in what the execution
     # learned (storage, backend, route decision, batch key, region)
     fp_learn: Optional[dict] = None
+    # kvproto Context.region_id / region_epoch.version: the region the
+    # client cut this task's ranges for.  When set, the node refuses
+    # (EpochNotMatch) to serve from any other region or epoch — a task
+    # clipped to bounds that a split has since moved would otherwise be
+    # answered, in silence, with only the rows the new region holds
+    region_ctx: Optional[tuple] = None
 
 
 @dataclass

@@ -170,12 +170,18 @@ class TxnClient:
         # once and reused until a NotLeader/EpochNotMatch invalidates it
         # — without it every mutation in a batch pays a PD RPC
         self._region_cache: dict[int, tuple[Region, Peer]] = {}
+        # a fan-out's tasks re-route on threads of their own: one at a
+        # time walks or drops the cached routes
+        self._route_mu = threading.Lock()
         from ..utils.health import CircuitBreaker
         self.hedge_reads = hedge_reads
         self._breaker_cfg = (breaker_threshold, breaker_cooldown_s)
         self._breakers: dict[int, CircuitBreaker] = {}
         self._hedge_pool = None
         self._hedge_mu = threading.Lock()
+        # the fan-out's workers, made at the first fan-out and kept (a
+        # thread a task a read would be most of a small read's cost)
+        self._fanout_pool = None
         # recent point-read latencies (seconds) → adaptive P95 delay
         self._read_lat: list[float] = []
         self.hedges_fired = 0
@@ -484,12 +490,15 @@ class TxnClient:
             return self._hedge_pool
 
     def close(self) -> None:
-        """Release the hedge executor's threads (tests / short-lived
-        clients)."""
+        """Release the hedge and fan-out executors' threads (tests /
+        short-lived clients)."""
         with self._hedge_mu:
             if self._hedge_pool is not None:
                 self._hedge_pool.shutdown(wait=False)
                 self._hedge_pool = None
+            if self._fanout_pool is not None:
+                self._fanout_pool[1].shutdown(wait=False)
+                self._fanout_pool = None
 
     def replica_get(self, key: bytes,
                     version: Optional[int] = None,
@@ -724,6 +733,192 @@ class TxnClient:
             except Exception:   # noqa: BLE001 — try the other leg
                 continue
         return f_leader.result(timeout=timeout + 1)
+
+    # -- coprocessor fan-out (client-go CopClient / copIterator) --
+
+    # replies that say the task went to the wrong place or was cut for
+    # a region that has changed: drop the route, cut again, send again
+    _REROUTE_KINDS = ("not_leader", "epoch_not_match", "region_not_found",
+                      "region_merging")
+
+    def coprocessor_fanout(self, dag, concurrency: int = 15,
+                           resource_group: str = "default",
+                           request_source: str = "",
+                           timeout: float = 60) -> dict:
+        """``dag`` over every region its ranges touch, as TiDB's copr
+        client reads a table: one cop task a region with the ranges
+        clipped to the region's bounds, at most ``concurrency`` in
+        flight (``tidb_distsql_scan_concurrency``'s default), each sent
+        to its region's leader under the region and epoch it was cut
+        for.  A task whose region changed under it (``epoch_not_match``,
+        ``region_not_found``, ``not_leader``) has ITS ranges cut again
+        and re-sent inside ``timeout``; ``key_is_locked`` and anything
+        else is the caller's, as from ``coprocessor``.
+
+        → one summary shaped like a single reply, with the partial
+        replies under ``responses`` in range order (merging partial
+        aggregates is the SQL layer's) and their count under ``tasks``:
+        ``backend`` is ``"device"`` only if every task's was;
+        ``time_detail.labels`` is the union of the tasks' (plus
+        ``cop_tasks``, and ``fanout_retries`` where a task was cut
+        again); ``phases_ms`` / ``total_rpc_wall_ms`` / ``trace_id`` are
+        those of the task that returned last, the read's critical path
+        (each task has a server-minted trace id of its own: the store's
+        trace buffer keeps one tracker an id), with any task's
+        ``host_exec`` and the client's own phases added: ``fanout_cut``,
+        ``fanout_tasks``, ``fanout_straggler``, ``fanout_task``
+        (utils/trace_vocab.py)."""
+        import dataclasses
+        import statistics
+        from ..utils import tracker
+        # everything of the request but a task's ranges and region,
+        # encoded once (wire.enc_dag's keys, in coprocessor()'s order)
+        env = {"tp": 103,
+               "dag": wire.enc_dag(dataclasses.replace(dag, ranges=())),
+               "force_backend": None,
+               "paging_size": 0, "resume_token": None,
+               "resource_group": resource_group,
+               "request_source": request_source}
+        tr, tok = tracker.install(sampled=False)
+        try:
+            with tracker.phase("fanout_cut"):
+                tasks = self._cut_by_region(dag.ranges)
+            pool = self._fanout_executor(concurrency)
+            futs = [pool.submit(self._run_cop_task, task, env, timeout)
+                    for task in tasks]
+            try:
+                ran = [f.result() for f in futs]
+            except BaseException:
+                for f in futs:
+                    f.cancel()
+                raise
+            parts = [part for got, _n in ran for part in got]
+            if not parts:
+                raise TxnError("coprocessor fan-out over no ranges")
+            back = [b for _r, _s, b in parts]
+            crit = parts[back.index(max(back))][0]
+            tracker.add_phase("fanout_tasks",
+                              max(back) - min(s for _r, s, _b in parts))
+            tracker.add_phase("fanout_straggler",
+                              max(back) - statistics.median(back))
+            tracker.add_phase("fanout_task", statistics.median(
+                b - s for _r, s, b in parts))
+        finally:
+            tracker.uninstall(tok)
+        replies = [r for r, _s, _b in parts]
+        detail = dict(crit.get("time_detail", {}))
+        phases = dict(detail.get("phases_ms", {}))
+        labels: dict = {}
+        backends = set()
+        for r in replies:
+            td = r.get("time_detail", {})
+            labels.update(td.get("labels", {}))
+            if "host_exec" in td.get("phases_ms", {}):
+                phases.setdefault("host_exec", td["phases_ms"]["host_exec"])
+            backends.add(r.get("backend"))
+        phases.update(tr.time_detail()["phases_ms"])
+        labels["cop_tasks"] = str(len(replies))
+        retries = sum(n for _got, n in ran)
+        if retries:
+            labels["fanout_retries"] = str(retries)
+        detail["phases_ms"], detail["labels"] = phases, labels
+        return {"responses": replies, "tasks": len(replies),
+                "backend": "device" if backends == {"device"}
+                else sorted(str(b) for b in backends - {"device"})[0],
+                "time_detail": detail, "trace_id": crit.get("trace_id")}
+
+    def _fanout_executor(self, concurrency: int):
+        """This client's fan-out workers: ``concurrency`` of them, so
+        that many of its cop tasks are in flight at most (callers that
+        share a client share the bound).  Asked for another width, the
+        pool is made anew; tasks the old one holds still finish."""
+        import concurrent.futures as cf
+        with self._hedge_mu:
+            if self._fanout_pool is None or \
+                    self._fanout_pool[0] != concurrency:
+                if self._fanout_pool is not None:
+                    self._fanout_pool[1].shutdown(wait=False)
+                self._fanout_pool = (concurrency, cf.ThreadPoolExecutor(
+                    max_workers=max(1, concurrency),
+                    thread_name_prefix="copr-fanout"))
+            return self._fanout_pool[1]
+
+    def _cut_by_region(self, ranges) -> list:
+        """``ranges`` cut at the bounds of the regions they touch, found
+        through the region cache → [(region, leader, (KeyRange, ...))]
+        in range order; neighbouring pieces of one region share a
+        task."""
+        from ..executors.ranges import KeyRange
+        from ..storage.txn_types import decode_key
+        tasks: list = []
+        with self._route_mu:
+            for r in ranges:
+                start = r.start
+                while start < r.end:
+                    region, leader = self._lookup_region(start)
+                    # region bounds are engine keys (_lookup_region)
+                    bound = decode_key(region.end_key) \
+                        if region.end_key else None
+                    end = r.end if bound is None else min(r.end, bound)
+                    if end <= start:
+                        raise TxnError(f"region {region.id} does not "
+                                       f"hold {start!r}")
+                    if tasks and tasks[-1][0].id == region.id:
+                        tasks[-1][2].append(KeyRange(start, end))
+                    else:
+                        # workers only read these two tables
+                        self._store_client(leader.store_id)
+                        self._breaker(leader.store_id)
+                        tasks.append((region, leader,
+                                      [KeyRange(start, end)]))
+                    start = end
+        return [(region, leader, tuple(pieces))
+                for region, leader, pieces in tasks]
+
+    def _run_cop_task(self, task, env: dict, timeout: float) -> tuple:
+        """One cop task to its region's leader → ([(reply, sent_ns,
+        back_ns)], times it was cut again): more than one reply where
+        the region had changed under the task."""
+        from ..utils.backoff import Backoff
+        from ..utils.failpoint import fail_point
+        from ..utils.health import CircuitOpen
+        bo = Backoff(base=0.02, cap=0.5, deadline_s=timeout)
+        todo, out, recuts = [task], [], 0
+        while todo:
+            region, leader, ranges = todo.pop(0)
+            req = dict(env, dag=dict(env["dag"],
+                                     ranges=wire.enc_ranges(ranges)),
+                       context=wire.enc_region_ctx(region))
+            sent = time.perf_counter_ns()
+            try:
+                resp = self._store_call(leader.store_id, "Coprocessor",
+                                        req, timeout=bo.rpc_timeout(timeout))
+            except wire.RemoteError as e:
+                if e.kind == "server_is_busy":
+                    # overloaded, not misrouted (_call_leader)
+                    hint = e.err.get("retry_after_ms")
+                    if not bo.sleep(hint_s=hint / 1000.0 if hint else None):
+                        raise
+                    todo.insert(0, (region, leader, ranges))
+                    continue
+                if e.kind not in self._REROUTE_KINDS and \
+                        "KeyNotInRegion" not in str(e):
+                    raise
+                last = e
+            except CircuitOpen as e:
+                last = e
+            else:
+                out.append((resp, sent, time.perf_counter_ns()))
+                continue
+            recuts += 1
+            with self._route_mu:
+                for r in ranges:
+                    self._invalidate_region(r.start)
+            fail_point("client::before_retry")
+            if not bo.sleep():
+                raise last
+            todo[:0] = self._cut_by_region(ranges)
+        return out, recuts
 
     def coprocessor_replica(self, dag, key_hint: Optional[bytes] = None,
                             resource_group: str = "default",

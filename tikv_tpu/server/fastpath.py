@@ -80,6 +80,7 @@ from typing import Callable, Optional
 from ..datatype import device_const_dtype
 from ..utils.failpoint import fail_point
 from ..utils.metrics import COPR_FASTPATH_COUNTER
+from . import wire
 
 # slot kinds
 K_CONST = "const"            # int/float predicate/aggregate constant
@@ -346,7 +347,10 @@ class WireTemplate:
 # resolved-ts gate, so follower stale reads always take the full path)
 _ALLOWED_REQ_KEYS = frozenset((
     "tp", "dag", "force_backend", "paging_size", "resume_token",
-    "resource_group", "request_source", "deadline_ms", "trace_id"))
+    "resource_group", "request_source", "deadline_ms", "trace_id",
+    # a fan-out task's region and epoch (wire.enc_region_ctx): fixed
+    # bytes of its template, so a class is learned per region and epoch
+    "context"))
 
 # plan-IR request envelope: same eligibility rules, "plan" body
 _ALLOWED_PLAN_KEYS = frozenset((
@@ -618,13 +622,16 @@ class _ClassEntry:
         "trace_class", "range_start", "resource_group",
         "request_source", "tag", "key_hint", "ranges", "base_key",
         "storage_ref", "config_gen", "bkey", "share_fill", "n_est",
-        "d2h_bytes", "hits", "invalidated")
+        "d2h_bytes", "hits", "invalidated", "region_ctx")
 
     def __init__(self):
         self.hits = 0
         self.invalidated = None     # reason str once dead
         self.tier = "dispatch"
         self.make_plan = None
+        # the learned request's region context: a hit that falls back
+        # to the full ceremony is still held to it (node._copr_snapshot)
+        self.region_ctx = None
 
     def storage(self):
         ref = self.storage_ref
@@ -812,6 +819,7 @@ class FastPathCache:
         from .node import encode_first
         ent.key_hint = encode_first(ent.range_start or b"")
         ent.ranges = dag.ranges
+        ent.region_ctx = wire.dec_region_ctx(req.get("context"))
         scan = dag.executors[0]
         region = info.get("region")
         epoch_ver = info.get("epoch_version")
@@ -921,6 +929,7 @@ class FastPathCache:
         ent.trace_class = ent.class_key
         ent.range_start = dag.ranges[0].start if dag.ranges else None
         ent.ranges = dag.ranges
+        ent.region_ctx = wire.dec_region_ctx(req.get("context"))
         self._learn_common(ent, req)
         self._admit(ent)
         return True

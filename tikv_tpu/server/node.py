@@ -1048,6 +1048,14 @@ class Node:
         with tracker.phase("snapshot"):
             snap = self.raft_kv.snapshot(
                 SnapContext(key_hint=key_hint, stale_read=stale))
+        ctx = getattr(req, "region_ctx", None)
+        if ctx is not None and \
+                (snap.region.id, snap.region.epoch.version) != tuple(ctx):
+            # a fan-out task (TxnClient.coprocessor_fanout) names the
+            # region and epoch its ranges were clipped to: served from
+            # another, it would silently lose the rows a split moved
+            from ..raftstore.metapb import EpochNotMatch
+            raise EpochNotMatch(snap.region)
         execs = req.dag.executors
         if execs and isinstance(execs[0], TableScanDesc):
             # the replica leg labels its cache access as replica_patch:
@@ -1455,6 +1463,10 @@ class Node:
                      "leader": p.is_leader(),
                      "term": p.node.term,
                      "applied": p.node.applied,
+                     # the split checker's last estimate (0 = not
+                     # scanned yet): a loader waits on it for a layout
+                     # the checker has nothing left to do with
+                     "approximate_size": p.approximate_size,
                      "resolved_ts": self.resolved_ts.resolver(
                          p.region.id).resolved_ts}
                     for p in self.raft_store.peers.values()],

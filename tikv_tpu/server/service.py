@@ -274,7 +274,8 @@ class KvService:
                         request_source=ent.request_source)
                 creq = CopRequest(REQ_TYPE_DAG, dag,
                                   resource_group=ent.resource_group,
-                                  request_source=ent.request_source)
+                                  request_source=ent.request_source,
+                                  region_ctx=ent.region_ctx)
                 if ent.tier == "decode":
                     # decode tier: only the wire decode is skipped —
                     # the full ceremony (snapshot, routing, freshness)
@@ -778,7 +779,8 @@ class KvService:
             resource_group=req.get("resource_group", "default"),
             request_source=req.get("request_source", ""),
             stale_read=req.get("stale_read", False),
-            fp_learn=learn)
+            fp_learn=learn,
+            region_ctx=wire.dec_region_ctx(req.get("context")))
         # dispatch under the read-pool slot, await outside it: handle()
         # resolves the "__deferred" marker after the slot is released
         d = self.endpoint.handle_async(creq)

@@ -89,6 +89,15 @@ class StatusServer:
                         # occupancy, router decision mix, solo-degrade
                         # count
                         body["coalescer"] = coal.stats()
+                    # Coprocessor RPCs this process has answered, ok
+                    # or error, on either serving leg: /metrics'
+                    # tikv_grpc_msg_total{method="Coprocessor"}, as one
+                    # count two samples can difference (a fan-out read
+                    # is one of these a region)
+                    from ..utils.metrics import GRPC_MSG_COUNTER
+                    body["coprocessor"] = {"requests_served": int(sum(
+                        GRPC_MSG_COUNTER.labels("Coprocessor", st).value
+                        for st in ("ok", "err")))}
                     fp = getattr(node, "fastpath", None)
                     if fp is not None and hasattr(fp, "stats"):
                         # microsecond warm path: learned wire-template

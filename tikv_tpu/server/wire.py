@@ -76,6 +76,17 @@ def dec_region(d: dict) -> Region:
                   tuple(dec_peer(p) for p in d["peers"]))
 
 
+def enc_region_ctx(r: Region) -> dict:
+    """kvproto Context.region_id + region_epoch.version of a read cut
+    for region ``r`` (a conf change moves no key, so conf_ver stays
+    out, as upstream's epoch check leaves it out for reads)."""
+    return {"region_id": r.id, "version": r.epoch.version}
+
+
+def dec_region_ctx(d) -> tuple | None:
+    return None if d is None else (d["region_id"], d["version"])
+
+
 # -- raft messages (eraftpb analog) --
 
 def enc_raft_msg(m: Message) -> dict:
@@ -289,11 +300,15 @@ def enc_dag(dag) -> dict:
         else:   # pragma: no cover
             raise ValueError(ex)
     return {"execs": execs,
-            "ranges": [{"s": r.start, "e": r.end} for r in dag.ranges],
+            "ranges": enc_ranges(dag.ranges),
             "start_ts": dag.start_ts,
             "output_offsets": list(dag.output_offsets)
             if dag.output_offsets is not None else None,
             "encode_type": dag.encode_type}
+
+
+def enc_ranges(ranges) -> list:
+    return [{"s": r.start, "e": r.end} for r in ranges]
 
 
 def dec_dag(d: dict):

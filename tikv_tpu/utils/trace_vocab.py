@@ -38,6 +38,15 @@ SPAN_VOCABULARY: dict[str, str] = {
     "await_deferred": "service thread parked on the deferred device "
                       "completion (decomposed by completion-side spans)",
     "resp_serialize": "SelectResult rows → wire response encode",
+    # -- client fan-out (server/client.py coprocessor_fanout; on the
+    # client's clock, in the summary's phases_ms) --
+    "fanout_cut": "region lookup through the client's region cache and "
+                  "the cut of the request's ranges into cop tasks",
+    "fanout_tasks": "first cop task sent → last partial reply back",
+    "fanout_straggler": "the last task's return minus the median "
+                        "task's: what the read waited for its slowest "
+                        "region",
+    "fanout_task": "median over the read's cop tasks of send → reply",
     # -- storage / host pipeline --
     "kv_read": "point/scan MVCC read through Storage",
     "snapshot": "raft lease read + engine snapshot acquisition",
