@@ -626,3 +626,475 @@ def test_readpool_class_keyed_ewma(rig):
             dict(rp._class_ema)[sel_key]
     assert rp.class_ema("KvGet") > 0.0
     assert rp.stats()["ema_classes"] >= 3
+
+
+# ---------------------------------------------------- multi-lane launches
+#
+# Closed share groups of one launch class leave as the LANES of one
+# launch (module doc of server/coalescer.py).  The Pallas body runs here
+# in interpret mode, as in tests/test_pallas_hash_interpret.py: the
+# tests patch ``pl.pallas_call``, lift the runner's TPU gate on the
+# instance and shrink BLOCK; no product knob.
+
+LANE_BLOCK = 1 << 12
+
+
+@pytest.fixture
+def lane_runner(monkeypatch):
+    import functools
+
+    import jax
+
+    from tikv_tpu.device import pallas_hash
+    from tikv_tpu.parallel import make_mesh
+    monkeypatch.setattr(
+        pallas_hash.pl, "pallas_call",
+        functools.partial(pallas_hash.pl.pallas_call, interpret=True))
+    monkeypatch.setattr(pallas_hash, "BLOCK", LANE_BLOCK)
+    r = DeviceRunner(mesh=make_mesh(jax.devices()[:1]))
+    r._is_tpu = True            # lift the CPU gate (aggregate.agg_bodies)
+    r._block_local = LANE_BLOCK
+    return r
+
+
+def lane_table():
+    return Table(8700, (
+        TableColumn("id", 1, FieldType.long(not_null=True),
+                    is_pk_handle=True),
+        TableColumn("k", 2, FieldType.long(not_null=True)),
+        TableColumn("v", 3, FieldType.long(not_null=True))))
+
+
+def lane_snapshot(seed, n=3 * LANE_BLOCK + 100, groups=40, sparse=False):
+    """One 'region' of the lane table: its own handles, its own rows."""
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(0, groups, n).astype(np.int64)
+    if sparse:
+        keys = rng.integers(0, 1 << 62, groups, dtype=np.int64)[keys]
+    ones = np.ones(n, np.bool_)
+    return ColumnarTable.from_arrays(
+        lane_table(), np.arange(seed * 100_000, seed * 100_000 + n,
+                                dtype=np.int64),
+        {"k": Column(EvalType.INT, keys, ones),
+         "v": Column(EvalType.INT,
+                     rng.integers(-1000, 1000, n).astype(np.int64), ones)})
+
+
+def lane_dag(i, ranges=()):
+    """The cell's plan; ``start_ts`` says which snapshot it reads (the
+    rig's storage provider reads it back: a region id by other means)."""
+    import dataclasses
+    s = DagSelect.from_table(lane_table(), ["id", "k", "v"])
+    dag = s.aggregate([s.col("k")],
+                      [("count_star", None), ("sum", s.col("v"))]).build()
+    return dataclasses.replace(dag, start_ts=i + 1, ranges=tuple(ranges))
+
+
+class LaneRig:
+    """An endpoint over several snapshots whose dispatcher can be HELD:
+    while held, closed groups pile up in ``_ready`` exactly as they do
+    behind a busy dispatcher, and ``release`` lets it take them."""
+
+    def __init__(self, runner, snaps, window_ms=20.0, idle_bypass=False):
+        self.runner, self.snaps = runner, snaps
+        self.coal = RequestCoalescer(runner, window_ms=window_ms,
+                                     max_group=8)
+        self.coal.idle_bypass = idle_bypass
+        self.ep = Endpoint(lambda req: snaps[req.dag.start_ts - 1],
+                           device_runner=runner, device_row_threshold=1,
+                           coalescer=self.coal)
+        self._gate = threading.Event()
+        self._gate.set()
+        take = self.coal._take_fusable
+
+        def gated(g):
+            self._gate.wait(30)
+            return take(g)
+
+        self.coal._take_fusable = gated
+
+    def one(self, dag):
+        return self.ep.handle(CopRequest(REQ_TYPE_DAG, dag))
+
+    def warm(self):
+        """One read a snapshot: every line's kernel class is learnt."""
+        for i in range(len(self.snaps)):
+            self.one(lane_dag(i))
+
+    def together(self, dags, n_groups):
+        """Send ``dags`` at once with the dispatcher held until all
+        ``n_groups`` groups have closed behind the one it popped."""
+        import time
+        self._gate.clear()
+        out, errs = [None] * len(dags), []
+
+        def one(i):
+            try:
+                out[i] = self.one(dags[i])
+            except Exception as e:      # noqa: BLE001 — surfaced below
+                errs.append((i, e))
+
+        ts = [threading.Thread(target=one, args=(i,))
+              for i in range(len(dags))]
+        for t in ts:
+            t.start()
+        t_end = time.monotonic() + 10
+        while len(self.coal._ready) < n_groups - 1 and \
+                time.monotonic() < t_end:
+            time.sleep(0.002)
+        self._gate.set()
+        for t in ts:
+            t.join()
+        assert not errs, errs
+        return out
+
+    def wait_built(self):
+        """Until the builder threads have the kernel's lane programs."""
+        import time
+        t_end = time.monotonic() + 60
+        while time.monotonic() < t_end:
+            progs = [e.get("lane_progs") for key, e in
+                     self.runner._kernel_cache.items()
+                     if key[0] == "hashpl" and isinstance(e, dict)]
+            if progs and all(p and all(p.values()) for p in progs):
+                return
+            time.sleep(0.01)
+        raise AssertionError("lane programs not built")
+
+    def solo(self, i, ranges=()):
+        dag = lane_dag(i, ranges)
+        return sorted(BatchExecutorsRunner(
+            dag, self.snaps[i]).handle_request().rows())
+
+    def close(self):
+        self.ep.close()
+        assert self.runner._arena.pinned_bytes() == 0   # every pin, once
+
+
+def lanes_of(rig) -> dict:
+    st = rig.coal.stats()
+    return {k: st[k] for k in (
+        "lanes_hist", "multi_lane_launches", "lanes_sum", "groups_merged",
+        "same_lane_merges", "lane_class_mismatch", "unbuilt_fallbacks",
+        "solo_degrade")}
+
+
+@pytest.mark.parametrize("k,sparse", [(2, False), (3, False), (4, False),
+                                      (5, False), (6, False), (3, True)])
+def test_k_lane_answers_equal_the_solo_answers(lane_runner, k, sparse):
+    """k closed groups over k feeds of one compile class leave as ONE
+    launch (one flight-recorder entry, one Pallas call a lane) and every
+    lane's answer is its own snapshot's, exactly; two members of one key
+    share a lane.  Sparse slot-mode lanes fuse too (the slot plane is
+    one more input a lane)."""
+    rig = LaneRig(lane_runner, [lane_snapshot(s, sparse=sparse)
+                                for s in range(k)])
+    try:
+        rig.warm()
+        rig.wait_built()
+        dags = [lane_dag(i) for i in range(k)] + [lane_dag(1)]
+        rec = lane_runner.flight_recorder
+        before = rec.stats()["launches"]
+        out = rig.together(dags, k)
+        for got, i in zip(out, list(range(k)) + [1]):
+            assert sorted(got.rows()) == rig.solo(i), i
+            assert got.backend == "device"
+        # a kernel has programs of 2, 3 and 4 lanes: up to four lanes
+        # ONE launch, five and six two (4 + 1, 4 + 2)
+        first, rest = min(k, 4), k - min(k, 4)
+        assert rec.stats()["launches"] == before + (2 if rest else 1)
+        assert rec.items()[-1]["compile_class"] == "pallas_hash"
+        assert rec.stats()["faults"] == 0
+        st = lanes_of(rig)
+        assert st["lanes_hist"][str(first)] == (2 if rest == 4 else 1), st
+        assert st["multi_lane_launches"] == (2 if rest > 1 else 1), st
+        assert st["groups_merged"] == k - 1, st
+        assert st["solo_degrade"] == 0, st
+        ag = lane_runner.mesh_stats()["lanes"]
+        assert ag["launches_by_lanes"][str(first)] >= 1 and \
+            ag["launch_failures"] == 0 and ag["programs_built"] == 3, ag
+    finally:
+        rig.close()
+
+
+def test_lane_programs_never_compile_on_the_dispatcher(
+        lane_runner, monkeypatch):
+    """A kernel's lane programs are built beside the kernel, on builder
+    threads; while they are building, groups of its class leave one by
+    one, by launches that are built, and no staging waits for them."""
+    from tikv_tpu.device import aggregate
+    built_on = []
+    hold = threading.Event()
+    build = aggregate._build_lane_program
+
+    def spy(call, k, *rest):
+        built_on.append((threading.current_thread().name, k))
+        hold.wait(30)
+        return build(call, k, *rest)
+
+    monkeypatch.setattr(aggregate, "_build_lane_program", spy)
+    rig = LaneRig(lane_runner, [lane_snapshot(s) for s in range(3)])
+    try:
+        rig.warm()
+        rec = lane_runner.flight_recorder
+        before = rec.stats()["launches"]
+        rig.together([lane_dag(i) for i in range(3)], 3)
+        # three groups, three single launches, nothing merged yet
+        assert rec.stats()["launches"] == before + 3
+        st = lanes_of(rig)
+        assert st["multi_lane_launches"] == 0 and \
+            st["groups_merged"] == 0 and st["unbuilt_fallbacks"] >= 1, st
+        hold.set()
+        rig.wait_built()
+        assert sorted(built_on) == [("copr-lane-builder", k)
+                                    for k in (2, 3, 4)], built_on
+        # two lanes next: the largest built count that fits
+        rig.together([lane_dag(i) for i in range(2)], 2)
+        assert lanes_of(rig)["lanes_hist"]["2"] == 1
+    finally:
+        hold.set()
+        rig.close()
+
+
+def test_lanes_at_two_versions_of_one_line(lane_runner):
+    """Two generations of ONE line are two lanes: each is its own
+    snapshot (its own ``req_v``, rows, feed) and gets its own answer."""
+    from tikv_tpu.copr.region_cache import FeedLineage
+    old, new = lane_snapshot(11), lane_snapshot(12)
+    lineage = FeedLineage()
+    # what lies between the two is no row patch: the feed re-uploads
+    lineage.record({"structural": True, "spans": []})
+    for snap, v in ((old, 0), (new, 1)):
+        snap.feed_lineage, snap.feed_version = lineage, v
+    rig = LaneRig(lane_runner, [old, new])
+    try:
+        rig.warm()
+        rig.wait_built()
+        want = [rig.solo(0), rig.solo(1)]
+        assert want[0] != want[1]
+        got = rig.together([lane_dag(0), lane_dag(1)], 2)
+        assert [sorted(g.rows()) for g in got] == want
+        st = lanes_of(rig)
+        assert st["groups_merged"] == 1 and st["lanes_hist"]["2"] == 1, st
+    finally:
+        rig.close()
+
+
+@pytest.mark.parametrize("what", ["n_pad", "capacity", "tile"])
+def test_other_launch_classes_are_not_fused(lane_runner, what):
+    """A feed in another ``n_pad`` bucket, another ``capacity``, or a
+    bucket-tile request (ranges over part of a region) leaves alone,
+    today's path; answers exact."""
+    from tikv_tpu.codec.keys import table_record_key
+    from tikv_tpu.executors.ranges import KeyRange
+    other = {"n_pad": lane_snapshot(1, n=9 * LANE_BLOCK + 7),
+             "capacity": lane_snapshot(1, groups=2000),
+             "tile": lane_snapshot(1)}[what]
+    rig = LaneRig(lane_runner, [lane_snapshot(0), other])
+    try:
+        rig.warm()
+        ranges = ()
+        if what == "tile":
+            ranges = (KeyRange(table_record_key(8700, 100_000 + 256),
+                               table_record_key(8700, 100_000 + 9000)),)
+            assert other.row_slices(ranges) == [(256, 9000)]
+            rig.one(lane_dag(1, ranges))    # its kernel, warm
+        before = lane_runner.flight_recorder.stats()["launches"]
+        got = rig.together([lane_dag(0), lane_dag(1, ranges)], 2)
+        assert sorted(got[0].rows()) == rig.solo(0)
+        assert sorted(got[1].rows()) == rig.solo(1, ranges)
+        assert all(g.backend == "device" for g in got)
+        assert lane_runner.flight_recorder.stats()["launches"] == before + 2
+        st = lanes_of(rig)
+        assert st["groups_merged"] == 0 and \
+            st["multi_lane_launches"] == 0, st
+        # a tile request has no launch class at all; another class is
+        # counted as what kept the two apart (whichever was popped
+        # first saw the other behind it)
+        assert st["lane_class_mismatch"] == (0 if what == "tile" else 1), st
+    finally:
+        rig.close()
+
+
+@pytest.mark.parametrize("fault", ["failpoint", "launch_raises",
+                                   "fetch_fault"])
+def test_a_failed_lane_launch_never_fails_its_members(lane_runner, fault):
+    """``copr::coalesce_dispatch`` and a raising multi-lane program:
+    every member retries solo on the device.  A fault in the launch's
+    one fetch: every lane degrades by itself to the host pipeline.
+    Exact answers, every pin released once."""
+    rig = LaneRig(lane_runner, [lane_snapshot(s) for s in range(3)])
+    try:
+        rig.warm()
+        dags = [lane_dag(i) for i in range(3)] + [lane_dag(2)]
+        rig.together(dags, 3)
+        rig.wait_built()
+        if fault == "failpoint":
+            failpoint.cfg("copr::coalesce_dispatch", "1*return")
+        elif fault == "fetch_fault":
+            failpoint.cfg("device::before_fetch", "1*return")
+        else:
+            def boom(*_a, **_k):
+                raise RuntimeError("injected lane launch failure")
+            for key, e in lane_runner._kernel_cache.items():
+                if key[0] == "hashpl":
+                    e["lane_progs"][3] = boom
+        got = rig.together(dags, 3)
+        for g, i in zip(got, (0, 1, 2, 2)):
+            assert sorted(g.rows()) == rig.solo(i), i
+            # (a lane's fetch fault is served by the runner's own host
+            # rung, as a share group's always was: still "device" here)
+            assert g.backend == "device"
+        st = lanes_of(rig)
+        assert st["solo_degrade"] == \
+            (0 if fault == "fetch_fault" else 4), st
+        if fault == "launch_raises":
+            ag = lane_runner.mesh_stats()["lanes"]
+            assert ag["launch_failures"] == 1, ag
+    finally:
+        rig.close()
+
+
+def test_same_key_groups_that_closed_in_turn_share_a_lane(lane_runner):
+    """Two groups of ONE key, closed one after the other, wait behind
+    the dispatcher: they leave as one lane of one launch, one result."""
+    import time
+    rig = LaneRig(lane_runner, [lane_snapshot(0)])
+    try:
+        rig.warm()
+        before = lane_runner.flight_recorder.stats()["launches"]
+        rig._gate.clear()
+        out = []
+        ts = [threading.Thread(
+            target=lambda: out.append(rig.one(lane_dag(0))))
+            for _ in range(3)]
+        ts[0].start()
+        t_end = time.monotonic() + 10
+        closes = rig.coal.stats()["closes"].get("window", 0)
+        while rig.coal.stats()["closes"].get("window", 0) == closes and \
+                time.monotonic() < t_end:
+            time.sleep(0.002)       # the first group closed, and is held
+        for t in ts[1:]:
+            t.start()
+        while not rig.coal._ready and time.monotonic() < t_end:
+            time.sleep(0.002)       # the second closed behind it
+        rig._gate.set()
+        for t in ts:
+            t.join()
+        assert [sorted(g.rows()) for g in out] == [rig.solo(0)] * 3
+        assert lane_runner.flight_recorder.stats()["launches"] == before + 1
+        st = lanes_of(rig)
+        assert st["same_lane_merges"] == 1 and st["groups_merged"] == 0 \
+            and st["multi_lane_launches"] == 0, st
+    finally:
+        rig.close()
+
+
+def test_an_open_group_of_the_class_leaves_with_the_launch(lane_runner):
+    """A group still collecting when a launch of its class leaves is
+    closed early (``lanes``) and goes with it: it waits less, never
+    longer."""
+    import time
+    rig = LaneRig(lane_runner, [lane_snapshot(0), lane_snapshot(1)],
+                  window_ms=20.0)
+    try:
+        rig.warm()
+        rig.wait_built()
+        rig.coal.configure(window_ms=5000.0)
+        rig._gate.clear()
+        out = {}
+        first = threading.Thread(
+            target=lambda: out.setdefault(0, rig.one(lane_dag(0))))
+        first.start()
+        t_end = time.monotonic() + 10
+        while not rig.coal._open and time.monotonic() < t_end:
+            time.sleep(0.002)
+        # close the first group now; the dispatcher pops it and is held
+        with rig.coal._cv:
+            for g in list(rig.coal._open.values()):
+                rig.coal._close_locked(g, "window")
+        rig.coal.configure(window_ms=5000.0)
+        second = threading.Thread(
+            target=lambda: out.setdefault(1, rig.one(lane_dag(1))))
+        second.start()
+        while not rig.coal._open and time.monotonic() < t_end:
+            time.sleep(0.002)       # the second collects, 5 s to go
+        t0 = time.monotonic()
+        rig._gate.set()
+        first.join()
+        second.join()
+        assert time.monotonic() - t0 < 2.0      # not its 5 s window
+        assert sorted(out[0].rows()) == rig.solo(0)
+        assert sorted(out[1].rows()) == rig.solo(1)
+        st = rig.coal.stats()
+        assert st["closes"].get("lanes") == 1, st
+        assert st["groups_merged"] == 1 and st["lanes_hist"]["2"] == 1, st
+    finally:
+        rig.close()
+
+
+def test_a_lone_request_on_an_idle_store_leaves_at_once(lane_runner):
+    """Nothing parked, nothing in flight: the group closes ``idle``
+    and is one lane; merging adds no window and no wait."""
+    import time
+    rig = LaneRig(lane_runner, [lane_snapshot(0)], window_ms=2000.0,
+                  idle_bypass=True)
+    try:
+        rig.warm()
+        closes = dict(rig.coal.stats()["closes"])
+        t0 = time.monotonic()
+        got = rig.one(lane_dag(0))
+        assert time.monotonic() - t0 < 1.0      # never the 2 s window
+        assert sorted(got.rows()) == rig.solo(0)
+        st = rig.coal.stats()
+        assert st["closes"]["idle"] == closes.get("idle", 0) + 1, st
+        assert st["groups_merged"] == st["same_lane_merges"] == 0, st
+        assert set(st["lanes_hist"]) == {"1"}, st
+    finally:
+        rig.close()
+
+
+def test_every_member_of_a_lane_launch_can_show_its_launch(lane_runner):
+    """Each member's OWN trace holds a ``device_dispatch`` span with the
+    launch's flight record, its lane count and its lane; only the
+    leader carries it as a phase, so no member's phases outgrow it."""
+    from tikv_tpu.utils import tracker
+    k = 3
+    rig = LaneRig(lane_runner, [lane_snapshot(s) for s in range(k)])
+    try:
+        rig.warm()
+        dags = [lane_dag(i) for i in range(k)] + [lane_dag(0)]
+        rig.together(dags, k)
+        rig.wait_built()
+        trackers = [None] * len(dags)
+        one = rig.one
+
+        def traced(dag, _i=iter(range(len(dags)))):
+            tr, tok = tracker.install(sampled=True)
+            trackers[dags.index(dag) if trackers[dags.index(dag)] is None
+                     else len(dags) - 1] = tr
+            try:
+                return one(dag)
+            finally:
+                tr.finish()
+                tracker.uninstall(tok)
+
+        rig.one = traced
+        rig.together(dags, k)
+        seen = []
+        with_phase = 0
+        for tr in trackers:
+            spans = [s for s in tr.spans if s.name == "device_dispatch"]
+            assert len(spans) == 1, [s.name for s in tr.spans]
+            attrs = spans[0].attrs
+            assert attrs["compile_class"] == "pallas_hash", attrs
+            assert attrs["lanes"] == k
+            if "device_dispatch" in tr.phases:
+                with_phase += 1
+            else:
+                seen.append(attrs["lane"])
+                assert sum(tr.phases.values()) <= tr.total_ns()
+        assert with_phase == 1          # the leader, as before
+        assert len(seen) == k and set(seen) <= set(range(k)), seen
+    finally:
+        rig.close()

@@ -50,7 +50,10 @@ KEYS = {
 }
 # one table for each test that changes its table's layout
 TABLE_IDS = {"dense": 9903, "sparse": 9904, "split": 9905, "tiny": 9906,
-             "unsettled": 9907, "loadgen": 9908}
+             "unsettled": 9907, "loadgen": 9908, "lanes": 9909}
+# the Pallas body in interpret mode (``lane_store``): a region's ~3,300
+# rows are four of these blocks
+LANE_BLOCK = 1 << 10
 CELL = "agg-regions96-closed4"
 
 
@@ -92,6 +95,37 @@ def store(table_kind):
     in process; the region size limit is cut with the table, so that the
     split checker sizes a region of a few thousand rows as it sizes one
     of 96 MiB."""
+    runner = DeviceRunner(mesh=make_mesh(jax.devices()[:1]),
+                          chunk_rows=1 << 12)
+    yield from serve(table_kind, runner, ("dense", "sparse", "split", "tiny"))
+
+
+@pytest.fixture(scope="module")
+def lane_store(table_kind):
+    """The same store with the Pallas hash body serving, in interpret
+    mode as tests/test_pallas_hash_interpret.py runs it (``pallas_call``
+    patched, the runner's TPU gate lifted on the instance, BLOCK shrunk;
+    no product knob): what the chip's store does with a read's six
+    tasks, closed groups of one compile class leaving as the lanes of
+    one launch, on the CPU."""
+    import functools
+
+    from tikv_tpu.device import pallas_hash
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pallas_hash.pl, "pallas_call",
+               functools.partial(pallas_hash.pl.pallas_call, interpret=True))
+    mp.setattr(pallas_hash, "BLOCK", LANE_BLOCK)
+    runner = DeviceRunner(mesh=make_mesh(jax.devices()[:1]),
+                          chunk_rows=1 << 12)
+    runner._is_tpu = True           # lift the CPU gate (agg_bodies)
+    runner._block_local = LANE_BLOCK
+    try:
+        yield from serve(table_kind, runner, ("lanes",))
+    finally:
+        mp.undo()
+
+
+def serve(table_kind, runner, names):
     pytest.importorskip("grpc")
     from tikv_tpu.raftstore.metapb import Store
     from tikv_tpu.server import (
@@ -102,8 +136,6 @@ def store(table_kind):
         load_config()["table"]["region_split_size_mb"] == 96
     config.raftstore.region_split_size_mb = SPLIT_MB
     config.coprocessor.device_row_threshold = THRESHOLD
-    runner = DeviceRunner(mesh=make_mesh(jax.devices()[:1]),
-                          chunk_rows=1 << 12)
     pd_server = PdServer("127.0.0.1:0")
     pd_server.start()
     pd_addr = f"127.0.0.1:{pd_server.port}"
@@ -115,7 +147,7 @@ def store(table_kind):
     srv.start()
     client = TxnClient(pd_addr)
     ctxs = {}
-    for name in ("dense", "sparse", "split", "tiny"):
+    for name in names:
         spec = table_spec(name)
         table = table_kind.fixture(spec)
         cols = table_kind.make(spec, SEED, ROWS)
@@ -554,3 +586,126 @@ def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
     assert tasks >= N * len(result["records"])
     assert end["flight_recorder"]["launches"] > \
         go["flight_recorder"]["launches"]
+
+
+# ------------------------------------------- a read's tasks as lanes
+
+
+def held_read(store, kind, params, name="lanes"):
+    """One read with the store's dispatcher HELD until the groups of all
+    its tasks but the first have closed behind it: the state a busy
+    dispatcher finds them in.  → (record, reply)."""
+    coal = store.node.endpoint.coalescer
+    gate = threading.Event()
+    take = coal._take_fusable
+
+    def gated(g):
+        gate.wait(30)
+        return take(g)
+
+    coal._take_fusable = gated
+    out = []
+    try:
+        t = threading.Thread(
+            target=lambda: out.append(read(store, kind, params, name)))
+        t.start()
+        t_end = time.monotonic() + 20
+        while len(coal._ready) < N - 1 and time.monotonic() < t_end:
+            time.sleep(0.002)
+        gate.set()
+        t.join()
+    finally:
+        gate.set()
+        coal._take_fusable = take
+    return out[0]
+
+
+def warm_lanes(store, kind, params, name="lanes"):
+    """Reads until every region's kernel class is learnt and the lane
+    programs a held read asks for are built (off the dispatcher)."""
+    for _ in range(3):
+        rec, _resp = read(store, kind, params, name)
+        assert rec["ok"], rec
+    rec, _resp = held_read(store, kind, params, name)
+    assert rec["ok"], rec
+    t_end = time.monotonic() + 120
+    while time.monotonic() < t_end:
+        lanes = health(store)["device_mesh"]["lanes"]
+        asked = [e["lane_progs"] for k, e in
+                 store.runner._kernel_cache.items()
+                 if k[0] == "hashpl" and isinstance(e, dict)
+                 and "lane_progs" in e]
+        if asked and all(p is not None for d in asked for p in d.values()):
+            return lanes
+        time.sleep(0.05)
+    raise AssertionError(f"lane programs not built: {lanes}")
+
+
+def test_a_reads_six_tasks_leave_as_lanes_and_health_counts_them(
+        lane_store, kind, params):
+    """The cell's read on the Pallas body: exact against the numpy
+    reference, every task on the fast path, and the six closed groups
+    leave as lanes of fewer launches than tasks; ``/health`` says so
+    (``coalescer`` block, ``device_mesh.lanes``)."""
+    store = lane_store
+    warm_lanes(store, kind, params)
+    ctx = store.ctxs["lanes"]
+    h0 = health(store)
+    launches0 = store.runner.flight_recorder.stats()["launches"]
+    rec, resp = held_read(store, kind, params)
+    assert rec["ok"] and resp["tasks"] == N
+    assert rec["labels"].get("fastpath") == "hit"
+    checks = kind.check(ctx, [rec], params, kind.reference(ctx, params))
+    assert failing(checks) == [], checks
+    h1 = health(store)
+    c0, c1 = h0["coalescer"], h1["coalescer"]
+    # the first task left alone or led the others; the rest were merged
+    assert c1["groups_merged"] - c0["groups_merged"] >= N - 2, (c0, c1)
+    assert c1["multi_lane_launches"] > c0["multi_lane_launches"]
+    assert c1["lanes_sum"] - c0["lanes_sum"] == N
+    assert c1["lane_class_mismatch"] == 0 and c1["solo_degrade"] == 0
+    assert c1["unbuilt_fallbacks"] == c0["unbuilt_fallbacks"]
+    launches = store.runner.flight_recorder.stats()["launches"] - launches0
+    assert launches < N, launches
+    l0, l1 = h0["device_mesh"]["lanes"], h1["device_mesh"]["lanes"]
+    assert l1["lanes_sum"] - l0["lanes_sum"] == N
+    assert l1["launch_failures"] == 0
+    assert store.runner.flight_recorder.stats()["faults"] == 0
+    assert store.runner._arena.pinned_bytes() == 0
+
+
+def test_a_critical_task_that_did_not_lead_still_shows_its_launch(
+        lane_store, kind, params):
+    """``loadgen.py probe()`` fetches the trace of the read's critical
+    task, the one that returned last, and fails the run unless it holds
+    a ``device_dispatch`` span of the compile class the plan is meant to
+    take.  In a merged launch that task is as a rule not the leader: it
+    has the span all the same, outside ``phases_ms``, so its phases do
+    not outgrow its wall."""
+    store = lane_store
+    warm_lanes(store, kind, params)
+    followers = 0
+    for _ in range(6):
+        rec, resp = held_read(store, kind, params)
+        assert rec["ok"]
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{store.status_port}/debug/trace/"
+                f"{resp['trace_id']}", timeout=30) as r:
+            trace = json.loads(r.read())
+        classes = sorted({s["attrs"]["compile_class"]
+                          for s in trace["spans"]
+                          if s["name"] == "device_dispatch"
+                          and "compile_class" in s.get("attrs", {})})
+        assert classes and set(classes) <= set(kind.CLASSES), classes
+        td = trace["time_detail"]
+        if "device_dispatch" in td["phases_ms"]:
+            continue                    # this one led its launch
+        followers += 1
+        span, = [s for s in trace["spans"]
+                 if s["name"] == "device_dispatch"]
+        assert span["attrs"]["lanes"] >= 2 and \
+            0 <= span["attrs"]["lane"] < span["attrs"]["lanes"]
+        assert "coalesce_wait" in td["phases_ms"]
+        assert sum(td["phases_ms"].values()) <= \
+            td["total_rpc_wall_ms"] + 0.01, td
+    assert followers, "every critical task led its launch"

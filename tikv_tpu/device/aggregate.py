@@ -18,7 +18,8 @@ feeds and caches, by these names and no others: ``_is_tpu``,
 ``_feed_unit``, ``_pick_chunk``, ``_kernel_cache``, ``_shard_kernel``,
 ``_cached_scalar``, ``_kern_key``, ``_dispatch_phase``, ``_result``,
 ``_max_hash_capacity``, ``_psum``, ``_shard_index``, ``_eval_masked``,
-``flight_recorder``.  The arrows point one way: the runner imports this
+``flight_recorder``, and for a launch of lanes ``_device_scope`` and
+``_readback``.  The arrows point one way: the runner imports this
 module, and this module imports nothing from the runner; the types
 both need are in device/request.py.
 
@@ -31,6 +32,8 @@ The benchmark reads this module by name: the compile classes of
 from __future__ import annotations
 
 import logging
+import threading
+import time
 from contextlib import nullcontext
 from typing import Optional
 
@@ -40,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
 
 from .. import native
 from ..datatype import Column, EvalType, FieldType
@@ -68,7 +72,14 @@ from .kernels import (
     twolevel_partial,
     twolevel_unpack,
 )
-from .request import _FallbackToHost, _Pending, _Plan, _rpn_col_indices
+from .request import (
+    HOST_STAGER,
+    _FallbackToHost,
+    _LanePending,
+    _Pending,
+    _Plan,
+    _rpn_col_indices,
+)
 from .selection import _next_pow2
 
 _log = logging.getLogger(__name__)
@@ -134,16 +145,27 @@ class DeviceAggregator:
 
     def __init__(self, runner):
         self._runner = runner
+        # multi-lane launches (``launch_lanes``): what left, by lane
+        # count, and what could not leave as one program
+        self._lane_mu = threading.Lock()
+        self.lanes_hist: dict[int, int] = {}
+        self.unbuilt_fallbacks = 0
+        self.lane_launch_failures = 0
+        self.lane_builds = 0
+        self.lane_build_s = 0.0     # the longest build
 
     # -- hash aggregation --
 
     def run_hash(self, dag, plan, host_cols, dtypes, n, feed, meta,
-                 tile_spans=None):
+                 tile_spans=None, lanes: bool = False):
         """One GROUP BY request over ``feed`` → a finished result or a
         ``_Pending``.  ``meta`` is the request's guarded memo (key
         bounds, byte-plane widths and the sparse recode live there);
         ``tile_spans`` the row intervals of a request over part of the
-        region's rows (bucket tiles), else None."""
+        region's rows (bucket tiles), else None.  ``lanes``: the caller
+        stages several requests under one hold of the dispatch lock and
+        launches them together (``launch_lanes``): a warm Pallas launch
+        then comes back prepared and unlaunched, a ``_LanePending``."""
         runner = self._runner
         if "hash_bounds" in meta:
             base, span, arg_nbytes = meta["hash_bounds"]
@@ -218,7 +240,8 @@ class DeviceAggregator:
             got = self._try_pallas(dag, plan, feed, dtypes, n, base,
                                    capacity, layouts, p8, arg_nbytes,
                                    arg_ok_is_mask, mode, spans=tile_spans,
-                                   slots_dev=slots_dev)
+                                   slots_dev=slots_dev, meta=meta,
+                                   lanes=lanes)
             if got is not None:
                 synced, parts, pl_LO = got
 
@@ -227,6 +250,9 @@ class DeviceAggregator:
                         plan, parts, pl_LO, p8, layouts, slots, base,
                         capacity, slot_keys))
 
+                if isinstance(parts, _LanePending):
+                    parts.finalize = from_packed
+                    return parts
                 return from_packed(parts) if synced \
                     else _Pending(parts, from_packed)
             bodies = bodies[1:]
@@ -431,7 +457,8 @@ class DeviceAggregator:
 
     def _try_pallas(self, dag, plan, feed, dtypes, n, base, capacity,
                     layouts, p8, arg_nbytes, arg_ok_is_mask, mode,
-                    spans=None, slots_dev=None):
+                    spans=None, slots_dev=None, meta=None,
+                    lanes: bool = False):
         """Fused Pallas fast path for the direct-index aggregation
         (dense / sparse-slot / simple modes — pallas_hash module doc),
         for a plan ``agg_bodies`` gave to the kernel.
@@ -452,7 +479,13 @@ class DeviceAggregator:
         + validate ran synchronously so that Mosaic rejections fall
         back, and ``parts`` is its one fetched sum; else the parts are
         still on the device and the caller fetches them (possibly on a
-        completion thread — the async serving path).
+        completion thread — the async serving path).  With ``lanes`` a
+        warm whole-feed launch on one device does not leave here:
+        ``parts`` is a ``_LanePending`` holding what the call needs,
+        and the caller's ``launch_lanes`` sends it with the other lanes
+        of its staging.  ``meta``: the request's memo, where a served
+        whole-feed launch leaves its kernel key as ``lane_class``, what
+        ``DeviceRunner.launch_class`` tells the coalescer.
 
         A build or compile failure is cached so the fallback is taken
         once per plan, not per request.  SHARDED meshes ride the same
@@ -515,7 +548,10 @@ class DeviceAggregator:
                 run, LO, _HI = pallas_hash.build(
                     plan, layouts, p8, capacity, nb, col_map, mode=mode)
                 runs_by_nb[nb] = run
-            return {"runs": runs_by_nb, "LO": LO}
+            # in_shapes: what a lane program is traced over
+            # (_ask_lane_programs)
+            return {"runs": runs_by_nb, "LO": LO, "in_shapes": tuple(
+                (c.shape, c.dtype) for c in cols)}
 
         def launch(entry) -> list:
             """One launch of a built kernel → its packed parts, still
@@ -537,6 +573,12 @@ class DeviceAggregator:
         if entry is False:
             return None
         first = entry is None
+        whole = spans is None and runner._single
+        if lanes and whole and not first:
+            (lo, hi, blk0, nb), = tiles
+            return False, _LanePending(
+                (key, entry, entry["runs"][nb], (lo, hi, base, blk0)),
+                cols), entry["LO"]
         try:
             # the first build is a launch like any other: its compile
             # wall and class land in the flight recorder
@@ -545,6 +587,11 @@ class DeviceAggregator:
             with runner._dispatch_phase("pallas_hash", key):
                 if first:
                     entry = build()
+                    if whole:
+                        # the kernel's lane programs build beside its
+                        # own compile, each on its thread: they are
+                        # there when the first read is (launch_lanes)
+                        self._ask_lane_programs(entry, key)
                     # compile + validate now so Mosaic / shard_map
                     # rejections fall back to the XLA bodies
                     got = (True, [_sum_parts(launch(entry))], entry["LO"])
@@ -564,7 +611,203 @@ class DeviceAggregator:
         # success clears the transient strike count — three isolated
         # hiccups over a process lifetime must not kill the fast path
         cache.pop(("hashpl_tries", key), None)
+        if whole and meta is not None:
+            meta["lane_class"] = key
         return got
+
+    # -- multi-lane launches --
+
+    # the lane counts a kernel has programs for: a staging of more
+    # lanes leaves as launches of the largest first (six lanes: 4 + 2).
+    # A launch costs ~2.8 ms and a lane ~1.45 on the v5e's host
+    # (PERF.md section 6, PR 33), so past four lanes a second launch
+    # adds little, and every count is one more Mosaic compile at start
+    _LANE_COUNTS = (2, 3, 4)
+    # consecutive failed launches after which a lane count's program
+    # is given up (its lanes then leave in smaller launches)
+    _LANE_PROGRAM_TRIES = 3
+
+    def launch_lanes(self, lanes: list) -> list:
+        """Send the prepared lanes of one staging (``_LanePending``s,
+        in the caller's order, under the caller's hold of the dispatch
+        lock) and bind each to its launch.  Lanes of one kernel cache
+        key leave as ONE jitted program that runs the built Pallas
+        call once a lane, over that lane's feed and row bounds alone
+        (no padded lanes, no stacked feeds: a lane is a request of its
+        own, its own snapshot), and returns the packed ``(2, HI, W)``
+        results stacked in pinned host memory: one
+        ``_dispatch_phase("pallas_hash")`` (one flight-recorder
+        launch), one readback (``_LaneLaunch``).  A lane count without
+        a built program (more than ``_LANE_COUNTS`` has, or one still
+        building on ``_build_lanes``'s threads) leaves as the largest
+        built count plus the rest, single launches at worst: the thread
+        that stages never compiles.
+
+        → the lanes whose launch FAILED (unbound; the caller releases
+        their pins and their members retry solo).
+        """
+        runner = self._runner
+        by_key: dict = {}
+        for p in lanes:
+            by_key.setdefault(p.kernel[0], []).append(p)
+        failed = []
+        for key, todo in by_key.items():
+            entry = todo[0].kernel[1]
+            while todo:
+                k, prog = self._lane_program(entry, key, todo)
+                batch, todo = todo[:k], todo[k:]
+                try:
+                    with runner._dispatch_phase("pallas_hash", key) as info:
+                        if k > 1:
+                            trace.annotate(lanes=k)
+                            with jax.enable_x64(False):
+                                out = prog(
+                                    tuple(p.kernel[2].scalars(*p.kernel[3])
+                                          for p in batch),
+                                    tuple(p.cols for p in batch))
+                        else:
+                            _key, _entry, run, (lo, hi, base, blk0) = \
+                                batch[0].kernel
+                            out = [run(lo, hi, base, blk0, batch[0].cols)]
+                    launch = _LaneLaunch(runner, out)
+                except Exception as e:  # noqa: BLE001 — members go solo
+                    self._lane_launch_failed(entry, key, k, e)
+                    failed += batch
+                    continue
+                with self._lane_mu:
+                    self.lanes_hist[k] = self.lanes_hist.get(k, 0) + 1
+                entry.get("lane_fails", {}).pop(k, None)
+                for i, p in enumerate(batch):
+                    p.launch, p.index = launch, i
+                    p.info = dict(info, attrs=dict(
+                        info.get("attrs", ()), lanes=k, lane=i))
+                    p.kernel = p.cols = None
+        return failed
+
+    def _lane_program(self, entry: dict, key, todo: list) -> tuple:
+        """``(k, program)``: how many of ``todo``'s lanes leave in the
+        next launch: as many as the largest built program takes
+        (``_LANE_COUNTS``; 1: the kernel's own ``run``)."""
+        want = min(len(todo), self._LANE_COUNTS[-1])
+        if want == 1:
+            return 1, None
+        progs = self._ask_lane_programs(entry, key)
+        k = max((k for k, built in progs.items() if built and k <= want),
+                default=1)
+        return k, progs.get(k)
+
+    def _ask_lane_programs(self, entry: dict, key) -> dict:
+        """The kernel's lane programs by lane count (None: still
+        building, False: not buildable).  The first call, the kernel's
+        own build, starts their builds, all counts at once and each on
+        a thread of its own: no later lane count compiles, and on a
+        cold compile cache they are ready about when the kernel is."""
+        progs = entry.get("lane_progs")
+        if progs is None:
+            with self._lane_mu:
+                progs = entry.get("lane_progs")
+                if progs is None:
+                    progs = entry["lane_progs"] = dict.fromkeys(
+                        self._LANE_COUNTS)
+                    (run,) = entry["runs"].values()
+                    for k in self._LANE_COUNTS:
+                        threading.Thread(
+                            target=self._build_lanes, daemon=True,
+                            args=(entry, key, run, k),
+                            name="copr-lane-builder").start()
+        return progs
+
+    def lanes_ready(self, key) -> bool:
+        """Whether closed groups of the kernel cached under ``key`` can
+        leave together yet (``DeviceRunner.lanes_ready``): it has a
+        built lane program.  Until one is there the groups leave one by
+        one, as before (``unbuilt_fallbacks`` counts the asks that met
+        none)."""
+        entry = self._runner._kernel_cache.get(key)
+        if not isinstance(entry, dict) or len(entry.get("runs", ())) != 1:
+            return False
+        if any(self._ask_lane_programs(entry, key).values()):
+            return True
+        with self._lane_mu:
+            self.unbuilt_fallbacks += 1
+        return False
+
+    def _lane_launch_failed(self, entry: dict, key, k: int, e) -> None:
+        with self._lane_mu:
+            self.lane_launch_failures += 1
+            fails = entry.setdefault("lane_fails", {})
+            fails[k] = fails.get(k, 0) + 1
+            give_up = k > 1 and fails[k] >= self._LANE_PROGRAM_TRIES
+            if give_up:
+                entry["lane_progs"][k] = False
+        _log.warning(
+            "pallas hash %d-lane launch failed for plan %r (%s: %s); its "
+            "members retry solo%s", k, key[1], type(e).__name__, e,
+            "; the lane count is given up" if give_up else "")
+
+    def _build_lanes(self, entry: dict, key, run, k: int) -> None:
+        """Build one k-lane program off the dispatcher's thread (a
+        thread a program: a build is one Mosaic compile, ~12.5 s cold
+        on a v5e whatever k, PERF.md section 6, PR 33, and they do not
+        wait for each other): trace, compile (the persistent compile
+        cache keeps the executable for the next start) and run once
+        over one dummy column with empty row bounds, so that the jitted
+        program and the pinned stager's class of its stacked output are
+        warm when the dispatcher first calls them.  Where the pinned
+        stager runs (request.HOST_STAGER: a TPU), the program's own
+        output lies in pinned host memory, which saves the launch the
+        stager's program, a second PjRt execute (0.27 ms of the
+        launching thread's CPU on a v5e's host, 0.13 more for the
+        copy it starts; PERF.md section 6, PR 33)."""
+        runner = self._runner
+        t0 = time.perf_counter()
+        prog = False
+        with runner._device_scope(), jax.enable_x64(False):
+            cols = tuple(jnp.zeros(shape, dtype)
+                         for shape, dtype in entry["in_shapes"])
+            args = (run.scalars(0, 0, 0, 0),) * k, (cols,) * k
+            pinned = None
+            if HOST_STAGER.enabled is None:     # not probed yet
+                HOST_STAGER.stage(jnp.zeros((8,), jnp.int32))
+            if HOST_STAGER.enabled:
+                (dev,) = cols[0].devices()
+                pinned = SingleDeviceSharding(
+                    dev, memory_kind=HOST_STAGER.memory_kind)
+            for out_sharding in ((pinned, None) if pinned is not None
+                                 else (None,)):
+                try:
+                    built = _build_lane_program(run.call, k, out_sharding)
+                    jax.block_until_ready(HOST_STAGER.stage(built(*args)))
+                    prog = built
+                    break
+                except Exception as e:  # noqa: BLE001 — lanes in parts
+                    _log.warning(
+                        "pallas hash %d-lane program not built for plan "
+                        "%r (output %s): %s: %s", k, key[1],
+                        "pinned" if out_sharding is not None
+                        else "on the device", type(e).__name__, e)
+        with self._lane_mu:
+            self.lane_builds += 1
+            self.lane_build_s = max(self.lane_build_s,
+                                    time.perf_counter() - t0)
+        entry["lane_progs"][k] = prog
+
+    def lane_stats(self) -> dict:
+        """Multi-lane launches for ``/health`` (``device_mesh.lanes``):
+        launches by lane count, asks that found a kernel's lane
+        programs still building (``lanes_ready``), failed launches, the
+        programs built and the longest build's seconds."""
+        with self._lane_mu:
+            hist = dict(sorted(self.lanes_hist.items()))
+            return {
+                "launches_by_lanes": {str(k): n for k, n in hist.items()},
+                "multi_lane_launches": sum(
+                    n for k, n in hist.items() if k > 1),
+                "lanes_sum": sum(k * n for k, n in hist.items()),
+                "unbuilt_fallbacks": self.unbuilt_fallbacks,
+                "launch_failures": self.lane_launch_failures,
+                "programs_built": self.lane_builds,
+                "longest_build_s": round(self.lane_build_s, 3)}
 
     def _pallas_failed(self, key, e, building: bool) -> None:
         """One failed build or launch of the kernel cached under
@@ -1116,6 +1359,57 @@ class DeviceAggregator:
 
 # -- the finalize: the fetched accumulator in, planes and Columns out.
 #    Pure functions of numpy arrays: no runner, no device. --
+
+def _build_lane_program(call, k: int, out_sharding=None):
+    """The jitted k-lane program: the built ``pallas_call`` once a lane
+    (k ``pallas_hash`` custom calls in one module run, each over its
+    own lane's columns and scalars), the k packed results stacked, in
+    ``out_sharding``'s memory where one is given.  Named as the single
+    launch's program is (``jit_pallas_hash``)."""
+
+    def pallas_hash(scals, cols):
+        return jnp.stack([call(s, *c) for s, c in zip(scals, cols)])
+
+    return jax.jit(pallas_hash, out_shardings=out_sharding)
+
+
+class _LaneLaunch:
+    """The shared fetch of one launch of lanes: the stacked output,
+    staged to pinned host memory once, read back once (memoized, from
+    whichever lane's completion worker joins first), its lanes'
+    ``_LanePending.fetch`` slicing it.  A failed readback is memoized
+    too and re-raised to every lane, each of which degrades by itself
+    (``DeferredResult._resolve``); ``strike_once`` lets only the first
+    of them strike the slice's health score."""
+
+    __slots__ = ("_runner", "_tree", "_mu", "_memo", "_struck")
+
+    def __init__(self, runner, tree):
+        self._runner = runner
+        # staged and set on its way to the host as any launch's output
+        self._tree = _Pending(tree, None).tree
+        self._mu = threading.Lock()
+        self._memo = None
+        self._struck = False
+
+    def fetch(self):
+        with self._mu:
+            if self._memo is None:
+                try:
+                    self._memo = ("ok", self._runner._readback(self._tree))
+                except BaseException as e:  # noqa: BLE001 — memoized
+                    self._memo = ("err", e)
+                self._tree = None
+            kind, val = self._memo
+        if kind == "err":
+            raise val
+        return val
+
+    def strike_once(self) -> bool:
+        with self._mu:
+            first, self._struck = not self._struck, True
+        return first
+
 
 def finalize_packed(parts, LO, p8, layouts, specs, slots, base, capacity,
                     slot_keys):

@@ -384,12 +384,22 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
 
     scal_cache: dict = {}
 
-    def run(row_lo, row_hi, base, blk0, cols):
+    def scalars(row_lo, row_hi, base, blk0):
+        """The kernel's four prefetch scalars for concrete row bounds,
+        on the device, cached per (feed, tile)."""
         if mode != MODE_DENSE:
             # only the dense key expression reads ``base``; a sparse
             # domain's minimum (up to 2^62) does not fit the int32
             # prefetch scalars (numpy 2 raises instead of wrapping)
             base = 0
+        key = (row_lo, int(row_hi), int(base), int(blk0))
+        scal = scal_cache.get(key)
+        if scal is None:
+            scal = jnp.asarray(np.asarray(key, np.int32))
+            scal_cache[key] = scal
+        return scal
+
+    def run(row_lo, row_hi, base, blk0, cols):
         # the scalar tuple is constant per (feed, tile): cache it, on
         # the device the kernel runs on, so a warm request issues no
         # scalar H2D and the jitted call takes it as it lies (what an
@@ -400,19 +410,21 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
         # there is no H2D to save; theirs are the runner's cached
         # scalars, replicated over its mesh (DeviceRunner._cached_scalar).
         if isinstance(row_lo, (int, np.integer)):
-            key = (row_lo, int(row_hi), int(base), int(blk0))
-            scal = scal_cache.get(key)
-            if scal is None:
-                scal = jnp.asarray(np.asarray(key, np.int32))
-                scal_cache[key] = scal
+            scal = scalars(row_lo, row_hi, base, blk0)
         else:
             with jax.enable_x64(False):
                 scal = jnp.stack([
                     jnp.asarray(v).astype(jnp.int32)
-                    for v in (row_lo, row_hi, base, blk0)])
+                    for v in (row_lo, row_hi,
+                              base if mode == MODE_DENSE else 0, blk0)])
         with jax.enable_x64(False):
             return pallas_hash(scal, *cols)
 
+    # what a multi-lane program (aggregate ``_build_lane_program``) is
+    # made of: the bare pallas_call, once a lane, and each lane's cached
+    # scalars
+    run.call = call
+    run.scalars = scalars
     return run, LO, HI
 
 

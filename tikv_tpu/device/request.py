@@ -144,6 +144,8 @@ class _PinnedStager:
                 tuple(sharding.device_set)
             if len(devices) != 1:
                 return None         # sharded leaf: leave to GSPMD
+            if getattr(sharding, "memory_kind", None) == self.memory_kind:
+                return None         # its program put it there already
             dev = devices[0]
             key = (x.shape, str(x.dtype), dev.id)
         except Exception:   # noqa: BLE001 — not a jax array
@@ -245,3 +247,35 @@ class _Pending:
                 x.copy_to_host_async()
             except Exception:   # pragma: no cover - CPU arrays
                 pass
+
+
+class _LanePending(_Pending):
+    """One LANE of a multi-lane launch (aggregate.DeviceAggregator
+    ``launch_lanes``): a hash aggregation prepared as a request of its
+    own (its own feed, row bounds, snapshot and finalize) whose kernel
+    call has not left yet, because the dispatcher is staging other
+    closed groups of the same compile class and all of them leave as
+    ONE program.  Until ``launch_lanes`` binds it, it holds what its
+    call needs (``kernel``: the kernel cache key, its entry, the built
+    ``run`` and the row bounds; ``cols``: its feed's kernel inputs);
+    after, ``launch`` is the shared fetch and ``index`` this lane's
+    place in it.  ``info``: the launch's ``_dispatch_phase`` record,
+    for the ``device_dispatch`` span every member's trace gets."""
+
+    __slots__ = ("kernel", "cols", "launch", "index", "info")
+
+    def __init__(self, kernel, cols):
+        # no tree yet, nothing to stage: the launch stages its one
+        # stacked output
+        self.tree = None
+        self.finalize = None
+        self.small = True
+        self.kernel = kernel
+        self.cols = cols
+        self.launch = None
+        self.index = 0
+        self.info = None
+
+    def fetch(self):
+        """This lane's packed parts, from the launch's one readback."""
+        return [self.launch.fetch()[self.index]]

@@ -88,6 +88,7 @@ from .request import (
     _DEVICE_ETS,
     HOST_STAGER,
     _FallbackToHost,
+    _LanePending,
     _Pending,
     _Plan,
     _remap_rpn,
@@ -250,7 +251,8 @@ class DeferredResult:
     """
 
     __slots__ = ("_runner", "_pending", "_dag", "_storage", "_mu",
-                 "_memo", "small", "_pin_anchor", "_meter_ctx", "_mesh")
+                 "_memo", "small", "_pin_anchor", "_meter_ctx", "_mesh",
+                 "_launch_info")
 
     def __init__(self, runner, pending: _Pending, dag, storage,
                  pin_anchor=None):
@@ -274,6 +276,31 @@ class DeferredResult:
         # trackers) is labelled with it; None once a rescue re-served
         # the request elsewhere (that launch labelled its own tracker)
         self._mesh = runner._mesh_desc
+        # the launch this thread just made for the request
+        # (``_dispatch_phase``'s record): see ``launch_info``
+        self._launch_info = runner._take_launch_info()
+
+    @property
+    def launch_info(self) -> Optional[dict]:
+        """The ``_dispatch_phase`` record of the launch that serves this
+        request: ``t0_ns`` / ``t1_ns`` and ``attrs``, the flight-recorder
+        entry (plus ``lanes`` and ``lane`` for a lane of a multi-lane
+        launch).  The coalescer copies it into the trace of every
+        member that did not lead the dispatch, as a ``device_dispatch``
+        span outside ``phases_ms``.  None where no launch was made."""
+        info = getattr(self._pending, "info", None)     # a lane's
+        return info if info is not None else self._launch_info
+
+    def abandon(self) -> None:
+        """Give the arena pin back without resolving: a lane whose
+        launch failed (its members retry solo, each with its own)."""
+        with self._mu:
+            anchor, self._pin_anchor = self._pin_anchor, None
+        if anchor is not None:
+            try:
+                self._runner._arena.unpin(anchor)
+            except Exception:   # noqa: BLE001
+                pass
 
     def result(self):
         from .. import resource_metering as rm
@@ -319,8 +346,12 @@ class DeferredResult:
             # persistent slice_dead fault names it) — rescue the
             # request onto a healthy slice/submesh before falling to
             # the host rung.  The pin release in result()'s finally is
-            # untouched either way: exactly-once, never doubled.
-            self._runner._note_slice_fault("fetch")
+            # untouched either way: exactly-once, never doubled.  The
+            # lanes of one launch share its fetch and its fault: one
+            # strike for it, not one a lane.
+            launch = getattr(self._pending, "launch", None)
+            if launch is None or launch.strike_once():
+                self._runner._note_slice_fault("fetch")
             self._mesh = None
             rescued = self._runner._rescue(self._dag, self._storage)
             if rescued is not None:
@@ -581,6 +612,8 @@ class DeviceRunner:
         # the expensive part the async serving path overlaps — always
         # block OUTSIDE it.
         self._dispatch_mu = threading.Lock()
+        # per thread: the last launch's record (_take_launch_info)
+        self._launched = threading.local()
         from collections import OrderedDict
         self._scalar_cache: "OrderedDict" = OrderedDict()
         # per-plan observed-selectivity EWMAs + aggregate route counts
@@ -964,7 +997,9 @@ class DeviceRunner:
         whether the extension built; ``scalar_cache``: look-ups of the
         cached device scalars that found the value on the device
         (``hits``) or had to put it there (``uploads``), sub-runners'
-        included: a warm launch only hits; all monotone), the resident
+        included: a warm launch only hits; ``lanes``: this runner's
+        multi-lane launches, ``DeviceAggregator.lane_stats``; all
+        monotone), the resident
         bytes of the live mesh's fullest shard, and the placement
         rollup."""
         shape = dict(zip(ROW_AXES,
@@ -983,12 +1018,29 @@ class DeviceRunner:
                    "native_available":
                        native.hash_finalize_packed is not None},
                "scalar_cache": self.flight_recorder.scalar_counts(),
+               "lanes": self.lane_stats(),
                "submesh_rebuilds": self._submesh_rebuilds,
                "feed_bytes_per_shard": max(
                    live._arena.resident_bytes_by_device().values(),
                    default=0)}
         if self._placer is not None:
             out["placement"] = self._placer.stats()
+        return out
+
+    def lane_stats(self) -> dict:
+        """``DeviceAggregator.lane_stats`` of this runner and of its
+        placement slices (a lane launch runs where its lines live),
+        added up."""
+        out = self._aggregator.lane_stats()
+        for sub in (self._placer.slices if self._placer is not None
+                    else ()):
+            for k, v in sub._aggregator.lane_stats().items():
+                if isinstance(v, dict):
+                    for kk, n in v.items():
+                        out[k][kk] = out[k].get(kk, 0) + n
+                else:
+                    out[k] = max(out[k], v) if k == "longest_build_s" \
+                        else out[k] + v
         return out
 
     def mvcc_resolver(self, create: bool = True):
@@ -1082,6 +1134,11 @@ class DeviceRunner:
         slice id joins the key so groups never straddle chips); only
         whole-mesh sharded dispatches — already launch-amortized by
         GSPMD — stay uncoalesced.
+
+        A key says who shares a RESULT.  Who shares a LAUNCH is wider:
+        closed ``share`` groups over different anchors, versions or
+        ranges whose ``launch_class`` is equal leave as the lanes of one
+        program (``handle_lanes``), each with its own answer.
         """
         if not hasattr(storage, "scan_columns"):
             return None
@@ -1115,6 +1172,109 @@ class DeviceRunner:
             return ("share", id(anchor), req_v, dag.plan_key(),
                     dag.ranges)
         return None
+
+    def launch_class(self, key, dag: DAGRequest, storage):
+        """What a closed group's launch can be fused on, or None where
+        it leaves alone.  ``key`` is the group's ``batch_class`` key,
+        ``dag`` / ``storage`` its lead member's.
+
+        Two groups of one launch class are LANES of one launch: the
+        same runner (slice), the same plan and the same kernel compile
+        class (``n_pad`` bucket, dtypes, ``capacity``, slot mode,
+        ``arg_nbytes``), which is the kernel cache key the request's
+        last whole-feed Pallas launch left in its memo
+        (``DeviceAggregator._try_pallas``, ``lane_class``; one memo a
+        line: two generations of a line are two lanes of one class,
+        and a refresh drops it until the next launch).  Only a
+        ``share`` group of a hash aggregation that the Pallas body has
+        already served whole has one: a ``stack`` group, another plan
+        kind, a mesh, a bucket-tile request (its ranges have no memo of
+        their own), a cold or refreshed line take today's path.  The
+        class only decides who is staged together; each lane's kernel
+        is looked up again when it is prepared, and lanes that turn
+        out to differ leave as launches of their own."""
+        prefix = ()
+        if key[0] == "slice":
+            prefix, key = key[:2], key[2:]
+        if key[0] != "share":
+            return None
+        runner = self
+        if self._placer is not None:
+            runner = self._placer.route(storage)
+            if (id(runner) != prefix[1]) if prefix else runner is not self:
+                return None
+        if not runner._single:
+            return None
+        plan = runner._analyze(dag)
+        if plan is None or plan.kind != "hash_agg":
+            return None
+        per_storage = runner._arena.bucket(runner._feed_anchor(storage),
+                                           create=False)
+        meta = per_storage.get(("meta", (dag.plan_key(), dag.ranges))) \
+            if per_storage is not None else None
+        if not meta:
+            return None
+        klass = meta.get("lane_class")
+        return None if klass is None else prefix + klass
+
+    def lanes_ready(self, klass, storage) -> bool:
+        """Whether groups of launch class ``klass`` over DIFFERENT
+        feeds can leave together yet: the kernel has a built lane
+        program (``DeviceAggregator.lanes_ready``: they are built
+        beside the kernel itself, off the serving threads, and until
+        one is there such groups leave one by one, as before).
+        ``storage``:
+        a lead member's, for the slice the class lives on."""
+        runner = self
+        if klass[0] == "slice":
+            klass = klass[2:]
+            if self._placer is not None:
+                runner = self._placer.route(storage)
+        return runner._aggregator.lanes_ready(klass)
+
+    def handle_lanes(self, lanes) -> list:
+        """ONE staging for ``lanes``, a list of ``(dag, storage)``
+        leads of closed ``share`` groups with one ``launch_class``:
+        under one hold of the dispatch lock each lane is prepared as a
+        request of its own (``_handle_local``: its memo, its feed, its
+        row bounds, its arena pin), then the prepared kernels leave
+        together (``DeviceAggregator.launch_lanes``: one program, one
+        Pallas call a lane, one fetch).
+
+        → one outcome a lane, in order: a ``DeferredResult`` (or a
+        result that settled in line) for its members to share as a
+        ``share`` group's always did, or None where the lane could not
+        be staged or its launch failed: its members then retry solo
+        (the coalescer's ``_solo_fallback``), as a failed group's
+        always did.  Raises ``_BatchUnavailable`` where no lane can be
+        staged here at all."""
+        if self._placer is not None and lanes:
+            target = self._placer.route(lanes[0][1])
+            if target is not self:
+                return target.handle_lanes(lanes)
+        if not self._single:
+            raise _BatchUnavailable("lanes need a single-device runner")
+        out = []
+        with self._device_scope(), self._dispatch_mu:
+            for dag, storage in lanes:
+                try:
+                    if self._placer is not None and \
+                            self._placer.route(storage) is not self:
+                        raise _BatchUnavailable("lane placed elsewhere")
+                    out.append(self._handle_local(dag, storage, True, None,
+                                                  _lanes=True))
+                except Exception:   # noqa: BLE001 — the lane goes solo
+                    out.append(None)
+            waiting = [i for i, d in enumerate(out)
+                       if isinstance(d, DeferredResult) and
+                       isinstance(d._pending, _LanePending)]
+            failed = {id(p) for p in self._aggregator.launch_lanes(
+                [out[i]._pending for i in waiting])}
+            for i in waiting:
+                if id(out[i]._pending) in failed:
+                    out[i].abandon()
+                    out[i] = None
+        return out
 
     def handle_batched(self, members) -> "_BatchedSelectionGroup":
         """ONE stacked dispatch for ``members`` — a list of
@@ -2427,16 +2587,21 @@ class DeviceRunner:
         from .. import resource_metering as rm
         from ..utils import tracker
         rec = self.flight_recorder
+        # what the launch was, for whoever has to show it in a trace
+        # the span above is not in (``DeferredResult.launch_info``):
+        # filled when the launch is over
+        info: dict = {}
         with tracker.phase("device_dispatch"):
-            t0 = time.perf_counter()
+            t0_ns = time.perf_counter_ns()
             ok = True
             try:
-                yield
+                yield info
             except BaseException:
                 ok = False
                 raise
             finally:
-                wall_s = time.perf_counter() - t0
+                t1_ns = time.perf_counter_ns()
+                wall_s = (t1_ns - t0_ns) / 1e9
                 # RU metering: every launch wall is charged to the
                 # ambient (tag, region) — a coalesced group's shared
                 # launch splits by occupancy share across member tags
@@ -2457,6 +2622,16 @@ class DeviceRunner:
                         ok=ok, shards=num_shards(self._mesh),
                         whole_mesh=self._failover_parent is None)
                     tracker.annotate(**entry)
+                    info["attrs"] = entry
+                info["t0_ns"], info["t1_ns"] = t0_ns, t1_ns
+                self._launched.info = info
+
+    def _take_launch_info(self) -> Optional[dict]:
+        """The record of this thread's last ``_dispatch_phase`` on this
+        runner, once."""
+        info = getattr(self._launched, "info", None)
+        self._launched.info = None
+        return info
 
     # -- packed device→host readback (one transfer, one sync) --
 
@@ -2600,15 +2775,20 @@ class DeviceRunner:
         return jax.default_device(self._pin_device)
 
     def _handle_local(self, dag: DAGRequest, storage, deferred: bool,
-                      _stack):
+                      _stack, _lanes: bool = False):
         """``handle_request`` on THIS runner's devices (placement and
-        degrade routing already done)."""
+        degrade routing already done).  ``_lanes``: one lane of
+        ``handle_lanes``, which holds the dispatch lock and launches
+        the lanes' kernels itself; a lane that would be served on the
+        host raises ``_BatchUnavailable`` instead, as a stacked group
+        does."""
         plan = self._analyze(dag)
         if plan is None:
             raise RuntimeError("plan not supported by device backend")
+        grouped = _stack is not None or _lanes
 
         if self._refuse_if_quarantined():
-            if _stack is not None:
+            if grouped:
                 # a group must not burn the leader's deadline on a
                 # throwaway synchronous host run — the coalescer's
                 # solo retries re-route each member via the placer,
@@ -2809,7 +2989,7 @@ class DeviceRunner:
             # scans re-sort, desc scans reverse)
             positional = isinstance(plan.scan, TableScanDesc) and \
                 not getattr(plan.scan, "desc", False)
-            with self._dispatch_mu:
+            with nullcontext() if _lanes else self._dispatch_mu:
                 if not self._single:
                     # one shard's enqueue failing (device loss, ICI
                     # fault) surfaces as a whole-launch failure mid-
@@ -2838,7 +3018,7 @@ class DeviceRunner:
                 elif plan.kind == "hash_agg":
                     result = self._aggregator.run_hash(
                         dag, plan, host_cols, dtypes, n, feed, gmeta,
-                        tile_spans=tile_spans)
+                        tile_spans=tile_spans, lanes=_lanes)
                 elif plan.kind == "topn":
                     result = self._run_topn(dag, plan, host_cols, dtypes,
                                             n, get_batch, feed)
@@ -2880,7 +3060,7 @@ class DeviceRunner:
             # whole-mesh runner's slice-attributable strikes happen at
             # the _preflight_slice / _readback sites instead)
             self._note_slice_fault("dispatch")
-            if _stack is not None:
+            if grouped:
                 # a degrade mid-group must not serve the LEADER's host
                 # answer to every member — the coalescer retries each
                 # member as a solo dispatch (per-member degrade intact)
@@ -2907,7 +3087,9 @@ class DeviceRunner:
 
         from ..utils import tracker
         t0 = _time.perf_counter()
-        fetched = self._readback(pending.tree)
+        # a lane of a multi-lane launch slices the launch's one readback
+        fetched = pending.fetch() if isinstance(pending, _LanePending) \
+            else self._readback(pending.tree)
         with tracker.phase("host_materialize"):
             out = pending.finalize(fetched)
         # a served request decays the slice's strike score (and feeds
@@ -2954,6 +3136,7 @@ class DeviceRunner:
         meta.pop("n_rows", None)
         meta.pop("host_cols", None)
         meta.pop("sparse_slots", None)
+        meta.pop("lane_class", None)    # re-learnt by the next launch
         keep = patches is not None and \
             not any(p.get("structural") for p in patches)
         if keep:

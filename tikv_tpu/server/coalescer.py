@@ -16,7 +16,9 @@ Two pieces:
 co-resident HBM feed and share a compile class (the const-blind
 ``shape_key`` from the hoisted-parameter selection kernels, or a
 byte-identical plan) are grouped into ONE stacked device dispatch with
-a shared D2H, under a bounded, deadline-aware collection window:
+a shared D2H, under a bounded, deadline-aware collection window, and
+closed groups over DIFFERENT feeds of one compile class share a launch
+as its lanes:
 
 - a group closes on SIZE (``max_group`` members), WINDOW expiry
   (``window_ms``), or tightest-deadline PRESSURE — a member is never
@@ -36,11 +38,40 @@ a shared D2H, under a bounded, deadline-aware collection window:
 - results resolve through the endpoint's CompletionPool as per-request
   slices: ONE fetch, N resolutions, with each member's host gather
   running on its own completion worker;
+- a group is one co-resident feed, and a LAUNCH may hold several:
+  when the dispatcher takes a closed ``share`` group it takes with it
+  every other group that WAITS, closed behind it in ``_ready`` or still
+  collecting in ``_open``, whose launch the runner can fuse with it
+  (``DeviceRunner.launch_class``: same runner or slice, same plan, same
+  kernel compile class; a hash aggregation on the Pallas body).  They
+  leave as the LANES of ONE launch (``DeviceRunner.handle_lanes``: one
+  staging under one hold of the dispatch lock, one program that runs
+  the kernel once a lane over that lane's feed alone, one fetch), each
+  lane still its own answer for its own snapshot; a group of a key
+  already among them joins that lane.  Nothing is held back for it and
+  nothing waits longer: a closed group waits for nothing but the
+  dispatcher thread, and an open one is closed early (``lanes``), the
+  launch its window was waiting to amortize being this one; so there
+  is no second window, no knob, and a lone request never waits;
+  ``stack`` groups, other plan kinds, a mesh, a bucket-tile request, a
+  class mismatch and a kernel whose lane programs are still building
+  take the one-group path.  ``stats()`` (``/health`` ``coalescer``)
+  counts it: ``lanes_hist`` (launches by lane count;
+  ``multi_lane_launches``, ``lanes_sum``), ``groups_merged`` (waiting
+  groups folded into another's launch as lanes of their own),
+  ``same_lane_merges`` (folded into a lane of their own key),
+  ``closes["lanes"]`` (of both, those taken while still open),
+  ``lane_class_mismatch`` (left behind: another class),
+  ``unbuilt_fallbacks`` (the runner's: asks that found a kernel's lane
+  programs still being built, off this thread: the groups then leave
+  one by one, and no staging waits for a compile);
 - the group pins its arena lines once (generation-guarded pin tokens,
-  device/supervisor.py) for the shared dispatch;
+  device/supervisor.py) for the shared dispatch; each lane of a merged
+  launch pins its own line, released once when that lane resolves;
 - a failed group NEVER fails its members: a batched-launch failure
-  (incl. the ``copr::coalesce_dispatch`` failpoint) retries every
-  member as a solo dispatch, and a fetch-side fault degrades each
+  (incl. the ``copr::coalesce_dispatch`` failpoint, and a lane whose
+  staging or launch failed) retries every member as a solo dispatch,
+  and a fetch-side fault degrades each
   member to the host pipeline through the endpoint's existing
   per-request contract.  That contract extends across CHIP DEATH
   (device/supervisor.py failure domains): a group whose slice dies
@@ -308,7 +339,7 @@ class _Member:
     """One request parked in a collection window."""
 
     __slots__ = ("dag", "storage", "future", "tracker", "tag",
-                 "deadline_at", "t_submit_ns", "rc_defers")
+                 "deadline_at", "t_submit_ns", "rc_defers", "t_closed_ns")
 
     def __init__(self, dag, storage, future, tracker, tag, deadline_at):
         self.dag = dag
@@ -321,11 +352,19 @@ class _Member:
         # collection windows this member was DWFQ-deferred past
         # (resource_control.select_stacked bounds it at MAX_DEFERS)
         self.rc_defers = 0
+        # when its group was queued for the dispatcher (_close_locked;
+        # 0: dispatched inline at shutdown, never queued).  On the
+        # member, not only on the group: groups merged into one launch
+        # keep each its own instant
+        self.t_closed_ns = 0
+
+
+_UNASKED = object()
 
 
 class _Group:
     __slots__ = ("key", "members", "close_at", "window_close_at",
-                 "closed", "t_closed_ns")
+                 "closed", "t_closed_ns", "klass")
 
     def __init__(self, key, close_at: float):
         self.key = key
@@ -336,6 +375,9 @@ class _Group:
         # when _close_locked queued it for the dispatcher (0: a
         # shutdown-time group dispatched inline, never queued)
         self.t_closed_ns = 0
+        # the runner's launch class of the group's launch, asked once
+        # (_launch_class; None: it leaves alone)
+        self.klass = _UNASKED
 
 
 class RequestCoalescer:
@@ -413,6 +455,17 @@ class RequestCoalescer:
         self._shared: dict = {}
         self.plan_share_hits = 0
         self.plan_share_groups = 0
+        # merged launches (_take_fusable, module doc): launches by lane
+        # count; ready groups folded into another group's launch, as
+        # lanes of their own (groups_merged) or into a lane of the same
+        # key (same_lane_merges); ready groups left behind because
+        # their launch class differed (lane_class_mismatch).  stats()
+        # adds the runner's unbuilt_fallbacks: groups that left alone
+        # because their kernel's lane programs were still building
+        self.lanes_hist: dict[int, int] = {}
+        self.groups_merged = 0
+        self.same_lane_merges = 0
+        self.lane_class_mismatch = 0
 
     # ------------------------------------------------------------ wiring
 
@@ -575,6 +628,8 @@ class RequestCoalescer:
             return
         g.closed = True
         g.t_closed_ns = time.perf_counter_ns()
+        for m in g.members:
+            m.t_closed_ns = g.t_closed_ns
         if self._open.get(g.key) is g:
             del self._open[g.key]
         self._ready.append(g)
@@ -651,30 +706,119 @@ class RequestCoalescer:
                 if self._ready:
                     g = self._ready.popleft()
             if g is not None:
-                self._dispatch(g)
+                self._dispatch(g, self._take_fusable(g))
+
+    def _take_fusable(self, g: _Group) -> list:
+        """The other groups that leave with ``g``: every group that
+        WAITS, closed in ``_ready`` or still collecting in ``_open``,
+        whose launch the runner can fuse with ``g``'s
+        (``DeviceRunner.launch_class`` equal), oldest first, up to
+        ``max_group`` lanes.  A group of ``g``'s own key joins its
+        lane; groups over other feeds need the kernel's lane programs
+        built (``lanes_ready``) and leave alone until they are.
+
+        Nothing is held back for this and nothing waits longer.  A
+        closed group waits for nothing but this thread; an open one is
+        closed EARLY (reason ``lanes``), as the pipeline close does
+        for a device that ran dry: its window was for gathering
+        members while the device is busy elsewhere, and the launch it
+        was waiting to amortize is leaving now.  With nothing parked
+        this is two length checks."""
+        if not self._ready and not self._open:
+            return []
+        klass = self._launch_class(g)
+        if klass is None:
+            return []
+        with self._mu:
+            waiting = list(self._ready) + [
+                og for og in self._open.values() if og.members]
+        keys = {g.key}
+        take = []
+        mismatch = 0
+        ready = None        # lanes_ready, asked at most once
+        for og in waiting:
+            if og.key not in keys:
+                if len(keys) >= self.max_group:
+                    continue
+                other = self._launch_class(og)
+                if other != klass:
+                    mismatch += other is not None
+                    continue
+                if ready is None:
+                    ready = self._lanes_ready(g, klass)
+                if not ready:
+                    continue
+                keys.add(og.key)
+            take.append(og)
+        with self._cv:
+            self.lane_class_mismatch += mismatch
+            for og in take:
+                if not og.closed and self._open.get(og.key) is og:
+                    self._close_locked(og, "lanes")
+            # close() may have taken the queue meanwhile: only what is
+            # still there is this thread's to dispatch
+            take = [og for og in take if og in self._ready]
+            for og in take:
+                self._ready.remove(og)
+        return take
+
+    def _launch_class(self, g: _Group):
+        if g.klass is not _UNASKED:
+            return g.klass
+        lead = g.members[0] if g.members else None
+        klass = None
+        if lead is not None and not self._shutdown:
+            try:
+                klass = self._runner.launch_class(g.key, lead.dag,
+                                                  lead.storage)
+            except Exception:   # noqa: BLE001 — it leaves alone
+                pass
+        g.klass = klass
+        return klass
+
+    def _lanes_ready(self, g: _Group, klass) -> bool:
+        try:
+            return self._runner.lanes_ready(klass, g.members[0].storage)
+        except Exception:       # noqa: BLE001 — they leave alone
+            return False
 
     # ---------------------------------------------------------- dispatch
 
-    def _dispatch(self, group: _Group) -> None:
-        """Stage one closed group's launch, on whichever thread runs it
-        (the dispatcher; a submitter or close() at shutdown)."""
+    def _dispatch(self, group: _Group, merged=()) -> None:
+        """Stage one closed group's launch, and with it the ``merged``
+        groups of its launch class, on whichever thread runs it (the
+        dispatcher; a submitter or close() at shutdown)."""
         from ..utils import tracker
         t_begin_ns = time.perf_counter_ns()
         lead = group.members[0].tracker if group.members else None
         with tracker.timed("group_dispatch",
                            lead.trace_id if lead is not None else None):
-            self._stage(group, t_begin_ns)
+            self._stage(group, t_begin_ns, merged)
 
-    def _stage(self, group: _Group, t_begin_ns: int) -> None:
+    def _stage(self, group: _Group, t_begin_ns: int, merged=()) -> None:
         from ..device.runner import (
             DeferredResult,
             _BatchUnavailable,
         )
         members = group.members
-        # a member's coalesce_wait splits at these two instants: submit
-        # → closed is the collection window, closed → begin the wait
-        # for this (one) dispatcher, the rest the shared staging below
-        t_closed_ns = group.t_closed_ns or t_begin_ns
+        # the launch's LANES: one a key, in the order the groups closed
+        # (a merged group of a key already there joins that lane: its
+        # members share the lane's one result, as if they had closed
+        # together).  One lane, no merge: today's path.
+        lanes = None
+        if merged:
+            by_key = {group.key: members}
+            for og in merged:
+                lane = by_key.get(og.key)
+                if lane is None:
+                    by_key[og.key] = list(og.members)
+                else:
+                    lane.extend(og.members)
+            lanes = list(by_key.values())
+            members = [m for lane in lanes for m in lane]
+            with self._mu:
+                self.groups_merged += len(lanes) - 1
+                self.same_lane_merges += len(merged) - (len(lanes) - 1)
         # resource control (resource_control.py): stacked-group
         # membership is chosen by deficit-weighted fair queuing over
         # the parked members' groups instead of FIFO — one tenant's
@@ -698,6 +842,8 @@ class RequestCoalescer:
                     window_s=self.window_s, reserve_s=reserve)
                 if deferred:
                     self._defer_members(group.key, deferred)
+        if lanes is None:
+            lanes = [members]
         size = len(members)
         COPR_BATCH_OCCUPANCY.observe(size)
         with self._mu:
@@ -725,7 +871,7 @@ class RequestCoalescer:
         gsp = None
         if span_tr is not None:
             gsp = span_tr.begin("group_dispatch")
-            span_tr.annotate_span(gsp, occupancy=size,
+            span_tr.annotate_span(gsp, occupancy=size, lanes=len(lanes),
                                   group_kind=str(group.key[0]))
         lead_tok = tracker.adopt(
             lead_tr, parent=gsp if span_tr is lead_tr else None) \
@@ -744,23 +890,39 @@ class RequestCoalescer:
             with GLOBAL_RECORDER.group_scope(meter_members):
                 if fail_point("copr::coalesce_dispatch") is not None:
                     raise _BatchUnavailable("copr::coalesce_dispatch")
-                if group.key[0] == "stack" and size > 1:
+                if len(lanes) > 1:
+                    # closed groups of one launch class: ONE staging,
+                    # one program, one fetch; a lane the runner could
+                    # not launch comes back None and its members
+                    # retry solo below
+                    outcomes = self._runner.handle_lanes(
+                        [(lane[0].dag, lane[0].storage) for lane in lanes])
+                elif group.key[0] == "stack" and size > 1:
                     handle = self._runner.handle_batched(
                         [(m.dag, m.storage) for m in members])
                     resolvers = [
                         (lambda i=i, h=handle: h.member_result(i))
                         for i in range(size)]
+                    infos = [None] * size
+                    outcomes = None
                 else:
                     # singleton / identical-plan share: one solo
                     # dispatch, its (memoized, thread-safe) fetch
                     # serves every member
-                    d = self._runner.handle_request(
+                    outcomes = [self._runner.handle_request(
                         members[0].dag, members[0].storage,
-                        deferred=True)
-                    if isinstance(d, DeferredResult):
-                        resolvers = [d.result] * size
-                    else:
-                        resolvers = [(lambda r=d: r)] * size
+                        deferred=True)]
+                if outcomes is not None:
+                    resolvers, infos = [], []
+                    for lane, d in zip(lanes, outcomes):
+                        if isinstance(d, DeferredResult):
+                            resolve, info = d.result, d.launch_info
+                        elif d is None:
+                            resolve = info = None       # solo, below
+                        else:
+                            resolve, info = (lambda r=d: r), None
+                        resolvers += [resolve] * len(lane)
+                        infos += [info] * len(lane)
         except Exception:   # noqa: BLE001 — incl. _BatchUnavailable
             # the batched LAUNCH failed: a failed group must never fail
             # its members — each retries as a solo dispatch (and any
@@ -770,7 +932,7 @@ class RequestCoalescer:
                 tracker.uninstall(lead_tok)
                 lead_tok = None
             self.router.note_launch(time.perf_counter() - t0, size)
-            self._solo_fallback(members, t_closed_ns, t_begin_ns)
+            self._solo_fallback(members, t_begin_ns)
             return
         finally:
             if lead_tok is not None:
@@ -785,13 +947,35 @@ class RequestCoalescer:
                     mtr.link_from("group_dispatch", span_tr.trace_id,
                                   gsp.span_id, occupancy=size, lane=i)
         self.router.note_launch(time.perf_counter() - t0, size)
+        self._note_lanes(infos)
         t_staged_ns = time.perf_counter_ns()
-        for m, resolve in zip(members, resolvers):
-            self._complete(m, resolve, t_closed_ns, t_begin_ns,
-                           t_staged_ns)
+        solo = []
+        for m, resolve, info in zip(members, resolvers, infos):
+            if resolve is None:
+                solo.append(m)
+                continue
+            # the launch is in the leader's trace as the phase it was
+            # (device_dispatch, under group_dispatch); every other
+            # member gets it as a span of its own trace
+            self._complete(m, resolve, t_begin_ns, t_staged_ns,
+                           None if m.tracker is lead_tr else info)
+        if solo:
+            self._solo_fallback(solo, t_begin_ns)
 
-    def _solo_fallback(self, members, t_closed_ns: int,
-                       t_begin_ns: int) -> None:
+    def _note_lanes(self, infos) -> None:
+        """Count the launches of one staging by their lane count, from
+        the records its lanes came back with."""
+        seen = {}
+        for info in infos:
+            if info is not None:
+                seen[info["t0_ns"]] = info.get("attrs", {}).get("lanes", 1)
+        if not seen:
+            return
+        with self._mu:
+            for k in seen.values():
+                self.lanes_hist[k] = self.lanes_hist.get(k, 0) + 1
+
+    def _solo_fallback(self, members, t_begin_ns: int) -> None:
         from ..device.runner import DeferredResult
         from ..resource_metering import GLOBAL_RECORDER, region_of
         with self._mu:
@@ -821,7 +1005,7 @@ class RequestCoalescer:
                 resolve = d.result
             else:
                 resolve = (lambda r=d: r)
-            self._complete(m, resolve, t_closed_ns, t_begin_ns, t_ns)
+            self._complete(m, resolve, t_begin_ns, t_ns)
 
     def _defer_members(self, key, members) -> None:
         """Re-park DWFQ-deferred members into ``key``'s next
@@ -841,6 +1025,8 @@ class RequestCoalescer:
             # the members return to PARKED state: the close that
             # counted them in-flight is being partially unwound
             self._inflight = max(0, self._inflight - len(members))
+            for m in members:
+                m.t_closed_ns = 0       # its next close sets it again
             if self._shutdown:
                 g = _Group(key, now)
                 g.members.extend(members)
@@ -869,13 +1055,20 @@ class RequestCoalescer:
         if inline is not None:
             self._dispatch(inline)
 
-    def _complete(self, m: _Member, resolve, t_closed_ns: int,
-                  t_begin_ns: int, t_staged_ns: int) -> None:
+    def _complete(self, m: _Member, resolve, t_begin_ns: int,
+                  t_staged_ns: int, launch: Optional[dict] = None
+                  ) -> None:
         """Hand the member's resolution (shared fetch join + its own
         host gather) to the completion pool; its result lands on the
-        member's future for CopDeferred.wait()."""
+        member's future for CopDeferred.wait().  ``launch``: the record
+        of the launch that serves it (``DeferredResult.launch_info``),
+        for a member whose own trace does not hold that launch."""
         from ..resource_metering import GLOBAL_RECORDER, region_of
         from ..utils import tracker
+        # a member's coalesce_wait splits at these two instants: submit
+        # → closed is the collection window, closed → begin the wait
+        # for this (one) dispatcher, the rest the shared staging
+        t_closed_ns = m.t_closed_ns or t_begin_ns
 
         def task():
             tok = tracker.adopt(m.tracker) if m.tracker is not None \
@@ -892,6 +1085,18 @@ class RequestCoalescer:
                                  t_closed_ns, sp)
                 tracker.add_span("dispatch_queue_wait", t_closed_ns,
                                  t_begin_ns, sp)
+                if launch is not None and sp is not None:
+                    # the launch that serves this member, with its
+                    # flight record (compile class, lanes, lane), where
+                    # it fell inside the staging: a span of the trace
+                    # alone (the wait above already covers it in
+                    # phases_ms, and the aggregate has it once, from
+                    # the thread that launched)
+                    mtr = m.tracker
+                    dsp = mtr.begin("device_dispatch", sp,
+                                    launch["t0_ns"])
+                    mtr.end(dsp, launch["t1_ns"])
+                    mtr.annotate_span(dsp, **launch.get("attrs", {}))
                 # group_fetch_wait: this member's join of the group's
                 # shared (memoized) fetch — for the first joiner it
                 # nests the real d2h_wait/host_materialize spans, for
@@ -976,6 +1181,18 @@ class RequestCoalescer:
                 "closes": dict(self.closes),
                 "plan_share_groups": self.plan_share_groups,
                 "plan_share_hits": self.plan_share_hits,
+                "lanes_hist": {str(k): n for k, n in
+                               sorted(self.lanes_hist.items())},
+                "multi_lane_launches": sum(
+                    n for k, n in self.lanes_hist.items() if k > 1),
+                "lanes_sum": sum(k * n
+                                 for k, n in self.lanes_hist.items()),
+                "groups_merged": self.groups_merged,
+                "same_lane_merges": self.same_lane_merges,
+                "lane_class_mismatch": self.lane_class_mismatch,
             }
+        lane_stats = getattr(self._runner, "lane_stats", None)
+        out["unbuilt_fallbacks"] = lane_stats()["unbuilt_fallbacks"] \
+            if lane_stats is not None else 0
         out["router"] = self.router.stats()
         return out
