@@ -1098,3 +1098,84 @@ def test_every_member_of_a_lane_launch_can_show_its_launch(lane_runner):
         assert len(seen) == k and set(seen) <= set(range(k)), seen
     finally:
         rig.close()
+
+
+# ------------------------------------------- lanes that differ in constants
+
+
+def const_dag(i, threshold, grouped):
+    """The lane plan under a predicate whose constant differs from
+    request to request: one const-blind compile class."""
+    import dataclasses
+    s = DagSelect.from_table(lane_table(), ["id", "k", "v"])
+    dag = s.where(s.col("v") > threshold).aggregate(
+        [s.col("k")] if grouped else [],
+        [("count_star", None), ("sum", s.col("v"))]).build()
+    return dataclasses.replace(dag, start_ts=i + 1)
+
+
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["group_by", "no_group_by"])
+def test_lanes_of_one_class_carry_their_own_constants(lane_runner, grouped):
+    """An aggregation's constants are operands of its kernel, so closed
+    groups that differ in them ALONE (and in their feeds) have one launch
+    class: they leave as lanes of one launch, each lane's scalars its own
+    constants, each answer its own.  One kernel entry serves them all,
+    GROUP BY or not."""
+    thresholds = (-500, 0, 250, 900)
+    rig = LaneRig(lane_runner, [lane_snapshot(s) for s in range(4)])
+    try:
+        for i, c in enumerate(thresholds):      # every line learns the class
+            rig.one(const_dag(i, thresholds[0], grouped))
+        rig.wait_built()
+        entries = [k for k in lane_runner._kernel_cache if k[0] == "hashpl"]
+        assert len(entries) == 1, entries
+        first0 = lane_runner.flight_recorder.stats()["first_launches"]
+        launches0 = lane_runner.flight_recorder.stats()["launches"]
+        dags = [const_dag(i, c, grouped) for i, c in enumerate(thresholds)]
+        classes = {lane_runner.launch_class(
+            lane_runner.batch_class(d, rig.snaps[i]), d, rig.snaps[i])
+            for i, d in enumerate(dags)}
+        assert len(classes) == 1 and None not in classes
+        out = rig.together(dags, 4)
+        for i, (dag, got) in enumerate(zip(dags, out)):
+            want = BatchExecutorsRunner(
+                dag, rig.snaps[i]).handle_request().rows()
+            assert sorted(got.rows()) == sorted(want), i
+        assert len({tuple(sorted(r.rows())) for r in out}) == 4
+        stats = lane_runner.flight_recorder.stats()
+        assert stats["launches"] - launches0 < 4        # fused
+        assert stats["first_launches"] == first0        # nothing built
+        assert [k for k in lane_runner._kernel_cache
+                if k[0] == "hashpl"] == entries
+        assert lanes_of(rig)["multi_lane_launches"] >= 1
+        assert lanes_of(rig)["lane_class_mismatch"] == 0
+        assert all(e["params"] == 1 for e in
+                   lane_runner.flight_recorder.items())
+    finally:
+        rig.close()
+
+
+def test_a_memo_is_shared_by_constants_and_split_by_the_key(runner):
+    """What a request's memo holds is a property of the data and of the
+    GROUP BY key: two constant tuples share one, another key constant
+    has its own (its key bounds differ)."""
+    def dag(threshold, shift):
+        s = DagSelect.from_table(lane_table(), ["id", "k", "v"])
+        return s.where(s.col("v") > threshold).aggregate(
+            [s.col("k") + shift],
+            [("count_star", None), ("sum", s.col("v"))]).build()
+
+    def key(d):
+        return runner._meta_key(d, runner._analyze(d))
+
+    assert key(dag(5, 1)) == key(dag(700, 1))
+    assert key(dag(5, 1)) != key(dag(5, 2))
+    assert dag(5, 1).plan_key() != dag(700, 1).plan_key()
+    snap = lane_snapshot(11)
+    for threshold, shift in ((5, 1), (700, 1), (5, 2)):
+        d = dag(threshold, shift)
+        assert sorted(runner.handle_request(d, snap).rows()) == sorted(
+            BatchExecutorsRunner(d, snap).handle_request().rows())
+    metas = [k for k in runner._arena.bucket(snap) if k[0] == "meta"]
+    assert len(metas) == 2

@@ -24,7 +24,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional, Sequence, Union
 
-from ..datatype import EvalType, FieldType, device_const_dtype
+from ..datatype import EvalType, FieldType, const_bucket
 from ..expr import Expr
 
 
@@ -145,7 +145,9 @@ class DAGRequest:
         """Const-blind COMPILE-CLASS identity: ``plan_key`` with numeric
         constant VALUES erased (bucketed by device dtype only).  Two
         requests differing solely in predicate/aggregate int/float
-        constants map to one class — the same hoisted-parameter grid the
+        constants (DECIMAL ones included: bucketed by scale and by the
+        dtype of their scaled integer) map to one class — the same
+        hoisted-parameter grid the
         device selection kernels share one trace over
         (device/selection.py split_params/shape_key) — so per-class
         service-time EWMAs (read-pool shedding) and the cross-request
@@ -155,11 +157,11 @@ class DAGRequest:
         def expr_key(e: Expr):
             if e.kind == "const":
                 v = e.value
-                if isinstance(v, bool) or v is None or \
-                        not isinstance(v, (int, float)):
+                bucket = const_bucket(v)
+                if bucket is None:
                     return ("c", repr(v),
                             e.eval_type.value if e.eval_type else None)
-                return ("c?", device_const_dtype(v),
+                return ("c?", bucket,
                         e.eval_type.value if e.eval_type else None)
             if e.kind == "column":
                 return ("col", e.col_idx,

@@ -109,6 +109,7 @@ import numpy as np
 from ..codec import decode_record_handle, decode_row
 from ..codec.keys import table_record_range
 from ..datatype import Column
+from ..datatype.mydecimal import to_scaled
 from ..engine.traits import CF_DEFAULT, CF_LOCK, CF_WRITE
 from ..executors.columnar import ColumnarTable
 from ..storage.mvcc.reader import _PAST_VERSIONS, MvccReader, \
@@ -135,13 +136,30 @@ class _TableShim:
 
 from ..datatype import EvalType
 
-# native builder kind codes (fastbuild.cpp Col.kind)
+# native builder kind codes (fastbuild.cpp Col.kind).  DECIMAL (4) is
+# the scaled form (Column.frac): the column's values times ten to the
+# scale its FieldType declares, as int64.
 _NATIVE_KINDS = {
     EvalType.INT: 0, EvalType.DURATION: 0,
     EvalType.REAL: 1,
     EvalType.BYTES: 2,
     EvalType.DATETIME: 3, EvalType.ENUM: 3, EvalType.SET: 3,
+    EvalType.DECIMAL: 4,
 }
+# an int64 holds every value of up to 18 digits
+_MAX_SCALED_DIGITS = 18
+
+
+def scaled_frac(ft) -> Optional[int]:
+    """The scale a DECIMAL column of FieldType ``ft`` is held scaled at,
+    or None where its type does not fix one an int64 can carry (then it
+    stays an object column of ``Decimal``s, as the interpreted build
+    makes it)."""
+    if ft.eval_type is not EvalType.DECIMAL or \
+            not 0 <= ft.decimal <= _MAX_SCALED_DIGITS or \
+            not ft.decimal <= ft.flen <= _MAX_SCALED_DIGITS:
+        return None
+    return ft.decimal
 
 
 def _scan_blocking_locks(snap, lower: bytes, upper: bytes):
@@ -165,18 +183,25 @@ def _build_native(snap, table_id: int, col_infos: Sequence, read_ts: int):
     rng = getattr(snap, "range_cf", None)
     if rng is None:
         return None
-    ids, kinds = [], []
+    from ..utils import tracker
+    ids, kinds, scales = [], [], []
     for info in col_infos:
         if info.is_pk_handle:
             continue
         ft = info.field_type
         kind = _NATIVE_KINDS.get(ft.eval_type)
         if kind is None or info.default_value is not None:
-            return None     # DECIMAL/JSON payloads or non-NULL defaults
+            return None     # JSON payloads or non-NULL defaults
         if kind == 0 and ft.is_unsigned:
             kind = 3        # unsigned BIGINT: values live above 2^63
+        frac = 0
+        if kind == 4:
+            frac = scaled_frac(ft)
+            if frac is None:
+                return None     # a DECIMAL without a scale int64 carries
         ids.append(info.col_id)
         kinds.append(kind)
+        scales.append(frac)
     lo, hi = table_record_range(table_id)
     got = rng(CF_WRITE, encode_key(lo), encode_key(hi))
     if got is None:
@@ -184,17 +209,23 @@ def _build_native(snap, table_id: int, col_infos: Sequence, read_ts: int):
     keys, vals, skip = got
     try:
         out = mvcc_build_columnar(keys, vals, read_ts, skip,
-                                  tuple(ids), tuple(kinds))
+                                  tuple(ids), tuple(kinds), tuple(scales))
     except ValueError:
-        # stored row payloads can hold datums outside the native
-        # envelope (DECIMAL ExtType datums of *unrequested* columns, exotic
-        # tags): the interpreted path is the behavioral reference
+        # a stored datum outside the native envelope: a DECIMAL beyond
+        # its column's declared scale or int64, a type the column does
+        # not have, an exotic tag.  The interpreted path is the
+        # behavioral reference (a datum of a column nobody asked for
+        # is read past, whatever it is).
         return None
+    # on the columnar_build span: what the build made of the rows
+    tracker.annotate(decimal_cols=sum(1 for k in kinds if k == 4),
+                     skipped_datums=out.get("skipped_datums", 0))
 
     n = out["n"]
     handles = np.frombuffer(out["handles"], dtype=np.int64)
     columns: dict = {}
-    np_dtypes = {0: np.int64, 1: np.float64, 3: np.uint64}
+    np_dtypes = {0: np.int64, 1: np.float64, 3: np.uint64, 4: np.int64}
+    frac_of = dict(zip(ids, scales))
     by_id = {}
     for col_id, kind, payload, validity in out["cols"]:
         valid = np.frombuffer(validity, dtype=np.bool_)
@@ -210,7 +241,8 @@ def _build_native(snap, table_id: int, col_infos: Sequence, read_ts: int):
             values = np.frombuffer(payload, dtype=np_dtypes[kind])
         et = next(info.field_type.eval_type for info in col_infos
                   if not info.is_pk_handle and info.col_id == col_id)
-        col = Column(et, values, valid)
+        col = Column(et, values, valid,
+                     frac_of[col_id] if kind == 4 else None)
         columns[col_id] = col
         by_id[col_id] = col
     # big values (> SHORT_VALUE_MAX_LEN) live in CF_DEFAULT: batch the
@@ -232,6 +264,10 @@ def _build_native(snap, table_id: int, col_infos: Sequence, read_ts: int):
             for col_id, pv in payload_row.items():
                 slot = per_col.get(col_id)
                 if slot is not None and pv is not None:
+                    if by_id[col_id].frac is not None:
+                        pv = to_scaled(pv, by_id[col_id].frac)
+                        if pv is None:
+                            return None     # beyond the declared scale
                     slot[0].append(row)
                     slot[1].append(pv)
         for col_id, (rows_idx, vals_list) in per_col.items():
@@ -523,8 +559,9 @@ class MvccColumnarSnapshot:
                     check_lock_conflict(lock, key, read_ts, bypass_locks)
                     break
 
-    def scan_columns(self, desc: TableScanDesc, ranges):
-        return self._tbl.scan_columns(desc, ranges)
+    def scan_columns(self, desc: TableScanDesc, ranges,
+                     scaled: bool = False):
+        return self._tbl.scan_columns(desc, ranges, scaled=scaled)
 
     def to_kv_pairs(self, ranges=None):
         """Logical row pairs for the CHECKSUM admin request."""
@@ -651,9 +688,9 @@ class _LineState:
     publish time, so concurrent scans never observe a torn patch.
     """
 
-    __slots__ = ("table_id", "col_meta", "cap", "n", "n_dead", "handles",
-                 "cols", "alive", "locks", "safe_ts", "build_ts",
-                 "lineage")
+    __slots__ = ("table_id", "col_meta", "col_frac", "cap", "n", "n_dead",
+                 "handles", "cols", "alive", "locks", "safe_ts",
+                 "build_ts", "lineage")
 
     SLACK_MIN = 256
 
@@ -664,6 +701,10 @@ class _LineState:
         self.col_meta = {info.col_id: (info.field_type.eval_type,
                                        info.default_value)
                          for info in col_infos if not info.is_pk_handle}
+        # col_id -> scale, for the DECIMAL columns the build left scaled
+        # (Column.frac): every later row of theirs is scaled alike
+        self.col_frac = {cid: col.frac for cid, col in tbl.columns.items()
+                         if col.frac is not None}
         n = len(tbl.handles)
         self.n = n
         self.n_dead = 0
@@ -688,7 +729,7 @@ class _LineState:
     def publish(self) -> MvccColumnarSnapshot:
         n = self.n
         columns = {cid: Column(self.col_meta[cid][0], bufs[0][:n],
-                               bufs[1][:n])
+                               bufs[1][:n], self.col_frac.get(cid))
                    for cid, bufs in self.cols.items()}
         alive = self.alive[:n] if self.alive is not None else None
         tbl = ColumnarTable.__new__(ColumnarTable)
@@ -721,6 +762,25 @@ class _LineState:
         for cid, (_et, default) in self.col_meta.items():
             v = payload.get(cid, default)
             out[cid] = (v, v is not None)
+        return out
+
+    def scaled_payload(self, payload: dict) -> Optional[dict]:
+        """``payload`` with each scaled column's ``Decimal`` as the
+        integer the line holds, or None where one does not fit its
+        column's scale or int64: the line cannot take the row, and the
+        caller rebuilds it (the interpreted build then keeps that
+        column as objects)."""
+        if not self.col_frac:
+            return payload
+        out = dict(payload)
+        for cid, frac in self.col_frac.items():
+            v = out.get(cid)
+            if v is None:
+                continue
+            v = to_scaled(v, frac) if hasattr(v, "scaleb") else None
+            if v is None:
+                return None
+            out[cid] = v
         return out
 
     def _cow_columns(self) -> None:
@@ -1145,6 +1205,7 @@ class RegionColumnarCache:
         child = _LineState.__new__(_LineState)
         child.table_id = st.table_id
         child.col_meta = dict(st.col_meta)
+        child.col_frac = dict(st.col_frac)
         n = hi - lo
         child.n = n
         cap = n + max(_LineState.SLACK_MIN, n >> 3)
@@ -1533,8 +1594,12 @@ class RegionColumnarCache:
                     deletes.append(pos)
                 continue
             payload = self._resolve_payload(snap, d)
+            if payload is not None:
+                payload = state.scaled_payload(payload)
             if payload is None:
-                return None     # spilled value unavailable: rebuild
+                # spilled value unavailable, or a DECIMAL beyond the
+                # scale its column is held at: rebuild
+                return None
             if present:
                 (revives if dead else updates).append((pos, payload))
             else:

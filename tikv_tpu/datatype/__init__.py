@@ -10,7 +10,7 @@ shapes so XLA sees fixed shapes (SURVEY.md §7 "Dynamic shapes").
 """
 
 from .eval_type import (EvalType, FieldType, FieldTypeFlag, FieldTypeTp,
-                        device_const_dtype)
+                        const_bucket, device_const_dtype)
 from .column import Column, ColumnBatch
 from .tile import Tile, TileBatch, pad_to_tile, TILE_ROWS
 

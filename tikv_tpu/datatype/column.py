@@ -28,15 +28,25 @@ class Column:
     be a *harmless* value — 0 — so device kernels never see NaN/garbage).
 
     For BYTES/JSON, ``values`` is a 1-D object array of ``bytes``.
+
+    A DECIMAL column has two forms.  The host pipeline's is an object
+    array of ``decimal.Decimal`` (``frac`` None).  Where the column's
+    FieldType fixes its scale and every value fits it, the columnar
+    cache holds it SCALED: ``values`` is an int64 array of
+    ``value * 10**frac`` (DECIMAL(15,2) 0.06 is 6) and ``frac`` that
+    scale, the form the device feeds are cut from.  ``unscaled()``
+    gives the object form; the two never mix in one column.
     """
 
-    __slots__ = ("eval_type", "values", "validity")
+    __slots__ = ("eval_type", "values", "validity", "frac")
 
-    def __init__(self, eval_type: EvalType, values: np.ndarray, validity: np.ndarray):
+    def __init__(self, eval_type: EvalType, values: np.ndarray,
+                 validity: np.ndarray, frac: Optional[int] = None):
         assert values.shape == validity.shape, (values.shape, validity.shape)
         self.eval_type = eval_type
         self.values = values
         self.validity = validity
+        self.frac = frac
 
     # -- constructors -------------------------------------------------------
 
@@ -99,11 +109,26 @@ class Column:
     def __len__(self) -> int:
         return len(self.values)
 
+    def unscaled(self) -> "Column":
+        """The host pipeline's form of this column: a scaled DECIMAL as
+        an object array of ``Decimal``s at its scale (exact: an int
+        and a power of ten); any other column is itself."""
+        if self.frac is None:
+            return self
+        from .mydecimal import from_scaled
+        values = np.empty(len(self.values), dtype=object)
+        frac = self.frac
+        values[:] = [from_scaled(v, frac) for v in self.values.tolist()]
+        return Column(self.eval_type, values, self.validity)
+
     def get(self, i: int):
         """Scalar accessor: value or None."""
         if not self.validity[i]:
             return None
         v = self.values[i]
+        if self.frac is not None:
+            from .mydecimal import from_scaled
+            return from_scaled(int(v), self.frac)
         if isinstance(v, np.generic):
             return v.item()
         return v
@@ -117,22 +142,28 @@ class Column:
     # -- mutation (builder-style; used by executors assembling output) ------
 
     def take(self, indices: np.ndarray) -> "Column":
-        return Column(self.eval_type, self.values[indices], self.validity[indices])
+        return Column(self.eval_type, self.values[indices],
+                      self.validity[indices], self.frac)
 
     def filter(self, mask: np.ndarray) -> "Column":
-        return Column(self.eval_type, self.values[mask], self.validity[mask])
+        return Column(self.eval_type, self.values[mask],
+                      self.validity[mask], self.frac)
 
     def slice(self, start: int, stop: int) -> "Column":
-        return Column(self.eval_type, self.values[start:stop], self.validity[start:stop])
+        return Column(self.eval_type, self.values[start:stop],
+                      self.validity[start:stop], self.frac)
 
     @staticmethod
     def concat(cols: Sequence["Column"]) -> "Column":
         assert cols
         et = cols[0].eval_type
+        if len({c.frac for c in cols}) > 1:
+            cols = [c.unscaled() for c in cols]
         return Column(
             et,
             np.concatenate([c.values for c in cols]),
             np.concatenate([c.validity for c in cols]),
+            cols[0].frac,
         )
 
     def __repr__(self) -> str:

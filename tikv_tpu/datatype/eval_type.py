@@ -215,3 +215,22 @@ def device_const_dtype(v) -> str:
     if isinstance(v, float):
         return "float32"
     return "int32" if -(2 ** 31) <= v < 2 ** 31 else "int64"
+
+
+def const_bucket(v):
+    """The compile-class bucket of a constant that rotates within a
+    class, or None for one that does not (str / bytes / None / bool
+    constants are class identity): ``device_const_dtype`` for an int or
+    a float; for a finite ``Decimal``, which the device holds as a
+    scaled integer (device/lowering.py), its scale, which shapes the
+    lowered expression, and the dtype of that integer.  What
+    ``DAGRequest.class_key`` keeps of a constant and what a fast-path
+    slot guards (server/fastpath.py): one function, so they cannot
+    drift."""
+    import decimal
+    if type(v) in (int, float):
+        return device_const_dtype(v)
+    if type(v) is decimal.Decimal and v.is_finite():
+        frac = max(0, -v.as_tuple().exponent)
+        return ("decimal", frac, device_const_dtype(int(v.scaleb(frac))))
+    return None

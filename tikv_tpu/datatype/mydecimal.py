@@ -10,7 +10,10 @@ a 65-digit fixed-point type with
 The reference implements its own 9-digits-per-word bignum; here the host
 representation IS ``decimal.Decimal`` (arbitrary precision, exact), with
 this module supplying the MySQL-specific scale/rounding envelope.  The
-device path never sees DECIMAL (DeviceRunner gates on INT/REAL).
+device sees a DECIMAL column only as a SCALED integer plane
+(``to_scaled`` / ``from_scaled``: the value times ten to its FieldType's
+scale), and decimal arithmetic as the integer arithmetic
+device/lowering.py proves equal to it.
 """
 
 from __future__ import annotations
@@ -28,6 +31,29 @@ CTX = decimal.Context(prec=WORD_BUF_LEN_MAX_DIGITS,
                       rounding=decimal.ROUND_HALF_UP)
 
 ZERO = Decimal(0)
+
+
+_I64_MIN, _I64_MAX = -(1 << 63), (1 << 63) - 1
+
+
+def to_scaled(d: Decimal, frac: int) -> Optional[int]:
+    """``d * 10**frac`` as an int where that is exact and inside int64
+    (the scaled form of a DECIMAL(p, frac) column), else None: a value
+    with more digits right of the point than ``frac``, a NaN, an
+    infinity."""
+    if not d.is_finite():
+        return None
+    scaled = d.scaleb(frac, context=CTX)
+    if scaled != scaled.to_integral_value():
+        return None
+    v = int(scaled)
+    return v if _I64_MIN <= v <= _I64_MAX else None
+
+
+def from_scaled(v: int, frac: int) -> Decimal:
+    """The Decimal a scaled integer stands for, at exactly ``frac``
+    digits right of the point (6 at scale 2 is Decimal('0.06'))."""
+    return Decimal(int(v)).scaleb(-frac, context=CTX)
 
 
 def frac_of(d: Decimal) -> int:

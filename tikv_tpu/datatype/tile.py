@@ -32,7 +32,28 @@ from .eval_type import EvalType
 TILE_ROWS = 1 << 20
 
 
+# The packed datetime core (datatype/time.py) keeps hour, minute,
+# second and microsecond in its low 41 bits.  A column whose FieldType
+# is DATE has them all zero, so ``core >> DATE_SHIFT`` (year, month, day:
+# under 2**23 for any year below 8192) is a lossless, order-preserving
+# int32 plane: the form in which a DATE column reaches the Pallas kernel,
+# which takes int32 inputs only.
+DATE_SHIFT = 41
+
+
+def date_plane(values: np.ndarray) -> np.ndarray:
+    """A DATE column's packed cores as its int32 plane."""
+    return (values >> np.uint64(DATE_SHIFT)).astype(np.int32)
+
+
 def _device_dtype(eval_type: EvalType, values: np.ndarray) -> np.dtype:
+    if eval_type is EvalType.DECIMAL:
+        # the scaled form only (Column.frac): an int64 array narrowed
+        # by its values as any INT column is
+        if values.dtype.kind != "i":
+            raise ValueError("an unscaled DECIMAL column has no "
+                             "device-native representation")
+        eval_type = EvalType.INT
     if eval_type in (EvalType.INT, EvalType.DURATION):
         if values.size and (values.min() < -(2**31) or values.max() >= 2**31):
             return np.dtype(np.int64)

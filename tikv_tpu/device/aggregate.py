@@ -47,6 +47,7 @@ from jax.sharding import SingleDeviceSharding
 
 from .. import native
 from ..datatype import Column, EvalType, FieldType
+from ..datatype.mydecimal import from_scaled
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef
 from ..ops.agg import (
@@ -220,7 +221,7 @@ class DeviceAggregator:
         n_cols = len(plan.used_cols)
 
         agg_out = self._agg_out(plan)
-        schema = agg_out[0] + [FieldType.long()]
+        schema = list(agg_out[0]) + [FieldType.long()]
 
         def result(cols):
             return runner._result(dag, list(schema), cols)
@@ -430,13 +431,17 @@ class DeviceAggregator:
         out = plan.agg_out
         if out is None:
             from ..executors.aggregation import _agg_ret_ft
+            # a lowered DECIMAL's SUM is typed as the host pipeline
+            # types it (the argument was a DECIMAL before the lowering)
+            fracs = tuple(plan.agg_fracs) or (None,) * len(plan.specs)
             fts = [_agg_ret_ft(spec.kind,
-                               spec.eval_type if spec.kind not in
+                               EvalType.DECIMAL if frac is not None
+                               else spec.eval_type if spec.kind not in
                                ("count", "count_star") else None)
-                   for spec in plan.specs]
+                   for spec, frac in zip(plan.specs, fracs)]
             out = plan.agg_out = (fts, [
                 np.dtype(np.uint64) if ft.is_unsigned
-                else ft.eval_type.np_dtype for ft in fts])
+                else ft.eval_type.np_dtype for ft in fts], fracs)
         return out
 
     def _packed_columns(self, plan, parts, LO, p8, layouts, slots, base,
@@ -495,6 +500,8 @@ class DeviceAggregator:
         """
         runner = self._runner
         sparse = mode == pallas_hash.MODE_SPARSE
+        # the request's constants, operands of the const-blind kernel
+        _sel, _aggs, pvals, pdts = pallas_hash.plan_params(plan)
         B = pallas_hash.BLOCK
         total_blocks = feed["n_pad"] // B
         tiles = []          # (row_lo, row_hi, blk0, span_blocks)
@@ -541,7 +548,8 @@ class DeviceAggregator:
                     plan, layouts, p8, capacity,
                     feed["n_pad"] // (S * B), col_map, mode=mode)
                 return {"sharded": self._pallas_sharded_wrap(
-                    run, len(cols), feed["n_pad"] // S), "LO": LO}
+                    run, len(cols), feed["n_pad"] // S, len(pdts)),
+                    "LO": LO}
             runs_by_nb = {}
             LO = None
             for nb in sorted({t[3] for t in tiles}):
@@ -557,17 +565,27 @@ class DeviceAggregator:
             """One launch of a built kernel → its packed parts, still
             on the device (one on a mesh, one a tile; they add)."""
             if "sharded" in entry:
+                # (a plan without constants passes what it always did)
+                consts = (self._param_vector(entry, pvals),) if pvals \
+                    else ()
                 return [entry["sharded"](
                     runner._cached_scalar(n, jnp.int64),
-                    runner._cached_scalar(base, jnp.int64), *cols)]
+                    runner._cached_scalar(base, jnp.int64), *consts,
+                    *cols)]
             runs_by_nb = entry["runs"]
-            return [runs_by_nb[nb](lo, hi, base, blk0, cols)
+            return [runs_by_nb[nb](lo, hi, base, blk0, cols, pvals)
                     for lo, hi, blk0, nb in tiles]
 
-        key = ("hashpl", dag.plan_key(), mode,
+        # const-blind, as the selection route's kernels are: the plan's
+        # class (constants by device dtype bucket, ``class_key``), the
+        # GROUP BY key's constants by value (the key bounds a kernel is
+        # built for depend on them) and the operands' dtypes.  Every
+        # constant tuple of a prepared statement shares one built
+        # kernel; a constant that crosses a bucket is a new class.
+        key = ("hashpl", dag.class_key(), pallas_hash.key_consts(plan), mode,
                tuple(sorted({t[3] for t in tiles})), tuple(dtypes),
                capacity, arg_nbytes, tuple(arg_ok_is_mask),
-               runner._nshards())
+               runner._nshards(), pdts)
         cache = runner._kernel_cache
         entry = cache.get(key)
         if entry is False:
@@ -577,14 +595,15 @@ class DeviceAggregator:
         if lanes and whole and not first:
             (lo, hi, blk0, nb), = tiles
             return False, _LanePending(
-                (key, entry, entry["runs"][nb], (lo, hi, base, blk0)),
-                cols), entry["LO"]
+                (key, entry, entry["runs"][nb],
+                 (lo, hi, base, blk0, pvals), mode), cols), entry["LO"]
         try:
             # the first build is a launch like any other: its compile
             # wall and class land in the flight recorder
             # (first_launch=True), and a rejected build counts as a
             # recorder fault before the XLA fallback serves
-            with runner._dispatch_phase("pallas_hash", key):
+            with runner._dispatch_phase("pallas_hash", key,
+                                        params=len(pvals), slot_mode=mode):
                 if first:
                     entry = build()
                     if whole:
@@ -608,6 +627,8 @@ class DeviceAggregator:
             return None
         if first:
             cache[key] = entry
+            if pdts:
+                runner.flight_recorder.note_const_class()
         # success clears the transient strike count — three isolated
         # hiccups over a process lifetime must not kill the fast path
         cache.pop(("hashpl_tries", key), None)
@@ -657,7 +678,10 @@ class DeviceAggregator:
                 k, prog = self._lane_program(entry, key, todo)
                 batch, todo = todo[:k], todo[k:]
                 try:
-                    with runner._dispatch_phase("pallas_hash", key) as info:
+                    with runner._dispatch_phase(
+                            "pallas_hash", key,
+                            params=len(batch[0].kernel[3][4]),
+                            slot_mode=batch[0].kernel[4]) as info:
                         if k > 1:
                             trace.annotate(lanes=k)
                             with jax.enable_x64(False):
@@ -666,9 +690,11 @@ class DeviceAggregator:
                                           for p in batch),
                                     tuple(p.cols for p in batch))
                         else:
-                            _key, _entry, run, (lo, hi, base, blk0) = \
+                            _key, _entry, run, bounds, _mode = \
                                 batch[0].kernel
-                            out = [run(lo, hi, base, blk0, batch[0].cols)]
+                            lo, hi, base, blk0, pvals = bounds
+                            out = [run(lo, hi, base, blk0, batch[0].cols,
+                                       pvals)]
                     launch = _LaneLaunch(runner, out)
                 except Exception as e:  # noqa: BLE001 — members go solo
                     self._lane_launch_failed(entry, key, k, e)
@@ -765,7 +791,8 @@ class DeviceAggregator:
         with runner._device_scope(), jax.enable_x64(False):
             cols = tuple(jnp.zeros(shape, dtype)
                          for shape, dtype in entry["in_shapes"])
-            args = (run.scalars(0, 0, 0, 0),) * k, (cols,) * k
+            args = (run.scalars(0, 0, 0, 0, (0,) * len(key[-1])),) * k, \
+                (cols,) * k
             pinned = None
             if HOST_STAGER.enabled is None:     # not probed yet
                 HOST_STAGER.stage(jnp.zeros((8,), jnp.int32))
@@ -842,7 +869,21 @@ class DeviceAggregator:
                 "%r: %s: %s", key[1], name, e)
             cache[key] = False
 
-    def _pallas_sharded_wrap(self, run, n_in: int, n_local_pad: int):
+    def _param_vector(self, entry: dict, pvals: tuple):
+        """A sharded kernel's constants as one int32 vector replicated
+        over the mesh, cached by value on its entry (a tuple seen
+        before costs no H2D; bounded as ``pallas_hash``'s scalars)."""
+        cache = entry.setdefault("param_vectors", {})
+        vec = cache.get(pvals)
+        if vec is None:
+            if len(cache) >= 8192:
+                cache.clear()
+            vec = cache[pvals] = jax.device_put(
+                np.asarray(pvals, np.int32), self._runner._repl)
+        return vec
+
+    def _pallas_sharded_wrap(self, run, n_in: int, n_local_pad: int,
+                             n_params: int = 0):
         """shard_map wrapper for the fused kernel: each shard runs one
         grid over its LOCAL feed slice (row bounds traced from the
         shard index — the kernel's dead-block guard masks the ragged
@@ -855,16 +896,22 @@ class DeviceAggregator:
         the psum makes the output replicated by construction."""
         runner = self._runner
 
-        def pallas_hash_sharded(n_arr, base_arr, *cols_local):
+        def pallas_hash_sharded(n_arr, base_arr, *rest):
+            # the plan's constants, where it has any: one replicated
+            # int32 vector before the columns (``_param_vector``)
+            params = tuple(rest[0][j] for j in range(n_params)) \
+                if n_params else ()
+            cols_local = rest[1:] if n_params else rest
             start = runner._shard_index() * n_local_pad
             row_hi = jnp.clip(n_arr - start, 0, n_local_pad)
             packed = run(jnp.asarray(0, jnp.int32), row_hi, base_arr,
-                         jnp.asarray(0, jnp.int32), cols_local)
+                         jnp.asarray(0, jnp.int32), cols_local, params)
             return lax.psum(packed, ROW_AXES)
 
         return jax.jit(jax.shard_map(
             pallas_hash_sharded, mesh=runner._mesh,
-            in_specs=(P(), P()) + (P(ROW_AXES),) * n_in,
+            in_specs=(P(), P()) + ((P(),) if n_params else ()) +
+            (P(ROW_AXES),) * n_in,
             out_specs=P(), check_vma=False))
 
     def _bucket_blocks(self, blocks: int) -> int:
@@ -1283,9 +1330,10 @@ class DeviceAggregator:
 
     # -- simple aggregation --
 
-    def run_simple(self, dag, plan, host_cols, dtypes, n, feed, meta):
+    def run_simple(self, dag, plan, host_cols, dtypes, n, feed, meta,
+                   lanes: bool = False):
         """One aggregation without GROUP BY over ``feed`` → a finished
-        result or a ``_Pending``."""
+        result or a ``_Pending`` (``lanes``: as ``run_hash``)."""
         runner = self._runner
         # the fused Pallas kernel serves simple aggregations too (r6):
         # a single-slot grid turns SUM/COUNT/AVG into one direct-index
@@ -1308,7 +1356,7 @@ class DeviceAggregator:
                       layouts, p8, pf, 1, mode, False)[0] == "pallas_hash":
             got = self._try_pallas(dag, plan, feed, dtypes, n, 0, 1,
                                    layouts, p8, arg_nbytes, arg_ok_is_mask,
-                                   mode)
+                                   mode, meta=meta, lanes=lanes)
             if got is not None:
                 synced, parts, LO = got
 
@@ -1319,6 +1367,9 @@ class DeviceAggregator:
                                for k, v in s.items()} for s in states]
                     return self._simple_result(dag, plan, merged)
 
+                if isinstance(parts, _LanePending):
+                    parts.finalize = from_packed
+                    return parts
                 return from_packed(parts) if synced \
                     else _Pending(parts, from_packed)
 
@@ -1351,9 +1402,12 @@ class DeviceAggregator:
 
     def _simple_result(self, dag, plan, merged):
         finals = finalize_simple(plan.specs, merged)
-        fts = self._agg_out(plan)[0]
-        cols = [Column.from_list(ft.eval_type, [val])
-                for ft, val in zip(fts, finals)]
+        fts, _dts, fracs = self._agg_out(plan)
+        cols = [Column.from_list(
+            ft.eval_type,
+            [val if frac is None or val is None
+             else from_scaled(val, frac)])
+            for ft, val, frac in zip(fts, finals, fracs)]
         return self._runner._result(dag, list(fts), cols)
 
 
@@ -1510,7 +1564,13 @@ def _hash_columns(agg_out, finalized):
     ``DeviceAggregator._agg_out`` of the plan; ``finalized``:
     ``finalize_hash``'s ``((keys, key_valid), planes)``."""
     (keys, key_valid), planes = finalized
+    fts, dts, fracs = agg_out
     cols = [Column(ft.eval_type, vals.astype(dt, copy=False), ok)
-            for ft, dt, (vals, ok) in zip(*agg_out, planes)]
+            if frac is None else
+            # a lowered DECIMAL's SUM: the exact integer sums, handed
+            # on scaled (the host form is made where a row is encoded)
+            Column(ft.eval_type, np.asarray(vals, np.int64), ok,
+                   frac).unscaled()
+            for ft, dt, frac, (vals, ok) in zip(fts, dts, fracs, planes)]
     cols.append(Column(EvalType.INT, keys, key_valid))
     return cols

@@ -147,8 +147,11 @@ def make_planes(layouts, specs, cols, mask):
             nb = lay.nb
             v64 = values.astype(jnp.int64) if nb > 4 else \
                 values.astype(jnp.int32)
-            biased = (v64 + (1 << (8 * nb - 1))).astype(
-                jnp.uint64 if nb > 4 else jnp.uint32)
+            # the bias is added UNSIGNED, where it wraps: at nb = 4 / 8
+            # it is 2^31 / 2^63, which the signed width cannot hold (a
+            # computed argument is as wide as its planes' dtype)
+            udt = np.uint64 if nb > 4 else np.uint32
+            biased = v64.astype(udt) + udt(1 << (8 * nb - 1))
             for k in range(nb):
                 byte = ((biased >> (8 * k)) & 0xFF).astype(jnp.int32) - 128
                 int8_planes.append(

@@ -93,7 +93,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..expr.eval import eval_rpn
-from ..expr.rpn import RpnColumnRef
+from ..expr.rpn import RpnColumnRef, RpnConst
+from .selection import split_params
 
 # Rows per grid step.  Swept on v5e at 100M rows (r5): 2^18 beats 2^17
 # by ~3.5 ms/pass (fewer ~10 us grid steps) and 2^19 regresses (VMEM
@@ -122,6 +123,42 @@ _i32 = jnp.int32
 
 def _rpn_cols(rpn) -> set:
     return {n.col_idx for n in rpn.nodes if isinstance(n, RpnColumnRef)}
+
+
+def plan_params(plan) -> tuple:
+    """An aggregation's predicate and aggregate constants as kernel
+    OPERANDS → ``(sel_rpns, agg_rpns, values, dtypes)``: the plan's
+    selection and aggregate-argument expressions with every numeric
+    constant replaced by a reference to parameter ``len(used_cols) + i``
+    (``selection.split_params``, the selection route's own hoisting),
+    the constants' values in that order, and their device dtype
+    buckets.  The expressions are the same for every constant tuple of
+    the plan's const-blind class (``DAGRequest.class_key``), so ONE
+    built kernel serves them all and the values ride its
+    scalar-prefetch operand beside the row bounds.  The GROUP BY key's
+    constants stay in its expression: the key bounds a kernel is built
+    for depend on them.  Memoized on the plan."""
+    got = plan.agg_params
+    if got is None:
+        n_sel = len(plan.sel_rpns)
+        rpns, vals, dts = split_params(
+            list(plan.sel_rpns) +
+            [r for r in plan.agg_rpns if r is not None],
+            len(plan.used_cols))
+        aggs = iter(rpns[n_sel:])
+        got = plan.agg_params = (
+            rpns[:n_sel],
+            [None if r is None else next(aggs) for r in plan.agg_rpns],
+            vals, dts)
+    return got
+
+
+def key_consts(plan) -> tuple:
+    """The GROUP BY key expression's constants, by value: the part of a
+    const-blind identity (the kernel's cache key, the request memo's)
+    that stays exact, since the key bounds depend on them."""
+    return () if plan.key_rpn is None else tuple(
+        nd.value for nd in plan.key_rpn.nodes if isinstance(nd, RpnConst))
 
 
 def kernel_col_ids(plan, mode: str) -> tuple:
@@ -183,6 +220,8 @@ def supported(plan, feed, dtypes, pf: int, capacity: int,
     """
     if pf != 0:
         return False
+    if any(dt != "int32" for dt in plan_params(plan)[3]):
+        return False        # the prefetch operand is int32 scalars
     if n_slots(plan, capacity, mode) > MAX_SLOTS:
         return False
     if feed["n_pad"] % (max(1, n_shards) * BLOCK) != 0:
@@ -203,6 +242,10 @@ def supported(plan, feed, dtypes, pf: int, capacity: int,
 # kernel (BENCHMARK.json: kernel.main_ms).
 KERNEL_NAME = "pallas_hash_tpu_custom_call"
 
+# cached prefetch-scalar vectors a built kernel keeps (``scalars``): one
+# a (feed, tile, constant tuple), 16-50 bytes each on the device
+_SCALARS_MAX = 8192
+
 
 def build(plan, layouts, p8: int, capacity: int, nblk: int,
           col_map, mode: str = MODE_DENSE):
@@ -221,12 +264,18 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
     touches).  In ``sparse`` mode one extra int32 slot-id column rides
     after the mapped columns.
 
+    The plan's predicate and aggregate constants are not in the
+    module: they are operands (``plan_params``), int32 scalars after
+    the four bounds in the scalar-prefetch vector, so the built kernel
+    is that of the plan's const-blind class.
+
     Returns ``(run, LO, HI)`` with
-    ``run(row_lo, row_hi, base, blk0, cols) -> (2, HI, p8*LO) int32``
+    ``run(row_lo, row_hi, base, blk0, cols, params=()) ->
+    (2, HI, p8*LO) int32``
     packed accumulator pair covering absolute rows
     [row_lo, row_hi) ⊆ [blk0*BLOCK, (blk0+nblk)*BLOCK); ``cols`` is the
     already-selected input tuple (mapped columns, then slot ids when
-    sparse).
+    sparse), ``params`` the request's constant values.
     """
     slots = n_slots(plan, capacity, mode)
     hi_n = -(-slots // LO)
@@ -238,9 +287,9 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
     SENT = HI * LO
     nullable = mode != MODE_SIMPLE and (
         mode == MODE_SPARSE or not key_never_null(plan))
-    sel_rpns = plan.sel_rpns
+    sel_rpns, agg_rpns, _vals, param_dts = plan_params(plan)
+    n_params = len(param_dts)
     key_rpn = plan.key_rpn
-    agg_rpns = plan.agg_rpns
     lobits = LO.bit_length() - 1
     n_cols_in = sum(1 for p in col_map if p >= 0)
     sparse = mode == MODE_SPARSE
@@ -276,6 +325,10 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
             # row_mask; unmapped columns never appear in these rpns
             pairs = [None if p < 0 else (refs[p][:], row_mask)
                      for p in col_map]
+            # the request's constants: scalars from SMEM, valid as a
+            # baked constant is (eval._const_pair)
+            true0 = jnp.ones((), jnp.bool_)
+            pairs += [(sref[4 + j], true0) for j in range(n_params)]
             mask = row_mask
             for rpn in sel_rpns:
                 v, ok = eval_rpn(rpn, pairs, B, jnp)
@@ -341,7 +394,10 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
                     p += 1
                 if lay.byte_planes:
                     nb = lay.nb
-                    biased = v + _i32(1 << (8 * nb - 1))
+                    # (four planes: 2^31 is int32's -2^31, and the add
+                    # wraps to the same bits)
+                    biased = v + _i32((1 << (8 * nb - 1)) if nb < 4
+                                      else -(1 << 31))
                     if not aliased:
                         # NULL argument on a live row: bytes must not leak
                         biased = biased * ok32
@@ -384,22 +440,25 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
 
     scal_cache: dict = {}
 
-    def scalars(row_lo, row_hi, base, blk0):
-        """The kernel's four prefetch scalars for concrete row bounds,
-        on the device, cached per (feed, tile)."""
+    def scalars(row_lo, row_hi, base, blk0, params=()):
+        """The kernel's prefetch scalars for concrete row bounds and
+        constants, on the device, cached per (feed, tile, constant
+        tuple): a tuple seen before costs no H2D."""
         if mode != MODE_DENSE:
             # only the dense key expression reads ``base``; a sparse
             # domain's minimum (up to 2^62) does not fit the int32
             # prefetch scalars (numpy 2 raises instead of wrapping)
             base = 0
-        key = (row_lo, int(row_hi), int(base), int(blk0))
+        key = (row_lo, int(row_hi), int(base), int(blk0)) + tuple(params)
         scal = scal_cache.get(key)
         if scal is None:
+            if len(scal_cache) >= _SCALARS_MAX:
+                scal_cache.clear()
             scal = jnp.asarray(np.asarray(key, np.int32))
             scal_cache[key] = scal
         return scal
 
-    def run(row_lo, row_hi, base, blk0, cols):
+    def run(row_lo, row_hi, base, blk0, cols, params=()):
         # the scalar tuple is constant per (feed, tile): cache it, on
         # the device the kernel runs on, so a warm request issues no
         # scalar H2D and the jitted call takes it as it lies (what an
@@ -410,13 +469,14 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
         # there is no H2D to save; theirs are the runner's cached
         # scalars, replicated over its mesh (DeviceRunner._cached_scalar).
         if isinstance(row_lo, (int, np.integer)):
-            scal = scalars(row_lo, row_hi, base, blk0)
+            scal = scalars(row_lo, row_hi, base, blk0, params)
         else:
             with jax.enable_x64(False):
                 scal = jnp.stack([
                     jnp.asarray(v).astype(jnp.int32)
                     for v in (row_lo, row_hi,
-                              base if mode == MODE_DENSE else 0, blk0)])
+                              base if mode == MODE_DENSE else 0, blk0,
+                              *params)])
         with jax.enable_x64(False):
             return pallas_hash(scal, *cols)
 

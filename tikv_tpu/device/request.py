@@ -34,6 +34,10 @@ class _FallbackToHost(Exception):
 #  and min/max/count ride the same kernels as INT.  Years >= 8192 pack
 #  above 2^63 and would corrupt the int64 carries — the feed guard
 #  routes such columns to host.
+#  DECIMAL is not among them: a DECIMAL column whose FieldType fixes
+#  its scale reaches the device as a scaled INTEGER plane, and plan
+#  analysis lowers decimal RPN over it to integer RPN before this gate
+#  sees it (device/lowering.py); what cannot be lowered stays host.
 _DEVICE_ETS = (EvalType.INT, EvalType.REAL, EvalType.DATETIME,
                EvalType.DURATION)
 
@@ -55,9 +59,12 @@ def _remap_rpn(rpn: RpnExpression, mapping: dict) -> RpnExpression:
 def _rpn_device_safe(rpn: RpnExpression, scan_ets: Sequence[EvalType]) -> bool:
     for n in rpn.nodes:
         if isinstance(n, RpnConst):
+            # (a Decimal constant the lowering took is an int by now)
             if n.value is not None and not isinstance(n.value, (int, float, bool)):
                 return False
         elif isinstance(n, RpnColumnRef):
+            # ``scan_ets``: the eval types of the PLANES, INT for a
+            # column the lowering put on a scaled or a date plane
             if n.col_idx >= len(scan_ets) or scan_ets[n.col_idx] not in _DEVICE_ETS:
                 return False
         elif isinstance(n, RpnFnCall):
@@ -96,6 +103,17 @@ class _Plan:
     # lazy (result FieldTypes, container dtypes) of ``specs``
     # (aggregate.py DeviceAggregator._agg_out)
     agg_out: Optional[tuple] = None
+    # device/lowering.py: per aggregate the scale its SUM comes back as
+    # a DECIMAL at (None: not a lowered DECIMAL); per used column
+    # whether it rides as the int32 date plane; whether anything was
+    # lowered (then ``lowering.fits`` must hold for the feed)
+    agg_fracs: list = field(default_factory=list)
+    date_planes: tuple = ()
+    lowered: bool = False
+    # lazy: the aggregation's constants as kernel operands,
+    # (param sel_rpns, param agg_rpns, values, dtypes)
+    # (aggregate.py agg_params)
+    agg_params: Optional[tuple] = None
 
 
 class _PinnedStager:
@@ -257,7 +275,8 @@ class _LanePending(_Pending):
     closed groups of the same compile class and all of them leave as
     ONE program.  Until ``launch_lanes`` binds it, it holds what its
     call needs (``kernel``: the kernel cache key, its entry, the built
-    ``run`` and the row bounds; ``cols``: its feed's kernel inputs);
+    ``run``, the row bounds with the lane's OWN constants, and the slot
+    mode; ``cols``: its feed's kernel inputs);
     after, ``launch`` is the shared fetch and ``index`` this lane's
     place in it.  ``info``: the launch's ``_dispatch_phase`` record,
     for the ``device_dispatch`` span every member's trace gets."""

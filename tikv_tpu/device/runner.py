@@ -76,12 +76,13 @@ from ..copr.dag import (
     TopNDesc,
 )
 from ..datatype import Column, ColumnBatch, EvalType
-from ..datatype.tile import _device_dtype
+from ..datatype.tile import _device_dtype, date_plane
 from ..expr import build_rpn
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef, RpnExpression
 from ..ops.agg import AggSpec
 from ..parallel import ROW_AXES, make_mesh, num_shards, row_sharding
+from . import lowering, pallas_hash
 from .aggregate import DeviceAggregator
 from .kernels import named_program
 from .request import (
@@ -171,6 +172,22 @@ def _fits_dtype(vals: np.ndarray, valid, dt: np.dtype) -> bool:
         return 0 <= lo and hi < (1 << 63)
     info = np.iinfo(dt)
     return info.min <= lo and hi <= info.max
+
+
+def _is_date_plane(info, dt: np.dtype) -> bool:
+    """Whether used column ``info`` rides a feed as the int32 date plane
+    (device/lowering.py): a time column on a signed plane is one, since
+    a packed core rides unsigned."""
+    return dt.kind == "i" and not info.is_pk_handle and \
+        info.field_type.eval_type is EvalType.DATETIME
+
+
+def _to_plane(info, vals: np.ndarray, dt: np.dtype) -> np.ndarray:
+    """A used column's host values as the values of its device plane,
+    before the cast to ``dt``: a DATE column on the date plane drops
+    its core's zero low bits; every other column is itself (a scaled
+    DECIMAL is scaled in the cache line already)."""
+    return date_plane(vals) if _is_date_plane(info, dt) else vals
 
 
 def _fp_degrade(name: str) -> None:
@@ -997,7 +1014,10 @@ class DeviceRunner:
         whether the extension built; ``scalar_cache``: look-ups of the
         cached device scalars that found the value on the device
         (``hits``) or had to put it there (``uploads``), sub-runners'
-        included: a warm launch only hits; ``lanes``: this runner's
+        included: a warm launch only hits; ``agg_params``: launches
+        that carried an aggregation's constants as kernel operands,
+        const-blind kernel entries built, scaled-DECIMAL and int32-date
+        planes cut (``FlightRecorder.agg_param_counts``); ``lanes``: this runner's
         multi-lane launches, ``DeviceAggregator.lane_stats``; all
         monotone), the resident
         bytes of the live mesh's fullest shard, and the placement
@@ -1018,6 +1038,7 @@ class DeviceRunner:
                    "native_available":
                        native.hash_finalize_packed is not None},
                "scalar_cache": self.flight_recorder.scalar_counts(),
+               "agg_params": self.flight_recorder.agg_param_counts(),
                "lanes": self.lane_stats(),
                "submesh_rebuilds": self._submesh_rebuilds,
                "feed_bytes_per_shard": max(
@@ -1186,8 +1207,10 @@ class DeviceRunner:
         (``DeviceAggregator._try_pallas``, ``lane_class``; one memo a
         line: two generations of a line are two lanes of one class,
         and a refresh drops it until the next launch).  Only a
-        ``share`` group of a hash aggregation that the Pallas body has
-        already served whole has one: a ``stack`` group, another plan
+        ``share`` group of an aggregation (GROUP BY or not) that the
+        Pallas body has already served whole has one (the class is
+        const-blind: groups that differ in their constants alone are
+        lanes of one launch, each with its own operands): a ``stack`` group, another plan
         kind, a mesh, a bucket-tile request (its ranges have no memo of
         their own), a cold or refreshed line take today's path.  The
         class only decides who is staged together; each lane's kernel
@@ -1206,11 +1229,11 @@ class DeviceRunner:
         if not runner._single:
             return None
         plan = runner._analyze(dag)
-        if plan is None or plan.kind != "hash_agg":
+        if plan is None or plan.kind not in ("hash_agg", "simple_agg"):
             return None
         per_storage = runner._arena.bucket(runner._feed_anchor(storage),
                                            create=False)
-        meta = per_storage.get(("meta", (dag.plan_key(), dag.ranges))) \
+        meta = per_storage.get(("meta", runner._meta_key(dag, plan))) \
             if per_storage is not None else None
         if not meta:
             return None
@@ -1443,33 +1466,59 @@ class DeviceRunner:
 
         rpns_to_check = list(sel_rpns)
         plan = _Plan(scan=scan, kind="scan", used_cols=[])
+        date_cols: set = set()      # scan offsets on the int32 date plane
 
         if isinstance(terminal, AggregationDesc):
             if len(terminal.group_by) > 1:
                 return None
-            agg_rpns, specs = [], []
-            for i, a in enumerate(terminal.aggs):
+            agg_rpns = []
+            for a in terminal.aggs:
                 if a.kind not in ("count", "count_star", "sum", "avg",
                                  "min", "max", "first", "var_pop",
                                  "var_samp", "stddev_pop", "stddev_samp"):
                     # bit_and/or/xor: no XLA scatter-bitop lowering on TPU
                     # → host (they're exact int ops; host numpy is fine)
                     return None
-                if a.arg is not None:
-                    r = build_rpn(a.arg)
+                agg_rpns.append(None if a.arg is None
+                                else build_rpn(a.arg))
+            key_rpn = build_rpn(terminal.group_by[0]) \
+                if terminal.group_by else None
+            if lowering.needs_lowering(scan, sel_rpns, agg_rpns, key_rpn):
+                # DECIMAL columns as scaled integers, a DATE column as
+                # its int32 plane: decimal RPN becomes the integer RPN
+                # the kernels evaluate, each SUM's scale carried beside
+                # it to the finalize (device/lowering.py).  What has no
+                # exact integer form is not a device plan.
+                from ..utils import tracker
+                with tracker.span("decimal_lower"):
+                    try:
+                        low = lowering.lower(
+                            scan, sel_rpns, agg_rpns,
+                            [a.kind for a in terminal.aggs], key_rpn)
+                    except lowering.NotLowerable:
+                        return None
+                sel_rpns, agg_rpns, key_rpn = \
+                    low.sel_rpns, low.agg_rpns, low.key_rpn
+                rpns_to_check = list(sel_rpns)
+                plan.agg_fracs = low.agg_fracs
+                plan.lowered = bool(low.dec_cols or low.date_cols)
+                date_cols = low.date_cols
+                scan_ets = [EvalType.INT if i in low.dec_cols or
+                            i in low.date_cols else et
+                            for i, et in enumerate(scan_ets)]
+            specs = []
+            for i, (a, r) in enumerate(zip(terminal.aggs, agg_rpns)):
+                if r is not None:
                     if r.ret_type in _TIME_ETS and a.kind not in (
                             "count", "min", "max", "first"):
                         return None     # SUM(datetime) etc. → host
-                    agg_rpns.append(r)
                     rpns_to_check.append(r)
                     specs.append(AggSpec(a.kind, i, r.ret_type))
                 else:
-                    agg_rpns.append(None)
                     specs.append(AggSpec(a.kind, i))
-            if terminal.group_by:
+            if key_rpn is not None:
                 if any(s.kind == "first" for s in specs):
                     return None     # FIRST needs source-row gather → host
-                key_rpn = build_rpn(terminal.group_by[0])
                 if key_rpn.ret_type is not EvalType.INT:
                     return None
                 rpns_to_check.append(key_rpn)
@@ -1527,6 +1576,9 @@ class DeviceRunner:
                 plan.compact_ok = True
         mapping = {old: new for new, old in enumerate(used)}
         plan.used_cols = used
+        plan.date_planes = tuple(ci in date_cols for ci in used)
+        if not plan.agg_fracs:
+            plan.agg_fracs = [None] * len(plan.agg_rpns)
         plan.sel_rpns = [_remap_rpn(r, mapping) for r in sel_rpns]
         plan.agg_rpns = [None if r is None else _remap_rpn(r, mapping)
                          for r in plan.agg_rpns]
@@ -1540,6 +1592,10 @@ class DeviceRunner:
 
     def _scan_batch(self, dag: DAGRequest, plan: _Plan, storage) -> ColumnBatch:
         if hasattr(storage, "scan_columns"):
+            if plan.lowered:
+                # the cache line's scaled DECIMAL columns as they lie
+                return storage.scan_columns(plan.scan, dag.ranges,
+                                            scaled=True)
             return storage.scan_columns(plan.scan, dag.ranges)
         from ..executors.scan import (
             BatchIndexScanExecutor,
@@ -1739,7 +1795,11 @@ class DeviceRunner:
         # to the plain upload below, which is always correct.
         if lineage is not None and \
                 getattr(lineage, "cold_bundle", None) is not None:
-            if positional and cache is not None:
+            if positional and cache is not None and not any(
+                    _is_date_plane(i, np.dtype(d))
+                    for i, d in zip(used_infos or (), dtypes or ())):
+                # (the resolver gathers packed cores: a date plane is
+                # cut from the host mirror instead)
                 bundle = lineage.take_cold(req_v)
                 if bundle is not None:
                     feed = bundle.mint(self, used_infos, dtypes, n,
@@ -1825,6 +1885,7 @@ class DeviceRunner:
                             valid = None
                         else:
                             vals, valid = span["cols"][info.col_id]
+                        vals = _to_plane(info, vals, dt)
                         if not _fits_dtype(vals, valid, dt):
                             return False
                         if valid is not None and not valid.all() and \
@@ -2572,7 +2633,8 @@ class DeviceRunner:
     # -- dispatch span + flight-recorder feed --
 
     @contextmanager
-    def _dispatch_phase(self, klass: str, key=None):
+    def _dispatch_phase(self, klass: str, key=None, params: int = 0,
+                        slot_mode: str = ""):
         """Every kernel launch site runs under this: the
         ``device_dispatch`` tracker span, plus one flight-recorder
         entry (launch wall, compile class, first-launch flag, mesh
@@ -2583,7 +2645,9 @@ class DeviceRunner:
         ``key`` refines the compile class (n_pad bucket / kernel cache
         key) so the ``first_launch`` flag distinguishes a real
         cold-compile launch from a warm cache hit within the same plan
-        kind."""
+        kind.  ``params``: the constants the launch carries as kernel
+        operands; ``slot_mode``: the Pallas kernel's (both on the span
+        and in the entry)."""
         from .. import resource_metering as rm
         from ..utils import tracker
         rec = self.flight_recorder
@@ -2620,7 +2684,8 @@ class DeviceRunner:
                         if len(self._slice_indices) == 1 else None,
                         pinned_bytes=self._arena.pinned_bytes(),
                         ok=ok, shards=num_shards(self._mesh),
-                        whole_mesh=self._failover_parent is None)
+                        whole_mesh=self._failover_parent is None,
+                        params=params, slot_mode=slot_mode)
                     tracker.annotate(**entry)
                     info["attrs"] = entry
                 info["t0_ns"], info["t1_ns"] = t0_ns, t1_ns
@@ -2832,10 +2897,7 @@ class DeviceRunner:
                 dag = DAGRequest(dag.executors, (), dag.start_ts,
                                  dag.output_offsets, dag.encode_type)
 
-        # keyed on the full plan: hash_bounds/arg_nbytes depend on the
-        # key/arg expressions, not just on which columns are shipped
-        meta_key = (dag.plan_key(), dag.ranges)
-        meta = self._request_meta(storage, meta_key)
+        meta = self._request_meta(storage, self._meta_key(dag, plan))
         lineage = getattr(storage, "feed_lineage", None)
         # the generation THIS snapshot reflects — the line may already
         # be further ahead (or this may be a history-served older
@@ -2900,9 +2962,24 @@ class DeviceRunner:
                 return meta["dtypes"]
             batch = get_batch()
             dts = []
-            for ci in plan.used_cols:
+            bounds = []
+            for pos, ci in enumerate(plan.used_cols):
                 col = batch.columns[ci]
-                dt = _device_dtype(col.eval_type, col.values)
+                if col.eval_type is EvalType.DECIMAL and col.frac is None:
+                    # the build kept this DECIMAL column as objects (a
+                    # value beyond its declared scale, a store without
+                    # the native build): host, as before the lowering
+                    meta["force_host"] = True
+                    raise _FallbackToHost("unscaled DECIMAL column")
+                vals = col.values
+                if plan.date_planes and plan.date_planes[pos]:
+                    vals = date_plane(vals)
+                    dt = np.dtype(np.int32)
+                    self.flight_recorder.note_plane("date")
+                else:
+                    dt = _device_dtype(col.eval_type, vals)
+                    if col.frac is not None:
+                        self.flight_recorder.note_plane("decimal")
                 if dt == np.dtype(np.uint64) and col.values.size \
                         and int(col.values.max()) >= (1 << 63):
                     # packed cores above 2^63 (year >= 8192) would
@@ -2913,10 +2990,33 @@ class DeviceRunner:
                     meta["force_host"] = True
                     raise _FallbackToHost("u64 column beyond int64")
                 dts.append(str(dt))
+                if plan.lowered:
+                    bounds.append((int(vals.min()), int(vals.max()))
+                                  if vals.size else (0, 0))
+            if plan.lowered and not lowering.fits(plan, bounds, dts, n):
+                # the integer form may wrap at the planes' natural
+                # width: try every plane at int64 (the XLA bodies serve
+                # it), else the host pipeline, whose Decimals are exact
+                wide = ["int64" if np.dtype(d).kind == "i" else d
+                        for d in dts]
+                if not lowering.fits(plan, bounds, wide, n):
+                    meta["force_host"] = True
+                    raise _FallbackToHost("lowered DECIMAL arithmetic "
+                                          "not provably inside int64")
+                dts = wide
             memo["dtypes"] = tuple(dts)
             if memo_fresh():
                 meta["dtypes"] = memo["dtypes"]
             return memo["dtypes"]
+
+        def plane_pair(pos: int, col, ds: str) -> tuple:
+            """Used column ``pos`` as its device plane's numpy pair."""
+            vals = col.values
+            if plan.date_planes and plan.date_planes[pos]:
+                vals = date_plane(vals)
+            return (np.ascontiguousarray(
+                vals.astype(np.dtype(ds), copy=False)),
+                np.ascontiguousarray(col.validity))
 
         def host_cols():
             """Device-dtype numpy column pairs.
@@ -2933,11 +3033,8 @@ class DeviceRunner:
             dts = get_dtypes()
             batch = get_batch()
             cols = []
-            for ci, ds in zip(plan.used_cols, dts):
-                col = batch.columns[ci]
-                cols.append((np.ascontiguousarray(
-                    col.values.astype(np.dtype(ds), copy=False)),
-                    np.ascontiguousarray(col.validity)))
+            for pos, (ci, ds) in enumerate(zip(plan.used_cols, dts)):
+                cols.append(plane_pair(pos, batch.columns[ci], ds))
             memo["host_cols"] = cols
             if memo_fresh():
                 meta["host_cols"] = cols
@@ -2960,11 +3057,8 @@ class DeviceRunner:
             dts = get_dtypes()
             batch = get_batch()
             built = []
-            for ci, ds in zip(plan.used_cols, dts):
-                col = batch.columns[ci]
-                pair = (np.ascontiguousarray(
-                    col.values.astype(np.dtype(ds), copy=False)),
-                    np.ascontiguousarray(col.validity))
+            for pos, (ci, ds) in enumerate(zip(plan.used_cols, dts)):
+                pair = plane_pair(pos, batch.columns[ci], ds)
                 built.append(pair)
                 yield pair
             memo["host_cols"] = built
@@ -3014,7 +3108,8 @@ class DeviceRunner:
                 gmeta = _GuardedMeta(meta, memo_fresh)
                 if plan.kind == "simple_agg":
                     result = self._aggregator.run_simple(
-                        dag, plan, host_cols, dtypes, n, feed, gmeta)
+                        dag, plan, host_cols, dtypes, n, feed, gmeta,
+                        lanes=_lanes)
                 elif plan.kind == "hash_agg":
                     result = self._aggregator.run_hash(
                         dag, plan, host_cols, dtypes, n, feed, gmeta,
@@ -3106,6 +3201,24 @@ class DeviceRunner:
                 [b.columns[i] for i in dag.output_offsets])
         return result
 
+    @staticmethod
+    def _meta_key(dag: DAGRequest, plan: _Plan) -> tuple:
+        """What a request's memo is kept under.  Keyed on the full
+        plan: hash_bounds / arg_nbytes depend on the key and argument
+        expressions, not just on which columns are shipped.  For an
+        aggregation the plan CONST-BLIND, but for the GROUP BY key's
+        constants: what its memo holds (row count, dtypes, host planes,
+        key bounds, byte-plane widths, the sparse recode) is a property
+        of the data and of the key, so requests that differ in their
+        predicates' and aggregates' constants share it and none of it
+        is derived again for a new constant tuple."""
+        if plan.kind in ("simple_agg", "hash_agg"):
+            # (which DATE columns ride the int32 plane hangs on the
+            # constants' VALUES, a time of day or none: device/lowering)
+            return (dag.class_key(), pallas_hash.key_consts(plan),
+                    plan.date_planes, dag.ranges)
+        return (dag.plan_key(), dag.ranges)
+
     def _request_meta(self, storage, meta_key) -> dict:
         """Snapshot-lifetime memo for host-derived request constants
         (device dtypes, hash key bounds, byte-plane widths).  Anchored
@@ -3137,7 +3250,10 @@ class DeviceRunner:
         meta.pop("host_cols", None)
         meta.pop("sparse_slots", None)
         meta.pop("lane_class", None)    # re-learnt by the next launch
-        keep = patches is not None and \
+        # (a lowered plan's dtypes stand on ``lowering.fits``'s proof
+        # over the columns' BOUNDS, which new rows may leave while
+        # still fitting the dtype: derive them again)
+        keep = patches is not None and not plan.lowered and \
             not any(p.get("structural") for p in patches)
         if keep:
             used_infos = [plan.scan.columns[ci] for ci in plan.used_cols]
@@ -3160,15 +3276,22 @@ class DeviceRunner:
                     vals, valid = (span["handles"], None) \
                         if info.is_pk_handle \
                         else span["cols"][info.col_id]
-                    if not _fits_dtype(vals, valid, dt):
+                    if not _fits_dtype(_to_plane(info, vals, dt), valid,
+                                       dt):
                         return False
 
         def span_pairs(span):
+            """The span's rows as the plan's rpns see them: plane
+            values (a DATE column on the date plane shifted)."""
             pairs = []
-            for info in used_infos:
+            for ci, info in enumerate(used_infos):
                 if info.is_pk_handle:
                     h = span["handles"]
                     pairs.append((h, np.ones(len(h), np.bool_)))
+                elif dtypes is not None:
+                    v, ok = span["cols"][info.col_id]
+                    pairs.append((_to_plane(info, v,
+                                            np.dtype(dtypes[ci])), ok))
                 else:
                     pairs.append(span["cols"][info.col_id])
             return pairs

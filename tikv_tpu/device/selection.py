@@ -99,7 +99,8 @@ def split_params(sel_rpns, n_cols: int):
     """Hoist numeric predicate constants into traced parameters.
 
     Returns ``(param_rpns, values, dtypes)`` where every int/float
-    RpnConst in ``sel_rpns`` is replaced by an RpnColumnRef addressing a
+    RpnConst in ``sel_rpns`` (but a ``fixed`` one, which is the plan's
+    structure) is replaced by an RpnColumnRef addressing a
     scalar parameter column at position ``n_cols + i``.  The parameter
     pairs the runner feeds (0-d value array, 0-d True validity) are
     exactly what ``eval._const_pair`` would have produced for the baked
@@ -113,7 +114,7 @@ def split_params(sel_rpns, n_cols: int):
         nodes = []
         for nd in rpn.nodes:
             if isinstance(nd, RpnConst) and nd.value is not None and \
-                    isinstance(nd.value, (int, float)):
+                    isinstance(nd.value, (int, float)) and not nd.fixed:
                 dt = device_const_dtype(nd.value)
                 nodes.append(RpnColumnRef(n_cols + len(vals), nd.eval_type))
                 vals.append(nd.value)
@@ -135,6 +136,8 @@ def shape_key(plan) -> tuple:
         if isinstance(nd, RpnConst):
             if nd.value is None:
                 return ("cN", nd.eval_type.value)
+            if nd.fixed:
+                return ("cF", nd.value)     # structure: never hoisted
             if isinstance(nd.value, (int, float)):
                 return ("c", device_const_dtype(nd.value))
             return ("c", repr(nd.value))    # non-numeric: host-only plans

@@ -151,6 +151,14 @@ class FlightRecorder:
         # already on the device: a warm launch only hits
         self.scalar_hits = 0
         self.scalar_uploads = 0
+        # aggregations whose constants are kernel OPERANDS
+        # (device/aggregate.py agg_params): launches that carried some,
+        # const-blind kernel entries built (one serves every constant
+        # tuple of its class), and the scaled-DECIMAL / int32-date
+        # planes cut for feeds (device/lowering.py), by kind
+        self.param_launches = 0
+        self.const_classes = 0
+        self.planes = {"decimal": 0, "date": 0}
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -160,7 +168,8 @@ class FlightRecorder:
     def note(self, klass: str, key=None, wall_s: float = 0.0,
              mesh: str = "", slice_id=None, pinned_bytes: int = 0,
              ok: bool = True, shards: int = 1,
-             whole_mesh: bool = False) -> dict:
+             whole_mesh: bool = False, params: int = 0,
+             slot_mode: str = "") -> dict:
         ck = (klass, key)
         with self._mu:
             first = ck not in self._seen
@@ -176,6 +185,8 @@ class FlightRecorder:
                 self.faults += 1
             if whole_mesh:
                 self.sharded_launches += 1
+            if params:
+                self.param_launches += 1
             entry = {"t_unix_s": round(time.time(), 6),
                      "launch_ms": round(wall_s * 1e3, 3),
                      "compile_class": klass,
@@ -184,6 +195,10 @@ class FlightRecorder:
                      "shards": int(shards),
                      "slice": slice_id,
                      "pinned_bytes": int(pinned_bytes),
+                     # constants the launch carried as operands, and
+                     # the Pallas kernel's slot mode ("" elsewhere)
+                     "params": int(params),
+                     "slot_mode": slot_mode,
                      "ok": ok}
             self._ring.append(entry)
         return entry
@@ -194,6 +209,21 @@ class FlightRecorder:
                 self.finalize_native += 1
             else:
                 self.finalize_numpy += 1
+
+    def note_plane(self, kind: str) -> None:
+        with self._mu:
+            self.planes[kind] += 1
+
+    def note_const_class(self) -> None:
+        with self._mu:
+            self.const_classes += 1
+
+    def agg_param_counts(self) -> dict:
+        with self._mu:
+            return {"param_launches": self.param_launches,
+                    "const_classes": self.const_classes,
+                    "decimal_planes": self.planes["decimal"],
+                    "date_planes": self.planes["date"]}
 
     def note_scalar(self, hit: bool) -> None:
         with self._mu:

@@ -111,7 +111,7 @@ class ColumnarTable:
             tc = table[name]
             if isinstance(data, Column):
                 col = Column(data.eval_type, data.values[order],
-                             data.validity[order])
+                             data.validity[order], data.frac)
             else:
                 arr = np.asarray(data)[order]
                 col = Column.from_values(tc.field_type.eval_type, arr)
@@ -175,9 +175,14 @@ class ColumnarTable:
             self._ones_validity = ones
         return ones[:n]
 
-    def scan_columns(self, desc,
-                     ranges: Sequence[KeyRange]) -> ColumnBatch:
-        """Vectorized range scan → ColumnBatch in ``desc.columns`` order."""
+    def scan_columns(self, desc, ranges: Sequence[KeyRange],
+                     scaled: bool = False) -> ColumnBatch:
+        """Vectorized range scan → ColumnBatch in ``desc.columns`` order.
+
+        ``scaled``: hand a DECIMAL column the cache holds as a scaled
+        integer (``Column.frac``) out as it lies, for the device
+        runner's feed; every other caller gets the host pipeline's
+        ``Decimal`` objects, made from the scanned rows alone."""
         if isinstance(desc, IndexScanDesc):
             return self._scan_index_columns(desc, ranges)
         slices = self._range_slices(ranges)
@@ -222,7 +227,8 @@ class ColumnarTable:
                     info.field_type.eval_type, [info.default_value] * n))
                 continue
             v, m = gather(col.values, col.validity)
-            out_cols.append(Column(col.eval_type, v, m))
+            out = Column(col.eval_type, v, m, col.frac)
+            out_cols.append(out if scaled else out.unscaled())
         return ColumnBatch([c.field_type for c in desc.columns], out_cols)
 
     # -- late-materialized gather (device selection vector → rows) ----------
@@ -291,7 +297,8 @@ class ColumnarTable:
                     [info.default_value] * len(phys)))
                 continue
             out_cols.append(Column(col.eval_type, col.values[phys],
-                                   col.validity[phys]))
+                                   col.validity[phys],
+                                   col.frac).unscaled())
         return ColumnBatch([c.field_type for c in desc.columns], out_cols)
 
     def _index_sorted(self, col_id: int):
@@ -302,7 +309,7 @@ class ColumnarTable:
             cache = self._index_order_cache = {}
         got = cache.get(col_id)
         if got is None:
-            col = self.columns[col_id]
+            col = self.columns[col_id].unscaled()
             values, validity, handles = col.values, col.validity, \
                 self.handles
             if self.alive is not None:

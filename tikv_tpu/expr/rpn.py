@@ -20,6 +20,10 @@ from .tree import Expr
 class RpnConst:
     value: object               # None = NULL
     eval_type: EvalType
+    # part of the plan's STRUCTURE, not a parameter of the request (a
+    # rescaling power of ten the decimal lowering put in,
+    # device/lowering.py): never hoisted into a traced operand
+    fixed: bool = False
 
 
 @dataclass(frozen=True)

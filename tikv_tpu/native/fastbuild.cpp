@@ -27,6 +27,7 @@
 #include <Python.h>
 
 #include <cstdint>
+#include <cstdio>
 #include <cstring>
 #include <deque>
 #include <string>
@@ -76,7 +77,9 @@ int mc_decode(const uint8_t* p, Py_ssize_t len, Py_ssize_t* off,
 
 /* minimal msgpack value (codec/row.py envelope) */
 struct MpVal {
-  enum { NIL, INT, FLT, BIN } type;
+  /* EXT: a msgpack ext datum, its type code in ``i`` and its data in
+   * ``b`` / ``blen`` (codec/row.py: ExtType(1) is a DECIMAL's text) */
+  enum { NIL, INT, FLT, BIN, EXT } type;
   int64_t i;
   double f;
   const uint8_t* b;
@@ -149,6 +152,21 @@ int mp_read(const uint8_t* p, Py_ssize_t len, Py_ssize_t* off, MpVal* v) {
       if (!need(n)) return -1;
       v->type = MpVal::BIN; v->b = p + *off; v->blen = n;
       *off += n; return 0; }
+    case 0xD4: case 0xD5: case 0xD6: case 0xD7: case 0xD8: {
+      uint32_t n = 1u << (t - 0xD4);          /* fixext 1/2/4/8/16 */
+      if (!need(1 + (Py_ssize_t)n)) return -1;
+      v->type = MpVal::EXT; v->i = (int8_t)p[(*off)++];
+      v->b = p + *off; v->blen = n;
+      *off += n; return 0; }
+    case 0xC7: case 0xC8: case 0xC9: {        /* ext 8/16/32 */
+      int w = 1 << (t - 0xC7);
+      if (!need(w)) return -1;
+      uint32_t n = 0;
+      for (int k = 0; k < w; k++) n = (n << 8) | p[(*off)++];
+      if (!need(1 + (Py_ssize_t)n)) return -1;
+      v->type = MpVal::EXT; v->i = (int8_t)p[(*off)++];
+      v->b = p + *off; v->blen = n;
+      *off += n; return 0; }
     default:
       if (t >= 0xA0 && t <= 0xBF) {  /* fixstr */
         uint32_t n = t & 0x1F;
@@ -158,6 +176,45 @@ int mp_read(const uint8_t* p, Py_ssize_t len, Py_ssize_t* off, MpVal* v) {
       }
       return -1;
   }
+}
+
+/* A DECIMAL datum's text ("-12.50", codec/row.py: format(d, "f")) as
+ * value * 10^scale, exactly: 0 where it has at most ``scale`` digits
+ * right of the point and the result fits int64, else -1 (the caller
+ * leaves its envelope: the interpreted build keeps such a column as
+ * Decimal objects). */
+int dec_text_scaled(const uint8_t* p, uint32_t n, int scale, int64_t* out) {
+  uint32_t i = 0;
+  bool neg = false;
+  if (i < n && (p[i] == '-' || p[i] == '+')) { neg = p[i] == '-'; i++; }
+  unsigned __int128 acc = 0;
+  const unsigned __int128 kMax = (unsigned __int128)1 << 63;
+  int frac = -1, digits = 0;
+  for (; i < n; i++) {
+    uint8_t ch = p[i];
+    if (ch == '.') {
+      if (frac >= 0) return -1;
+      frac = 0;
+      continue;
+    }
+    if (ch < '0' || ch > '9') return -1;      /* exponent, NaN, Infinity */
+    if (frac >= 0 && ++frac > scale) {
+      if (ch != '0') return -1;               /* beyond the declared scale */
+      frac = scale;
+      continue;
+    }
+    acc = acc * 10 + (ch - '0');
+    if (acc > kMax) return -1;
+    digits++;
+  }
+  if (!digits) return -1;
+  for (int k = frac < 0 ? 0 : frac; k < scale; k++) {
+    acc *= 10;
+    if (acc > kMax) return -1;
+  }
+  if (acc > kMax - (neg ? 0 : 1)) return -1;
+  *out = neg ? (int64_t)(0 - (uint64_t)acc) : (int64_t)acc;
+  return 0;
 }
 
 int mp_map_len(const uint8_t* p, Py_ssize_t len, Py_ssize_t* off,
@@ -183,7 +240,10 @@ int mp_map_len(const uint8_t* p, Py_ssize_t len, Py_ssize_t* off,
 
 struct Col {
   int64_t id;
-  int kind;  /* 0=int64 1=float64 2=bytes(object) 3=uint64 */
+  /* 0=int64 1=float64 2=bytes(object) 3=uint64
+   * 4=DECIMAL as int64 scaled by 10^scale */
+  int kind;
+  int scale = 0;
   std::vector<int64_t> i64;
   std::vector<double> f64;
   std::vector<uint64_t> u64;
@@ -197,11 +257,13 @@ PyObject* fail(const char* msg) {
 }
 
 PyObject* mvcc_build(PyObject*, PyObject* args) {
-  PyObject *keys_o, *vals_o, *colids_o, *colkinds_o;
+  /* (keys, vals, read_ts, prefix_skip, col_ids, col_kinds
+   *  [, col_scales: a DECIMAL column's scale, 0 for the others]) */
+  PyObject *keys_o, *vals_o, *colids_o, *colkinds_o, *colscales_o = nullptr;
   unsigned long long read_ts;
   Py_ssize_t prefix_skip;
-  if (!PyArg_ParseTuple(args, "OOKnOO", &keys_o, &vals_o, &read_ts,
-                        &prefix_skip, &colids_o, &colkinds_o))
+  if (!PyArg_ParseTuple(args, "OOKnOO|O", &keys_o, &vals_o, &read_ts,
+                        &prefix_skip, &colids_o, &colkinds_o, &colscales_o))
     return nullptr;
 
   PyObject* keys = PySequence_Fast(keys_o, "keys not a sequence");
@@ -225,10 +287,23 @@ PyObject* mvcc_build(PyObject*, PyObject* args) {
     col.objs = (col.kind == 2) ? PyList_New(0) : nullptr;
     Py_XDECREF(ido);
     Py_XDECREF(ko);
+    if (colscales_o && colscales_o != Py_None) {
+      PyObject* so = PySequence_GetItem(colscales_o, c);
+      col.scale = so ? (int)PyLong_AsLong(so) : 0;
+      Py_XDECREF(so);
+    }
+    if (col.scale < 0 || col.scale > 18) {
+      for (auto& c2 : cols) Py_XDECREF(c2.objs);
+      Py_XDECREF(col.objs);
+      Py_DECREF(keys); Py_DECREF(vals);
+      return fail("decimal scale outside int64");
+    }
     cols.push_back(std::move(col));
   }
 
   std::vector<int64_t> handles;
+  /* datums of columns nobody asked for: read past, whatever their kind */
+  unsigned long long skipped = 0;
   uint64_t safe_ts = 0;
   std::string user_key, prev_key;
   bool resolved = false;
@@ -315,7 +390,7 @@ PyObject* mvcc_build(PyObject*, PyObject* args) {
     for (auto& c : cols) {
       c.valid.push_back(0);
       switch (c.kind) {
-        case 0: c.i64.push_back(0); break;
+        case 0: case 4: c.i64.push_back(0); break;
         case 1: c.f64.push_back(0.0); break;
         case 3: c.u64.push_back(0); break;
         case 2:
@@ -352,11 +427,20 @@ PyObject* mvcc_build(PyObject*, PyObject* args) {
         cleanup();
         return fail("bad row datum");
       }
+      bool wanted = false;
       for (auto& c : cols) {
         if (c.id != cid.i) continue;
+        wanted = true;
         if (val.type == MpVal::NIL) break;
         c.valid[row] = 1;
         switch (c.kind) {
+          case 4:
+            if (val.type != MpVal::EXT || val.i != 1 ||
+                dec_text_scaled(val.b, val.blen, c.scale, &c.i64[row]) < 0) {
+              cleanup();
+              return fail("decimal datum outside the column's scale");
+            }
+            break;
           case 0:
             if (val.type == MpVal::INT) c.i64[row] = val.i;
             else if (val.type == MpVal::FLT) c.i64[row] = (int64_t)val.f;
@@ -389,6 +473,7 @@ PyObject* mvcc_build(PyObject*, PyObject* args) {
         }
         break;
       }
+      if (!wanted) skipped++;
     }
   }
 
@@ -433,11 +518,12 @@ PyObject* mvcc_build(PyObject*, PyObject* args) {
     }
     Py_DECREF(tup);
   }
-  PyObject* ret = Py_BuildValue("{s:O,s:n,s:K,s:O,s:O}",
+  PyObject* ret = Py_BuildValue("{s:O,s:n,s:K,s:O,s:O,s:K}",
                                 "handles", handles_b, "n", n,
                                 "safe_ts", (unsigned long long)safe_ts,
                                 "cols", out_cols,
-                                "need_default", need_default);
+                                "need_default", need_default,
+                                "skipped_datums", skipped);
   Py_DECREF(handles_b);
   Py_DECREF(out_cols);
   cleanup();  /* drops our refs; ret holds its own */
@@ -932,14 +1018,22 @@ inline uint32_t crc32_buf(const uint8_t* p, size_t n) {
 }
 
 PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
-  /* (table_id, handles_i64_bytes, col_ids tuple, col_kinds tuple
-     (0=int64,1=float64), col_bufs tuple of bytes, col_valid tuple of
-     bytes-or-None, commit_ts, start_ts) -> v2 sst blob */
+  /* (table_id, handles_i64_bytes, col_ids tuple, col_kinds tuple,
+     col_bufs tuple of bytes, col_valid tuple of bytes-or-None,
+     commit_ts, start_ts [, col_aux tuple]) -> v2 sst blob.
+     Kinds, each byte-identical to codec/row.py encode_row's datum:
+       0 int64 (a packed date core is one: a positive int)
+       1 float64
+       2 DECIMAL: int64 scaled by 10^aux (aux: the scale, an int)
+                  -> ExtType(1, format(d, "f"))
+       3 bytes:   buf is the concatenated values, aux the n+1 int64
+                  offsets into it, as bytes -> bin */
   long long table_id, commit_ts, start_ts;
-  PyObject *handles_o, *ids_o, *kinds_o, *bufs_o, *valid_o;
-  if (!PyArg_ParseTuple(args, "LOOOOOLL", &table_id, &handles_o, &ids_o,
+  PyObject *handles_o, *ids_o, *kinds_o, *bufs_o, *valid_o,
+      *aux_o = nullptr;
+  if (!PyArg_ParseTuple(args, "LOOOOOLL|O", &table_id, &handles_o, &ids_o,
                         &kinds_o, &bufs_o, &valid_o, &commit_ts,
-                        &start_ts))
+                        &start_ts, &aux_o))
     return nullptr;
   char* hp;
   Py_ssize_t hlen;
@@ -952,6 +1046,10 @@ PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
   std::vector<int> kinds(ncols);
   std::vector<const uint8_t*> bufs(ncols);
   std::vector<const uint8_t*> valid(ncols, nullptr);
+  std::vector<int> scales(ncols, 0);
+  std::vector<const int64_t*> offsets(ncols, nullptr);
+  std::vector<int64_t> pow10(19, 1);
+  for (int k = 1; k < 19; k++) pow10[k] = pow10[k - 1] * 10;
   for (Py_ssize_t c = 0; c < ncols; c++) {
     PyObject* io = PySequence_GetItem(ids_o, c);
     PyObject* ko = PySequence_GetItem(kinds_o, c);
@@ -963,9 +1061,36 @@ PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
     if (PyBytes_AsStringAndSize(bo, &bp, &blen) < 0) {
       Py_XDECREF(bo); return nullptr;
     }
-    if (blen < n * 8) { Py_XDECREF(bo); return fail("short column buffer"); }
+    if (kinds[c] != 3 && blen < n * 8) {
+      Py_XDECREF(bo); return fail("short column buffer");
+    }
     bufs[c] = reinterpret_cast<const uint8_t*>(bp);
     Py_XDECREF(bo);   /* caller keeps the bytes alive via the tuple */
+    if (kinds[c] == 2 || kinds[c] == 3) {
+      PyObject* ao = aux_o ? PySequence_GetItem(aux_o, c) : nullptr;
+      if (!ao) { PyErr_Clear(); return fail("column kind needs its aux"); }
+      if (kinds[c] == 2) {
+        scales[c] = (int)PyLong_AsLong(ao);
+        Py_DECREF(ao);
+        if (scales[c] < 0 || scales[c] > 18)
+          return fail("decimal scale outside int64");
+      } else {
+        char* ap; Py_ssize_t alen;
+        if (PyBytes_AsStringAndSize(ao, &ap, &alen) < 0) {
+          Py_DECREF(ao); return nullptr;
+        }
+        Py_DECREF(ao);
+        if (alen < (n + 1) * 8) return fail("short offsets buffer");
+        offsets[c] = reinterpret_cast<const int64_t*>(ap);
+        if (n && (offsets[c][0] < 0 || offsets[c][n] > blen))
+          return fail("offsets outside the bytes buffer");
+        for (Py_ssize_t i = 0; i < n; i++)
+          if (offsets[c][i + 1] < offsets[c][i])
+            return fail("offsets not ascending");
+      }
+    } else if (kinds[c] != 0 && kinds[c] != 1) {
+      return fail("unknown column kind");
+    }
     PyObject* vo = PySequence_GetItem(valid_o, c);
     if (vo != Py_None) {
       char* vp; Py_ssize_t vlen;
@@ -1020,6 +1145,32 @@ PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
         uint64_t u;
         std::memcpy(&u, bufs[c] + 8 * i, 8);
         put_be64(&payload, u);
+      } else if (kinds[c] == 3) {
+        mp_put_bin(&payload, bufs[c] + offsets[c][i],
+                   (uint32_t)(offsets[c][i + 1] - offsets[c][i]));
+      } else if (kinds[c] == 2) {
+        /* format(Decimal, "f"): sign, integer digits, '.', exactly
+         * ``scale`` fraction digits; then msgpack's ext framing */
+        int64_t v;
+        std::memcpy(&v, bufs[c] + 8 * i, 8);
+        uint64_t mag = v < 0 ? 0 - (uint64_t)v : (uint64_t)v;
+        uint64_t p10 = (uint64_t)pow10[scales[c]];
+        char text[48];
+        int len = snprintf(text, sizeof text, scales[c] ? "%s%llu.%0*llu"
+                           : "%s%llu", v < 0 ? "-" : "",
+                           (unsigned long long)(mag / p10), scales[c],
+                           (unsigned long long)(mag % p10));
+        switch (len) {
+          case 1: payload.push_back((char)0xD4); break;
+          case 2: payload.push_back((char)0xD5); break;
+          case 4: payload.push_back((char)0xD6); break;
+          case 8: payload.push_back((char)0xD7); break;
+          case 16: payload.push_back((char)0xD8); break;
+          default: payload.push_back((char)0xC7);
+                   payload.push_back((char)len);
+        }
+        payload.push_back((char)1);                   /* _EXT_DECIMAL */
+        payload.append(text, (size_t)len);
       } else {
         int64_t v;
         std::memcpy(&v, bufs[c] + 8 * i, 8);

@@ -43,7 +43,7 @@ Invalidation (fall back to full decode, re-learn):
 event                       mechanism
 ==========================  =============================================
 wire shape change           fixed-segment byte mismatch
-const dtype bucket change   per-slot ``device_const_dtype`` guard
+const dtype bucket change   per-slot ``const_bucket`` guard
 region epoch bump / split   snapshot ``base_key`` embeds the epoch —
                             ``get_fast`` misses, entry invalidated
 delta patch / rebuild       generation guard: the storage object served
@@ -75,15 +75,18 @@ from __future__ import annotations
 import struct
 import threading
 from collections import OrderedDict
+from decimal import Decimal
 from typing import Callable, Optional
 
-from ..datatype import device_const_dtype
+from ..datatype import const_bucket
 from ..utils.failpoint import fail_point
 from ..utils.metrics import COPR_FASTPATH_COUNTER
 from . import wire
 
 # slot kinds
-K_CONST = "const"            # int/float predicate/aggregate constant
+K_CONST = "const"            # int / float / DECIMAL predicate or
+#                              aggregate constant (a DATE or DATETIME
+#                              constant is its packed core, an int)
 K_START_TS = "start_ts"      # dag.start_ts (per-request TSO)
 K_DEADLINE = "deadline_ms"   # top-level remaining-budget field
 K_TRACE_ID = "trace_id"      # client-propagated trace id
@@ -107,7 +110,7 @@ class _Slot:
         # True from masquerading as the learned integer constant
         if self.vtype is not None and type(v) is not self.vtype:
             return False
-        if self.dtype is not None and device_const_dtype(v) != self.dtype:
+        if self.dtype is not None and const_bucket(v) != self.dtype:
             return False
         return True
 
@@ -177,8 +180,27 @@ def _pack_scalar(v, out: bytearray) -> None:
         else:
             out += b"\xc6" + n.to_bytes(4, "big")
         out += v
+    elif type(v) is Decimal:
+        # codec/row.py msgpack_default: ExtType(1, text), framed as
+        # msgpack frames an ext (fixext for 1/2/4/8/16 bytes)
+        text = format(v, "f").encode()
+        n = len(text)
+        fix = _FIXEXT.get(n)
+        if fix is not None:
+            out.append(fix)
+        elif n <= 0xFF:
+            out += b"\xc7" + n.to_bytes(1, "big")
+        else:
+            raise _Ineligible("oversized decimal constant")
+        out.append(_EXT_DECIMAL)
+        out += text
     else:
         raise _Ineligible(f"unsupported wire scalar {type(v).__name__}")
+
+
+_EXT_DECIMAL = 1
+_FIXEXT = {1: 0xD4, 2: 0xD5, 4: 0xD6, 8: 0xD7, 16: 0xD8}
+_FIXEXT_LEN = {tag: n for n, tag in _FIXEXT.items()}
 
 
 class _Ineligible(Exception):
@@ -290,7 +312,22 @@ def _parse_scalar(buf: bytes, off: int):
         if end > len(buf):
             return None
         return buf[hend:end], end
-    return None                         # container / ext / reserved
+    if b in _FIXEXT_LEN or b == 0xC7:   # a DECIMAL constant's ext
+        if b == 0xC7:
+            if off + 2 > len(buf):
+                return None
+            n, hend = buf[off + 1], off + 2
+        else:
+            n, hend = _FIXEXT_LEN[b], off + 1
+        end = hend + 1 + n
+        if end > len(buf) or buf[hend] != _EXT_DECIMAL:
+            return None
+        try:
+            return wire.msgpack_ext_hook(
+                _EXT_DECIMAL, bytes(buf[hend + 1:end])), end
+        except Exception:   # noqa: BLE001 — not a decimal's text
+            return None
+    return None                         # container / other ext / reserved
 
 
 class WireTemplate:
@@ -382,13 +419,14 @@ def _mark_slots(req: dict):
         if e["k"] == "c":
             v = e.get("v")
             out = dict(e)
-            # only int/float constants rotate within a compile class
-            # (class_key buckets them by device dtype); str/bytes/None
-            # constants are part of the class identity — they stay
-            # fixed bytes, and changing one is a structural miss
-            if type(v) in (int, float):
-                out["v"] = _Slot(K_CONST, n_const, type(v),
-                                 device_const_dtype(v))
+            # only numeric constants rotate within a compile class
+            # (datatype.const_bucket: int, float, DECIMAL; class_key
+            # buckets them alike); str/bytes/None constants are part of
+            # the class identity — they stay fixed bytes, and changing
+            # one is a structural miss
+            bucket = const_bucket(v)
+            if bucket is not None:
+                out["v"] = _Slot(K_CONST, n_const, type(v), bucket)
                 n_const += 1
             return out
         if e["k"] == "f":
@@ -504,12 +542,12 @@ def _dag_const_substituter(dag) -> Callable:
 
     def has_const(e) -> bool:
         if e.kind == "const":
-            return type(e.value) in (int, float)
+            return const_bucket(e.value) is not None
         return any(has_const(c) for c in e.children)
 
     def sub_expr(e, it):
         if e.kind == "const":
-            if type(e.value) in (int, float):
+            if const_bucket(e.value) is not None:
                 return Expr(kind="const", value=next(it),
                             eval_type=e.eval_type)
             return e
@@ -576,7 +614,8 @@ def _key_template(key: tuple):
     def compile_node(t):
         nonlocal count
         if isinstance(t, tuple):
-            if len(t) == 3 and t[0] == "c" and type(t[1]) in (int, float):
+            if len(t) == 3 and t[0] == "c" and \
+                    const_bucket(t[1]) is not None:
                 count += 1
                 et = t[2]
                 return lambda it, et=et: ("c", next(it), et)
@@ -1089,13 +1128,14 @@ def encode_response(env: dict, result) -> bytes:
 
 
 def _const_at(dag_dict: dict, index: int):
-    """The ``index``-th rotating (int/float) constant of the wire dag,
-    in the same DFS order _mark_slots assigns."""
+    """The ``index``-th rotating constant (``const_bucket``) of the wire
+    dag, in the same DFS order _mark_slots assigns."""
     found = []
 
     def walk_expr(e):
         if e.get("k") == "c":
-            if type(e.get("v")) in (int, float):
+            v = e.get("v")
+            if const_bucket(v) is not None:
                 found.append(e["v"])
         elif e.get("k") == "f":
             for c in e.get("ch", ()):

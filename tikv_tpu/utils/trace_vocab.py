@@ -26,6 +26,9 @@ SPAN_VOCABULARY: dict[str, str] = {
     "untracked": "synthesized residual: root wall no child span covers",
     "admission": "umbrella: deadline/resource gating + class keying",
     "plan_decode": "wire → DAGRequest decode (compile-class keying)",
+    "decimal_lower": "span-only, inside plan analysis on a plan-cache "
+                     "miss: decimal and date RPN lowered to the integer "
+                     "RPN the device evaluates (device/lowering.py)",
     "copr_handler": "umbrella: coprocessor handler (snapshot, "
                     "routing, dispatch) — endpoint overhead between "
                     "finer spans",
