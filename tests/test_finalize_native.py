@@ -1,4 +1,4 @@
-"""The hash aggregation's finalize after a Pallas launch is ONE native call.
+"""An aggregation's finalize after a Pallas launch is ONE native call.
 
 ``aggregate.finalize_packed`` hands the fetched ``(2, HI, W)`` int32
 accumulator parts to ``native.hash_finalize_packed``
@@ -11,6 +11,13 @@ the same bytes, for every aggregate, key mode, NULL shape, grid shape
 and width the Pallas hash path can produce.  What the native call cannot
 serve must reach the chain unchanged, and the runner must count which of
 the two ran.
+
+An aggregation without GROUP BY takes the same call since PR 35: its
+accumulator is a grid of ONE slot (``slots`` 1: no key, no NULL group,
+no scrap row) that is one row whether or not a row reached it.  Its
+oracle is the finalize as ``run_simple`` ran it before:
+``_pallas_states`` → ``ops.agg.finalize_simple`` → ``from_scaled`` →
+``Column.from_list``.
 
 Off a TPU no plan reaches ``_try_pallas``, so the accumulators here are
 synthetic: plane sums drawn at random, packed as the kernel packs them.
@@ -27,7 +34,7 @@ import pytest
 import jax
 
 from tikv_tpu import native
-from tikv_tpu.datatype import EvalType, FieldType
+from tikv_tpu.datatype import Column, EvalType, FieldType
 from tikv_tpu.datatype.column import ColumnBatch
 from tikv_tpu.device import DeviceRunner
 from tikv_tpu.device import aggregate as agg_mod
@@ -35,7 +42,8 @@ from tikv_tpu.device.aggregate import DeviceAggregator
 from tikv_tpu.device.request import _Plan
 from tikv_tpu.device.kernels import PlaneLayout, build_layouts
 from tikv_tpu.executors.runner import SelectResult
-from tikv_tpu.ops.agg import AggSpec, finalize_hash
+from tikv_tpu.datatype.mydecimal import from_scaled
+from tikv_tpu.ops.agg import AggSpec, finalize_hash, finalize_simple
 from tikv_tpu.parallel import make_mesh
 from tikv_tpu.server import fastpath
 
@@ -52,11 +60,19 @@ I64_MAX = (1 << 63) - 1
 def make_case(aggs=(("count_star", 0), ("sum", 2)), *, mode="dense",
               base=0, capacity=1024, groups=700, null_group=False,
               zero_nonnull=False, parts=1, tight=False, big=False,
-              ok_is_mask=False, LO=32, seed=0):
+              ok_is_mask=False, LO=32, seed=0, fracs=()):
     return dict(aggs=aggs, mode=mode, base=base, capacity=capacity,
                 groups=groups, null_group=null_group,
                 zero_nonnull=zero_nonnull, parts=parts, tight=tight,
-                big=big, ok_is_mask=ok_is_mask, LO=LO, seed=seed)
+                big=big, ok_is_mask=ok_is_mask, LO=LO, seed=seed,
+                fracs=fracs)
+
+
+def simple_case(aggs=(("count_star", 0), ("sum", 2)), *, rows=True, **kw):
+    """An aggregation without GROUP BY: a grid of one slot, reached by
+    some row (``rows``) or by none."""
+    return make_case(aggs, mode="simple", base=0, capacity=1,
+                     groups=int(rows), **kw)
 
 
 def split(rng, total, n_parts):
@@ -70,8 +86,9 @@ def split(rng, total, n_parts):
 
 def accumulator(case):
     """→ (parts, LO, p8, layouts, specs, slots, base, capacity,
-    slot_keys): what ``run_hash`` hands ``finalize_packed`` after a
-    Pallas launch, with plane sums drawn at random."""
+    slot_keys): what ``run_hash`` (``run_simple`` in mode ``simple``)
+    hands ``finalize_packed`` after a Pallas launch, with plane sums
+    drawn at random."""
     rng = np.random.default_rng(case["seed"])
     capacity, LO = case["capacity"], case["LO"]
     specs = [AggSpec(kind, i, EvalType.INT)
@@ -80,11 +97,13 @@ def accumulator(case):
         specs, [False] * len(specs), [nb for _k, nb in case["aggs"]],
         [case["ok_is_mask"]] * len(specs))
     assert pf == 0
-    slots = capacity + 2                        # + NULL + scrap
+    simple = case["mode"] == "simple"
+    slots = 1 if simple else capacity + 2       # + NULL + scrap
     # the kernel's tight grid: no scrap row, and no NULL row for a key
     # that cannot be NULL, so HI·LO may be under ``slots``
     grid = capacity if case["tight"] else slots
-    HI = -(-grid // LO)
+    # (pallas_hash.build rounds HI up to whole sublane tiles of 8)
+    HI = 8 if simple else -(-grid // LO)
     if case["tight"]:
         assert HI * LO < slots and not case["null_group"]
 
@@ -99,7 +118,9 @@ def accumulator(case):
     present[rng.choice(key_slots, case["groups"], replace=False)] = True
     if case["null_group"]:
         present[capacity] = True
-    if not case["tight"]:
+    if simple:
+        present[1:] = True      # nothing scatters past slot 0: never read
+    elif not case["tight"]:
         present[capacity + 1] = True            # the scrap slot: ignored
 
     cmax = 1 << 37 if case["big"] else 1 << 12
@@ -129,9 +150,9 @@ def accumulator(case):
             slot_keys)
 
 
-def plan_of(specs):
+def plan_of(specs, fracs=()):
     return _Plan(scan=None, kind="hash_agg", used_cols=[],
-                            specs=list(specs))
+                 specs=list(specs), agg_fracs=list(fracs))
 
 
 def numpy_chain(parts, LO, p8, layouts, specs, slots, base, capacity,
@@ -145,8 +166,27 @@ def numpy_chain(parts, LO, p8, layouts, specs, slots, base, capacity,
         finalize_hash(specs, merged, base, capacity, slot_keys=slot_keys))
 
 
-def wire_bytes(specs, cols):
-    schema = DeviceAggregator._agg_out(plan_of(specs))[0] + [FieldType.long()]
+def simple_chain(parts, LO, p8, layouts, specs, slots, _base, _capacity,
+                 _slot_keys, fracs=()):
+    """``run_simple``'s ``from_packed`` and ``_simple_result`` as they
+    stood before PR 35, Python scalars and all: the oracle of the
+    one-slot grid."""
+    assert slots == 1
+    _present, states = agg_mod._pallas_states(
+        agg_mod._sum_parts(parts), LO, p8, layouts, specs, 1)
+    merged = [{k: np.asarray(v).reshape(-1)[0] for k, v in s.items()}
+              for s in states]
+    finals = finalize_simple(specs, merged)
+    fts, _dts, fracs = DeviceAggregator._agg_out(plan_of(specs, fracs))
+    return [Column.from_list(
+        ft.eval_type,
+        [val if frac is None or val is None else from_scaled(val, frac)])
+        for ft, val, frac in zip(fts, finals, fracs)]
+
+
+def wire_bytes(specs, cols, fracs=(), keyed=True):
+    schema = DeviceAggregator._agg_out(plan_of(specs, fracs))[0] + \
+        [FieldType.long()] * keyed
     return fastpath.encode_response(
         {"backend": "device", "trace_id": "t"},
         SelectResult(ColumnBatch(schema, list(cols)), []))
@@ -250,6 +290,88 @@ def test_native_planes_equal_the_numpy_chain(name):
             assert all((np.abs(c.values) > 1 << 32).any() for c in sums)
 
 
+Q6 = (("sum", 4),)            # one SUM of a 4-byte product, p8 6
+
+SIMPLE_CASES = {
+    # each kind alone, then together; own validity planes and aliased
+    "count_star": simple_case((("count_star", 0),)),
+    "count": simple_case((("count", 0),)),
+    "sum": simple_case((("sum", 2),)),
+    "avg": simple_case((("avg", 2),)),
+    "together": simple_case(ALL_FOUR),
+    "together-ok-is-mask": simple_case(ALL_FOUR, ok_is_mask=True),
+    "q6": simple_case(Q6),
+    "q6-decimal-frac-4": simple_case(Q6, fracs=(4,)),
+    "decimal-frac-beside-int": simple_case(
+        (("count_star", 0), ("sum", 8), ("sum", 3), ("avg", 2)),
+        fracs=(None, 2, None, None), big=True),
+    # tiles add
+    "three-parts": simple_case(ALL_FOUR, parts=3),
+    "three-parts-ok-is-mask-big": simple_case(ALL_FOUR, parts=3, big=True,
+                                              ok_is_mask=True),
+    "LO-128": simple_case(ALL_FOUR, LO=128),
+    # widths: negative sums come out of the bias term; nb 8 wraps int64
+    **{f"nb-{nb}": simple_case((("sum", nb), ("avg", nb)), seed=nb)
+       for nb in range(1, 9)},
+    **{f"big-nb-{nb}": simple_case((("count", 0), ("sum", nb), ("avg", nb)),
+                                   big=True, seed=nb) for nb in (2, 4, 8)},
+    # ONE row whatever reached the slot: COUNT 0, SUM and AVG NULL
+    "no-row": simple_case(ALL_FOUR, rows=False),
+    "no-row-ok-is-mask": simple_case(ALL_FOUR, rows=False, ok_is_mask=True),
+    "no-row-three-parts": simple_case(ALL_FOUR, rows=False, parts=3),
+    "no-row-decimal-frac": simple_case(Q6, rows=False, fracs=(4,)),
+    # rows, none of whose arguments was non-NULL
+    "rows-whose-arguments-are-all-null": simple_case(ALL_FOUR,
+                                                     zero_nonnull=True),
+}
+
+
+@needs_native
+@pytest.mark.parametrize("name", SIMPLE_CASES)
+def test_a_one_slot_grid_finalizes_as_finalize_simple(name, monkeypatch):
+    case = SIMPLE_CASES[name]
+    fracs = case["fracs"]
+    args = accumulator(case)
+    specs, slots = args[4], args[5]
+    assert slots == 1
+    want = simple_chain(*args, fracs=fracs)
+    finalized, was_native = agg_mod.finalize_packed(*args)
+    assert was_native
+    (keys, key_valid), planes = finalized
+    assert keys is None and key_valid is None
+    assert all(len(vals) == len(ok) == 1 for vals, ok in planes)
+    got = agg_mod._hash_columns(
+        DeviceAggregator._agg_out(plan_of(specs, fracs)), finalized)
+    assert_same_columns(got, want)
+    assert wire_bytes(specs, got, fracs, keyed=False) == \
+        wire_bytes(specs, want, fracs, keyed=False)
+    # the chain through ``_simple_planes`` (what the native call
+    # declines) wraps to the same columns
+    monkeypatch.setattr(native, "hash_finalize_packed", None)
+    chained, was_native = agg_mod.finalize_packed(*args)
+    assert not was_native
+    assert_same_columns(agg_mod._hash_columns(
+        DeviceAggregator._agg_out(plan_of(specs, fracs)), chained), want)
+    # the oracle is not vacuous
+    for col, (kind, nb), frac in zip(want, case["aggs"],
+                                     fracs or (None,) * len(want)):
+        if kind in ("count_star", "count"):
+            assert col.validity[0]
+            reached = case["groups"] and not (
+                kind == "count" and case["zero_nonnull"]
+                and not case["ok_is_mask"])
+            assert (col.values[0] > 0) == bool(reached)
+        else:
+            assert col.validity[0] == bool(
+                case["groups"] and not case["zero_nonnull"])
+            if frac is not None:
+                assert col.eval_type is EvalType.DECIMAL
+                assert not col.validity[0] or \
+                    col.values[0].as_tuple().exponent == -frac
+            if kind == "sum" and case["big"] and nb >= 4 and frac is None:
+                assert abs(int(col.values[0])) > 1 << 32
+
+
 @needs_native
 def test_the_native_finalize_never_lets_go_of_the_gil():
     """It is one call because nothing inside it hands the GIL on."""
@@ -317,21 +439,28 @@ DECLINED = {
     "parts-not-contiguous": strided_parts,
     "extension-absent": lambda args: args,
 }
+# a grid of one slot has no key domain to be outside int64
+ONE_SLOT = "one-slot-"
+DECLINED_GRIDS = [*DECLINED, *(ONE_SLOT + name for name in DECLINED
+                               if "uint64" not in name)]
 
 
-@pytest.mark.parametrize("name", DECLINED)
+@pytest.mark.parametrize("name", DECLINED_GRIDS)
 def test_what_the_native_call_declines_takes_the_numpy_chain(
         name, runner, monkeypatch):
+    one_slot = name.startswith(ONE_SLOT)
+    name = name.removeprefix(ONE_SLOT)
+    aggs = (("count_star", 0), ("sum", 2))
     args = DECLINED[name](accumulator(
-        make_case((("count_star", 0), ("sum", 2)), null_group=True,
-                  parts=2, seed=5)))
+        simple_case(aggs, parts=2, seed=5) if one_slot else
+        make_case(aggs, null_group=True, parts=2, seed=5)))
     if name == "extension-absent":
         monkeypatch.setattr(native, "hash_finalize_packed", None)
     else:
         def never(*_a):
             raise AssertionError(f"{name}: handed to the native call")
         monkeypatch.setattr(native, "hash_finalize_packed", never)
-    want = outcome(numpy_chain, *args)
+    want = outcome(simple_chain if one_slot else numpy_chain, *args)
     before = runner.mesh_stats()["finalize"]
     (parts, LO, p8, layouts, specs, slots, base, capacity, slot_keys) = args
     got = outcome(runner._aggregator._packed_columns, plan_of(specs), parts, LO, p8,
@@ -340,10 +469,12 @@ def test_what_the_native_call_declines_takes_the_numpy_chain(
     assert got[0] == want[0]
     if want[0] == "columns":
         assert_same_columns(got[1], want[1])
-        assert len(got[1][-1].values) == 701
+        assert [len(c.values) for c in got[1]] == \
+            ([1, 1] if one_slot else [701] * 3)
         if "uint64" in name:
             assert got[1][-1].values.dtype == np.uint64
-        assert wire_bytes(specs, got[1]) == wire_bytes(specs, want[1])
+        assert wire_bytes(specs, got[1], keyed=not one_slot) == \
+            wire_bytes(specs, want[1], keyed=not one_slot)
     else:
         # never on the Pallas path: the chain fails as it did before
         assert name in ("f32-plane", "kind-outside-the-four")
@@ -365,3 +496,37 @@ def test_the_runner_counts_a_native_finalize(runner):
     after = runner.mesh_stats()["finalize"]
     assert after == {"native": before["native"] + 1,
                      "numpy": before["numpy"], "native_available": True}
+
+
+# ----------------------------------------- the one-slot grid, on the runner
+
+
+@needs_native
+@pytest.mark.parametrize("rows", [True, False], ids=["rows", "no-row"])
+def test_the_runner_counts_a_native_finalize_of_one_slot(runner, rows):
+    args = accumulator(simple_case(ALL_FOUR, rows=rows, parts=3))
+    (parts, LO, p8, layouts, specs, slots, base, capacity, slot_keys) = args
+    before = runner.mesh_stats()["finalize"]
+    cols = runner._aggregator._packed_columns(
+        plan_of(specs), parts, LO, p8, layouts, slots, base, capacity,
+        slot_keys)
+    assert_same_columns(cols, simple_chain(*args))
+    assert [c.validity[0] for c in cols] == [True, True, rows, rows]
+    after = runner.mesh_stats()["finalize"]
+    assert after == {"native": before["native"] + 1,
+                     "numpy": before["numpy"], "native_available": True}
+
+
+@needs_native
+@pytest.mark.parametrize("fault", ["validity-without-a-key", "empty-grid"])
+def test_the_native_call_refuses_a_keyless_grid_it_cannot_read(fault):
+    parts, LO, p8, layouts, *_ = accumulator(simple_case())
+    desc = agg_mod._native_layout_desc(layouts)
+    outs = [(np.empty(1, np.int64), np.empty(1, np.bool_)) for _ in layouts]
+    if fault == "empty-grid":
+        args, err = ([p[:, :0] for p in parts], None, None), ValueError
+    else:
+        args, err = (parts, None, np.empty(1, np.bool_)), TypeError
+    with pytest.raises(err):
+        native.hash_finalize_packed(args[0], LO, p8, 1, 0, None, desc,
+                                    args[1], args[2], outs)

@@ -79,7 +79,8 @@ def _finalized_natively(runner: DeviceRunner) -> None:
     and the warm launch's parts) became planes in the one native call
     (native/fastbuild.cpp ``hash_finalize_packed``) where the extension
     built, and in the numpy chain where it did not: counted either way,
-    once a finalize (the only tier-1 path through ``from_packed``)."""
+    once a finalize, with GROUP BY and without (the tier-1 path through
+    both ``from_packed``s)."""
     built = native.hash_finalize_packed is not None
     assert runner.mesh_stats()["finalize"] == {
         "native": 2 if built else 0, "numpy": 0 if built else 2,
@@ -169,6 +170,7 @@ def test_simple_mode_matches_numpy(interpret, n_devices):
         assert row[0] == int(vv.sum()) and row[1] == len(vv), row
         assert row[2] == int(vv.sum()) / len(vv), row
     _served_by_pallas(runner)
+    _finalized_natively(runner)
 
 
 @pytest.mark.parametrize("keys", ["dense", "sparse"])

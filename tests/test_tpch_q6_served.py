@@ -14,8 +14,9 @@ Held here: the fanned-out answer against the numpy reference AND the host
 pipeline, exactly, at the clause's validation tuple, at both ends of every
 parameter's range and where no row passes; two tuples, one kernel build;
 lanes of one launch that carry different tuples; the fast path's hit on a
-second tuple with the constants it extracted; the control; and the whole
-flow of ``benchmark/loadgen.py`` as a child process."""
+second tuple with the constants it extracted; the control; every task's
+finalize in the one native call (PR 35), counted on ``/health``; and the
+whole flow of ``benchmark/loadgen.py`` as a child process."""
 
 import decimal
 import functools
@@ -35,7 +36,9 @@ import jax
 
 from tikv_tpu.config import TikvConfig
 from tikv_tpu.datatype import Column, EvalType
+from tikv_tpu import native
 from tikv_tpu.device import DeviceRunner, pallas_hash
+from tikv_tpu.device import aggregate as agg_mod
 from tikv_tpu.executors.columnar import ColumnarTable
 from tikv_tpu.executors.runner import BatchExecutorsRunner
 from tikv_tpu.parallel import make_mesh
@@ -251,8 +254,16 @@ def test_q6_equals_the_reference_and_the_host_pipeline(store, kind, params,
                                                        case):
     tup, named = CASES[case]
     index = kind.TUPLES.index(named) if named else 0
+    fin0 = health(store)["device_mesh"]["finalize"]
     rec, resp = read(store, kind, params, index, tup)
     assert rec["ok"], rec
+    # a task's one-slot accumulator is finalized once, natively where
+    # the extension built (where no row passes too: one row, NULL)
+    fin1 = health(store)["device_mesh"]["finalize"]
+    built = native.hash_finalize_packed is not None
+    assert fin1["native_available"] is built
+    assert fin1["native"] - fin0["native"] == (N if built else 0)
+    assert fin1["numpy"] - fin0["numpy"] == (0 if built else N)
     assert resp["tasks"] == N and rec["labels"]["cop_tasks"] == str(N)
     got_index, total, exact = np.frombuffer(rec["answer"], np.int64)
     want = kind.revenue(store.ctx, index, tup=tup)
@@ -293,6 +304,26 @@ def test_a_float_partial_is_a_wrong_answer(store, kind, params):
     rec["answer"] = kind.digest(store.ctx, resp, params)
     assert failing(kind.check(store.ctx, [rec], params, None)) == \
         ["tpch_q6.wrong_answers"]
+
+
+@pytest.mark.skipif(native.hash_finalize_packed is None,
+                    reason="native/fastbuild.cpp did not build here")
+def test_no_numpy_runs_over_a_served_tasks_accumulator(store, kind, params,
+                                                       monkeypatch):
+    """The served finalize is the native call alone: with the numpy
+    chain's first step made to raise, every task of a read (whole-feed
+    launches and lanes alike) still answers from the device, undegraded."""
+    def chain(_parts):
+        raise AssertionError("the numpy chain on the served path")
+    monkeypatch.setattr(agg_mod, "_sum_parts", chain)
+    fin0 = health(store)["device_mesh"]["finalize"]
+    for index in (7, 33):
+        rec, _resp = read(store, kind, params, index)
+        assert rec["ok"], rec
+        assert failing(kind.check(store.ctx, [rec], params, None)) == []
+    fin1 = health(store)["device_mesh"]["finalize"]
+    assert fin1["native"] - fin0["native"] == 2 * N
+    assert fin1["numpy"] == fin0["numpy"]
 
 
 # ------------------------------------------------- one kernel, many tuples

@@ -47,7 +47,6 @@ from jax.sharding import SingleDeviceSharding
 
 from .. import native
 from ..datatype import Column, EvalType, FieldType
-from ..datatype.mydecimal import from_scaled
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef
 from ..ops.agg import (
@@ -446,11 +445,11 @@ class DeviceAggregator:
 
     def _packed_columns(self, plan, parts, LO, p8, layouts, slots, base,
                         capacity, slot_keys):
-        """The hash aggregation's finalize after a Pallas launch:
-        ``finalize_packed`` (one native call where it can, the numpy
-        chain where it cannot), counted once on the physical runner's
-        flight recorder as what it was (``mesh_stats`` ``finalize``),
-        then ``_hash_columns``."""
+        """An aggregation's finalize after a Pallas launch, GROUP BY
+        or not (``slots`` 1): ``finalize_packed`` (one native call
+        where it can, the numpy chain where it cannot), counted once on
+        the physical runner's flight recorder as what it was
+        (``mesh_stats`` ``finalize``), then ``_hash_columns``."""
         runner = self._runner
         finalized, was_native = finalize_packed(
             parts, LO, p8, layouts, plan.specs, slots, base, capacity,
@@ -1339,7 +1338,9 @@ class DeviceAggregator:
         # a single-slot grid turns SUM/COUNT/AVG into one direct-index
         # pass — the XLA scan's per-step and fusion-boundary costs
         # (pallas_hash.py module doc) taxed config 3 the same way they
-        # taxed config 4
+        # taxed config 4.  Its finalize is the GROUP BY's
+        # (``_packed_columns``: one native call that holds the GIL, the
+        # planes wrapped by ``_hash_columns``) over that one slot
         layouts = p8 = pf = arg_nbytes = arg_ok_is_mask = None
         if matmul_supported(plan.specs):
             arg_nbytes = meta.get("simple_arg_nbytes")
@@ -1351,6 +1352,11 @@ class DeviceAggregator:
             arg_ok_is_mask = self._arg_ok_is_mask(plan, feed)
             layouts, p8, pf = build_layouts(plan.specs, arg_is_real,
                                             arg_nbytes, arg_ok_is_mask)
+        agg_out = self._agg_out(plan)
+
+        def result(cols):
+            return runner._result(dag, list(agg_out[0]), cols)
+
         mode = pallas_hash.MODE_SIMPLE
         if agg_bodies(runner._is_tpu, runner._nshards(), plan, feed, dtypes,
                       layouts, p8, pf, 1, mode, False)[0] == "pallas_hash":
@@ -1361,11 +1367,10 @@ class DeviceAggregator:
                 synced, parts, LO = got
 
                 def from_packed(parts):
-                    _present, states = _pallas_states(
-                        _sum_parts(parts), LO, p8, layouts, plan.specs, 1)
-                    merged = [{k: np.asarray(v).reshape(-1)[0]
-                               for k, v in s.items()} for s in states]
-                    return self._simple_result(dag, plan, merged)
+                    # the fetched accumulator is a grid of ONE slot: no
+                    # key, no NULL group, no scrap row (``slots`` 1)
+                    return result(self._packed_columns(
+                        plan, parts, LO, p8, layouts, 1, 0, 1, None))
 
                 if isinstance(parts, _LanePending):
                     parts.finalize = from_packed
@@ -1396,19 +1401,10 @@ class DeviceAggregator:
             with nullcontext() if runner._single \
                     else trace.phase("shard_merge"):
                 merged = self._merge_stacked(plan.specs, summed, stacked)
-            return self._simple_result(dag, plan, merged)
+            return result(_hash_columns(
+                agg_out, _simple_planes(plan.specs, merged)))
 
         return _Pending(carry, fin)
-
-    def _simple_result(self, dag, plan, merged):
-        finals = finalize_simple(plan.specs, merged)
-        fts, _dts, fracs = self._agg_out(plan)
-        cols = [Column.from_list(
-            ft.eval_type,
-            [val if frac is None or val is None
-             else from_scaled(val, frac)])
-            for ft, val, frac in zip(fts, finals, fracs)]
-        return self._runner._result(dag, list(fts), cols)
 
 
 # -- the finalize: the fetched accumulator in, planes and Columns out.
@@ -1467,28 +1463,34 @@ class _LaneLaunch:
 
 def finalize_packed(parts, LO, p8, layouts, specs, slots, base, capacity,
                     slot_keys):
-    """The fetched Pallas hash accumulator → ``finalize_hash``'s planes.
+    """The fetched Pallas accumulator → ``finalize_hash``'s planes.
 
     ``parts``: one (2, HI, p8·LO) int32 pair per tile (one on a
     whole-feed launch and on a mesh); they add.  Returns
-    ``(((keys, key_valid), planes), native)``.
+    ``(((keys, key_valid), planes), native)``.  ``slots`` 1 is the grid
+    of an aggregation without GROUP BY (``pallas_hash.MODE_SIMPLE``): no
+    key (``keys`` and ``key_valid`` None), no NULL group, and ONE row
+    whether or not a row reached the slot (COUNT 0, SUM and AVG NULL:
+    ``finalize_simple``'s rule, where a GROUP BY answers no group).
 
-    Where the extension built and the input is what the Pallas hash
-    path produces — int32 parts, integer layouts of COUNT / SUM / AVG,
-    a key domain inside int64 — this is ONE call into
+    Where the extension built and the input is what the Pallas path
+    produces — int32 parts, integer layouts of COUNT / SUM / AVG, a key
+    domain inside int64 — this is ONE call into
     ``native.hash_finalize_packed``, which holds the GIL from entry to
-    return: the numpy chain below makes ~24 array calls on planes of
-    1k-4k elements, numpy drops the GIL around each, and on a serving
-    store every drop queues behind ~10 runnable threads (PERF.md
-    section 6, PRs 26 and 28).  The planes are views of buffers sized
-    ``capacity + 1`` (``np.empty`` and a slice drop no GIL).  What it
-    adapts to is in its input: anything else takes the numpy chain
-    (``_sum_parts`` → ``_pallas_states`` → ``finalize_hash``), the same
-    bytes, kept as the fallback and as the oracle of
-    tests/test_finalize_native.py.  ``native`` says which ran; the
-    caller counts it (``/health`` ``device_mesh.finalize``).
+    return: the numpy chain below makes ~24 array calls (~10 over one
+    slot) on planes of 1k-4k elements, numpy drops the GIL around each,
+    and on a serving store every drop queues behind ~10 runnable
+    threads (PERF.md section 6, PRs 26, 28 and 35).  The planes are
+    views of buffers sized ``capacity + 1`` (``np.empty`` and a slice
+    drop no GIL).  What it adapts to is in its input: anything else
+    takes the numpy chain (``_sum_parts`` → ``_pallas_states`` →
+    ``finalize_hash`` / ``finalize_simple``), the same bytes, kept as
+    the fallback and as the oracle of tests/test_finalize_native.py.
+    ``native`` says which ran; the caller counts it (``/health``
+    ``device_mesh.finalize``).
     """
     call = native.hash_finalize_packed
+    simple = slots == 1
     keys_fit_int64 = slot_keys.dtype == np.int64 if slot_keys is not None \
         else base + capacity <= _I64_MAX
     desc = None
@@ -1496,20 +1498,35 @@ def finalize_packed(parts, LO, p8, layouts, specs, slots, base, capacity,
             p.dtype == np.int32 and p.flags.c_contiguous for p in parts):
         desc = _native_layout_desc(layouts)
     if desc is not None:
-        n = capacity + 1                # + the NULL slot
-        keys = np.empty(n, np.int64)
-        key_valid = np.empty(n, np.bool_)
+        n = 1 if simple else capacity + 1   # + the NULL slot
+        key = (None, None) if simple else \
+            (np.empty(n, np.int64), np.empty(n, np.bool_))
         outs = [(np.empty(n, np.float64 if lay.kind == "avg" else np.int64),
                  np.empty(n, np.bool_)) for lay in layouts]
         k = call(parts, LO, p8, capacity, 0 if slot_keys is not None
-                 else base, slot_keys, desc, keys, key_valid, outs)
-        return ((keys[:k], key_valid[:k]),
+                 else base, slot_keys, desc, *key, outs)
+        return (key if simple else (key[0][:k], key[1][:k]),
                 [(vals[:k], ok[:k]) for vals, ok in outs]), True
     present, states = _pallas_states(
         _sum_parts(parts), LO, p8, layouts, specs, slots)
+    if simple:
+        return _simple_planes(specs, [
+            {k: np.asarray(v).reshape(-1)[0] for k, v in s.items()}
+            for s in states]), False
     return finalize_hash(
         specs, {"present": present, "overflow": False, "states": states},
         base, capacity, slot_keys=slot_keys), False
+
+
+def _simple_planes(specs, merged):
+    """``finalize_simple``'s one row in ``finalize_hash``'s form, every
+    plane of length one and no key: what ``_hash_columns`` wraps for an
+    aggregation without GROUP BY that the native call did not serve
+    (the XLA ``simple`` body's states; the Pallas body's through the
+    numpy chain)."""
+    return (None, None), [
+        (np.array([0 if v is None else v]), np.array([v is not None]))
+        for v in finalize_simple(specs, merged)]
 
 
 # kernels.PlaneLayout kinds the native finalize serves, by the code
@@ -1556,13 +1573,14 @@ def _pallas_states(packed, LO, p8, layouts, specs, slots):
 
 
 def _hash_columns(agg_out, finalized):
-    """Finalized hash-agg planes → result Columns (aggregates, then the
-    key): the ONE place where planes become Columns for every hash body,
-    whether ``ops.agg.finalize_hash`` or the native call of
-    ``finalize_packed`` made them.  No Python value is made per group
-    between the fetched accumulator and the wire encoder.  ``agg_out``:
-    ``DeviceAggregator._agg_out`` of the plan; ``finalized``:
-    ``finalize_hash``'s ``((keys, key_valid), planes)``."""
+    """Finalized planes → result Columns (aggregates, then the key of
+    a GROUP BY): the ONE place where planes become Columns for every
+    aggregation body, whether ``ops.agg.finalize_hash``, the native
+    call of ``finalize_packed`` or ``_simple_planes`` made them.  No
+    Python value is made per group between the fetched accumulator and
+    the wire encoder.  ``agg_out``: ``DeviceAggregator._agg_out`` of the
+    plan; ``finalized``: ``finalize_hash``'s ``((keys, key_valid),
+    planes)``, ``keys`` None where there is no GROUP BY."""
     (keys, key_valid), planes = finalized
     fts, dts, fracs = agg_out
     cols = [Column(ft.eval_type, vals.astype(dt, copy=False), ok)
@@ -1572,5 +1590,6 @@ def _hash_columns(agg_out, finalized):
             Column(ft.eval_type, np.asarray(vals, np.int64), ok,
                    frac).unscaled()
             for ft, dt, frac, (vals, ok) in zip(fts, dts, fracs, planes)]
-    cols.append(Column(EvalType.INT, keys, key_valid))
+    if keys is not None:
+        cols.append(Column(EvalType.INT, keys, key_valid))
     return cols

@@ -238,12 +238,12 @@ HOST_STAGER = _PinnedStager()
 class _Pending:
     """A dispatched device request: output pytree still on device plus
     the host finalize that turns the fetched numpy tree into a
-    SelectResult (the ``host_materialize`` phase; for a hash
-    aggregation after a Pallas launch ``finalize_packed``: ONE native
-    call from the fetched accumulator parts to the result planes, GIL
-    held throughout, then ``_hash_columns``'s wrap; the numpy chain
-    where that call cannot serve, and ``finalize_hash`` for the XLA
-    bodies' states).  Leaves are staged to
+    SelectResult (the ``host_materialize`` phase; for an aggregation
+    after a Pallas launch, GROUP BY or not, ``finalize_packed``: ONE
+    native call from the fetched accumulator parts to the result
+    planes, GIL held throughout, then ``_hash_columns``'s wrap; the
+    numpy chain where that call cannot serve, and ``finalize_hash`` /
+    ``finalize_simple`` for the XLA bodies' states).  Leaves are staged to
     pinned host memory at construction when the backend supports it
     (:class:`_PinnedStager`)
     and ``copy_to_host_async`` is issued for every leaf, so the D2H

@@ -2729,12 +2729,12 @@ class DeviceRunner:
         # p50 can be attributed from the artifact alone: "d2h_wait" is
         # the transfer + sync (here), "host_materialize" is the host
         # finalize that follows (_finish): fetched planes -> result
-        # Columns.  For a hash aggregation off the Pallas kernel that is
-        # one native call over the KBs of accumulator, which never
-        # lets go of the GIL (aggregate.finalize_packed; numpy over the
-        # same KBs for the XLA bodies, no Python value made per group
-        # either way); for a selection it is the host gather of the
-        # selected rows
+        # Columns.  For an aggregation off the Pallas kernel, with a
+        # GROUP BY or without, that is one native call over the KBs of
+        # accumulator, which never lets go of the GIL
+        # (aggregate.finalize_packed; numpy over the same KBs for the
+        # XLA bodies, no Python value made per group either way); for a
+        # selection it is the host gather of the selected rows
         with tracker.phase("d2h_wait"):
             leaves, treedef = jax.tree.flatten(tree)
             for x in leaves:

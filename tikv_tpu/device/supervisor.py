@@ -141,9 +141,10 @@ class FlightRecorder:
         # configured mesh (not on a placement slice, not on a degraded
         # submesh): what /health device_mesh reports beside ``launches``
         self.sharded_launches = 0
-        # Pallas hash accumulators finalized (device/aggregate.py
-        # finalize_packed), by what ran: the one native call that holds
-        # the GIL, or the numpy chain it falls back to
+        # Pallas accumulators finalized (device/aggregate.py
+        # finalize_packed: a GROUP BY's grid, or the one slot of an
+        # aggregation without), by what ran: the one native call that
+        # holds the GIL, or the numpy chain it falls back to
         self.finalize_native = 0
         self.finalize_numpy = 0
         # look-ups of the runners' cached device scalars

@@ -4,9 +4,10 @@ The reference's hot loops live in C++/Rust (RocksDB iterators, the row
 codec, tidb_query's decode paths); here two of them are one CPython
 extension (fastbuild.cpp): the data-loader — the MVCC→columnar builder
 feeding both the host pipeline and the TPU device feed — and, on the
-serving path, the hash aggregation's host finalize, which turns the
-fetched Pallas accumulator into result planes in one call that holds
-the GIL throughout (``hash_finalize_packed``).
+serving path, an aggregation's host finalize, which turns the fetched
+Pallas accumulator (a GROUP BY's grid, or the one slot of an
+aggregation without) into result planes in one call that holds the GIL
+throughout (``hash_finalize_packed``).
 
 The build is hermetic and optional: g++ compiles the module into
 ``_build/`` keyed by source hash (one compile per source change, ~2s);
@@ -74,8 +75,9 @@ build_mvcc_sst = getattr(_mod, "build_mvcc_sst", None)
 # with a spare core on the build path, where yielding on a single-CPU
 # box just hands the core to background tick threads)
 mvcc_parse_planes = getattr(_mod, "mvcc_parse_planes", None)
-# hash-agg host finalize: fetched (2, HI, W) int32 accumulator parts →
-# key / value / validity planes in caller-made buffers, GIL held from
-# entry to return (device/aggregate.py finalize_packed, which keeps the
-# numpy chain as the fallback and the tests' oracle)
+# an aggregation's host finalize: fetched (2, HI, W) int32 accumulator
+# parts → key / value / validity planes in caller-made buffers (no key
+# planes: the one row of an aggregation without GROUP BY), GIL held
+# from entry to return (device/aggregate.py finalize_packed, which
+# keeps the numpy chain as the fallback and the tests' oracle)
 hash_finalize_packed = getattr(_mod, "hash_finalize_packed", None)

@@ -1263,6 +1263,11 @@ PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
  *    in ascending slot order; slot ``capacity`` is the NULL key, last
  *  - COUNT always valid; SUM valid where ok > 0, else 0; AVG
  *    double(sum) / double(count) where count > 0, else 0.0
+ *  - no key planes (None, None): an aggregation without GROUP BY.  Its
+ *    grid is ONE slot, and slot 0 is a row whether or not a row reached
+ *    it: COUNT 0, SUM and AVG NULL over nothing, by the same arithmetic
+ *    (ops/agg.py finalize_simple); ``capacity``, ``base`` and
+ *    ``slot_keys`` are not read, the outputs hold one entry
  */
 
 /* every buffer the call holds, released on any way out */
@@ -1319,7 +1324,14 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
                     "hash_finalize_packed: LO, p8 > 0 and capacity >= 0");
     return nullptr;
   }
-  const Py_ssize_t n_out = capacity + 1;    /* + the NULL slot */
+  const bool keyed = keys_o != Py_None;
+  if (!keyed && key_valid_o != Py_None) {
+    PyErr_SetString(PyExc_TypeError,
+                    "hash_finalize_packed: key validity without a key plane");
+    return nullptr;
+  }
+  /* + the NULL slot; one row where there is no key */
+  const Py_ssize_t n_out = keyed ? capacity + 1 : 1;
   const Py_ssize_t W = p8 * LO;
   Views views;
 
@@ -1355,25 +1367,31 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
   /* sparse recode: per-slot key values, int64 */
   const int64_t* slot_keys = nullptr;
   Py_ssize_t n_keys = 0;
-  if (slot_keys_o != Py_None) {
+  if (keyed && slot_keys_o != Py_None) {
     Py_buffer* b = views.get(slot_keys_o, false, 8, "lq", "slot_keys");
     if (!b) return nullptr;
     slot_keys = static_cast<const int64_t*>(b->buf);
     n_keys = b->len / 8;
   }
 
-  /* outputs: capacity + 1 entries each */
-  Py_buffer* kb = views.get(keys_o, true, 8, "lq", "the key plane");
-  if (!kb) return nullptr;
-  Py_buffer* kvb = views.get(key_valid_o, true, 1, "?Bb", "the key validity");
-  if (!kvb) return nullptr;
-  if (kb->len < n_out * 8 || kvb->len < n_out) {
-    PyErr_SetString(PyExc_ValueError,
-                    "hash_finalize_packed: key planes shorter than capacity+1");
-    return nullptr;
+  /* outputs: n_out entries each */
+  int64_t* keys = nullptr;
+  uint8_t* key_valid = nullptr;
+  if (keyed) {
+    Py_buffer* kb = views.get(keys_o, true, 8, "lq", "the key plane");
+    if (!kb) return nullptr;
+    Py_buffer* kvb = views.get(key_valid_o, true, 1, "?Bb",
+                               "the key validity");
+    if (!kvb) return nullptr;
+    if (kb->len < n_out * 8 || kvb->len < n_out) {
+      PyErr_SetString(
+          PyExc_ValueError,
+          "hash_finalize_packed: key planes shorter than capacity+1");
+      return nullptr;
+    }
+    keys = static_cast<int64_t*>(kb->buf);
+    key_valid = static_cast<uint8_t*>(kvb->buf);
   }
-  int64_t* keys = static_cast<int64_t*>(kb->buf);
-  uint8_t* key_valid = static_cast<uint8_t*>(kvb->buf);
 
   /* layouts: per spec (kind, ok_plane, nb, nb byte-plane indices) */
   Py_buffer* db = views.get(desc_o, false, 8, "lq", "the layout description");
@@ -1433,7 +1451,7 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
     if (!ob) return nullptr;
     if (vb->len < n_out * 8 || ob->len < n_out) {
       PyErr_SetString(PyExc_ValueError,
-                      "hash_finalize_packed: output shorter than capacity+1");
+                      "hash_finalize_packed: output shorter than its rows");
       return nullptr;
     }
     sp.values = vb->buf;
@@ -1447,7 +1465,13 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
 
   /* the one pass.  Arithmetic is unsigned where numpy's int64 wraps. */
   Py_ssize_t k = 0;
-  const Py_ssize_t last = capacity < have ? capacity : have - 1;
+  const Py_ssize_t top = keyed ? capacity : 0;     /* the last slot read */
+  const Py_ssize_t last = top < have ? top : have - 1;
+  if (!keyed && have < 1) {
+    PyErr_SetString(PyExc_ValueError,
+                    "hash_finalize_packed: a grid without its one slot");
+    return nullptr;
+  }
   for (Py_ssize_t s = 0; s <= last; s++) {
     const Py_ssize_t cell = (s / LO) * W + s % LO;
     auto plane = [&](int64_t p) -> uint64_t {
@@ -1459,8 +1483,11 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
       return v;
     };
     const int64_t mask_count = (int64_t)plane(0);
-    if (mask_count <= 0) continue;
-    if (s == capacity) {
+    if (!keyed) {
+      /* no GROUP BY: the one row, present or not */
+    } else if (mask_count <= 0) {
+      continue;
+    } else if (s == capacity) {
       keys[k] = 0;
       key_valid[k] = 0;
     } else {
@@ -1522,8 +1549,9 @@ PyMethodDef methods[] = {
     {"hash_finalize_packed", hash_finalize_packed, METH_VARARGS,
      "Fetched Pallas hash-agg accumulator -> result planes, in one call\n"
      "that holds the GIL throughout: (parts, LO, p8, capacity, base,\n"
-     "slot_keys | None, layout_desc, keys_out, key_valid_out,\n"
-     "[(values_out, validity_out), ...]) -> group count"},
+     "slot_keys | None, layout_desc, keys_out | None, key_valid_out |\n"
+     "None, [(values_out, validity_out), ...]) -> row count; no key\n"
+     "planes: a grid of one slot, always one row"},
     {nullptr, nullptr, 0, nullptr}};
 
 PyModuleDef moddef = {PyModuleDef_HEAD_INIT, "_fastbuild",
