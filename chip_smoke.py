@@ -303,7 +303,8 @@ class ServedLeg:
         log(ln)
         m = re.fullmatch(r"device runner: platform=(\S+) "
                          r"device_kind='(.*)' n_devices=(\d+) mesh=(\S+) "
-                         r"native_finalize=(yes|no)", ln.strip())
+                         r"native_finalize=(yes|no) "
+                         r"gil_probe=(native|overshoot)", ln.strip())
         if m is None:
             raise SmokeFailure(f"platform check: cannot parse {ln!r}")
         # the store says itself whether its hash-agg finalize is the one

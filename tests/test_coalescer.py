@@ -1179,3 +1179,162 @@ def test_a_memo_is_shared_by_constants_and_split_by_the_key(runner):
             BatchExecutorsRunner(d, snap).handle_request().rows())
     metas = [k for k in runner._arena.bucket(snap) if k[0] == "meta"]
     assert len(metas) == 2
+
+
+# ------------------------------------- a group's phases never outgrow its wall
+
+
+class SlowStaging:
+    """A stub in front of a runner: every staging holds one phase for
+    ``ms`` before the real launch, long against everything else a toy
+    request does."""
+
+    def __init__(self, runner, ms):
+        self._runner, self._s = runner, ms / 1e3
+
+    def __getattr__(self, name):
+        return getattr(self._runner, name)
+
+    def handle_request(self, dag, storage, **kw):
+        import time
+
+        from tikv_tpu.utils import tracker
+        with tracker.phase("feed_upload"):
+            time.sleep(self._s)
+        return self._runner.handle_request(dag, storage, **kw)
+
+
+@pytest.fixture(scope="module")
+def group_of_three(runner):
+    """Three identical aggregations as ONE share group over a staging of
+    40 ms → {"leader": its tracker, "member": another's}."""
+    from tikv_tpu.utils import tracker
+    table, snap = make_snapshot(seed=11)
+    ep, coal = make_endpoint(SlowStaging(runner, 40.0), snap, max_group=3,
+                             window_ms=2000.0)
+    trackers = [None] * 3
+
+    def traced(i):
+        tr, tok = tracker.install(sampled=True)
+        trackers[i] = tr
+        try:
+            got = ep.handle(CopRequest(REQ_TYPE_DAG, agg_dag(table)))
+            assert got.backend == "device"
+        finally:
+            tr.finish()
+            tracker.uninstall(tok)
+
+    try:
+        ep.handle(CopRequest(REQ_TYPE_DAG, agg_dag(table)))     # warm
+        before = coal.stats()["groups_dispatched"]
+        ts = [threading.Thread(target=traced, args=(i,)) for i in range(3)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join()
+        assert coal.stats()["groups_dispatched"] == before + 1
+    finally:
+        ep.close()
+    led = [any(s.name == "group_dispatch" and not s.links for s in tr.spans)
+           for tr in trackers]
+    assert sorted(led) == [False, False, True], led
+    return {"leader": trackers[led.index(True)],
+            "member": trackers[led.index(False)]}
+
+
+@pytest.mark.parametrize("who", ["leader", "member"])
+def test_no_members_phases_sum_past_its_wall(group_of_three, who):
+    """ROADMAP D12: the leader's tracker is adopted over the shared
+    staging, so its phases hold the staging as the work it was; its
+    coalesce_wait therefore ends where the staging began (it used to run
+    to the staging's end: the staging twice).  Every other member waited
+    for the staging, and its coalesce_wait says so."""
+    from tikv_tpu.utils.trace_vocab import OUTSIDE_ROOT
+    tr, led = group_of_three[who], who == "leader"
+    in_root = {k: v for k, v in tr.phases.items() if k not in OUTSIDE_ROOT}
+    assert sum(in_root.values()) <= tr.total_ns(), (in_root, tr.total_ns())
+    wait_ms = tr.phases["coalesce_wait"] / 1e6
+    by = {s.name: s for s in tr.spans}
+    if led:
+        assert tr.phases["feed_upload"] >= 40e6
+        # window + dispatcher queue, exactly: its span-only children
+        kids = sum(by[n].t1 - by[n].t0 for n in
+                   ("coalesce_window", "dispatch_queue_wait"))
+        assert tr.phases["coalesce_wait"] == kids
+        assert by["coalesce_wait"].t1 <= by["feed_upload"].t0
+    else:
+        assert "feed_upload" not in tr.phases
+        assert wait_ms >= 40.0          # it waited for the staging
+    # the span sits where the wait was, its children inside it
+    cw = by["coalesce_wait"]
+    assert cw.t0 <= by["coalesce_window"].t0 and \
+        by["dispatch_queue_wait"].t1 <= cw.t1
+
+
+# ------------------------------------------------- the dispatch lock's wait
+
+
+def test_dispatch_lock_wait_is_a_phase_of_a_contended_launch(runner):
+    """A request that finds the runner's dispatch lock held says how
+    long it waited for it, on its own launch path."""
+    import time
+
+    from tikv_tpu.utils import trace as trace_mod
+    from tikv_tpu.utils import tracker
+    table, snap = make_snapshot(seed=12)
+    dag = agg_dag(table)
+    runner.handle_request(dag, snap)        # warm
+    row0 = trace_mod.AGGREGATE.snapshot()["dispatch_lock_wait"]
+    out = {}
+
+    def launch():
+        tr, tok = tracker.install()
+        try:
+            runner.handle_request(dag, snap)
+        finally:
+            tr.finish()
+            tracker.uninstall(tok)
+        out["tr"] = tr
+
+    runner._dispatch_mu.acquire()
+    try:
+        t = threading.Thread(target=launch)
+        t.start()
+        time.sleep(0.3)     # long against its way to the lock, loaded or not
+    finally:
+        runner._dispatch_mu.release()
+    t.join(timeout=60)
+    tr = out["tr"]
+    assert 100.0 <= tr.time_detail()["phases_ms"]["dispatch_lock_wait"]
+    assert tr.phases["dispatch_lock_wait"] + tr.phases["device_dispatch"] \
+        <= tr.total_ns()
+    row = trace_mod.AGGREGATE.snapshot()["dispatch_lock_wait"]
+    assert row["count"] == row0["count"] + 1
+    assert row["wall_ms"] >= row0["wall_ms"] + 100.0
+    assert "dispatch_lock_wait" not in trace_mod.ANNOTATED      # a wait
+
+
+def test_a_lane_launch_records_no_dispatch_lock_wait(lane_runner):
+    """``handle_lanes`` stages its lanes under one hold of the lock it
+    took itself: a lane's launch path takes none and records none."""
+    from tikv_tpu.utils import tracker
+    k = 2
+    rig = LaneRig(lane_runner, [lane_snapshot(s) for s in range(k)])
+    try:
+        rig.warm()
+        rig.together([lane_dag(i) for i in range(k)], k)
+        rig.wait_built()
+        tr, tok = tracker.install()
+        try:
+            outcomes = lane_runner.handle_lanes(
+                [(lane_dag(i), rig.snaps[i]) for i in range(k)])
+            assert all(d is not None for d in outcomes)
+            for d in outcomes:
+                d.result()
+        finally:
+            tr.finish()
+            tracker.uninstall(tok)
+        assert "device_dispatch" in tr.phases
+        assert "dispatch_lock_wait" not in tr.phases
+    finally:
+        rig.close()

@@ -529,12 +529,15 @@ def test_a_store_without_the_size_estimate_fails_at_once(store, table_kind,
 # ------------------------------------------------- loadgen.py, as run.py runs it
 
 
-def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
+@pytest.fixture(scope="module")
+def loadgen_result(store, tmp_path_factory):
     """``benchmark/loadgen.py`` itself, as a child with the ``warm`` /
     ``go`` / ``done`` hand-shake of ``run.py``, over the cell's own
     traffic file and its configuration (the table's id apart): the table
     kind's load, the first read, the probes (each fetches its trace),
-    the warm rounds, a window of one second, the check."""
+    the warm rounds, a window of one second, the check.  → (the ``warm``
+    line, the result file)."""
+    tmp_path = tmp_path_factory.mktemp("loadgen")
     config = load_config()
     config["table"]["table_id"] = TABLE_IDS["loadgen"]
     config_file = tmp_path / "config.json"
@@ -558,7 +561,6 @@ def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
             first = child.stdout.readline()
             assert first.startswith("warm "), (first, child.poll())
             warm = json.loads(first[len("warm "):])
-            assert warm["failed"] == 0, warm
             child.stdin.write("go\n")
             child.stdin.flush()
             assert child.stdout.readline().strip() == "done"
@@ -571,7 +573,12 @@ def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
             child.wait()
         child.stdin.close()
         child.stdout.close()
-    result = json.loads(out.read_text())
+    return warm, json.loads(out.read_text())
+
+
+def test_loadgen_child_runs_the_cell_end_to_end(loadgen_result):
+    warm, result = loadgen_result
+    assert warm["failed"] == 0, warm
     assert result["warm_failed"] == 0
     assert result["checks"] == [["hash_agg.wrong_answers", 0, 0],
                                 ["regions.reads_off_the_layout", 0, 0]]
@@ -586,6 +593,75 @@ def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
     assert tasks >= N * len(result["records"])
     assert end["flight_recorder"]["launches"] > \
         go["flight_recorder"]["launches"]
+
+
+# --------------------------- the metrics that wait for their entries (PR 36)
+#
+# A counter and the metric that reads it cannot land in one PR: line.py
+# refuses a traced line that lacks a declared metric, and the driver
+# makes the traced run on the parent too, whose program has no such
+# counter (PERF.md section 7, row 1a).  So a PR that brings a source
+# brings the metric's file complete, with its manifest entry under
+# ``pending_entry``; a later benchmark PR renames the key to
+# ``per_layer_entry`` (which tests/test_benchmark_manifest.py holds
+# equal to the manifest) and adds the entry.
+
+
+def pending_metrics() -> dict:
+    import glob
+    out = {}
+    for path in sorted(glob.glob(os.path.join(BENCH, "layer_metrics",
+                                              "*.json"))):
+        with open(path) as f:
+            spec = json.load(f)
+        if "pending_entry" in spec:
+            out[os.path.basename(path)[:-len(".json")]] = spec
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(pending_metrics()))
+def test_a_pending_metric_reads_the_loadgen_childs_result(loadgen_result,
+                                                          name):
+    """Its reader, over ``data`` as ``run.py`` builds it from the load
+    generator's result file, finds its source in this program and gives
+    a finite number; its entry is ready for the manifest and not in it."""
+    import math
+    _warm, result = loadgen_result
+    spec = pending_metrics()[name]
+    assert set(spec) == {"what", "reader", "args", "pending_entry"}
+    with open(os.path.join(BENCH, "traffic", f"{CELL}.json")) as f:
+        traffic = json.load(f)
+    data = {"reads": [r for r in result["records"] if r["ok"]],
+            "counters_go": result["counters_go"],
+            "counters_end": result["counters_end"], "trace": None,
+            "traffic": traffic, "rows": ROWS, "peaks": None,
+            "stats": {"loadgen_cpu_share": result["loadgen_cpu_share"]},
+            "setup": {"load_s": result["load_s"],
+                      "first_read_s": result["first_read_s"]}}
+    got = byname.load("readers", spec["reader"]).read(data, spec["args"])
+    if name == "mesh.dispatch_lock_wait_ms" and got is None:
+        # "did not occur": off a mesh only a launch the coalescer did
+        # not stage takes the lock on a request's path
+        for path in spec["args"].values():
+            assert isinstance(path, str) and \
+                path.startswith("health.tracing.phases.dispatch_lock_wait.")
+        assert "dispatch_lock_wait" in \
+            result["counters_end"]["health"]["tracing"]["phases"]
+    else:
+        assert isinstance(got, (int, float)) and math.isfinite(got) and \
+            got >= 0, (name, got)
+    entry = spec["pending_entry"]
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"} and entry["name"] == name
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    assert set(entry["workloads"]) <= {w["name"]
+                                       for w in manifest["workloads"]}
+    reported = {m["name"]: m for m in manifest["end_to_end"]}
+    assert entry["moves"] in reported
+    assert entry["better"] in ("lower", "higher")
+    assert entry["source"] in ("program_span", "program_counter")
+    assert name not in {m["name"] for m in manifest["per_layer"]}
 
 
 # ------------------------------------------- a read's tasks as lanes

@@ -543,7 +543,8 @@ def test_span_vocabulary_inventory():
     root = pathlib.Path(tikv_tpu.__file__).parent
     pat = re.compile(
         r'(?:\bphase|\badd_phase|\bspan|\badd_span|\btimed'
-        r'|\bbegin|\blink_from|_new_span|AGGREGATE\.add|_annotation)'
+        r'|\bbegin|\blink_from|_new_span|AGGREGATE\.add|_annotation'
+        r'|\bclient_phase)'
         r'\(\s*\n?\s*"([a-z0-9_]+)"')
     used = set()
     for p in root.rglob("*.py"):
@@ -1040,7 +1041,12 @@ def test_e2e_rpc_envelope_outside_the_root_span(rig):
     total = resp["time_detail"]["total_rpc_wall_ms"]
     assert total == doc["time_detail"]["total_rpc_wall_ms"]
     assert root["dur_us"] / 1e3 == pytest.approx(total, abs=0.001)
-    assert "rpc_accept_wait" not in resp["time_detail"]["phases_ms"]
+    # the store's own time_detail never holds it as a phase; the reply's
+    # does (PR 36), put there by the CLIENT from the reply's clock_ns,
+    # beside its own wire phases
+    assert "rpc_accept_wait" not in doc["time_detail"]["phases_ms"]
+    assert resp["time_detail"]["phases_ms"]["rpc_accept_wait"] == \
+        pytest.approx(root["attrs"]["rpc_accept_wait_us"] / 1e3, abs=0.002)
     assert "rpc_reply" not in resp["time_detail"]["phases_ms"]
     assert not {"rpc_accept_wait", "rpc_reply"} & \
         {s["name"] for s in doc["spans"]}
@@ -1095,3 +1101,227 @@ def test_e2e_served_call_annotations(rig, recorded_annotations):
     emitted = {n for n, _k in recorded_annotations}
     assert "copr:dispatcher_idle" in emitted
     assert emitted <= {f"copr:{n}" for n in trace_mod.ANNOTATED}
+
+
+# --------------------------------- an RPC's path across the wire (PR 36)
+
+CLIENT_PHASES = ("client_route", "client_encode", "wire_request",
+                 "wire_reply", "client_decode")
+
+
+def _left_over(td, wall_ms):
+    """The caller's wall around the call minus the seven parts of the
+    reply's path, from its own time_detail.  By construction six of them
+    are ``decoded - call`` (held here to the rounding of seven numbers),
+    whatever the box is doing."""
+    p, ck = td["phases_ms"], td["clock_ns"]
+    parts = [p[n] for n in CLIENT_PHASES] + \
+        [p["rpc_accept_wait"], td["total_rpc_wall_ms"]]
+    assert all(v >= 0 for v in parts), p
+    assert sum(parts) - p["client_route"] == pytest.approx(
+        (ck["decoded"] - ck["call"]) / 1e6, abs=0.005), (p, ck)
+    return wall_ms - sum(parts)
+
+
+@pytest.mark.parametrize("leg", ["full_decode", "fastpath"])
+def test_e2e_a_reads_path_across_the_wire_adds_up(rig, leg):
+    """Client and store read one clock (CLOCK_MONOTONIC): the client's
+    four stamps around the store's three cut the caller's wall into
+    named parts with nothing left over, on both serving legs."""
+    from tikv_tpu.utils.trace_vocab import CLIENT_CLOCK, OUTSIDE_ROOT
+    c = rig["client"]
+    for _ in range(3):      # the shape's template is learnt, then hit
+        c.coprocessor(_agg_dag(rig, c.tso()), timeout=120)
+    if leg == "full_decode":
+        failpoint.cfg("copr::fastpath", "return(miss)")
+    left = []
+    for _ in range(3):      # full-suite load can preempt the way back
+        dag = _agg_dag(rig, c.tso())
+        t0 = time.perf_counter_ns()
+        resp = c.coprocessor(dag, timeout=60)
+        wall_ms = (time.perf_counter_ns() - t0) / 1e6
+        td = resp["time_detail"]
+        left.append(_left_over(td, wall_ms))
+        if left[-1] <= 0.2:
+            break
+    assert -0.01 <= min(left) <= 0.2, left
+    assert (td["labels"].get("fastpath") == "hit") == (leg == "fastpath"), \
+        td["labels"]
+    assert "wire_clock" not in td["labels"]
+    assert set(CLIENT_PHASES) <= set(td["phases_ms"]) <= \
+        set(SPAN_VOCABULARY)
+    assert set(CLIENT_PHASES) <= CLIENT_CLOCK < OUTSIDE_ROOT
+    # the reply's seven stamps, in order, on the one clock
+    ck = td["clock_ns"]
+    order = [ck[k] for k in ("call", "sent", "accept", "t0", "t1",
+                             "bytes_in", "decoded")]
+    assert order == sorted(order) and t0 <= order[0], ck
+    assert (ck["t1"] - ck["t0"]) / 1e6 == pytest.approx(
+        td["total_rpc_wall_ms"], abs=0.001)
+    # what lies inside the root span still fits in it
+    assert sum(v for k, v in td["phases_ms"].items()
+               if k not in OUTSIDE_ROOT) <= td["total_rpc_wall_ms"] + 0.01
+
+
+def test_e2e_a_fanout_read_carries_its_critical_tasks_path(rig):
+    c = rig["client"]
+    try:
+        c.coprocessor_fanout(_agg_dag(rig, c.tso()), timeout=120)
+        t0 = time.perf_counter_ns()
+        resp = c.coprocessor_fanout(_agg_dag(rig, c.tso()), timeout=60)
+        wall_ms = (time.perf_counter_ns() - t0) / 1e6
+    finally:
+        c.close()       # its fan-out workers (the client stays usable)
+    td = resp["time_detail"]
+    crit, = [r for r in resp["responses"]
+             if r["trace_id"] == resp["trace_id"]]
+    for name in CLIENT_PHASES + ("rpc_accept_wait",):
+        assert td["phases_ms"][name] == \
+            crit["time_detail"]["phases_ms"][name]
+    assert td["clock_ns"] == crit["time_detail"]["clock_ns"]
+    # client_route runs from the fan-out's entry (the cut in it), so
+    # the seven cover the read up to the critical reply's decode: what
+    # is left is the hand-back to the caller and the summary
+    assert td["phases_ms"]["client_route"] >= td["phases_ms"]["fanout_cut"]
+    assert -0.01 <= _left_over(td, wall_ms) <= 50.0, (td, wall_ms)
+
+
+@pytest.mark.parametrize("how", ["no_stamps", "an_hour_off"])
+def test_e2e_an_unshared_clock_is_labelled_never_guessed(rig, monkeypatch,
+                                                         how):
+    """A store that sends no clock_ns (an old one), or one whose clock
+    is not the client's (another host): no wire phase, no negative
+    number, the label wire_clock=unshared."""
+    real = Tracker.time_detail
+
+    def other_store(self):
+        d = real(self)
+        if how == "no_stamps":
+            d.pop("clock_ns", None)
+        elif "clock_ns" in d:
+            d["clock_ns"] = {k: v + 3_600_000_000_000
+                             for k, v in d["clock_ns"].items()}
+        return d
+
+    c = rig["client"]
+    c.coprocessor(_agg_dag(rig, c.tso()), timeout=120)
+    monkeypatch.setattr(Tracker, "time_detail", other_store)
+    resp = c.coprocessor(_agg_dag(rig, c.tso()), timeout=60)
+    td = resp["time_detail"]
+    assert td["labels"]["wire_clock"] == "unshared"
+    assert not set(CLIENT_PHASES + ("rpc_accept_wait",)) & \
+        set(td["phases_ms"]), td["phases_ms"]
+    assert "call" not in td.get("clock_ns", {})
+    assert all(v >= 0 for v in td["phases_ms"].values())
+
+
+# ------------------------------------------------- the GIL probe (PR 36)
+
+
+def _spin(stop):
+    while not stop.is_set():
+        sum(range(200))
+
+
+@pytest.mark.parametrize("mode", ["native", "overshoot"])
+def test_gil_wait_rises_when_python_threads_spin(mode):
+    if mode == "native" and trace_mod.gil_mode() != "native":
+        pytest.skip("the extension has no gil_probe here")
+
+    def mean_us(n=25):
+        return sum(trace_mod.gil_sample(2_000_000, mode)
+                   for _ in range(n)) / n / 1e3
+
+    idle = mean_us()
+    stop = threading.Event()
+    spinners = [threading.Thread(target=_spin, args=(stop,))
+                for _ in range(2)]
+    for t in spinners:
+        t.start()
+    try:
+        busy = mean_us()
+    finally:
+        stop.set()
+        for t in spinners:
+            t.join(timeout=30)
+    # a thread that wants the GIL from a spinning holder gets it at the
+    # switch interval (5 ms), twice over with two of them in turn
+    assert busy > idle + 1_000.0, (idle, busy)
+
+
+def test_e2e_health_gil_block_only_rises_one_probe_a_process(rig):
+    t0 = time.monotonic()
+    h0 = _health_tracing(rig)
+    a = h0["gil"]
+    assert set(a) == {"mode", "hz", "samples", "wait_ms_sum",
+                      "wait_ms_max", "hist"}
+    assert a["mode"] == trace_mod.gil_mode() and a["hz"] == 20
+    assert list(a["hist"]) == ["le_100us", "le_500us", "le_1ms", "le_2ms",
+                               "le_5ms", "le_10ms", "gt_10ms"]
+    time.sleep(0.3)
+    # another node of the process asks for the probe again: still one
+    trace_mod.watch_gil()
+    trace_mod.watch_gil()
+    h1 = _health_tracing(rig)
+    b = h1["gil"]
+    # 20 a second, never a burst to catch up
+    assert 3 <= b["samples"] - a["samples"] <= \
+        (time.monotonic() - t0) * 20 + 2
+    assert b["wait_ms_sum"] >= a["wait_ms_sum"] and \
+        b["wait_ms_max"] >= a["wait_ms_max"]
+    assert all(b["hist"][k] >= a["hist"][k] for k in a["hist"])
+    assert sum(b["hist"].values()) == b["samples"]
+    probes = [t for t in threading.enumerate() if t.name == "gil-probe"]
+    assert len(probes) == 1 and probes[0].daemon
+    # each sample is also one occurrence of the aggregate's row
+    rise = h1["phases"]["gil_wait"]["count"] - \
+        h0["phases"]["gil_wait"]["count"]
+    assert abs(rise - (b["samples"] - a["samples"])) <= 1
+
+
+def test_e2e_health_threads_by_role(rig):
+    """Python's CPU by thread role: found by name, only rising, and
+    inside the process's own CPU clock."""
+    c = rig["client"]
+    c.coprocessor(_agg_dag(rig, c.tso()), timeout=120)
+    a = _health_tracing(rig)
+    for _ in range(5):
+        c.coprocessor(_agg_dag(rig, c.tso()), timeout=60)
+    b = _health_tracing(rig)
+    ta, tb = a["threads"], b["threads"]
+    assert tb["source"] in ("thread_cpuclock", "proc_stat")
+    assert set(tb["roles"]) == {
+        "grpc_serve", "rpc_handler", "copr-coalescer", "copr-dispatcher",
+        "copr-completion", "status-server", "gil-probe", "other"}
+    for role in ("grpc_serve", "rpc_handler", "copr-dispatcher",
+                 "copr-completion", "status-server", "gil-probe", "other"):
+        assert tb["roles"][role]["threads"] >= 1, (role, tb["roles"])
+    for role, row in ta["roles"].items():
+        assert tb["roles"][role]["cpu_ms"] >= row["cpu_ms"], role
+    assert tb["roles"]["rpc_handler"]["cpu_ms"] > 0
+    assert tb["python_cpu_ms"] == pytest.approx(
+        sum(r["cpu_ms"] for r in tb["roles"].values()), abs=0.01)
+    assert tb["python_cpu_ms"] >= ta["python_cpu_ms"]
+    tick_ms = 10.0
+    assert tb["python_cpu_ms"] <= b["process"]["cpu_ms"] + tick_ms
+    assert tb["python_cpu_ms"] + tb["native_cpu_ms"] == pytest.approx(
+        b["process"]["cpu_ms"], abs=0.002)
+
+
+def test_thread_cpu_keeps_what_an_ended_thread_had():
+    tc = trace_mod._ThreadCpu()
+    base = tc.snapshot()["roles"]["rpc_handler"]
+    seen = []
+
+    def burn():
+        _burn(20_000_000)
+        seen.append(tc.snapshot()["roles"]["rpc_handler"])
+
+    t = threading.Thread(target=burn, name="rpc-handler_9")
+    t.start()
+    t.join(timeout=30)
+    assert seen[0]["threads"] == base["threads"] + 1
+    assert seen[0]["cpu_ms"] >= base["cpu_ms"] + 19.0
+    after = tc.snapshot()["roles"]["rpc_handler"]
+    assert after["threads"] == base["threads"]
+    assert after["cpu_ms"] >= seen[0]["cpu_ms"]     # a role only rises

@@ -2633,6 +2633,22 @@ class DeviceRunner:
     # -- dispatch span + flight-recorder feed --
 
     @contextmanager
+    def _dispatch_locked(self):
+        """The dispatch lock on a request's launch path, its acquisition
+        timed: phase ``dispatch_lock_wait`` (a wait; once a launch, 0
+        where nobody held it).  A lane launch takes no lock and records
+        none."""
+        from ..utils import tracker
+        t0_ns = time.perf_counter_ns()
+        self._dispatch_mu.acquire()
+        try:
+            tracker.add_phase("dispatch_lock_wait",
+                              time.perf_counter_ns() - t0_ns)
+            yield
+        finally:
+            self._dispatch_mu.release()
+
+    @contextmanager
     def _dispatch_phase(self, klass: str, key=None, params: int = 0,
                         slot_mode: str = ""):
         """Every kernel launch site runs under this: the
@@ -3083,7 +3099,7 @@ class DeviceRunner:
             # scans re-sort, desc scans reverse)
             positional = isinstance(plan.scan, TableScanDesc) and \
                 not getattr(plan.scan, "desc", False)
-            with nullcontext() if _lanes else self._dispatch_mu:
+            with nullcontext() if _lanes else self._dispatch_locked():
                 if not self._single:
                     # one shard's enqueue failing (device loss, ICI
                     # fault) surfaces as a whole-launch failure mid-

@@ -81,3 +81,7 @@ mvcc_parse_planes = getattr(_mod, "mvcc_parse_planes", None)
 # from entry to return (device/aggregate.py finalize_packed, which
 # keeps the numpy chain as the fallback and the tests' oracle)
 hash_finalize_packed = getattr(_mod, "hash_finalize_packed", None)
+# the GIL probe's one sample: sleep with the GIL released, stamp the
+# wake-up, retake the GIL, stamp again (utils/trace.py watch_gil, which
+# falls back to time.sleep's lateness and says so: mode=overshoot)
+gil_probe = getattr(_mod, "gil_probe", None)

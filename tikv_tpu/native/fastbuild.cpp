@@ -29,6 +29,7 @@
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <ctime>
 #include <deque>
 #include <string>
 #include <vector>
@@ -1532,6 +1533,28 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
   return PyLong_FromSsize_t(k);
 }
 
+/* gil_probe(sleep_ns) -> (woke_ns, held_ns), both CLOCK_MONOTONIC (the
+ * clock of time.perf_counter_ns): sleep without the GIL, stamp the
+ * wake-up, take the GIL back, stamp again.  held - woke is what a
+ * thread returning from any blocking call waits before it runs Python
+ * again (utils/trace.py watch_gil). */
+PyObject* gil_probe(PyObject*, PyObject* args) {
+  long long sleep_ns;
+  if (!PyArg_ParseTuple(args, "L", &sleep_ns)) return nullptr;
+  if (sleep_ns < 0) sleep_ns = 0;
+  struct timespec req = {(time_t)(sleep_ns / 1000000000LL),
+                         (long)(sleep_ns % 1000000000LL)};
+  struct timespec woke, held;
+  Py_BEGIN_ALLOW_THREADS
+  nanosleep(&req, nullptr);
+  clock_gettime(CLOCK_MONOTONIC, &woke);
+  Py_END_ALLOW_THREADS
+  clock_gettime(CLOCK_MONOTONIC, &held);
+  return Py_BuildValue(
+      "LL", (long long)woke.tv_sec * 1000000000LL + woke.tv_nsec,
+      (long long)held.tv_sec * 1000000000LL + held.tv_nsec);
+}
+
 PyMethodDef methods[] = {
     {"mvcc_build_columnar", mvcc_build, METH_VARARGS,
      "One-pass MVCC resolve + row decode into columnar buffers.\n"
@@ -1552,6 +1575,9 @@ PyMethodDef methods[] = {
      "slot_keys | None, layout_desc, keys_out | None, key_valid_out |\n"
      "None, [(values_out, validity_out), ...]) -> row count; no key\n"
      "planes: a grid of one slot, always one row"},
+    {"gil_probe", gil_probe, METH_VARARGS,
+     "(sleep_ns) -> (woke_ns, held_ns) on CLOCK_MONOTONIC: sleep with\n"
+     "the GIL released, stamp the wake-up, retake the GIL, stamp again"},
     {nullptr, nullptr, 0, nullptr}};
 
 PyModuleDef moddef = {PyModuleDef_HEAD_INIT, "_fastbuild",

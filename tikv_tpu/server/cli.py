@@ -152,13 +152,16 @@ def main(argv=None) -> int:
             import os
 
             import jax
+
+            from ..utils.trace import gil_mode
             ms = device_runner.mesh_stats()
             print(f"device runner: platform={ms['platform']} "
                   f"device_kind={ms['device_kind']!r} "
                   f"n_devices={len(jax.devices())} "
                   f"mesh={'x'.join(str(v) for v in ms['shape'].values())} "
                   f"native_finalize="
-                  f"{'yes' if ms['finalize']['native_available'] else 'no'}",
+                  f"{'yes' if ms['finalize']['native_available'] else 'no'}"
+                  f" gil_probe={gil_mode()}",
                   flush=True)
             if ms["platform"] == "cpu" and "cpu" not in os.environ.get(
                     "JAX_PLATFORMS", "").split(","):

@@ -16,6 +16,7 @@ from typing import Optional, Sequence
 import grpc
 
 from ..raftstore.metapb import Peer, Region
+from ..utils.trace import client_phase
 from . import wire
 from .pd_server import RemotePdClient
 
@@ -29,18 +30,80 @@ class StoreClient:
         self._chan = make_channel(addr)
 
     def call(self, method: str, req: dict, timeout: float = 10) -> dict:
+        """One unary RPC.  A reply that carries a ``time_detail`` leaves
+        with the RPC's path across the wire in it (:func:`_note_wire`):
+        gRPC runs both serializers on the calling thread, so four
+        stamps around them and the store's three make one timeline."""
+        t_call = time.perf_counter_ns()
+        at = [0, 0, 0]      # sent, bytes_in, decoded
+
+        def pack(obj):
+            raw = wire.pack(obj)
+            at[0] = time.perf_counter_ns()
+            return raw
+
+        def unpack(raw):
+            at[1] = time.perf_counter_ns()
+            obj = wire.unpack(raw)
+            at[2] = time.perf_counter_ns()
+            return obj
+
         fn = self._chan.unary_unary(
-            "/tikv.Tikv/" + method, request_serializer=wire.pack,
-            response_deserializer=wire.unpack)
+            "/tikv.Tikv/" + method, request_serializer=pack,
+            response_deserializer=unpack)
         resp = fn(req, timeout=timeout)
         if resp.get("error"):
             raise wire.RemoteError(resp["error"])
+        td = resp.get("time_detail")
+        if isinstance(td, dict):
+            _note_wire(td, t_call, *at)
         return resp
 
     def __getattr__(self, name: str):
         if name.startswith("_"):
             raise AttributeError(name)
         return lambda req=None, **kw: self.call(name, req or kw)
+
+
+def _note_wire(td: dict, call: int, sent: int, bytes_in: int,
+               decoded: int) -> None:
+    """Place the client's four ``perf_counter_ns`` stamps around the
+    three the store put into the reply (``time_detail.clock_ns``:
+    ``accept``, ``t0``, ``t1``).  Client and store on one machine read
+    one clock (CLOCK_MONOTONIC); then ``phases_ms`` gains
+    ``client_encode``, ``wire_request``, ``rpc_accept_wait`` (the
+    store's own, per reply), ``wire_reply`` and ``client_decode``, which
+    with ``total_rpc_wall_ms`` add up to ``decoded - call`` to the
+    nanosecond, and ``clock_ns`` gains the client's stamps.  Where the
+    seven are not in order (another host's clock, a store that sends
+    none) nothing is guessed: the label ``wire_clock=unshared``."""
+    ck = td.get("clock_ns")
+    try:
+        shared = sent <= ck["accept"] <= ck["t0"] <= ck["t1"] <= bytes_in
+    except (TypeError, KeyError):
+        shared = False
+    if not shared:
+        td.setdefault("labels", {})["wire_clock"] = "unshared"
+        return
+    phases = td.setdefault("phases_ms", {})
+    client_phase("client_encode", sent - call, phases)
+    client_phase("wire_request", ck["accept"] - sent, phases)
+    client_phase("rpc_accept_wait", ck["t0"] - ck["accept"], phases)
+    client_phase("wire_reply", bytes_in - ck["t1"], phases)
+    client_phase("client_decode", decoded - bytes_in, phases)
+    ck.update(call=call, sent=sent, bytes_in=bytes_in, decoded=decoded)
+
+
+def _note_route(resp: dict, entry_ns: int) -> None:
+    """``client_route``: the caller's entry → the entry of the
+    ``StoreClient.call`` that brought ``resp`` (its ``clock_ns.call``):
+    plan encode, region lookup, breaker, leader choice, and whatever
+    was retried before.  Only beside the wire phases."""
+    td = resp.get("time_detail")
+    ck = td.get("clock_ns") if isinstance(td, dict) else None
+    if isinstance(ck, dict) and "call" in ck:
+        client_phase("client_route", ck["call"] - entry_ns,
+                     td["phases_ms"])
 
 
 class BatchCommandsClient:
@@ -644,6 +707,7 @@ class TxnClient:
                     timeout: float = 10,
                     deadline_ms: Optional[int] = None,
                     trace_id: Optional[str] = None) -> dict:
+        t_entry = time.perf_counter_ns()
         key = key_hint if key_hint is not None else \
             (dag.ranges[0].start if dag.ranges else b"")
         req = {
@@ -668,9 +732,12 @@ class TxnClient:
             # now a WARM one: a follower replica answering from its
             # own device feed (paged requests carry resume state and
             # stay leader-only)
-            return self._hedged_coprocessor(key, req, timeout)
-        return self._call_leader(key, "Coprocessor", req,
-                                 timeout=timeout)
+            resp = self._hedged_coprocessor(key, req, timeout)
+        else:
+            resp = self._call_leader(key, "Coprocessor", req,
+                                     timeout=timeout)
+        _note_route(resp, t_entry)
+        return resp
 
     def _hedged_coprocessor(self, key: bytes, req: dict,
                             timeout: float) -> dict:
@@ -767,10 +834,17 @@ class TxnClient:
         trace buffer keeps one tracker an id), with any task's
         ``host_exec`` and the client's own phases added: ``fanout_cut``,
         ``fanout_tasks``, ``fanout_straggler``, ``fanout_task``
-        (utils/trace_vocab.py)."""
+        (utils/trace_vocab.py).  The critical task's path across the
+        wire rides along (``StoreClient.call``: ``client_encode``,
+        ``wire_request``, ``rpc_accept_wait``, ``wire_reply``,
+        ``client_decode``, and its ``clock_ns``); its ``client_route``
+        runs from THIS call's entry to the task's send, so that the
+        seven, with ``total_rpc_wall_ms``, cover the read up to the
+        critical reply's decode."""
         import dataclasses
         import statistics
         from ..utils import tracker
+        t_entry = time.perf_counter_ns()
         # everything of the request but a task's ranges and region,
         # encoded once (wire.enc_dag's keys, in coprocessor()'s order)
         env = {"tp": 103,
@@ -784,7 +858,8 @@ class TxnClient:
             with tracker.phase("fanout_cut"):
                 tasks = self._cut_by_region(dag.ranges)
             pool = self._fanout_executor(concurrency)
-            futs = [pool.submit(self._run_cop_task, task, env, timeout)
+            futs = [pool.submit(self._run_cop_task, task, env, timeout,
+                                t_entry)
                     for task in tasks]
             try:
                 ran = [f.result() for f in futs]
@@ -875,10 +950,13 @@ class TxnClient:
         return [(region, leader, tuple(pieces))
                 for region, leader, pieces in tasks]
 
-    def _run_cop_task(self, task, env: dict, timeout: float) -> tuple:
+    def _run_cop_task(self, task, env: dict, timeout: float,
+                      t_entry: int) -> tuple:
         """One cop task to its region's leader → ([(reply, sent_ns,
         back_ns)], times it was cut again): more than one reply where
-        the region had changed under the task."""
+        the region had changed under the task.  ``t_entry``: when the
+        fan-out was entered, where each reply's ``client_route``
+        starts."""
         from ..utils.backoff import Backoff
         from ..utils.failpoint import fail_point
         from ..utils.health import CircuitOpen
@@ -909,6 +987,7 @@ class TxnClient:
                 last = e
             else:
                 out.append((resp, sent, time.perf_counter_ns()))
+                _note_route(resp, t_entry)
                 continue
             recuts += 1
             with self._route_mu:

@@ -855,7 +855,9 @@ class RequestCoalescer:
         from ..utils import tracker
         # the group's dispatch work (feed lookup, kernel cache, launch)
         # is attributed to the LEADER's TimeDetail — one member carries
-        # the shared cost's phases; every member still records its own
+        # the shared cost's phases, and ITS coalesce_wait ends where
+        # this staging began (_complete), so that its phases do not
+        # hold the staging twice; every member still records its own
         # coalesce_wait and resolution phases.  The explicit
         # group_dispatch span wraps the shared launch on the leader's
         # trace and is follows-from linked into every OTHER member's
@@ -957,8 +959,9 @@ class RequestCoalescer:
             # the launch is in the leader's trace as the phase it was
             # (device_dispatch, under group_dispatch); every other
             # member gets it as a span of its own trace
+            leads = m.tracker is lead_tr
             self._complete(m, resolve, t_begin_ns, t_staged_ns,
-                           None if m.tracker is lead_tr else info)
+                           None if leads else info, leads=leads)
         if solo:
             self._solo_fallback(solo, t_begin_ns)
 
@@ -1056,19 +1059,26 @@ class RequestCoalescer:
             self._dispatch(inline)
 
     def _complete(self, m: _Member, resolve, t_begin_ns: int,
-                  t_staged_ns: int, launch: Optional[dict] = None
-                  ) -> None:
+                  t_staged_ns: int, launch: Optional[dict] = None,
+                  leads: bool = False) -> None:
         """Hand the member's resolution (shared fetch join + its own
         host gather) to the completion pool; its result lands on the
         member's future for CopDeferred.wait().  ``launch``: the record
         of the launch that serves it (``DeferredResult.launch_info``),
-        for a member whose own trace does not hold that launch."""
+        for a member whose own trace does not hold that launch.
+        ``leads``: the staging ran under this member's tracker, which
+        holds its phases (device_dispatch, a cold build's, ...)."""
         from ..resource_metering import GLOBAL_RECORDER, region_of
         from ..utils import tracker
         # a member's coalesce_wait splits at these two instants: submit
         # → closed is the collection window, closed → begin the wait
-        # for this (one) dispatcher, the rest the shared staging
+        # for this (one) dispatcher, the rest the shared staging: a
+        # wait for every member but the leader, whose coalesce_wait
+        # ends where the staging began (what follows is in its phases
+        # as the work it was: no request's phases hold an interval
+        # twice)
         t_closed_ns = m.t_closed_ns or t_begin_ns
+        t_waited_ns = t_begin_ns if leads else t_staged_ns
 
         def task():
             tok = tracker.adopt(m.tracker) if m.tracker is not None \
@@ -1080,7 +1090,8 @@ class RequestCoalescer:
                 # collection window, then the wait for the dispatcher
                 # (what is left is the shared staging)
                 sp = tracker.add_phase("coalesce_wait",
-                                       t_staged_ns - m.t_submit_ns)
+                                       t_waited_ns - m.t_submit_ns,
+                                       t_waited_ns)
                 tracker.add_span("coalesce_window", m.t_submit_ns,
                                  t_closed_ns, sp)
                 tracker.add_span("dispatch_queue_wait", t_closed_ns,

@@ -557,10 +557,11 @@ class Node:
         # causal request tracing (utils/trace.py): per-node retention
         # buffer behind /debug/trace — tail-biased (slowest per class +
         # every errored/late/shed/degraded request pinned past the ring)
-        from ..utils.trace import TraceBuffer, watch_gc
+        from ..utils.trace import TraceBuffer, watch_gc, watch_gil
         self.trace_buffer = TraceBuffer(
             capacity=config.coprocessor.trace_buffer)
         watch_gc()      # gc_pause in /health tracing.phases
+        watch_gil()     # gil_wait there, and /health tracing.gil
         # compiled request fast path (server/fastpath.py): per-class
         # wire templates learned from slow-path requests; repeat-shape
         # requests skip msgpack/DAG decode and jump to the coalescer.

@@ -120,7 +120,9 @@ class TikvServer:
         # workers — grpc's stop() alone leaves them parked on the work
         # queue until the executor is garbage collected, which leaks a
         # thread per in-process server cycle (chaos restarts, tests)
-        self._pool = _HandlerPool(max_workers=max_workers)
+        # (named for /health tracing.threads: role rpc_handler)
+        self._pool = _HandlerPool(max_workers=max_workers,
+                                  thread_name_prefix="rpc-handler")
         self._server = grpc.server(self._pool)
         self._server.add_generic_rpc_handlers((
             _GenericHandler(

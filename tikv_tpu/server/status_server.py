@@ -226,7 +226,11 @@ class StatusServer:
                         # into per-request means and window shares
                         tracing["phases"] = \
                             trace_mod.AGGREGATE.snapshot()
-                        tracing["process"] = trace_mod.process_clock()
+                        # the GIL probe's counters, and Python's CPU
+                        # by thread role beside the process's clocks
+                        tracing["gil"] = trace_mod.GIL.snapshot()
+                        tracing["threads"], tracing["process"] = \
+                            trace_mod.thread_cpu()
                         body["tracing"] = tracing
                     # device-aware RU metering rollup: live knobs +
                     # cost-model weights (all online-updatable), tag

@@ -46,6 +46,7 @@ the buffer) as Chrome trace-event JSON that loads in Perfetto.
 
 from __future__ import annotations
 
+import bisect
 import contextvars
 import gc
 import os
@@ -119,7 +120,8 @@ class Tracker:
     """
 
     __slots__ = ("trace_id", "sampled", "cpu_all", "t0", "wall_t0",
-                 "t1", "wait_ns", "phases", "scan_rows", "scan_bytes",
+                 "t1", "accept_ns", "wait_ns", "phases", "scan_rows",
+                 "scan_bytes",
                  "labels", "_mu", "_next_id", "spans", "root",
                  "meter_ctx", "ru")
 
@@ -133,6 +135,7 @@ class Tracker:
         self.t0 = time.perf_counter_ns()
         self.wall_t0 = time.time()
         self.t1: Optional[int] = None       # set by finish()
+        self.accept_ns: Optional[int] = None    # set by note_accept()
         self.wait_ns = 0            # read-pool queue/slot wait
         self.phases: dict[str, int] = {}    # name -> ns (wire shape)
         self.scan_rows = 0          # processed versions / rows
@@ -259,6 +262,13 @@ class Tracker:
         }
         if self.labels:
             d["labels"] = dict(self.labels)
+        if self.accept_ns is not None and self.t1 is not None:
+            # absolute perf_counter_ns stamps (CLOCK_MONOTONIC: one
+            # clock for every process of a machine): a client on this
+            # machine places its own send and receive around them
+            # (server/client.py StoreClient.call)
+            d["clock_ns"] = {"accept": self.accept_ns, "t0": self.t0,
+                             "t1": self.t1}
         return d
 
     def scan_detail(self) -> dict:
@@ -447,6 +457,228 @@ def watch_gc() -> None:
         gc.callbacks.append(_on_gc)
 
 
+# ------------------------------------------------------ the GIL probe
+
+GIL_HZ = 20
+# fixed bucket edges: a mode at 5 ms is CPython's switch interval
+_GIL_EDGES_NS = (100_000, 500_000, 1_000_000, 2_000_000, 5_000_000,
+                 10_000_000)
+_GIL_BUCKETS = ("le_100us", "le_500us", "le_1ms", "le_2ms", "le_5ms",
+                "le_10ms", "gt_10ms")
+
+
+_native_probe = False       # not looked for yet
+
+
+def _native_gil_probe():
+    # looked for on first use: importing the extension may compile it
+    global _native_probe
+    if _native_probe is False:
+        from ..native import gil_probe
+        _native_probe = gil_probe
+    return _native_probe
+
+
+def gil_mode() -> str:
+    """``native``: the extension's helper stamps both sides of the GIL's
+    return; ``overshoot``: ``time.sleep``'s lateness stands in (the
+    timer's own lateness is in it too)."""
+    return "native" if _native_gil_probe() is not None else "overshoot"
+
+
+def gil_sample(sleep_ns: int, mode: Optional[str] = None) -> int:
+    """One sample → ns this thread waited for the GIL after a sleep of
+    ``sleep_ns`` without it: what a thread that returns from any
+    blocking call (a gRPC poll, a ``PjitFunction``, a condition wait)
+    waits before it runs Python again."""
+    probe = _native_gil_probe() if mode != "overshoot" else None
+    if probe is not None:
+        woke, held = probe(int(sleep_ns))
+        return max(0, held - woke)
+    t0 = time.perf_counter_ns()
+    time.sleep(sleep_ns / 1e9)
+    return max(0, time.perf_counter_ns() - t0 - int(sleep_ns))
+
+
+class _GilStats:
+    """Counters that only add: two snapshots difference."""
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        self.samples = 0
+        self.wait_ns_sum = 0
+        self.wait_ns_max = 0
+        self.hist = [0] * len(_GIL_BUCKETS)
+
+    def add(self, wait_ns: int) -> None:
+        b = bisect.bisect_left(_GIL_EDGES_NS, wait_ns)
+        with self._mu:
+            self.samples += 1
+            self.wait_ns_sum += wait_ns
+            self.wait_ns_max = max(self.wait_ns_max, wait_ns)
+            self.hist[b] += 1
+        AGGREGATE.add("gil_wait", wait_ns)
+
+    def snapshot(self) -> dict:
+        with self._mu:
+            return {"mode": gil_mode(), "hz": GIL_HZ,
+                    "samples": self.samples,
+                    "wait_ms_sum": round(self.wait_ns_sum / 1e6, 3),
+                    "wait_ms_max": round(self.wait_ns_max / 1e6, 3),
+                    "hist": dict(zip(_GIL_BUCKETS, self.hist))}
+
+
+GIL = _GilStats()
+_gil_thread: Optional[threading.Thread] = None
+_gil_mu = threading.Lock()
+
+
+def _gil_loop() -> None:
+    period = 1_000_000_000 // GIL_HZ
+    nxt = time.perf_counter_ns()
+    while True:
+        nxt += period
+        pause = nxt - time.perf_counter_ns()
+        if pause <= 0:          # fell behind: no burst to catch up
+            nxt, pause = time.perf_counter_ns() + period, period
+        GIL.add(gil_sample(pause))
+
+
+def watch_gil() -> None:
+    """Start the probe: ONE daemon thread a process (``gil-probe``),
+    :data:`GIL_HZ` samples a second into :data:`GIL` and the aggregate's
+    ``gil_wait`` row.  Idempotent; the node calls it beside
+    :func:`watch_gc`, and it outlives every node of the process."""
+    global _gil_thread
+    with _gil_mu:
+        if _gil_thread is None or not _gil_thread.is_alive():
+            _gil_thread = threading.Thread(
+                target=_gil_loop, daemon=True, name="gil-probe")
+            _gil_thread.start()
+
+
+# ------------------------------------------- Python's CPU by thread role
+
+# (role, test of a thread's name), first match wins; the rest is "other"
+# (the main thread, raft and PD loops, the client's pools)
+_THREAD_ROLES = (
+    ("grpc_serve", lambda n: n.endswith("(_serve)")),
+    ("rpc_handler", lambda n: n.startswith("rpc-handler")),
+    ("copr-coalescer", lambda n: n == "copr-coalescer"),
+    ("copr-dispatcher", lambda n: n == "copr-dispatcher"),
+    ("copr-completion", lambda n: n.startswith("copr-completion")),
+    ("status-server", lambda n: n == "status-server" or
+     n.endswith("(process_request_thread)")),
+    ("gil-probe", lambda n: n == "gil-probe"),
+)
+_IMPORT_THREAD = threading.get_native_id()
+_IMPORT_THREAD_CPU0 = time.thread_time_ns()
+_CLK_TCK = os.sysconf("SC_CLK_TCK") if hasattr(os, "sysconf") else 100
+
+
+def _thread_role(name: str) -> str:
+    for role, test in _THREAD_ROLES:
+        if test(name):
+            return role
+    return "other"
+
+
+def _thread_cpu_ns(t: threading.Thread) -> tuple:
+    """→ (ns of CPU the thread has had, which source said so:
+    ``thread_cpuclock`` or ``proc_stat``), or (None, None) for a thread
+    that is gone."""
+    try:
+        # the clock id glibc's pthread_getcpuclockid returns, made from
+        # the kernel's thread id: time.pthread_getcpuclockid(ident)
+        # dereferences the ident, and CPython warns that an expired one
+        # may segfault; a thread gone here is an EINVAL
+        return int(time.clock_gettime(
+            ((~t.native_id) << 3) | 6) * 1e9), "thread_cpuclock"
+    except (AttributeError, OSError, OverflowError, TypeError):
+        pass
+    try:
+        with open(f"/proc/self/task/{t.native_id}/stat") as f:
+            # utime and stime, the 14th and 15th fields, counted from
+            # behind the command's closing bracket
+            rest = f.read().rsplit(")", 1)[1].split()
+        return (int(rest[11]) + int(rest[12])) * 1_000_000_000 \
+            // _CLK_TCK, "proc_stat"
+    except (OSError, IndexError, ValueError):
+        return None, None
+
+
+class _ThreadCpu:
+    """CPU of the threads Python started, summed by role when asked
+    (``/health``: twice a benchmark window; nothing on the hot path).  A
+    thread that has ended keeps what it was last seen with, so every
+    role only rises."""
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        self._seen: dict = {}       # native_id -> (role, ns)
+        self._retired: dict = {}    # role -> ns of threads that ended
+
+    def snapshot(self) -> dict:
+        source = None
+        with self._mu:
+            live = {}
+            for t in threading.enumerate():
+                key = t.native_id
+                if key is None:         # not started yet
+                    continue
+                ns, src = _thread_cpu_ns(t)
+                if ns is None:
+                    continue
+                source = source or src
+                if key == _IMPORT_THREAD:
+                    ns = max(0, ns - _IMPORT_THREAD_CPU0)
+                old = self._seen.get(key)
+                if old is not None and ns < old[1]:
+                    # the id was another thread's: that one has ended
+                    self._retire(old)
+                live[key] = (_thread_role(t.name), ns)
+            for key, old in self._seen.items():
+                if key not in live:
+                    self._retire(old)
+            self._seen = live
+            roles = {role: {"threads": 0, "cpu_ms": 0}
+                     for role, _test in _THREAD_ROLES}
+            roles["other"] = {"threads": 0, "cpu_ms": 0}
+            for role, ns in self._retired.items():
+                roles[role]["cpu_ms"] += ns
+            for role, ns in live.values():
+                roles[role]["threads"] += 1
+                roles[role]["cpu_ms"] += ns
+        python_ns = sum(r["cpu_ms"] for r in roles.values())
+        for r in roles.values():
+            r["cpu_ms"] = round(r["cpu_ms"] / 1e6, 3)
+        return {"source": source, "roles": roles,
+                "python_cpu_ms": round(python_ns / 1e6, 3)}
+
+    def _retire(self, old: tuple) -> None:
+        role, ns = old
+        self._retired[role] = self._retired.get(role, 0) + ns
+
+
+THREADS = _ThreadCpu()
+
+
+def thread_cpu() -> tuple:
+    """→ (``/health`` ``tracing.threads``, ``tracing.process``): CPU by
+    thread role, their sum ``python_cpu_ms`` (bytecode under the one
+    GIL, at most 1.0 s a second of it, PLUS what those threads run with
+    the GIL released: the gRPC core's work on the calling thread, PjRt's
+    on the launching one, numpy, and the kernel's for their system
+    calls) and ``native_cpu_ms``, the rest of the process's ``cpu_ms``
+    (the threads XLA, PjRt and the gRPC core started themselves), read
+    after the threads so that the two add up to it."""
+    threads = THREADS.snapshot()
+    process = process_clock()
+    threads["native_cpu_ms"] = round(
+        process["cpu_ms"] - threads["python_cpu_ms"], 3)
+    return threads, process
+
+
 # ---------------------------------------------- profiler annotations
 
 # Spans that are WORK on some thread, emitted as ``copr:<name>`` through
@@ -521,6 +753,7 @@ def note_accept(tr: Tracker) -> None:
     if t is None:
         return
     _rpc.submit_ns = None       # a streamed task's later requests: none
+    tr.accept_ns = min(t, tr.t0)
     wait = max(0, tr.t0 - t)
     AGGREGATE.add("rpc_accept_wait", wait)
     tr.annotate_span(tr.root, rpc_accept_wait_us=round(wait / 1e3, 1))
@@ -651,10 +884,12 @@ def span(name: str) -> _Scoped:
     return _Scoped(name, False)
 
 
-def add_phase(name: str, ns: int) -> Optional[Span]:
-    """Retroactive attribution: ``ns`` of wall ENDING NOW (the interval
-    was measured on a thread that had no tracker context).  → the span,
-    for :func:`add_span` children (None when unsampled)."""
+def add_phase(name: str, ns: int,
+              end_ns: Optional[int] = None) -> Optional[Span]:
+    """Retroactive attribution: ``ns`` of wall ending at ``end_ns``
+    (default: now; the interval was measured on a thread that had no
+    tracker context).  → the span, for :func:`add_span` children (None
+    when unsampled)."""
     got = _current.get()
     if got is None:
         return None
@@ -664,10 +899,18 @@ def add_phase(name: str, ns: int) -> Optional[Span]:
     AGGREGATE.add(name, ns)
     if not tr.sampled:
         return None
-    now = time.perf_counter_ns()
-    sp = tr.begin(name, parent, now - ns)
-    tr.end(sp, now)
+    if end_ns is None:
+        end_ns = time.perf_counter_ns()
+    sp = tr.begin(name, parent, end_ns - ns)
+    tr.end(sp, end_ns)
     return sp
+
+
+def client_phase(name: str, ns: int, phases_ms: dict) -> None:
+    """A phase of trace_vocab's ``OUTSIDE_ROOT``, written by the CLIENT
+    into a reply's own ``phases_ms`` (server/client.py): no tracker, no
+    aggregate row of the store's, and outside ``total_rpc_wall_ms``."""
+    phases_ms[name] = round(ns / 1e6, 3)
 
 
 def add_span(name: str, t0_ns: int, t1_ns: int,

@@ -3,7 +3,8 @@
 Every ``tracker.phase(...)`` / ``add_phase(...)`` / ``add_span(...)`` /
 ``timed(...)`` / ``begin(...)`` / ``link_from(...)`` span name used
 anywhere in ``tikv_tpu/`` (and each ``AGGREGATE.add(...)`` row of
-utils/trace.py) MUST appear here (tests/test_trace.py scans the source tree both ways, like the
+utils/trace.py, each ``client_phase(...)`` of server/client.py) MUST
+appear here (tests/test_trace.py scans the source tree both ways, like the
 failpoint inventory): a typo'd phase label fails CI instead of silently
 forking the latency breakdown into two names no dashboard ever joins.
 The descriptions double as the README's span-vocabulary table — keep
@@ -41,8 +42,8 @@ SPAN_VOCABULARY: dict[str, str] = {
     "await_deferred": "service thread parked on the deferred device "
                       "completion (decomposed by completion-side spans)",
     "resp_serialize": "SelectResult rows → wire response encode",
-    # -- client fan-out (server/client.py coprocessor_fanout; on the
-    # client's clock, in the summary's phases_ms) --
+    # -- the client's side (server/client.py; CLIENT_CLOCK below: on the
+    # client's clock, added to the reply's phases_ms by the client) --
     "fanout_cut": "region lookup through the client's region cache and "
                   "the cut of the request's ranges into cop tasks",
     "fanout_tasks": "first cop task sent → last partial reply back",
@@ -50,6 +51,22 @@ SPAN_VOCABULARY: dict[str, str] = {
                         "task's: what the read waited for its slowest "
                         "region",
     "fanout_task": "median over the read's cop tasks of send → reply",
+    "client_route": "TxnClient.coprocessor / coprocessor_fanout entry → "
+                    "StoreClient.call entry: plan encode, region "
+                    "lookup, breaker, leader choice, retries (a cop "
+                    "task's also holds the cut and the worker's start)",
+    "client_encode": "StoreClient.call entry → its request serializer "
+                     "returned (stub construction, wire.pack)",
+    "wire_request": "request serializer returned → the store's handler "
+                    "pool was handed the call (reply's clock_ns.accept): "
+                    "the client's gRPC core, loopback, the server's "
+                    "core, _serve getting the GIL; one shared clock only",
+    "wire_reply": "root span sealed (clock_ns.t1) → the client's "
+                  "response deserializer entered: rpc_reply, gRPC's "
+                  "send, loopback, the calling thread waking and "
+                  "retaking the client's GIL; one shared clock only",
+    "client_decode": "response deserializer entered → returned "
+                     "(wire.unpack of the reply)",
     # -- storage / host pipeline --
     "kv_read": "point/scan MVCC read through Storage",
     "snapshot": "raft lease read + engine snapshot acquisition",
@@ -68,7 +85,9 @@ SPAN_VOCABULARY: dict[str, str] = {
     "completion_queue_wait": "wait for a completion-pool worker slot",
     "coalesce_wait": "coalescer submit → the group's launch staged: "
                      "collection window + wait for the one dispatcher "
-                     "thread + the shared launch staging",
+                     "thread + the shared launch staging (the group's "
+                     "leader: → the staging BEGAN; its own phases hold "
+                     "the staging as the work it was)",
     "coalesce_window": "span-only child of coalesce_wait: submit → the "
                        "member's group closed (the collection window)",
     "dispatch_queue_wait": "span-only child of coalesce_wait: group "
@@ -81,6 +100,9 @@ SPAN_VOCABULARY: dict[str, str] = {
     "group_fetch_wait": "member resolution joining the group's shared "
                         "(memoized) fetch",
     # -- device backend (device/runner.py) --
+    "dispatch_lock_wait": "a request's launch path waiting for the "
+                          "runner's dispatch lock (device/runner.py "
+                          "_dispatch_locked; a lane launch takes none)",
     "device_dispatch": "kernel launch enqueue (flight-recorder attrs)",
     "d2h_wait": "device→host transfer + sync wait",
     "device_wait": "span-only child of d2h_wait: block_until_ready on "
@@ -125,4 +147,21 @@ SPAN_VOCABULARY: dict[str, str] = {
     # -- the process (utils/trace.py) --
     "gc_pause": "one run of Python's cyclic collector, from "
                 "gc.callbacks (aggregate row only: count, wall)",
+    "gil_wait": "one sample of the GIL probe (watch_gil, 20 a second): "
+                "woken from a GIL-free sleep → holding the GIL again "
+                "(aggregate row only: count, wall; /health tracing.gil "
+                "has the histogram)",
 }
+
+# Names a reply's ``phases_ms`` can hold that lie OUTSIDE the store's
+# root span: whoever sums ``phases_ms`` against ``total_rpc_wall_ms``
+# leaves this set out.  The client's nine are on the client's clock;
+# ``rpc_accept_wait`` is the store's (``clock_ns.t0 - clock_ns.accept``),
+# put there by the client beside its own so that one reply adds up:
+# client_route + client_encode + wire_request + rpc_accept_wait +
+# total_rpc_wall_ms + wire_reply + client_decode = the caller's wall.
+CLIENT_CLOCK = frozenset({
+    "fanout_cut", "fanout_tasks", "fanout_straggler", "fanout_task",
+    "client_route", "client_encode", "wire_request", "wire_reply",
+    "client_decode"})
+OUTSIDE_ROOT = CLIENT_CLOCK | {"rpc_accept_wait"}
