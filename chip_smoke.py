@@ -304,6 +304,7 @@ class ServedLeg:
         m = re.fullmatch(r"device runner: platform=(\S+) "
                          r"device_kind='(.*)' n_devices=(\d+) mesh=(\S+) "
                          r"native_finalize=(yes|no) "
+                         r"native_encode=(yes|no) "
                          r"gil_probe=(native|overshoot)", ln.strip())
         if m is None:
             raise SmokeFailure(f"platform check: cannot parse {ln!r}")
@@ -312,6 +313,11 @@ class ServedLeg:
         self.checks.require("store says native_finalize=yes", m[5] == "yes",
                             "the store's extension lacks "
                             "hash_finalize_packed")
+        # ... and whether a fast-path reply's rows are the one native
+        # call's or the Python chain's
+        self.checks.require("store says native_encode=yes", m[6] == "yes",
+                            "the store's extension lacks "
+                            "encode_rows_msgpack")
         dev = {"platform": m[1], "kind": m[2], "count": int(m[3]),
                "mesh": m[4]}
         if dev["platform"] != "tpu" and not self.args.allow_cpu:
@@ -524,6 +530,12 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
                            w["labels"].get("device_feed") == "hit",
                            w["labels"])
             warm.append(w)
+        # the warm GROUP BY replies were fast-path hits over int64
+        # planes: the native call made their rows, the chain none
+        enc = http_json(leg.status_port, "/health")["fastpath"]["encode"]
+        checks.require("hash_agg warm: fast-path replies encoded by the "
+                       "native call", enc["native"] > 0 and
+                       enc["python"] == 0, enc)
 
         # -- simple agg --
         def simple_agg():
@@ -924,10 +936,11 @@ def main() -> int:
                            f"tikv_tpu package beside it — {e}")
     checks = Checks(args.allow_cpu)
     # a store that has silently lost its C++ loader, or the native
-    # hash-agg finalize that shares its extension, is a failure, not a
-    # slow run
+    # hash-agg finalize and reply encode that share its extension, is a
+    # failure, not a slow run
     for fn in ("mvcc_build_columnar", "build_mvcc_sst",
-               "mvcc_parse_planes", "hash_finalize_packed"):
+               "mvcc_parse_planes", "hash_finalize_packed",
+               "encode_rows_msgpack"):
         checks.require(f"native.{fn} built",
                        getattr(native, fn) is not None,
                        "g++ build of native/fastbuild.cpp failed")

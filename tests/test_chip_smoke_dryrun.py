@@ -66,8 +66,10 @@ def test_with_device_store_refuses_an_unasked_for_cpu_fallback():
     # call (no silent fallback to the numpy chain)
     from tikv_tpu import native
     built = "yes" if native.hash_finalize_packed is not None else "no"
+    # ... whether a fast-path reply's rows are the native call's
+    enc = "yes" if native.encode_rows_msgpack is not None else "no"
     # ... and whether the GIL probe samples through the extension
     from tikv_tpu.utils.trace import gil_mode
-    assert f" native_finalize={built} gil_probe={gil_mode()}\n" in \
-        r.stdout, r.stdout
+    assert f" native_finalize={built} native_encode={enc} " \
+        f"gil_probe={gil_mode()}\n" in r.stdout, r.stdout
     assert "found no accelerator" in r.stderr, r.stderr[-2000:]

@@ -187,7 +187,7 @@ def simple_chain(parts, LO, p8, layouts, specs, slots, _base, _capacity,
 def wire_bytes(specs, cols, fracs=(), keyed=True):
     schema = DeviceAggregator._agg_out(plan_of(specs, fracs))[0] + \
         [FieldType.long()] * keyed
-    return fastpath.encode_response(
+    return fastpath.encode_response_python(
         {"backend": "device", "trace_id": "t"},
         SelectResult(ColumnBatch(schema, list(cols)), []))
 

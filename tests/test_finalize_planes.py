@@ -290,7 +290,7 @@ def test_finalize_hash_rejects_first():
 
 def wire_bytes(schema, cols):
     env = {"backend": "device", "trace_id": "t"}
-    return fastpath.encode_response(
+    return fastpath.encode_response_python(
         env, SelectResult(ColumnBatch(list(schema), list(cols)), []))
 
 

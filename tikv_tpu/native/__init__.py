@@ -1,21 +1,24 @@
 """Native (C++) runtime components, compiled on first import.
 
 The reference's hot loops live in C++/Rust (RocksDB iterators, the row
-codec, tidb_query's decode paths); here two of them are one CPython
+codec, tidb_query's decode paths); here three of them are one CPython
 extension (fastbuild.cpp): the data-loader — the MVCC→columnar builder
 feeding both the host pipeline and the TPU device feed — and, on the
 serving path, an aggregation's host finalize, which turns the fetched
 Pallas accumulator (a GROUP BY's grid, or the one slot of an
 aggregation without) into result planes in one call that holds the GIL
-throughout (``hash_finalize_packed``).
+throughout (``hash_finalize_packed``), and a fast-path reply's encode,
+which turns result planes into the msgpack bytes of the rows without a
+Python value a cell (``encode_rows_msgpack``).
 
 The build is hermetic and optional: g++ compiles the module into
 ``_build/`` keyed by source hash (one compile per source change, ~2s);
 any failure leaves every export below ``None`` and callers use their
 interpreted or numpy fallback, so the framework never hard-requires a
 compiler.  No fallback is silent: the cold build labels itself
-``cold_build=native|interpreted``, and the finalize counts itself on
-``/health`` ``device_mesh.finalize``.
+``cold_build=native|interpreted``, the finalize counts itself on
+``/health`` ``device_mesh.finalize`` and the encode on ``/health``
+``fastpath.encode``.
 """
 
 from __future__ import annotations
@@ -81,6 +84,12 @@ mvcc_parse_planes = getattr(_mod, "mvcc_parse_planes", None)
 # from entry to return (device/aggregate.py finalize_packed, which
 # keeps the numpy chain as the fallback and the tests' oracle)
 hash_finalize_packed = getattr(_mod, "hash_finalize_packed", None)
+# a fast-path reply's rows: [(values, validity), ...] planes (int64 /
+# uint64 / float64 beside a bool validity) → the msgpack array of rows,
+# or None where it does not take a plane (server/fastpath.py
+# encode_response, which keeps the Python chain as the fallback and the
+# tests' oracle); GIL held for a small reply, released for a large one
+encode_rows_msgpack = getattr(_mod, "encode_rows_msgpack", None)
 # the GIL probe's one sample: sleep with the GIL released, stamp the
 # wake-up, retake the GIL, stamp again (utils/trace.py watch_gil, which
 # falls back to time.sleep's lateness and says so: mode=overshoot)

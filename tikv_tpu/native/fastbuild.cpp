@@ -958,26 +958,35 @@ inline void put_varu64(std::string* out, uint64_t v) {
   out->push_back((char)v);
 }
 
-/* msgpack minimal int encode — byte-identical to msgpack-python packb */
+/* msgpack minimal int encode — byte-identical to msgpack-python packb.
+ * The one definition of the widths: ``p`` has room for 9 bytes, the
+ * return is one past the last byte written. */
+inline char* mp_write_be(char* p, uint8_t tag, uint64_t v, int nbytes) {
+  *p++ = (char)tag;
+  for (int i = nbytes - 1; i >= 0; i--) *p++ = (char)((v >> (8 * i)) & 0xFF);
+  return p;
+}
+
+inline char* mp_write_uint(char* p, uint64_t u) {
+  if (u <= 0x7F) { *p++ = (char)u; return p; }
+  if (u <= 0xFF) return mp_write_be(p, 0xCC, u, 1);
+  if (u <= 0xFFFF) return mp_write_be(p, 0xCD, u, 2);
+  if (u <= 0xFFFFFFFFULL) return mp_write_be(p, 0xCE, u, 4);
+  return mp_write_be(p, 0xCF, u, 8);
+}
+
+inline char* mp_write_int(char* p, int64_t v) {
+  if (v >= 0) return mp_write_uint(p, (uint64_t)v);
+  if (v >= -32) { *p++ = (char)(int8_t)v; return p; }
+  if (v >= -128) return mp_write_be(p, 0xD0, (uint64_t)v, 1);
+  if (v >= -32768) return mp_write_be(p, 0xD1, (uint64_t)v, 2);
+  if (v >= -2147483648LL) return mp_write_be(p, 0xD2, (uint64_t)v, 4);
+  return mp_write_be(p, 0xD3, (uint64_t)v, 8);
+}
+
 inline void mp_put_int(std::string* out, int64_t v) {
-  if (v >= 0) {
-    uint64_t u = (uint64_t)v;
-    if (u <= 0x7F) { out->push_back((char)u); }
-    else if (u <= 0xFF) { out->push_back((char)0xCC); out->push_back((char)u); }
-    else if (u <= 0xFFFF) { out->push_back((char)0xCD);
-      out->push_back((char)(u >> 8)); out->push_back((char)(u & 0xFF)); }
-    else if (u <= 0xFFFFFFFFULL) { out->push_back((char)0xCE); put_be32(out, (uint32_t)u); }
-    else { out->push_back((char)0xCF); put_be64(out, u); }
-  } else {
-    if (v >= -32) { out->push_back((char)(int8_t)v); }
-    else if (v >= -128) { out->push_back((char)0xD0); out->push_back((char)(int8_t)v); }
-    else if (v >= -32768) { out->push_back((char)0xD1);
-      out->push_back((char)(((uint16_t)(int16_t)v) >> 8));
-      out->push_back((char)(((uint16_t)(int16_t)v) & 0xFF)); }
-    else if (v >= -2147483648LL) { out->push_back((char)0xD2);
-      put_be32(out, (uint32_t)(int32_t)v); }
-    else { out->push_back((char)0xD3); put_be64(out, (uint64_t)v); }
-  }
+  char b[9];
+  out->append(b, mp_write_int(b, v) - b);
 }
 
 inline void mp_put_bin(std::string* out, const uint8_t* p, uint32_t n) {
@@ -1271,6 +1280,15 @@ PyObject* build_mvcc_sst(PyObject*, PyObject* args) {
  *    ``slot_keys`` are not read, the outputs hold one entry
  */
 
+/* the one struct format code of ``b`` if it is in ``codes``, in native
+ * byte order and of ``itemsize`` bytes, else 0 */
+char format_code(const Py_buffer* b, Py_ssize_t itemsize, const char* codes) {
+  const char* f = b->format ? b->format : "B";
+  if (*f == '@' || *f == '=' || *f == '<') f++;
+  if (b->itemsize != itemsize || !*f || f[1] || !strchr(codes, *f)) return 0;
+  return *f;
+}
+
 /* every buffer the call holds, released on any way out */
 struct Views {
   std::deque<Py_buffer> held;
@@ -1289,9 +1307,7 @@ struct Views {
       return nullptr;
     }
     Py_buffer* b = &held.back();
-    const char* f = b->format ? b->format : "B";
-    if (*f == '@' || *f == '=' || *f == '<') f++;
-    if (b->itemsize != itemsize || !*f || f[1] || !strchr(codes, *f)) {
+    if (!format_code(b, itemsize, codes)) {
       PyErr_Format(PyExc_TypeError,
                    "hash_finalize_packed: %s has format %s, itemsize %zd",
                    what, b->format ? b->format : "(none)", b->itemsize);
@@ -1299,6 +1315,30 @@ struct Views {
     }
     return b;
   }
+  /* the same of a read-only 1-D plane, for a caller that DECLINES what
+   * it does not take: the view and its code in ``*code``; nullptr where
+   * the buffer is something else (another format, 2-D, strided) with no
+   * exception set, or where ``o`` gives no buffer at all, with one */
+  const Py_buffer* plane(PyObject* o, Py_ssize_t itemsize, const char* codes,
+                         char* code) {
+    held.emplace_back();
+    if (PyObject_GetBuffer(o, &held.back(), PyBUF_STRIDES | PyBUF_FORMAT) < 0) {
+      held.pop_back();
+      return nullptr;
+    }
+    const Py_buffer* b = &held.back();
+    *code = format_code(b, itemsize, codes);
+    if (!*code || b->ndim != 1 ||
+        (b->shape[0] > 1 && b->strides[0] != itemsize))
+      return nullptr;
+    return b;
+  }
+};
+
+/* a reference the call owns */
+struct Drop {
+  PyObject* o;
+  ~Drop() { Py_XDECREF(o); }
 };
 
 enum FinKind : int64_t { FIN_COUNT_STAR = 0, FIN_COUNT = 1, FIN_SUM = 2,
@@ -1339,10 +1379,7 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
   /* the parts: (2, HI, W) int32 each, one HI for all */
   PyObject* parts = PySequence_Fast(parts_o, "parts not a sequence");
   if (!parts) return nullptr;
-  struct Drop {
-    PyObject* o;
-    ~Drop() { Py_XDECREF(o); }
-  } drop_parts{parts};
+  Drop drop_parts{parts};
   const Py_ssize_t n_parts = PySequence_Fast_GET_SIZE(parts);
   if (n_parts < 1) {
     PyErr_SetString(PyExc_ValueError, "hash_finalize_packed: no parts");
@@ -1533,6 +1570,140 @@ PyObject* hash_finalize_packed(PyObject*, PyObject* args) {
   return PyLong_FromSsize_t(k);
 }
 
+/* ---- a fast-path reply's rows: result planes -> msgpack bytes
+ *
+ * What server/fastpath.py's Python chain does with a ``tolist`` a
+ * column, a ``zip`` to one tuple a row and ``msgpack.Packer.pack``: the
+ * array of rows, each row the array of its cells, byte for byte what
+ * ``msgpack.Packer(use_bin_type=True)`` writes for it.  No Python value
+ * is made for a cell.  Kept in lockstep with that chain
+ * (``encode_response_python``), which stays as the fallback and as the
+ * oracle of tests/test_encode_native.py:
+ *  - array header by length, for the row list and for a row: fixarray
+ *    below 16, array16 up to 65,535, array32 beyond
+ *  - an int64 or uint64 cell in the shortest form (mp_write_int /
+ *    mp_write_uint), a float64 cell as 0xCB + its 8 bytes big-endian
+ *  - a cell whose validity is false as 0xC0, whatever its value slot
+ *    holds
+ * It takes 1-D C-contiguous planes in native byte order: int64, uint64
+ * or float64 values beside a bool validity, one length for all.
+ * Anything else that is a buffer (an object plane, another dtype, a
+ * strided view, lengths that differ, no column at all) it DECLINES by
+ * returning None, and the chain serves the reply.
+ */
+
+/* Cells (rows x columns) above which the loop runs with the GIL
+ * released.  Held, the loop keeps the ~20 other threads of a serving
+ * store off the GIL for its length; released, this thread queues
+ * behind them to take it back: 1.45-2.04 ms in the mean on a loaded
+ * store (PERF.md section 5, gil.wait_ms, PR 36; PR 26 found the same
+ * of numpy's inner loops).  So letting go pays only where the loop is
+ * longer than that wait.  The loop runs at 7.1-8.0 ns a cell of random
+ * int64 on the chip machine's host (PERF.md section 6, PR 37: 1,024 x
+ * 3 fresh planes in 23 us, 65,536 x 3 in 1.39-1.50 ms): 200,000 cells
+ * are ~1.5 ms.  A GROUP BY reply of 1,024 groups x 3 holds; a
+ * selection's 209,380 rows x 3 (4.5-8.8 ms) lets go. */
+constexpr uint64_t kEncodeReleaseCells = 200000;
+
+enum EncKind : int { ENC_I64, ENC_U64, ENC_F64 };
+
+struct EncCol {
+  EncKind kind;
+  const char* values;
+  const uint8_t* validity;
+};
+
+inline char* mp_write_array_header(char* p, uint64_t n) {
+  if (n < 16) { *p++ = (char)(0x90 | n); return p; }
+  if (n <= 0xFFFF) return mp_write_be(p, 0xDC, n, 2);
+  return mp_write_be(p, 0xDD, n, 4);
+}
+
+PyObject* encode_rows_msgpack(PyObject*, PyObject* arg) {
+  Drop seq{PySequence_Fast(arg, "columns not a sequence")};
+  if (!seq.o) return nullptr;
+  const Py_ssize_t n_cols = PySequence_Fast_GET_SIZE(seq.o);
+  if (n_cols < 1) Py_RETURN_NONE;
+  Views views;
+  std::vector<EncCol> cols(n_cols);
+  Py_ssize_t n_rows = -1;
+  for (Py_ssize_t c = 0; c < n_cols; c++) {
+    PyObject* pair = PySequence_Fast_GET_ITEM(seq.o, c);
+    if (!PyTuple_Check(pair) || PyTuple_GET_SIZE(pair) != 2) {
+      PyErr_SetString(PyExc_TypeError,
+                      "encode_rows_msgpack: a column is (values, validity)");
+      return nullptr;
+    }
+    char code, bool_code;
+    const Py_buffer* vals =
+        views.plane(PyTuple_GET_ITEM(pair, 0), 8, "lqLQd", &code);
+    const Py_buffer* valid =
+        vals ? views.plane(PyTuple_GET_ITEM(pair, 1), 1, "?", &bool_code)
+             : nullptr;
+    if (!valid || vals->shape[0] != valid->shape[0] ||
+        (n_rows >= 0 && vals->shape[0] != n_rows)) {
+      if (PyErr_Occurred()) return nullptr;
+      Py_RETURN_NONE;
+    }
+    n_rows = vals->shape[0];
+    cols[c].kind = code == 'd' ? ENC_F64
+                   : (code == 'L' || code == 'Q') ? ENC_U64 : ENC_I64;
+    cols[c].values = static_cast<const char*>(vals->buf);
+    cols[c].validity = static_cast<const uint8_t*>(valid->buf);
+  }
+
+  /* the most the rows can take: a 5-byte header, and a row its header
+   * and 9 bytes a cell */
+  char row_header[5];
+  const Py_ssize_t row_header_len =
+      mp_write_array_header(row_header, (uint64_t)n_cols) - row_header;
+  const uint64_t row_most = (uint64_t)row_header_len + 9 * (uint64_t)n_cols;
+  if ((uint64_t)n_rows > 0xFFFFFFFFULL || (uint64_t)n_cols > 0xFFFFFFFFULL ||
+      (n_rows > 0 &&
+       row_most > ((uint64_t)PY_SSIZE_T_MAX - 5) / (uint64_t)n_rows)) {
+    PyErr_SetString(PyExc_OverflowError,
+                    "encode_rows_msgpack: more rows or columns than a "
+                    "msgpack array holds");
+    return nullptr;
+  }
+  PyObject* out = PyBytes_FromStringAndSize(
+      nullptr, (Py_ssize_t)(5 + row_most * (uint64_t)n_rows));
+  if (!out) return nullptr;
+  char* const start = PyBytes_AS_STRING(out);
+  char* p = mp_write_array_header(start, (uint64_t)n_rows);
+
+  /* the planes are held by their views and ``out`` is this call's
+   * alone: the loop touches no Python object */
+  PyThreadState* released =
+      (uint64_t)n_rows * (uint64_t)n_cols > kEncodeReleaseCells
+          ? PyEval_SaveThread() : nullptr;
+  for (Py_ssize_t i = 0; i < n_rows; i++) {
+    memcpy(p, row_header, row_header_len);
+    p += row_header_len;
+    for (const EncCol& col : cols) {
+      if (!col.validity[i]) {
+        *p++ = (char)0xC0;
+        continue;
+      }
+      const char* cell = col.values + 8 * i;
+      if (col.kind == ENC_I64) {
+        int64_t v;
+        memcpy(&v, cell, 8);
+        p = mp_write_int(p, v);
+      } else {
+        /* a float64 goes as its bits (NaN payloads and -0.0 too) */
+        uint64_t u;
+        memcpy(&u, cell, 8);
+        p = col.kind == ENC_U64 ? mp_write_uint(p, u)
+                                : mp_write_be(p, 0xCB, u, 8);
+      }
+    }
+  }
+  if (released) PyEval_RestoreThread(released);
+  if (_PyBytes_Resize(&out, p - start) < 0) return nullptr;
+  return out;
+}
+
 /* gil_probe(sleep_ns) -> (woke_ns, held_ns), both CLOCK_MONOTONIC (the
  * clock of time.perf_counter_ns): sleep without the GIL, stamp the
  * wake-up, take the GIL back, stamp again.  held - woke is what a
@@ -1575,6 +1746,12 @@ PyMethodDef methods[] = {
      "slot_keys | None, layout_desc, keys_out | None, key_valid_out |\n"
      "None, [(values_out, validity_out), ...]) -> row count; no key\n"
      "planes: a grid of one slot, always one row"},
+    {"encode_rows_msgpack", encode_rows_msgpack, METH_O,
+     "A reply's rows as msgpack bytes, in one call that makes no Python\n"
+     "value for a cell: ([(values, validity), ...]) -> bytes, the array\n"
+     "of rows as msgpack.Packer(use_bin_type=True) writes it; None where\n"
+     "a plane is not 1-D contiguous int64 / uint64 / float64 beside a\n"
+     "bool validity of one length (the caller's Python chain serves)"},
     {"gil_probe", gil_probe, METH_VARARGS,
      "(sleep_ns) -> (woke_ns, held_ns) on CLOCK_MONOTONIC: sleep with\n"
      "the GIL released, stamp the wake-up, retake the GIL, stamp again"},

@@ -153,6 +153,7 @@ def main(argv=None) -> int:
 
             import jax
 
+            from .. import native
             from ..utils.trace import gil_mode
             ms = device_runner.mesh_stats()
             print(f"device runner: platform={ms['platform']} "
@@ -161,6 +162,8 @@ def main(argv=None) -> int:
                   f"mesh={'x'.join(str(v) for v in ms['shape'].values())} "
                   f"native_finalize="
                   f"{'yes' if ms['finalize']['native_available'] else 'no'}"
+                  f" native_encode="
+                  f"{'no' if native.encode_rows_msgpack is None else 'yes'}"
                   f" gil_probe={gil_mode()}",
                   flush=True)
             if ms["platform"] == "cpu" and "cpu" not in os.environ.get(

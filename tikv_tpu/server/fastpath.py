@@ -78,6 +78,7 @@ from collections import OrderedDict
 from decimal import Decimal
 from typing import Callable, Optional
 
+from .. import native
 from ..datatype import const_bucket
 from ..utils.failpoint import fail_point
 from ..utils.metrics import COPR_FASTPATH_COUNTER
@@ -146,6 +147,14 @@ def _pack_int(v: int, out: bytearray) -> None:
             out += b"\xd2" + v.to_bytes(4, "big", signed=True)
         else:
             out += b"\xd3" + v.to_bytes(8, "big", signed=True)
+
+
+def _map_header(n: int) -> bytes:
+    if n < 16:
+        return bytes((0x80 | n,))
+    if n <= 0xFFFF:
+        return b"\xde" + n.to_bytes(2, "big")
+    return b"\xdf" + n.to_bytes(4, "big")
 
 
 def _pack_scalar(v, out: bytearray) -> None:
@@ -234,13 +243,7 @@ def _encode_segments(obj) -> tuple:
             for x in o:
                 walk(x)
         elif isinstance(o, dict):
-            n = len(o)
-            if n < 16:
-                cur.append(0x80 | n)
-            elif n <= 0xFFFF:
-                cur += b"\xde" + n.to_bytes(2, "big")
-            else:
-                cur += b"\xdf" + n.to_bytes(4, "big")
+            cur += _map_header(len(o))
             for k, v in o.items():
                 walk(k)
                 walk(v)
@@ -714,6 +717,10 @@ class FastPathCache:
         self.fallback = 0
         self.learned = 0
         self.reasons: dict = {}
+        # replies encoded (encode_response), by what made the rows: the
+        # one native call over the result's planes, or the Python chain
+        self.encode_native = 0
+        self.encode_python = 0
 
     @property
     def enabled(self) -> bool:
@@ -1050,6 +1057,13 @@ class FastPathCache:
         ent.hits += 1
         self._note("hit", "ok")
 
+    def note_encode(self, native: bool) -> None:
+        with self._mu:
+            if native:
+                self.encode_native += 1
+            else:
+                self.encode_python += 1
+
     def configure(self, capacity: Optional[int] = None) -> None:
         with self._mu:
             if capacity is not None:
@@ -1076,6 +1090,11 @@ class FastPathCache:
                 "hit_rate": round(self.hit / total, 4) if total else 0.0,
                 "config_gen": self.config_gen,
                 "reasons": dict(self.reasons),
+                "encode": {
+                    "native": self.encode_native,
+                    "python": self.encode_python,
+                    "native_available":
+                        native.encode_rows_msgpack is not None},
             }
 
 
@@ -1101,30 +1120,68 @@ def _column_list(c) -> list:
     return vals
 
 
-def encode_response(env: dict, result) -> bytes:
-    """Streaming response encode for a fast-path hit: result planes →
+def encode_response_python(env: dict, result) -> bytes:
+    """The Python chain of a fast-path hit's encode: result planes →
     wire bytes through ONE thread-local ``msgpack.Packer`` whose
     internal buffer is reused across requests (``autoreset=False`` —
     the preallocated response body), with rows materialized by
     columnar ``tolist`` + ``zip`` instead of the slow path's
     ``enc_rows`` row-list walk.  Byte-compatible with the slow leg:
     msgpack encodes the zipped tuples exactly as ``enc_rows``'s
-    lists, and the field order matches ``_enc_cop_resp`` + the seal."""
-    import msgpack
-
-    from ..codec.row import msgpack_default
-    p = getattr(_PACKER_LOCAL, "p", None)
-    if p is None:
-        p = _PACKER_LOCAL.p = msgpack.Packer(
-            use_bin_type=True, default=msgpack_default, autoreset=False)
+    lists, and the field order matches ``_enc_cop_resp`` + the seal.
+    It serves what the native call declines, and it is the oracle
+    tests/test_encode_native.py holds that call's bytes to."""
     batch = result.batch
     rows = list(zip(*[_column_list(c) for c in batch.columns])) \
         if batch.num_rows else []
+    return _pack({"rows": rows, **env})
+
+
+def _pack(obj) -> bytes:
+    p = getattr(_PACKER_LOCAL, "p", None)
+    if p is None:
+        import msgpack
+
+        from ..codec.row import msgpack_default
+        p = _PACKER_LOCAL.p = msgpack.Packer(
+            use_bin_type=True, default=msgpack_default, autoreset=False)
     try:
-        p.pack({"rows": rows, **env})
+        p.pack(obj)
         return p.bytes()
     finally:
         p.reset()
+
+
+def _rows_native(batch) -> Optional[bytes]:
+    """The msgpack array of ``batch``'s rows from ONE native call over
+    its planes (native/fastbuild.cpp ``encode_rows_msgpack``: no Python
+    value is made for a cell), or None where that call does not take
+    them and the Python chain has to serve: an object plane (BYTES,
+    JSON, a DECIMAL's ``Decimal``s), another dtype, a strided view,
+    lengths that differ, the extension absent.  What the code can see
+    in the planes decides, never a plan's name."""
+    if native.encode_rows_msgpack is None:
+        return None
+    return native.encode_rows_msgpack(
+        [(c.values, c.validity) for c in batch.columns])
+
+
+def encode_response(env: dict, result,
+                    fp: Optional["FastPathCache"] = None) -> bytes:
+    """A fast-path hit's response: ``{"rows": rows, **env}`` as wire
+    bytes.  The rows come from the native call where it takes the
+    result's planes, spliced between the map header + ``"rows"`` key
+    and ``env``'s packed items (the same field order, the same bytes);
+    from ``encode_response_python`` where it declines.  ``fp`` counts
+    which (``/health`` ``fastpath.encode``)."""
+    rows = _rows_native(result.batch)
+    if fp is not None:
+        fp.note_encode(rows is not None)
+    if rows is None:
+        return encode_response_python(env, result)
+    # env alone packs as its own map: its items follow that header
+    tail = memoryview(_pack(env))[len(_map_header(len(env))):]
+    return b"".join((_map_header(len(env) + 1), b"\xa4rows", rows, tail))
 
 
 def _const_at(dag_dict: dict, index: int):

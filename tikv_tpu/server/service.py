@@ -221,7 +221,7 @@ class KvService:
         if result is None:
             return env      # error response: dict, server packs it
         from .fastpath import encode_response
-        return encode_response(env, result)
+        return encode_response(env, result, fp)
 
     def _fastpath_dispatch(self, fp, ent, storage, consts,
                            start_ts: int, deadline_ms):

@@ -22,7 +22,9 @@ SPAN_VOCABULARY: dict[str, str] = {
                        "message receive, wait for the GIL); aggregate "
                        "row + root-span attribute rpc_accept_wait_us",
     "rpc_reply": "after the root span: trace sealed → response "
-                 "serializer returned (seal tail, encode_response, "
+                 "serializer returned (seal tail, encode_response: a "
+                 "fast-path hit's rows in one native call over the "
+                 "result's planes + env's pack, or the Python chain, "
                  "gRPC's hand-off, wire pack); aggregate row only",
     "untracked": "synthesized residual: root wall no child span covers",
     "admission": "umbrella: deadline/resource gating + class keying",
