@@ -252,6 +252,60 @@ def ref_hash_agg(c0, c1) -> list:
                   for g in range(GROUPS) if cnt[g])
 
 
+# TPC-H Q1's shape (benchmark/requests/tpch_q1.py) on a table of its
+# seven columns: a GROUP BY over two CHAR(1) keys, DECIMAL products of
+# scales 4 and 6 (the second one past int32: summed as limbs), a date
+# predicate.  Days are 1..28 of June 1995..1998; the cut is 1998-06-15.
+Q1_ROWS = 1 << 18
+Q1_FLAGS, Q1_STATUS = (b"R", b"A", b"N"), (b"O", b"F")
+Q1_CUT = (1998, 6, 15)
+
+
+def q1_table():
+    from tikv_tpu.datatype import FieldType, FieldTypeFlag, FieldTypeTp
+    from tikv_tpu.testing.fixture import Table, TableColumn
+    nn = FieldTypeFlag.NOT_NULL
+    dec = FieldType(tp=FieldTypeTp.NEW_DECIMAL, flag=nn, flen=15, decimal=2)
+    ch1 = FieldType(tp=FieldTypeTp.STRING, flag=nn, flen=1)
+    return Table(TABLE_ID + 2, (
+        TableColumn("id", 1, FieldType.long(not_null=True),
+                    is_pk_handle=True),
+        TableColumn("qty", 2, dec), TableColumn("price", 3, dec),
+        TableColumn("disc", 4, dec), TableColumn("tax", 5, dec),
+        TableColumn("flag", 6, ch1), TableColumn("status", 7, ch1),
+        TableColumn("ship", 8, FieldType(tp=FieldTypeTp.DATE, flag=nn))))
+
+
+def q1_data(seed: int, n: int) -> dict:
+    rng = np.random.default_rng([seed, 7])
+    qty = rng.integers(1, 51, n)
+    return {"qty": qty * 100, "price": qty * rng.integers(90000, 209900, n),
+            "disc": rng.integers(0, 11, n), "tax": rng.integers(0, 9, n),
+            "flag": rng.integers(0, 3, n), "status": rng.integers(0, 2, n),
+            "year": rng.integers(1995, 1999, n),
+            "day": rng.integers(1, 29, n)}
+
+
+def ref_q1(d: dict) -> list:
+    """Sorted [sum_qty, sum_price, sum_disc_price, sum_charge, count,
+    flag, status] rows, the sums x 10^2, 10^2, 10^4, 10^6: numpy and
+    Python ints only."""
+    keep = (d["year"] < Q1_CUT[0]) | ((d["year"] == Q1_CUT[0]) &
+                                      (d["day"] <= Q1_CUT[2]))
+    price = d["price"].astype(np.int64)
+    disc_price = price * (100 - d["disc"])
+    charge = disc_price * (100 + d["tax"])
+    out = []
+    for i, flag in enumerate(Q1_FLAGS):
+        for j, status in enumerate(Q1_STATUS):
+            m = keep & (d["flag"] == i) & (d["status"] == j)
+            if m.any():
+                out.append([int(d["qty"][m].sum()), int(price[m].sum()),
+                            int(disc_price[m].sum()), int(charge[m].sum()),
+                            int(m.sum()), flag, status])
+    return sorted(out, key=lambda r: r[-2:])
+
+
 # ---------------------------------------------------------- phase A
 
 
@@ -360,6 +414,36 @@ class ServedLeg:
                          chunk=2 << 20, timeout=300)
         c.import_switch_mode(self.store_id, False)
         return time.perf_counter() - t0
+
+    def load_q1(self, table, d: dict) -> None:
+        """``q1_data``'s rows through the native SST encoder's DECIMAL
+        and bytes column kinds (benchmark/tables/lineitem_presplit.py's
+        shape)."""
+        from tikv_tpu.codec.keys import table_record_key
+        from tikv_tpu.sst_importer import (
+            bytes_column, decimal_column, fast_mvcc_table_sst,
+        )
+        c = self.client
+        n = len(d["qty"])
+
+        def chars(idx, texts):      # one byte a row
+            return bytes_column(
+                np.frombuffer(b"".join(texts), np.uint8)[idx].tobytes(),
+                np.arange(n + 1, dtype=np.int64))
+
+        cols = [(cid, decimal_column(d[name].astype(np.int64), 2), None)
+                for cid, name in ((2, "qty"), (3, "price"), (4, "disc"),
+                                  (5, "tax"))]
+        cols += [(6, chars(d["flag"], Q1_FLAGS), None),
+                 (7, chars(d["status"], Q1_STATUS), None),
+                 (8, (d["year"].astype(np.int64) << 50) | (6 << 46) |
+                  (d["day"].astype(np.int64) << 41), None)]
+        c.import_switch_mode(self.store_id, True)
+        c.ingest_sst(fast_mvcc_table_sst(
+            table.table_id, np.arange(n, dtype=np.int64), cols,
+            commit_ts=c.tso()), table_record_key(table.table_id, 0),
+            chunk=2 << 20, timeout=300)
+        c.import_switch_mode(self.store_id, False)
 
     def request(self, name: str, send, classes, backend="device",
                 spans=()) -> dict:
@@ -488,6 +572,11 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
         handles = np.arange(n, dtype=np.int64)
         load_s = leg.load(table, handles, c0, c1)
         leg.load(build_t, np.arange(BUILD_ROWS, dtype=np.int64), b0, b1)
+        # (every table before the first read: one region holds them all,
+        # and an ingest under a warm line would re-upload its feed)
+        q1_t = q1_table()
+        qd = q1_data(args.seed, min(n, Q1_ROWS))
+        leg.load_q1(q1_t, qd)
         log(f"loaded {n} rows in {load_s:.1f}s")
 
         def select():
@@ -556,6 +645,52 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
                 row[0] == total and row[1] == n and
                 abs(row[2] - total / n) <= 1e-9 * max(1.0, abs(total / n)),
                 f"{row} vs {(total, n, total / n)}")
+
+        # -- TPC-H Q1's shape: a composite key of two CHAR(1) code
+        #    planes, a 37-bit DECIMAL product summed as limbs (PR 38) --
+        def q1():
+            import decimal
+            s = DagSelect.from_table(
+                q1_t, ["qty", "price", "disc", "tax", "flag", "status",
+                       "ship"])
+            one = Expr.const(decimal.Decimal(1), EvalType.DECIMAL)
+            disc_price = Expr.call(
+                "MultiplyDecimal", s.col("price"),
+                Expr.call("MinusDecimal", one, s.col("disc")))
+            y, m, d = Q1_CUT
+            dag = s.where(Expr.call("LeTime", s.col("ship"), Expr.const(
+                (y << 50) | (m << 46) | (d << 41), EvalType.DATETIME))
+            ).aggregate([s.col("flag"), s.col("status")], [
+                ("sum", s.col("qty")), ("sum", s.col("price")),
+                ("sum", disc_price),
+                ("sum", Expr.call(
+                    "MultiplyDecimal", disc_price,
+                    Expr.call("PlusDecimal", one, s.col("tax")))),
+                ("count_star", None)]).build(start_ts=c.tso())
+            return c.coprocessor(dag, timeout=900)
+
+        want_q1 = ref_q1(qd)
+        q1s = []
+        for i in range(2):      # cold (the kernel's build), then warm
+            q1s.append(leg.request(f"q1 {i}", q1, CLASS_PALLAS))
+            rows = q1s[-1]["resp"]["rows"]
+            got_q1 = sorted(
+                ([int(v.scaleb(e)) for v, e in zip(r[:4], (2, 2, 4, 6))] +
+                 list(r[4:]) for r in rows), key=lambda r: r[-2:])
+            checks.require(f"q1 {i}: equals numpy reference",
+                           got_q1 == want_q1,
+                           lambda: f"{got_q1[:1]} vs {want_q1[:1]}")
+            checks.require(
+                f"q1 {i}: bytes keys, DECIMALs of scales 2 2 4 6",
+                all([-v.as_tuple().exponent for v in r[:4]] == [2, 2, 4, 6]
+                    and isinstance(r[5], bytes) and isinstance(r[6], bytes)
+                    for r in rows), rows[:1])
+        params = http_json(leg.status_port,
+                           "/health")["device_mesh"]["agg_params"]
+        checks.on_chip("q1: a composite key, a limb sum, code planes",
+                       params["composite_key_launches"] >= 2 and
+                       params["limb_sums"] >= 2 and
+                       params["code_planes"] >= 2, params)
 
         # -- selection (2%: a unary gRPC response is capped at 4 MB by
         #    the client's default, ~350k rows of this table) --
@@ -690,6 +825,7 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
         "queries": {
             "hash_agg": family([cold] + warm),
             "simple_agg": family(simple),
+            "q1": family(q1s),
             "selection": {**family(sel), "rows": len(want_sel),
                           "routing": sel[-1]["labels"].get("routing")},
             "topn": family(top),

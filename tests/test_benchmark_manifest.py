@@ -109,3 +109,44 @@ def test_no_operator_module_imports_the_runner(module):
                        "executors" not in dotted.split(".")
                        for dotted in imported), \
             (module, node.lineno, imported)
+
+
+# A request kind or a table kind may ask the program for a capability by
+# name before it sends anything, so that a program without it ends a run
+# in seconds (``tables/lineitem_presplit.py`` ``load``:
+# ``sst_importer.NATIVE_COLUMN_KINDS``; ``requests/tpch_q1.py``
+# ``prepare``: ``datatype/tile.py`` ``code_plane``).  A rename in the
+# program would make the cell refuse the program that has it.
+
+def capabilities_the_benchmark_asks_for() -> list:
+    import ast
+    out = []
+    for path in sorted(glob.glob(os.path.join(ROOT, "benchmark", "*",
+                                              "*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        modules = {}
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom) and \
+                    (node.module or "").startswith("tikv_tpu"):
+                for a in node.names:
+                    modules[a.asname or a.name] = f"{node.module}.{a.name}"
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "id", None) in ("hasattr", "getattr") \
+                    and len(node.args) >= 2 \
+                    and getattr(node.args[0], "id", None) in modules \
+                    and isinstance(node.args[1], ast.Constant):
+                out.append((modules[node.args[0].id], node.args[1].value))
+    return sorted(set(out))
+
+
+@pytest.mark.parametrize("module,name", capabilities_the_benchmark_asks_for())
+def test_the_program_has_the_capability_the_benchmark_asks_for(module, name):
+    import importlib
+    assert hasattr(importlib.import_module(module), name), (module, name)
+
+
+def test_the_q1_cell_asks_for_code_planes_by_that_name():
+    assert ("tikv_tpu.datatype.tile", "code_plane") in \
+        capabilities_the_benchmark_asks_for()

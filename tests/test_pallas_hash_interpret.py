@@ -213,3 +213,188 @@ def test_a_warm_sharded_launch_takes_its_arguments_as_they_lie(interpret,
     for key, arr in runner._scalar_cache.items():
         assert arr.committed and arr.devices() == four, key
         assert arr.sharding.is_equivalent_to(runner._repl, arr.ndim), key
+
+
+# ------------------------------------------------- composite keys, limbs
+
+
+def _two_key_snapshot(n: int, seed: int, spans=(7, 5)):
+    """``int_table``'s shape with two int keys: ``a`` in [100, 100 +
+    spans[0]), ``b`` in [-3, -3 + spans[1]), ``v`` as ``_snapshot``'s."""
+    rng = np.random.default_rng(seed)
+    table = Table(7350 + seed, (
+        TableColumn("id", 1, FieldType.long(not_null=True),
+                    is_pk_handle=True),
+        TableColumn("a", 2, FieldType.long(not_null=True)),
+        TableColumn("b", 3, FieldType.long(not_null=True)),
+        TableColumn("v", 4, FieldType.long(not_null=True))))
+    a = rng.integers(100, 100 + spans[0], n).astype(np.int64)
+    b = rng.integers(-3, -3 + spans[1], n).astype(np.int64)
+    v = rng.integers(-1000, 1000, n).astype(np.int64)
+    ones = np.ones(n, np.bool_)
+    snap = ColumnarTable.from_arrays(
+        table, np.arange(n, dtype=np.int64),
+        {name: Column(EvalType.INT, arr, ones)
+         for name, arr in (("a", a), ("b", b), ("v", v))})
+    return table, snap, a, b, v
+
+
+def _lane_builds_done(runner: DeviceRunner) -> None:
+    """Wait for the kernels' lane programs (``_build_lanes``: daemon
+    threads that compile beside a first build): a process that exits
+    while one still traces aborts."""
+    import time
+    t_end = time.monotonic() + 180
+    while time.monotonic() < t_end:
+        progs = [e.get("lane_progs") for k, e in
+                 runner._kernel_cache.items()
+                 if isinstance(k, tuple) and k[:1] == ("hashpl",)
+                 and isinstance(e, dict)]
+        if all(p is None or all(v is not None for v in p.values())
+               for p in progs):
+            return
+        time.sleep(0.05)
+    raise AssertionError("lane programs still building")
+
+
+def _want_pairs(a, b, v, mask) -> dict:
+    want = {}
+    for ka, kb in {(int(x), int(y)) for x, y in zip(a[mask], b[mask])}:
+        vv = v[mask & (a == ka) & (b == kb)]
+        want[ka, kb] = (len(vv), int(vv.sum()))
+    return want
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_composite_key_matches_numpy(interpret, n_devices):
+    """GROUP BY two int keys in the kernel's dense branch: the slot is
+    the keys' mixed-radix number, each key's base and span an operand,
+    and the finalize takes it apart again."""
+    table, snap, a, b, v = _two_key_snapshot(N_ROWS, seed=8)
+    runner = _runner(n_devices)
+
+    def dag():
+        sel = DagSelect.from_table(table, ["id", "a", "b", "v"])
+        return sel.where(sel.col("v") > 0).aggregate(
+            [sel.col("a"), sel.col("b")],
+            [("count_star", None), ("sum", sel.col("v"))]).build()
+
+    want = _want_pairs(a, b, v, v > 0)
+    assert len(want) == 35
+    for _ in range(2):
+        got = {(r[-2], r[-1]): tuple(r[:-2])
+               for r in runner.handle_request(dag(), snap).rows()}
+        assert got == want
+    _served_by_pallas(runner)
+    _finalized_natively(runner)
+    recent = runner.flight_recorder.items()
+    assert all(e["keys"] == 2 and e["slot_mode"] == "dense" and
+               e["planes"] >= 3 for e in recent), recent
+    assert runner.flight_recorder.agg_param_counts()[
+        "composite_key_launches"] == 2
+    _lane_builds_done(runner)
+
+
+def test_a_composite_key_past_the_grid_takes_the_sparse_recode(interpret):
+    """Key spans whose product is over MAX_SLOTS do not index the grid:
+    the keys' number is recoded on the host as a sparse key is, and the
+    same kernel serves the slot ids."""
+    table, snap, a, b, v = _two_key_snapshot(N_ROWS, seed=9,
+                                             spans=(3000, 2))
+    assert 3000 * 2 > pallas_hash.MAX_SLOTS
+    runner = _runner(1)
+    runner._max_hash_capacity = 1 << 12
+
+    def dag():
+        sel = DagSelect.from_table(table, ["id", "a", "b", "v"])
+        return sel.aggregate(
+            [sel.col("a"), sel.col("b")],
+            [("count_star", None), ("sum", sel.col("v"))]).build()
+
+    want = _want_pairs(a, b, v, np.ones(N_ROWS, np.bool_))
+    got = {(r[-2], r[-1]): tuple(r[:-2])
+           for r in runner.handle_request(dag(), snap).rows()}
+    assert got == want
+    recent = runner.flight_recorder.items()
+    assert all(e["slot_mode"] != "dense" for e in recent), recent
+    _lane_builds_done(runner)
+
+
+def test_a_refused_composite_build_is_served_recoded(interpret, monkeypatch):
+    """A composite key whose kernel the compiler refuses rides the XLA
+    stand-in with the keys' number recoded, this request and the next."""
+    table, snap, a, b, v = _two_key_snapshot(N_ROWS, seed=11)
+    runner = _runner(1)
+
+    def refuse(*_a, **_k):
+        raise NotImplementedError("Mosaic says no")
+
+    monkeypatch.setattr(pallas_hash, "build", refuse)
+
+    def dag():
+        sel = DagSelect.from_table(table, ["id", "a", "b", "v"])
+        return sel.aggregate(
+            [sel.col("a"), sel.col("b")],
+            [("count_star", None), ("sum", sel.col("v"))]).build()
+
+    want = _want_pairs(a, b, v, np.ones(N_ROWS, np.bool_))
+    for _ in range(2):
+        got = {(r[-2], r[-1]): tuple(r[:-2])
+               for r in runner.handle_request(dag(), snap).rows()}
+        assert got == want
+    # the dense kernel, then the sparse one over the recoded number,
+    # both refused once; then the stand-in, twice
+    classes = [e["compile_class"] for e in runner.flight_recorder.items()]
+    assert classes == ["pallas_hash"] * 2 + ["hash_twolevel"] * 2
+    assert runner.flight_recorder.stats()["faults"] == 2
+
+
+def test_a_product_past_int32_is_summed_as_limbs(interpret):
+    """SUM(a * b) over DECIMAL planes whose product needs 37 bits: the
+    kernel sums ``(a >> 16) * b`` and ``(a & 0xFFFF) * b``, the finalize
+    puts them together, and the answer is the int64 sum, as the host
+    pipeline's Decimals say."""
+    import decimal
+    from tikv_tpu.datatype import FieldTypeTp
+    from tikv_tpu.executors.runner import BatchExecutorsRunner
+    from tikv_tpu.expr import Expr
+    n = 3 * BLOCK + 77
+    rng = np.random.default_rng(10)
+    dec = FieldType(tp=FieldTypeTp.NEW_DECIMAL, flen=15, decimal=2)
+    table = Table(7390, (
+        TableColumn("id", 1, FieldType.long(not_null=True),
+                    is_pk_handle=True),
+        TableColumn("x", 2, dec), TableColumn("m", 3, dec),
+        TableColumn("g", 4, FieldType.long(not_null=True))))
+    # both signs of both factors; the largest product 1.1e11
+    x = rng.integers(-10 ** 9, 10 ** 9, n).astype(np.int64)
+    m = rng.integers(-108, 109, n).astype(np.int64)
+    x[:4], m[:4] = (10 ** 9 - 1, -10 ** 9, 65535, -65536), (108, -108, 108, 1)
+    g = rng.integers(0, 5, n).astype(np.int64)
+    ones = np.ones(n, np.bool_)
+    snap = ColumnarTable.from_arrays(
+        table, np.arange(n, dtype=np.int64),
+        {"x": Column(EvalType.DECIMAL, x, ones, 2),
+         "m": Column(EvalType.DECIMAL, m, ones, 2),
+         "g": Column(EvalType.INT, g, ones)})
+    runner = _runner(1)
+
+    def dag():
+        sel = DagSelect.from_table(table, ["id", "x", "m", "g"])
+        return sel.aggregate([sel.col("g")], [
+            ("sum", Expr.call("MultiplyDecimal", sel.col("x"),
+                              sel.col("m"))),
+            ("count_star", None)]).build()
+
+    want = {int(k): int((x[g == k] * m[g == k]).sum()) for k in range(5)}
+    assert max(abs(x * m)) > 2 ** 31
+    for _ in range(2):
+        got = {r[-1]: r for r in runner.handle_request(dag(), snap).rows()}
+        assert {k: int(r[0].scaleb(4)) for k, r in got.items()} == want
+        assert all(r[0].as_tuple().exponent == -4 for r in got.values())
+    host = {r[-1]: r for r in BatchExecutorsRunner(
+        dag(), snap).handle_request().rows()}
+    assert host == got and isinstance(got[0][0], decimal.Decimal)
+    _served_by_pallas(runner)
+    assert runner.flight_recorder.agg_param_counts()["limb_sums"] == 2
+    _lane_builds_done(runner)

@@ -110,6 +110,7 @@ from ..codec import decode_record_handle, decode_row
 from ..codec.keys import table_record_range
 from ..datatype import Column
 from ..datatype.mydecimal import to_scaled
+from ..datatype.tile import code_width
 from ..engine.traits import CF_DEFAULT, CF_LOCK, CF_WRITE
 from ..executors.columnar import ColumnarTable
 from ..storage.mvcc.reader import _PAST_VERSIONS, MvccReader, \
@@ -218,7 +219,12 @@ def _build_native(snap, table_id: int, col_infos: Sequence, read_ts: int):
         # is read past, whatever it is).
         return None
     # on the columnar_build span: what the build made of the rows
+    # (``code_cols``: the short CHAR columns a feed takes as code
+    # planes, datatype/tile.py: built here as the strings they are)
     tracker.annotate(decimal_cols=sum(1 for k in kinds if k == 4),
+                     code_cols=sum(
+                         1 for info in col_infos if not info.is_pk_handle
+                         and code_width(info.field_type) is not None),
                      skipped_datums=out.get("skipped_datums", 0))
 
     n = out["n"]

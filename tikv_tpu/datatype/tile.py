@@ -22,8 +22,9 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .collation import _PAD_BIN, BINARY, normalize_id
 from .column import Column, ColumnBatch
-from .eval_type import EvalType
+from .eval_type import EvalType, FieldTypeTp
 
 # Default device tile: 1 Mi rows. The reference's BATCH_MAX_SIZE is 1024
 # (runner.rs:45) because its unit of work is a CPU cache tile; on TPU the
@@ -44,6 +45,73 @@ DATE_SHIFT = 41
 def date_plane(values: np.ndarray) -> np.ndarray:
     """A DATE column's packed cores as its int32 plane."""
     return (values >> np.uint64(DATE_SHIFT)).astype(np.int32)
+
+
+# A CHAR(n) column of at most CODE_MAX_LEN bytes rides the device as its
+# CODE plane: a value's bytes as one big-endian integer of the column's
+# ``flen`` bytes, NUL-padded on the right (CHAR(1) b"A" is 65; CHAR(2)
+# b"A" is 0x4100 and b"AB" 0x4142).  Codes keep the order and the
+# equality of the raw bytes, which is how the host pipeline compares and
+# groups a binary or ``_bin`` string, so a GROUP BY over codes is exact
+# and nothing (no dictionary) has to be kept in step with writes.
+CODE_MAX_LEN = 4
+
+
+def code_width(ft) -> Optional[int]:
+    """The bytes a CHAR column of FieldType ``ft`` has on its code
+    plane, or None where it has none: wider than ``CODE_MAX_LEN``
+    characters, of unknown width, or under a collation whose order is
+    not the bytes' (``_ci``: the host pipeline is where a collation is
+    applied).  A value of more bytes than characters (multi-byte UTF-8)
+    is found where the plane is cut (``code_plane``)."""
+    if ft.tp not in (FieldTypeTp.STRING, FieldTypeTp.VAR_CHAR,
+                     FieldTypeTp.VAR_STRING) or \
+            not 0 < ft.flen <= CODE_MAX_LEN:
+        return None
+    coll = normalize_id(ft.collation)
+    if coll != BINARY and coll not in _PAD_BIN:
+        return None
+    return ft.flen
+
+
+def code_plane(values: np.ndarray, width: int) -> Optional[np.ndarray]:
+    """An object array of ``bytes`` as the int64 codes of a column
+    ``width`` bytes wide, or None where a value has no code that gives
+    it back: longer than ``width``, or holding a NUL byte (the pad)."""
+    n = len(values)
+    if not n:
+        return np.zeros(0, np.int64)
+    try:
+        fixed = values.astype("S")
+    except (TypeError, ValueError):
+        return None             # not bytes
+    if fixed.dtype.itemsize > width:
+        return None
+    u8 = np.ascontiguousarray(fixed.astype(f"S{width}")) \
+        .view(np.uint8).reshape(n, width)
+    nz = u8 != 0
+    # NULs only as the pad: a suffix of every row, and as many bytes
+    # kept as the values hold (``S`` drops trailing NULs unseen)
+    if width > 1 and not (nz[:, 1:] <= nz[:, :-1]).all():
+        return None
+    if int(nz.sum()) != sum(map(len, values.tolist())):
+        return None
+    codes = np.zeros(n, np.int64)
+    for k in range(width):
+        codes |= u8[:, k].astype(np.int64) << (8 * (width - 1 - k))
+    return codes
+
+
+def code_bytes(codes: np.ndarray, width: int) -> np.ndarray:
+    """``code_plane``'s inverse: the object array of ``bytes``."""
+    codes = np.asarray(codes, np.int64)
+    u8 = np.empty((len(codes), width), np.uint8)
+    for k in range(width):
+        u8[:, k] = (codes >> (8 * (width - 1 - k))) & 0xFF
+    out = np.empty(len(codes), dtype=object)
+    out[:] = u8.view(f"S{width}").reshape(-1).tolist() if len(codes) \
+        else []
+    return out
 
 
 def _device_dtype(eval_type: EvalType, values: np.ndarray) -> np.dtype:

@@ -47,6 +47,7 @@ from jax.sharding import SingleDeviceSharding
 
 from .. import native
 from ..datatype import Column, EvalType, FieldType
+from ..datatype.tile import code_bytes
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef
 from ..ops.agg import (
@@ -58,7 +59,7 @@ from ..ops.agg import (
 )
 from ..parallel import ROW_AXES
 from ..utils import trace
-from . import pallas_hash
+from . import lowering, pallas_hash
 from .kernels import (
     build_layouts,
     int_planes_needed,
@@ -157,7 +158,8 @@ class DeviceAggregator:
     # -- hash aggregation --
 
     def run_hash(self, dag, plan, host_cols, dtypes, n, feed, meta,
-                 tile_spans=None, lanes: bool = False):
+                 tile_spans=None, lanes: bool = False,
+                 recode: bool = False):
         """One GROUP BY request over ``feed`` → a finished result or a
         ``_Pending``.  ``meta`` is the request's guarded memo (key
         bounds, byte-plane widths and the sparse recode live there);
@@ -165,39 +167,35 @@ class DeviceAggregator:
         region's rows (bucket tiles), else None.  ``lanes``: the caller
         stages several requests under one hold of the dispatch lock and
         launches them together (``launch_lanes``): a warm Pallas launch
-        then comes back prepared and unlaunched, a ``_LanePending``."""
+        then comes back prepared and unlaunched, a ``_LanePending``.
+        ``recode``: a composite key whose kernel was refused is on its
+        way to a stand-in."""
         runner = self._runner
+        composite = len(plan.key_rpns) > 1
         if "hash_bounds" in meta:
             base, span, arg_nbytes = meta["hash_bounds"]
         else:
-            kv, km = eval_rpn(plan.key_rpn, host_cols(), n, np)
-            kv = np.broadcast_to(kv, (n,))
-            km = np.broadcast_to(km, (n,))
-            valid_keys = kv[km]
-            if valid_keys.size:
-                base = int(valid_keys.min())
-                span = int(valid_keys.max()) - base + 1
-            else:
+            if composite:
+                # every key's own bounds; the grid is indexed by the
+                # keys' mixed-radix number, from 0
+                meta["key_bounds"] = self._key_bounds(plan, host_cols(), n)
                 base, span = 0, 1
+                for _b, s in meta["key_bounds"]:
+                    span *= s
+            else:
+                kv, km = eval_rpn(plan.key_rpns[0], host_cols(), n, np)
+                kv = np.broadcast_to(kv, (n,))
+                km = np.broadcast_to(km, (n,))
+                valid_keys = kv[km]
+                if valid_keys.size:
+                    base = int(valid_keys.min())
+                    span = int(valid_keys.max()) - base + 1
+                else:
+                    base, span = 0, 1
             arg_nbytes = self._arg_nbytes(plan, host_cols(), n)
             meta["hash_bounds"] = (base, span, arg_nbytes)
             meta.setdefault("n_rows", n)
-        # the sparse recode: the distinct keys, and each row's slot id
-        # on the device
-        slot_keys = slots_dev = None
-        if span > runner._max_hash_capacity:
-            # sparse key domain: direct indexing can't span it, but the
-            # DISTINCT count may still be small — dictionary-encode the
-            # key once per snapshot and feed dense slot ids (the
-            # reference's fast_hash_aggr_executor.rs handles arbitrary
-            # int keys with a hashmap, runner.rs:293-318)
-            got = self._sparse_slots(plan, host_cols, n, feed, meta)
-            if got is None:
-                raise _FallbackToHost(f"hash key span {span}")
-            slot_keys, _nd, capacity, slots_dev = got
-        else:
-            capacity = max(1024, _next_pow2(span))
-        slots = capacity + 2
+        key_bounds = meta["key_bounds"] if composite else None
         arg_is_real = [r is not None and r.ret_type is EvalType.REAL
                        for r in plan.agg_rpns]
         # a bare reference to a NOT NULL column has validity ≡ row mask —
@@ -208,6 +206,42 @@ class DeviceAggregator:
         if matmul_supported(plan.specs):
             layouts, p8, pf = build_layouts(plan.specs, arg_is_real,
                                             arg_nbytes, arg_ok_is_mask)
+        tiled = tile_spans is not None
+        dense = span <= runner._max_hash_capacity
+        if dense:
+            # (a composite key's grid is as tight as the kernel's eight
+            # sublanes of slots allow: its spans ride as operands, so a
+            # wider product is another kernel only past a power of two)
+            capacity = max(256 if composite else 1024, _next_pow2(span))
+            if composite:
+                # a composite key indexes the grid in the fused kernel
+                # alone: its stand-ins take it recoded, as a sparse key
+                # (which of the two, decided once a feed)
+                memo = "key_dense_tiled" if tiled else "key_dense"
+                if memo not in meta:
+                    meta[memo] = agg_bodies(
+                        runner._is_tpu, runner._nshards(), plan, feed,
+                        dtypes, layouts, p8, pf, capacity,
+                        pallas_hash.MODE_DENSE, tiled)[:1] == \
+                        ("pallas_hash",)
+                dense = meta[memo] and not recode
+        # the sparse recode: the distinct keys, and each row's slot id
+        # on the device
+        slot_keys = slots_dev = None
+        if not dense:
+            # sparse key domain: direct indexing can't span it, but the
+            # DISTINCT count may still be small — dictionary-encode the
+            # key once per snapshot and feed dense slot ids (the
+            # reference's fast_hash_aggr_executor.rs handles arbitrary
+            # int keys with a hashmap, runner.rs:293-318)
+            # (a composite key's number is formed in int64: where the
+            # spans' product leaves it, two key tuples would share one)
+            got = None if span >= 1 << 63 else \
+                self._sparse_slots(plan, host_cols, n, feed, meta)
+            if got is None:
+                raise _FallbackToHost(f"hash key span {span}")
+            slot_keys, _nd, capacity, slots_dev = got
+        slots = capacity + 2
         sparse = slots_dev is not None
         # the sparse slot column rides the sharded flat inputs like any
         # other column (one extra all-valid pair after the scan columns)
@@ -220,19 +254,22 @@ class DeviceAggregator:
         n_cols = len(plan.used_cols)
 
         agg_out = self._agg_out(plan)
-        schema = list(agg_out[0]) + [FieldType.long()]
+        shape = _result_shape(plan, key_bounds)
+        schema = list(agg_out[0]) + [
+            FieldType.var_char() if width else FieldType.long()
+            for width in plan.key_codes or (0,) * len(plan.key_rpns)]
 
         def result(cols):
             return runner._result(dag, list(schema), cols)
 
         def hash_result(merged):
             return result(_hash_columns(agg_out, finalize_hash(
-                plan.specs, merged, base, capacity, slot_keys=slot_keys)))
+                plan.specs, merged, base, capacity, slot_keys=slot_keys),
+                shape))
 
         mode = pallas_hash.MODE_SPARSE if sparse else pallas_hash.MODE_DENSE
         bodies = agg_bodies(runner._is_tpu, runner._nshards(), plan, feed,
-                            dtypes, layouts, p8, pf, capacity, mode,
-                            tile_spans is not None)
+                            dtypes, layouts, p8, pf, capacity, mode, tiled)
         if bodies[:1] == ("pallas_hash",):
             # the fused direct-index kernel is the default body for
             # both dense and (dictionary-encoded) sparse key domains —
@@ -241,20 +278,23 @@ class DeviceAggregator:
                                    capacity, layouts, p8, arg_nbytes,
                                    arg_ok_is_mask, mode, spans=tile_spans,
                                    slots_dev=slots_dev, meta=meta,
-                                   lanes=lanes)
+                                   lanes=lanes, key_bounds=key_bounds)
             if got is not None:
                 synced, parts, pl_LO = got
 
                 def from_packed(parts):
                     return result(self._packed_columns(
                         plan, parts, pl_LO, p8, layouts, slots, base,
-                        capacity, slot_keys))
+                        capacity, slot_keys, shape))
 
                 if isinstance(parts, _LanePending):
                     parts.finalize = from_packed
                     return parts
                 return from_packed(parts) if synced \
                     else _Pending(parts, from_packed)
+            if composite and not sparse:
+                return self.run_hash(dag, plan, host_cols, dtypes, n, feed,
+                                     meta, tile_spans, lanes, recode=True)
             bodies = bodies[1:]
         if not bodies:
             # bucket tiles exist only on the fused-kernel path; the
@@ -362,9 +402,21 @@ class DeviceAggregator:
         runner = self._runner
         if "sparse_slots" in meta:
             return meta["sparse_slots"]
-        kv, km = eval_rpn(plan.key_rpn, host_cols(), n, np)
-        kv = np.broadcast_to(kv, (n,))
-        km = np.broadcast_to(km, (n,))
+        if len(plan.key_rpns) > 1:
+            # the keys' mixed-radix number, as the kernel's dense
+            # branch forms it: ``_hash_columns`` takes it apart
+            kv = np.zeros(n, np.int64)
+            # (``run_hash`` holds the spans' product under 2^63, so
+            # neither a key's offset nor the number wraps)
+            for r, (b, s) in zip(plan.key_rpns, meta["key_bounds"]):
+                v, _ok = eval_rpn(r, host_cols(), n, np)
+                kv = kv * s + (np.broadcast_to(v, (n,)).astype(np.int64)
+                               - b)
+            km = np.ones(n, np.bool_)
+        else:
+            kv, km = eval_rpn(plan.key_rpns[0], host_cols(), n, np)
+            kv = np.broadcast_to(kv, (n,))
+            km = np.broadcast_to(km, (n,))
         valid = kv[km] if not km.all() else kv
         got = None
         if valid.size:
@@ -388,15 +440,42 @@ class DeviceAggregator:
         meta["sparse_slots"] = got
         return got
 
+    @staticmethod
+    def _key_bounds(plan: _Plan, host_cols, n: int) -> tuple:
+        """``((base, span), ...)`` of a composite key's keys over the
+        feed's rows.  A key with a NULL goes to the host: SQL keeps
+        (NULL, 1) and (NULL, 2) apart, and the grid has one NULL slot.
+        So does an unsigned one: the key's number is an int64's."""
+        bounds = []
+        for r in plan.key_rpns:
+            kv, km = eval_rpn(r, host_cols, n, np)
+            if not np.all(km):
+                raise _FallbackToHost("NULL in a composite GROUP BY key")
+            if np.asarray(kv).dtype.kind == "u":
+                raise _FallbackToHost("unsigned composite GROUP BY key")
+            kv = np.broadcast_to(kv, (n,))
+            lo = int(kv.min()) if n else 0
+            bounds.append((lo, int(kv.max()) - lo + 1 if n else 1))
+        return tuple(bounds)
+
     def _arg_nbytes(self, plan: _Plan, host_cols, n: int) -> tuple:
         """Byte-plane count per aggregate arg for the MXU int path.
 
         Plain column refs use the column's actual value range (host
         min/max, vectorized); computed expressions use the device dtype
         width (int arithmetic wraps in-dtype on device — documented
-        deviation, expr/functions.py)."""
+        deviation, expr/functions.py), but a LOWERED plan's: there
+        ``lowering`` proves each argument's interval from the columns'
+        bounds (it proved the plan exact from the same), so a limb
+        product of 21 bits rides three planes, not four."""
+        proven = {}
+        if plan.lowered:
+            proven = lowering.agg_intervals(
+                plan, [(int(v.min()), int(v.max())) if v.size else (0, 0)
+                       for v, _ok in host_cols],
+                [str(v.dtype) for v, _ok in host_cols])
         out = []
-        for r in plan.agg_rpns:
+        for j, r in enumerate(plan.agg_rpns):
             if r is None or r.ret_type is EvalType.REAL:
                 out.append(0)
                 continue
@@ -407,6 +486,8 @@ class DeviceAggregator:
                     out.append(int_planes_needed(int(v.min()), int(v.max())))
                 else:
                     out.append(1)
+            elif j in proven:
+                out.append(int_planes_needed(*proven[j]))
             else:
                 widths = [host_cols[i][0].dtype.itemsize
                           for i in _rpn_col_indices(r)] or [4]
@@ -431,20 +512,25 @@ class DeviceAggregator:
         if out is None:
             from ..executors.aggregation import _agg_ret_ft
             # a lowered DECIMAL's SUM is typed as the host pipeline
-            # types it (the argument was a DECIMAL before the lowering)
-            fracs = tuple(plan.agg_fracs) or (None,) * len(plan.specs)
+            # types it (the argument was a DECIMAL before the lowering);
+            # a limb pair (``plan.agg_recipes``) as its first limb
+            dev_fracs = tuple(plan.agg_fracs) or (None,) * len(plan.specs)
+            firsts = [src if isinstance(src, int) else src[0]
+                      for src in plan.agg_recipes or range(len(plan.specs))]
+            fracs = tuple(dev_fracs[j] for j in firsts)
             fts = [_agg_ret_ft(spec.kind,
                                EvalType.DECIMAL if frac is not None
                                else spec.eval_type if spec.kind not in
                                ("count", "count_star") else None)
-                   for spec, frac in zip(plan.specs, fracs)]
+                   for spec, frac in zip((plan.specs[j] for j in firsts),
+                                         fracs)]
             out = plan.agg_out = (fts, [
                 np.dtype(np.uint64) if ft.is_unsigned
                 else ft.eval_type.np_dtype for ft in fts], fracs)
         return out
 
     def _packed_columns(self, plan, parts, LO, p8, layouts, slots, base,
-                        capacity, slot_keys):
+                        capacity, slot_keys, shape=None):
         """An aggregation's finalize after a Pallas launch, GROUP BY
         or not (``slots`` 1): ``finalize_packed`` (one native call
         where it can, the numpy chain where it cannot), counted once on
@@ -455,14 +541,14 @@ class DeviceAggregator:
             parts, LO, p8, layouts, plan.specs, slots, base, capacity,
             slot_keys)
         runner.flight_recorder.note_finalize(was_native)
-        return _hash_columns(self._agg_out(plan), finalized)
+        return _hash_columns(self._agg_out(plan), finalized, shape)
 
     # -- the Pallas launch --
 
     def _try_pallas(self, dag, plan, feed, dtypes, n, base, capacity,
                     layouts, p8, arg_nbytes, arg_ok_is_mask, mode,
                     spans=None, slots_dev=None, meta=None,
-                    lanes: bool = False):
+                    lanes: bool = False, key_bounds=None):
         """Fused Pallas fast path for the direct-index aggregation
         (dense / sparse-slot / simple modes — pallas_hash module doc),
         for a plan ``agg_bodies`` gave to the kernel.
@@ -501,6 +587,17 @@ class DeviceAggregator:
         sparse = mode == pallas_hash.MODE_SPARSE
         # the request's constants, operands of the const-blind kernel
         _sel, _aggs, pvals, pdts = pallas_hash.plan_params(plan)
+        n_consts = len(pvals)
+        if key_bounds is not None and not sparse:
+            # a composite key's bases and spans ride ahead of them
+            # (``pallas_hash.build``): a feed's own, not the kernel's
+            pvals = tuple(v for b in key_bounds for v in b) + tuple(pvals)
+            pdts = ("int32",) * (2 * len(key_bounds)) + tuple(pdts)
+        # what the recorder says of the launch: its GROUP BY keys and
+        # the byte planes it contracts (the kernel's time follows rows
+        # x planes: PERF.md section 6, PR 34)
+        launched = {"keys": len(plan.key_rpns), "planes": p8,
+                    "limb_sums": len(plan.limbs)}
         B = pallas_hash.BLOCK
         total_blocks = feed["n_pad"] // B
         tiles = []          # (row_lo, row_hi, blk0, span_blocks)
@@ -595,14 +692,16 @@ class DeviceAggregator:
             (lo, hi, blk0, nb), = tiles
             return False, _LanePending(
                 (key, entry, entry["runs"][nb],
-                 (lo, hi, base, blk0, pvals), mode), cols), entry["LO"]
+                 (lo, hi, base, blk0, pvals), mode,
+                 dict(launched, params=n_consts)), cols), entry["LO"]
         try:
             # the first build is a launch like any other: its compile
             # wall and class land in the flight recorder
             # (first_launch=True), and a rejected build counts as a
             # recorder fault before the XLA fallback serves
             with runner._dispatch_phase("pallas_hash", key,
-                                        params=len(pvals), slot_mode=mode):
+                                        params=n_consts, slot_mode=mode,
+                                        **launched):
                 if first:
                     entry = build()
                     if whole:
@@ -679,8 +778,8 @@ class DeviceAggregator:
                 try:
                     with runner._dispatch_phase(
                             "pallas_hash", key,
-                            params=len(batch[0].kernel[3][4]),
-                            slot_mode=batch[0].kernel[4]) as info:
+                            slot_mode=batch[0].kernel[4],
+                            **batch[0].kernel[5]) as info:
                         if k > 1:
                             trace.annotate(lanes=k)
                             with jax.enable_x64(False):
@@ -689,7 +788,7 @@ class DeviceAggregator:
                                           for p in batch),
                                     tuple(p.cols for p in batch))
                         else:
-                            _key, _entry, run, bounds, _mode = \
+                            _key, _entry, run, bounds, _mode, _said = \
                                 batch[0].kernel
                             lo, hi, base, blk0, pvals = bounds
                             out = [run(lo, hi, base, blk0, batch[0].cols,
@@ -959,7 +1058,7 @@ class DeviceAggregator:
                                 scrap)
                 overflow = jnp.zeros((), jnp.bool_)
             else:
-                key_pair = eval_rpn(plan.key_rpn, pairs, n_local, jnp)
+                key_pair = eval_rpn(plan.key_rpns[0], pairs, n_local, jnp)
                 idx, overflow = slot_index(key_pair, capacity, aux, mask)
             L8, Lf = make_planes(layouts, specs, cols, mask)
             S2_8, S2_f = twolevel_partial(idx, L8, Lf, LO, HI)
@@ -986,7 +1085,7 @@ class DeviceAggregator:
                 key_pair = (jnp.zeros((n_local,), jnp.int32), mask)
                 tile_base = ("precomp", pairs[n_cols][0])
             else:
-                key_pair = eval_rpn(plan.key_rpn, pairs, n_local, jnp)
+                key_pair = eval_rpn(plan.key_rpns[0], pairs, n_local, jnp)
                 tile_base = aux
             st = hash_agg_tile(jnp, specs, key_pair, cols, capacity,
                                tile_base, row_mask=mask)
@@ -1370,7 +1469,8 @@ class DeviceAggregator:
                     # the fetched accumulator is a grid of ONE slot: no
                     # key, no NULL group, no scrap row (``slots`` 1)
                     return result(self._packed_columns(
-                        plan, parts, LO, p8, layouts, 1, 0, 1, None))
+                        plan, parts, LO, p8, layouts, 1, 0, 1, None,
+                        _result_shape(plan)))
 
                 if isinstance(parts, _LanePending):
                     parts.finalize = from_packed
@@ -1402,7 +1502,8 @@ class DeviceAggregator:
                     else trace.phase("shard_merge"):
                 merged = self._merge_stacked(plan.specs, summed, stacked)
             return result(_hash_columns(
-                agg_out, _simple_planes(plan.specs, merged)))
+                agg_out, _simple_planes(plan.specs, merged),
+                _result_shape(plan)))
 
         return _Pending(carry, fin)
 
@@ -1572,17 +1673,34 @@ def _pallas_states(packed, LO, p8, layouts, specs, slots):
     return states_from_matmul(layouts, specs, S8, None, xp=np)
 
 
-def _hash_columns(agg_out, finalized):
-    """Finalized planes → result Columns (aggregates, then the key of
+def _result_shape(plan, key_bounds=None):
+    """What ``_hash_columns`` needs of a plan beyond ``_agg_out`` to
+    turn the DEVICE's aggregates and its one key plane into the plan's
+    columns, None where they are the same: ``(recipes, a composite
+    key's bounds, the keys' code widths)``."""
+    if plan.agg_recipes is None and key_bounds is None and \
+            not any(plan.key_codes):
+        return None
+    return plan.agg_recipes, key_bounds, plan.key_codes
+
+
+def _hash_columns(agg_out, finalized, shape=None):
+    """Finalized planes → result Columns (aggregates, then the keys of
     a GROUP BY): the ONE place where planes become Columns for every
     aggregation body, whether ``ops.agg.finalize_hash``, the native
     call of ``finalize_packed`` or ``_simple_planes`` made them.  No
     Python value is made per group between the fetched accumulator and
     the wire encoder.  ``agg_out``: ``DeviceAggregator._agg_out`` of the
     plan; ``finalized``: ``finalize_hash``'s ``((keys, key_valid),
-    planes)``, ``keys`` None where there is no GROUP BY."""
+    planes)``, ``keys`` None where there is no GROUP BY; ``shape``:
+    ``_result_shape`` of the plan: limb pairs put together
+    (``lowering.recipe_planes``), a composite key's number
+    taken apart into its keys, a code plane's codes back to bytes."""
     (keys, key_valid), planes = finalized
     fts, dts, fracs = agg_out
+    recipes, key_bounds, key_codes = shape or (None,) * 3
+    if recipes is not None:
+        planes = lowering.recipe_planes(recipes, planes)
     cols = [Column(ft.eval_type, vals.astype(dt, copy=False), ok)
             if frac is None else
             # a lowered DECIMAL's SUM: the exact integer sums, handed
@@ -1590,6 +1708,18 @@ def _hash_columns(agg_out, finalized):
             Column(ft.eval_type, np.asarray(vals, np.int64), ok,
                    frac).unscaled()
             for ft, dt, frac, (vals, ok) in zip(fts, dts, fracs, planes)]
-    if keys is not None:
-        cols.append(Column(EvalType.INT, keys, key_valid))
+    if keys is None:
+        return cols
+    parts = [keys]
+    if key_bounds is not None:
+        # (a composite key is never NULL: ``run_hash``)
+        rest, parts = np.asarray(keys, np.int64), []
+        for b, s in reversed(key_bounds):
+            parts.append(rest % s + b)
+            rest = rest // s
+        parts.reverse()
+    for vals, width in zip(parts, key_codes or (0,) * len(parts)):
+        cols.append(Column(EvalType.BYTES, code_bytes(vals, width),
+                           key_valid) if width
+                    else Column(EvalType.INT, vals, key_valid))
     return cols

@@ -20,7 +20,10 @@ is the default body for every aggregation shape that qualifies):
 
 - ``dense``  — GROUP BY over a small contiguous key domain: the key
   expression evaluates in-kernel and ``key - base`` indexes the grid
-  directly (BASELINE config 4).
+  directly (BASELINE config 4).  Several keys (a COMPOSITE key) index
+  it by ``(k0 - base0) * span1 + (k1 - base1)`` and so on, each key's
+  base and span an operand beside the plan's constants, the product of
+  the spans inside the grid (TPC-H Q1's two CHAR(1) keys: 18 x 10).
 - ``sparse`` — arbitrary int64 key domains: the host dictionary-encodes
   the keys once per snapshot (aggregate.py _sparse_slots) and the dense slot
   ids ride as ONE extra int32 input column, so the kernel never touches
@@ -154,11 +157,23 @@ def plan_params(plan) -> tuple:
 
 
 def key_consts(plan) -> tuple:
-    """The GROUP BY key expression's constants, by value: the part of a
-    const-blind identity (the kernel's cache key, the request memo's)
-    that stays exact, since the key bounds depend on them."""
-    return () if plan.key_rpn is None else tuple(
-        nd.value for nd in plan.key_rpn.nodes if isinstance(nd, RpnConst))
+    """What of a plan stays exact in a const-blind identity (the
+    kernel's cache key, the request memo's): the GROUP BY keys'
+    constants by value, since the key bounds depend on them, and the
+    aggregates' STRUCTURAL constants (``fixed``: never operands, so a
+    kernel is built around their values; device/lowering.py) with the
+    aggregates summed as limbs.  Memoized on the plan (asked twice a
+    request)."""
+    got = plan.ident
+    if got is None:
+        keys = tuple(nd.value for r in plan.key_rpns for nd in r.nodes
+                     if isinstance(nd, RpnConst))
+        fixed = tuple(nd.value for r in plan.agg_rpns if r is not None
+                      for nd in r.nodes
+                      if isinstance(nd, RpnConst) and nd.fixed)
+        got = plan.ident = keys + (("fixed", fixed, plan.limbs),) \
+            if fixed or plan.limbs else keys
+    return got
 
 
 def kernel_col_ids(plan, mode: str) -> tuple:
@@ -176,7 +191,8 @@ def kernel_col_ids(plan, mode: str) -> tuple:
         if r is not None:
             ids |= _rpn_cols(r)
     if mode == MODE_DENSE:
-        ids |= _rpn_cols(plan.key_rpn)
+        for r in plan.key_rpns:
+            ids |= _rpn_cols(r)
     return tuple(sorted(ids))
 
 
@@ -186,8 +202,8 @@ def key_never_null(plan) -> bool:
     ``supported`` gate already requires every kernel-input column be
     non-nullable; expression keys keep a NULL slot because a function
     may introduce NULL, e.g. out-of-domain casts.)"""
-    nodes = plan.key_rpn.nodes
-    return len(nodes) == 1 and isinstance(nodes[0], RpnColumnRef)
+    return all(len(r.nodes) == 1 and isinstance(r.nodes[0], RpnColumnRef)
+               for r in plan.key_rpns)
 
 
 def n_slots(plan, capacity: int, mode: str = MODE_DENSE) -> int:
@@ -223,6 +239,11 @@ def supported(plan, feed, dtypes, pf: int, capacity: int,
     if any(dt != "int32" for dt in plan_params(plan)[3]):
         return False        # the prefetch operand is int32 scalars
     if n_slots(plan, capacity, mode) > MAX_SLOTS:
+        return False
+    if mode == MODE_DENSE and len(plan.key_rpns) > 1 and \
+            not key_never_null(plan):
+        # a composite key has no NULL slot: SQL keeps (NULL, 1) and
+        # (NULL, 2) apart, which one slot cannot
         return False
     if feed["n_pad"] % (max(1, n_shards) * BLOCK) != 0:
         return False
@@ -289,7 +310,11 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
         mode == MODE_SPARSE or not key_never_null(plan))
     sel_rpns, agg_rpns, _vals, param_dts = plan_params(plan)
     n_params = len(param_dts)
-    key_rpn = plan.key_rpn
+    key_rpns = plan.key_rpns
+    # a composite key's (base, span) pairs ride the prefetch scalars
+    # ahead of the plan's constants (``key_scalars``)
+    n_keysc = 2 * len(key_rpns) if mode == MODE_DENSE and \
+        len(key_rpns) > 1 else 0
     lobits = LO.bit_length() - 1
     n_cols_in = sum(1 for p in col_map if p >= 0)
     sparse = mode == MODE_SPARSE
@@ -328,7 +353,8 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
             # the request's constants: scalars from SMEM, valid as a
             # baked constant is (eval._const_pair)
             true0 = jnp.ones((), jnp.bool_)
-            pairs += [(sref[4 + j], true0) for j in range(n_params)]
+            pairs += [(sref[4 + n_keysc + j], true0)
+                      for j in range(n_params)]
             mask = row_mask
             for rpn in sel_rpns:
                 v, ok = eval_rpn(rpn, pairs, B, jnp)
@@ -342,8 +368,24 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
                 # = NULL-key slot, capacity+1 = scrap/padding → SENT
                 s = refs[n_cols_in][:].astype(_i32)
                 idx = jnp.where(mask & (s < _i32(slots)), s, _i32(SENT))
+            elif n_keysc:
+                # composite key: every key inside its own span, the
+                # slot their mixed-radix number (< the spans' product
+                # <= capacity: run_hash); never NULL (``supported``)
+                rel = _i32(0)
+                km = in_range = jnp.ones((B,), jnp.bool_)
+                for j, rpn in enumerate(key_rpns):
+                    kv, ok = eval_rpn(rpn, pairs, B, jnp)
+                    kv = jnp.broadcast_to(kv, (B,)).astype(_i32)
+                    km = km & jnp.broadcast_to(ok, (B,))
+                    span_j = sref[5 + 2 * j]
+                    rel_j = kv - sref[4 + 2 * j]
+                    in_range = in_range & (rel_j >= _i32(0)) & \
+                        (rel_j < span_j)
+                    rel = rel * span_j + rel_j
+                idx = jnp.where(mask & km & in_range, rel, _i32(SENT))
             else:
-                kv, km = eval_rpn(key_rpn, pairs, B, jnp)
+                kv, km = eval_rpn(key_rpns[0], pairs, B, jnp)
                 kv = jnp.broadcast_to(kv, (B,)).astype(_i32)
                 km = jnp.broadcast_to(km, (B,))
                 rel = kv - base

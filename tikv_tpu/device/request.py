@@ -38,6 +38,10 @@ class _FallbackToHost(Exception):
 #  its scale reaches the device as a scaled INTEGER plane, and plan
 #  analysis lowers decimal RPN over it to integer RPN before this gate
 #  sees it (device/lowering.py); what cannot be lowered stays host.
+#  Nor is BYTES: a CHAR column of at most four bytes under a binary or
+#  ``_bin`` collation reaches the device as its int32 CODE plane
+#  (datatype/tile.py code_plane) the same way, typed INT by the time
+#  this gate sees it; a wider string or a function of one stays host.
 _DEVICE_ETS = (EvalType.INT, EvalType.REAL, EvalType.DATETIME,
                EvalType.DURATION)
 
@@ -88,7 +92,6 @@ class _Plan:
     sel_rpns: list = field(default_factory=list)
     specs: list = field(default_factory=list)        # AggSpec per agg
     agg_rpns: list = field(default_factory=list)     # RpnExpression | None
-    key_rpn: Optional[RpnExpression] = None
     order_rpn: Optional[RpnExpression] = None
     order_desc: bool = False
     limit: int = 0
@@ -114,6 +117,22 @@ class _Plan:
     # (param sel_rpns, param agg_rpns, values, dtypes)
     # (aggregate.py agg_params)
     agg_params: Optional[tuple] = None
+    # every GROUP BY key (several: the composite key, aggregate.py
+    # ``run_hash``); per key the bytes of its CHAR code plane (0: an
+    # integer key), per used column likewise (device/lowering.py)
+    key_rpns: list = field(default_factory=list)
+    key_codes: tuple = ()
+    code_planes: tuple = ()
+    # per aggregate of the plan which of ``specs``, the DEVICE's
+    # aggregates, it is: an index or a limb pair's (hi, lo), put
+    # together by the finalize (lowering.recipe_planes); None: each its
+    # own; the limb-split aggregates of this variant (lowering.fit);
+    # and the plan's variants by that tuple (runner ``_limb_variant``)
+    agg_recipes: Optional[list] = None
+    limbs: tuple = ()
+    variants: dict = field(default_factory=dict)
+    # lazy: pallas_hash.key_consts
+    ident: Optional[tuple] = None
 
 
 class _PinnedStager:

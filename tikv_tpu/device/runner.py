@@ -76,7 +76,9 @@ from ..copr.dag import (
     TopNDesc,
 )
 from ..datatype import Column, ColumnBatch, EvalType
-from ..datatype.tile import _device_dtype, date_plane
+from ..datatype.tile import (
+    _device_dtype, code_plane, code_width, date_plane,
+)
 from ..expr import build_rpn
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef, RpnExpression
@@ -185,9 +187,19 @@ def _is_date_plane(info, dt: np.dtype) -> bool:
 def _to_plane(info, vals: np.ndarray, dt: np.dtype) -> np.ndarray:
     """A used column's host values as the values of its device plane,
     before the cast to ``dt``: a DATE column on the date plane drops
-    its core's zero low bits; every other column is itself (a scaled
-    DECIMAL is scaled in the cache line already)."""
-    return date_plane(vals) if _is_date_plane(info, dt) else vals
+    its core's zero low bits, a CHAR column is its codes (a value that
+    has none reads beyond every dtype: ``_fits_dtype`` then refuses the
+    patch); every other column is itself (a scaled DECIMAL is scaled in
+    the cache line already)."""
+    if _is_date_plane(info, dt):
+        return date_plane(vals)
+    # (a string column rides a feed in no other form than its codes)
+    width = code_width(info.field_type) if vals.dtype == object else None
+    if width:
+        codes = code_plane(vals, width)
+        return codes if codes is not None else \
+            np.full(len(vals), 1 << 62, np.int64)
+    return vals
 
 
 def _fp_degrade(name: str) -> None:
@@ -1467,10 +1479,9 @@ class DeviceRunner:
         rpns_to_check = list(sel_rpns)
         plan = _Plan(scan=scan, kind="scan", used_cols=[])
         date_cols: set = set()      # scan offsets on the int32 date plane
+        code_cols: set = set()      # scan offsets on a CHAR code plane
 
         if isinstance(terminal, AggregationDesc):
-            if len(terminal.group_by) > 1:
-                return None
             agg_rpns = []
             for a in terminal.aggs:
                 if a.kind not in ("count", "count_star", "sum", "avg",
@@ -1481,49 +1492,58 @@ class DeviceRunner:
                     return None
                 agg_rpns.append(None if a.arg is None
                                 else build_rpn(a.arg))
-            key_rpn = build_rpn(terminal.group_by[0]) \
-                if terminal.group_by else None
-            if lowering.needs_lowering(scan, sel_rpns, agg_rpns, key_rpn):
+            agg_kinds = [a.kind for a in terminal.aggs]
+            key_rpns = [build_rpn(e) for e in terminal.group_by]
+            if lowering.needs_lowering(scan, sel_rpns, agg_rpns, key_rpns):
                 # DECIMAL columns as scaled integers, a DATE column as
-                # its int32 plane: decimal RPN becomes the integer RPN
-                # the kernels evaluate, each SUM's scale carried beside
-                # it to the finalize (device/lowering.py).  What has no
-                # exact integer form is not a device plan.
+                # its int32 plane, a short CHAR column as its code
+                # plane: decimal RPN becomes the integer RPN the kernels
+                # evaluate, each SUM's scale carried beside it to the
+                # finalize (device/lowering.py).  What has no exact
+                # integer form is not a device plan.
                 from ..utils import tracker
                 with tracker.span("decimal_lower"):
                     try:
                         low = lowering.lower(
-                            scan, sel_rpns, agg_rpns,
-                            [a.kind for a in terminal.aggs], key_rpn)
+                            scan, sel_rpns, agg_rpns, agg_kinds, key_rpns)
                     except lowering.NotLowerable:
                         return None
-                sel_rpns, agg_rpns, key_rpn = \
-                    low.sel_rpns, low.agg_rpns, low.key_rpn
+                    tracker.annotate(fixed_consts=low.fixed_consts())
+                sel_rpns, agg_rpns, key_rpns = \
+                    low.sel_rpns, low.agg_rpns, low.key_rpns
                 rpns_to_check = list(sel_rpns)
                 plan.agg_fracs = low.agg_fracs
                 plan.lowered = bool(low.dec_cols or low.date_cols)
                 date_cols = low.date_cols
+                code_cols = low.code_cols
+                plan.key_codes = tuple(low.key_codes)
                 scan_ets = [EvalType.INT if i in low.dec_cols or
-                            i in low.date_cols else et
-                            for i, et in enumerate(scan_ets)]
+                            i in low.date_cols or i in low.code_cols
+                            else et for i, et in enumerate(scan_ets)]
             specs = []
-            for i, (a, r) in enumerate(zip(terminal.aggs, agg_rpns)):
+            for i, (kind, r) in enumerate(zip(agg_kinds, agg_rpns)):
                 if r is not None:
-                    if r.ret_type in _TIME_ETS and a.kind not in (
+                    if r.ret_type in _TIME_ETS and kind not in (
                             "count", "min", "max", "first"):
                         return None     # SUM(datetime) etc. → host
                     rpns_to_check.append(r)
-                    specs.append(AggSpec(a.kind, i, r.ret_type))
+                    specs.append(AggSpec(kind, i, r.ret_type))
                 else:
-                    specs.append(AggSpec(a.kind, i))
-            if key_rpn is not None:
+                    specs.append(AggSpec(kind, i))
+            if key_rpns:
                 if any(s.kind == "first" for s in specs):
                     return None     # FIRST needs source-row gather → host
-                if key_rpn.ret_type is not EvalType.INT:
+                if any(r.ret_type is not EvalType.INT for r in key_rpns):
                     return None
-                rpns_to_check.append(key_rpn)
+                if len(key_rpns) > 1 and not all(
+                        s.kind in ("count", "count_star", "sum", "avg")
+                        for s in specs):
+                    # a composite key's groups come back through the
+                    # additive bodies only (aggregate.py run_hash)
+                    return None
+                rpns_to_check += key_rpns
                 plan.kind = "hash_agg"
-                plan.key_rpn = key_rpn
+                plan.key_rpns = key_rpns
             else:
                 plan.kind = "simple_agg"
             plan.specs = specs
@@ -1577,16 +1597,43 @@ class DeviceRunner:
         mapping = {old: new for new, old in enumerate(used)}
         plan.used_cols = used
         plan.date_planes = tuple(ci in date_cols for ci in used)
+        if code_cols:
+            plan.code_planes = tuple(
+                code_width(scan.columns[ci].field_type)
+                if ci in code_cols else 0 for ci in used)
         if not plan.agg_fracs:
             plan.agg_fracs = [None] * len(plan.agg_rpns)
         plan.sel_rpns = [_remap_rpn(r, mapping) for r in sel_rpns]
         plan.agg_rpns = [None if r is None else _remap_rpn(r, mapping)
                          for r in plan.agg_rpns]
-        if plan.key_rpn is not None:
-            plan.key_rpn = _remap_rpn(plan.key_rpn, mapping)
+        plan.key_rpns = [_remap_rpn(r, mapping) for r in plan.key_rpns]
         if plan.order_rpn is not None:
             plan.order_rpn = _remap_rpn(plan.order_rpn, mapping)
         return plan
+
+    @staticmethod
+    def _limb_variant(plan: _Plan, limbs: tuple) -> _Plan:
+        """``plan`` with the aggregates ``lowering.fit`` named summed as
+        two 16-bit limbs each (``lowering.split_limbs``): the plan a
+        feed is served by whose bounds ask for it.  Made once a tuple
+        and kept on the plan, so every such feed shares one variant
+        (and with it one kernel)."""
+        got = plan.variants.get(limbs)
+        if got is None:
+            import dataclasses
+            from ..utils import tracker
+            with tracker.span("decimal_lower"):
+                rpns, kinds, fracs, recipes = lowering.split_limbs(
+                    plan, limbs)
+                tracker.annotate(limb_sums=len(limbs))
+            got = plan.variants[limbs] = dataclasses.replace(
+                plan, agg_rpns=rpns, agg_fracs=fracs,
+                agg_recipes=recipes, limbs=limbs, variants={},
+                specs=[AggSpec(k, i) if r is None
+                       else AggSpec(k, i, r.ret_type)
+                       for i, (k, r) in enumerate(zip(kinds, rpns))],
+                agg_out=None, agg_params=None, ident=None)
+        return got
 
     # ------------------------------------------------------------------ scan
 
@@ -2650,7 +2697,8 @@ class DeviceRunner:
 
     @contextmanager
     def _dispatch_phase(self, klass: str, key=None, params: int = 0,
-                        slot_mode: str = ""):
+                        slot_mode: str = "", keys: int = 0,
+                        planes: int = 0, limb_sums: int = 0):
         """Every kernel launch site runs under this: the
         ``device_dispatch`` tracker span, plus one flight-recorder
         entry (launch wall, compile class, first-launch flag, mesh
@@ -2662,8 +2710,10 @@ class DeviceRunner:
         key) so the ``first_launch`` flag distinguishes a real
         cold-compile launch from a warm cache hit within the same plan
         kind.  ``params``: the constants the launch carries as kernel
-        operands; ``slot_mode``: the Pallas kernel's (both on the span
-        and in the entry)."""
+        operands; ``slot_mode``: the Pallas kernel's; ``keys`` /
+        ``planes`` / ``limb_sums``: its GROUP BY keys, the byte planes
+        it contracts and the SUMs it sums as limbs (on the span and in
+        the entry; counted on ``/health`` ``device_mesh.agg_params``)."""
         from .. import resource_metering as rm
         from ..utils import tracker
         rec = self.flight_recorder
@@ -2701,7 +2751,8 @@ class DeviceRunner:
                         pinned_bytes=self._arena.pinned_bytes(),
                         ok=ok, shards=num_shards(self._mesh),
                         whole_mesh=self._failover_parent is None,
-                        params=params, slot_mode=slot_mode)
+                        params=params, slot_mode=slot_mode, keys=keys,
+                        planes=planes, limb_sums=limb_sums)
                     tracker.annotate(**entry)
                     info["attrs"] = entry
                 info["t0_ns"], info["t1_ns"] = t0_ns, t1_ns
@@ -2975,6 +3026,7 @@ class DeviceRunner:
             if "dtypes" in memo:
                 return memo["dtypes"]
             if "dtypes" in meta and memo_fresh():
+                memo["limbs"] = meta.get("limbs", ())
                 return meta["dtypes"]
             batch = get_batch()
             dts = []
@@ -2992,6 +3044,17 @@ class DeviceRunner:
                     vals = date_plane(vals)
                     dt = np.dtype(np.int32)
                     self.flight_recorder.note_plane("date")
+                elif plan.code_planes and plan.code_planes[pos]:
+                    vals = code_plane(vals, plan.code_planes[pos])
+                    if vals is None:
+                        # a value wider than the column's declared
+                        # bytes, or holding the pad byte: no code gives
+                        # it back, so the strings stay with the host
+                        meta["force_host"] = True
+                        raise _FallbackToHost("CHAR value without a code")
+                    dt = _device_dtype(EvalType.INT, vals)
+                    memo.setdefault("code_vals", {})[pos] = vals
+                    self.flight_recorder.note_plane("code")
                 else:
                     dt = _device_dtype(col.eval_type, vals)
                     if col.frac is not None:
@@ -3009,20 +3072,27 @@ class DeviceRunner:
                 if plan.lowered:
                     bounds.append((int(vals.min()), int(vals.max()))
                                   if vals.size else (0, 0))
-            if plan.lowered and not lowering.fits(plan, bounds, dts, n):
-                # the integer form may wrap at the planes' natural
-                # width: try every plane at int64 (the XLA bodies serve
-                # it), else the host pipeline, whose Decimals are exact
-                wide = ["int64" if np.dtype(d).kind == "i" else d
-                        for d in dts]
-                if not lowering.fits(plan, bounds, wide, n):
-                    meta["force_host"] = True
-                    raise _FallbackToHost("lowered DECIMAL arithmetic "
-                                          "not provably inside int64")
-                dts = wide
+            limbs = ()
+            if plan.lowered:
+                limbs = lowering.fit(plan, bounds, dts, n)
+                if limbs is None:
+                    # the integer form may wrap at the planes' natural
+                    # width even with its products summed as limbs: try
+                    # every plane at int64 (the XLA bodies serve it),
+                    # else the host pipeline, whose Decimals are exact
+                    wide = ["int64" if np.dtype(d).kind == "i" else d
+                            for d in dts]
+                    if not lowering.fits(plan, bounds, wide, n):
+                        meta["force_host"] = True
+                        raise _FallbackToHost(
+                            "lowered DECIMAL arithmetic not provably "
+                            "inside int64")
+                    dts, limbs = wide, ()
             memo["dtypes"] = tuple(dts)
+            memo["limbs"] = limbs
             if memo_fresh():
                 meta["dtypes"] = memo["dtypes"]
+                meta["limbs"] = limbs
             return memo["dtypes"]
 
         def plane_pair(pos: int, col, ds: str) -> tuple:
@@ -3030,6 +3100,13 @@ class DeviceRunner:
             vals = col.values
             if plan.date_planes and plan.date_planes[pos]:
                 vals = date_plane(vals)
+            elif plan.code_planes and plan.code_planes[pos]:
+                # (``get_dtypes`` made them for this batch, where it ran)
+                vals = memo.get("code_vals", {}).get(pos)
+                if vals is None:
+                    vals = code_plane(col.values, plan.code_planes[pos])
+                if vals is None:
+                    raise _FallbackToHost("CHAR value without a code")
             return (np.ascontiguousarray(
                 vals.astype(np.dtype(ds), copy=False)),
                 np.ascontiguousarray(col.validity))
@@ -3089,6 +3166,10 @@ class DeviceRunner:
             # device::slice_dead names one of mine
             self._preflight_slice()
             dtypes = get_dtypes()
+            if memo["limbs"]:
+                # this feed's bounds ask for products summed as 16-bit
+                # limbs (lowering.fit): the plan's variant that does
+                plan = self._limb_variant(plan, memo["limbs"])
 
             feed_key = (tuple(plan.scan.columns[ci].col_id
                               for ci in plan.used_cols),
@@ -3266,6 +3347,8 @@ class DeviceRunner:
         meta.pop("host_cols", None)
         meta.pop("sparse_slots", None)
         meta.pop("lane_class", None)    # re-learnt by the next launch
+        meta.pop("key_dense", None)     # (likewise: run_hash)
+        meta.pop("key_dense_tiled", None)
         # (a lowered plan's dtypes stand on ``lowering.fits``'s proof
         # over the columns' BOUNDS, which new rows may leave while
         # still fitting the dtype: derive them again)
@@ -3278,7 +3361,9 @@ class DeviceRunner:
                                             spans)
         if not keep:
             meta.pop("dtypes", None)
+            meta.pop("limbs", None)
             meta.pop("hash_bounds", None)
+            meta.pop("key_bounds", None)
             meta.pop("simple_arg_nbytes", None)
         meta["lineage_v"] = to_v
 
@@ -3330,16 +3415,25 @@ class DeviceRunner:
 
         if "hash_bounds" in meta:
             base, width, arg_nbytes = meta["hash_bounds"]
+            # (a composite key: each key inside its own bounds, and
+            # never NULL)
+            key_bounds = meta.get("key_bounds") \
+                if len(plan.key_rpns) > 1 else ((base, width),)
+            if key_bounds is None:
+                return False
             for span in spans:
                 pairs = span_pairs(span)
                 m = len(span["handles"])
-                kv, km = eval_rpn(plan.key_rpn, pairs, m, np)
-                kv = np.broadcast_to(kv, (m,))
-                km = np.broadcast_to(km, (m,))
-                live = kv[km]
-                if live.size and (int(live.min()) < base or
-                                  int(live.max()) >= base + width):
-                    return False
+                for rpn, (lo, wid) in zip(plan.key_rpns, key_bounds):
+                    kv, km = eval_rpn(rpn, pairs, m, np)
+                    kv = np.broadcast_to(kv, (m,))
+                    km = np.broadcast_to(km, (m,))
+                    if len(key_bounds) > 1 and not km.all():
+                        return False
+                    live = kv[km]
+                    if live.size and (int(live.min()) < lo or
+                                      int(live.max()) >= lo + wid):
+                        return False
             if not arg_planes_ok(arg_nbytes):
                 return False
         if "simple_arg_nbytes" in meta and \

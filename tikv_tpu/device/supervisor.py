@@ -159,7 +159,14 @@ class FlightRecorder:
         # planes cut for feeds (device/lowering.py), by kind
         self.param_launches = 0
         self.const_classes = 0
-        self.planes = {"decimal": 0, "date": 0}
+        self.planes = {"decimal": 0, "date": 0, "code": 0}
+        # what the fused kernel's launches were made of: those whose
+        # GROUP BY had several keys (the composite key), the SUMs they
+        # summed as 16-bit limbs, and the byte planes they contracted
+        # (the kernel's time follows rows x planes)
+        self.composite_key_launches = 0
+        self.limb_sums = 0
+        self.planes_sum = 0
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -170,7 +177,8 @@ class FlightRecorder:
              mesh: str = "", slice_id=None, pinned_bytes: int = 0,
              ok: bool = True, shards: int = 1,
              whole_mesh: bool = False, params: int = 0,
-             slot_mode: str = "") -> dict:
+             slot_mode: str = "", keys: int = 0, planes: int = 0,
+             limb_sums: int = 0) -> dict:
         ck = (klass, key)
         with self._mu:
             first = ck not in self._seen
@@ -188,6 +196,10 @@ class FlightRecorder:
                 self.sharded_launches += 1
             if params:
                 self.param_launches += 1
+            if keys > 1:
+                self.composite_key_launches += 1
+            self.limb_sums += limb_sums
+            self.planes_sum += planes
             entry = {"t_unix_s": round(time.time(), 6),
                      "launch_ms": round(wall_s * 1e3, 3),
                      "compile_class": klass,
@@ -200,6 +212,10 @@ class FlightRecorder:
                      # the Pallas kernel's slot mode ("" elsewhere)
                      "params": int(params),
                      "slot_mode": slot_mode,
+                     # its GROUP BY keys and the byte planes it
+                     # contracted (0 off the fused kernel)
+                     "keys": int(keys),
+                     "planes": int(planes),
                      "ok": ok}
             self._ring.append(entry)
         return entry
@@ -224,7 +240,11 @@ class FlightRecorder:
             return {"param_launches": self.param_launches,
                     "const_classes": self.const_classes,
                     "decimal_planes": self.planes["decimal"],
-                    "date_planes": self.planes["date"]}
+                    "date_planes": self.planes["date"],
+                    "code_planes": self.planes["code"],
+                    "composite_key_launches": self.composite_key_launches,
+                    "limb_sums": self.limb_sums,
+                    "planes_sum": self.planes_sum}
 
     def note_scalar(self, hit: bool) -> None:
         with self._mu:
