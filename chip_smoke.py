@@ -224,6 +224,23 @@ def http_json(port: int, path: str) -> dict:
         return json.loads(r.read())
 
 
+def one_probe_no_walk(checks: Checks, name: str, fp0: dict,
+                      fp1: dict) -> dict:
+    """Over a window of warm reads, ``/health`` ``fastpath`` before and
+    after: every request a hit whose class was found in one ``match``
+    (``find.probes == finds``) and whose DAG arrived with its keys
+    (``keys.walked`` rose by 0).  → what the counters rose by."""
+    d = {k: fp1[a][b] - fp0[a][b] for k, a, b in (
+        ("finds", "find", "finds"), ("probes", "find", "probes"),
+        ("carried", "keys", "carried"), ("walked", "keys", "walked"))}
+    d["hit"] = fp1["hit"] - fp0["hit"]
+    checks.require(
+        f"{name}: found in one probe, no key walked",
+        d["hit"] > 0 and d["finds"] == d["probes"] == d["carried"] ==
+        d["hit"] and d["walked"] == 0, d)
+    return d
+
+
 # ------------------------------------------------------------ the data
 
 
@@ -612,6 +629,7 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
              cold["labels"].get("device_feed")) == want_cold,
             f"{cold['labels']}, want cold_build/device_feed={want_cold}")
         warm = []
+        fp0 = http_json(leg.status_port, "/health")["fastpath"]
         for i in range(6):
             w = leg.request(f"hash_agg warm {i}", hash_agg, CLASS_PALLAS)
             same_rows(f"hash_agg warm {i}", w["resp"]["rows"], want)
@@ -621,10 +639,13 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
             warm.append(w)
         # the warm GROUP BY replies were fast-path hits over int64
         # planes: the native call made their rows, the chain none
-        enc = http_json(leg.status_port, "/health")["fastpath"]["encode"]
+        fp1 = http_json(leg.status_port, "/health")["fastpath"]
+        enc = fp1["encode"]
         checks.require("hash_agg warm: fast-path replies encoded by the "
                        "native call", enc["native"] > 0 and
                        enc["python"] == 0, enc)
+        found = {"hash_agg": one_probe_no_walk(
+            checks, "hash_agg warm", fp0, fp1)}
 
         # -- simple agg --
         def simple_agg():
@@ -685,6 +706,10 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
                 all([-v.as_tuple().exponent for v in r[:4]] == [2, 2, 4, 6]
                     and isinstance(r[5], bytes) and isinstance(r[6], bytes)
                     for r in rows), rows[:1])
+            if i == 0:          # the warm read's window opens here
+                fp0 = http_json(leg.status_port, "/health")["fastpath"]
+        found["q1"] = one_probe_no_walk(checks, "q1 warm", fp0, http_json(
+            leg.status_port, "/health")["fastpath"])
         params = http_json(leg.status_port,
                            "/health")["device_mesh"]["agg_params"]
         checks.on_chip("q1: a composite key, a limb sum, code planes",
@@ -822,6 +847,7 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
         "cold_phases_ms": cold["phases_ms"],
         "warm_p50_s": walls[len(walls) // 2], "warm_n": len(walls),
         "warm_phases_ms": warm[-1]["phases_ms"],
+        "fastpath_warm": found,
         "queries": {
             "hash_agg": family([cold] + warm),
             "simple_agg": family(simple),

@@ -40,6 +40,13 @@ def test_dry_run_passes_on_cpu_and_says_so():
     assert a["rows"] == b["rows"] == 65536
     assert a["cold_labels"]["cold_build"] == "device"
     assert a["queries"]["after_write"]["device_feed"] == "patch"
+    # the warm GROUP BY and Q1-shaped reads: one probe a find, their
+    # DAGs' keys carried, none walked (/health fastpath.find / keys)
+    assert a["fastpath_warm"] == {
+        "hash_agg": {"finds": 6, "probes": 6, "carried": 6, "walked": 0,
+                     "hit": 6},
+        "q1": {"finds": 1, "probes": 1, "carried": 1, "walked": 0,
+               "hit": 1}}
 
 
 def test_without_the_flag_a_cpu_machine_fails_the_platform_check():

@@ -368,6 +368,27 @@ def test_warm_tasks_take_the_compiled_fast_path(store, kind, params):
     assert store.node.fastpath.stats()["hit"] == before + N
 
 
+@pytest.mark.parametrize("name", ["dense", "sparse"])
+def test_warm_tasks_find_in_one_probe_and_walk_no_key(store, kind, params,
+                                                      name):
+    """Six region classes a table, two tables in the cache: a warm
+    task's class is found by its ``context`` in ONE ``match`` and its
+    DAG arrives with its keys (``/health`` ``fastpath.find`` /
+    ``keys``)."""
+    for _ in range(3):
+        read(store, kind, params, name)
+    fp0 = health(store)["fastpath"]
+    for _ in range(3):
+        rec, _resp = read(store, kind, params, name)
+        assert rec["ok"] and rec["labels"].get("fastpath") == "hit"
+    fp1 = health(store)["fastpath"]
+    finds = fp1["find"]["finds"] - fp0["find"]["finds"]
+    assert finds == fp1["hit"] - fp0["hit"] == 3 * N
+    assert fp1["find"]["probes"] - fp0["find"]["probes"] == finds
+    assert fp1["keys"]["carried"] - fp0["keys"]["carried"] == finds
+    assert fp1["keys"]["walked"] == fp0["keys"]["walked"]
+
+
 def test_a_task_on_the_host_fails_the_whole_read(store, kind, params):
     """One task that the runner's host rung served shows in the summary
     (label ``degraded``, phase ``host_exec``), so ``loadgen.py

@@ -380,6 +380,26 @@ def test_a_second_delta_hits_the_fast_path(store, kind, params):
     assert fp1["encode"]["python"] - fp0["encode"]["python"] == N
 
 
+def test_warm_reads_find_in_one_probe_and_walk_no_key(store, kind, params):
+    """The largest plan the cells send (eleven aggregates, a two-column
+    key): a warm task's class is found in ONE ``match`` among the twelve
+    regions', and its DAG arrives with its keys, so neither the handler
+    nor the dispatcher walks that plan again."""
+    for index in (30, 31):
+        read(store, kind, params, index)
+    fp0 = health(store)["fastpath"]
+    for index in (32, 60, 0):
+        rec, _resp = read(store, kind, params, index)
+        assert rec["ok"] and rec["labels"].get("fastpath") == "hit"
+        assert failing(kind.check(store.ctx, [rec], params, None)) == []
+    fp1 = health(store)["fastpath"]
+    finds = fp1["find"]["finds"] - fp0["find"]["finds"]
+    assert finds == fp1["hit"] - fp0["hit"] == 3 * N
+    assert fp1["find"]["probes"] - fp0["find"]["probes"] == finds
+    assert fp1["keys"]["carried"] - fp0["keys"]["carried"] == finds
+    assert fp1["keys"]["walked"] == fp0["keys"]["walked"]
+
+
 def test_a_traced_reply_carries_the_new_span_attributes(store, kind, params,
                                                         table_kind):
     """``decimal_lower`` says what it made structure and what it split,

@@ -432,6 +432,28 @@ def test_a_second_tuple_hits_the_fast_path(store, kind, params):
     assert fp1["learned"] == fp0["learned"]
 
 
+def test_warm_reads_find_in_one_probe_and_walk_no_key(store, kind, params):
+    """Twelve region classes that share every byte up to their ranges:
+    a warm task's class is found by its ``context`` in ONE ``match``,
+    and the DAG it builds arrives with its class's keys, its plan key
+    re-stamped with the tuple's constants: nothing downstream walks the
+    expression tree (``/health`` ``fastpath.find`` / ``keys``)."""
+    for index in (30, 31):
+        read(store, kind, params, index)
+    fp0 = health(store)["fastpath"]
+    for index in (32, 70, 5):
+        rec, _resp = read(store, kind, params, index)
+        assert rec["ok"] and rec["labels"].get("fastpath") == "hit"
+        assert failing(kind.check(store.ctx, [rec], params, None)) == []
+    fp1 = health(store)["fastpath"]
+    finds = fp1["find"]["finds"] - fp0["find"]["finds"]
+    assert finds == fp1["hit"] - fp0["hit"] == 3 * N
+    assert fp1["find"]["probes"] - fp0["find"]["probes"] == finds
+    assert fp1["keys"]["carried"] - fp0["keys"]["carried"] == finds
+    assert fp1["keys"]["walked"] == fp0["keys"]["walked"]
+    assert fp1["classes"] >= N
+
+
 def test_the_template_extracts_the_constants_it_renders(store, kind):
     """The wire template of one tuple matches another's bytes, extracts
     its five constants, and renders them back to the same bytes."""

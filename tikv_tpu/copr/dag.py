@@ -131,15 +131,7 @@ class DAGRequest:
 
     def plan_key(self) -> tuple:
         """Hashable plan identity for the device-kernel jit cache."""
-        def expr_key(e: Expr):
-            if e.kind == "const":
-                return ("c", e.value, e.eval_type.value if e.eval_type else None)
-            if e.kind == "column":
-                return ("col", e.col_idx,
-                        e.eval_type.value if e.eval_type else None)
-            return ("f", e.sig, tuple(expr_key(c) for c in e.children))
-
-        return self._plan_parts(expr_key)
+        return self._key("_plan_key", _plan_expr_key)
 
     def class_key(self) -> tuple:
         """Const-blind COMPILE-CLASS identity: ``plan_key`` with numeric
@@ -154,21 +146,41 @@ class DAGRequest:
         coalescer group requests that are batchable into one dispatch.
         A constant crossing the int32/int64 boundary is a genuine new
         trace and keys separately."""
-        def expr_key(e: Expr):
-            if e.kind == "const":
-                v = e.value
-                bucket = const_bucket(v)
-                if bucket is None:
-                    return ("c", repr(v),
-                            e.eval_type.value if e.eval_type else None)
-                return ("c?", bucket,
-                        e.eval_type.value if e.eval_type else None)
-            if e.kind == "column":
-                return ("col", e.col_idx,
-                        e.eval_type.value if e.eval_type else None)
-            return ("f", e.sig, tuple(expr_key(c) for c in e.children))
+        return self._key("_class_key", _class_expr_key)
 
-        return self._plan_parts(expr_key)
+    def _key(self, name: str, expr_key) -> tuple:
+        """A key is walked off the expression tree once a DAG and kept
+        beside the fields, in ``__dict__``, where ``==``, ``hash`` and
+        ``repr`` (the fields alone) do not see it; ``carry_keys`` puts
+        one there that was never walked."""
+        memo = self.__dict__
+        key = memo.get(name)
+        if key is None:
+            key = memo[name] = self._plan_parts(expr_key)
+            walked = memo.get("_on_walk")
+            if walked is not None:
+                walked()
+        return key
+
+    def carry_keys(self, class_key: tuple, plan_key: tuple,
+                   on_walk=None) -> "DAGRequest":
+        """Hand this DAG the keys its builder already holds (a fast-path
+        hit: server/fastpath.py, which proves them equal to the walked
+        ones when it learns the class).  ``on_walk`` is called if a key
+        is walked all the same, on this DAG or one ``over_ranges`` made
+        of it."""
+        self.__dict__.update(_class_key=class_key, _plan_key=plan_key,
+                             _on_walk=on_walk)
+        return self
+
+    def over_ranges(self, ranges: tuple) -> "DAGRequest":
+        """The same plan over other ranges, with the keys this one has
+        (no key reads the ranges)."""
+        dag = DAGRequest(self.executors, ranges, self.start_ts,
+                         self.output_offsets, self.encode_type)
+        dag.__dict__.update(
+            (k, v) for k, v in self.__dict__.items() if k[0] == "_")
+        return dag
 
     def _plan_parts(self, expr_key) -> tuple:
         parts = []
@@ -200,3 +212,27 @@ class DAGRequest:
             elif isinstance(ex, LimitDesc):
                 parts.append(("limit", ex.limit))
         return tuple(parts) + (self.output_offsets,)
+
+
+def _column_key(e: Expr) -> tuple:
+    return ("col", e.col_idx, e.eval_type.value if e.eval_type else None)
+
+
+def _plan_expr_key(e: Expr) -> tuple:
+    if e.kind == "const":
+        return ("c", e.value, e.eval_type.value if e.eval_type else None)
+    if e.kind == "column":
+        return _column_key(e)
+    return ("f", e.sig, tuple(_plan_expr_key(c) for c in e.children))
+
+
+def _class_expr_key(e: Expr) -> tuple:
+    if e.kind == "const":
+        v = e.value
+        bucket = const_bucket(v)
+        if bucket is None:
+            return ("c", repr(v), e.eval_type.value if e.eval_type else None)
+        return ("c?", bucket, e.eval_type.value if e.eval_type else None)
+    if e.kind == "column":
+        return _column_key(e)
+    return ("f", e.sig, tuple(_class_expr_key(c) for c in e.children))

@@ -504,8 +504,8 @@ class Endpoint:
         return CopDeferred(self, req, storage, tag, t0, backend,
                            future=fut)
 
-    def handle_async_fast(self, req: CopRequest, storage, ent,
-                          consts) -> "CopDeferred":
+    def handle_async_fast(self, req: CopRequest, storage,
+                          ent) -> "CopDeferred":
         """Fast-path dispatch (server/fastpath.py): the decode products
         are pre-bound on the class entry ``ent`` and ``storage`` is the
         already-validated warm columnar snapshot — no provider walk, no
@@ -532,7 +532,7 @@ class Endpoint:
                 bkey = None
                 if coal.enabled:
                     bkey = ent.bkey if ent.share_fill is None \
-                        else ent.share_fill(consts)
+                        else ent.share_fill(req.dag.plan_key())
                 decision, bkey, hint = coal.router.route_fast(
                     ent.n_est, ent.d2h_bytes, bkey)
                 if decision == "shed":

@@ -2961,8 +2961,7 @@ class DeviceRunner:
                 tile_spans = tuple(spans)
                 # feed/meta keyed WITHOUT ranges: every tiled request
                 # over this snapshot shares one region feed
-                dag = DAGRequest(dag.executors, (), dag.start_ts,
-                                 dag.output_offsets, dag.encode_type)
+                dag = dag.over_ranges(())
 
         meta = self._request_meta(storage, self._meta_key(dag, plan))
         lineage = getattr(storage, "feed_lineage", None)

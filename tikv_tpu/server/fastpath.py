@@ -72,6 +72,7 @@ PlanRequest with the TSO re-stamped; constants are class identity).
 
 from __future__ import annotations
 
+import dataclasses
 import struct
 import threading
 from collections import OrderedDict
@@ -529,72 +530,89 @@ def _dag_const_substituter(dag) -> Callable:
     """Precompiled per-class DAG constructor: → make_dag(consts,
     start_ts) rebuilding only the executor subtrees that hold rotating
     constants (everything else — columns, ranges, offsets — is shared
-    with the learned template object).
+    with the learned template object).  Which nodes those are is a
+    property of the class and is decided here, once: each expression
+    compiles to itself (shared) or to a builder of its path down to
+    the constants, so a hit asks ``const_bucket`` nothing.
 
     The substitution order is the same DFS the wire walk uses
     (executors in order, conditions/exprs/aggs/order keys in the
     enc_dag field order), and learn() verifies it by equality against
     the slow path's decoded DAG."""
-    import dataclasses
-
     from ..copr.dag import (
-        AggExprDesc, AggregationDesc, PartitionTopNDesc, ProjectionDesc,
-        SelectionDesc, TopNDesc,
+        AggExprDesc, AggregationDesc, DAGRequest, PartitionTopNDesc,
+        ProjectionDesc, SelectionDesc, TopNDesc,
     )
     from ..expr import Expr
 
-    def has_const(e) -> bool:
+    def expr(e):
+        """``e`` itself, or build(it) → ``e`` with its rotating
+        constants drawn from ``it``."""
         if e.kind == "const":
-            return const_bucket(e.value) is not None
-        return any(has_const(c) for c in e.children)
+            if const_bucket(e.value) is None:
+                return e
+            et = e.eval_type
+            return lambda it: Expr(kind="const", value=next(it),
+                                   eval_type=et)
+        subs = exprs(e.children)
+        if not callable(subs):
+            return e
+        rest = {f.name: getattr(e, f.name) for f in dataclasses.fields(e)
+                if f.name != "children"}
+        return lambda it: Expr(children=subs(it), **rest)
 
-    def sub_expr(e, it):
-        if e.kind == "const":
-            if const_bucket(e.value) is not None:
-                return Expr(kind="const", value=next(it),
-                            eval_type=e.eval_type)
-            return e
-        if e.kind == "column" or not has_const(e):
-            return e
-        return dataclasses.replace(
-            e, children=tuple(sub_expr(c, it) for c in e.children))
+    def exprs(es):
+        """The tuple ``es`` itself, or build(it) → its members rebuilt."""
+        subs = tuple(expr(e) for e in es)
+        if not any(callable(s) for s in subs):
+            return es
+        return lambda it: tuple(
+            s(it) if callable(s) else s for s in subs)
+
+    def fill(sub, it):
+        return sub(it) if callable(sub) else sub
+
+    def ordered(order_by):
+        subs = exprs([e for e, _ in order_by])
+        if not callable(subs):
+            return order_by
+        descs = tuple(d for _, d in order_by)
+        return lambda it: tuple(zip(subs(it), descs))
 
     builders = []
     for ex in dag.executors:
-        if isinstance(ex, SelectionDesc) and \
-                any(has_const(c) for c in ex.conditions):
-            builders.append(lambda it, ex=ex: SelectionDesc(
-                tuple(sub_expr(c, it) for c in ex.conditions)))
-        elif isinstance(ex, ProjectionDesc) and \
-                any(has_const(e) for e in ex.exprs):
-            builders.append(lambda it, ex=ex: ProjectionDesc(
-                tuple(sub_expr(e, it) for e in ex.exprs)))
-        elif isinstance(ex, AggregationDesc) and (
-                any(has_const(e) for e in ex.group_by) or
-                any(a.arg is not None and has_const(a.arg)
-                    for a in ex.aggs)):
-            builders.append(lambda it, ex=ex: AggregationDesc(
-                tuple(sub_expr(e, it) for e in ex.group_by),
-                tuple(AggExprDesc(a.kind, sub_expr(a.arg, it)
-                                  if a.arg is not None else None)
-                      for a in ex.aggs), ex.streamed))
-        elif isinstance(ex, TopNDesc) and \
-                any(has_const(e) for e, _ in ex.order_by):
-            builders.append(lambda it, ex=ex: TopNDesc(
-                tuple((sub_expr(e, it), d) for e, d in ex.order_by),
-                ex.limit))
-        elif isinstance(ex, PartitionTopNDesc) and (
-                any(has_const(e) for e in ex.partition_by) or
-                any(has_const(e) for e, _ in ex.order_by)):
-            builders.append(lambda it, ex=ex: PartitionTopNDesc(
-                tuple(sub_expr(e, it) for e in ex.partition_by),
-                tuple((sub_expr(e, it), d) for e, d in ex.order_by),
-                ex.limit))
-        else:
-            builders.append(ex)     # shared verbatim
+        built = ex                      # shared verbatim
+        if isinstance(ex, SelectionDesc):
+            conds = exprs(ex.conditions)
+            if callable(conds):
+                built = lambda it, c=conds: SelectionDesc(c(it))
+        elif isinstance(ex, ProjectionDesc):
+            out = exprs(ex.exprs)
+            if callable(out):
+                built = lambda it, c=out: ProjectionDesc(c(it))
+        elif isinstance(ex, AggregationDesc):
+            group = exprs(ex.group_by)
+            args = [None if a.arg is None else expr(a.arg)
+                    for a in ex.aggs]
+            if callable(group) or any(callable(a) for a in args):
+                aggs = tuple(zip(ex.aggs, args))
+                built = lambda it, ex=ex, g=group, aggs=aggs: \
+                    AggregationDesc(
+                        fill(g, it),
+                        tuple(AggExprDesc(a.kind, s(it)) if callable(s)
+                              else a for a, s in aggs), ex.streamed)
+        elif isinstance(ex, TopNDesc):
+            order = ordered(ex.order_by)
+            if callable(order):
+                built = lambda it, ex=ex, o=order: TopNDesc(o(it), ex.limit)
+        elif isinstance(ex, PartitionTopNDesc):
+            part, order = exprs(ex.partition_by), ordered(ex.order_by)
+            if callable(part) or callable(order):
+                built = lambda it, ex=ex, p=part, o=order: \
+                    PartitionTopNDesc(fill(p, it), fill(o, it), ex.limit)
+        builders.append(built)
 
     ranges, offsets, enc = dag.ranges, dag.output_offsets, dag.encode_type
-    from ..copr.dag import DAGRequest
 
     def make(consts, start_ts: int) -> DAGRequest:
         it = iter(consts)
@@ -664,10 +682,11 @@ class _ClassEntry:
         "trace_class", "range_start", "resource_group",
         "request_source", "tag", "key_hint", "ranges", "base_key",
         "storage_ref", "config_gen", "bkey", "share_fill", "n_est",
-        "d2h_bytes", "hits", "invalidated", "region_ctx")
+        "d2h_bytes", "hits", "last_hit", "invalidated", "region_ctx")
 
     def __init__(self):
         self.hits = 0
+        self.last_hit = 0           # the cache's tick at its last find
         self.invalidated = None     # reason str once dead
         self.tier = "dispatch"
         self.make_plan = None
@@ -688,20 +707,32 @@ class FastPathCache:
     """Bounded per-class template cache (one per node).
 
     ``find(raw)`` → (entry, values) on a byte-level hit; ``learn()``
-    admits a class from a slow-path execution.  Entries live in ONE
-    move-to-front list: every TableScan request shares its first ~26
-    wire bytes (map header, "tp", "dag", "execs", "tscan" — the
-    discriminating table/columns/ranges bytes come later, and a
-    selection's first rotating constant can come early), so no fixed
-    byte prefix discriminates classes reliably; a linear walk with
-    fail-fast ``seg0`` comparison (templates diverge within a few
-    dozen bytes) costs single-digit µs at the capacity bound, and the
-    move-to-front keeps the hottest class first."""
+    admits a class from a slow-path execution.  Every TableScan request
+    shares its first ~26 wire bytes and a selection's rotating constants
+    come early, so no byte PREFIX tells classes apart; the END of the
+    request does: a template's last fixed segment holds whatever follows
+    its last slot, which for a fan-out task is its ``context`` (region
+    and epoch, the request's last key).  Entries are indexed by that
+    segment, and ``find`` tries only the classes whose segment ends
+    ``raw``, the most recently hit first: one dict probe a distinct
+    segment length (one or two), then one whole ``WireTemplate.match``
+    (every fixed segment compared, every slot parsed and guarded) a
+    candidate, which is what a find costs: the match of its own class,
+    ~20 us on a five-constant DECIMAL selection, however many classes
+    the cache holds.  Classes with one last segment (same region,
+    other plans or tenants) are walked among themselves.  The index
+    chooses who is tried, never what counts as a hit.  ``stats()``
+    ``find`` counts both: ``probes`` (matches tried) over ``finds``."""
 
     def __init__(self, capacity: int = 64):
         self.capacity = max(0, int(capacity))
         self._mu = threading.Lock()
-        self._entries: list = []        # front = most recently hit
+        self._entries: list = []
+        # the index: (last fixed segment → its classes, the distinct
+        # lengths of those segments), replaced whole under ``_mu``
+        # (``_reindex``), so ``find`` reads one pair unlocked
+        self._index: tuple = ({}, ())
+        self._tick = 0                  # recency clock: ``ent.last_hit``
         # negative cache: compile classes whose learn attempt was
         # rejected (non-canonical client encoding, unsupported shape)
         # — without it every request of such a class would repay the
@@ -717,6 +748,13 @@ class FastPathCache:
         self.fallback = 0
         self.learned = 0
         self.reasons: dict = {}
+        # lookups, and the ``WireTemplate.match`` calls they made
+        self.finds = 0
+        self.probes = 0
+        # hits whose DAG left with its class's keys (``make_dag``), and
+        # the keys such a DAG had walked off its expression tree anyway
+        self.keys_carried = 0
+        self.keys_walked = 0
         # replies encoded (encode_response), by what made the rows: the
         # one native call over the result's planes, or the Python chain
         self.encode_native = 0
@@ -726,11 +764,12 @@ class FastPathCache:
     def enabled(self) -> bool:
         return self.capacity > 0
 
-    def _note(self, outcome: str, reason: str) -> None:
+    def _note(self, outcome: str, reason: str, carried: int = 0) -> None:
         with self._mu:
             setattr(self, outcome, getattr(self, outcome) + 1)
             k = f"{outcome}:{reason}"
             self.reasons[k] = self.reasons.get(k, 0) + 1
+            self.keys_carried += carried
         _count(outcome, reason)
 
     # ------------------------------------------------------------ lookup
@@ -750,32 +789,49 @@ class FastPathCache:
             return None, "failpoint"
         if not self.enabled:
             return None, "disabled"
-        with self._mu:
-            cands = list(self._entries)
-            gen = self.config_gen
-        for ent in cands:
-            if ent.invalidated is not None:
+        (by_tail, lens), gen, size = self._index, self.config_gen, len(raw)
+        cands = ()
+        for n in lens:
+            got = by_tail.get(raw[size - n:]) if n <= size else None
+            if got:
+                cands = cands + got if cands else got
+        if len(cands) > 1:
+            cands = sorted(cands, key=lambda e: -e.last_hit)
+        probes, ent, values = 0, None, None
+        for cand in cands:
+            if cand.invalidated is not None:
                 continue
-            if ent.config_gen != gen:
-                self.drop(ent, "config")
+            if cand.config_gen != gen:
+                self.drop(cand, "config")
                 continue
-            values = ent.template.match(raw)
+            probes += 1
+            values = cand.template.match(raw)
             if values is not None:
-                with self._mu:
-                    # move-to-front: the hottest class matches first,
-                    # and the capacity bound evicts the COLDEST
-                    try:
-                        self._entries.remove(ent)
-                        self._entries.insert(0, ent)
-                    except ValueError:      # raced an evict — fine
-                        pass
+                ent = cand
+                break
+        with self._mu:
+            self.finds += 1
+            self.probes += probes
+            if ent is not None:
+                # the capacity bound evicts the COLDEST
+                self._tick += 1
+                ent.last_hit = self._tick
                 return ent, values
         self._note("miss", "no_template" if not cands else "mismatch")
         return None, "mismatch"
 
+    def _reindex(self) -> None:
+        """(under ``_mu``)"""
+        by_tail: dict = {}
+        for e in self._entries:
+            tail = e.template.segments[-1]
+            by_tail[tail] = by_tail.get(tail, ()) + (e,)
+        self._index = by_tail, tuple({len(t) for t in by_tail})
+
     def _corrupt_one(self) -> None:
         with self._mu:
-            ent = self._entries[0] if self._entries else None
+            ent = max(self._entries, key=lambda e: e.last_hit,
+                      default=None)
         if ent is None:
             return
         segs = ent.template.segments
@@ -829,26 +885,9 @@ class FastPathCache:
             self._reject(reject_key)
             return False
         try:
-            marked, n_const = _mark_slots(req)
-            segments, slots = _encode_segments(marked)
-            template = WireTemplate(segments, slots)
-            # self-validation 1: byte-exact render round trip — the
-            # template's encoder agrees with the client's msgpack for
-            # THIS shape, or the class never fast-paths
-            orig = _slot_originals(slots, req, "dag")
-            if template.render(orig) != raw:
-                raise _Ineligible("render mismatch")
-            make_dag = _dag_const_substituter(dag)
-            # self-validation 2: the constructor rebuilds the decoded
-            # DAG exactly from the wire-extracted values
-            consts = [v for s, v in zip(slots, orig) if s.kind == K_CONST]
-            if make_dag(consts, dag.start_ts) != dag:
-                raise _Ineligible("constructor mismatch")
+            template, make_dag, plan_key = self._compile(raw, req, dag)
         except Exception as e:   # noqa: BLE001 — ineligible, never fatal
-            reason = e.args[0] if isinstance(e, _Ineligible) and e.args \
-                else "learn_error"
-            self._note("bypass", str(reason)[:40])
-            self._reject(reject_key)
+            self._ineligible(e, reject_key)
             return False
 
         ent = _ClassEntry()
@@ -885,15 +924,17 @@ class FastPathCache:
         nested = bkey[2] if head == "slice" and len(bkey) > 2 else None
         if "share" in (head, nested):
             # ("share", ...) / slice-share keys embed the const-
-            # SENSITIVE plan_key — pre-compile the const re-stamping
-            # so a hit never walks the expr tree to rebuild it
-            fill, n = _key_template(bkey)
-            if n != n_const:
-                # const order/coverage disagreement — never guess
+            # SENSITIVE plan_key, three places behind "share": a hit
+            # puts the one its DAG carries there (``make_dag`` filled
+            # it), so the key is filled once and the tree never walked
+            at = bkey.index("share") + 3
+            if len(bkey) <= at or bkey[at] != plan_key:
+                # not the key this class fills — never guess
                 self._note("bypass", "share_key_shape")
                 self._reject(reject_key)
                 return False
-            ent.share_fill = fill
+            ent.share_fill = lambda key, head=bkey[:at], \
+                tail=bkey[at + 1:]: head + (key,) + tail
         elif bkey is not None and "stack" not in (head, nested):
             # unknown key shape: reusing it verbatim could group
             # mismatched kernels — stay on the full decode path
@@ -905,6 +946,60 @@ class FastPathCache:
         self._admit(ent)
         return True
 
+    def _compile(self, raw: bytes, req: dict, dag) -> tuple:
+        """What a DAG class fixes, computed once → (template, make_dag,
+        plan_key), or ``_Ineligible`` by a self-validation."""
+        marked, n_const = _mark_slots(req)
+        segments, slots = _encode_segments(marked)
+        template = WireTemplate(segments, slots)
+        # self-validation 1: byte-exact render round trip — the
+        # template's encoder agrees with the client's msgpack for
+        # THIS shape, or the class never fast-paths
+        orig = _slot_originals(slots, req, "dag")
+        if template.render(orig) != raw:
+            raise _Ineligible("render mismatch")
+        build = _dag_const_substituter(dag)
+        # self-validation 2: the constructor rebuilds the decoded
+        # DAG exactly from the wire-extracted values
+        consts = [v for s, v in zip(slots, orig) if s.kind == K_CONST]
+        if build(consts, dag.start_ts) != dag:
+            raise _Ineligible("constructor mismatch")
+        # the keys a hit's DAG arrives with: the class key as learned
+        # (const-blind, and a hit's constants passed their buckets'
+        # guards), the plan key re-stamped with the hit's constants
+        walked = dataclasses.replace(dag)       # no memo: walked afresh
+        class_key, plan_key = walked.class_key(), walked.plan_key()
+        fill, n = _key_template(plan_key)
+        note_walk = self._note_key_walk
+
+        def make_dag(consts, start_ts: int):
+            return build(consts, start_ts).carry_keys(
+                class_key, fill(consts), note_walk)
+
+        # self-validation 3: the carried keys are the walked ones
+        made = make_dag(consts, dag.start_ts)
+        if n != n_const or made.class_key() != class_key or \
+                made.plan_key() != plan_key:
+            raise _Ineligible("key mismatch")
+        return template, make_dag, plan_key
+
+    def _ineligible(self, e: Exception, reject_key) -> None:
+        reason = e.args[0] if isinstance(e, _Ineligible) and e.args \
+            else "learn_error"
+        self._note("bypass", str(reason)[:40])
+        self._reject(reject_key)
+
+    def _note_key_walk(self) -> None:
+        with self._mu:
+            self.keys_walked += 1
+
+    def _trim(self) -> None:
+        """(under ``_mu``) hold the capacity bound, the coldest out."""
+        if len(self._entries) > self.capacity:
+            self._entries.sort(key=lambda e: -e.last_hit)
+            del self._entries[self.capacity:]
+        self._reindex()
+
     def _admit(self, ent: _ClassEntry) -> None:
         with self._mu:
             # retire dead entries and any template this one SUPERSEDES
@@ -915,13 +1010,15 @@ class FastPathCache:
             # two regions/tenants is two distinct templates that must
             # coexist, not mutually evict.
             kinds = [s.kind for s in ent.template.slots]
-            self._entries[:] = [
+            self._entries = [
                 e for e in self._entries
                 if e.invalidated is None and not (
                     e.template.segments == ent.template.segments and
                     [s.kind for s in e.template.slots] == kinds)]
-            self._entries.insert(0, ent)
-            del self._entries[self.capacity:]
+            self._tick += 1
+            ent.last_hit = self._tick
+            self._entries.append(ent)
+            self._trim()
             self.learned += 1
         _count("learn", "ok")
 
@@ -950,22 +1047,9 @@ class FastPathCache:
         serving ceremony with only the wire decode hoisted."""
         dag = info["dag"]
         try:
-            marked, _ = _mark_slots(req)
-            segments, slots = _encode_segments(marked)
-            template = WireTemplate(segments, slots)
-            orig = _slot_originals(slots, req, "dag")
-            if template.render(orig) != raw:
-                raise _Ineligible("render mismatch")
-            make_dag = _dag_const_substituter(dag)
-            consts = [v for s, v in zip(slots, orig)
-                      if s.kind == K_CONST]
-            if make_dag(consts, dag.start_ts) != dag:
-                raise _Ineligible("constructor mismatch")
+            template, make_dag, _ = self._compile(raw, req, dag)
         except Exception as e:   # noqa: BLE001 — ineligible, never fatal
-            reason = e.args[0] if isinstance(e, _Ineligible) and e.args \
-                else "learn_error"
-            self._note("bypass", str(reason)[:40])
-            self._reject(reject_key)
+            self._ineligible(e, reject_key)
             return False
         ent = _ClassEntry()
         ent.tier = "decode"
@@ -995,8 +1079,6 @@ class FastPathCache:
             orig = _slot_originals(slots, req, "plan")
             if template.render(orig) != raw:
                 raise _Ineligible("render mismatch")
-            import dataclasses
-
             def make_plan(start_ts: int, preq=preq):
                 return dataclasses.replace(preq, start_ts=start_ts)
 
@@ -1005,10 +1087,7 @@ class FastPathCache:
             if make_plan(preq.start_ts) != preq:
                 raise _Ineligible("constructor mismatch")
         except Exception as e:   # noqa: BLE001 — ineligible, never fatal
-            reason = e.args[0] if isinstance(e, _Ineligible) and e.args \
-                else "learn_error"
-            self._note("bypass", str(reason)[:40])
-            self._reject(reject_key)
+            self._ineligible(e, reject_key)
             return False
         ent = _ClassEntry()
         ent.tier = "plan"
@@ -1055,7 +1134,7 @@ class FastPathCache:
 
     def note_hit(self, ent: _ClassEntry) -> None:
         ent.hits += 1
-        self._note("hit", "ok")
+        self._note("hit", "ok", carried=ent.make_dag is not None)
 
     def note_encode(self, native: bool) -> None:
         with self._mu:
@@ -1068,7 +1147,7 @@ class FastPathCache:
         with self._mu:
             if capacity is not None:
                 self.capacity = max(0, int(capacity))
-                del self._entries[self.capacity:]
+                self._trim()
 
     # -------------------------------------------------------------- stats
 
@@ -1090,6 +1169,9 @@ class FastPathCache:
                 "hit_rate": round(self.hit / total, 4) if total else 0.0,
                 "config_gen": self.config_gen,
                 "reasons": dict(self.reasons),
+                "find": {"finds": self.finds, "probes": self.probes},
+                "keys": {"carried": self.keys_carried,
+                         "walked": self.keys_walked},
                 "encode": {
                     "native": self.encode_native,
                     "python": self.encode_python,

@@ -295,8 +295,7 @@ class KvService:
                     tracker.label("fastpath", "fallback")
                     return self.endpoint.handle_async(creq)
                 fp.note_hit(ent)
-                return self.endpoint.handle_async_fast(creq, got, ent,
-                                                       consts)
+                return self.endpoint.handle_async_fast(creq, got, ent)
 
             dl_tok = dl_mod.install(dl) if dl is not None else None
             resp = None
