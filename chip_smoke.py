@@ -323,6 +323,50 @@ def ref_q1(d: dict) -> list:
     return sorted(out, key=lambda r: r[-2:])
 
 
+# TPC-H Q15's shape (benchmark/requests/tpch_q15.py): GROUP BY a BIGINT
+# key of 10,000 values (a grid of 16,384 slots on the one Pallas body,
+# sixteen times Q1's), one DECIMAL product, a window of two dates, the
+# reply asked as a chunk.  Days as ``q1_data``'s; the window is
+# [1996-06-01, 1996-06-15).
+Q15_SUPPLIERS = 10_000
+Q15_WINDOW = ((1996, 6, 1), (1996, 6, 15))
+
+
+def q15_table():
+    from tikv_tpu.datatype import FieldType, FieldTypeFlag, FieldTypeTp
+    from tikv_tpu.testing.fixture import Table, TableColumn
+    nn = FieldTypeFlag.NOT_NULL
+    dec = FieldType(tp=FieldTypeTp.NEW_DECIMAL, flag=nn, flen=15, decimal=2)
+    return Table(TABLE_ID + 3, (
+        TableColumn("id", 1, FieldType.long(not_null=True),
+                    is_pk_handle=True),
+        TableColumn("supp", 2, FieldType.long(not_null=True)),
+        TableColumn("price", 3, dec), TableColumn("disc", 4, dec),
+        TableColumn("ship", 5, FieldType(tp=FieldTypeTp.DATE, flag=nn))))
+
+
+def q15_data(seed: int, n: int) -> dict:
+    rng = np.random.default_rng([seed, 15])
+    supp = rng.integers(1, Q15_SUPPLIERS + 1, n)
+    supp[:2] = 1, Q15_SUPPLIERS
+    return {"supp": supp,
+            "price": rng.integers(1, 51, n) * rng.integers(90000, 209900, n),
+            "disc": rng.integers(0, 11, n),
+            "year": rng.integers(1995, 1999, n),
+            "day": rng.integers(1, 29, n)}
+
+
+def ref_q15(d: dict) -> list:
+    """Sorted [revenue x 10^4, supplier] rows: numpy and Python ints."""
+    keep = (d["year"] == 1996) & (d["day"] < Q15_WINDOW[1][2])
+    rev = d["price"].astype(np.int64) * (100 - d["disc"])
+    sums = np.zeros(Q15_SUPPLIERS + 1, np.int64)
+    np.add.at(sums, d["supp"][keep], rev[keep])
+    seen = np.zeros(Q15_SUPPLIERS + 1, np.bool_)
+    seen[d["supp"][keep]] = True
+    return sorted([int(sums[k]), int(k)] for k in np.nonzero(seen)[0])
+
+
 # ---------------------------------------------------------- phase A
 
 
@@ -436,11 +480,7 @@ class ServedLeg:
         """``q1_data``'s rows through the native SST encoder's DECIMAL
         and bytes column kinds (benchmark/tables/lineitem_presplit.py's
         shape)."""
-        from tikv_tpu.codec.keys import table_record_key
-        from tikv_tpu.sst_importer import (
-            bytes_column, decimal_column, fast_mvcc_table_sst,
-        )
-        c = self.client
+        from tikv_tpu.sst_importer import bytes_column, decimal_column
         n = len(d["qty"])
 
         def chars(idx, texts):      # one byte a row
@@ -455,12 +495,30 @@ class ServedLeg:
                  (7, chars(d["status"], Q1_STATUS), None),
                  (8, (d["year"].astype(np.int64) << 50) | (6 << 46) |
                   (d["day"].astype(np.int64) << 41), None)]
+        self._ingest(table, n, cols)
+
+    def _ingest(self, table, n: int, cols: list) -> None:
+        """``cols`` (the native SST encoder's column triples) as ``n``
+        rows of ``table`` at handles 0..n-1, in one ImportSST."""
+        from tikv_tpu.codec.keys import table_record_key
+        from tikv_tpu.sst_importer import fast_mvcc_table_sst
+        c = self.client
         c.import_switch_mode(self.store_id, True)
         c.ingest_sst(fast_mvcc_table_sst(
             table.table_id, np.arange(n, dtype=np.int64), cols,
             commit_ts=c.tso()), table_record_key(table.table_id, 0),
             chunk=2 << 20, timeout=300)
         c.import_switch_mode(self.store_id, False)
+
+    def load_q15(self, table, d: dict) -> None:
+        """``q15_data``'s rows, as ``load_q1`` loads Q1's."""
+        from tikv_tpu.sst_importer import decimal_column
+        self._ingest(table, len(d["supp"]), [
+            (2, d["supp"].astype(np.int64), None),
+            (3, decimal_column(d["price"].astype(np.int64), 2), None),
+            (4, decimal_column(d["disc"].astype(np.int64), 2), None),
+            (5, (d["year"].astype(np.int64) << 50) | (6 << 46) |
+             (d["day"].astype(np.int64) << 41), None)])
 
     def request(self, name: str, send, classes, backend="device",
                 spans=()) -> dict:
@@ -594,6 +652,9 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
         q1_t = q1_table()
         qd = q1_data(args.seed, min(n, Q1_ROWS))
         leg.load_q1(q1_t, qd)
+        q15_t = q15_table()
+        q15d = q15_data(args.seed, min(n, Q1_ROWS))
+        leg.load_q15(q15_t, q15d)
         log(f"loaded {n} rows in {load_s:.1f}s")
 
         def select():
@@ -716,6 +777,65 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
                        params["composite_key_launches"] >= 2 and
                        params["limb_sums"] >= 2 and
                        params["code_planes"] >= 2, params)
+
+        # -- TPC-H Q15's shape: a key span over 4,096 on the one Pallas
+        #    body (a grid of 16,384 slots, fewer rows a step), the reply
+        #    a chunk whose DECIMAL stays its scaled plane (PR 40) --
+        def q15(chunk=True, backend=None):
+            import dataclasses
+            import decimal
+            s = DagSelect.from_table(q15_t, ["supp", "price", "disc",
+                                             "ship"])
+
+            def day(ymd):
+                y, m, d = ymd
+                return Expr.const((y << 50) | (m << 46) | (d << 41),
+                                  EvalType.DATETIME)
+
+            dag = s.where(
+                Expr.call("GeTime", s.col("ship"), day(Q15_WINDOW[0])),
+                Expr.call("LtTime", s.col("ship"), day(Q15_WINDOW[1])),
+            ).aggregate([s.col("supp")], [("sum", Expr.call(
+                "MultiplyDecimal", s.col("price"),
+                Expr.call("MinusDecimal", Expr.const(
+                    decimal.Decimal(1), EvalType.DECIMAL),
+                    s.col("disc"))))]).build(start_ts=c.tso())
+            if chunk:
+                dag = dataclasses.replace(dag, encode_type="chunk")
+            return c.coprocessor(dag, force_backend=backend, timeout=900)
+
+        from tikv_tpu.server import wire
+        want_q15 = ref_q15(q15d)
+        host_q15 = sorted(q15(chunk=False, backend="host")["rows"],
+                          key=lambda r: r[1])
+        checks.require("q15: the host pipeline equals the numpy reference",
+                       [[int(v.scaleb(4)), k] for v, k in host_q15] ==
+                       sorted(want_q15, key=lambda r: r[1]) and
+                       len(host_q15) > 4096, host_q15[:1])
+        q15s = []
+        for i in range(2):      # cold (the kernel's build), then warm
+            q15s.append(leg.request(f"q15 {i}", q15, CLASS_PALLAS))
+            resp = q15s[-1]["resp"]
+            cols = resp.get("chunk", {}).get("cols", [{}, {}])
+            checks.require(
+                f"q15 {i}: the reply is a chunk, the sum an int64 plane "
+                f"at scale 4",
+                "rows" not in resp and [
+                    (col.get("t"), col.get("frac")) for col in cols] ==
+                [("i8", 4), ("i8", None)], {k: v for k, v in resp.items()
+                                            if k in ("rows", "backend")})
+            got_q15 = sorted(wire.chunk_rows(resp["chunk"]),
+                             key=lambda r: r[1])
+            checks.require(f"q15 {i}: the chunk's rows equal the host "
+                           f"pipeline's", got_q15 == host_q15,
+                           lambda: f"{got_q15[:1]} vs {host_q15[:1]}")
+        health = http_json(leg.status_port, "/health")
+        params = health["device_mesh"]["agg_params"]
+        checks.on_chip("q15: two launches over a grid of 16,384 slots",
+                       params["slots_sum"] >= 2 * 16384, params)
+        checks.require("q15: two chunk replies counted",
+                       health["coprocessor"]["replies"]["chunk"] >= 2,
+                       health["coprocessor"])
 
         # -- selection (2%: a unary gRPC response is capped at 4 MB by
         #    the client's default, ~350k rows of this table) --
@@ -852,6 +972,7 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
             "hash_agg": family([cold] + warm),
             "simple_agg": family(simple),
             "q1": family(q1s),
+            "q15": family(q15s),
             "selection": {**family(sel), "rows": len(want_sel),
                           "routing": sel[-1]["labels"].get("routing")},
             "topn": family(top),

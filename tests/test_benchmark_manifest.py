@@ -150,3 +150,20 @@ def test_the_program_has_the_capability_the_benchmark_asks_for(module, name):
 def test_the_q1_cell_asks_for_code_planes_by_that_name():
     assert ("tikv_tpu.datatype.tile", "code_plane") in \
         capabilities_the_benchmark_asks_for()
+
+
+def test_the_q15_cell_asks_for_the_chunk_decoder_and_the_wide_grid():
+    """``requests/tpch_q15.py`` ``prepare``: ``server/wire.py``
+    ``chunk_rows`` by ``hasattr``, and the fused kernel's ``MAX_SLOTS``
+    read from ``device/pallas_hash.py``'s source (the load generator
+    must not import JAX): the name it finds there is the module's."""
+    import sys
+    assert ("tikv_tpu.server.wire", "chunk_rows") in \
+        capabilities_the_benchmark_asks_for()
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import byname
+    from tikv_tpu.device import pallas_hash
+    kind = byname.load("requests", "tpch_q15")
+    assert kind.kernel_max_slots() == pallas_hash.MAX_SLOTS >= kind.GRID

@@ -623,6 +623,30 @@ def test_pinned_stager_mechanics_on_host_space():
         assert tree["x"] is x
 
 
+@pytest.mark.parametrize("memory_kind", ["pinned_host", "unpinned_host"])
+def test_a_leaf_past_the_stagers_limit_is_fetched_from_where_it_lies(
+        memory_kind):
+    """The landing buffers are for the KBs of an aggregation's states:
+    a leaf past ``MAX_BYTES`` (a 16,384-slot grid's accumulator is 786 KB
+    a lane) passes through untouched, registers no program and is not
+    counted, beside a small leaf of the same tree that is staged where
+    the backend can."""
+    import jax.numpy as jnp
+
+    from tikv_tpu.device.request import _PinnedStager
+    st = _PinnedStager(memory_kind=memory_kind)
+    assert st.MAX_BYTES == 1 << 19
+    at = jnp.zeros((2, 8, st.MAX_BYTES // 64), jnp.int32)
+    over = jnp.zeros((2, 512, 192), jnp.int32)      # Q15's, one lane
+    assert at.nbytes == st.MAX_BYTES < over.nbytes == 786_432
+    assert st._fn_for(over) is None and st.classes == 0
+    tree = st.stage({"over": over, "at": at})
+    assert tree["over"] is over
+    assert st.staged == (1 if st.enabled else 0)
+    assert st.staged_bytes == (at.nbytes if st.enabled else 0)
+    np.testing.assert_array_equal(np.asarray(tree["at"]), np.asarray(at))
+
+
 # -------------------------------------------- decode / plan tiers
 
 

@@ -195,6 +195,11 @@ def wire_bytes(specs, cols, fracs=(), keyed=True):
 def assert_same_columns(new, old):
     assert len(new) == len(old)
     for got, want in zip(new, old):
+        # a DECIMAL SUM leaves ``_hash_columns`` as its scaled plane;
+        # the oracle's form is the host's, made where rows are asked for
+        assert (got.frac is None) == (want.frac is None) or \
+            got.values.dtype == np.int64
+        got, want = got.unscaled(), want.unscaled()
         assert got.eval_type is want.eval_type
         assert got.values.dtype == want.values.dtype
         assert got.validity.dtype == want.validity.dtype == np.bool_
@@ -430,13 +435,53 @@ def strided_parts(args):
     return ([w[:, :, ::2] for w in wide],) + args[1:]
 
 
+def sublane_minor_parts(args):
+    """The accumulator as a TPU hands it back past 128 sublanes of slots
+    (a result layout of ``{2,3,1,0}``: the sublane dimension minor-most,
+    kept by ``np.asarray`` as strides)."""
+    return ([np.asfortranarray(p.transpose(1, 2, 0)).transpose(2, 0, 1)
+             for p in args[0]],) + args[1:]
+
+
+NOT_CONTIGUOUS = {"strided": strided_parts,
+                  "sublane-minor": sublane_minor_parts}
+
+
+@needs_native
+@pytest.mark.parametrize("one_slot", [False, True], ids=["grid", "one-slot"])
+@pytest.mark.parametrize("name", NOT_CONTIGUOUS)
+def test_parts_that_are_not_contiguous_are_copied_and_served_natively(
+        name, one_slot, runner):
+    """PR 28 sent them to the numpy chain; on a 16,384-slot grid that
+    was every task's finalize, ~24 strided passes (PERF.md section 6,
+    PR 40).  One copy, then the one native call, the same planes."""
+    aggs = (("count_star", 0), ("sum", 2))
+    plain = accumulator(
+        simple_case(aggs, parts=2, seed=5) if one_slot else
+        make_case(aggs, null_group=True, parts=2, seed=5))
+    args = NOT_CONTIGUOUS[name](plain)
+    assert not any(p.flags.c_contiguous for p in args[0])
+    assert all(np.array_equal(a, b) for a, b in zip(args[0], plain[0]))
+    want = (simple_chain if one_slot else numpy_chain)(*plain)
+    before = runner.mesh_stats()["finalize"]
+    (parts, LO, p8, layouts, specs, slots, base, capacity, slot_keys) = args
+    got = runner._aggregator._packed_columns(
+        plan_of(specs), parts, LO, p8, layouts, slots, base, capacity,
+        slot_keys)
+    after = runner.mesh_stats()["finalize"]
+    assert_same_columns(got, want)
+    assert wire_bytes(specs, got, keyed=not one_slot) == \
+        wire_bytes(specs, want, keyed=not one_slot)
+    assert after["native"] - before["native"] == 1
+    assert after["numpy"] == before["numpy"]
+
+
 DECLINED = {
     "f32-plane": f32_layout,
     "kind-outside-the-four": unknown_kind,
     "uint64-key-domain": uint64_domain,
     "uint64-slot-keys": uint64_slot_keys,
     "int64-parts": int64_parts,
-    "parts-not-contiguous": strided_parts,
     "extension-absent": lambda args: args,
 }
 # a grid of one slot has no key domain to be outside int64

@@ -558,7 +558,7 @@ def test_key_spans_over_the_grid_leave_the_fused_kernel(monkeypatch):
     span."""
     from tikv_tpu.device import aggregate as agg_mod
     runner = DeviceRunner(mesh=make_mesh(jax.devices()[:1]))
-    table, names, snap = _int_keys(8970, 300, spans=(100, 100, 2))
+    table, names, snap = _int_keys(8970, 300, spans=(200, 100, 2))
     s = DagSelect.from_table(table, names)
     dag = s.aggregate([s.col(names[0]), s.col(names[1])],
                       [("count_star", None)]).build()
@@ -567,14 +567,16 @@ def test_key_spans_over_the_grid_leave_the_fused_kernel(monkeypatch):
     from tikv_tpu.device.kernels import build_layouts
     layouts, p8, pf = build_layouts(plan.specs, [False], (0,), [False])
     args = (plan, feed, ("int32", "int32"), layouts, p8, pf)
-    assert agg_mod.agg_bodies(True, 1, *args, 8192, "dense", False) == \
+    over, at = 2 * pallas_hash.MAX_SLOTS, pallas_hash.MAX_SLOTS
+    assert 200 * 100 > at
+    assert agg_mod.agg_bodies(True, 1, *args, over, "dense", False) == \
         ("hash_twolevel",)
-    assert agg_mod.agg_bodies(True, 1, *args, 4096, "dense", False) == \
+    assert agg_mod.agg_bodies(True, 1, *args, at, "dense", False) == \
         ("pallas_hash", "hash_twolevel")
     assert not pallas_hash.supported(plan, feed, ("int32", "int32"), 0,
-                                     8192, 1, "dense")
+                                     over, 1, "dense")
     assert pallas_hash.supported(plan, feed, ("int32", "int32"), 0,
-                                 4096, 1, "dense")
+                                 at, 1, "dense")
     got, want, _res = _both(runner, dag, snap)
     assert sorted(got) == sorted(want)
 

@@ -299,11 +299,10 @@ def test_a_composite_key_past_the_grid_takes_the_sparse_recode(interpret):
     """Key spans whose product is over MAX_SLOTS do not index the grid:
     the keys' number is recoded on the host as a sparse key is, and the
     same kernel serves the slot ids."""
-    table, snap, a, b, v = _two_key_snapshot(N_ROWS, seed=9,
-                                             spans=(3000, 2))
-    assert 3000 * 2 > pallas_hash.MAX_SLOTS
+    n = 2 * BLOCK + 77      # few enough rows that the pairs fit a grid
+    table, snap, a, b, v = _two_key_snapshot(n, seed=9, spans=(9000, 2))
+    assert 9000 * 2 > pallas_hash.MAX_SLOTS
     runner = _runner(1)
-    runner._max_hash_capacity = 1 << 12
 
     def dag():
         sel = DagSelect.from_table(table, ["id", "a", "b", "v"])
@@ -311,12 +310,15 @@ def test_a_composite_key_past_the_grid_takes_the_sparse_recode(interpret):
             [sel.col("a"), sel.col("b")],
             [("count_star", None), ("sum", sel.col("v"))]).build()
 
-    want = _want_pairs(a, b, v, np.ones(N_ROWS, np.bool_))
+    want = _want_pairs(a, b, v, np.ones(n, np.bool_))
+    assert 4096 < len(want) < 8192
     got = {(r[-2], r[-1]): tuple(r[:-2])
            for r in runner.handle_request(dag(), snap).rows()}
     assert got == want
     recent = runner.flight_recorder.items()
-    assert all(e["slot_mode"] != "dense" for e in recent), recent
+    assert recent and all(
+        e["compile_class"] == "pallas_hash" and e["slot_mode"] == "sparse"
+        and e["slots"] == 8193 for e in recent), recent
     _lane_builds_done(runner)
 
 
@@ -398,3 +400,118 @@ def test_a_product_past_int32_is_summed_as_limbs(interpret):
     _served_by_pallas(runner)
     assert runner.flight_recorder.agg_param_counts()["limb_sums"] == 2
     _lane_builds_done(runner)
+
+
+# ------------------------------------------------- grids past 4,096 slots
+
+
+@pytest.fixture
+def wide(interpret, monkeypatch):
+    """The one-hot's budget shrunk with BLOCK, so that the step follows
+    the grid here as at full size: BLOCK rows up to 4,096 slots (128
+    sublanes), a quarter of it at 16,384."""
+    monkeypatch.setattr(pallas_hash, "A_BYTES", 128 * BLOCK)
+
+
+def test_the_step_is_the_block_for_every_grid_up_to_4096_slots():
+    """No cell of the benchmark before Q15's changes its kernel: 2^18
+    rows a step whatever the grid, and past it a power of two that
+    divides the feeds' padding."""
+    assert pallas_hash.BLOCK == 1 << 18 and pallas_hash.MAX_SLOTS == 1 << 14
+    for slots in (1, 2, 180, 1024, 1025, 4095, 4096):
+        assert pallas_hash.block_rows(slots) == 1 << 18, slots
+    assert pallas_hash.block_rows(4097) == 1 << 17
+    assert pallas_hash.block_rows(8192) == 1 << 17
+    assert pallas_hash.block_rows(8193) == 1 << 16
+    assert pallas_hash.block_rows(10_000) == 1 << 16      # 320 sublanes
+    assert pallas_hash.block_rows(16_384) == 1 << 16      # 512
+    for slots in range(1, pallas_hash.MAX_SLOTS + 1, 97):
+        step = pallas_hash.block_rows(slots)
+        hi = pallas_hash.sublanes(slots)
+        assert pallas_hash.BLOCK % step == 0
+        assert hi * step <= pallas_hash.A_BYTES < 2 * hi * step or \
+            step == pallas_hash.BLOCK
+
+
+WIDE_ROWS = 4 * BLOCK + 77
+
+
+def _wide_dag(table, key=None):
+    sel = DagSelect.from_table(table, ["id", "k", "v"])
+    return sel.where(sel.col("v") > 0).aggregate(
+        [key(sel) if key else sel.col("k")],
+        [("count_star", None), ("sum", sel.col("v"))]).build()
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize("span", [4097, 10_000, 16_384])
+def test_a_grid_past_4096_slots_matches_numpy(wide, n_devices, span):
+    """GROUP BY a key of ``span`` values (TPC-H Q15's ``l_suppkey`` has
+    10,000): the same kernel body over a wider grid, fewer rows a step,
+    on one device and psummed over four."""
+    rng = np.random.default_rng(span)
+    keys = rng.integers(1, 1 + span, WIDE_ROWS).astype(np.int64)
+    keys[:2] = 1, span
+    table, snap, v = _snapshot(WIDE_ROWS, keys, seed=span % 40 + 20)
+    runner = _runner(n_devices)
+    want = _want_groups(keys, v, v > 0)
+    assert len(want) > 3000
+    for _ in range(2):
+        assert _group_rows(runner.handle_request(_wide_dag(table),
+                                                 snap)) == want
+    _served_by_pallas(runner)
+    _finalized_natively(runner)
+    capacity = 8192 if span == 4097 else 16_384
+    recent = runner.flight_recorder.items()
+    assert all(e["slots"] == capacity and e["slot_mode"] == "dense" and
+               e["block_rows"] == pallas_hash.block_rows(capacity) < BLOCK
+               for e in recent), recent
+    assert runner.flight_recorder.agg_param_counts()["slots_sum"] == \
+        2 * capacity
+    _lane_builds_done(runner)
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_a_span_over_the_grid_is_served_by_the_stand_in(wide, n_devices):
+    """16,385 values do not fit the grid: ``hash_twolevel`` serves, as
+    it served past 4,096, and no kernel build is tried."""
+    span = pallas_hash.MAX_SLOTS + 1
+    rng = np.random.default_rng(5)
+    keys = rng.integers(1, 1 + span, WIDE_ROWS).astype(np.int64)
+    keys[:2] = 1, span
+    table, snap, v = _snapshot(WIDE_ROWS, keys, seed=45)
+    runner = _runner(n_devices)
+    want = _want_groups(keys, v, v > 0)
+    for _ in range(2):
+        assert _group_rows(runner.handle_request(_wide_dag(table),
+                                                 snap)) == want
+    recent = runner.flight_recorder.items()
+    assert [e["compile_class"] for e in recent] == ["hash_twolevel"] * 2
+    assert all(e["slots"] == 0 for e in recent)
+    assert runner.flight_recorder.stats()["faults"] == 0
+
+
+def test_a_key_that_may_be_null_keeps_its_slot_on_a_wide_grid(wide):
+    """An expression key keeps a NULL slot beside its groups: 5,000
+    values are 8,192 + 1 slots on the kernel; at 16,384 + 1 the grid is
+    full and the stand-in serves."""
+    from tikv_tpu.expr import Expr
+
+    def shifted(sel):
+        return Expr.call("PlusInt", sel.col("k"),
+                         Expr.const(7, EvalType.INT))
+
+    for span, klass, slots in ((5000, "pallas_hash", 8193),
+                               (10_000, "hash_twolevel", 0)):
+        rng = np.random.default_rng(span)
+        keys = rng.integers(1, 1 + span, WIDE_ROWS).astype(np.int64)
+        keys[:2] = 1, span
+        table, snap, v = _snapshot(WIDE_ROWS, keys, seed=46 + span % 3)
+        runner = _runner(1)
+        want = _want_groups(keys + 7, v, v > 0)
+        assert _group_rows(runner.handle_request(
+            _wide_dag(table, shifted), snap)) == want
+        recent = runner.flight_recorder.items()
+        assert [(e["compile_class"], e["slots"]) for e in recent] == \
+            [(klass, slots)], recent
+        _lane_builds_done(runner)

@@ -126,8 +126,13 @@ class DAGRequest:
     ranges: tuple                 # tuple[KeyRange]
     start_ts: int = 0
     output_offsets: Optional[tuple] = None
-    # response encoding: "rows" (python rows) | "chunk" (columnar)
-    encode_type: str = "chunk"
+    # how the reply carries the result (tipb EncodeType): "rows", the
+    # rows as msgpack values (what every reply was before a store read
+    # this field, whatever it said), or "chunk": a buffer a column, a
+    # DECIMAL as its scaled int64 plane, where every column of the
+    # result is a plane a chunk carries, else rows all the same
+    # (server/wire.py ``enc_cop_body``)
+    encode_type: str = "rows"
 
     def plan_key(self) -> tuple:
         """Hashable plan identity for the device-kernel jit cache."""

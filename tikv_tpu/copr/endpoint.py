@@ -75,6 +75,10 @@ class CopResponse:
     result: "SelectResult"
     elapsed_ns: int = 0
     backend: str = "host"
+    # how the request asked for its result (``DAGRequest.encode_type``):
+    # what the serving legs encode it as where they can
+    # (server/wire.py ``enc_cop_body``)
+    encode_type: str = "rows"
 
     def rows(self):
         return self.result.rows()
@@ -328,7 +332,7 @@ class Endpoint:
         elapsed = time.perf_counter_ns() - t0
         m.COPR_REQ_COUNTER.labels("plan").inc()
         m.COPR_REQ_DURATION.labels("plan").observe(elapsed / 1e9)
-        return CopResponse(result, elapsed, "plan")
+        return CopResponse(result, elapsed, "plan", preq.encode_type)
 
     def _completion(self):
         with self._completion_mu:
@@ -583,7 +587,8 @@ class Endpoint:
         elapsed = time.perf_counter_ns() - d.t0
         m.COPR_REQ_COUNTER.labels(backend).inc()
         m.COPR_REQ_DURATION.labels(backend).observe(elapsed / 1e9)
-        return CopResponse(result, elapsed, backend)
+        return CopResponse(result, elapsed, backend,
+                           d.req.dag.encode_type)
 
     def _degrade_at_wait(self, d: "CopDeferred"):
         """Deferred-fetch failure → host pipeline (unless forced)."""

@@ -181,7 +181,7 @@ class PlanRequest:
     root: PlanNode
     start_ts: int = 0
     output_offsets: Optional[tuple] = None
-    encode_type: str = "chunk"
+    encode_type: str = "rows"       # as ``DAGRequest.encode_type``
 
     def plan_key(self) -> tuple:
         """Hashable plan identity (share-class key, jit-cache key)."""
@@ -1004,7 +1004,11 @@ class PlanExecutor:
         if frag.backend == "device":
             runner = self._endpoint._device_runner
             try:
-                return runner.handle_request(dag, storage).batch
+                batch = runner.handle_request(dag, storage).batch
+                # the operators above a leaf read values: a DECIMAL SUM
+                # the device handed on as its scaled plane, unscaled
+                return ColumnBatch(batch.schema, [
+                    c.unscaled() for c in batch.columns])
             except Exception:   # noqa: BLE001 — per-fragment degrade
                 if force == "device":
                     raise

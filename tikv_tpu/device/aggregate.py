@@ -109,8 +109,10 @@ def agg_bodies(is_tpu: bool, n_shards: int, plan, feed, dtypes, layouts,
     (``pallas_hash.supported``).  ``mode`` is the slot mode: ``dense``
     keys index the grid by ``key - base``, ``sparse`` keys (a span over
     the runner's ``max_hash_capacity``) ride as recoded slot ids,
-    ``simple`` has one slot.  Bucket tiles (``tiled``: a request over
-    part of a region's rows) exist on one device only.
+    ``simple`` has one slot; past 4,096 slots a grid step takes fewer
+    rows (``pallas_hash.block_rows``), the same body.  Bucket tiles
+    (``tiled``: a request over part of a region's rows) exist on one
+    device only.
 
     Then ONE stand-in, for what the kernel does not take and for a plan
     whose kernel build was refused (``_try_pallas`` returned None):
@@ -118,7 +120,7 @@ def agg_bodies(is_tpu: bool, n_shards: int, plan, feed, dtypes, layouts,
     - ``hash_twolevel``: COUNT / SUM / AVG whose planes fit one MXU
       lane tile (``kernels.twolevel_lo``): SUM / AVG of a REAL
       argument, nullable or int64 kernel columns, more than MAX_SLOTS
-      slots, and every such plan off a TPU (what tier-1 runs).
+      (16,384) slots, and every such plan off a TPU (what tier-1 runs).
     - ``hash_scatter``: the rest: MIN / MAX / the variance family, or
       planes too wide for the two-level kernel.
     - ``simple``: an aggregation without GROUP BY.
@@ -593,12 +595,18 @@ class DeviceAggregator:
             # (``pallas_hash.build``): a feed's own, not the kernel's
             pvals = tuple(v for b in key_bounds for v in b) + tuple(pvals)
             pdts = ("int32",) * (2 * len(key_bounds)) + tuple(pdts)
-        # what the recorder says of the launch: its GROUP BY keys and
-        # the byte planes it contracts (the kernel's time follows rows
-        # x planes: PERF.md section 6, PR 34)
+        # what the recorder says of the launch: its GROUP BY keys, the
+        # byte planes it contracts and the grid it contracts them over,
+        # its slots and the rows a grid step takes (the kernel's time
+        # follows rows x planes x sublanes of slots: PERF.md section 6,
+        # PRs 34 and 40; the step follows the grid)
+        slots = pallas_hash.n_slots(plan, capacity, mode)
+        B = pallas_hash.block_rows(slots)
         launched = {"keys": len(plan.key_rpns), "planes": p8,
-                    "limb_sums": len(plan.limbs)}
-        B = pallas_hash.BLOCK
+                    "limb_sums": len(plan.limbs), "slots": slots,
+                    "block_rows": B}
+        # (the feed pads to whole BLOCKs a shard, ``supported``: whole
+        # steps of any grid)
         total_blocks = feed["n_pad"] // B
         tiles = []          # (row_lo, row_hi, blk0, span_blocks)
         if spans is None:
@@ -878,7 +886,8 @@ class DeviceAggregator:
         over one dummy column with empty row bounds, so that the jitted
         program and the pinned stager's class of its stacked output are
         warm when the dispatcher first calls them.  Where the pinned
-        stager runs (request.HOST_STAGER: a TPU), the program's own
+        stager runs (request.HOST_STAGER: a TPU) and would take the
+        stacked output (``MAX_BYTES``), the program's own
         output lies in pinned host memory, which saves the launch the
         stager's program, a second PjRt execute (0.27 ms of the
         launching thread's CPU on a v5e's host, 0.13 more for the
@@ -894,7 +903,11 @@ class DeviceAggregator:
             pinned = None
             if HOST_STAGER.enabled is None:     # not probed yet
                 HOST_STAGER.stage(jnp.zeros((8,), jnp.int32))
-            if HOST_STAGER.enabled:
+            part = jax.eval_shape(run.call, args[0][0], *cols)
+            if HOST_STAGER.enabled and k * part.size * \
+                    part.dtype.itemsize <= HOST_STAGER.MAX_BYTES:
+                # (past the stager's limit the stacked output stays in
+                # device memory and is fetched from there)
                 (dev,) = cols[0].devices()
                 pinned = SingleDeviceSharding(
                     dev, memory_kind=HOST_STAGER.memory_kind)
@@ -1583,7 +1596,13 @@ def finalize_packed(parts, LO, p8, layouts, specs, slots, base, capacity,
     and on a serving store every drop queues behind ~10 runnable
     threads (PERF.md section 6, PRs 26, 28 and 35).  The planes are
     views of buffers sized ``capacity + 1`` (``np.empty`` and a slice
-    drop no GIL).  What it adapts to is in its input: anything else
+    drop no GIL).  A part that is not C-contiguous is copied so first:
+    past 128 sublanes of slots the TPU hands the accumulator back with
+    its sublane dimension minor-most (a result layout of ``{2,3,1,0}``
+    for a lane program's ``(k, 2, 512, 192)``, which ``np.asarray``
+    keeps as strides), and the chain's ~24 passes over strided planes
+    cost fifty times the one copy (PERF.md section 6, PR 40).  What it
+    adapts to is in its input: anything else
     takes the numpy chain (``_sum_parts`` → ``_pallas_states`` →
     ``finalize_hash`` / ``finalize_simple``), the same bytes, kept as
     the fallback and as the oracle of tests/test_finalize_native.py.
@@ -1596,9 +1615,10 @@ def finalize_packed(parts, LO, p8, layouts, specs, slots, base, capacity,
         else base + capacity <= _I64_MAX
     desc = None
     if call is not None and keys_fit_int64 and all(
-            p.dtype == np.int32 and p.flags.c_contiguous for p in parts):
+            p.dtype == np.int32 for p in parts):
         desc = _native_layout_desc(layouts)
     if desc is not None:
+        parts = [np.ascontiguousarray(p) for p in parts]
         n = 1 if simple else capacity + 1   # + the NULL slot
         key = (None, None) if simple else \
             (np.empty(n, np.int64), np.empty(n, np.bool_))
@@ -1704,9 +1724,10 @@ def _hash_columns(agg_out, finalized, shape=None):
     cols = [Column(ft.eval_type, vals.astype(dt, copy=False), ok)
             if frac is None else
             # a lowered DECIMAL's SUM: the exact integer sums, handed
-            # on scaled (the host form is made where a row is encoded)
-            Column(ft.eval_type, np.asarray(vals, np.int64), ok,
-                   frac).unscaled()
+            # on as the scaled plane they were summed as (``Column.frac``:
+            # a chunk reply carries the plane; ``Decimal``s are made
+            # where a row is asked for)
+            Column(ft.eval_type, np.asarray(vals, np.int64), ok, frac)
             for ft, dt, frac, (vals, ok) in zip(fts, dts, fracs, planes)]
     if keys is None:
         return cols

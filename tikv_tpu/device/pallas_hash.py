@@ -62,7 +62,11 @@ its share of the HBM roofline are in PERF.md section 5, ledger-cited):
   halving the step count from 2^17 blocks saves ~4 ms per 100M rows.
   int8 operands with int32 accumulation are exact at any block size
   (products <= 127, per-dot sums <= 127*2^18 << 2^31), unlike bf16/f32
-  whose 2^24 mantissa bounds the contraction at 2^17 rows.
+  whose 2^24 mantissa bounds the contraction at 2^17 rows.  **The step
+  follows the grid**: past 4,096 slots (128 sublanes of A) a step takes
+  fewer rows, so that A stays the 32 MB it is there (``block_rows``:
+  2^16 rows at 16,384 slots, TPC-H Q15's GROUP BY l_suppkey); every
+  grid up to 4,096 slots keeps 2^18.
 - Everything is **lane-major**: 1-D row vectors are natively (1, B), so
   one-hots are built TRANSPOSED — ``A (HI, B)``, planes ``(LO, B)`` —
   with major-dim broadcasts, and the contraction is an NT-form
@@ -99,9 +103,11 @@ from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef, RpnConst
 from .selection import split_params
 
-# Rows per grid step.  Swept on v5e at 100M rows (r5): 2^18 beats 2^17
-# by ~3.5 ms/pass (fewer ~10 us grid steps) and 2^19 regresses (VMEM
-# pressure breaks double-buffering).
+# Rows per grid step of every grid up to 4,096 slots, and the unit the
+# feeds pad to (a multiple of every smaller step: ``block_rows``).
+# Swept on v5e at 100M rows (r5): 2^18 beats 2^17 by ~3.5 ms/pass
+# (fewer ~10 us grid steps) and 2^19 regresses (VMEM pressure breaks
+# double-buffering).
 BLOCK = 1 << 18
 
 # Low radix of the slot factorization: slot = hi*LO + lo.  32 balances
@@ -111,11 +117,19 @@ BLOCK = 1 << 18
 # tile).
 LO = 32
 
-# Slot-span cap: A is (slots/LO, BLOCK) int8 in VMEM — 4096 slots is
-# a 32 MB A operand at BLOCK=2^18, leaving headroom for the weight
-# planes under the ~110 MB VMEM budget.  Above this the XLA two-level
-# path serves (up to its own 2^20 ceiling).
-MAX_SLOTS = 1 << 12
+# The A one-hot is (slots/LO, rows a step) int8 in VMEM: 32 MB at 4,096
+# slots and BLOCK rows, which leaves headroom for the weight planes
+# under the ~110 MB VMEM budget.  A wider grid keeps A at that size by
+# taking fewer rows a step (``block_rows``).
+A_BYTES = 1 << 25
+
+# Slot-span cap: 16,384 slots are 512 sublanes of A and 2^16 rows a
+# step.  Above this the XLA two-level path serves (up to its own 2^20
+# ceiling): at the next doubling a step is 2^15 rows, four times the
+# ~10 us grid steps for the same rows, the contraction twice as long
+# again (it follows rows x sublanes), and the accumulator pair and the
+# output are 2 MiB each a launch.
+MAX_SLOTS = 1 << 14
 
 MODE_DENSE = "dense"
 MODE_SPARSE = "sparse"
@@ -218,6 +232,21 @@ def n_slots(plan, capacity: int, mode: str = MODE_DENSE) -> int:
     return capacity + (0 if key_never_null(plan) else 1)
 
 
+def sublanes(slots: int) -> int:
+    """Rows of the A one-hot a ``slots``-slot grid needs (``HI``): a
+    sublane for every LO slots, in whole tiles of eight."""
+    return (-(-slots // LO) + 7) // 8 * 8
+
+
+def block_rows(slots: int) -> int:
+    """Rows a grid step of a ``slots``-slot grid takes: BLOCK while the
+    A one-hot fits ``A_BYTES`` (every grid up to 4,096 slots), else the
+    largest power of two that keeps it there (2^16 at 16,384 slots), so
+    that a step divides the feeds' padding whatever the grid."""
+    rows = A_BYTES // sublanes(slots)
+    return min(BLOCK, 1 << (rows.bit_length() - 1))
+
+
 def supported(plan, feed, dtypes, pf: int, capacity: int,
               n_shards: int = 1, mode: str = MODE_DENSE) -> bool:
     """Static gate for the Pallas fast path.
@@ -230,7 +259,8 @@ def supported(plan, feed, dtypes, pf: int, capacity: int,
 
     ``n_shards > 1``: the sharded mesh runs this same kernel PER SHARD
     under shard_map — each shard's grid covers its local feed slice,
-    so the padded feed must split into whole BLOCKs per shard; the
+    so the padded feed must split into whole BLOCKs per shard (whole
+    steps of any grid: ``block_rows`` divides BLOCK); the
     per-shard packed partials psum on ICI (aggregate.py
     _pallas_sharded_wrap).
     """
@@ -272,7 +302,8 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
           col_map, mode: str = MODE_DENSE):
     """Build the pallas_call for one (plan, grid-span) pair.
 
-    ``nblk`` is the GRID SPAN in blocks, not the whole feed: the
+    ``nblk`` is the GRID SPAN in grid steps of ``block_rows(slots)``
+    rows (``run.block_rows``), not the whole feed: the
     "region → chip, bucket → tile" mapping (SURVEY §5.7, pd_client
     buckets) dispatches one kernel per covered bucket span — the
     scalar-prefetched block offset shifts the input index map, so a
@@ -294,15 +325,14 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
     ``run(row_lo, row_hi, base, blk0, cols, params=()) ->
     (2, HI, p8*LO) int32``
     packed accumulator pair covering absolute rows
-    [row_lo, row_hi) ⊆ [blk0*BLOCK, (blk0+nblk)*BLOCK); ``cols`` is the
-    already-selected input tuple (mapped columns, then slot ids when
-    sparse), ``params`` the request's constant values.
+    [row_lo, row_hi) ⊆ [blk0*B, (blk0+nblk)*B), B the grid's step;
+    ``cols`` is the already-selected input tuple (mapped columns, then
+    slot ids when sparse), ``params`` the request's constant values.
     """
     slots = n_slots(plan, capacity, mode)
-    hi_n = -(-slots // LO)
-    HI = ((hi_n + 7) // 8) * 8
+    HI = sublanes(slots)
     W = p8 * LO
-    B = BLOCK
+    B = block_rows(slots)
     # the sentinel hi value for rows with no destination slot: outside
     # [0, HI), so the row's one-hot column is all-zero
     SENT = HI * LO
@@ -527,6 +557,7 @@ def build(plan, layouts, p8: int, capacity: int, nblk: int,
     # scalars
     run.call = call
     run.scalars = scalars
+    run.block_rows = B
     return run, LO, HI
 
 

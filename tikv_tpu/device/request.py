@@ -157,10 +157,18 @@ class _PinnedStager:
     ``unpinned_host`` on its devices but has no
     ``annotate_device_placement`` implementation, so the probe fails
     there: ``probed: true, enabled: false``.)  Sharded leaves pass
-    through.
+    through, and so does a leaf past ``MAX_BYTES``: the landing buffers
+    are for the KBs of an aggregation's states.  For outputs of 0.8-3 MB
+    (a 16,384-slot grid's accumulator, 786 KB a lane) the runtime maps
+    fresh DMA buffers inside the serving window (``MapDmaBuffer``, tens
+    of ms with the device idle) and a fetch waited 8-14 ms where the
+    plain transfer waits 1.4-2.1 (PERF.md section 6, PR 40).
     """
 
     _MAX_CLASSES = 256
+    # the largest leaf that lands in pinned memory: every accumulator of
+    # a grid up to 4,096 slots (a 4-lane launch of TPC-H Q1's is 221 KB)
+    MAX_BYTES = 1 << 19
 
     def __init__(self, memory_kind: str = "pinned_host"):
         # "pinned_host" on TPU; tests pass "unpinned_host" to drive the
@@ -176,6 +184,8 @@ class _PinnedStager:
 
     def _fn_for(self, x):
         try:
+            if x.nbytes > self.MAX_BYTES:
+                return None         # fetched from where it lies
             sharding = x.sharding
             devices = getattr(sharding, "_device_assignment", None) or \
                 tuple(sharding.device_set)

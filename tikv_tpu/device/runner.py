@@ -568,10 +568,12 @@ class DeviceRunner:
         self._is_tpu = self._mesh.devices.flat[0].platform == "tpu"
         if chunk_rows is None:
             # feeds pad to the Pallas block so the fused hash kernel
-            # (pallas_hash.BLOCK rows/grid step) divides the feed — per
-            # SHARD on a sharded TPU mesh, since the sharded fast path
-            # runs the same kernel per shard before the tree-reduce;
-            # the XLA scan paths gcd down from this.  A sharded CPU
+            # (pallas_hash.BLOCK rows/grid step, or a power-of-two part
+            # of it on a grid past 4,096 slots: pallas_hash.block_rows)
+            # divides the feed — per SHARD on a sharded TPU mesh, since
+            # the sharded fast path runs the same kernel per shard
+            # before the tree-reduce; the XLA scan paths gcd down from
+            # this.  A sharded CPU
             # mesh (virtual-device parity tests) keeps the smaller
             # unit: no Mosaic lowering exists there and 8×2^18-row
             # minimum pads would swamp the fixtures.
@@ -2698,7 +2700,8 @@ class DeviceRunner:
     @contextmanager
     def _dispatch_phase(self, klass: str, key=None, params: int = 0,
                         slot_mode: str = "", keys: int = 0,
-                        planes: int = 0, limb_sums: int = 0):
+                        planes: int = 0, limb_sums: int = 0,
+                        slots: int = 0, block_rows: int = 0):
         """Every kernel launch site runs under this: the
         ``device_dispatch`` tracker span, plus one flight-recorder
         entry (launch wall, compile class, first-launch flag, mesh
@@ -2712,8 +2715,10 @@ class DeviceRunner:
         kind.  ``params``: the constants the launch carries as kernel
         operands; ``slot_mode``: the Pallas kernel's; ``keys`` /
         ``planes`` / ``limb_sums``: its GROUP BY keys, the byte planes
-        it contracts and the SUMs it sums as limbs (on the span and in
-        the entry; counted on ``/health`` ``device_mesh.agg_params``)."""
+        it contracts and the SUMs it sums as limbs; ``slots`` /
+        ``block_rows``: the grid it contracts them over and the rows a
+        grid step takes (on the span and in the entry; counted on
+        ``/health`` ``device_mesh.agg_params``)."""
         from .. import resource_metering as rm
         from ..utils import tracker
         rec = self.flight_recorder
@@ -2752,7 +2757,8 @@ class DeviceRunner:
                         ok=ok, shards=num_shards(self._mesh),
                         whole_mesh=self._failover_parent is None,
                         params=params, slot_mode=slot_mode, keys=keys,
-                        planes=planes, limb_sums=limb_sums)
+                        planes=planes, limb_sums=limb_sums, slots=slots,
+                        block_rows=block_rows)
                     tracker.annotate(**entry)
                     info["attrs"] = entry
                 info["t0_ns"], info["t1_ns"] = t0_ns, t1_ns

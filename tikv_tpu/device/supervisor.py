@@ -162,11 +162,13 @@ class FlightRecorder:
         self.planes = {"decimal": 0, "date": 0, "code": 0}
         # what the fused kernel's launches were made of: those whose
         # GROUP BY had several keys (the composite key), the SUMs they
-        # summed as 16-bit limbs, and the byte planes they contracted
-        # (the kernel's time follows rows x planes)
+        # summed as 16-bit limbs, the byte planes they contracted and
+        # the slots of the grids they contracted them over (the kernel's
+        # time follows rows x planes x sublanes of slots)
         self.composite_key_launches = 0
         self.limb_sums = 0
         self.planes_sum = 0
+        self.slots_sum = 0
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -178,7 +180,8 @@ class FlightRecorder:
              ok: bool = True, shards: int = 1,
              whole_mesh: bool = False, params: int = 0,
              slot_mode: str = "", keys: int = 0, planes: int = 0,
-             limb_sums: int = 0) -> dict:
+             limb_sums: int = 0, slots: int = 0,
+             block_rows: int = 0) -> dict:
         ck = (klass, key)
         with self._mu:
             first = ck not in self._seen
@@ -200,6 +203,7 @@ class FlightRecorder:
                 self.composite_key_launches += 1
             self.limb_sums += limb_sums
             self.planes_sum += planes
+            self.slots_sum += slots
             entry = {"t_unix_s": round(time.time(), 6),
                      "launch_ms": round(wall_s * 1e3, 3),
                      "compile_class": klass,
@@ -216,6 +220,10 @@ class FlightRecorder:
                      # contracted (0 off the fused kernel)
                      "keys": int(keys),
                      "planes": int(planes),
+                     # the slot grid it contracted them over and the
+                     # rows a grid step took (the step follows the grid)
+                     "slots": int(slots),
+                     "block_rows": int(block_rows),
                      "ok": ok}
             self._ring.append(entry)
         return entry
@@ -244,7 +252,8 @@ class FlightRecorder:
                     "code_planes": self.planes["code"],
                     "composite_key_launches": self.composite_key_launches,
                     "limb_sums": self.limb_sums,
-                    "planes_sum": self.planes_sum}
+                    "planes_sum": self.planes_sum,
+                    "slots_sum": self.slots_sum}
 
     def note_scalar(self, hit: bool) -> None:
         with self._mu:

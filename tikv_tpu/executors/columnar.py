@@ -7,7 +7,9 @@ blocks without a per-row Python decode loop (SURVEY.md §7 "Decode on the hot
 path"), so the storage layer can hand the executor a *columnar snapshot*:
 sorted handle array + dense value/validity arrays per column — the moral
 equivalent of the reference's Chunk encode_type
-(tidb_query_executors/src/runner.rs:71-76) applied at rest.
+(tidb_query_executors/src/runner.rs:71-76) applied at rest.  (On the wire
+the same field is a reply's form: a request whose DAG says ``encode_type
+= "chunk"`` is answered a buffer a column, server/wire.py ``enc_chunk``.)
 
 ``ColumnarTable`` implements the scan feed consumed by both the host
 executors (``BatchColumnarTableScanExecutor``) and the device runner, and

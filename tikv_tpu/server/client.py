@@ -33,7 +33,10 @@ class StoreClient:
         """One unary RPC.  A reply that carries a ``time_detail`` leaves
         with the RPC's path across the wire in it (:func:`_note_wire`):
         gRPC runs both serializers on the calling thread, so four
-        stamps around them and the store's three make one timeline."""
+        stamps around them and the store's three make one timeline.
+        A Coprocessor reply that is a chunk (the request's
+        ``encode_type``) comes back with ``chunk`` decoded
+        (``wire.dec_chunk``), inside ``client_decode``."""
         t_call = time.perf_counter_ns()
         at = [0, 0, 0]      # sent, bytes_in, decoded
 
@@ -45,6 +48,11 @@ class StoreClient:
         def unpack(raw):
             at[1] = time.perf_counter_ns()
             obj = wire.unpack(raw)
+            if "chunk" in obj:
+                # a chunk reply's buffers wrapped as arrays where they
+                # lie: no value a cell (``wire.chunk_rows`` makes rows
+                # of them for a caller who wants values)
+                wire.dec_chunk(obj["chunk"])
             at[2] = time.perf_counter_ns()
             return obj
 

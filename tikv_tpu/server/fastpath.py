@@ -1194,6 +1194,10 @@ def _column_list(c) -> list:
     (``device/aggregate.py _hash_columns``), so this call is the first and only
     place its answer becomes Python values: msgpack wants them."""
     import numpy as np
+    if getattr(c, "frac", None) is not None:
+        # a scaled DECIMAL plane becomes its ``Decimal``s here, where
+        # rows are asked for
+        c = c.unscaled()
     vals = c.values.tolist()
     validity = c.validity
     if len(validity) and not validity.all():
@@ -1242,20 +1246,32 @@ def _rows_native(batch) -> Optional[bytes]:
     JSON, a DECIMAL's ``Decimal``s), another dtype, a strided view,
     lengths that differ, the extension absent.  What the code can see
     in the planes decides, never a plan's name."""
-    if native.encode_rows_msgpack is None:
+    if native.encode_rows_msgpack is None or \
+            any(getattr(c, "frac", None) is not None
+                for c in batch.columns):
+        # (a scaled DECIMAL's row form is a ``Decimal``, not its
+        # plane's integer)
         return None
     return native.encode_rows_msgpack(
         [(c.values, c.validity) for c in batch.columns])
 
 
 def encode_response(env: dict, result,
-                    fp: Optional["FastPathCache"] = None) -> bytes:
+                    fp: Optional["FastPathCache"] = None,
+                    encode_type: str = "rows") -> bytes:
     """A fast-path hit's response: ``{"rows": rows, **env}`` as wire
     bytes.  The rows come from the native call where it takes the
     result's planes, spliced between the map header + ``"rows"`` key
     and ``env``'s packed items (the same field order, the same bytes);
     from ``encode_response_python`` where it declines.  ``fp`` counts
-    which (``/health`` ``fastpath.encode``)."""
+    which (``/health`` ``fastpath.encode``).  A request that asked for a
+    chunk (``encode_type``) gets ``{"chunk": chunk, **env}`` where the
+    result's planes make one (``wire.enc_cop_body``, the slow leg's
+    too): a buffer a column, no value a cell, nothing for either
+    encoder of rows to do."""
+    body = wire.enc_cop_body(result, encode_type)
+    if body is not None:
+        return _pack({**body, **env})
     rows = _rows_native(result.batch)
     if fp is not None:
         fp.note_encode(rows is not None)

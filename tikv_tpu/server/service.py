@@ -210,7 +210,7 @@ class KvService:
         tr, tok = tracker.install(trace_id=tid, sampled=sampled)
         tracker.note_accept(tr)
         try:
-            env, result = self._fastpath_dispatch(
+            env, resp = self._fastpath_dispatch(
                 fp, ent, storage, consts, start_ts, deadline_ms)
         finally:
             tracker.uninstall(tok)
@@ -218,16 +218,16 @@ class KvService:
         if ent.range_start is not None:
             synth["__trace_range_start"] = ent.range_start
         env = self._seal_traced("Coprocessor", synth, env, tr)
-        if result is None:
+        if resp is None:
             return env      # error response: dict, server packs it
         from .fastpath import encode_response
-        return encode_response(env, result, fp)
+        return encode_response(env, resp.result, fp, resp.encode_type)
 
     def _fastpath_dispatch(self, fp, ent, storage, consts,
                            start_ts: int, deadline_ms):
         """The fast leg of ``_dispatch_rpc``: pre-bound admission →
         read-pool slot → validated snapshot → coalescer/solo dispatch
-        → await outside the slot.  → (response env dict, SelectResult
+        → await outside the slot.  → (response env dict, CopResponse
         or None on error)."""
         from ..utils import deadline as dl_mod
         from ..utils import metrics as m
@@ -334,7 +334,7 @@ class KvService:
         m.GRPC_MSG_DURATION.labels(method).observe(
             time.perf_counter() - t0)
         m.GRPC_MSG_COUNTER.labels(method, "ok").inc()
-        return env, result
+        return env, resp
 
     def _dispatch_rpc(self, method: str, fn, req: dict, prio) -> dict:
         from ..utils import deadline as dl_mod
@@ -471,6 +471,8 @@ class KvService:
                 nbytes = len(v)
             elif "rows" in resp and isinstance(resp["rows"], list):
                 nbytes = 32 * len(resp["rows"])     # row estimate
+            elif "chunk" in resp:
+                nbytes = 32 * resp["chunk"]["n"]
         if nbytes:
             rgm.charge_request(group, bytes_touched=nbytes, requests=0)
         m.GRPC_MSG_DURATION.labels(method).observe(
@@ -714,9 +716,14 @@ class KvService:
                     for s in resp.result.exec_summaries]}
 
     def _enc_cop_resp(self, resp) -> dict:
+        """The slow leg's reply: the result as the chunk the request
+        asked for where its planes make one (``wire.enc_cop_body``, the
+        fast leg's too), else as rows."""
         with tracker.phase("resp_serialize"):
-            rows = wire.enc_rows(resp.rows())
-        return {"rows": rows, **self._cop_envelope(resp)}
+            body = wire.enc_cop_body(resp.result, resp.encode_type)
+            if body is None:
+                body = {"rows": wire.enc_rows(resp.rows())}
+        return {**body, **self._cop_envelope(resp)}
 
     def Coprocessor(self, req: dict) -> dict:
         # umbrella span over the handler (snapshot, backend routing,

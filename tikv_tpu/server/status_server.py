@@ -94,10 +94,22 @@ class StatusServer:
                     # tikv_grpc_msg_total{method="Coprocessor"}, as one
                     # count two samples can difference (a fan-out read
                     # is one of these a region)
-                    from ..utils.metrics import GRPC_MSG_COUNTER
+                    # and how the replies carried their results
+                    # (wire.enc_cop_body: rows, or a chunk where the
+                    # request asked for one), with the chunks' rows and
+                    # buffer bytes
+                    from ..utils import metrics as m
                     body["coprocessor"] = {"requests_served": int(sum(
-                        GRPC_MSG_COUNTER.labels("Coprocessor", st).value
-                        for st in ("ok", "err")))}
+                        m.GRPC_MSG_COUNTER.labels("Coprocessor", st).value
+                        for st in ("ok", "err"))),
+                        "replies": {
+                            "rows": int(m.COPR_REPLY_COUNTER.labels(
+                                "rows").value),
+                            "chunk": int(m.COPR_REPLY_COUNTER.labels(
+                                "chunk").value),
+                            "chunk_rows_sum": int(m.COPR_CHUNK_ROWS.value),
+                            "chunk_bytes_sum": int(
+                                m.COPR_CHUNK_BYTES.value)}}
                     fp = getattr(node, "fastpath", None)
                     if fp is not None and hasattr(fp, "stats"):
                         # microsecond warm path: learned wire-template

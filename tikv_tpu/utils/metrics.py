@@ -286,6 +286,17 @@ READ_POOL_PENDING_GAUGE = REGISTRY.gauge(
 COPR_REQ_COUNTER = REGISTRY.counter(
     "tikv_coprocessor_request_total", "coprocessor requests by backend",
     labels=("backend",))
+COPR_REPLY_COUNTER = REGISTRY.counter(
+    "tikv_coprocessor_reply_total",
+    "coprocessor replies by how they carry their result "
+    "(rows / chunk: a buffer a column, where the request asked for one)",
+    labels=("encode",))
+COPR_CHUNK_ROWS = REGISTRY.counter(
+    "tikv_coprocessor_reply_chunk_rows_total",
+    "rows that left in chunk replies")
+COPR_CHUNK_BYTES = REGISTRY.counter(
+    "tikv_coprocessor_reply_chunk_bytes_total",
+    "bytes of the column buffers of chunk replies")
 COPR_REQ_DURATION = REGISTRY.histogram(
     "tikv_coprocessor_request_duration_seconds",
     "coprocessor request duration", labels=("backend",))

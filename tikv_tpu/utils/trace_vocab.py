@@ -43,7 +43,12 @@ SPAN_VOCABULARY: dict[str, str] = {
                 "hit/fallback — served)",
     "await_deferred": "service thread parked on the deferred device "
                       "completion (decomposed by completion-side spans)",
-    "resp_serialize": "SelectResult rows → wire response encode",
+    "resp_serialize": "SelectResult → wire response encode: rows, or "
+                      "the chunk the request asked for",
+    "chunk_encode": "a result's planes → a chunk reply's buffers, on "
+                    "either serving leg (server/wire.py enc_cop_body; "
+                    "aggregate row only: count = requests that asked "
+                    "for a chunk)",
     # -- the client's side (server/client.py; CLIENT_CLOCK below: on the
     # client's clock, added to the reply's phases_ms by the client) --
     "fanout_cut": "region lookup through the client's region cache and "
@@ -68,7 +73,8 @@ SPAN_VOCABULARY: dict[str, str] = {
                   "send, loopback, the calling thread waking and "
                   "retaking the client's GIL; one shared clock only",
     "client_decode": "response deserializer entered → returned "
-                     "(wire.unpack of the reply)",
+                     "(wire.unpack of the reply, and a chunk's buffers "
+                     "wrapped as arrays: wire.dec_chunk)",
     # -- storage / host pipeline --
     "kv_read": "point/scan MVCC read through Storage",
     "snapshot": "raft lease read + engine snapshot acquisition",
