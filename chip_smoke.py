@@ -707,6 +707,13 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
                        enc["python"] == 0, enc)
         found = {"hash_agg": one_probe_no_walk(
             checks, "hash_agg warm", fp0, fp1)}
+        # and each was staged from its class's prepared record (a mesh
+        # has none: its launches leave from the request's thread)
+        prep = http_json(leg.status_port,
+                         "/health")["device_mesh"]["prepared"]
+        checks.on_chip("hash_agg warm: staged from the prepared record",
+                       prep["hits"] == 0 if whole_mesh
+                       else prep["hits"] >= len(warm), prep)
 
         # -- simple agg --
         def simple_agg():

@@ -5,7 +5,10 @@ Columns.
 ``DeviceRunner._handle_local`` builds the feed and calls
 ``DeviceAggregator.run_hash`` / ``run_simple`` under its dispatch lock;
 what comes back is a finished result (a cold build validates in line)
-or a ``_Pending`` whose finalize runs after the fetch.  Top to bottom:
+or a ``_Pending`` whose finalize runs after the fetch.  A warm
+whole-feed Pallas launch on one device leaves its preparation behind
+(``_Prepared``, in the request memo), and the class's next requests are
+staged from that without coming here.  Top to bottom:
 ``agg_bodies`` (which body serves which plan); ``run_hash`` (a GROUP BY
 request: key bounds, the sparse recode, layouts, then the bodies in
 that order); the Pallas launch; the XLA bodies with their scan program,
@@ -76,9 +79,9 @@ from .kernels import (
 from .request import (
     HOST_STAGER,
     _FallbackToHost,
-    _LanePending,
     _Pending,
     _Plan,
+    _Prepared,
     _rpn_col_indices,
 )
 from .selection import _next_pow2
@@ -168,8 +171,9 @@ class DeviceAggregator:
         ``tile_spans`` the row intervals of a request over part of the
         region's rows (bucket tiles), else None.  ``lanes``: the caller
         stages several requests under one hold of the dispatch lock and
-        launches them together (``launch_lanes``): a warm Pallas launch
-        then comes back prepared and unlaunched, a ``_LanePending``.
+        launches them together (``launch_lanes``): a warm whole-feed
+        Pallas launch then comes back prepared and unlaunched, a
+        ``_LanePending`` (without it, launched: ``_try_pallas``).
         ``recode``: a composite key whose kernel was refused is on its
         way to a stand-in."""
         runner = self._runner
@@ -276,24 +280,20 @@ class DeviceAggregator:
             # the fused direct-index kernel is the default body for
             # both dense and (dictionary-encoded) sparse key domains —
             # the slot column rides as one extra int32 kernel input
+
+            def from_packed(dag, parts, LO):
+                return runner._result(dag, list(schema), self._packed_columns(
+                    plan, parts, LO, p8, layouts, slots, base, capacity,
+                    slot_keys, shape))
+
             got = self._try_pallas(dag, plan, feed, dtypes, n, base,
                                    capacity, layouts, p8, arg_nbytes,
-                                   arg_ok_is_mask, mode, spans=tile_spans,
-                                   slots_dev=slots_dev, meta=meta,
-                                   lanes=lanes, key_bounds=key_bounds)
+                                   arg_ok_is_mask, mode, from_packed,
+                                   spans=tile_spans, slots_dev=slots_dev,
+                                   meta=meta, lanes=lanes,
+                                   key_bounds=key_bounds)
             if got is not None:
-                synced, parts, pl_LO = got
-
-                def from_packed(parts):
-                    return result(self._packed_columns(
-                        plan, parts, pl_LO, p8, layouts, slots, base,
-                        capacity, slot_keys, shape))
-
-                if isinstance(parts, _LanePending):
-                    parts.finalize = from_packed
-                    return parts
-                return from_packed(parts) if synced \
-                    else _Pending(parts, from_packed)
+                return got
             if composite and not sparse:
                 return self.run_hash(dag, plan, host_cols, dtypes, n, feed,
                                      meta, tile_spans, lanes, recode=True)
@@ -548,7 +548,7 @@ class DeviceAggregator:
     # -- the Pallas launch --
 
     def _try_pallas(self, dag, plan, feed, dtypes, n, base, capacity,
-                    layouts, p8, arg_nbytes, arg_ok_is_mask, mode,
+                    layouts, p8, arg_nbytes, arg_ok_is_mask, mode, finish,
                     spans=None, slots_dev=None, meta=None,
                     lanes: bool = False, key_bounds=None):
         """Fused Pallas fast path for the direct-index aggregation
@@ -561,23 +561,29 @@ class DeviceAggregator:
         dead-block guard makes the bucketed padding cost DMA only.
         Span tiles keep bucketed block counts for compile-class reuse
         (block offset via prefetch scalar); the packed partials ADD —
-        psum-partial merge semantics.
+        psum-partial merge semantics.  ``finish(dag, parts, LO)``: the
+        caller's finalize of the packed partials, one a tile (they add:
+        ``_sum_parts``).
 
         Returns None when the kernel cannot serve this request (no
         live tile, a build that was refused, a launch that failed: the
-        caller then runs its XLA stand-in), else
-        ``(synced, parts, LO)``: the packed partials, one a tile (they
-        add: ``_sum_parts``).  ``synced``: a first build, whose compile
-        + validate ran synchronously so that Mosaic rejections fall
-        back, and ``parts`` is its one fetched sum; else the parts are
-        still on the device and the caller fetches them (possibly on a
-        completion thread — the async serving path).  With ``lanes`` a
-        warm whole-feed launch on one device does not leave here:
-        ``parts`` is a ``_LanePending`` holding what the call needs,
-        and the caller's ``launch_lanes`` sends it with the other lanes
-        of its staging.  ``meta``: the request's memo, where a served
-        whole-feed launch leaves its kernel key as ``lane_class``, what
-        ``DeviceRunner.launch_class`` tells the coalescer.
+        caller then runs its XLA stand-in), else what the caller hands
+        on.  A first build: the finished result (compile + validate ran
+        synchronously so that Mosaic rejections fall back).  Bucket
+        tiles and a mesh's sharded entry: a ``_Pending`` whose parts
+        are still on the device (fetched possibly on a completion
+        thread — the async serving path).  The whole feed of a
+        single-device runner over a built kernel: the preparation is
+        left in ``meta`` (the request's memo) as its ``_Prepared``
+        record, from which the class's next requests are staged
+        without coming here (``DeviceRunner._stage_prepared``; its
+        ``key`` is what ``DeviceRunner.launch_class`` tells the
+        coalescer), and this request is its first lane, a
+        ``_LanePending``: launched here through ``launch_lanes`` as a
+        launch of one lane, or, with ``lanes`` (the caller stages
+        several requests under one hold of the dispatch lock), left to
+        the caller's ``launch_lanes`` with the other lanes of its
+        staging.
 
         A build or compile failure is cached so the fallback is taken
         once per plan, not per request.  SHARDED meshes ride the same
@@ -588,23 +594,23 @@ class DeviceAggregator:
         runner = self._runner
         sparse = mode == pallas_hash.MODE_SPARSE
         # the request's constants, operands of the const-blind kernel
-        _sel, _aggs, pvals, pdts = pallas_hash.plan_params(plan)
-        n_consts = len(pvals)
-        if key_bounds is not None and not sparse:
-            # a composite key's bases and spans ride ahead of them
-            # (``pallas_hash.build``): a feed's own, not the kernel's
-            pvals = tuple(v for b in key_bounds for v in b) + tuple(pvals)
-            pdts = ("int32",) * (2 * len(key_bounds)) + tuple(pdts)
-        # what the recorder says of the launch: its GROUP BY keys, the
-        # byte planes it contracts and the grid it contracts them over,
-        # its slots and the rows a grid step takes (the kernel's time
-        # follows rows x planes x sublanes of slots: PERF.md section 6,
-        # PRs 34 and 40; the step follows the grid)
+        _sel, _aggs, consts, const_dts = pallas_hash.plan_params(plan)
+        # a composite key's bases and spans ride ahead of them
+        # (``pallas_hash.build``): a feed's own, not the kernel's
+        key_scalars = tuple(v for b in key_bounds for v in b) \
+            if key_bounds is not None and not sparse else ()
+        pvals = key_scalars + tuple(consts)
+        pdts = ("int32",) * len(key_scalars) + tuple(const_dts)
+        # what the recorder says of the launch: its constants, its
+        # GROUP BY keys, the byte planes it contracts and the grid it
+        # contracts them over, its slots and the rows a grid step takes
+        # (the kernel's time follows rows x planes x sublanes of slots:
+        # PERF.md section 6, PRs 34 and 40; the step follows the grid)
         slots = pallas_hash.n_slots(plan, capacity, mode)
         B = pallas_hash.block_rows(slots)
-        launched = {"keys": len(plan.key_rpns), "planes": p8,
-                    "limb_sums": len(plan.limbs), "slots": slots,
-                    "block_rows": B}
+        said = {"params": len(consts), "keys": len(plan.key_rpns),
+                "planes": p8, "limb_sums": len(plan.limbs), "slots": slots,
+                "block_rows": B}
         # (the feed pads to whole BLOCKs a shard, ``supported``: whole
         # steps of any grid)
         total_blocks = feed["n_pad"] // B
@@ -670,12 +676,10 @@ class DeviceAggregator:
             on the device (one on a mesh, one a tile; they add)."""
             if "sharded" in entry:
                 # (a plan without constants passes what it always did)
-                consts = (self._param_vector(entry, pvals),) if pvals \
-                    else ()
+                vec = (self._param_vector(entry, pvals),) if pvals else ()
                 return [entry["sharded"](
                     runner._cached_scalar(n, jnp.int64),
-                    runner._cached_scalar(base, jnp.int64), *consts,
-                    *cols)]
+                    runner._cached_scalar(base, jnp.int64), *vec, *cols)]
             runs_by_nb = entry["runs"]
             return [runs_by_nb[nb](lo, hi, base, blk0, cols, pvals)
                     for lo, hi, blk0, nb in tiles]
@@ -696,51 +700,66 @@ class DeviceAggregator:
             return None
         first = entry is None
         whole = spans is None and runner._single
-        if lanes and whole and not first:
+        if first or not whole:
+            # what has no record to leave by: a first build, bucket
+            # tiles, a mesh's sharded entry
+            try:
+                # the first build is a launch like any other: its
+                # compile wall and class land in the flight recorder
+                # (first_launch=True), and a rejected build counts as a
+                # recorder fault before the XLA fallback serves
+                with runner._dispatch_phase("pallas_hash", key,
+                                            slot_mode=mode, **said):
+                    if first:
+                        entry = build()
+                        if whole:
+                            # the kernel's lane programs build beside
+                            # its own compile, each on its thread: they
+                            # are there when the first read is
+                            # (launch_lanes)
+                            self._ask_lane_programs(entry, key)
+                        # compile + validate now so Mosaic / shard_map
+                        # rejections fall back to the XLA bodies
+                        parts = [_sum_parts(launch(entry))]
+                    else:
+                        parts = launch(entry)
+            except Exception as e:
+                # a failed build, or a failed launch of a cached
+                # kernel, falls back to the XLA body for THIS request
+                # and never fails the coprocessor request.  (A failure
+                # surfacing later, at the possibly-deferred fetch,
+                # degrades to the host pipeline via the DeferredResult
+                # / endpoint contract instead.)
+                self._pallas_failed(key, e, building=first)
+                return None
+            if first:
+                cache[key] = entry
+                if pdts:
+                    runner.flight_recorder.note_const_class()
+            # success clears the transient strike count — three
+            # isolated hiccups over a process lifetime must not kill
+            # the fast path
+            cache.pop(("hashpl_tries", key), None)
+        LO = entry["LO"]
+        if whole:
             (lo, hi, blk0, nb), = tiles
-            return False, _LanePending(
-                (key, entry, entry["runs"][nb],
-                 (lo, hi, base, blk0, pvals), mode,
-                 dict(launched, params=n_consts)), cols), entry["LO"]
-        try:
-            # the first build is a launch like any other: its compile
-            # wall and class land in the flight recorder
-            # (first_launch=True), and a rejected build counts as a
-            # recorder fault before the XLA fallback serves
-            with runner._dispatch_phase("pallas_hash", key,
-                                        params=n_consts, slot_mode=mode,
-                                        **launched):
-                if first:
-                    entry = build()
-                    if whole:
-                        # the kernel's lane programs build beside its
-                        # own compile, each on its thread: they are
-                        # there when the first read is (launch_lanes)
-                        self._ask_lane_programs(entry, key)
-                    # compile + validate now so Mosaic / shard_map
-                    # rejections fall back to the XLA bodies
-                    got = (True, [_sum_parts(launch(entry))], entry["LO"])
-                else:
-                    got = (False, launch(entry), entry["LO"])
-        except Exception as e:
-            # a failed build, or a failed launch of a cached kernel,
-            # falls back to the XLA body for THIS request and never
-            # fails the coprocessor request.  (A failure surfacing
-            # later, at the possibly-deferred fetch, degrades to the
-            # host pipeline via the DeferredResult / endpoint contract
-            # instead.)
-            self._pallas_failed(key, e, building=first)
-            return None
+            rec = _Prepared(
+                key=key, entry=entry, run=entry["runs"][nb],
+                bounds=(lo, hi, base, blk0), cols=cols, feed=feed,
+                flat=feed["flat"], feed_key=feed.get("key"), mode=mode,
+                said=said, limbs=plan.limbs, key_scalars=key_scalars,
+                param_dts=tuple(const_dts), finish=finish, LO=LO)
+            if meta is not None:
+                meta["prepared"] = rec
+                runner.flight_recorder.note_prepared("builds")
         if first:
-            cache[key] = entry
-            if pdts:
-                runner.flight_recorder.note_const_class()
-        # success clears the transient strike count — three isolated
-        # hiccups over a process lifetime must not kill the fast path
-        cache.pop(("hashpl_tries", key), None)
-        if whole and meta is not None:
-            meta["lane_class"] = key
-        return got
+            return finish(dag, parts, LO)
+        if not whole:
+            return _Pending(parts, lambda parts: finish(dag, parts, LO))
+        lane = rec.lane(dag, consts, prepared=False)
+        if not lanes and self.launch_lanes([lane]):
+            return None     # (struck by ``launch_lanes``)
+        return lane
 
     # -- multi-lane launches --
 
@@ -757,50 +776,53 @@ class DeviceAggregator:
     def launch_lanes(self, lanes: list) -> list:
         """Send the prepared lanes of one staging (``_LanePending``s,
         in the caller's order, under the caller's hold of the dispatch
-        lock) and bind each to its launch.  Lanes of one kernel cache
+        lock) and bind each to its launch: the ONE way a warm
+        whole-feed launch of a single-device runner leaves, a request
+        alone as a launch of one lane.  Lanes of one kernel cache
         key leave as ONE jitted program that runs the built Pallas
         call once a lane, over that lane's feed and row bounds alone
         (no padded lanes, no stacked feeds: a lane is a request of its
         own, its own snapshot), and returns the packed ``(2, HI, W)``
         results stacked in pinned host memory: one
         ``_dispatch_phase("pallas_hash")`` (one flight-recorder
-        launch), one readback (``_LaneLaunch``).  A lane count without
-        a built program (more than ``_LANE_COUNTS`` has, or one still
-        building on ``_build_lanes``'s threads) leaves as the largest
-        built count plus the rest, single launches at worst: the thread
-        that stages never compiles.
+        launch, ``prepared``: its lanes staged from their class's
+        record alone), one readback (``_LaneLaunch``).  A lane count
+        without a built program (more than ``_LANE_COUNTS`` has, or one
+        still building on ``_build_lanes``'s threads) leaves as the
+        largest built count plus the rest, single launches at worst:
+        the thread that stages never compiles.
 
         → the lanes whose launch FAILED (unbound; the caller releases
-        their pins and their members retry solo).
+        their pins and their members retry solo; a failed launch of
+        ONE lane is the kernel's own: ``_pallas_failed``).
         """
         runner = self._runner
         by_key: dict = {}
         for p in lanes:
-            by_key.setdefault(p.kernel[0], []).append(p)
+            by_key.setdefault(p.rec.key, []).append(p)
         failed = []
         for key, todo in by_key.items():
-            entry = todo[0].kernel[1]
+            entry = todo[0].rec.entry
             while todo:
                 k, prog = self._lane_program(entry, key, todo)
                 batch, todo = todo[:k], todo[k:]
+                rec = batch[0].rec
                 try:
                     with runner._dispatch_phase(
-                            "pallas_hash", key,
-                            slot_mode=batch[0].kernel[4],
-                            **batch[0].kernel[5]) as info:
+                            "pallas_hash", key, slot_mode=rec.mode,
+                            prepared=sum(p.prepared for p in batch),
+                            **rec.said) as info:
                         if k > 1:
                             trace.annotate(lanes=k)
                             with jax.enable_x64(False):
                                 out = prog(
-                                    tuple(p.kernel[2].scalars(*p.kernel[3])
-                                          for p in batch),
-                                    tuple(p.cols for p in batch))
+                                    tuple(p.rec.run.scalars(
+                                        *p.rec.bounds, p.pvals)
+                                        for p in batch),
+                                    tuple(p.rec.cols for p in batch))
                         else:
-                            _key, _entry, run, bounds, _mode, _said = \
-                                batch[0].kernel
-                            lo, hi, base, blk0, pvals = bounds
-                            out = [run(lo, hi, base, blk0, batch[0].cols,
-                                       pvals)]
+                            out = [rec.run(*rec.bounds, rec.cols,
+                                           batch[0].pvals)]
                     launch = _LaneLaunch(runner, out)
                 except Exception as e:  # noqa: BLE001 — members go solo
                     self._lane_launch_failed(entry, key, k, e)
@@ -808,12 +830,14 @@ class DeviceAggregator:
                     continue
                 with self._lane_mu:
                     self.lanes_hist[k] = self.lanes_hist.get(k, 0) + 1
-                entry.get("lane_fails", {}).pop(k, None)
+                if entry.get("lane_fails", {}).pop(k, None) and k == 1:
+                    # (success clears the kernel's transient strikes)
+                    runner._kernel_cache.pop(("hashpl_tries", key), None)
                 for i, p in enumerate(batch):
                     p.launch, p.index = launch, i
                     p.info = dict(info, attrs=dict(
                         info.get("attrs", ()), lanes=k, lane=i))
-                    p.kernel = p.cols = None
+                    p.rec = p.pvals = None
         return failed
 
     def _lane_program(self, entry: dict, key, todo: list) -> tuple:
@@ -872,6 +896,9 @@ class DeviceAggregator:
             give_up = k > 1 and fails[k] >= self._LANE_PROGRAM_TRIES
             if give_up:
                 entry["lane_progs"][k] = False
+        if k == 1:
+            # one lane is the kernel's own ``run``: its strikes
+            self._pallas_failed(key, e, building=False)
         _log.warning(
             "pallas hash %d-lane launch failed for plan %r (%s: %s); its "
             "members retry solo%s", k, key[1], type(e).__name__, e,
@@ -1472,24 +1499,20 @@ class DeviceAggregator:
         mode = pallas_hash.MODE_SIMPLE
         if agg_bodies(runner._is_tpu, runner._nshards(), plan, feed, dtypes,
                       layouts, p8, pf, 1, mode, False)[0] == "pallas_hash":
-            got = self._try_pallas(dag, plan, feed, dtypes, n, 0, 1,
-                                   layouts, p8, arg_nbytes, arg_ok_is_mask,
-                                   mode, meta=meta, lanes=lanes)
-            if got is not None:
-                synced, parts, LO = got
 
-                def from_packed(parts):
-                    # the fetched accumulator is a grid of ONE slot: no
-                    # key, no NULL group, no scrap row (``slots`` 1)
-                    return result(self._packed_columns(
+            def from_packed(dag, parts, LO):
+                # the fetched accumulator is a grid of ONE slot: no
+                # key, no NULL group, no scrap row (``slots`` 1)
+                return runner._result(
+                    dag, list(agg_out[0]), self._packed_columns(
                         plan, parts, LO, p8, layouts, 1, 0, 1, None,
                         _result_shape(plan)))
 
-                if isinstance(parts, _LanePending):
-                    parts.finalize = from_packed
-                    return parts
-                return from_packed(parts) if synced \
-                    else _Pending(parts, from_packed)
+            got = self._try_pallas(dag, plan, feed, dtypes, n, 0, 1,
+                                   layouts, p8, arg_nbytes, arg_ok_is_mask,
+                                   mode, from_packed, meta=meta, lanes=lanes)
+            if got is not None:
+                return got
 
         chunk = runner._pick_chunk(feed["n_pad"], _CHUNK_AGG)
         n_cols = len(plan.used_cols)

@@ -296,30 +296,87 @@ class _Pending:
                 pass
 
 
+@dataclass(slots=True)
+class _Prepared:
+    """What a warm whole-feed Pallas launch needs, computed ONCE a
+    (line, generation, const-blind class) and kept in that request
+    memo as ``meta["prepared"]`` (aggregate.DeviceAggregator
+    ``_try_pallas`` writes it where it has a built kernel and the whole
+    feed of a single-device runner; ``DeviceRunner._refresh_meta`` drops
+    it with the generation, the arena's bucket with the line).  A
+    request of the class then stages from it (``DeviceRunner.
+    _stage_prepared``): its guards, its own operands, its pin.
+
+    ``key`` / ``entry``: the kernel cache key (the launch class
+    ``DeviceRunner.launch_class`` tells the coalescer) and the entry it
+    named when the record was written; ``run`` / ``bounds``: the built
+    kernel of the one tile and ``(row_lo, row_hi, base, blk0)``;
+    ``cols``: the feed's kernel inputs, the sparse slot column
+    included; ``feed`` / ``flat`` / ``feed_key``: the feed they were cut
+    from, its planes as they were and the key it is cached under (a hit
+    holds all three to the arena's bucket by identity); ``mode`` /
+    ``said``: the slot mode and what the recorder says of the launch;
+    ``limbs``: the plan variant in force (``DeviceRunner.
+    _limb_variant``); ``key_scalars`` / ``param_dts``: a composite
+    key's bounds, which ride ahead of the request's constants, and the
+    constants' dtypes the kernel was built for; ``finish(dag, parts,
+    LO)`` / ``LO``: the finalize with everything but the request fixed.
+    Not a dict, and none of its fields is named as a feed's are: the
+    arena counts the ``flat`` and ``sparse_slots`` of a bucket's dict
+    values (supervisor ``_bucket_arrays``), and a record holds no byte
+    the feed and the memo do not."""
+
+    key: tuple
+    entry: dict
+    run: object
+    bounds: tuple
+    cols: tuple
+    feed: dict
+    flat: tuple
+    feed_key: Optional[tuple]
+    mode: str
+    said: dict
+    limbs: tuple
+    key_scalars: tuple
+    param_dts: tuple
+    finish: object
+    LO: int
+
+    def lane(self, dag, pvals, prepared: bool) -> "_LanePending":
+        """One request of the class, ready to leave: ``pvals`` are ITS
+        constants, ``prepared`` whether it was staged from the record
+        alone."""
+        finish, LO = self.finish, self.LO
+        return _LanePending(self, self.key_scalars + tuple(pvals),
+                            lambda parts: finish(dag, parts, LO), prepared)
+
+
 class _LanePending(_Pending):
-    """One LANE of a multi-lane launch (aggregate.DeviceAggregator
-    ``launch_lanes``): a hash aggregation prepared as a request of its
-    own (its own feed, row bounds, snapshot and finalize) whose kernel
-    call has not left yet, because the dispatcher is staging other
-    closed groups of the same compile class and all of them leave as
-    ONE program.  Until ``launch_lanes`` binds it, it holds what its
-    call needs (``kernel``: the kernel cache key, its entry, the built
-    ``run``, the row bounds with the lane's OWN constants, and the slot
-    mode; ``cols``: its feed's kernel inputs);
-    after, ``launch`` is the shared fetch and ``index`` this lane's
-    place in it.  ``info``: the launch's ``_dispatch_phase`` record,
-    for the ``device_dispatch`` span every member's trace gets."""
+    """One LANE of a launch of lanes (aggregate.DeviceAggregator
+    ``launch_lanes``): an aggregation over a whole feed prepared as a
+    request of its own (its own feed, row bounds, snapshot, constants
+    and finalize) whose kernel call has not left yet: the dispatcher
+    may be staging other closed groups of the same compile class, all
+    of which leave as ONE program, and a request alone leaves as a
+    launch of one lane.  Until ``launch_lanes`` binds it, it holds what
+    its call needs (``rec``: the ``_Prepared`` record of its class;
+    ``pvals``: the lane's OWN operands); after, ``launch`` is the
+    shared fetch and ``index`` this lane's place in it.  ``prepared``:
+    staged from the record alone (counted on the launch).  ``info``:
+    the launch's ``_dispatch_phase`` record, for the
+    ``device_dispatch`` span every member's trace gets."""
 
-    __slots__ = ("kernel", "cols", "launch", "index", "info")
+    __slots__ = ("rec", "pvals", "prepared", "launch", "index", "info")
 
-    def __init__(self, kernel, cols):
+    def __init__(self, rec, pvals, finalize, prepared: bool):
         # no tree yet, nothing to stage: the launch stages its one
         # stacked output
         self.tree = None
-        self.finalize = None
+        self.finalize = finalize
         self.small = True
-        self.kernel = kernel
-        self.cols = cols
+        self.rec = rec
+        self.pvals = pvals
+        self.prepared = prepared
         self.launch = None
         self.index = 0
         self.info = None

@@ -94,6 +94,7 @@ from .request import (
     _LanePending,
     _Pending,
     _Plan,
+    _Prepared,
     _remap_rpn,
     _rpn_col_indices,
     _rpn_device_safe,
@@ -1031,8 +1032,12 @@ class DeviceRunner:
         included: a warm launch only hits; ``agg_params``: launches
         that carried an aggregation's constants as kernel operands,
         const-blind kernel entries built, scaled-DECIMAL and int32-date
-        planes cut (``FlightRecorder.agg_param_counts``); ``lanes``: this runner's
-        multi-lane launches, ``DeviceAggregator.lane_stats``; all
+        planes cut (``FlightRecorder.agg_param_counts``); ``prepared``:
+        warm whole-feed Pallas launches staged from their class's
+        prepared record, ``hits`` a lane, the records written
+        (``builds``) and dropped, by cause (``drops``:
+        ``FlightRecorder.prepared_counts``); ``lanes``: this runner's
+        launches of lanes, ``DeviceAggregator.lane_stats``; all
         monotone), the resident
         bytes of the live mesh's fullest shard, and the placement
         rollup."""
@@ -1053,6 +1058,7 @@ class DeviceRunner:
                        native.hash_finalize_packed is not None},
                "scalar_cache": self.flight_recorder.scalar_counts(),
                "agg_params": self.flight_recorder.agg_param_counts(),
+               "prepared": self.flight_recorder.prepared_counts(),
                "lanes": self.lane_stats(),
                "submesh_rebuilds": self._submesh_rebuilds,
                "feed_bytes_per_shard": max(
@@ -1216,11 +1222,11 @@ class DeviceRunner:
         Two groups of one launch class are LANES of one launch: the
         same runner (slice), the same plan and the same kernel compile
         class (``n_pad`` bucket, dtypes, ``capacity``, slot mode,
-        ``arg_nbytes``), which is the kernel cache key the request's
-        last whole-feed Pallas launch left in its memo
-        (``DeviceAggregator._try_pallas``, ``lane_class``; one memo a
-        line: two generations of a line are two lanes of one class,
-        and a refresh drops it until the next launch).  Only a
+        ``arg_nbytes``), which is the kernel cache key of the prepared
+        record the request's last whole-feed Pallas launch left in its
+        memo (``DeviceAggregator._try_pallas``, ``_Prepared.key``; one
+        memo a line: two generations of a line are two lanes of one
+        class, and a refresh drops it until the next launch).  Only a
         ``share`` group of an aggregation (GROUP BY or not) that the
         Pallas body has already served whole has one (the class is
         const-blind: groups that differ in their constants alone are
@@ -1249,10 +1255,8 @@ class DeviceRunner:
                                            create=False)
         meta = per_storage.get(("meta", runner._meta_key(dag, plan))) \
             if per_storage is not None else None
-        if not meta:
-            return None
-        klass = meta.get("lane_class")
-        return None if klass is None else prefix + klass
+        rec = meta.get("prepared") if meta else None
+        return None if rec is None else prefix + rec.key
 
     def lanes_ready(self, klass, storage) -> bool:
         """Whether groups of launch class ``klass`` over DIFFERENT
@@ -1274,7 +1278,8 @@ class DeviceRunner:
         leads of closed ``share`` groups with one ``launch_class``:
         under one hold of the dispatch lock each lane is prepared as a
         request of its own (``_handle_local``: its memo, its feed, its
-        row bounds, its arena pin), then the prepared kernels leave
+        row bounds, its arena pin; from its class's prepared record
+        where one stands), then the prepared kernels leave
         together (``DeviceAggregator.launch_lanes``: one program, one
         Pallas call a lane, one fetch).
 
@@ -1824,7 +1829,7 @@ class DeviceRunner:
                 getattr(lineage, "split_stash", None):
             feed = self._take_split_feed(lineage, feed_key, n)
             if feed is not None:
-                cache[feed_key] = feed
+                self._cache_feed(cache, feed_key, feed)
                 self._arena.admit(anchor)
                 if feed.get("lineage_v") == req_v or self._try_patch_feed(
                         feed, lineage, used_infos, dtypes, n, req_v):
@@ -1857,7 +1862,7 @@ class DeviceRunner:
                         tracker.label("device_feed", "device_resolve")
                         feed["lineage_v"] = req_v
                         self._mark_splittable(feed, used_infos)
-                        cache[feed_key] = feed
+                        self._cache_feed(cache, feed_key, feed)
                         self._arena.admit(anchor)
                         self._register_digests(lineage, feed_key, feed)
                         return feed
@@ -1876,12 +1881,20 @@ class DeviceRunner:
         if positional:
             self._mark_splittable(feed, used_infos)
         if cache is not None:
-            cache[feed_key] = feed
+            self._cache_feed(cache, feed_key, feed)
             # admission runs under the dispatch lock (this call site):
             # the budget check may evict other, unpinned anchors
             self._arena.admit(anchor)
             self._register_digests(lineage, feed_key, feed)
         return feed
+
+    @staticmethod
+    def _cache_feed(bucket: dict, feed_key, feed: dict) -> None:
+        """``feed`` into its anchor's bucket.  A cached feed says what
+        it is cached under (``key``): a prepared record holds it to
+        that slot of the bucket by identity (``_stage_prepared``)."""
+        feed["key"] = feed_key
+        bucket[feed_key] = feed
 
     @staticmethod
     def _register_digests(lineage, feed_key, feed) -> None:
@@ -2342,7 +2355,7 @@ class DeviceRunner:
                         nf.get("lineage_v") is not None and \
                         cur["lineage_v"] >= nf["lineage_v"]:
                     continue
-                bucket[fkey] = nf
+                self._cache_feed(bucket, fkey, nf)
                 self._register_digests(
                     anchor if hasattr(anchor, "feed_digests") else None,
                     fkey, nf)
@@ -2701,7 +2714,8 @@ class DeviceRunner:
     def _dispatch_phase(self, klass: str, key=None, params: int = 0,
                         slot_mode: str = "", keys: int = 0,
                         planes: int = 0, limb_sums: int = 0,
-                        slots: int = 0, block_rows: int = 0):
+                        slots: int = 0, block_rows: int = 0,
+                        prepared: int = 0):
         """Every kernel launch site runs under this: the
         ``device_dispatch`` tracker span, plus one flight-recorder
         entry (launch wall, compile class, first-launch flag, mesh
@@ -2718,7 +2732,10 @@ class DeviceRunner:
         it contracts and the SUMs it sums as limbs; ``slots`` /
         ``block_rows``: the grid it contracts them over and the rows a
         grid step takes (on the span and in the entry; counted on
-        ``/health`` ``device_mesh.agg_params``)."""
+        ``/health`` ``device_mesh.agg_params``); ``prepared``: the
+        launch's lanes that were staged from their class's prepared
+        record alone (``_stage_prepared``; ``device_mesh.prepared``
+        ``hits``)."""
         from .. import resource_metering as rm
         from ..utils import tracker
         rec = self.flight_recorder
@@ -2758,7 +2775,7 @@ class DeviceRunner:
                         whole_mesh=self._failover_parent is None,
                         params=params, slot_mode=slot_mode, keys=keys,
                         planes=planes, limb_sums=limb_sums, slots=slots,
-                        block_rows=block_rows)
+                        block_rows=block_rows, prepared=prepared)
                     tracker.annotate(**entry)
                     info["attrs"] = entry
                 info["t0_ns"], info["t1_ns"] = t0_ns, t1_ns
@@ -3163,6 +3180,12 @@ class DeviceRunner:
             if memo_fresh():
                 meta["host_cols"] = built
 
+        # what a warm whole-feed Pallas launch of this class needs was
+        # left in the memo by the last one (``_Prepared``): a request
+        # of the class stages from it (its guards, its own operands,
+        # its pin) and nothing below the guards runs
+        rec = meta.get("prepared") if _stack is None and \
+            tile_spans is None and memo_fresh() else None
         pin_anchor = None
         try:
             _fp_degrade("device::before_dispatch")
@@ -3170,6 +3193,21 @@ class DeviceRunner:
             # slice, and fail the way the chip would when
             # device::slice_dead names one of mine
             self._preflight_slice()
+            if rec is not None:
+                with nullcontext() if _lanes else self._dispatch_locked():
+                    staged = self._stage_prepared(rec, meta, dag, plan,
+                                                  storage, req_v, _lanes)
+                if staged is not None:
+                    result, pin_anchor = staged
+                    if deferred:
+                        return DeferredResult(self, result, dag, storage,
+                                              pin_anchor=pin_anchor)
+                    try:
+                        return self._apply_output_offsets(
+                            dag, self._finish(result))
+                    finally:
+                        self._arena.unpin(pin_anchor)
+                        pin_anchor = None
             dtypes = get_dtypes()
             if memo["limbs"]:
                 # this feed's bounds ask for products summed as 16-bit
@@ -3230,15 +3268,19 @@ class DeviceRunner:
                     # landing between the preflight gate and the launch
                     # means a kernel ran on a condemned chip
                     self._health.launched_quarantined += 1
-                if isinstance(result, _Pending) and \
-                        hasattr(storage, "scan_columns"):
-                    # pin the line for the in-flight dispatch: budget
-                    # eviction (arena.admit, also under this lock) must
-                    # never reclaim HBM a launched kernel still reads
+                if hasattr(storage, "scan_columns"):
                     anc = self._feed_anchor(storage)
-                    pin_anchor = self._arena.pin(anc)
+                    if isinstance(result, _Pending):
+                        # pin the line for the in-flight dispatch:
+                        # budget eviction (arena.admit, also under this
+                        # lock) must never reclaim HBM a launched
+                        # kernel still reads
+                        pin_anchor = self._arena.pin(anc)
                     # re-account: the run may have cached new device
-                    # state (sparse slot planes) in the request memo
+                    # state (sparse slot planes) in the request memo.
+                    # After EVERY full staging, a first build's that
+                    # settled in line too: the requests that follow are
+                    # staged from its record and admit nothing
                     self._arena.admit(anc)
             if isinstance(result, _Pending) and not deferred:
                 # synchronous callers block here; the before_fetch
@@ -3277,6 +3319,61 @@ class DeviceRunner:
             return DeferredResult(self, result, orig_dag, storage,
                                   pin_anchor=pin_anchor)
         return self._apply_output_offsets(orig_dag, result)
+
+    def _stage_prepared(self, rec: _Prepared, meta: dict, dag, plan,
+                        storage, req_v, lanes: bool):
+        """One request staged from its class's prepared record, under
+        the dispatch lock → ``(its lane, its arena pin)``, launched
+        unless the caller launches its ``lanes`` together; or None where
+        the record no longer stands, and the caller stages in full
+        (which writes the next record).  The caller has held the memo
+        to the request's generation (a write is followed by one full
+        staging) and fired the dispatch's guards; what is checked here,
+        on every request: the arena's bucket still holds THAT feed
+        under its key, planes untouched and at this generation (so a
+        budget eviction, ``drop_feed``, a scrub quarantine, a split's
+        or a move's take, a patch or a re-upload all miss), and the
+        kernel cache still holds THAT entry (a failed launch's
+        ``False`` misses).  Then the request's own: its operands from
+        ITS plan, its pin.  No ``arena.admit``: a hit caches no new
+        device state (it stays on every miss, where a feed or a slot
+        column may have been added)."""
+        from ..utils import tracker
+        anchor = self._feed_anchor(storage)
+        bucket = self._arena.bucket(anchor, create=False)
+        feed = rec.feed
+        if rec.limbs:
+            plan = self._limb_variant(plan, rec.limbs)
+        _sel, _aggs, pvals, pdts = pallas_hash.plan_params(plan)
+        cause = None
+        if bucket is None or bucket.get(rec.feed_key) is not feed or \
+                feed["flat"] is not rec.flat or \
+                feed.get("lineage_v") != req_v:
+            cause = "feed"
+        elif self._kernel_cache.get(rec.key) is not rec.entry or \
+                tuple(pdts) != rec.param_dts:
+            # (a memo is a const-blind class's, and so are the
+            # operands' dtypes the kernel was built for: held, not
+            # assumed)
+            cause = "kernel"
+        if cause is not None:
+            if meta.get("prepared") is rec:
+                del meta["prepared"]
+                self.flight_recorder.note_prepared(cause)
+            return None
+        tracker.label("device_feed", "hit")
+        lane = rec.lane(dag, pvals, prepared=True)
+        if self._health is not None and self._health.quarantined():
+            # (the invariant counter chaos audits: ``_handle_local``)
+            self._health.launched_quarantined += 1
+        # pin the line for the in-flight dispatch, as every launch does
+        pin = self._arena.pin(anchor)
+        if not lanes and self._aggregator.launch_lanes([lane]):
+            # the launch failed (struck): the full staging decides what
+            # serves this request
+            self._arena.unpin(pin)
+            return None
+        return lane, pin
 
     def _finish(self, pending: _Pending):
         """Blocking fetch + host finalize for a dispatched request."""
@@ -3351,7 +3448,10 @@ class DeviceRunner:
         meta.pop("n_rows", None)
         meta.pop("host_cols", None)
         meta.pop("sparse_slots", None)
-        meta.pop("lane_class", None)    # re-learnt by the next launch
+        if meta.pop("prepared", None) is not None:
+            # (the record, and with it the line's launch class: both
+            # re-learnt by the next launch, which stages in full)
+            self.flight_recorder.note_prepared("refresh")
         meta.pop("key_dense", None)     # (likewise: run_hash)
         meta.pop("key_dense_tiled", None)
         # (a lowered plan's dtypes stand on ``lowering.fits``'s proof

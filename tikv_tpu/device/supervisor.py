@@ -169,6 +169,13 @@ class FlightRecorder:
         self.limb_sums = 0
         self.planes_sum = 0
         self.slots_sum = 0
+        # warm whole-feed Pallas launches staged from their class's
+        # prepared record (device/request.py ``_Prepared``): lanes that
+        # left staged from one (``hits``), records written, and records
+        # dropped, by what a request found changed
+        self.prepared_hits = 0
+        self.prepared_builds = 0
+        self.prepared_drops = {"refresh": 0, "feed": 0, "kernel": 0}
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -181,7 +188,7 @@ class FlightRecorder:
              whole_mesh: bool = False, params: int = 0,
              slot_mode: str = "", keys: int = 0, planes: int = 0,
              limb_sums: int = 0, slots: int = 0,
-             block_rows: int = 0) -> dict:
+             block_rows: int = 0, prepared: int = 0) -> dict:
         ck = (klass, key)
         with self._mu:
             first = ck not in self._seen
@@ -204,6 +211,8 @@ class FlightRecorder:
             self.limb_sums += limb_sums
             self.planes_sum += planes
             self.slots_sum += slots
+            if ok:
+                self.prepared_hits += prepared
             entry = {"t_unix_s": round(time.time(), 6),
                      "launch_ms": round(wall_s * 1e3, 3),
                      "compile_class": klass,
@@ -224,6 +233,8 @@ class FlightRecorder:
                      # rows a grid step took (the step follows the grid)
                      "slots": int(slots),
                      "block_rows": int(block_rows),
+                     # its lanes staged from a prepared record alone
+                     "prepared": int(prepared),
                      "ok": ok}
             self._ring.append(entry)
         return entry
@@ -254,6 +265,23 @@ class FlightRecorder:
                     "limb_sums": self.limb_sums,
                     "planes_sum": self.planes_sum,
                     "slots_sum": self.slots_sum}
+
+    def note_prepared(self, event: str) -> None:
+        """A prepared record written (``builds``) or dropped
+        (``refresh`` / ``feed`` / ``kernel``: the generation moved, the
+        arena no longer holds the feed it was cut from, the kernel
+        cache no longer holds its entry)."""
+        with self._mu:
+            if event == "builds":
+                self.prepared_builds += 1
+            else:
+                self.prepared_drops[event] += 1
+
+    def prepared_counts(self) -> dict:
+        with self._mu:
+            return {"hits": self.prepared_hits,
+                    "builds": self.prepared_builds,
+                    "drops": dict(self.prepared_drops)}
 
     def note_scalar(self, hit: bool) -> None:
         with self._mu:

@@ -111,7 +111,11 @@ SPAN_VOCABULARY: dict[str, str] = {
     "dispatch_lock_wait": "a request's launch path waiting for the "
                           "runner's dispatch lock (device/runner.py "
                           "_dispatch_locked; a lane launch takes none)",
-    "device_dispatch": "kernel launch enqueue (flight-recorder attrs)",
+    "device_dispatch": "kernel launch enqueue (flight-recorder attrs, "
+                       "among them prepared: the launch's lanes staged "
+                       "from their class's prepared record alone, "
+                       "counted on /health device_mesh.prepared "
+                       "{hits, builds, drops})",
     "d2h_wait": "device→host transfer + sync wait",
     "device_wait": "span-only child of d2h_wait: block_until_ready on "
                    "the result leaves (the program has not finished)",
