@@ -42,6 +42,7 @@ and no result line; a failed run prints neither.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import re
@@ -62,6 +63,7 @@ GROUPS = 1024
 BUILD_ROWS = 1024               # join build side
 PLACEMENT_ROWS = 1 << 18        # per table, placement leg (4 chips)
 TABLE_ID = 9900
+MUX_ROWS = 1 << 19              # the fan-out's table: two regions of half
 DEFAULT_ROW_THRESHOLD = 131072  # etc/config-template.toml
 LOAD_CHUNK = 1 << 20
 SEL_FLOOR = 960                 # c1 >= 960: 2% of [-1000, 1000)
@@ -420,7 +422,8 @@ class ServedLeg:
                          r"device_kind='(.*)' n_devices=(\d+) mesh=(\S+) "
                          r"native_finalize=(yes|no) "
                          r"native_encode=(yes|no) "
-                         r"gil_probe=(native|overshoot)", ln.strip())
+                         r"gil_probe=(native|overshoot) mux=(\S+)",
+                         ln.strip())
         if m is None:
             raise SmokeFailure(f"platform check: cannot parse {ln!r}")
         # the store says itself whether its hash-agg finalize is the one
@@ -622,7 +625,7 @@ class ServedLeg:
 
 def served_leg(args, checks: Checks, workdir: str) -> dict:
     """Phase A's main leg: single chip (1x1) or the whole 2x2 mesh."""
-    from tikv_tpu.codec.keys import table_record_range
+    from tikv_tpu.codec.keys import table_record_key, table_record_range
     from tikv_tpu.copr import plan_ir as pir
     from tikv_tpu.copr.dag import (
         AggExprDesc,
@@ -655,6 +658,16 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
         q15_t = q15_table()
         q15d = q15_data(args.seed, min(n, Q1_ROWS))
         leg.load_q15(q15_t, q15d)
+        # the fan-out's table (PR 43), the highest id: its second half
+        # is a region of its own, cut BEFORE its rows arrive, so every
+        # other table still sits in one region
+        mux_t = int_table(2, table_id=TABLE_ID + 40)
+        half = min(n, MUX_ROWS) // 2
+        m0, m1 = table_data(args.seed, 40, 2 * half)
+        c.split(table_record_key(mux_t.table_id, half))
+        for lo in (0, half):
+            leg.load(mux_t, np.arange(lo, lo + half, dtype=np.int64),
+                     m0[lo:lo + half], m1[lo:lo + half])
         log(f"loaded {n} rows in {load_s:.1f}s")
 
         def select():
@@ -947,6 +960,69 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
             post["labels"].get("copr_cache") == "delta" and
             post["labels"].get("device_feed") == "patch", post["labels"])
 
+        # -- a fan-out's cop tasks ride the store's ONE BatchCommands
+        #    stream as raw commands; coprocessor() stays a unary call --
+        def mux_plan():
+            s = DagSelect.from_table(mux_t, ["id", "c0", "c1"])
+            return s.aggregate(
+                [s.col("c0")],
+                [("count_star", None), ("sum", s.col("c1"))]
+            ).build(start_ts=c.tso())
+
+        def carried() -> dict:
+            h = http_json(leg.status_port, "/health")
+            return dict(h["batch_commands"],
+                        served=h["coprocessor"]["requests_served"])
+
+        fan = []
+        for i in range(3):
+            before = carried()
+            fan.append(leg.request(
+                f"fanout {i}", lambda: c.coprocessor_fanout(
+                    mux_plan(), timeout=900), CLASS_PALLAS))
+            after = carried()
+            merged: dict = {}
+            for part in fan[-1]["resp"]["responses"]:
+                for cnt, sm, key in part["rows"]:
+                    acc = merged.setdefault(key, [0, 0])
+                    acc[0] += cnt
+                    acc[1] += sm
+            same_rows(f"fanout {i}",
+                      [[v[0], v[1], k] for k, v in merged.items()],
+                      ref_hash_agg(m0, m1))
+            checks.require(
+                f"fanout {i}: two cop tasks, both raw commands on the mux",
+                fan[-1]["resp"]["tasks"] == 2 and
+                after["raw_commands"] - before["raw_commands"] ==
+                after["served"] - before["served"] == 2 and
+                after["unary_resends"] == 0 and
+                "unary_resends" not in fan[-1]["labels"],
+                f"{before} -> {after}, labels={fan[-1]['labels']}")
+        checks.require("fanout warm: fast-path hit over the mux, the seven "
+                       "wire phases on the reply",
+                       fan[-1]["labels"].get("fastpath") == "hit" and
+                       all(k in fan[-1]["phases_ms"] for k in (
+                           "client_route", "client_encode", "wire_request",
+                           "rpc_accept_wait", "wire_reply",
+                           "client_decode")),
+                       f"{fan[-1]['labels']} {fan[-1]['phases_ms']}")
+        before = carried()
+        lone = leg.request("left half by coprocessor()", lambda: c.coprocessor(
+            dataclasses.replace(mux_plan(), ranges=(KeyRange(
+                table_record_range(mux_t.table_id)[0],
+                table_record_key(mux_t.table_id, half)),)), timeout=900),
+            CLASS_PALLAS)
+        after = carried()
+        same_rows("left half by coprocessor()", lone["resp"]["rows"],
+                  ref_hash_agg(m0[:half], m1[:half]))
+        checks.require(
+            "coprocessor() sends no command",
+            after["raw_commands"] == before["raw_commands"] and
+            after["commands_in"] == before["commands_in"] and
+            after["served"] - before["served"] == 1,
+            f"{before} -> {after}")
+        log(f"mux: {after}")
+
         rollup = leg.health_checks()
         by_dev = rollup["hbm"]["resident_bytes_by_device"]
         checks.require(
@@ -987,7 +1063,9 @@ def served_leg(args, checks: Checks, workdir: str) -> dict:
                      "first_s": round(jn["wall_s"], 3), "router": routed},
             "after_write": {**family([post]),
                             "device_feed":
-                            post["labels"].get("device_feed")}},
+                            post["labels"].get("device_feed")},
+            "fanout_mux": family(fan)},
+        "batch_commands": {k: v for k, v in after.items() if k != "served"},
         "resident_bytes_by_device": by_dev,
         "pinned_readback": rollup["pinned_readback"],
         "flight_recorder": rollup["flight_recorder"],

@@ -78,5 +78,5 @@ def test_with_device_store_refuses_an_unasked_for_cpu_fallback():
     # ... and whether the GIL probe samples through the extension
     from tikv_tpu.utils.trace import gil_mode
     assert f" native_finalize={built} native_encode={enc} " \
-        f"gil_probe={gil_mode()}\n" in r.stdout, r.stdout
+        f"gil_probe={gil_mode()} mux=raw\n" in r.stdout, r.stdout
     assert "found no accelerator" in r.stderr, r.stderr[-2000:]

@@ -162,7 +162,7 @@ def serve(table_kind, runner, names):
         client._fanout_executor(15).submit(gate.wait)
     gate.wait()
     yield types.SimpleNamespace(
-        node=node, runner=runner, client=client, pd_addr=pd_addr,
+        node=node, srv=srv, runner=runner, client=client, pd_addr=pd_addr,
         ctxs=ctxs, TxnClient=TxnClient,
         status_port=srv.status_server.port)
     client.close()

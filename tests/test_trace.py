@@ -1291,8 +1291,9 @@ def test_e2e_health_threads_by_role(rig):
     ta, tb = a["threads"], b["threads"]
     assert tb["source"] in ("thread_cpuclock", "proc_stat")
     assert set(tb["roles"]) == {
-        "grpc_serve", "rpc_handler", "copr-coalescer", "copr-dispatcher",
-        "copr-completion", "status-server", "gil-probe", "other"}
+        "grpc_serve", "rpc_handler", "mux_command", "mux_stream",
+        "copr-coalescer", "copr-dispatcher", "copr-completion",
+        "status-server", "gil-probe", "other"}
     for role in ("grpc_serve", "rpc_handler", "copr-dispatcher",
                  "copr-completion", "status-server", "gil-probe", "other"):
         assert tb["roles"][role]["threads"] >= 1, (role, tb["roles"])

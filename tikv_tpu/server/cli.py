@@ -164,7 +164,7 @@ def main(argv=None) -> int:
                   f"{'yes' if ms['finalize']['native_available'] else 'no'}"
                   f" native_encode="
                   f"{'no' if native.encode_rows_msgpack is None else 'yes'}"
-                  f" gil_probe={gil_mode()}",
+                  f" gil_probe={gil_mode()} mux=raw",
                   flush=True)
             if ms["platform"] == "cpu" and "cpu" not in os.environ.get(
                     "JAX_PLATFORMS", "").split(","):

@@ -9,6 +9,7 @@ callers but its tests exercise via test fixtures.
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from typing import Optional, Sequence
@@ -28,15 +29,22 @@ class StoreClient:
         self.addr = addr
         from .security import make_channel
         self._chan = make_channel(addr)
+        # this store's ONE BatchCommands stream, opened at the first
+        # command and again at the first after it died
+        self._mux: Optional[BatchCommandsClient] = None
+        self._mux_mu = threading.Lock()
 
-    def call(self, method: str, req: dict, timeout: float = 10) -> dict:
+    def call(self, method: str, req: dict, timeout: float = 10,
+             resend: bool = False) -> dict:
         """One unary RPC.  A reply that carries a ``time_detail`` leaves
         with the RPC's path across the wire in it (:func:`_note_wire`):
         gRPC runs both serializers on the calling thread, so four
         stamps around them and the store's three make one timeline.
         A Coprocessor reply that is a chunk (the request's
         ``encode_type``) comes back with ``chunk`` decoded
-        (``wire.dec_chunk``), inside ``client_decode``."""
+        (``wire.dec_chunk``), inside ``client_decode``.  ``resend``: the
+        call carries again a command whose stream died, and says so in
+        its metadata (the store counts them)."""
         t_call = time.perf_counter_ns()
         at = [0, 0, 0]      # sent, bytes_in, decoded
 
@@ -47,30 +55,77 @@ class StoreClient:
 
         def unpack(raw):
             at[1] = time.perf_counter_ns()
-            obj = wire.unpack(raw)
-            if "chunk" in obj:
-                # a chunk reply's buffers wrapped as arrays where they
-                # lie: no value a cell (``wire.chunk_rows`` makes rows
-                # of them for a caller who wants values)
-                wire.dec_chunk(obj["chunk"])
+            obj = _unpack_reply(raw)
             at[2] = time.perf_counter_ns()
             return obj
 
         fn = self._chan.unary_unary(
             "/tikv.Tikv/" + method, request_serializer=pack,
             response_deserializer=unpack)
-        resp = fn(req, timeout=timeout)
-        if resp.get("error"):
-            raise wire.RemoteError(resp["error"])
-        td = resp.get("time_detail")
-        if isinstance(td, dict):
-            _note_wire(td, t_call, *at)
-        return resp
+        resp = fn(req, timeout=timeout, metadata=(
+            (wire.MUX_RESEND_KEY, "1"),) if resend else None)
+        return _checked(resp, t_call, *at)
+
+    def call_raw(self, method: str, raw: bytes,
+                 timeout: float = 10) -> tuple:
+        """``raw``, the bytes ``call`` would have sent, as one command on
+        this store's BatchCommands stream → (the reply's bytes as
+        ``call`` would have received them, ``sent`` ns, ``bytes_in`` ns:
+        ``BatchCommandsClient.call_raw``).  ``MuxClosed`` where the
+        stream died under the command; the next call opens another."""
+        with self._mux_mu:
+            mux = self._mux
+            if mux is None or mux.closed:
+                mux = self._mux = BatchCommandsClient(chan=self._chan)
+        return mux.call_raw(method, raw, timeout)
+
+    def call_mux(self, method: str, req: dict, timeout: float = 10) -> dict:
+        """``call`` over the mux: the same bytes out, the same reply
+        back, the same seven stamps to :func:`_note_wire`: ``sent`` is
+        when the MESSAGE holding the command left its serializer,
+        ``bytes_in`` when the message holding the reply entered its
+        deserializer, ``decoded`` when the command's own reply was
+        unpacked (so ``client_decode`` holds the wake of this thread
+        too)."""
+        t_call = time.perf_counter_ns()
+        reply, sent, bytes_in = self.call_raw(method, wire.pack(req),
+                                              timeout)
+        resp = _unpack_reply(reply)
+        return _checked(resp, t_call, sent, bytes_in,
+                        time.perf_counter_ns())
+
+    def close(self) -> None:
+        with self._mux_mu:
+            mux, self._mux = self._mux, None
+        if mux is not None:
+            mux.close()
 
     def __getattr__(self, name: str):
         if name.startswith("_"):
             raise AttributeError(name)
         return lambda req=None, **kw: self.call(name, req or kw)
+
+
+def _unpack_reply(raw: bytes) -> dict:
+    obj = wire.unpack(raw)
+    if "chunk" in obj:
+        # a chunk reply's buffers wrapped as arrays where they lie: no
+        # value a cell (``wire.chunk_rows`` makes rows of them for a
+        # caller who wants values)
+        wire.dec_chunk(obj["chunk"])
+    return obj
+
+
+def _checked(resp: dict, call: int, sent: int, bytes_in: int,
+             decoded: int) -> dict:
+    """A decoded reply on its way to the caller: an error raised, a
+    ``time_detail`` given the RPC's path across the wire."""
+    if resp.get("error"):
+        raise wire.RemoteError(resp["error"])
+    td = resp.get("time_detail")
+    if isinstance(td, dict):
+        _note_wire(td, call, sent, bytes_in, decoded)
+    return resp
 
 
 def _note_wire(td: dict, call: int, sent: int, bytes_in: int,
@@ -114,92 +169,121 @@ def _note_route(resp: dict, entry_ns: int) -> None:
                      td["phases_ms"])
 
 
+class MuxClosed(RuntimeError):
+    """The BatchCommands stream died before it answered the command."""
+
+
+class _MuxCall:
+    """One command in flight on a ``BatchCommandsClient``."""
+
+    __slots__ = ("cmd", "ev", "sent", "bytes_in", "ent")
+
+    def __init__(self, cmd: dict):
+        self.cmd = cmd
+        self.ev = threading.Event()
+        self.sent = 0           # ns: its message left the serializer
+        self.bytes_in = 0       # ns: its reply's message reached ours
+        self.ent = None         # the response entry
+
+
+def _pack_commands(batch: list) -> bytes:
+    raw = wire.pack({"requests": [c.cmd for c in batch]})
+    t = time.perf_counter_ns()
+    for c in batch:
+        c.sent = t
+    return raw
+
+
+def _unpack_responses(raw: bytes) -> tuple:
+    return time.perf_counter_ns(), wire.unpack(raw)
+
+
 class BatchCommandsClient:
     """Client side of the batch_commands mux (service/kv.rs:921 +
     service/batch.rs): ONE bidirectional stream carries every RPC,
     demultiplexed by request id — concurrent callers share the stream
-    instead of a connection/HTTP2-stream each."""
+    instead of a connection/HTTP2-stream each.  Two command forms
+    (``wire.mux_command``): ``call`` sends a request decoded,
+    ``call_raw`` the bytes the unary call would have sent."""
 
-    def __init__(self, addr: str):
+    def __init__(self, addr: Optional[str] = None, chan=None):
         import queue
 
         self.addr = addr
-        from .security import make_channel
-        self._chan = make_channel(addr)
+        if chan is None:
+            from .security import make_channel
+            chan = make_channel(addr)
+        self._chan = chan
         self._q: "queue.Queue" = queue.Queue()
         self._pending: dict = {}
         self._mu = threading.Lock()
         self._next_id = 0
-        self._closed = False
+        self.closed = False
         fn = self._chan.stream_stream(
-            "/tikv.Tikv/BatchCommands", request_serializer=wire.pack,
-            response_deserializer=wire.unpack)
+            "/tikv.Tikv/BatchCommands", request_serializer=_pack_commands,
+            response_deserializer=_unpack_responses)
         self._responses = fn(self._outbound())
-        self._recv = threading.Thread(target=self._recv_loop, daemon=True)
+        self._recv = threading.Thread(target=self._recv_loop, daemon=True,
+                                      name="mux-recv")
         self._recv.start()
 
     def _outbound(self):
-        import queue
-        while not self._closed:
-            try:
-                first = self._q.get(timeout=0.5)
-            except queue.Empty:
-                continue
-            if first is None:
-                return
-            batch = [first]
-            # drain whatever else queued: one message, many commands
-            while True:
-                try:
-                    nxt = self._q.get_nowait()
-                except queue.Empty:
-                    break
-                if nxt is None:
-                    break
-                batch.append(nxt)
-            yield {"requests": batch}
+        # whatever is queued: one message, many commands
+        return wire.mux_batches(self._q, None)
 
     def _recv_loop(self):
         try:
-            for msg in self._responses:
+            for bytes_in, msg in self._responses:
                 for ent in msg.get("responses", ()):
                     with self._mu:
-                        box = self._pending.pop(ent["request_id"], None)
-                    if box is not None:
-                        box["resp"] = ent["response"]
-                        box["ev"].set()
+                        c = self._pending.pop(ent["request_id"], None)
+                    if c is not None:
+                        c.bytes_in, c.ent = bytes_in, ent
+                        c.ev.set()
         except Exception:
             pass
         with self._mu:
-            # stream died: later call()s must fail fast, not park for
+            # stream died: later calls must fail fast, not park for
             # their full timeout against a reader that will never run
-            self._closed = True
+            self.closed = True
             pending, self._pending = self._pending, {}
-        for box in pending.values():
-            box["ev"].set()     # wake waiters with no resp
+        for c in pending.values():
+            c.ev.set()          # wake waiters with no response
+        self._q.put(None)       # and gRPC's thread out of _outbound
+
+    def _roundtrip(self, method: str, req, timeout: float) -> _MuxCall:
+        with self._mu:
+            if self.closed:
+                raise MuxClosed("mux closed")
+            self._next_id += 1
+            c = _MuxCall(wire.mux_command(self._next_id, method, req))
+            self._pending[self._next_id] = c
+        self._q.put(c)
+        if not c.ev.wait(timeout):
+            with self._mu:
+                self._pending.pop(c.cmd["request_id"], None)
+            raise TimeoutError(f"mux call {method} timed out")
+        if c.ent is None:
+            raise MuxClosed("mux stream closed")
+        return c
 
     def call(self, method: str, req: dict, timeout: float = 10) -> dict:
-        with self._mu:
-            if self._closed:
-                raise RuntimeError("mux closed")
-            self._next_id += 1
-            rid = self._next_id
-            box = {"ev": threading.Event()}
-            self._pending[rid] = box
-        self._q.put({"request_id": rid, "method": method, "req": req})
-        if not box["ev"].wait(timeout):
-            with self._mu:
-                self._pending.pop(rid, None)
-            raise TimeoutError(f"mux call {method} timed out")
-        resp = box.get("resp")
-        if resp is None:
-            raise RuntimeError("mux stream closed")
+        resp = self._roundtrip(method, req, timeout).ent["response"]
         if resp.get("error"):
             raise wire.RemoteError(resp["error"])
         return resp
 
+    def call_raw(self, method: str, raw: bytes,
+                 timeout: float = 10) -> tuple:
+        """→ (the reply's bytes, ``sent`` ns, ``bytes_in`` ns): when the
+        message holding the command left this end's serializer, and
+        when the message holding the reply entered its deserializer."""
+        c = self._roundtrip(method, raw, timeout)
+        return c.ent["raw"], c.sent, c.bytes_in
+
     def close(self):
-        self._closed = True
+        with self._mu:
+            self.closed = True
         self._q.put(None)
 
 
@@ -282,19 +366,37 @@ class TxnClient:
         return {sid: br.stats() for sid, br in self._breakers.items()}
 
     def _store_call(self, store_id: int, method: str, req: dict,
-                    timeout: float = 10) -> dict:
+                    timeout: float = 10,
+                    mux: Optional[list] = None) -> dict:
         """One RPC to one store through its circuit breaker.
 
         Only TRANSPORT failures (timeouts, channel errors) count
         against the breaker — a logical RemoteError proves the store
-        answered and resets it."""
+        answered and resets it.
+
+        ``mux``: None for a unary call; a fan-out task's tally ``[n]``
+        sends the request as a command on the store's BatchCommands
+        stream.  A stream that died under the command is a transport
+        failure of its own, and the request goes again ONCE, as a unary
+        call inside what is left of ``timeout`` (``mux[0]`` counts
+        them): a read does not fail for the transport's sake."""
         from ..utils.health import CircuitOpen
         br = self._breaker(store_id)
         if not br.allow():
             raise CircuitOpen(f"store {store_id}")
+        sc = self._store_client(store_id)
         try:
-            r = self._store_client(store_id).call(method, req,
-                                                  timeout=timeout)
+            if mux is None:
+                r = sc.call(method, req, timeout=timeout)
+            else:
+                t_end = time.monotonic() + timeout
+                try:
+                    r = sc.call_mux(method, req, timeout=timeout)
+                except MuxClosed:
+                    br.record_failure()
+                    mux[0] += 1
+                    r = sc.call(method, req, resend=True, timeout=max(
+                        0.001, t_end - time.monotonic()))
         except wire.RemoteError:
             br.record_success()
             raise
@@ -570,6 +672,8 @@ class TxnClient:
             if self._fanout_pool is not None:
                 self._fanout_pool[1].shutdown(wait=False)
                 self._fanout_pool = None
+        for sc in list(self._stores.values()):
+            sc.close()      # its BatchCommands stream, where one opened
 
     def replica_get(self, key: bytes,
                     version: Optional[int] = None,
@@ -827,17 +931,23 @@ class TxnClient:
         to its region's leader under the region and epoch it was cut
         for.  A task whose region changed under it (``epoch_not_match``,
         ``region_not_found``, ``not_leader``) has ITS ranges cut again
-        and re-sent inside ``timeout``; ``key_is_locked`` and anything
-        else is the caller's, as from ``coprocessor``.
+        and re-sent inside ``timeout``.  Where a store gets more than
+        one task of the read, its tasks travel as commands on that
+        ``StoreClient``'s ONE BatchCommands stream (``call_mux``: the
+        same bytes, the same reply, the same wire phases); a lone task
+        is a unary call, as ``coprocessor()``'s request is.
+        ``key_is_locked`` and anything else is the caller's, as from
+        ``coprocessor``.
 
         → one summary shaped like a single reply, with the partial
         replies under ``responses`` in range order (merging partial
         aggregates is the SQL layer's) and their count under ``tasks``:
         ``backend`` is ``"device"`` only if every task's was;
         ``time_detail.labels`` is the union of the tasks' (plus
-        ``cop_tasks``, and ``fanout_retries`` where a task was cut
-        again); ``phases_ms`` / ``total_rpc_wall_ms`` / ``trace_id`` are
-        those of the task that returned last, the read's critical path
+        ``cop_tasks``, ``fanout_retries`` where a task was cut again,
+        and ``unary_resends`` where a task's stream died under it);
+        ``phases_ms`` / ``total_rpc_wall_ms`` / ``trace_id`` are those
+        of the task that returned last, the read's critical path
         (each task has a server-minted trace id of its own: the store's
         trace buffer keeps one tracker an id), with any task's
         ``host_exec`` and the client's own phases added: ``fanout_cut``,
@@ -866,8 +976,13 @@ class TxnClient:
             with tracker.phase("fanout_cut"):
                 tasks = self._cut_by_region(dag.ranges)
             pool = self._fanout_executor(concurrency)
+            # tasks of a store that gets more than one travel as
+            # commands on that store's ONE stream; a lone task has
+            # nothing to batch with and stays a unary call
+            to_store = collections.Counter(
+                leader.store_id for _region, leader, _ranges in tasks)
             futs = [pool.submit(self._run_cop_task, task, env, timeout,
-                                t_entry)
+                                t_entry, to_store[task[1].store_id] > 1)
                     for task in tasks]
             try:
                 ran = [f.result() for f in futs]
@@ -875,7 +990,7 @@ class TxnClient:
                 for f in futs:
                     f.cancel()
                 raise
-            parts = [part for got, _n in ran for part in got]
+            parts = [part for got, _n, _m in ran for part in got]
             if not parts:
                 raise TxnError("coprocessor fan-out over no ranges")
             back = [b for _r, _s, b in parts]
@@ -901,9 +1016,12 @@ class TxnClient:
             backends.add(r.get("backend"))
         phases.update(tr.time_detail()["phases_ms"])
         labels["cop_tasks"] = str(len(replies))
-        retries = sum(n for _got, n in ran)
+        retries = sum(n for _got, n, _m in ran)
         if retries:
             labels["fanout_retries"] = str(retries)
+        resends = sum(m for _got, _n, m in ran)
+        if resends:
+            labels["unary_resends"] = str(resends)
         detail["phases_ms"], detail["labels"] = phases, labels
         return {"responses": replies, "tasks": len(replies),
                 "backend": "device" if backends == {"device"}
@@ -959,17 +1077,20 @@ class TxnClient:
                 for region, leader, pieces in tasks]
 
     def _run_cop_task(self, task, env: dict, timeout: float,
-                      t_entry: int) -> tuple:
+                      t_entry: int, mux: bool = False) -> tuple:
         """One cop task to its region's leader → ([(reply, sent_ns,
-        back_ns)], times it was cut again): more than one reply where
-        the region had changed under the task.  ``t_entry``: when the
-        fan-out was entered, where each reply's ``client_route``
-        starts."""
+        back_ns)], times it was cut again, times it was re-sent as a
+        unary call): more than one reply where the region had changed
+        under the task.  ``t_entry``: when the fan-out was entered,
+        where each reply's ``client_route`` starts.  ``mux``: the task
+        (and what it is cut into again) goes as a command on its store's
+        BatchCommands stream (``_store_call``)."""
         from ..utils.backoff import Backoff
         from ..utils.failpoint import fail_point
         from ..utils.health import CircuitOpen
         bo = Backoff(base=0.02, cap=0.5, deadline_s=timeout)
         todo, out, recuts = [task], [], 0
+        resent = [0] if mux else None
         while todo:
             region, leader, ranges = todo.pop(0)
             req = dict(env, dag=dict(env["dag"],
@@ -978,7 +1099,8 @@ class TxnClient:
             sent = time.perf_counter_ns()
             try:
                 resp = self._store_call(leader.store_id, "Coprocessor",
-                                        req, timeout=bo.rpc_timeout(timeout))
+                                        req, timeout=bo.rpc_timeout(timeout),
+                                        mux=resent)
             except wire.RemoteError as e:
                 if e.kind == "server_is_busy":
                     # overloaded, not misrouted (_call_leader)
@@ -1005,7 +1127,7 @@ class TxnClient:
             if not bo.sleep():
                 raise last
             todo[:0] = self._cut_by_region(ranges)
-        return out, recuts
+        return out, recuts, resent[0] if mux else 0
 
     def coprocessor_replica(self, dag, key_hint: Optional[bytes] = None,
                             resource_group: str = "default",

@@ -20,11 +20,18 @@ from .node import Node
 from .service import KvService
 
 
+# BatchCommands streams one server hosts at once (threads are made as
+# streams open): far above any deployment's connection count, so that no
+# stream ever queues behind another for its generator's thread
+_MAX_STREAMS = 1024
+
+
 class _HandlerPool(futures.ThreadPoolExecutor):
     """The gRPC handler pool, stamping when gRPC hands it each call:
     ``rpc_accept_wait`` runs from that stamp to the request's
     ``tracker.install`` (the pool's queue, the message receive, the
-    wait for the GIL)."""
+    wait for the GIL).  The mux's command pool is another of these:
+    there the stamp is the stream's feeder handing it a raw command."""
 
     def submit(self, fn, /, *args, **kwargs):
         return super().submit(_stamped, time.perf_counter_ns(), fn,
@@ -50,6 +57,18 @@ def _replying(pack):
     return serialize
 
 
+def _mux_replying(out):
+    """The BatchCommands response serializer: one message of every
+    response ``batch_commands`` found ready, and each raw command's
+    ``rpc_reply`` (handed over by its worker) closed when the message
+    holding its reply is packed."""
+    try:
+        return wire.pack({"responses": [resp for resp, _handed in out]})
+    finally:
+        for _resp, handed in out:
+            tracker.reply_close(handed)
+
+
 class _GenericHandler(grpc.GenericRpcHandler):
     """Routes /tikv.Tikv/* to the service: unary by default, plus the
     two streaming surfaces of the reference — coprocessor_stream
@@ -57,11 +76,17 @@ class _GenericHandler(grpc.GenericRpcHandler):
     batch_commands (service/kv.rs:921, the bidirectional mux)."""
 
     def __init__(self, prefix: str, dispatch, stream_dispatch=None,
-                 batch_dispatch=None, raw_dispatch=None):
+                 batch_dispatch=None, raw_dispatch=None,
+                 command_pool=None, stream_pool=None, mux_stats=None):
         self._prefix = prefix
         self._dispatch = dispatch
         self._stream_dispatch = stream_dispatch
         self._batch_dispatch = batch_dispatch
+        # the mux's two pools (TikvServer): raw commands run on the
+        # first, a hosted stream's response generator parks on the second
+        self._command_pool = command_pool
+        self._stream_pool = stream_pool
+        self._mux_stats = mux_stats
         # methods served from RAW wire bytes (no eager unpack): the
         # coprocessor fast path template-matches the bytes and only
         # falls back to a full decode on a miss; responses may come
@@ -86,15 +111,26 @@ class _GenericHandler(grpc.GenericRpcHandler):
 
         if method == "BatchCommands" and self._batch_dispatch is not None:
             def batch(request_iterator, ctx):
-                yield from self._batch_dispatch(request_iterator)
+                yield from self._batch_dispatch(
+                    request_iterator, self._raw_dispatch,
+                    self._command_pool)
+            # grpcio runs a handler that names a pool on THAT pool
+            # (grpc/_server.py _select_thread_pool_for_behavior): a
+            # stream's generator, parked for the stream's whole life,
+            # takes no worker from the pool the unary RPCs share
+            batch.experimental_thread_pool = self._stream_pool
             return grpc.stream_stream_rpc_method_handler(
-                batch, request_deserializer=wire.unpack,
-                response_serializer=wire.pack)
+                batch, request_deserializer=lambda b: b,
+                response_serializer=_mux_replying)
 
         if method in self._raw_dispatch:
             fn = self._raw_dispatch[method]
 
             def raw_unary(raw: bytes, ctx, fn=fn):
+                if self._mux_stats is not None:
+                    for key, _value in ctx.invocation_metadata():
+                        if key == wire.MUX_RESEND_KEY:
+                            self._mux_stats.note(unary_resends=1)
                 return fn(method, raw)
             return grpc.unary_unary_rpc_method_handler(
                 raw_unary, request_deserializer=lambda b: b,
@@ -123,6 +159,18 @@ class TikvServer:
         # (named for /health tracing.threads: role rpc_handler)
         self._pool = _HandlerPool(max_workers=max_workers,
                                   thread_name_prefix="rpc-handler")
+        # the mux (service.batch_commands): raw commands of every hosted
+        # BatchCommands stream run on ONE pool of the handler pool's
+        # width, stamped as the handler pool stamps a call, so the store
+        # admits as many cop tasks at once over the mux as over unary
+        # calls; a stream's response generator parks on a pool of its
+        # own (a thread a live stream, made when the stream opens) and
+        # its feeder on a thread of its own, so neither takes a worker
+        # from the commands nor from the unary RPCs
+        self._command_pool = _HandlerPool(max_workers=max_workers,
+                                          thread_name_prefix="mux-command")
+        self._stream_pool = futures.ThreadPoolExecutor(
+            max_workers=_MAX_STREAMS, thread_name_prefix="mux-stream")
         self._server = grpc.server(self._pool)
         self._server.add_generic_rpc_handlers((
             _GenericHandler(
@@ -135,7 +183,10 @@ class TikvServer:
                 batch_dispatch=self.service.batch_commands,
                 raw_dispatch={
                     "Coprocessor": self.service.handle_raw,
-                }),))
+                },
+                command_pool=self._command_pool,
+                stream_pool=self._stream_pool,
+                mux_stats=self.service.mux_stats),))
         from .security import bind_port
         self.port = bind_port(self._server, node.addr)
         assert self.port, f"cannot bind {node.addr}"
@@ -166,7 +217,8 @@ class TikvServer:
         # handler workers — stop-under-load must leave no threads
         self._server.stop(grace).wait()
         self.node.stop()
-        self._pool.shutdown(wait=True)
+        for pool in (self._pool, self._command_pool, self._stream_pool):
+            pool.shutdown(wait=True)
 
     def wait(self) -> None:
         self._server.wait_for_termination()

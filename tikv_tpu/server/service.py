@@ -10,7 +10,9 @@ the unary Coprocessor RPC, which is bound at the RAW-BYTES level
 fast path (server/fastpath.py) without ever decoding its body, and
 only a template miss pays the historical decode-per-request pipeline
 (which then doubles as the template learner).  Responses may come back
-pre-packed (wire.pack_response passes bytes through).
+pre-packed (wire.pack_response passes bytes through).  ``batch_commands``
+(:921) serves the same raw bytes as commands on one stream: a fan-out's
+cop tasks reach ``handle_raw`` unchanged, on a bounded pool.
 """
 
 from __future__ import annotations
@@ -54,6 +56,31 @@ _slow_query_logger = logging.getLogger("tikv_tpu.slow_query")
 _TRACE_ID_RE = re.compile(r"[0-9A-Za-z_-]{1,64}")
 
 
+class MuxStats:
+    """``/health`` ``batch_commands``: what the mux has carried since
+    process start.  ``commands_in`` over ``messages_in`` and
+    ``responses_out`` over ``messages_out`` are the batch sizes either
+    way; ``raw_commands`` over ``coprocessor.requests_served`` the share
+    of cop tasks that came as raw commands; ``unary_resends`` the unary
+    calls that said they re-send a command whose stream had died."""
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        # ``streams`` counts the streams opened, ``open`` those live
+        self._n = {"streams": 0, "open": 0, "messages_in": 0,
+                   "commands_in": 0, "raw_commands": 0, "messages_out": 0,
+                   "responses_out": 0, "unary_resends": 0}
+
+    def note(self, **steps: int) -> None:
+        with self._mu:
+            for name, step in steps.items():
+                self._n[name] += step
+
+    def stats(self) -> dict:
+        with self._mu:
+            return dict(self._n)
+
+
 class KvService:
     """All RPC handlers over one node's Storage + raftstore."""
 
@@ -72,6 +99,9 @@ class KvService:
         self._import_staged: dict = {}
         # ServiceEvent PAUSE_GRPC state (components/service)
         self.paused = False
+        # what batch_commands has carried (the status server reads it
+        # off the node: /health batch_commands)
+        self.mux_stats = node.mux_stats = MuxStats()
 
     # ---------------------------------------------------------- helpers
 
@@ -918,26 +948,47 @@ class KvService:
         except Exception as e:      # noqa: BLE001 — errors ride the wire
             yield {"error": wire.enc_error(e)}
 
-    def batch_commands(self, request_iterator):
+    def batch_commands(self, request_iterator, raw_dispatch, pool):
         """Bidirectional mux (service/kv.rs:921): inbound messages carry
-        (request_id, method, req) triples.  Each command dispatches to a
-        worker pool and responses stream back AS THEY COMPLETE — a
-        parked command (pessimistic-lock wait) must not head-of-line
-        block the very commit that would release it."""
+        commands in either form of ``wire.mux_command``, and responses
+        stream back AS THEY COMPLETE, every one that is ready in ONE
+        message — a parked command (pessimistic-lock wait) must not
+        head-of-line block the very commit that would release it.
+
+        A RAW command of a method in ``raw_dispatch`` (server.py: the
+        methods bound at raw bytes, today ``Coprocessor``) is handed to
+        ``pool``, the server's BOUNDED command pool (``_HandlerPool``:
+        its ``submit`` stamps the hand-off, so ``rpc_accept_wait`` and
+        ``clock_ns.accept`` mean on a command what they mean on a unary
+        call), and runs ``fn(method, raw)`` there, i.e. ``handle_raw``
+        as the unary call runs it; its open ``rpc_reply`` rides with the
+        response to the serializer that packs the message holding it.
+        Every other command keeps a thread of its own.  ``request_iterator``
+        yields the messages still packed: they are unpacked here, on the
+        stream's feeder thread, not on gRPC's ``_serve``.
+
+        → an iterator of ``[(response, handed rpc_reply | None), ...]``,
+        one list a message (server.py ``_mux_replying`` packs it)."""
         import queue as _q
         import threading as _t
 
+        stats = self.mux_stats
         done: "_q.Queue" = _q.Queue()
         sentinel = object()
         outstanding = [0]
         drained = _t.Event()
         mu = _t.Lock()
 
-        def run_one(ent):
+        def run(ent, serve):
             try:
-                resp = self.handle(ent["method"], ent.get("req") or {})
-                done.put({"request_id": ent["request_id"],
-                          "response": resp})
+                try:
+                    resp = serve(ent)
+                except Exception as e:  # noqa: BLE001 — answers ITS command
+                    resp = {"error": wire.enc_error(e)}
+                if "raw" in ent:
+                    resp = wire.pack_response(resp)
+                done.put((wire.mux_response(ent["request_id"], resp),
+                          tracker.reply_handoff()))
             finally:
                 with mu:
                     outstanding[0] -= 1
@@ -945,40 +996,57 @@ class KvService:
                 if last:
                     done.put(sentinel)
 
+        def serve_raw(ent):
+            return raw_dispatch[ent["method"]](ent["method"], ent["raw"])
+
+        def serve_decoded(ent):
+            return self.handle(ent["method"], wire.unpack(ent["raw"])
+                               if "raw" in ent else ent.get("req") or {})
+
         def feeder():
-            # one thread per in-flight command, NOT a bounded pool: N
-            # parked pessimistic-lock waits must never occupy every
-            # worker and queue the releasing commit behind themselves
+            lost = False
             try:
-                for batch in request_iterator:
-                    for ent in batch.get("requests", ()):
+                for msg in request_iterator:
+                    ents = wire.unpack(msg).get("requests", ())
+                    raws = 0
+                    for ent in ents:
                         with mu:
                             outstanding[0] += 1
-                        _t.Thread(target=run_one, args=(ent,),
+                        if "raw" in ent and ent["method"] in raw_dispatch:
+                            raws += 1
+                            try:
+                                pool.submit(run, ent, serve_raw)
+                                continue
+                            except RuntimeError:
+                                pass    # the pool is shut: the store stops
+                        # one thread per in-flight command, NOT a bounded
+                        # pool: N parked pessimistic-lock waits must never
+                        # occupy every worker and queue the releasing
+                        # commit behind themselves
+                        _t.Thread(target=run, args=(ent, serve_decoded),
                                   daemon=True).start()
+                    stats.note(messages_in=1, commands_in=len(ents),
+                               raw_commands=raws)
+            except Exception:   # noqa: BLE001 — the stream was cancelled
+                lost = True
             finally:
                 with mu:
                     drained.set()
                     idle = outstanding[0] == 0
-                if idle:
+                if idle or lost:
+                    # (lost: nobody reads what is still to come)
                     done.put(sentinel)
 
-        _t.Thread(target=feeder, daemon=True).start()
-        while True:
-            item = done.get()
-            if item is sentinel:
-                return
-            out = [item]
-            while True:     # opportunistic batching of ready responses
-                try:
-                    nxt = done.get_nowait()
-                except _q.Empty:
-                    break
-                if nxt is sentinel:
-                    yield {"responses": out}
-                    return
-                out.append(nxt)
-            yield {"responses": out}
+        stats.note(streams=1, open=1)
+        try:
+            _t.Thread(target=feeder, daemon=True,
+                      name="mux-stream-feeder").start()
+            for out in wire.mux_batches(done, sentinel):
+                # every response that is ready: one message
+                stats.note(messages_out=1, responses_out=len(out))
+                yield out
+        finally:
+            stats.note(open=-1)
 
     # ---------------------------------------------------------- raft
 

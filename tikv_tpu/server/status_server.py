@@ -110,6 +110,14 @@ class StatusServer:
                             "chunk_rows_sum": int(m.COPR_CHUNK_ROWS.value),
                             "chunk_bytes_sum": int(
                                 m.COPR_CHUNK_BYTES.value)}}
+                    mux = getattr(node, "mux_stats", None)
+                    if mux is not None:
+                        # the BatchCommands mux (service.py MuxStats):
+                        # commands_in / messages_in and responses_out /
+                        # messages_out are the batch sizes; raw_commands
+                        # over coprocessor.requests_served the share of
+                        # cop tasks that came by the mux
+                        body["batch_commands"] = mux.stats()
                     fp = getattr(node, "fastpath", None)
                     if fp is not None and hasattr(fp, "stats"):
                         # microsecond warm path: learned wire-template

@@ -55,6 +55,53 @@ def pack_response(obj: Any) -> bytes:
     return pack(obj)
 
 
+# -- batch_commands (service/kv.rs:921): ONE stream, two command forms --
+#
+# A message either way is ``{"requests": [command, ...]}`` /
+# ``{"responses": [response, ...]}``, demultiplexed by ``request_id``.
+# The RAW form carries the exact bytes the unary call of the method would
+# have carried (``pack(req)``), so the store's fast path template-matches
+# them as it does a unary body, and is answered with the bytes that call
+# would have returned (``pack_response`` of what ``handle_raw`` gave); the
+# DICT form carries the request decoded, and serves every other method.
+
+# invocation metadata of the unary call that re-sends a command whose
+# stream died under it (the store counts them: /health batch_commands)
+MUX_RESEND_KEY = "tikv-mux-resend"
+
+
+def mux_batches(q, stop):
+    """What either end sends: blocks for one item of the queue ``q``,
+    takes whatever else is queued behind it → one list a message, until
+    ``stop`` comes out of the queue."""
+    import queue
+    item = q.get()
+    while item is not stop:
+        batch = [item]
+        try:
+            while (item := q.get_nowait()) is not stop:
+                batch.append(item)
+        except queue.Empty:
+            yield batch
+            item = q.get()
+        else:
+            yield batch
+
+
+def mux_command(request_id: int, method: str, req) -> dict:
+    """``req``: bytes → the raw form; a dict → the dict form."""
+    if type(req) is bytes:
+        return {"request_id": request_id, "method": method, "raw": req}
+    return {"request_id": request_id, "method": method, "req": req}
+
+
+def mux_response(request_id: int, resp) -> dict:
+    """The answer to a command, in its command's form."""
+    if type(resp) is bytes:
+        return {"request_id": request_id, "raw": resp}
+    return {"request_id": request_id, "response": resp}
+
+
 # -- metapb --
 
 def enc_peer(p: Peer) -> dict:

@@ -564,6 +564,8 @@ def watch_gil() -> None:
 _THREAD_ROLES = (
     ("grpc_serve", lambda n: n.endswith("(_serve)")),
     ("rpc_handler", lambda n: n.startswith("rpc-handler")),
+    ("mux_command", lambda n: n.startswith("mux-command")),
+    ("mux_stream", lambda n: n.startswith("mux-stream")),
     ("copr-coalescer", lambda n: n == "copr-coalescer"),
     ("copr-dispatcher", lambda n: n == "copr-dispatcher"),
     ("copr-completion", lambda n: n.startswith("copr-completion")),
@@ -772,15 +774,29 @@ def reply_begin(tr: Tracker) -> None:
 
 
 def reply_done() -> None:
+    reply_close(reply_handoff())
+
+
+def reply_handoff() -> Optional[tuple]:
+    """A mux command's worker (``service.py`` ``batch_commands``) gives
+    its open ``rpc_reply`` to the thread whose serializer packs the
+    response MESSAGE holding the reply → what :func:`reply_close` takes
+    there, or None.  The annotation and the CPU clock are this
+    thread's, so both end here; the wall runs on."""
     got = _rpc.reply
     if got is None:
-        return
+        return None
     _rpc.reply = None
     t_finish, c0, ann = got
-    AGGREGATE.add("rpc_reply", time.perf_counter_ns() - t_finish, None
-                  if c0 is None else time.thread_time_ns() - c0)
     if ann is not None:
         ann.__exit__(None, None, None)
+    return t_finish, None if c0 is None else time.thread_time_ns() - c0
+
+
+def reply_close(handed: Optional[tuple]) -> None:
+    if handed is not None:
+        AGGREGATE.add("rpc_reply", time.perf_counter_ns() - handed[0],
+                      handed[1])
 
 
 # ------------------------------------------------------------- context

@@ -19,13 +19,19 @@ SPAN_VOCABULARY: dict[str, str] = {
     "rpc": "root span: the whole RPC from admission to response",
     "rpc_accept_wait": "before the root span: gRPC's hand-off to the "
                        "handler pool → tracker install (pool queue, "
-                       "message receive, wait for the GIL); aggregate "
-                       "row + root-span attribute rpc_accept_wait_us",
+                       "message receive, wait for the GIL); on a mux "
+                       "command the stream's feeder's hand-off to the "
+                       "command pool → install (that pool's queue); "
+                       "aggregate row + root-span attribute "
+                       "rpc_accept_wait_us",
     "rpc_reply": "after the root span: trace sealed → response "
                  "serializer returned (seal tail, encode_response: a "
                  "fast-path hit's rows in one native call over the "
                  "result's planes + env's pack, or the Python chain, "
-                 "gRPC's hand-off, wire pack); aggregate row only",
+                 "gRPC's hand-off, wire pack); on a mux command → the "
+                 "response MESSAGE holding the reply packed (the "
+                 "worker's hand-over, the generator's wake); "
+                 "aggregate row only",
     "untracked": "synthesized residual: root wall no child span covers",
     "admission": "umbrella: deadline/resource gating + class keying",
     "plan_decode": "wire → DAGRequest decode (compile-class keying)",
@@ -67,14 +73,23 @@ SPAN_VOCABULARY: dict[str, str] = {
     "wire_request": "request serializer returned → the store's handler "
                     "pool was handed the call (reply's clock_ns.accept): "
                     "the client's gRPC core, loopback, the server's "
-                    "core, _serve getting the GIL; one shared clock only",
+                    "core, _serve getting the GIL; on a mux command the "
+                    "MESSAGE's serializer returned → the command pool "
+                    "was handed the command (_serve's one event a "
+                    "message, the feeder's unpack; the queue of cop "
+                    "tasks is then rpc_accept_wait's); one shared "
+                    "clock only",
     "wire_reply": "root span sealed (clock_ns.t1) → the client's "
-                  "response deserializer entered: rpc_reply, gRPC's "
+                  "response deserializer entered (a mux command: of the "
+                  "message holding the reply): rpc_reply, gRPC's "
                   "send, loopback, the calling thread waking and "
                   "retaking the client's GIL; one shared clock only",
     "client_decode": "response deserializer entered → returned "
                      "(wire.unpack of the reply, and a chunk's buffers "
-                     "wrapped as arrays: wire.dec_chunk)",
+                     "wrapped as arrays: wire.dec_chunk); a mux "
+                     "command: the message's deserializer entered → "
+                     "the command's own reply unpacked on the caller's "
+                     "thread, its wake included",
     # -- storage / host pipeline --
     "kv_read": "point/scan MVCC read through Storage",
     "snapshot": "raft lease read + engine snapshot acquisition",

@@ -37,7 +37,9 @@ from .trace import (      # noqa: F401 — re-exported compat surface
     note_accept,
     phase,
     reply_begin,
+    reply_close,
     reply_done,
+    reply_handoff,
     rpc_task_begin,
     rpc_task_end,
     span,
