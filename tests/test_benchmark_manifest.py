@@ -90,8 +90,8 @@ def test_the_device_layer_has_the_name_the_benchmark_reads(name):
 
 # the operator modules of device/ and what they share: the runner
 # imports them, never the other way, at module level or inside a function
-OPERATORS = ("aggregate.py", "join.py", "mvcc.py", "request.py",
-             "selection.py")
+OPERATORS = ("aggregate.py", "feed.py", "join.py", "mvcc.py",
+             "request.py", "selection.py")
 
 
 @pytest.mark.parametrize("module", OPERATORS)

@@ -572,3 +572,128 @@ def test_constants_are_operands_of_one_const_blind_kernel(interpret,
     assert r.flight_recorder.stats()["faults"] == 0
     assert len([k for k in r._kernel_cache if isinstance(k, tuple)
                 and k[:1] == ("hashpl",)]) == 2
+
+
+# ------------------------------------------------- one rule, cold and patched
+
+
+CHAR1 = FieldType(tp=FieldTypeTp.STRING, flen=1, collation=63)
+_HANDLE0 = 1 << 40          # handles past int32: the plane rides int64
+
+
+def _kinds_snapshot(table, handles, cols):
+    ones = np.ones(len(handles), np.bool_)
+    texts = np.empty(len(handles), dtype=object)
+    texts[:] = cols["f"]
+    return ColumnarTable.from_arrays(table, handles, {
+        "a": Column(EvalType.INT, cols["a"], ones),
+        "d": Column(EvalType.DATETIME, cols["d"], ones),
+        "f": Column(EvalType.BYTES, texts, ones),
+        "q": Column(EvalType.DECIMAL, cols["q"], ones, 2)})
+
+
+# per plane kind: the one row a write leaves behind (None: appended)
+# and what it holds
+_WRITES = {
+    "int64_handle": (None, {}),
+    "int32": (11, {"a": -77777}),
+    "date": (12, {"d": int(pack_datetime(1994, 7, 4))}),
+    "code": (13, {"f": b"N"}),
+    "decimal": (14, {"q": 99999999}),
+}
+
+
+@pytest.mark.parametrize("kind", list(_WRITES))
+def test_a_patched_line_equals_a_cold_build_of_the_patched_data(kind):
+    """ONE rule turns a column into a plane (device/feed.py
+    ``plane_values``), whoever asks: a line built cold and then patched
+    by a write holds, plane for plane, what a cold build of the written
+    data holds, and its digests are the host truth's."""
+    from tikv_tpu.copr.region_cache import FeedLineage
+    from tikv_tpu.device.feed import anchor, value_plane_index
+    from tikv_tpu.device.supervisor import host_plane_digest
+    from tikv_tpu.utils import tracker
+
+    runner = DeviceRunner(mesh=make_mesh(jax.devices()[:1]))
+    n = 2000
+    rng = np.random.default_rng(44)
+    table = Table(8844, (PK, TableColumn("a", 2, FieldType.long()),
+                         TableColumn("d", 3, DATE),
+                         TableColumn("f", 4, CHAR1),
+                         TableColumn("q", 5, DEC2)))
+    handles = _HANDLE0 + np.arange(n, dtype=np.int64)
+    cols = {"a": rng.integers(-10 ** 5, 10 ** 5, n),
+            "d": pack_datetime(rng.integers(1993, 1996, n),
+                               rng.integers(1, 13, n),
+                               rng.integers(1, 29, n)),
+            "f": [(b"R", b"A")[i] for i in rng.integers(0, 2, n)],
+            "q": rng.integers(-10 ** 6, 10 ** 6, n)}
+    old = _kinds_snapshot(table, handles, cols)
+
+    row, written = _WRITES[kind]
+    if row is None:             # an append: the new handle is the write
+        row = n
+        handles = np.append(handles, _HANDLE0 + n)
+        cols = {"a": np.append(cols["a"], 5),
+                "d": np.append(cols["d"], pack_datetime(1994, 2, 2)),
+                "f": cols["f"] + [b"A"],
+                "q": np.append(cols["q"], 123)}
+    else:
+        cols = {k: (list(v) if k == "f" else v.copy())
+                for k, v in cols.items()}
+        for name, v in written.items():
+            cols[name][row] = v
+    new = _kinds_snapshot(table, handles, cols)
+    lineage = FeedLineage()
+    for v, snap in enumerate((old, new)):
+        snap.feed_lineage, snap.feed_version = lineage, v
+    one = np.ones(1, np.bool_)
+    lineage.record({"n": len(handles), "spans": [{
+        "lo": row, "handles": handles[row:row + 1],
+        "cols": {c.col_id: (new.columns[c.col_id].values[row:row + 1], one)
+                 for c in table.columns if not c.is_pk_handle}}]})
+
+    s = DagSelect.from_table(table, ["id", "a", "d", "f", "q"])
+    dag = s.where(
+        Expr.call("GeTime", s.col("d"), Expr.const(
+            int(pack_datetime(1994, 1, 1)), EvalType.DATETIME)),
+        Expr.call("LtTime", s.col("d"), Expr.const(
+            int(pack_datetime(1995, 1, 1)), EvalType.DATETIME)),
+    ).aggregate([s.col("f")], [("sum", s.col("q")), ("sum", s.col("a")),
+                               ("max", s.col("id"))]).build()
+
+    def served(snap):
+        tr, tok = tracker.install()
+        try:
+            got = runner.handle_request(dag, snap)
+        finally:
+            tracker.uninstall(tok)
+        assert sorted(got.rows()) == sorted(
+            BatchExecutorsRunner(dag, snap).handle_request().rows())
+        feed, = [v for v in runner._arena.bucket(anchor(snap)).values()
+                 if isinstance(v, dict) and "flat" in v]
+        return tr.time_detail()["labels"]["device_feed"], feed
+
+    assert served(old)[0] == "upload"
+    how, patched = served(new)
+    assert how == "patch"
+    how, built = served(_kinds_snapshot(table, handles, cols))
+    assert how == "upload"
+
+    assert patched["kinds"] == built["kinds"] == \
+        (None, None, "date", 1, None)
+    assert patched["null_flags"] == built["null_flags"]
+    assert [str(a.dtype) for a in patched["flat"]] == \
+        [str(a.dtype) for a in built["flat"]] == \
+        ["int64", "int32", "int32", "int32", "int32"]
+    m = len(handles)
+    truth = [handles, cols["a"], cols["d"] >> np.uint64(41),
+             np.array([v[0] for v in cols["f"]]), cols["q"]]
+    for fi, want in zip(value_plane_index(patched["null_flags"]), truth):
+        got = np.asarray(patched["flat"][fi])
+        assert np.array_equal(got, np.asarray(built["flat"][fi])), fi
+        assert np.array_equal(got[:m], want), fi
+        assert not got[m:].any(), "the pad stays zero"
+        assert int(np.asarray(patched["digests"][fi])) == \
+            int(np.asarray(built["digests"][fi])) == \
+            host_plane_digest(want.astype(got.dtype), m), fi

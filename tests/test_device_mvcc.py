@@ -117,14 +117,15 @@ def _parity(eng, table_id, infos, read_ts, resolver, ctx=""):
 
 def _mint_feed(bundle, runner, infos, dtypes):
     n = bundle.n
-    return bundle.mint(runner, list(infos), list(dtypes), n,
-                       runner._pad_rows(n))
+    return bundle.mint(runner._feeds, list(infos), list(dtypes), n,
+                       runner._feeds.pad_rows(n))
 
 
 def _feed_vs_host(feed, tbl, infos, dtypes, n):
     """Minted device feed must equal the host-truth table plane for
-    plane (the _build_flat layout contract)."""
+    plane (``FeedStore.make_feed``'s layout contract)."""
     assert feed is not None
+    assert feed["kinds"] == (None,) * len(infos)
     flat = feed["flat"]
     fi = 0
     for info, ds in zip(infos, dtypes):

@@ -518,8 +518,8 @@ def test_pad_rows_keeps_compile_class_on_first_append_at_exact_fill():
 
     from tikv_tpu.parallel import make_mesh
     r = DeviceRunner(mesh=make_mesh(jax.devices()[:1]))
-    unit = r._feed_unit()
+    unit = r._feeds.unit()
     for blocks in (9, 10, 15, 16, 40, 100, 400):
         n = blocks * unit
-        assert r._pad_rows(n) == r._pad_rows(n + 1), blocks
-        assert r._pad_rows(n) >= n + 1
+        assert r._feeds.pad_rows(n) == r._feeds.pad_rows(n + 1), blocks
+        assert r._feeds.pad_rows(n) >= n + 1

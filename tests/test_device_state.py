@@ -104,7 +104,7 @@ def test_host_and_device_digests_agree():
         n = 4000
         import jax.numpy as jnp
         dev = jnp.asarray(arr)
-        got = int(np.asarray(runner.device_digest(dev, n)))
+        got = int(np.asarray(runner._feeds.device_digest(dev, n)))
         assert got == host_plane_digest(arr, n), dtype
 
 
@@ -276,7 +276,7 @@ def test_corruption_before_patch_survives_patch_and_is_caught():
         feed = next(v for _a, b in device.arena_items()
                     for v in b.values()
                     if isinstance(v, dict) and "flat" in v)
-        device.corrupt_resident_plane(feed)
+        device._feeds.corrupt_resident_plane(feed)
         # a write now patches the feed in place, refreshing digests
         model[300] = (1, 7)
         c.txn_write([("put",) + encode_table_row(

@@ -29,6 +29,7 @@ from tikv_tpu.chaos import (
 from tikv_tpu.chaos.nemesis import Fault, Nemesis
 from tikv_tpu.datatype import Column, EvalType, FieldType
 from tikv_tpu.device import DeviceRunner
+from tikv_tpu.device.feed import anchor as feed_anchor
 from tikv_tpu.device.supervisor import RemintGovernor
 from tikv_tpu.executors.columnar import ColumnarTable
 from tikv_tpu.executors.runner import BatchExecutorsRunner
@@ -118,7 +119,7 @@ def test_migrate_moves_feed_and_serves_parity():
         snap = _snap(table, 2048, 500 + seed, **kw)
         host = _rows(BatchExecutorsRunner(dag, snap).handle_request())
         assert _rows(runner.handle_request(dag, snap)) == host
-        anchor = runner._feed_anchor(snap)
+        anchor = feed_anchor(snap)
         src = _owner_idx(runner, anchor)
         dst = (src + 1) % len(placer.slices)
         before = placer.stats()["migrations"]
@@ -150,7 +151,7 @@ def test_migrated_digests_live_on_destination_device():
     table = _table()
     snap = _snap(table, 2048, 900)
     runner.handle_request(_agg(table), snap)
-    anchor = runner._feed_anchor(snap)
+    anchor = feed_anchor(snap)
     src = _owner_idx(runner, anchor)
     dst = (src + 1) % len(placer.slices)
     assert placer.migrate(anchor, src, dst)
@@ -171,7 +172,7 @@ def test_migrate_noop_and_bad_indices():
     table = _table()
     snap = _snap(table, 2048, 700)
     runner.handle_request(_agg(table), snap)
-    anchor = runner._feed_anchor(snap)
+    anchor = feed_anchor(snap)
     src = _owner_idx(runner, anchor)
     assert not placer.migrate(anchor, src, src)
     assert not placer.migrate(anchor, src, len(placer.slices))
@@ -189,21 +190,21 @@ def test_migrate_stale_copy_never_clobbers_newer_generation():
     snap = _snap(table, 2048, 701)
     host = _rows(BatchExecutorsRunner(dag, snap).handle_request())
     assert _rows(runner.handle_request(dag, snap)) == host
-    anchor = runner._feed_anchor(snap)
+    anchor = feed_anchor(snap)
     src_r = placer.owner(anchor)
-    feeds, skipped = src_r.extract_feeds(anchor)
+    feeds, skipped = src_r._feeds.extract_feeds(anchor)
     assert feeds and skipped == 0
     for f in feeds.values():
         f["lineage_v"] = 1          # the in-flight (stale) generation
     dst_r = placer.slices[
         (placer.slices.index(src_r) + 1) % len(placer.slices)]
-    assert dst_r.install_feeds(anchor, feeds) == "moved"
+    assert dst_r._feeds.install_feeds(anchor, feeds) == "moved"
     fkey = next(iter(feeds))
     bucket = dst_r._arena.bucket(anchor, create=False)
     newer = dict(bucket[fkey])
     newer["lineage_v"] = 2          # the racing re-mint won
     bucket[fkey] = newer
-    assert dst_r.install_feeds(anchor, {fkey: feeds[fkey]}) == "moved"
+    assert dst_r._feeds.install_feeds(anchor, {fkey: feeds[fkey]}) == "moved"
     assert dst_r._arena.bucket(anchor, create=False)[fkey] is newer, \
         "a stale in-flight copy clobbered the newer resident generation"
     runner.drop_feed(anchor)
@@ -221,7 +222,7 @@ def test_migrate_fault_caught_by_arrival_verify():
     snap = _snap(table, 2048, 702)
     host = _rows(BatchExecutorsRunner(dag, snap).handle_request())
     assert _rows(runner.handle_request(dag, snap)) == host
-    anchor = runner._feed_anchor(snap)
+    anchor = feed_anchor(snap)
     src = _owner_idx(runner, anchor)
     dst = (src + 1) % len(placer.slices)
     nem = Nemesis(None)
@@ -252,7 +253,7 @@ def test_inflight_requests_survive_migration_churn():
     snap = _snap(table, 2048, 703, null_frac=0.1)
     host = _rows(BatchExecutorsRunner(dag, snap).handle_request())
     assert _rows(runner.handle_request(dag, snap)) == host
-    anchor = runner._feed_anchor(snap)
+    anchor = feed_anchor(snap)
     stop = threading.Event()
     errors = []
 
@@ -476,21 +477,21 @@ def test_take_split_feed_matches_shape_exactly():
                           chunk_rows=8 * 64)
     lineage = FeedLineage()
     n = 100
-    pad = runner._pad_rows(n)
-    feed = {"n_live": n, "n_pad": pad, "flat": (), "null_flags": ()}
+    pad = runner._feeds.pad_rows(n)
+    feed = runner._feeds.make_feed((), (), pad, (), (), n)
     lineage.split_stash = [
         {"col_ids": (1, 2), "dtypes": ("int64", "int64"), "feed": feed}]
     key = ((1, 2), ("int64", "int64"), None)
     # wrong live count, wrong cols, wrong dtypes: all refuse
-    assert runner._take_split_feed(lineage, key, n + 1) is None
-    assert runner._take_split_feed(
+    assert runner._feeds.take_split_feed(lineage, key, n + 1) is None
+    assert runner._feeds.take_split_feed(
         lineage, ((1, 3), ("int64", "int64"), None), n) is None
-    assert runner._take_split_feed(
+    assert runner._feeds.take_split_feed(
         lineage, ((1, 2), ("int64", "int32"), None), n) is None
-    got = runner._take_split_feed(lineage, key, n)
+    got = runner._feeds.take_split_feed(lineage, key, n)
     assert got is not None and got["n_live"] == n
     assert not lineage.split_stash, "consumption is one-shot"
-    assert runner._take_split_feed(lineage, key, n) is None
+    assert runner._feeds.take_split_feed(lineage, key, n) is None
 
 
 def test_split_under_churn_mints_no_columnar_build():

@@ -230,7 +230,7 @@ def test_the_four_shards_partials_add_up_to_the_whole(store, kind, params,
     which is also what the mesh serves."""
     ctx = store.ctxs[keys]
     runner = store.runner
-    n_local = runner._pad_rows(ROWS) // 4
+    n_local = runner._feeds.pad_rows(ROWS) // 4
     assert 3 * n_local < ROWS <= 4 * n_local    # four live shards
     one = DeviceRunner(mesh=make_mesh(jax.devices()[:1]),
                        chunk_rows=1 << 12)

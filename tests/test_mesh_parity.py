@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from tikv_tpu.datatype import Column, EvalType, FieldType
 from tikv_tpu.device import DeviceRunner
+from tikv_tpu.device.feed import anchor as feed_anchor
 from tikv_tpu.executors.columnar import ColumnarTable
 from tikv_tpu.executors.runner import BatchExecutorsRunner
 from tikv_tpu.parallel import make_mesh, mesh_slices, parse_mesh_shape
@@ -446,7 +447,7 @@ def test_placement_spreads_anchors_and_rebalances():
     doubled = max(range(8),
                   key=lambda i: st["slices"][i]["placed_anchors"])
     hot = next(i for i, s in enumerate(snaps)
-               if placer.owner(runner._feed_anchor(s)) is
+               if placer.owner(feed_anchor(s)) is
                placer.slices[doubled])
     for _ in range(30):
         runner.handle_request(dag, snaps[hot])
@@ -464,7 +465,7 @@ def test_placement_spreads_anchors_and_rebalances():
     runner.placer.publish_metrics()
     assert m.DEVICE_SLICE_RESIDENT_BYTES.labels("0").value >= 0
     # drop fans out to slices and forgets the placement
-    anchor = runner._feed_anchor(snaps[0])
+    anchor = feed_anchor(snaps[0])
     assert runner.drop_feed(anchor) > 0
     assert placer.owner(anchor) is None
 

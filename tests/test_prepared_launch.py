@@ -43,6 +43,7 @@ from test_coalescer import (  # noqa: F401 — the rig and its fixture
     lane_table,
 )
 from tikv_tpu.datatype import Column, EvalType, FieldType
+from tikv_tpu.device.feed import anchor as feed_anchor
 from tikv_tpu.executors.columnar import ColumnarTable
 from tikv_tpu.executors.runner import BatchExecutorsRunner
 from tikv_tpu.testing.dag import DagSelect
@@ -128,7 +129,7 @@ def prepared(runner) -> dict:
 
 def record_of(runner, dag, snap):
     """The prepared record in ``dag``'s memo over ``snap``, or None."""
-    bucket = runner._arena.bucket(runner._feed_anchor(snap), create=False)
+    bucket = runner._arena.bucket(feed_anchor(snap), create=False)
     meta = (bucket or {}).get(
         ("meta", runner._meta_key(dag, runner._analyze(dag))))
     return (meta or {}).get("prepared")
@@ -373,13 +374,13 @@ def test_the_record_is_missed_and_rebuilt_after(lane_runner, monkeypatch,
             runner.quarantine(snap, reason="test")
             host_first = True
         elif cause == "moved_copy_installed":
-            feeds, skipped = runner.extract_feeds(snap)
+            feeds, skipped = runner._feeds.extract_feeds(snap)
             assert feeds and not skipped
-            assert runner.install_feeds(snap, feeds) == "moved"
+            assert runner._feeds.install_feeds(snap, feeds) == "moved"
             drop = "feed"
         elif cause == "planes_replaced":
-            runner.corrupt_resident_plane(rec.feed)
-            runner.corrupt_resident_plane(rec.feed)     # (and back)
+            runner._feeds.corrupt_resident_plane(rec.feed)
+            runner._feeds.corrupt_resident_plane(rec.feed)     # (and back)
             drop = "feed"
         elif cause == "kernel_false":
             runner._kernel_cache[rec.key] = False

@@ -47,6 +47,7 @@ from tikv_tpu.chaos import (
 )
 from tikv_tpu.datatype import Column, EvalType, FieldType
 from tikv_tpu.device import DeviceRunner
+from tikv_tpu.device.feed import anchor as feed_anchor
 from tikv_tpu.device.supervisor import SliceHealth, SliceHealthBoard
 from tikv_tpu.executors.columnar import ColumnarTable
 from tikv_tpu.executors.runner import BatchExecutorsRunner
@@ -190,7 +191,7 @@ def test_latency_trip_fires_drain_listeners():
     assert _rows(runner.handle_request(dag, snap)) == _rows(
         BatchExecutorsRunner(dag, snap).handle_request())
     oidx = runner.placer.slices.index(
-        runner.placer.owner(runner._feed_anchor(snap)))
+        runner.placer.owner(feed_anchor(snap)))
     trips = []
     runner._board.add_trip_listener(lambda i, r: trips.append((i, r)))
     # feed outlier latencies straight into the slice's ok path (the
@@ -333,7 +334,7 @@ def test_quarantined_slice_refuses_dispatch():
     snap = _snap(table, 2048, 999)
     host = _rows(BatchExecutorsRunner(dag, snap).handle_request())
     assert _rows(runner.handle_request(dag, snap)) == host
-    owner = runner.placer.owner(runner._feed_anchor(snap))
+    owner = runner.placer.owner(feed_anchor(snap))
     oidx = runner.placer.slices.index(owner)
     runner._board.trip(oidx, "test")
     try:
@@ -451,7 +452,7 @@ def test_scrub_quarantine_reaches_degraded_submesh():
             assert _rows(runner.handle_request(dag, snap)) == host
         sub = runner._degraded_sub()
         assert sub is not None
-        anchor = runner._feed_anchor(snap)
+        anchor = feed_anchor(snap)
         assert sub._arena.resident_bytes() > 0
         # the scrubber's verdict, delivered to the TOP runner
         runner.quarantine(anchor, reason="scrub divergence")
@@ -489,7 +490,7 @@ def test_batched_refusal_raises_batch_unavailable():
     snap = _snap(table, 4096, 93)
     d1, d2 = _sel(table, -10_000), _sel(table, 10_000)
     assert runner.batch_class(d1, snap) is not None   # place + warm
-    owner = runner.placer.owner(runner._feed_anchor(snap))
+    owner = runner.placer.owner(feed_anchor(snap))
     oidx = runner.placer.slices.index(owner)
     runner._board.trip(oidx, "test")
     try:
@@ -510,7 +511,7 @@ def test_half_open_readmission_decays_score():
     snap = _snap(table, 2048, 77)
     runner.handle_request(dag, snap)
     oidx = runner.placer.slices.index(
-        runner.placer.owner(runner._feed_anchor(snap)))
+        runner.placer.owner(feed_anchor(snap)))
     failpoint.cfg("device::slice_dead", f"return({oidx})")
     try:
         for _ in range(3):
@@ -547,7 +548,7 @@ def test_inflight_deferred_rescue_races_slice_death():
     snap = _snap(table, 2048, 55, null_frac=0.1)
     host = _rows(BatchExecutorsRunner(dag, snap).handle_request())
     assert _rows(runner.handle_request(dag, snap)) == host   # warm
-    owner = runner.placer.owner(runner._feed_anchor(snap))
+    owner = runner.placer.owner(feed_anchor(snap))
     oidx = runner.placer.slices.index(owner)
     before = DEVICE_FAILOVER_COUNTER.labels("rescue").value
     d = runner.handle_request(dag, snap, deferred=True)
@@ -585,7 +586,7 @@ def test_inflight_group_rescue_races_slice_death():
     k1 = runner.batch_class(d1, snap)
     k2 = runner.batch_class(d2, snap)
     assert k1 is not None and k1[0] == "slice" and k1 == k2, (k1, k2)
-    owner = runner.placer.owner(runner._feed_anchor(snap))
+    owner = runner.placer.owner(feed_anchor(snap))
     oidx = runner.placer.slices.index(owner)
     group = runner.handle_batched([(d1, snap), (d2, snap)])
     before = DEVICE_FAILOVER_COUNTER.labels("rescue").value

@@ -1132,8 +1132,9 @@ class PlanExecutor:
                 # construction — host-join rather than fake a shard
                 return None, None
             return runner.joiner(), None
-        la = runner._feed_anchor(lstor)
-        ra = runner._feed_anchor(rstor)
+        from ..device.feed import anchor as feed_anchor
+        la = feed_anchor(lstor)
+        ra = feed_anchor(rstor)
         placer.note_join(la, ra)
         lrun = placer.route(lstor)
         rrun = placer.route(rstor)

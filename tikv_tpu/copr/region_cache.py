@@ -71,8 +71,8 @@ device-state supervisor, device/supervisor.py):
   slices the parent's host state by key range into two CHILD lines
   at the new epoch (fresh lineages, exact ``data_index`` stamps from
   the split point), and the runner slices the parent's resident
-  device feed into digest-verified child feeds
-  (``split_resident_feeds``) — a load-split under churn mints zero
+  device feed into digest-verified child feeds (device/feed.py
+  ``split_resident_feeds``) — a load-split under churn mints zero
   ``columnar_build``s.  Only the parent lines at the superseded
   epoch retire;
 - **lifecycle invalidation** — :meth:`RegionColumnarCache.
@@ -628,7 +628,7 @@ class FeedLineage:
         # mints the born-resident feed from them; any delta landing
         # first releases them (the host upload path is always correct)
         self.cold_bundle = None
-        # device-side region split (runner.split_resident_feeds): on a
+        # device-side region split (FeedStore.split_resident_feeds): on a
         # CHILD lineage, the digest-verified feed candidates sliced
         # from the parent's resident planes — the child's first feed
         # miss consumes a match instead of re-uploading from host
@@ -875,7 +875,7 @@ class RegionColumnarCache:
     Thread-safe: coprocessor requests arrive on concurrent gRPC handler
     threads; builds AND delta patches for one (line, data version) are
     serialized on per-version events so a slow full-region MVCC build
-    never holds the global lock (ADVICE r2), and concurrent bridges of
+    never holds the global lock, and concurrent bridges of
     one line serialize on the line's own mutex.
 
     ``delta_source`` (a :class:`~tikv_tpu.copr.delta.DeltaSink`) supplies
@@ -1096,7 +1096,7 @@ class RegionColumnarCache:
         resident parent feeds (device/supervisor.py orders the two).
 
         Returns one split spec per sliced parent line for
-        ``DeviceRunner.split_resident_feeds``: {parent_lineage,
+        ``FeedStore.split_resident_feeds``: {parent_lineage,
         parent_version, pos, n_parent, left: {lineage, n}, right: ...}.
         """
         if left_index is None:

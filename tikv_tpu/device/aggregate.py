@@ -18,7 +18,7 @@ needs no runner, the finalize (``finalize_packed`` and its parts).
 The operator owns no device state.  It serves through its runner's
 feeds and caches, by these names and no others: ``_is_tpu``,
 ``_single``, ``_mesh``, ``_row_sharding``, ``_repl``, ``_nshards``,
-``_feed_unit``, ``_pick_chunk``, ``_kernel_cache``, ``_shard_kernel``,
+``_feeds`` (``unit``, ``pick_chunk``), ``_kernel_cache``, ``_shard_kernel``,
 ``_cached_scalar``, ``_kern_key``, ``_dispatch_phase``, ``_result``,
 ``_max_hash_capacity``, ``_psum``, ``_shard_index``, ``_eval_masked``,
 ``flight_recorder``, and for a launch of lanes ``_device_scope`` and
@@ -63,6 +63,7 @@ from ..ops.agg import (
 from ..parallel import ROW_AXES
 from ..utils import trace
 from . import lowering, pallas_hash
+from .feed import value_plane_index
 from .kernels import (
     build_layouts,
     int_planes_needed,
@@ -304,7 +305,8 @@ class DeviceAggregator:
             raise _FallbackToHost("bucket tiles need the pallas kernel")
         if bodies[0] == "hash_twolevel":
             LO, HI = twolevel_dims(slots, p8, pf)
-            chunk = runner._pick_chunk(feed["n_pad"], runner._feed_unit())
+            chunk = runner._feeds.pick_chunk(feed["n_pad"],
+                                             runner._feeds.unit())
             key = runner._kern_key("hash2l", dag, feed, chunk,
                                    tuple(dtypes), capacity, arg_nbytes,
                                    tuple(arg_ok_is_mask), sparse)
@@ -336,7 +338,7 @@ class DeviceAggregator:
 
             return _Pending(carry, fin_twolevel)
         else:
-            chunk = runner._pick_chunk(feed["n_pad"], _CHUNK_AGG)
+            chunk = runner._feeds.pick_chunk(feed["n_pad"], _CHUNK_AGG)
             key = runner._kern_key("hashsc", dag, feed, chunk,
                                    tuple(dtypes), capacity, sparse)
             # sharded: the order-sensitive stacked states (min/max)
@@ -636,14 +638,13 @@ class DeviceAggregator:
         # column; everything else (e.g. the raw int64 sparse key) stays
         # host/XLA-side
         kset = set(pallas_hash.kernel_col_ids(plan, mode))
-        col_sel, col_map, fi = [], [], 0
-        for i, has_nulls in enumerate(feed["null_flags"]):
+        col_sel, col_map = [], []
+        for i, fi in enumerate(value_plane_index(feed["null_flags"])):
             if i in kset:
                 col_map.append(len(col_sel))
                 col_sel.append(fi)
             else:
                 col_map.append(-1)
-            fi += 2 if has_nulls else 1
         col_map = tuple(col_map)
         cols = tuple(feed["flat"][j] for j in col_sel)
         if sparse:
@@ -1054,7 +1055,7 @@ class DeviceAggregator:
 
     def _bucket_blocks(self, blocks: int) -> int:
         """Round a grid span up to a 4-significant-bit block count —
-        the compile-class grid shared with _pad_rows."""
+        the compile-class grid shared with feed.py ``pad_rows``."""
         if blocks > 8:
             s = blocks.bit_length() - 4
             k = -(-blocks // (1 << s))
@@ -1514,7 +1515,7 @@ class DeviceAggregator:
             if got is not None:
                 return got
 
-        chunk = runner._pick_chunk(feed["n_pad"], _CHUNK_AGG)
+        chunk = runner._feeds.pick_chunk(feed["n_pad"], _CHUNK_AGG)
         n_cols = len(plan.used_cols)
         key = runner._kern_key("simple", dag, feed, chunk, tuple(dtypes))
         carry = self._cached_carry(key,

@@ -1,0 +1,922 @@
+"""The device feed: a scan's used columns as flat padded planes in HBM,
+kept across requests (the region-cache-engine analog).
+
+``DeviceRunner._handle_local`` asks ``FeedStore.get`` for a request's
+feed under its dispatch lock; the supervisor scrubs, moves and splits
+resident feeds through the same store.  Top to bottom: the FORMAT (how
+a column becomes a plane, where its planes lie in ``flat``, the
+request's host halves: ``HostPlanes``), then ``FeedStore``: the shape,
+the one constructor, the build ladder (``get``), the patch, the
+digests, the move between slices and the split of a region's line.
+
+A feed is a dict: ``flat`` (per used column its value plane, then its
+validity plane if the column holds a NULL), ``null_flags`` (which do),
+``n_pad``, ``kinds`` (``plane_kinds``), where the runner records
+digests ``digests`` / ``n_live``; ``lineage_v`` (the generation it
+reflects), ``key`` (what its bucket holds it under), ``positional`` /
+``pk_flags`` (what a device split needs).
+
+The store owns no state.  It serves through its runner's, by these
+names and no others: ``_arena``, ``_kernel_cache``, ``_single``,
+``_mesh``, ``_row_sharding``, ``_nshards``, ``_block_local``,
+``_chunk_override``, ``scrub_digests``, ``_dispatch_mu``,
+``_sub_runners``.  The runner, ``aggregate.py``, ``mvcc.py`` and
+``join.py`` import this module, and it imports none of them.
+"""
+
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..copr.dag import TableScanDesc
+from ..datatype import EvalType
+from ..datatype.tile import _device_dtype, code_plane, date_plane
+from ..utils import tracker
+from . import lowering
+from .kernels import named_program
+from .request import _FallbackToHost, _fp_degrade
+
+# same-width unsigned views for bit-exact digest/corruption bitcasts
+_UINT_BY_ITEMSIZE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
+                     8: jnp.uint64}
+
+
+# ------------------------------------------------------------- the format
+
+def anchor(storage):
+    """Feed/meta cache key object.  Delta-maintained snapshots carry
+    a ``feed_lineage`` whose identity is stable across patch
+    generations (copr/region_cache.py FeedLineage) — anchoring on it
+    keeps the HBM feed warm across writes; plain snapshots anchor on
+    themselves (invalidation by identity, as before)."""
+    lineage = getattr(storage, "feed_lineage", None)
+    return storage if lineage is None else lineage
+
+
+def generation(storage) -> tuple:
+    """→ (``storage``'s feed lineage or None, the generation of the line
+    THIS snapshot reflects): the line may already be further ahead, or
+    this may be a history-served older generation."""
+    lineage = getattr(storage, "feed_lineage", None)
+    v = getattr(storage, "feed_version", None)
+    if lineage is not None and v is None:
+        v = lineage.version
+    return lineage, v
+
+
+def plane_kinds(plan) -> tuple:
+    """Per used column of ``plan`` what its value plane holds: ``"date"``
+    (a DATE column's ``core >> 41`` as int32), a CHAR column's width in
+    bytes (its codes, datatype/tile.py), or None (the column itself: a
+    scaled DECIMAL is scaled in the cache line already).  Plan analysis
+    decides (device/lowering.py); a feed carries its kinds from its
+    build on, so a patch or a split asks the feed."""
+    codes = plan.code_planes or (0,) * len(plan.used_cols)
+    return tuple("date" if d else (w or None)
+                 for d, w in zip(plan.date_planes, codes))
+
+
+def plane_values(kind, vals: np.ndarray):
+    """A used column's host values as the values of its device plane,
+    before the cast to the plane's dtype: THE rule from a column to a
+    plane, for a cold build and for a patch alike.  None where a CHAR
+    value has no code (wider than the column's declared bytes, or
+    holding the pad byte): such strings stay with the host."""
+    if kind is None:
+        return vals
+    if kind == "date":
+        return date_plane(vals)
+    return code_plane(vals, kind)
+
+
+def fits_dtype(vals: np.ndarray, valid, dt: np.dtype) -> bool:
+    """May ``vals`` be represented in the feed's established device
+    dtype?  Floats narrow exactly like a fresh astype would; ints must
+    fit the integer range (and uint64 stays below 2^63 — the same feed
+    guard that routes beyond-int64 cores to the host)."""
+    if dt.kind not in "iu":
+        return True
+    live = vals if valid is None or valid.all() else vals[valid]
+    if not live.size:
+        return True
+    lo, hi = int(live.min()), int(live.max())
+    if dt == np.dtype(np.uint64):
+        return 0 <= lo and hi < (1 << 63)
+    info = np.iinfo(dt)
+    return info.min <= lo and hi <= info.max
+
+
+def value_plane_index(null_flags) -> list:
+    """Per used column, the index in ``flat`` of its value plane (its
+    validity plane, where it has one, is the next)."""
+    out, fi = [], 0
+    for has_nulls in null_flags:
+        out.append(fi)
+        fi += 2 if has_nulls else 1
+    return out
+
+
+def span_planes(span, used_infos, kinds):
+    """One row span of a lineage's patch journal as plane values: per
+    used column ``(values, validity)``, the values None where a CHAR
+    value has no code, the validity None for the pk handle."""
+    for info, kind in zip(used_infos, kinds):
+        vals, valid = (span["handles"], None) if info.is_pk_handle \
+            else span["cols"][info.col_id]
+        yield plane_values(kind, vals), valid
+
+
+class HostPlanes:
+    """One request's used columns as the host halves of their planes:
+    device dtypes, then (values, validity) numpy pairs at those dtypes.
+
+    Both are kept for the snapshot's lifetime in ``meta`` (as the feed
+    is: the astype alone costs ~2s per 100M-row REAL column, and the
+    TopN candidate refine reads the pairs on every request),
+    version-guarded: what a request derives once the line has moved on
+    (``fresh()`` false) stays request-local, in ``memo``.  ``force_host``
+    alone goes to ``meta`` whatever the generation: conservative-sticky,
+    so repeat requests do not rebuild columns to re-discover it."""
+
+    __slots__ = ("plan", "infos", "kinds", "meta", "memo", "fresh",
+                 "get_batch", "n", "recorder")
+
+    def __init__(self, plan, meta: dict, memo: dict, fresh, get_batch,
+                 n: int, recorder):
+        self.plan = plan
+        self.infos = [plan.scan.columns[ci] for ci in plan.used_cols]
+        self.kinds = plane_kinds(plan)
+        self.meta = meta
+        self.memo = memo
+        self.fresh = fresh
+        self.get_batch = get_batch
+        self.n = n
+        self.recorder = recorder
+
+    def dtypes(self) -> tuple:
+        memo, meta = self.memo, self.meta
+        if "dtypes" not in memo:
+            if "dtypes" in meta and self.fresh():
+                memo["dtypes"] = meta["dtypes"]
+                memo["limbs"] = meta.get("limbs", ())
+            else:
+                self._derive()
+        return memo["dtypes"]
+
+    @property
+    def limbs(self) -> tuple:
+        """The aggregates ``lowering.fit`` asks to be summed as limbs."""
+        self.dtypes()
+        return self.memo["limbs"]
+
+    def _host(self, reason: str):
+        self.meta["force_host"] = True
+        raise _FallbackToHost(reason)
+
+    def _values(self, pos: int, col):
+        """Used column ``pos``'s plane values, made once a request."""
+        made = self.memo.setdefault("plane_vals", {})
+        if pos not in made:
+            made[pos] = plane_values(self.kinds[pos], col.values)
+        return made[pos]
+
+    def _derive(self) -> None:
+        plan, memo = self.plan, self.memo
+        batch = self.get_batch()
+        dts = []
+        bounds = []
+        for pos, ci in enumerate(plan.used_cols):
+            col = batch.columns[ci]
+            if col.eval_type is EvalType.DECIMAL and col.frac is None:
+                # the build kept this DECIMAL column as objects (a
+                # value beyond its declared scale, a store without
+                # the native build): host, as before the lowering
+                self._host("unscaled DECIMAL column")
+            kind = self.kinds[pos]
+            vals = self._values(pos, col)
+            if vals is None:
+                self._host("CHAR value without a code")
+            if kind == "date":
+                dt = np.dtype(np.int32)
+                self.recorder.note_plane("date")
+            elif kind:
+                dt = _device_dtype(EvalType.INT, vals)
+                self.recorder.note_plane("code")
+            else:
+                dt = _device_dtype(col.eval_type, vals)
+                if col.frac is not None:
+                    self.recorder.note_plane("decimal")
+            if dt == np.dtype(np.uint64) and not fits_dtype(vals, None, dt):
+                # packed cores above 2^63 (year >= 8192) would
+                # wrap in the int64 state carries
+                self._host("u64 column beyond int64")
+            dts.append(str(dt))
+            if plan.lowered:
+                bounds.append((int(vals.min()), int(vals.max()))
+                              if vals.size else (0, 0))
+        limbs = ()
+        if plan.lowered:
+            limbs = lowering.fit(plan, bounds, dts, self.n)
+            if limbs is None:
+                # the integer form may wrap at the planes' natural
+                # width even with its products summed as limbs: try
+                # every plane at int64 (the XLA bodies serve it),
+                # else the host pipeline, whose Decimals are exact
+                wide = ["int64" if np.dtype(d).kind == "i" else d
+                        for d in dts]
+                if not lowering.fits(plan, bounds, wide, self.n):
+                    self._host("lowered DECIMAL arithmetic not provably "
+                               "inside int64")
+                dts, limbs = wide, ()
+        memo["dtypes"] = tuple(dts)
+        memo["limbs"] = limbs
+        if self.fresh():
+            self.meta["dtypes"] = memo["dtypes"]
+            self.meta["limbs"] = limbs
+
+    def stream(self):
+        """Yield the pairs one column at a time, building the memo
+        incrementally: the cold feed upload issues each column's
+        (async) device_put as soon as that column is converted, so the
+        H2D transfer of column i overlaps the astype of column i+1 —
+        double-buffering the tail of a columnar build instead of
+        serializing convert-all then upload-all."""
+        memo, meta = self.memo, self.meta
+        if "host_cols" in memo:
+            yield from memo["host_cols"]
+            return
+        if "host_cols" in meta and self.fresh():
+            yield from meta["host_cols"]
+            return
+        dts = self.dtypes()
+        batch = self.get_batch()
+        built = []
+        for pos, (ci, ds) in enumerate(zip(self.plan.used_cols, dts)):
+            col = batch.columns[ci]
+            vals = self._values(pos, col)
+            if vals is None:
+                raise _FallbackToHost("CHAR value without a code")
+            pair = (np.ascontiguousarray(
+                vals.astype(np.dtype(ds), copy=False)),
+                np.ascontiguousarray(col.validity))
+            built.append(pair)
+            yield pair
+        memo["host_cols"] = built
+        if self.fresh():
+            meta["host_cols"] = built
+
+    def cols(self) -> list:
+        return list(self.stream())
+
+
+# -------------------------------------------------------------- the store
+
+class FeedStore:
+    """The feeds of one ``DeviceRunner`` (module docstring)."""
+
+    def __init__(self, runner):
+        self._runner = runner
+
+    # --------------------------------------------- the shape, the builders
+
+    def unit(self) -> int:
+        return self._runner._nshards() * self._runner._block_local
+
+    def pad_rows(self, n: int) -> int:
+        unit = self.unit()
+        blocks = max(1, -(-n // unit))
+        # bucket the block count into a 9/8-geometric grid: every
+        # padded shape is a compile class (pallas grid + XLA scan
+        # length), and live regions change size on every write — exact
+        # padding would recompile the kernels on each data version.
+        # Bucketing bounds the number of compile classes
+        # logarithmically and taxes ONLY the cache key, never the
+        # computed extent: blocks past the live rows skip their MXU /
+        # aggregation work (pl.when dead-block guard in pallas_hash,
+        # lax.cond guard in aggregate.py _scan_program's step), so the
+        # ≤12.5% padding costs DMA + grid steps, not kernel time.
+        if not self._runner._chunk_override and blocks > 8:
+            # one ROW of growth headroom BEFORE bucketing: it only
+            # moves sizes whose live rows exactly fill their last block
+            # (ceil absorbs it everywhere else), so such a feed — e.g.
+            # a power-of-two bulk load — does not change compile class
+            # (XLA recompile + full re-upload) on the very first
+            # appended row.  (Was one BLOCK: where the bucket grid is
+            # one block wide — 9..15 blocks — n and n+1 then still
+            # landed in different buckets; found on a 2x2 v5e mesh,
+            # where 10,485,760 rows fill ten 2^20-row blocks exactly.)
+            blocks = -(-(n + 1) // unit)
+            # round up to a 4-significant-bit block count (k·2^s,
+            # 8 ≤ k ≤ 15): keeps n_pad rich in powers of two so
+            # pick_chunk's gcd still finds large scan chunks
+            s = blocks.bit_length() - 4
+            k = -(-blocks // (1 << s))
+            if k > 15:
+                s += 1
+                k = -(-blocks // (1 << s))
+            blocks = k << s
+        return blocks * unit
+
+    def pick_chunk(self, n_pad: int, desired: int) -> int:
+        """Largest scan-block size ≤ desired that divides the padded feed
+        and splits evenly over shards."""
+        unit = self.unit()
+        if self._runner._chunk_override:
+            desired = unit
+        desired = max(unit, (desired // unit) * unit)
+        return math.gcd(n_pad, desired)
+
+    def make_feed(self, flat, null_flags, n_pad: int, kinds, truth,
+                  n: int) -> dict:
+        """THE feed dict: every builder (the upload, the device MVCC
+        resolve, a split's child) comes here with its planes and the
+        HOST ``truth`` they hold (per used column (values, validity) at
+        the planes' dtypes; read only where the runner records digests).
+        The digests anchor there, never to the planes they audit: a wrong
+        resolve, slice or gather diverges at the next scrub instead of
+        laundering."""
+        feed = {"flat": tuple(flat), "null_flags": tuple(null_flags),
+                "n_pad": n_pad, "kinds": tuple(kinds)}
+        if self._runner.scrub_digests:
+            from .supervisor import host_plane_digest
+            digests = []
+            for (v, ok), has_nulls in zip(truth, feed["null_flags"]):
+                digests.append(host_plane_digest(v, n))
+                if has_nulls:
+                    digests.append(host_plane_digest(ok, n))
+            feed["digests"] = tuple(digests)
+            feed["n_live"] = n
+            self._warm_digest_kernels(feed["flat"])
+        return feed
+
+    def _warm_digest_kernels(self, flat) -> None:
+        """Pre-register the planes' digest kernels now (a cold path) so
+        the warm patch path's incremental digest update mints no new
+        kernel cache entries — compile classes stay churn-stable."""
+        for a in flat:
+            self.range_digest_kernel(a.dtype, a.shape[0])
+
+    def _build_flat(self, host_cols, n: int, kinds) -> dict:
+        """One flat padded array per column value; a validity array only
+        for columns that actually contain NULLs — all-valid columns
+        reuse the on-device row mask (synthesized from iota < n), saving
+        the HBM footprint and H2D bandwidth of an all-true mask."""
+        r = self._runner
+        n_pad = self.pad_rows(n)
+        flat, flags, pairs = [], [], []
+
+        def put_padded(arr):
+            if r._single:
+                if n_pad == n:
+                    return jnp.asarray(arr)
+                # pad on the HOST: a device-side concatenate would
+                # compile per exact n (every data version has a new row
+                # count), costing seconds per cache rebuild; a host
+                # memcpy is shape-oblivious
+                p = np.zeros(n_pad, dtype=arr.dtype)
+                p[:n] = arr
+                return jnp.asarray(p)
+            # a sharded cold build, span by span: the host's padded
+            # copy, then handing one slice to each shard (the put is
+            # not waited for: the next plane's pad overlaps it, and the
+            # first launch waits for what is left)
+            with tracker.span("feed_host_pad"):
+                p = np.zeros(n_pad, dtype=arr.dtype)
+                p[:n] = arr
+            with tracker.span("feed_shard_put"):
+                return jax.device_put(p, r._row_sharding)
+
+        for v, ok in host_cols:
+            pairs.append((v, ok))
+            flat.append(put_padded(v))
+            flags.append(not bool(ok.all()))
+            if flags[-1]:
+                flat.append(put_padded(ok))
+        return self.make_feed(flat, flags, n_pad, kinds, pairs, n)
+
+    def get(self, storage, planes: HostPlanes, ranges, n: int, lineage,
+            req_v) -> dict:
+        """The feed of ``planes`` over ``ranges`` of ``storage``'s line
+        at generation ``req_v``, from the cheapest rung that has it: the
+        arena's (hit), that one patched forward, a split's stash, the
+        device MVCC resolve's bundle, the upload."""
+        scan, used_infos, dtypes = planes.plan.scan, planes.infos, \
+            planes.dtypes()
+        feed_key = (tuple(i.col_id for i in used_infos), dtypes, ranges)
+        # patching maps journal row positions straight onto feed
+        # rows — only sound for an ascending table scan (index
+        # scans re-sort, desc scans reverse)
+        positional = isinstance(scan, TableScanDesc) and \
+            not getattr(scan, "desc", False)
+        arena = self._runner._arena
+        cache = anc = None
+        if hasattr(storage, "scan_columns"):
+            anc = anchor(storage)
+            cache = arena.bucket(anc)
+        feed = cache.get(feed_key) if cache is not None else None
+        if feed is not None:
+            fv = feed.get("lineage_v")
+            if lineage is None or fv == req_v:
+                tracker.label("device_feed", "hit")
+                return feed
+            if fv is not None and fv > req_v:
+                # an older-generation read (history serve): never
+                # downgrade the shared feed — build a private one
+                cache = None
+            elif positional and self._try_patch_feed(
+                    feed, lineage, used_infos, dtypes, n, req_v):
+                # the snapshot moved forward under the feed: replay only
+                # the journal's dirty row spans into HBM instead of a
+                # cold re-upload — bucketed padding keeps n_pad (the
+                # compile class) stable across small deltas
+                tracker.label("device_feed", "patch")
+                self._register_digests(lineage, feed_key, feed)
+                return feed
+
+        def adopt(feed: dict) -> dict:
+            """A feed this ladder just made, into its line."""
+            if lineage is not None:
+                feed["lineage_v"] = req_v
+            if positional:
+                # which planes carry the pk-handle column (sourced from
+                # state.handles, not state.cols): a device-side region
+                # split re-anchors child digests to host truth by it
+                feed["positional"] = True
+                feed["pk_flags"] = tuple(bool(i.is_pk_handle)
+                                         for i in used_infos)
+            if cache is not None:
+                self._cache_feed(cache, feed_key, feed)
+                # admission runs under the dispatch lock (get's call
+                # site): the budget check may evict other, unpinned
+                # anchors
+                arena.admit(anc)
+                self._register_digests(lineage, feed_key, feed)
+            return feed
+
+        # device-side region split (supervisor.on_region_split): the
+        # parent feed was sliced by key range INTO this child lineage's
+        # stash — consume it instead of re-uploading from host.  The
+        # stash was digest-verified against the child's host truth at
+        # split time, so serving it is as safe as serving a scrubbed
+        # resident feed.
+        if lineage is not None and positional and cache is not None:
+            feed = self.take_split_feed(lineage, feed_key, n)
+            # (a child that moved past the stash where the journal
+            # cannot bridge it falls through to the upload)
+            if feed is not None and (
+                    feed.get("lineage_v") == req_v or self._try_patch_feed(
+                        feed, lineage, used_infos, dtypes, n, req_v)):
+                tracker.label("device_feed", "split")
+                return adopt(feed)
+        # cold-path kill (device/mvcc.py): a device build left its
+        # resolve artifacts on the lineage — mint the feed BORN
+        # RESIDENT (H2D of raw version planes — or nothing, if the
+        # streaming ingest pipeline already uploaded them — plus ONE
+        # resolve+gather dispatch) instead of the host pad/astype/upload
+        # pass.  One-shot and version-pinned; any failure falls through
+        # to the plain upload below, which is always correct.
+        if lineage is not None and \
+                getattr(lineage, "cold_bundle", None) is not None:
+            if positional and cache is not None and \
+                    not any(planes.kinds):
+                # (the resolver gathers the columns as they lie: a date
+                # or code plane is cut from the host mirror instead)
+                bundle = lineage.take_cold(req_v)
+                if bundle is not None:
+                    feed = bundle.mint(self, used_infos, dtypes, n,
+                                       self.pad_rows(n))
+                    if feed is not None:
+                        tracker.label("device_feed", "device_resolve")
+                        return adopt(feed)
+            else:
+                # first feed build for this line cannot consume the
+                # bundle (desc/index scan): release the raw planes
+                # now rather than pinning ~100 bytes/version on the
+                # lineage until a delta or teardown gets there
+                lineage.drop_cold()
+        tracker.label("device_feed", "upload")
+        _fp_degrade("device::before_feed_upload")
+        with tracker.phase("feed_upload"):
+            feed = self._build_flat(planes.stream(), n, planes.kinds)
+        return adopt(feed)
+
+    @staticmethod
+    def _cache_feed(bucket: dict, feed_key, feed: dict) -> None:
+        """``feed`` into its anchor's bucket.  A cached feed says what
+        it is cached under (``key``): a prepared record holds it to
+        that slot of the bucket by identity (``_stage_prepared``)."""
+        feed["key"] = feed_key
+        bucket[feed_key] = feed
+
+    @staticmethod
+    def _register_digests(lineage, feed_key, feed) -> None:
+        """Mirror the feed's per-plane digests into the FeedLineage's
+        host-visible journal — the line-level audit record the
+        supervisor reports (region_cache.py FeedLineage)."""
+        if lineage is not None and feed.get("digests") is not None and \
+                hasattr(lineage, "feed_digests"):
+            lineage.feed_digests[feed_key] = (feed.get("lineage_v"),
+                                              feed["digests"])
+
+    def take_split_feed(self, lineage, feed_key, n: int):
+        """Pop the stashed split-child feed matching this request's
+        shape (one-shot, like ``take_cold``): same columns and device
+        dtypes, same live row count, and the pad bucket THIS runner
+        would mint — a candidate sliced under a different feed unit
+        must not serve here.  Mutation races are benign: production
+        and consumption both run under the owning slice's dispatch
+        lock (children adopt the parent's slice)."""
+        stash = getattr(lineage, "split_stash", None)
+        if not stash:
+            return None
+        col_ids, dtypes, _ranges = feed_key
+        want_pad = self.pad_rows(max(n, 1))
+        for i, cand in enumerate(stash):
+            f = cand["feed"]
+            if cand["col_ids"] == col_ids and \
+                    cand["dtypes"] == tuple(dtypes) and \
+                    f.get("n_live") == n and f.get("n_pad") == want_pad:
+                del stash[i]
+                return dict(f)
+        return None
+
+    # ---------------------------------------------------------- the patch
+
+    def _try_patch_feed(self, feed, lineage, used_infos, dtypes,
+                        n: int, req_v) -> bool:
+        """Apply the lineage's dirty row spans to the device feed in
+        place of a cold upload.  Only sound when the patch journal
+        covers the gap with pure row patches (no repack/compaction/
+        tombstones), positions map 1:1 (full-snapshot ascending feed),
+        the padded shape is unchanged, and every patched value fits the
+        feed's established device dtypes.  Sharded feeds patch too:
+        GSPMD partitions the update and ``dus`` pins the result back
+        to the row sharding."""
+        patches = lineage.since(feed.get("lineage_v", -1), until=req_v)
+        if patches is None or any(p.get("structural") for p in patches):
+            return False
+        if patches and patches[-1]["n"] != n:
+            return False        # ranged feed: positions do not map 1:1
+        if self.pad_rows(max(n, 1)) != feed["n_pad"]:
+            return False        # row count crossed a pad bucket
+        plane = value_plane_index(feed["null_flags"])
+        flat = list(feed["flat"])
+        digests = list(feed["digests"]) \
+            if self._runner.scrub_digests and \
+            feed.get("digests") is not None else None
+        with tracker.phase("feed_patch"):
+            for p in patches:
+                for span in p["spans"]:
+                    lo = span["lo"]
+                    for ci, (vals, valid) in enumerate(span_planes(
+                            span, used_infos, feed["kinds"])):
+                        dt = np.dtype(dtypes[ci])
+                        if vals is None or \
+                                not fits_dtype(vals, valid, dt):
+                            return False
+                        if valid is not None and not valid.all() and \
+                                not feed["null_flags"][ci]:
+                            # first NULL in an all-valid column would
+                            # change the compile class: rebuild
+                            return False
+                        self._patch_plane(
+                            digests, flat, plane[ci], np.ascontiguousarray(
+                                vals.astype(dt, copy=False)), lo)
+                        if feed["null_flags"][ci]:
+                            mask = valid if valid is not None else \
+                                np.ones(len(vals), np.bool_)
+                            self._patch_plane(
+                                digests, flat, plane[ci] + 1,
+                                np.ascontiguousarray(mask), lo)
+        feed["flat"] = tuple(flat)
+        feed["lineage_v"] = req_v
+        if digests is not None:
+            feed["digests"] = tuple(digests)
+            feed["n_live"] = n
+        return True
+
+    def _patch_plane(self, digests, flat, fi: int, update: np.ndarray,
+                     lo: int) -> None:
+        """Plane ``flat[fi]``'s span patch + INCREMENTAL digest maintenance:
+        ``R' = R - H_span(old device plane) + H_span(new host data)``.
+        Never re-hashes the whole plane from device state — doing so
+        would launder any HBM corruption that landed since the last
+        scrub into the recorded digest (the recorded value must stay
+        anchored to the host-truth chain, so a pre-existing corruption
+        delta survives arithmetically and the next scrub still catches
+        it, wherever it sits relative to the patched span).  All device
+        scalars — nothing blocks under the dispatch lock."""
+        old = flat[fi]
+        new = flat[fi] = self.dus(old, update, lo)
+        if digests is not None:
+            hi = lo + len(update)
+            rng = self.range_digest_kernel(old.dtype, old.shape[0])
+            lo_arr = jnp.asarray(lo, jnp.int64)
+            hi_arr = jnp.asarray(hi, jnp.int64)
+            d_old = rng(old, lo_arr, hi_arr)
+            d_new = rng(new, lo_arr, hi_arr)
+            digests[fi] = jnp.uint64(digests[fi]) - d_old + d_new
+
+    def dus(self, arr, update, lo: int):
+        """Jitted in-place-style slice update (dynamic_update_slice);
+        the start index is traced, so repeated single-row patches at
+        different positions share one compile class per update length.
+        On a sharded feed GSPMD partitions the update and the jit's
+        ``out_shardings`` pins the result to the row sharding in the
+        SAME dispatch — no post-hoc device_put re-lay, so delta churn
+        on a sharded feed costs one small collective-free launch per
+        span, exactly like the single-device path."""
+        r = self._runner
+        fn = r._kernel_cache.get("feed_patch_fn")
+        if fn is None:
+            def feed_patch(a, u, i):
+                return lax.dynamic_update_slice(a, u, (i,))
+            fn = r._kernel_cache["feed_patch_fn"] = \
+                jax.jit(feed_patch) if r._single else \
+                jax.jit(feed_patch, out_shardings=r._row_sharding)
+        return fn(arr, update, jnp.asarray(lo, jnp.int32))
+
+    # -------------------------------------------------------- the digests
+    #
+    # The device half of device/supervisor.py's scrub: the on-device
+    # digest leaf the scrubber re-hashes resident planes with, and the
+    # fault its chaos arm injects.
+
+    def range_digest_kernel(self, dtype, n_pad: int):
+        """Jitted plane digest over rows [lo, hi) with GLOBAL position
+        weights: sum bits(x[i]) * (2i+1) mod 2^64 — the device half of
+        the scrub formula (host half: supervisor.host_plane_digest;
+        the full-prefix digest is just lo=0).  Cached per (dtype,
+        n_pad) like every other kernel; on a sharded feed GSPMD
+        partitions the reduction."""
+        dt = np.dtype(dtype)
+        key = ("scrubr", str(dt), n_pad)
+        cache = self._runner._kernel_cache
+        fn = cache.get(key)
+        if fn is None:
+            if dt == np.bool_ or (dt.kind in "iu" and dt.itemsize == 8):
+                # 64-bit ints convert, not bitcast: the wrap mod 2^64
+                # IS the bit pattern, and the TPU compiler has no
+                # 64-bit bitcast-convert (its X64 rewrite rejects it —
+                # found on v5e, libtpu 0.0.34)
+                to_bits = lambda x: x.astype(jnp.uint64)    # noqa: E731
+            else:
+                # narrower ints and float32: bitcast to the same-width
+                # unsigned view, then widen.  (float64 takes this
+                # branch too and lowers on CPU only; feed planes are
+                # never float64 — datatype/tile.py _device_dtype.)
+                udt = _UINT_BY_ITEMSIZE[dt.itemsize]
+
+                def to_bits(x, _udt=udt):
+                    return lax.bitcast_convert_type(x, _udt) \
+                        .astype(jnp.uint64)
+
+            def feed_digest(x, lo_arr, hi_arr):
+                iota = jnp.arange(n_pad, dtype=jnp.uint64)
+                w = 2 * iota + 1
+                sel = (iota >= lo_arr.astype(jnp.uint64)) & \
+                    (iota < hi_arr.astype(jnp.uint64))
+                return jnp.sum(jnp.where(sel, to_bits(x) * w,
+                                         jnp.uint64(0)))
+
+            fn = cache[key] = jax.jit(feed_digest)
+        return fn
+
+    def device_digest(self, arr, n: int):
+        """Digest of one resident plane's live prefix (device scalar —
+        the caller decides when to sync).  Deliberately avoids the
+        LRU scalar cache: the background scrubber calls this OUTSIDE
+        the dispatch lock, and the OrderedDict's move_to_end/popitem
+        is not safe against concurrent request threads."""
+        return self.range_digest_kernel(arr.dtype, arr.shape[0])(
+            arr, jnp.asarray(0, jnp.int64), jnp.asarray(n, jnp.int64))
+
+    @staticmethod
+    def corrupt_resident_plane(feed: dict) -> None:
+        """Fault injection (device::feed_corrupt): flip one element of
+        the first resident plane (a value plane: never bool) in place of
+        the HBM bit-flip a real device fault would cause.  Test/chaos
+        surface only."""
+        arr = feed["flat"][0]
+        dt = np.dtype(arr.dtype)
+        if dt.kind in "iu":
+            bad = arr.at[0].set(arr[0] ^ 1)     # single-bit flip
+        else:
+            # floats: a true single-BIT flip via bitcast → xor 1
+            u = lax.bitcast_convert_type(
+                arr, _UINT_BY_ITEMSIZE[dt.itemsize])
+            bad = lax.bitcast_convert_type(u.at[0].set(u[0] ^ 1),
+                                           arr.dtype)
+        feed["flat"] = (bad,) + feed["flat"][1:]
+
+    # ------------------------------------------- the move and the split
+    #
+    # Elastic stress without the host link: a placement move, a
+    # quarantine drain, or a co-location pull copies the resident
+    # feed between slices over the device interconnect (device_put
+    # across the mesh) instead of dropping it and re-minting from
+    # host truth; a region split slices the parent feed by key range
+    # on device into two child feeds.  Both re-verify against the
+    # scrub-digest chain before anything serves.
+
+    def extract_feeds(self, anchor):
+        """→ (migratable feeds by key, skipped count) for an ICI move
+        of ``anchor`` off this slice, or (None, 0) when nothing can
+        travel.  Only feeds carrying scrub digests are migratable —
+        the destination re-verifies on arrival, and a feed that
+        cannot be verified must re-mint from host truth instead of
+        serving unaudited (skipped counts those).  Snapshot under the
+        dispatch lock: (flat, digests) pairs update non-atomically on
+        the patch path."""
+        r = self._runner
+        if not r._single:
+            return None, 0
+        bucket = r._arena.bucket(anchor, create=False)
+        if not bucket:
+            return None, 0
+        out = {}
+        skipped = 0
+        with r._dispatch_mu:
+            for k, v in bucket.items():
+                if not (isinstance(v, dict) and "flat" in v):
+                    continue
+                if v.get("digests") is None:
+                    skipped += 1
+                    continue
+                out[k] = dict(v)
+        return (out or None), skipped
+
+    def install_feeds(self, anchor, feeds: dict) -> str:
+        """Arrival side of an ICI feed migration → ``"moved"`` or
+        ``"corrupt"``.  Each plane is device_put onto this slice and
+        re-hashed against the digests that traveled with it BEFORE
+        anything installs — a plane diverging mid-flight (ICI fault,
+        HBM corruption on either end; chaos arms
+        ``device::feed_migrate``) quarantines-and-rebuilds, never
+        serves silently corrupt.  A feed the destination already
+        holds at the same or newer lineage generation is never
+        clobbered (a request raced the move and re-minted)."""
+        from ..utils.failpoint import fail_point
+        r = self._runner
+        dev = r._mesh.devices.flat[0]
+        installed = {}
+        for fkey, feed in feeds.items():
+            nf = dict(feed, flat=tuple(jax.device_put(a, dev)
+                                       for a in feed["flat"]))
+            if fail_point("device::feed_migrate") is not None:
+                # the injected mid-transfer fault: one bit flips on a
+                # transferred plane; the verify below must catch it
+                self.corrupt_resident_plane(nf)
+            n = feed.get("n_live", 0)
+            arrived = []
+            for arr, want in zip(nf["flat"], feed["digests"]):
+                got = int(np.asarray(self.device_digest(arr, n)))
+                if got != int(np.asarray(want)):
+                    return "corrupt"
+                arrived.append(got)
+            # the digest chain must live where its planes live: a
+            # scalar still committed to the SOURCE slice would turn
+            # the next incremental patch into a cross-device subtract
+            nf["digests"] = tuple(
+                jax.device_put(jnp.asarray(w, jnp.uint64), dev)
+                for w in arrived)
+            installed[fkey] = nf
+            self._warm_digest_kernels(nf["flat"])
+        with r._dispatch_mu:
+            bucket = r._arena.bucket(anchor)
+            if bucket is None:
+                return "corrupt"    # untrackable anchor: caller re-mints
+            for fkey, nf in installed.items():
+                cur = bucket.get(fkey)
+                if isinstance(cur, dict) and \
+                        cur.get("lineage_v") is not None and \
+                        nf.get("lineage_v") is not None and \
+                        cur["lineage_v"] >= nf["lineage_v"]:
+                    continue
+                self._cache_feed(bucket, fkey, nf)
+                self._register_digests(anchor, fkey, nf)
+            r._arena.admit(anchor)
+        return "moved"
+
+    def _split_plane_kernel(self, dtype, n_pad_parent: int,
+                            n_pad_child: int, right: bool):
+        """Jitted key-range slice of one resident plane into a split
+        child: left takes rows [0, pos), right takes [pos, pos+n) via
+        a roll — the split position is traced, so every split of the
+        same (side, dtype, pad buckets) shares one compile class.
+        Rows past the child's live count zero out (padding invariant,
+        matching _build_flat's host zeros)."""
+        dt = np.dtype(dtype)
+        key = ("splitp", bool(right), str(dt), n_pad_parent, n_pad_child)
+        cache = self._runner._kernel_cache
+        fn = cache.get(key)
+        if fn is None:
+            def kern(x, pos, n_child):
+                y = (jnp.roll(x, -pos) if right else x)[:n_pad_child]
+                iota = jnp.arange(n_pad_child)
+                return jnp.where(iota < n_child, y,
+                                 jnp.zeros((), y.dtype))
+            fn = cache[key] = jax.jit(named_program(kern, "device_split"))
+        return fn
+
+    def split_resident_feeds(self, spec) -> str:
+        """Device-side region split of every resident feed anchored on
+        the parent lineage (``spec`` from RegionColumnarCache
+        .split_lines) → ``"split"`` when at least one child feed was
+        minted on device, else ``"none"``.  Fans out to whichever
+        runner holds the parent's bucket (placement slice, degraded
+        submesh, or this store's)."""
+        parent = spec["parent_lineage"]
+        for r in [self._runner] + self._runner._sub_runners():
+            bucket = r._arena.bucket(parent, create=False)
+            if bucket:
+                return r._feeds._split_local_feeds(bucket, spec)
+        return "none"
+
+    def _split_local_feeds(self, bucket, spec) -> str:
+        """Slice this runner's resident parent feeds into split-child
+        candidates, stashed on the child lineages for their first
+        request to consume (``take_split_feed``).  Child digests are
+        recomputed from the children's HOST state — never derived
+        from device planes, so a corruption that landed on the parent
+        since its last scrub fails the verify here instead of
+        laundering into the child's recorded chain."""
+        if not self._runner._single:
+            return "none"       # sharded whole-mesh feeds re-mint
+        out = "none"
+        with self._runner._dispatch_mu:
+            for fkey, feed in list(bucket.items()):
+                if not (isinstance(feed, dict) and "flat" in feed):
+                    continue
+                if not feed.get("positional") or \
+                        feed.get("pk_flags") is None or \
+                        feed.get("digests") is None:
+                    continue
+                if feed.get("lineage_v") != spec["parent_version"] or \
+                        feed.get("n_live") != spec["n_parent"]:
+                    continue    # stale generation: positions lie
+                for side in ("left", "right"):
+                    child = spec.get(side)
+                    if child is None or child["n"] <= 0:
+                        continue
+                    cf = self._mint_split_child(feed, fkey, spec, child,
+                                                right=(side == "right"))
+                    if cf is not None:
+                        stash = getattr(child["lineage"], "split_stash",
+                                        None)
+                        if stash is None:
+                            stash = child["lineage"].split_stash = []
+                        stash.append({"col_ids": fkey[0],
+                                      "dtypes": tuple(fkey[1]),
+                                      "feed": cf})
+                        out = "split"
+        return out
+
+    def _mint_split_child(self, feed, fkey, spec, child, right: bool):
+        """One child feed: slice every parent plane on device, anchor
+        the child's digest chain to its host truth, and verify the
+        sliced planes against it (the split's arrival verify) — or
+        None when anything diverges (that child re-uploads)."""
+        if any(feed["kinds"]):
+            # the child's host truth is read here as the columns lie
+            # (``plane_values`` is not applied): a line with a date or
+            # code plane re-uploads (ROADMAP D17)
+            return None
+        n_child = child["n"]
+        n_pad_child = self.pad_rows(max(n_child, 1))
+        parent_pad = feed["n_pad"]
+        if n_pad_child > parent_pad:
+            return None
+        state = child["state"]
+        pairs = []              # the child's host truth, plane by plane
+        for ci, pk in enumerate(feed["pk_flags"]):
+            bufs = (state.handles, None) if pk \
+                else state.cols.get(fkey[0][ci])
+            if bufs is None:
+                return None
+            valid = bufs[1][:n_child] if bufs[1] is not None \
+                else np.ones(n_child, np.bool_)
+            pairs.append((np.ascontiguousarray(bufs[0][:n_child].astype(
+                np.dtype(fkey[1][ci]), copy=False)),
+                np.ascontiguousarray(valid)))
+        pos_arr = jnp.asarray(spec["pos"], jnp.int32)
+        n_arr = jnp.asarray(n_child, jnp.int32)
+        cf = self.make_feed([self._split_plane_kernel(
+            a.dtype, parent_pad, n_pad_child, right)(a, pos_arr, n_arr)
+            for a in feed["flat"]], feed["null_flags"], n_pad_child,
+            feed["kinds"], pairs, n_child)
+        # the split's arrival verify (a store that records no digests
+        # has nothing to hold the slices to)
+        if cf.get("digests") is None or any(
+                int(np.asarray(self.device_digest(a, n_child))) != want
+                for a, want in zip(cf["flat"], cf["digests"])):
+            return None
+        cf.update(lineage_v=child["lineage"].version, positional=True,
+                  pk_flags=feed["pk_flags"])
+        return cf

@@ -74,6 +74,7 @@ from ..datatype import EvalType
 from ..expr import build_rpn
 from ..expr.eval import eval_rpn
 from ..utils.failpoint import fail_point
+from .feed import anchor as feed_anchor, generation
 from .kernels import named_program
 from .request import _remap_rpn, _rpn_col_indices, _rpn_device_safe
 
@@ -239,15 +240,6 @@ class DeviceJoiner:
                 freed += ent["nbytes"]
         return freed
 
-    @staticmethod
-    def _anchor_version(storage):
-        lineage = getattr(storage, "feed_lineage", None)
-        anchor = storage if lineage is None else lineage
-        v = getattr(storage, "feed_version", None)
-        if lineage is not None and v is None:
-            v = lineage.version
-        return anchor, v
-
     # ---------------------------------------------------------- kernels
 
     def _kern(self, key, build):
@@ -257,7 +249,7 @@ class DeviceJoiner:
         return fn
 
     def _pad(self, n: int) -> int:
-        return self._runner._pad_rows(max(1, n))
+        return self._runner._feeds.pad_rows(max(1, n))
 
     @staticmethod
     def _pad_plane(arr: np.ndarray, n_pad: int):
@@ -365,7 +357,8 @@ class DeviceJoiner:
                                   build_scan, right_key):
             return None
         # ---- build side: device-resident sorted dictionary ----
-        banchor, bver = self._anchor_version(build_storage)
+        banchor, bver = feed_anchor(build_storage), \
+            generation(build_storage)[1]
         bkey = ("build", id(banchor), bver, build_scan.columns[
             right_key].col_id, tuple(build_ranges))
         ent = self._cache_get(bkey)
@@ -396,7 +389,8 @@ class DeviceJoiner:
         rpns = [build_rpn(c) for c in probe_conds]
         used = sorted({i for r in rpns
                        for i in _rpn_col_indices(r)})
-        panchor, pver = self._anchor_version(probe_storage)
+        panchor, pver = feed_anchor(probe_storage), \
+            generation(probe_storage)[1]
         pkey_id = probe_scan.columns[left_key].col_id
         pkey_cache = ("probe", id(panchor), pver, pkey_id,
                       tuple(probe_scan.columns[i].col_id for i in used),

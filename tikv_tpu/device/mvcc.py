@@ -326,7 +326,7 @@ def host_mirror(planes: WritePlanes, winners: np.ndarray,
 
 
 def _bucket(n: int, floor: int = 256) -> int:
-    """Geometric capacity bucket (k·2^s, 8 ≤ k ≤ 15 — the _pad_rows
+    """Geometric capacity bucket (k·2^s, 8 ≤ k ≤ 15 — feed.py's ``pad_rows``
     grid) so version-plane shapes, like feed shapes, mint a bounded
     number of compile classes under growth."""
     n = max(floor, n)
@@ -415,7 +415,7 @@ class DeviceVersionPlanes:
 class ColdFeedBundle:
     """One cold build's device-resolve artifacts, stashed on the new
     cache line's FeedLineage until the runner's first feed miss mints
-    the born-resident feed from them (runner._get_feed).
+    the born-resident feed from them (device/feed.py ``FeedStore.get``).
 
     One-shot and version-0-only: any delta landing first (the line
     moved on) or a mint attempt (success OR failure) drops it — the
@@ -456,9 +456,9 @@ class ColdFeedBundle:
 
     # ------------------------------------------------------------ mint
 
-    def mint(self, runner, used_infos: Sequence, dtypes: Sequence,
+    def mint(self, store, used_infos: Sequence, dtypes: Sequence,
              n: int, n_pad: int):
-        """Build the feed dict (the exact ``_build_flat`` layout) by
+        """Build the feed (``store.make_feed``: the upload's layout) by
         resolving + gathering ON DEVICE.  Returns None when this bundle
         cannot serve the request (shape moved, columns missing) — the
         caller falls through to the host upload path."""
@@ -469,7 +469,7 @@ class ColdFeedBundle:
                     info.col_id not in self.mirror_cols:
                 return None
         try:
-            return self.resolver._mint(self, runner, used_infos,
+            return self.resolver._mint(self, store, used_infos,
                                        dtypes, n, n_pad)
         finally:
             self.release()
@@ -579,12 +579,13 @@ class DeviceMvccResolver:
 
     # -- feed mint ---------------------------------------------------------
 
-    def _mint(self, bundle: ColdFeedBundle, runner, used_infos,
+    def _mint(self, bundle: ColdFeedBundle, store, used_infos,
               dtypes, n: int, n_pad: int):
         import jax.numpy as jnp
 
         from ..utils import tracker
         from ..utils.failpoint import fail_point
+        from .feed import value_plane_index
         if fail_point("device::mvcc_resolve") is not None:
             self.mint_failures += 1
             return None
@@ -661,11 +662,7 @@ class DeviceMvccResolver:
             # PUTs whose payload lives in CF_DEFAULT — patch them from
             # the host-truth values fetched at build time
             if bundle.spill_patches:
-                plane_of = {}
-                fi = 0
-                for ci, info in enumerate(used_infos):
-                    plane_of[ci] = fi
-                    fi += 2 if null_flags[ci] else 1
+                plane_of = value_plane_index(null_flags)
                 for row, payload in bundle.spill_patches.items():
                     for ci, info in enumerate(used_infos):
                         if info.is_pk_handle:
@@ -675,39 +672,25 @@ class DeviceMvccResolver:
                         upd = np.asarray(
                             [col.values[row]]).astype(
                                 flat[fi].dtype, copy=False)
-                        flat[fi] = runner._dus(flat[fi], jnp.asarray(upd),
-                                               row)
+                        flat[fi] = store.dus(flat[fi], jnp.asarray(upd),
+                                             row)
                         if null_flags[ci]:
                             m = np.asarray([bool(col.validity[row])])
-                            flat[fi + 1] = runner._dus(
+                            flat[fi + 1] = store.dus(
                                 flat[fi + 1], jnp.asarray(m), row)
 
-        feed = {"flat": tuple(flat), "null_flags": tuple(null_flags),
-                "n_pad": n_pad}
-        if runner.scrub_digests:
-            # digests anchor to HOST truth (the mirror), never to the
-            # device planes they audit — a wrong resolve or a corrupt
-            # gather diverges at the next scrub instead of laundering
-            from .supervisor import host_plane_digest
-            digests = []
-            for info, ds, nulls in zip(used_infos, dtypes, null_flags):
-                if info.is_pk_handle:
-                    v = bundle.mirror_handles
-                else:
-                    v = bundle.mirror_cols[info.col_id].values
-                digests.append(host_plane_digest(
-                    np.ascontiguousarray(v.astype(np.dtype(ds),
-                                                  copy=False)), n))
-                if nulls:
-                    digests.append(host_plane_digest(
-                        np.ascontiguousarray(
-                            bundle.mirror_cols[info.col_id].validity), n))
-            feed["digests"] = tuple(digests)
-            feed["n_live"] = n
-            for a in feed["flat"]:
-                runner._range_digest_kernel(a.dtype, a.shape[0])
         self.mints += 1
-        return feed
+        mirror = [(bundle.mirror_handles, None) if i.is_pk_handle
+                  else (bundle.mirror_cols[i.col_id].values,
+                        bundle.mirror_cols[i.col_id].validity)
+                  for i in used_infos]
+        # every plane the column itself (``FeedStore.get`` keeps a date
+        # or code plane away from this rung); its host truth the mirror,
+        # cast only where the store reads it
+        return store.make_feed(
+            flat, null_flags, n_pad, (None,) * len(null_flags),
+            ((np.ascontiguousarray(v.astype(np.dtype(ds), copy=False)), ok)
+             for (v, ok), ds in zip(mirror, dtypes)), n)
 
     def stats(self) -> dict:
         with self._mu:

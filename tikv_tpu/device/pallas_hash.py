@@ -48,7 +48,7 @@ its share of the HBM roofline are in PERF.md section 5, ledger-cited):
   exactly in HI=32 sublanes (was 40 with scrap+NULL: 20% more one-hot
   generation and dot).
 - **Dead grid blocks skip the MXU (r6).**  The feed pads to a bucketed
-  shape (runner._pad_rows: the 9/8-geometric grid bounds compile
+  shape (feed.py ``pad_rows``: the 9/8-geometric grid bounds compile
   classes), but the bucketing must tax only the CACHE KEY, not the
   computed extent: blocks entirely outside [row_lo, row_hi) gate the
   whole one-hot + dot body behind ``pl.when``, so a masked block costs

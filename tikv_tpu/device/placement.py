@@ -80,6 +80,7 @@ from ..pd.scheduler import (
     rebalance_donor,
     slice_scores,
 )
+from .feed import anchor as feed_anchor
 
 # feeds at or above this many rows shard over the WHOLE mesh instead of
 # pinning to one slice: one chip's HBM pass over 4M+ rows costs more
@@ -281,7 +282,7 @@ class SlicePlacer:
         sub-runner, or the whole-mesh parent for large feeds and
         untrackable anchors."""
         from ..utils import metrics as m
-        anchor = self._parent._feed_anchor(storage)
+        anchor = feed_anchor(storage)
         if n_hint is None:
             est = getattr(storage, "estimated_rows", None)
             if callable(est):
@@ -410,7 +411,7 @@ class SlicePlacer:
         t0 = time.perf_counter()
         with tracker.phase("feed_migrate"):
             try:
-                feeds, skipped = src_r.extract_feeds(anchor)
+                feeds, skipped = src_r._feeds.extract_feeds(anchor)
             except Exception:   # noqa: BLE001 — migration is best-effort
                 feeds, skipped = None, 0
             if not feeds:
@@ -420,7 +421,7 @@ class SlicePlacer:
                     self.migration_failures += 1
                 return False
             try:
-                verdict = dst_r.install_feeds(anchor, feeds)
+                verdict = dst_r._feeds.install_feeds(anchor, feeds)
             except Exception:   # noqa: BLE001 — same contract
                 verdict = "corrupt"
             if verdict != "moved":
@@ -588,13 +589,6 @@ class SlicePlacer:
         return True
 
     # -- fan-out helpers (parent delegation) --------------------------
-
-    def drop_feed_all(self, anchor, reason: str) -> int:
-        freed = 0
-        for r in self._slices:
-            freed += r.drop_feed(anchor, reason=reason)
-        self.forget(anchor)
-        return freed
 
     def set_hbm_budget(self, parent_budget: int) -> None:
         """Per-slice share of the node budget: slices split it evenly

@@ -29,6 +29,17 @@ class _FallbackToHost(Exception):
     """Raised when a runtime property (not the plan) forces the host path."""
 
 
+def _fp_degrade(name: str) -> None:
+    """Failpoint site that degrades to the host backend: a fired
+    ``return`` action raises _FallbackToHost, so an injected device
+    fault (or a real one steered in tests) downgrades the query instead
+    of failing it — the runner's existing fallback machinery catches it.
+    """
+    from ..utils.failpoint import fail_point
+    if fail_point(name) is not None:
+        raise _FallbackToHost(name)
+
+
 #  DATETIME (packed u64 core — the bit layout is order-preserving) and
 #  DURATION (i64 ns) are device-native dense columns: comparisons, topN
 #  and min/max/count ride the same kernels as INT.  Years >= 8192 pack
@@ -148,7 +159,7 @@ class _PinnedStager:
     and the later ``np.asarray`` at fetch time reads settled host
     memory instead of paying the sync round trip.  One staging program
     is compiled per (shape, dtype, device) — shapes are already
-    pow2/9-8-geometric capacity buckets (``_pad_rows``), so the
+    pow2/9-8-geometric capacity buckets (feed.py ``pad_rows``), so the
     registration set is bounded exactly like the feed compile classes.
 
     Probed once per shape class: a backend that cannot run the

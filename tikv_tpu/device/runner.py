@@ -8,9 +8,10 @@ computation per plan over the whole HBM-resident feed:
 - rows are sharded over the ("range", "tile") mesh (parallel/mesh.py) —
   TiKV's region/bucket sharding mapped to mesh axes;
 - the feed is a set of flat padded column arrays cached in HBM across
-  requests (the region-cache-engine analog); row-validity for non-NULL
-  columns and the ragged tail is synthesized on device from an iota
-  compare, so it never crosses PCIe or burns HBM;
+  requests (the region-cache-engine analog; device/feed.py owns its
+  format, build ladder, patch, digests, move and split); row-validity
+  for non-NULL columns and the ragged tail is synthesized on device
+  from an iota compare, so it never crosses PCIe or burns HBM;
 - each request is ONE dispatch: a ``lax.scan`` over row blocks folds the
   aggregation carry on device (RpnExpression evaluation, the filter
   mask, and the aggregate kernels all trace into the same jit, so XLA
@@ -29,8 +30,8 @@ NamedSharding transfers — a 1-device mesh gains nothing from them;
 their co-located dispatch cost: not measured). A SHARDED mesh is a
 first-class backend, not a degraded
 one: feeds upload row-sharded and delta-PATCH in place
-(GSPMD-partitioned dynamic_update_slice, _dus), the fused Pallas kernel
-runs as per-shard partial grids psum-merged on ICI
+(GSPMD-partitioned dynamic_update_slice, feed.py ``dus``), the fused
+Pallas kernel runs as per-shard partial grids psum-merged on ICI
 (aggregate.py _pallas_sharded_wrap), selection mask/index routing is
 shard-concatenable, and hot regions optionally pin to single-device
 slices via the placement loop (device/placement.py) so a
@@ -76,9 +77,7 @@ from ..copr.dag import (
     TopNDesc,
 )
 from ..datatype import Column, ColumnBatch, EvalType
-from ..datatype.tile import (
-    _device_dtype, code_plane, code_width, date_plane,
-)
+from ..datatype.tile import _device_dtype, code_width
 from ..expr import build_rpn
 from ..expr.eval import eval_rpn
 from ..expr.rpn import RpnColumnRef, RpnExpression
@@ -86,11 +85,16 @@ from ..ops.agg import AggSpec
 from ..parallel import ROW_AXES, make_mesh, num_shards, row_sharding
 from . import lowering, pallas_hash
 from .aggregate import DeviceAggregator
+from .feed import (
+    FeedStore, HostPlanes, anchor as feed_anchor, fits_dtype, generation,
+    plane_kinds, span_planes,
+)
 from .kernels import named_program
 from .request import (
     _DEVICE_ETS,
     HOST_STAGER,
     _FallbackToHost,
+    _fp_degrade,
     _LanePending,
     _Pending,
     _Plan,
@@ -99,10 +103,6 @@ from .request import (
     _rpn_col_indices,
     _rpn_device_safe,
 )
-
-# same-width unsigned views for bit-exact digest/corruption bitcasts
-_UINT_BY_ITEMSIZE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
-                     8: jnp.uint64}
 
 # scan-block granularity per kernel kind (rows per lax.scan step; the
 # feed pads to a multiple of _FEED_UNIT per shard so any of these divide)
@@ -158,60 +158,6 @@ def _place_compile_cache() -> None:
         os.path.abspath(__file__))))
     jax.config.update("jax_compilation_cache_dir",
                       os.path.join(root, ".jax_cache"))
-
-
-def _fits_dtype(vals: np.ndarray, valid, dt: np.dtype) -> bool:
-    """May ``vals`` be represented in the feed's established device
-    dtype?  Floats narrow exactly like a fresh astype would; ints must
-    fit the integer range (and uint64 stays below 2^63 — the same feed
-    guard that routes beyond-int64 cores to the host)."""
-    if dt.kind not in "iu":
-        return True
-    live = vals if valid is None or valid.all() else vals[valid]
-    if not live.size:
-        return True
-    lo, hi = int(live.min()), int(live.max())
-    if dt == np.dtype(np.uint64):
-        return 0 <= lo and hi < (1 << 63)
-    info = np.iinfo(dt)
-    return info.min <= lo and hi <= info.max
-
-
-def _is_date_plane(info, dt: np.dtype) -> bool:
-    """Whether used column ``info`` rides a feed as the int32 date plane
-    (device/lowering.py): a time column on a signed plane is one, since
-    a packed core rides unsigned."""
-    return dt.kind == "i" and not info.is_pk_handle and \
-        info.field_type.eval_type is EvalType.DATETIME
-
-
-def _to_plane(info, vals: np.ndarray, dt: np.dtype) -> np.ndarray:
-    """A used column's host values as the values of its device plane,
-    before the cast to ``dt``: a DATE column on the date plane drops
-    its core's zero low bits, a CHAR column is its codes (a value that
-    has none reads beyond every dtype: ``_fits_dtype`` then refuses the
-    patch); every other column is itself (a scaled DECIMAL is scaled in
-    the cache line already)."""
-    if _is_date_plane(info, dt):
-        return date_plane(vals)
-    # (a string column rides a feed in no other form than its codes)
-    width = code_width(info.field_type) if vals.dtype == object else None
-    if width:
-        codes = code_plane(vals, width)
-        return codes if codes is not None else \
-            np.full(len(vals), 1 << 62, np.int64)
-    return vals
-
-
-def _fp_degrade(name: str) -> None:
-    """Failpoint site that degrades to the host backend: a fired
-    ``return`` action raises _FallbackToHost, so an injected device
-    fault (or a real one steered in tests) downgrades the query instead
-    of failing it — the runner's existing fallback machinery catches it.
-    """
-    from ..utils.failpoint import fail_point
-    if fail_point(name) is not None:
-        raise _FallbackToHost(name)
 
 
 _TIME_ETS = (EvalType.DATETIME, EvalType.DURATION)
@@ -632,6 +578,9 @@ class DeviceRunner:
         # the aggregation operator (device/aggregate.py), over this
         # runner's feeds, caches and dispatch span
         self._aggregator = DeviceAggregator(self)
+        # the feeds (device/feed.py): their format, build ladder, patch,
+        # digests, move and split, in this runner's arena
+        self._feeds = FeedStore(self)
         # dispatch serialization: two threads launching multi-device
         # executables concurrently can interleave their per-device
         # enqueues and deadlock the mesh (launch-order inversion), and
@@ -879,6 +828,16 @@ class DeviceRunner:
         with self._degrade_mu:
             return self._degraded[1] if self._degraded is not None \
                 else None
+
+    def _sub_runners(self) -> list:
+        """The runners under this one that hold feeds of their own: the
+        placer's slices and any degraded submesh runner."""
+        subs = list(self._placer.slices) if self._placer is not None \
+            else []
+        degraded = self._degraded_sub()
+        if degraded is not None:
+            subs.append(degraded)
+        return subs
 
     def _degraded_target(self) -> Optional["DeviceRunner"]:
         """The runner whole-mesh plans should use right now: a sub-
@@ -1194,11 +1153,8 @@ class DeviceRunner:
         plan = self._analyze(dag)
         if plan is None:
             return None
-        anchor = self._feed_anchor(storage)
-        lineage = getattr(storage, "feed_lineage", None)
-        req_v = getattr(storage, "feed_version", None)
-        if lineage is not None and req_v is None:
-            req_v = lineage.version
+        anchor = feed_anchor(storage)
+        _lineage, req_v = generation(storage)
         if plan.kind == "scan_sel" and plan.sel_rpns:
             if plan.sel_params is None:
                 from . import selection as selmod
@@ -1251,7 +1207,7 @@ class DeviceRunner:
         plan = runner._analyze(dag)
         if plan is None or plan.kind not in ("hash_agg", "simple_agg"):
             return None
-        per_storage = runner._arena.bucket(runner._feed_anchor(storage),
+        per_storage = runner._arena.bucket(feed_anchor(storage),
                                            create=False)
         meta = per_storage.get(("meta", runner._meta_key(dag, plan))) \
             if per_storage is not None else None
@@ -1668,358 +1624,16 @@ class DeviceRunner:
         return ColumnBatch.concat(chunks) if chunks \
             else ColumnBatch.empty(plan.scan.schema)
 
-    # ------------------------------------------------------------- feed (v2)
-
     def _nshards(self) -> int:
         return 1 if self._single else num_shards(self._mesh)
 
-    def _feed_unit(self) -> int:
-        return self._nshards() * self._block_local
-
-    def _pad_rows(self, n: int) -> int:
-        unit = self._feed_unit()
-        blocks = max(1, -(-n // unit))
-        # bucket the block count into a 9/8-geometric grid: every
-        # padded shape is a compile class (pallas grid + XLA scan
-        # length), and live regions change size on every write — exact
-        # padding would recompile the kernels on each data version.
-        # Bucketing bounds the number of compile classes
-        # logarithmically and taxes ONLY the cache key, never the
-        # computed extent: blocks past the live rows skip their MXU /
-        # aggregation work (pl.when dead-block guard in pallas_hash,
-        # lax.cond guard in aggregate.py _scan_program's step), so the
-        # ≤12.5% padding costs DMA + grid steps, not kernel time.
-        if not self._chunk_override and blocks > 8:
-            # one ROW of growth headroom BEFORE bucketing: it only
-            # moves sizes whose live rows exactly fill their last block
-            # (ceil absorbs it everywhere else), so such a feed — e.g.
-            # a power-of-two bulk load — does not change compile class
-            # (XLA recompile + full re-upload) on the very first
-            # appended row.  (Was one BLOCK: where the bucket grid is
-            # one block wide — 9..15 blocks — n and n+1 then still
-            # landed in different buckets; found on a 2x2 v5e mesh,
-            # where 10,485,760 rows fill ten 2^20-row blocks exactly.)
-            blocks = -(-(n + 1) // unit)
-            # round up to a 4-significant-bit block count (k·2^s,
-            # 8 ≤ k ≤ 15): keeps n_pad rich in powers of two so
-            # _pick_chunk's gcd still finds large scan chunks
-            s = blocks.bit_length() - 4
-            k = -(-blocks // (1 << s))
-            if k > 15:
-                s += 1
-                k = -(-blocks // (1 << s))
-            blocks = k << s
-        return blocks * unit
-
-    def _pick_chunk(self, n_pad: int, desired: int) -> int:
-        """Largest scan-block size ≤ desired that divides the padded feed
-        and splits evenly over shards."""
-        unit = self._feed_unit()
-        if self._chunk_override:
-            desired = unit
-        desired = max(unit, (desired // unit) * unit)
-        return math.gcd(n_pad, desired)
-
-    def _build_flat(self, host_cols, n: int) -> dict:
-        """→ {"flat": device arrays, "null_flags": per-col bool, "n_pad"}.
-
-        One flat padded array per column value; a validity array only for
-        columns that actually contain NULLs — all-valid columns reuse the
-        on-device row mask (synthesized from iota < n), saving the HBM
-        footprint and H2D bandwidth of an all-true mask.
-        """
-        from ..utils import tracker
-        n_pad = self._pad_rows(n)
-        flat, flags = [], []
-
-        def put_padded(arr, dtype):
-            if self._single:
-                if n_pad == n:
-                    return jnp.asarray(arr)
-                # pad on the HOST: a device-side concatenate would
-                # compile per exact n (every data version has a new row
-                # count), costing seconds per cache rebuild; a host
-                # memcpy is shape-oblivious
-                p = np.zeros(n_pad, dtype=arr.dtype)
-                p[:n] = arr
-                return jnp.asarray(p)
-            # a sharded cold build, span by span: the host's padded
-            # copy, then handing one slice to each shard (the put is
-            # not waited for: the next plane's pad overlaps it, and the
-            # first launch waits for what is left)
-            with tracker.span("feed_host_pad"):
-                p = np.zeros(n_pad, dtype=dtype)
-                p[:n] = arr
-            with tracker.span("feed_shard_put"):
-                return jax.device_put(p, self._row_sharding)
-
-        from .supervisor import host_plane_digest
-        digests = [] if self.scrub_digests else None
-        for v, ok in host_cols:
-            flat.append(put_padded(v, v.dtype))
-            has_nulls = not bool(ok.all())
-            flags.append(has_nulls)
-            if digests is not None:
-                # recorded from the HOST truth at build time: the scrub
-                # later re-hashes the resident device plane and compares
-                digests.append(host_plane_digest(v, n))
-            if has_nulls:
-                flat.append(put_padded(ok, np.bool_))
-                if digests is not None:
-                    digests.append(host_plane_digest(ok, n))
-        feed = {"flat": tuple(flat), "null_flags": tuple(flags),
-                "n_pad": n_pad}
-        if digests is not None:
-            feed["digests"] = tuple(digests)
-            feed["n_live"] = n
-            # pre-register the digest kernels now (cold path) so the
-            # warm patch path's incremental digest update mints no new
-            # kernel cache entries — compile classes stay churn-stable
-            for a in feed["flat"]:
-                self._range_digest_kernel(a.dtype, a.shape[0])
-        return feed
-
-    @staticmethod
-    def _feed_anchor(storage):
-        """Feed/meta cache key object.  Delta-maintained snapshots carry
-        a ``feed_lineage`` whose identity is stable across patch
-        generations (copr/region_cache.py FeedLineage) — anchoring on it
-        keeps the HBM feed warm across writes; plain snapshots anchor on
-        themselves (invalidation by identity, as before)."""
-        lineage = getattr(storage, "feed_lineage", None)
-        return storage if lineage is None else lineage
-
-    def _get_feed(self, storage, feed_key, host_cols, n: int,
-                  lineage=None, used_infos=None, dtypes=None,
-                  positional: bool = False, req_v=None) -> dict:
-        from ..utils import tracker
-        cache = None
-        anchor = None
-        if storage is not None and feed_key is not None and \
-                hasattr(storage, "scan_columns"):
-            anchor = self._feed_anchor(storage)
-            cache = self._arena.bucket(anchor)
-        feed = cache.get(feed_key) if cache is not None else None
-        if feed is not None:
-            fv = feed.get("lineage_v")
-            if lineage is None or fv == req_v:
-                tracker.label("device_feed", "hit")
-                return feed
-            if fv is not None and fv > req_v:
-                # an older-generation read (history serve): never
-                # downgrade the shared feed — build a private one
-                cache = None
-                feed = None
-            elif positional and self._try_patch_feed(
-                    feed, lineage, used_infos, dtypes, n, req_v):
-                # the snapshot moved forward under the feed: replay only
-                # the journal's dirty row spans into HBM instead of a
-                # cold re-upload — bucketed padding keeps n_pad (the
-                # compile class) stable across small deltas
-                tracker.label("device_feed", "patch")
-                self._register_digests(lineage, feed_key, feed)
-                return feed
-        # device-side region split (supervisor.on_region_split): the
-        # parent feed was sliced by key range INTO this child lineage's
-        # stash — consume it instead of re-uploading from host.  The
-        # stash was digest-verified against the child's host truth at
-        # split time, so serving it is as safe as serving a scrubbed
-        # resident feed.
-        if lineage is not None and positional and cache is not None and \
-                getattr(lineage, "split_stash", None):
-            feed = self._take_split_feed(lineage, feed_key, n)
-            if feed is not None:
-                self._cache_feed(cache, feed_key, feed)
-                self._arena.admit(anchor)
-                if feed.get("lineage_v") == req_v or self._try_patch_feed(
-                        feed, lineage, used_infos, dtypes, n, req_v):
-                    tracker.label("device_feed", "split")
-                    self._register_digests(lineage, feed_key, feed)
-                    return feed
-                # the child moved past the stash and the journal could
-                # not bridge it: fall through to the upload (which
-                # replaces the cache entry)
-                feed = None
-        # cold-path kill (device/mvcc.py): a device build left its
-        # resolve artifacts on the lineage — mint the feed BORN
-        # RESIDENT (H2D of raw version planes — or nothing, if the
-        # streaming ingest pipeline already uploaded them — plus ONE
-        # resolve+gather dispatch) instead of the host pad/astype/upload
-        # pass.  One-shot and version-pinned; any failure falls through
-        # to the plain upload below, which is always correct.
-        if lineage is not None and \
-                getattr(lineage, "cold_bundle", None) is not None:
-            if positional and cache is not None and not any(
-                    _is_date_plane(i, np.dtype(d))
-                    for i, d in zip(used_infos or (), dtypes or ())):
-                # (the resolver gathers packed cores: a date plane is
-                # cut from the host mirror instead)
-                bundle = lineage.take_cold(req_v)
-                if bundle is not None:
-                    feed = bundle.mint(self, used_infos, dtypes, n,
-                                       self._pad_rows(n))
-                    if feed is not None:
-                        tracker.label("device_feed", "device_resolve")
-                        feed["lineage_v"] = req_v
-                        self._mark_splittable(feed, used_infos)
-                        self._cache_feed(cache, feed_key, feed)
-                        self._arena.admit(anchor)
-                        self._register_digests(lineage, feed_key, feed)
-                        return feed
-            else:
-                # first feed build for this line cannot consume the
-                # bundle (desc/index scan): release the raw planes
-                # now rather than pinning ~100 bytes/version on the
-                # lineage until a delta or teardown gets there
-                lineage.drop_cold()
-        tracker.label("device_feed", "upload")
-        _fp_degrade("device::before_feed_upload")
-        with tracker.phase("feed_upload"):
-            feed = self._build_flat(host_cols(), n)
-        if lineage is not None:
-            feed["lineage_v"] = req_v
-        if positional:
-            self._mark_splittable(feed, used_infos)
-        if cache is not None:
-            self._cache_feed(cache, feed_key, feed)
-            # admission runs under the dispatch lock (this call site):
-            # the budget check may evict other, unpinned anchors
-            self._arena.admit(anchor)
-            self._register_digests(lineage, feed_key, feed)
-        return feed
-
-    @staticmethod
-    def _cache_feed(bucket: dict, feed_key, feed: dict) -> None:
-        """``feed`` into its anchor's bucket.  A cached feed says what
-        it is cached under (``key``): a prepared record holds it to
-        that slot of the bucket by identity (``_stage_prepared``)."""
-        feed["key"] = feed_key
-        bucket[feed_key] = feed
-
-    @staticmethod
-    def _register_digests(lineage, feed_key, feed) -> None:
-        """Mirror the feed's per-plane digests into the FeedLineage's
-        host-visible journal — the line-level audit record the
-        supervisor reports (region_cache.py FeedLineage)."""
-        if lineage is not None and feed.get("digests") is not None and \
-                hasattr(lineage, "feed_digests"):
-            lineage.feed_digests[feed_key] = (feed.get("lineage_v"),
-                                              feed["digests"])
-
-    def _try_patch_feed(self, feed, lineage, used_infos, dtypes,
-                        n: int, req_v=None) -> bool:
-        """Apply the lineage's dirty row spans to the device feed in
-        place of a cold upload.  Only sound when the patch journal
-        covers the gap with pure row patches (no repack/compaction/
-        tombstones), positions map 1:1 (full-snapshot ascending feed),
-        the padded shape is unchanged, and every patched value fits the
-        feed's established device dtypes.  Sharded feeds patch too:
-        GSPMD partitions the update and ``_dus`` pins the result back
-        to the row sharding."""
-        if used_infos is None or dtypes is None:
-            return False
-        patches = lineage.since(feed.get("lineage_v", -1), until=req_v)
-        if patches is None or any(p.get("structural") for p in patches):
-            return False
-        if patches and patches[-1]["n"] != n:
-            return False        # ranged feed: positions do not map 1:1
-        if self._pad_rows(max(n, 1)) != feed["n_pad"]:
-            return False        # row count crossed a pad bucket
-        # flat index of each used column's value plane
-        plane = []
-        fi = 0
-        for has_nulls in feed["null_flags"]:
-            plane.append(fi)
-            fi += 2 if has_nulls else 1
-        from ..utils import tracker
-        flat = list(feed["flat"])
-        digests = list(feed["digests"]) \
-            if self.scrub_digests and feed.get("digests") is not None \
-            else None
-        with tracker.phase("feed_patch"):
-            for p in patches:
-                for span in p["spans"]:
-                    lo = span["lo"]
-                    for ci, info in enumerate(used_infos):
-                        dt = np.dtype(dtypes[ci])
-                        if info.is_pk_handle:
-                            vals = span["handles"]
-                            valid = None
-                        else:
-                            vals, valid = span["cols"][info.col_id]
-                        vals = _to_plane(info, vals, dt)
-                        if not _fits_dtype(vals, valid, dt):
-                            return False
-                        if valid is not None and not valid.all() and \
-                                not feed["null_flags"][ci]:
-                            # first NULL in an all-valid column would
-                            # change the compile class: rebuild
-                            return False
-                        fi = plane[ci]
-                        flat[fi] = self._patch_plane(
-                            feed, digests, flat, fi,
-                            np.ascontiguousarray(
-                                vals.astype(dt, copy=False)), lo)
-                        if feed["null_flags"][ci]:
-                            mask = valid if valid is not None else \
-                                np.ones(len(vals), np.bool_)
-                            flat[fi + 1] = self._patch_plane(
-                                feed, digests, flat, fi + 1,
-                                np.ascontiguousarray(mask), lo)
-        feed["flat"] = tuple(flat)
-        feed["lineage_v"] = req_v
-        if digests is not None:
-            feed["digests"] = tuple(digests)
-            feed["n_live"] = n
-        return True
-
-    def _patch_plane(self, feed, digests, flat, fi: int,
-                     update: np.ndarray, lo: int):
-        """One plane's span patch + INCREMENTAL digest maintenance:
-        ``R' = R - H_span(old device plane) + H_span(new host data)``.
-        Never re-hashes the whole plane from device state — doing so
-        would launder any HBM corruption that landed since the last
-        scrub into the recorded digest (the recorded value must stay
-        anchored to the host-truth chain, so a pre-existing corruption
-        delta survives arithmetically and the next scrub still catches
-        it, wherever it sits relative to the patched span).  All device
-        scalars — nothing blocks under the dispatch lock."""
-        old = flat[fi]
-        new = self._dus(old, update, lo)
-        if digests is not None:
-            hi = lo + len(update)
-            rng = self._range_digest_kernel(old.dtype, old.shape[0])
-            lo_arr = jnp.asarray(lo, jnp.int64)
-            hi_arr = jnp.asarray(hi, jnp.int64)
-            d_old = rng(old, lo_arr, hi_arr)
-            d_new = rng(new, lo_arr, hi_arr)
-            digests[fi] = jnp.uint64(digests[fi]) - d_old + d_new
-        return new
-
-    def _dus(self, arr, update, lo: int):
-        """Jitted in-place-style slice update (dynamic_update_slice);
-        the start index is traced, so repeated single-row patches at
-        different positions share one compile class per update length.
-        On a sharded feed GSPMD partitions the update and the jit's
-        ``out_shardings`` pins the result to the row sharding in the
-        SAME dispatch — no post-hoc device_put re-lay, so delta churn
-        on a sharded feed costs one small collective-free launch per
-        span, exactly like the single-device path."""
-        fn = self._kernel_cache.get("feed_patch_fn")
-        if fn is None:
-            def feed_patch(a, u, i):
-                return lax.dynamic_update_slice(a, u, (i,))
-            fn = self._kernel_cache["feed_patch_fn"] = \
-                jax.jit(feed_patch) if self._single else \
-                jax.jit(feed_patch, out_shardings=self._row_sharding)
-        return fn(arr, update, jnp.asarray(lo, jnp.int32))
-
     # ------------------------------------- device-state supervision
     #
-    # The runner side of device/supervisor.py: explicit feed teardown
-    # (drop_feed replaces GC-timed reclamation), HBM accounting, the
-    # on-device digest leaf the scrubber re-hashes resident planes
-    # with, and the quarantine gate a scrub divergence arms.
+    # The runner side of device/supervisor.py, fanned out over the
+    # placer's slices, a degraded submesh and the joiner: explicit feed
+    # teardown (drop_feed replaces GC-timed reclamation), HBM accounting
+    # and the quarantine gate a scrub divergence arms.  (The feeds'
+    # own digests, move and split: device/feed.py.)
 
     def set_hbm_budget(self, nbytes: int) -> None:
         """Set (or clear, 0) the HBM budget and enforce it NOW — an
@@ -2064,15 +1678,10 @@ class DeviceRunner:
         # feed must show every mesh device, not device 0 alone)
         out["resident_bytes_by_device"] = \
             self._arena.resident_bytes_by_device()
-        subs = [r for r in self._placer.slices] \
-            if self._placer is not None else []
-        degraded = self._degraded_sub()
-        if degraded is not None:
-            subs.append(degraded)
         # node-level rollup: the budget invariant is judged against
         # ALL device-resident bytes, wherever the anchor is pinned —
         # placement slices and any degraded submesh runner included
-        for r in subs:
+        for r in self._sub_runners():
             sub = r.hbm_stats()
             for k in ("resident_bytes", "resident_lines",
                       "pinned_lines", "pinned_bytes", "evictions",
@@ -2089,12 +1698,8 @@ class DeviceRunner:
         slices and any degraded submesh runner included, so one scrub
         pass audits every resident plane on the node."""
         items = self._arena.items()
-        if self._placer is not None:
-            for r in self._placer.slices:
-                items.extend(r.arena_items())
-        degraded = self._degraded_sub()
-        if degraded is not None:
-            items.extend(degraded.arena_items())
+        for r in self._sub_runners():
+            items.extend(r.arena_items())
         return items
 
     def drop_feed(self, anchor, reason: str = "drop") -> int:
@@ -2120,11 +1725,10 @@ class DeviceRunner:
             # join build/probe planes anchored on the same lineage die
             # with the feed — stale-epoch join state must not survive
             freed += self._joiner.drop_anchor(anchor)
+        for r in self._sub_runners():
+            freed += r.drop_feed(anchor, reason=reason)
         if self._placer is not None:
-            freed += self._placer.drop_feed_all(anchor, reason)
-        degraded = self._degraded_sub()
-        if degraded is not None:
-            freed += degraded.drop_feed(anchor, reason=reason)
+            self._placer.forget(anchor)
         return freed
 
     def quarantine(self, anchor, reason: str = "") -> None:
@@ -2166,351 +1770,6 @@ class DeviceRunner:
     def _consume_quarantine(self, anchor) -> bool:
         with self._quar_mu:
             return self._quarantined.pop(id(anchor), None) is not None
-
-    def _range_digest_kernel(self, dtype, n_pad: int):
-        """Jitted plane digest over rows [lo, hi) with GLOBAL position
-        weights: sum bits(x[i]) * (2i+1) mod 2^64 — the device half of
-        the scrub formula (host half: supervisor.host_plane_digest;
-        the full-prefix digest is just lo=0).  Cached per (dtype,
-        n_pad) like every other kernel; on a sharded feed GSPMD
-        partitions the reduction."""
-        dt = np.dtype(dtype)
-        key = ("scrubr", str(dt), n_pad)
-        fn = self._kernel_cache.get(key)
-        if fn is None:
-            if dt == np.bool_ or (dt.kind in "iu" and dt.itemsize == 8):
-                # 64-bit ints convert, not bitcast: the wrap mod 2^64
-                # IS the bit pattern, and the TPU compiler has no
-                # 64-bit bitcast-convert (its X64 rewrite rejects it —
-                # found on v5e, libtpu 0.0.34)
-                to_bits = lambda x: x.astype(jnp.uint64)    # noqa: E731
-            else:
-                # narrower ints and float32: bitcast to the same-width
-                # unsigned view, then widen.  (float64 takes this
-                # branch too and lowers on CPU only; feed planes are
-                # never float64 — datatype/tile.py _device_dtype.)
-                udt = _UINT_BY_ITEMSIZE[dt.itemsize]
-
-                def to_bits(x, _udt=udt):
-                    return lax.bitcast_convert_type(x, _udt) \
-                        .astype(jnp.uint64)
-
-            def feed_digest(x, lo_arr, hi_arr):
-                iota = jnp.arange(n_pad, dtype=jnp.uint64)
-                w = 2 * iota + 1
-                sel = (iota >= lo_arr.astype(jnp.uint64)) & \
-                    (iota < hi_arr.astype(jnp.uint64))
-                return jnp.sum(jnp.where(sel, to_bits(x) * w,
-                                         jnp.uint64(0)))
-
-            fn = self._kernel_cache[key] = jax.jit(feed_digest)
-        return fn
-
-    def device_digest(self, arr, n: int):
-        """Digest of one resident plane's live prefix (device scalar —
-        the caller decides when to sync).  Deliberately avoids the
-        LRU scalar cache: the background scrubber calls this OUTSIDE
-        the dispatch lock, and the OrderedDict's move_to_end/popitem
-        is not safe against concurrent request threads."""
-        return self._range_digest_kernel(arr.dtype, arr.shape[0])(
-            arr, jnp.asarray(0, jnp.int64), jnp.asarray(n, jnp.int64))
-
-    def corrupt_resident_plane(self, feed: dict) -> None:
-        """Fault injection (device::feed_corrupt): flip one element of
-        the first resident plane in place of the HBM bit-flip a real
-        device fault would cause.  Test/chaos surface only."""
-        arr = feed["flat"][0]
-        dt = np.dtype(arr.dtype)
-        if dt == np.bool_:
-            bad = arr.at[0].set(~arr[0])
-        elif dt.kind in "iu":
-            bad = arr.at[0].set(arr[0] ^ 1)     # single-bit flip
-        else:
-            # floats: a true single-BIT flip via bitcast → xor 1
-            u = lax.bitcast_convert_type(
-                arr, _UINT_BY_ITEMSIZE[dt.itemsize])
-            bad = lax.bitcast_convert_type(u.at[0].set(u[0] ^ 1),
-                                           arr.dtype)
-        feed["flat"] = (bad,) + feed["flat"][1:]
-
-    # ------------------------------------- ICI feed migration + split
-    #
-    # Elastic stress without the host link: a placement move, a
-    # quarantine drain, or a co-location pull copies the resident
-    # feed between slices over the device interconnect (device_put
-    # across the mesh) instead of dropping it and re-minting from
-    # host truth; a region split slices the parent feed by key range
-    # on device into two child feeds.  Both re-verify against the
-    # scrub-digest chain before anything serves.
-
-    @staticmethod
-    def _mark_splittable(feed: dict, used_infos) -> None:
-        """Positional full-snapshot feeds record which planes carry
-        the pk-handle column (sourced from state.handles, not
-        state.cols) — the metadata a device-side region split needs
-        to re-anchor child digests to host truth."""
-        if used_infos is not None:
-            feed["positional"] = True
-            feed["pk_flags"] = tuple(bool(i.is_pk_handle)
-                                     for i in used_infos)
-
-    def _take_split_feed(self, lineage, feed_key, n: int):
-        """Pop the stashed split-child feed matching this request's
-        shape (one-shot, like ``take_cold``): same columns and device
-        dtypes, same live row count, and the pad bucket THIS runner
-        would mint — a candidate sliced under a different feed unit
-        must not serve here.  Mutation races are benign: production
-        and consumption both run under the owning slice's dispatch
-        lock (children adopt the parent's slice)."""
-        stash = getattr(lineage, "split_stash", None)
-        if not stash:
-            return None
-        col_ids, dtypes, _ranges = feed_key
-        want_pad = self._pad_rows(max(n, 1))
-        for i, cand in enumerate(stash):
-            f = cand["feed"]
-            if cand["col_ids"] == col_ids and \
-                    cand["dtypes"] == tuple(dtypes) and \
-                    f.get("n_live") == n and f.get("n_pad") == want_pad:
-                del stash[i]
-                return dict(f)
-        return None
-
-    def extract_feeds(self, anchor):
-        """→ (migratable feeds by key, skipped count) for an ICI move
-        of ``anchor`` off this slice, or (None, 0) when nothing can
-        travel.  Only feeds carrying scrub digests are migratable —
-        the destination re-verifies on arrival, and a feed that
-        cannot be verified must re-mint from host truth instead of
-        serving unaudited (skipped counts those).  Snapshot under the
-        dispatch lock: (flat, digests) pairs update non-atomically on
-        the patch path."""
-        if not self._single:
-            return None, 0
-        bucket = self._arena.bucket(anchor, create=False)
-        if not bucket:
-            return None, 0
-        out = {}
-        skipped = 0
-        with self._dispatch_mu:
-            for k, v in bucket.items():
-                if not (isinstance(v, dict) and "flat" in v):
-                    continue
-                if v.get("digests") is None:
-                    skipped += 1
-                    continue
-                out[k] = dict(v)
-        return (out or None), skipped
-
-    def install_feeds(self, anchor, feeds: dict) -> str:
-        """Arrival side of an ICI feed migration → ``"moved"`` or
-        ``"corrupt"``.  Each plane is device_put onto this slice and
-        re-hashed against the digests that traveled with it BEFORE
-        anything installs — a plane diverging mid-flight (ICI fault,
-        HBM corruption on either end; chaos arms
-        ``device::feed_migrate``) quarantines-and-rebuilds, never
-        serves silently corrupt.  A feed the destination already
-        holds at the same or newer lineage generation is never
-        clobbered (a request raced the move and re-minted)."""
-        from ..utils.failpoint import fail_point
-        dev = self._mesh.devices.flat[0]
-        installed = {}
-        for fkey, feed in feeds.items():
-            flat = [jax.device_put(a, dev) for a in feed["flat"]]
-            if fail_point("device::feed_migrate") is not None:
-                # the injected mid-transfer fault: one bit flips on a
-                # transferred plane; the verify below must catch it
-                tmp = dict(feed)
-                tmp["flat"] = tuple(flat)
-                self.corrupt_resident_plane(tmp)
-                flat = list(tmp["flat"])
-            n = feed.get("n_live", 0)
-            arrived = []
-            for arr, want in zip(flat, feed["digests"]):
-                got = int(np.asarray(self.device_digest(arr, n)))
-                if got != int(np.asarray(want)):
-                    return "corrupt"
-                arrived.append(got)
-            nf = dict(feed)
-            nf["flat"] = tuple(flat)
-            # the digest chain must live where its planes live: a
-            # scalar still committed to the SOURCE slice would turn
-            # the next incremental patch into a cross-device subtract
-            nf["digests"] = tuple(
-                jax.device_put(jnp.asarray(w, jnp.uint64), dev)
-                for w in arrived)
-            installed[fkey] = nf
-            # pre-register the digest kernels so the first patch on
-            # the new slice mints no new compile class mid-churn
-            for a in nf["flat"]:
-                self._range_digest_kernel(a.dtype, a.shape[0])
-        with self._dispatch_mu:
-            bucket = self._arena.bucket(anchor)
-            if bucket is None:
-                return "corrupt"    # untrackable anchor: caller re-mints
-            for fkey, nf in installed.items():
-                cur = bucket.get(fkey)
-                if isinstance(cur, dict) and \
-                        cur.get("lineage_v") is not None and \
-                        nf.get("lineage_v") is not None and \
-                        cur["lineage_v"] >= nf["lineage_v"]:
-                    continue
-                self._cache_feed(bucket, fkey, nf)
-                self._register_digests(
-                    anchor if hasattr(anchor, "feed_digests") else None,
-                    fkey, nf)
-            self._arena.admit(anchor)
-        return "moved"
-
-    def _split_plane_kernel(self, dtype, n_pad_parent: int,
-                            n_pad_child: int, right: bool):
-        """Jitted key-range slice of one resident plane into a split
-        child: left takes rows [0, pos), right takes [pos, pos+n) via
-        a roll — the split position is traced, so every split of the
-        same (side, dtype, pad buckets) shares one compile class.
-        Rows past the child's live count zero out (padding invariant,
-        matching _build_flat's host zeros)."""
-        dt = np.dtype(dtype)
-        key = ("splitp", bool(right), str(dt), n_pad_parent, n_pad_child)
-        fn = self._kernel_cache.get(key)
-        if fn is None:
-            if right:
-                def kern(x, pos, n_child):
-                    y = jnp.roll(x, -pos)[:n_pad_child]
-                    iota = jnp.arange(n_pad_child)
-                    return jnp.where(iota < n_child, y,
-                                     jnp.zeros((), y.dtype))
-            else:
-                def kern(x, pos, n_child):
-                    y = x[:n_pad_child]
-                    iota = jnp.arange(n_pad_child)
-                    return jnp.where(iota < n_child, y,
-                                     jnp.zeros((), y.dtype))
-            fn = self._kernel_cache[key] = jax.jit(
-                named_program(kern, "device_split"))
-        return fn
-
-    def split_resident_feeds(self, spec) -> str:
-        """Device-side region split of every resident feed anchored on
-        the parent lineage (``spec`` from RegionColumnarCache
-        .split_lines) → ``"split"`` when at least one child feed was
-        minted on device, else ``"none"``.  Fans out to whichever
-        runner holds the parent's bucket (placement slice, degraded
-        submesh, or this runner)."""
-        anchor = spec["parent_lineage"]
-        runners = [self]
-        if self._placer is not None:
-            runners.extend(self._placer.slices)
-        degraded = self._degraded_sub()
-        if degraded is not None:
-            runners.append(degraded)
-        for r in runners:
-            bucket = r._arena.bucket(anchor, create=False)
-            if bucket:
-                return r._split_local_feeds(bucket, spec)
-        return "none"
-
-    def _split_local_feeds(self, bucket, spec) -> str:
-        """Slice this runner's resident parent feeds into split-child
-        candidates, stashed on the child lineages for their first
-        request to consume (``_take_split_feed``).  Child digests are
-        recomputed from the children's HOST state — never derived
-        from device planes, so a corruption that landed on the parent
-        since its last scrub fails the verify here instead of
-        laundering into the child's recorded chain."""
-        if not self._single:
-            return "none"       # sharded whole-mesh feeds re-mint
-        out = "none"
-        with self._dispatch_mu:
-            for fkey, feed in list(bucket.items()):
-                if not (isinstance(feed, dict) and "flat" in feed):
-                    continue
-                if not feed.get("positional") or \
-                        feed.get("pk_flags") is None or \
-                        feed.get("digests") is None:
-                    continue
-                if feed.get("lineage_v") != spec["parent_version"] or \
-                        feed.get("n_live") != spec["n_parent"]:
-                    continue    # stale generation: positions lie
-                for side in ("left", "right"):
-                    child = spec.get(side)
-                    if child is None or child["n"] <= 0:
-                        continue
-                    cf = self._mint_split_child(feed, fkey, spec, child,
-                                                right=(side == "right"))
-                    if cf is not None:
-                        stash = getattr(child["lineage"], "split_stash",
-                                        None)
-                        if stash is None:
-                            stash = child["lineage"].split_stash = []
-                        stash.append({"col_ids": fkey[0],
-                                      "dtypes": tuple(fkey[1]),
-                                      "feed": cf})
-                        out = "split"
-        return out
-
-    def _mint_split_child(self, feed, fkey, spec, child, right: bool):
-        """One child feed: slice every parent plane on device, anchor
-        the child's digest chain to its host truth, and verify the
-        sliced planes against it (the split's arrival verify) — or
-        None when anything diverges (that child re-uploads)."""
-        from .supervisor import host_plane_digest
-        pos = spec["pos"]
-        n_child = child["n"]
-        n_pad_child = self._pad_rows(max(n_child, 1))
-        parent_pad = feed["n_pad"]
-        if n_pad_child > parent_pad:
-            return None
-        state = child["state"]
-        pos_arr = jnp.asarray(pos, jnp.int32)
-        n_arr = jnp.asarray(n_child, jnp.int32)
-        flat, digests = [], []
-        fi = 0
-        for ci, has_nulls in enumerate(feed["null_flags"]):
-            pk = feed["pk_flags"][ci]
-            dt = np.dtype(fkey[1][ci])
-            if pk:
-                vals = state.handles[:n_child]
-                valid = None
-            else:
-                bufs = state.cols.get(fkey[0][ci])
-                if bufs is None:
-                    return None
-                vals = bufs[0][:n_child]
-                valid = bufs[1][:n_child]
-            host_v = np.ascontiguousarray(vals.astype(dt, copy=False))
-            kern = self._split_plane_kernel(feed["flat"][fi].dtype,
-                                            parent_pad, n_pad_child,
-                                            right)
-            arr = kern(feed["flat"][fi], pos_arr, n_arr)
-            want = host_plane_digest(host_v, n_child)
-            if int(np.asarray(self.device_digest(arr, n_child))) != \
-                    int(want):
-                return None
-            flat.append(arr)
-            digests.append(want)
-            fi += 1
-            if has_nulls:
-                mask = np.ascontiguousarray(
-                    valid if valid is not None
-                    else np.ones(n_child, np.bool_))
-                kern = self._split_plane_kernel(np.bool_, parent_pad,
-                                                n_pad_child, right)
-                marr = kern(feed["flat"][fi], pos_arr, n_arr)
-                mwant = host_plane_digest(mask, n_child)
-                if int(np.asarray(self.device_digest(
-                        marr, n_child))) != int(mwant):
-                    return None
-                flat.append(marr)
-                digests.append(mwant)
-                fi += 1
-        cf = {"flat": tuple(flat), "null_flags": feed["null_flags"],
-              "n_pad": n_pad_child, "digests": tuple(digests),
-              "n_live": n_child, "lineage_v": child["lineage"].version,
-              "positional": True, "pk_flags": feed["pk_flags"]}
-        for a in cf["flat"]:
-            self._range_digest_kernel(a.dtype, a.shape[0])
-        return cf
 
     # --------------------------------------------------------------- kernels
 
@@ -2635,8 +1894,8 @@ class DeviceRunner:
 
         ``n_used`` (single-device): the live seglen-rounded row prefix —
         the kernel slices the feed to it so the bucketed padding
-        (_pad_rows) taxes only the cache key, never the top_k extent
-        (an XLA prefix slice streams at HBM speed; top_k over the same
+        (feed.py ``pad_rows``) taxes only the cache key, never the top_k
+        extent (an XLA prefix slice streams at HBM speed; top_k over the same
         rows costs an order of magnitude more).
         """
         S = self._nshards()
@@ -2957,7 +2216,7 @@ class DeviceRunner:
             return self._serve_on_host(dag, storage, "slice_quarantined")
 
         if self._quarantined and hasattr(storage, "scan_columns") and \
-                self._consume_quarantine(self._feed_anchor(storage)):
+                self._consume_quarantine(feed_anchor(storage)):
             # scrub divergence on this line: its feeds were dropped at
             # quarantine time; serve THIS request from the host
             # pipeline, then let the next one rebuild from host truth
@@ -2987,13 +2246,8 @@ class DeviceRunner:
                 dag = dag.over_ranges(())
 
         meta = self._request_meta(storage, self._meta_key(dag, plan))
-        lineage = getattr(storage, "feed_lineage", None)
-        # the generation THIS snapshot reflects — the line may already
-        # be further ahead (or this may be a history-served older
-        # generation); every shared-memo interaction pins to it
-        req_v = getattr(storage, "feed_version", None)
-        if lineage is not None and req_v is None:
-            req_v = lineage.version
+        # (every shared-memo interaction pins to the request's generation)
+        lineage, req_v = generation(storage)
         if lineage is not None:
             mv = meta.get("lineage_v", req_v)
             if mv < req_v:
@@ -3044,142 +2298,6 @@ class DeviceRunner:
             from ..executors.runner import BatchExecutorsRunner
             return BatchExecutorsRunner(orig_dag, storage).handle_request()
 
-        def get_dtypes() -> tuple:
-            if "dtypes" in memo:
-                return memo["dtypes"]
-            if "dtypes" in meta and memo_fresh():
-                memo["limbs"] = meta.get("limbs", ())
-                return meta["dtypes"]
-            batch = get_batch()
-            dts = []
-            bounds = []
-            for pos, ci in enumerate(plan.used_cols):
-                col = batch.columns[ci]
-                if col.eval_type is EvalType.DECIMAL and col.frac is None:
-                    # the build kept this DECIMAL column as objects (a
-                    # value beyond its declared scale, a store without
-                    # the native build): host, as before the lowering
-                    meta["force_host"] = True
-                    raise _FallbackToHost("unscaled DECIMAL column")
-                vals = col.values
-                if plan.date_planes and plan.date_planes[pos]:
-                    vals = date_plane(vals)
-                    dt = np.dtype(np.int32)
-                    self.flight_recorder.note_plane("date")
-                elif plan.code_planes and plan.code_planes[pos]:
-                    vals = code_plane(vals, plan.code_planes[pos])
-                    if vals is None:
-                        # a value wider than the column's declared
-                        # bytes, or holding the pad byte: no code gives
-                        # it back, so the strings stay with the host
-                        meta["force_host"] = True
-                        raise _FallbackToHost("CHAR value without a code")
-                    dt = _device_dtype(EvalType.INT, vals)
-                    memo.setdefault("code_vals", {})[pos] = vals
-                    self.flight_recorder.note_plane("code")
-                else:
-                    dt = _device_dtype(col.eval_type, vals)
-                    if col.frac is not None:
-                        self.flight_recorder.note_plane("decimal")
-                if dt == np.dtype(np.uint64) and col.values.size \
-                        and int(col.values.max()) >= (1 << 63):
-                    # packed cores above 2^63 (year >= 8192) would
-                    # wrap in the int64 state carries.  Remember the
-                    # verdict: repeat requests must not rebuild the
-                    # preceding columns just to re-discover it.
-                    # (Conservative-sticky: safe to set cross-version.)
-                    meta["force_host"] = True
-                    raise _FallbackToHost("u64 column beyond int64")
-                dts.append(str(dt))
-                if plan.lowered:
-                    bounds.append((int(vals.min()), int(vals.max()))
-                                  if vals.size else (0, 0))
-            limbs = ()
-            if plan.lowered:
-                limbs = lowering.fit(plan, bounds, dts, n)
-                if limbs is None:
-                    # the integer form may wrap at the planes' natural
-                    # width even with its products summed as limbs: try
-                    # every plane at int64 (the XLA bodies serve it),
-                    # else the host pipeline, whose Decimals are exact
-                    wide = ["int64" if np.dtype(d).kind == "i" else d
-                            for d in dts]
-                    if not lowering.fits(plan, bounds, wide, n):
-                        meta["force_host"] = True
-                        raise _FallbackToHost(
-                            "lowered DECIMAL arithmetic not provably "
-                            "inside int64")
-                    dts, limbs = wide, ()
-            memo["dtypes"] = tuple(dts)
-            memo["limbs"] = limbs
-            if memo_fresh():
-                meta["dtypes"] = memo["dtypes"]
-                meta["limbs"] = limbs
-            return memo["dtypes"]
-
-        def plane_pair(pos: int, col, ds: str) -> tuple:
-            """Used column ``pos`` as its device plane's numpy pair."""
-            vals = col.values
-            if plan.date_planes and plan.date_planes[pos]:
-                vals = date_plane(vals)
-            elif plan.code_planes and plan.code_planes[pos]:
-                # (``get_dtypes`` made them for this batch, where it ran)
-                vals = memo.get("code_vals", {}).get(pos)
-                if vals is None:
-                    vals = code_plane(col.values, plan.code_planes[pos])
-                if vals is None:
-                    raise _FallbackToHost("CHAR value without a code")
-            return (np.ascontiguousarray(
-                vals.astype(np.dtype(ds), copy=False)),
-                np.ascontiguousarray(col.validity))
-
-        def host_cols():
-            """Device-dtype numpy column pairs.
-
-            Cached for the snapshot's lifetime (in ``meta``, same policy
-            as the device feed): the astype alone costs ~2s per 100M-row
-            REAL column, and the TopN candidate refine reads these on
-            every request.  Version-guarded: if the line moved on, the
-            rebuild stays request-local (``memo``)."""
-            if "host_cols" in memo:
-                return memo["host_cols"]
-            if "host_cols" in meta and memo_fresh():
-                return meta["host_cols"]
-            dts = get_dtypes()
-            batch = get_batch()
-            cols = []
-            for pos, (ci, ds) in enumerate(zip(plan.used_cols, dts)):
-                cols.append(plane_pair(pos, batch.columns[ci], ds))
-            memo["host_cols"] = cols
-            if memo_fresh():
-                meta["host_cols"] = cols
-            return cols
-
-        def host_cols_stream():
-            """Yield device-dtype pairs one column at a time, building
-            the host_cols memo incrementally: the cold feed upload
-            issues each column's (async) device_put as soon as that
-            column is converted, so the H2D transfer of column i
-            overlaps the astype of column i+1 — double-buffering the
-            tail of a columnar build instead of serializing convert-all
-            then upload-all."""
-            if "host_cols" in memo:
-                yield from memo["host_cols"]
-                return
-            if "host_cols" in meta and memo_fresh():
-                yield from meta["host_cols"]
-                return
-            dts = get_dtypes()
-            batch = get_batch()
-            built = []
-            for pos, (ci, ds) in enumerate(zip(plan.used_cols, dts)):
-                pair = plane_pair(pos, batch.columns[ci], ds)
-                built.append(pair)
-                yield pair
-            memo["host_cols"] = built
-            if memo_fresh():
-                meta["host_cols"] = built
-
         # what a warm whole-feed Pallas launch of this class needs was
         # left in the memo by the last one (``_Prepared``): a request
         # of the class stages from it (its guards, its own operands,
@@ -3208,21 +2326,16 @@ class DeviceRunner:
                     finally:
                         self._arena.unpin(pin_anchor)
                         pin_anchor = None
-            dtypes = get_dtypes()
-            if memo["limbs"]:
+            # the full staging: the used columns' host halves, each
+            # derived at most once
+            planes = HostPlanes(plan, meta, memo, memo_fresh, get_batch, n,
+                                self.flight_recorder)
+            dtypes = planes.dtypes()
+            if planes.limbs:
                 # this feed's bounds ask for products summed as 16-bit
                 # limbs (lowering.fit): the plan's variant that does
-                plan = self._limb_variant(plan, memo["limbs"])
+                plan = self._limb_variant(plan, planes.limbs)
 
-            feed_key = (tuple(plan.scan.columns[ci].col_id
-                              for ci in plan.used_cols),
-                        tuple(dtypes), dag.ranges)
-            used_infos = [plan.scan.columns[ci] for ci in plan.used_cols]
-            # patching maps journal row positions straight onto feed
-            # rows — only sound for an ascending table scan (index
-            # scans re-sort, desc scans reverse)
-            positional = isinstance(plan.scan, TableScanDesc) and \
-                not getattr(plan.scan, "desc", False)
             with nullcontext() if _lanes else self._dispatch_locked():
                 if not self._single:
                     # one shard's enqueue failing (device loss, ICI
@@ -3235,28 +2348,23 @@ class DeviceRunner:
                     # (the launch-order-inversion hazard the lock
                     # exists for — see its comment at the definition)
                     _fp_degrade("device::shard_launch")
-                feed = self._get_feed(storage, feed_key,
-                                      host_cols_stream, n,
-                                      lineage=lineage,
-                                      used_infos=used_infos,
-                                      dtypes=dtypes,
-                                      positional=positional,
-                                      req_v=req_v)
+                feed = self._feeds.get(storage, planes, dag.ranges, n,
+                                       lineage, req_v)
                 # derived kernel constants written inside the run
                 # bodies ride the guarded view: a stale-generation
                 # request keeps them request-local
                 gmeta = _GuardedMeta(meta, memo_fresh)
                 if plan.kind == "simple_agg":
                     result = self._aggregator.run_simple(
-                        dag, plan, host_cols, dtypes, n, feed, gmeta,
+                        dag, plan, planes.cols, dtypes, n, feed, gmeta,
                         lanes=_lanes)
                 elif plan.kind == "hash_agg":
                     result = self._aggregator.run_hash(
-                        dag, plan, host_cols, dtypes, n, feed, gmeta,
+                        dag, plan, planes.cols, dtypes, n, feed, gmeta,
                         tile_spans=tile_spans, lanes=_lanes)
                 elif plan.kind == "topn":
-                    result = self._run_topn(dag, plan, host_cols, dtypes,
-                                            n, get_batch, feed)
+                    result = self._run_topn(dag, plan, planes.cols,
+                                            dtypes, n, get_batch, feed)
                 else:   # scan_sel
                     result = self._run_scan_sel(dag, plan, dtypes, n,
                                                 get_batch, feed, storage,
@@ -3269,7 +2377,7 @@ class DeviceRunner:
                     # means a kernel ran on a condemned chip
                     self._health.launched_quarantined += 1
                 if hasattr(storage, "scan_columns"):
-                    anc = self._feed_anchor(storage)
+                    anc = feed_anchor(storage)
                     if isinstance(result, _Pending):
                         # pin the line for the in-flight dispatch:
                         # budget eviction (arena.admit, also under this
@@ -3339,7 +2447,7 @@ class DeviceRunner:
         device state (it stays on every miss, where a feed or a slot
         column may have been added)."""
         from ..utils import tracker
-        anchor = self._feed_anchor(storage)
+        anchor = feed_anchor(storage)
         bucket = self._arena.bucket(anchor, create=False)
         feed = rec.feed
         if rec.limbs:
@@ -3426,7 +2534,7 @@ class DeviceRunner:
         ``_refresh_meta``)."""
         if not hasattr(storage, "scan_columns"):
             return {}
-        per_storage = self._arena.bucket(self._feed_anchor(storage))
+        per_storage = self._arena.bucket(feed_anchor(storage))
         if per_storage is None:         # anchor not trackable
             return {}
         return per_storage.setdefault(("meta", meta_key), {})
@@ -3474,33 +2582,16 @@ class DeviceRunner:
 
     def _verify_meta_consts(self, meta, plan, used_infos, spans) -> bool:
         from .kernels import int_planes_needed
-        dtypes = meta.get("dtypes")
-        if dtypes is not None:
-            for ci, info in enumerate(used_infos):
-                dt = np.dtype(dtypes[ci])
-                for span in spans:
-                    vals, valid = (span["handles"], None) \
-                        if info.is_pk_handle \
-                        else span["cols"][info.col_id]
-                    if not _fits_dtype(_to_plane(info, vals, dt), valid,
-                                       dt):
-                        return False
-
-        def span_pairs(span):
-            """The span's rows as the plan's rpns see them: plane
-            values (a DATE column on the date plane shifted)."""
-            pairs = []
-            for ci, info in enumerate(used_infos):
-                if info.is_pk_handle:
-                    h = span["handles"]
-                    pairs.append((h, np.ones(len(h), np.bool_)))
-                elif dtypes is not None:
-                    v, ok = span["cols"][info.col_id]
-                    pairs.append((_to_plane(info, v,
-                                            np.dtype(dtypes[ci])), ok))
-                else:
-                    pairs.append(span["cols"][info.col_id])
-            return pairs
+        # the spans' rows as the plan's rpns see them: plane values (a
+        # DATE column on the date plane shifted), of the kinds a feed
+        # built for this memo's plan carries (device/feed.py)
+        rows = [list(span_planes(span, used_infos, plane_kinds(plan)))
+                for span in spans]
+        dtypes = meta.get("dtypes") or (None,) * len(used_infos)
+        if not all(vals is not None and (
+                ds is None or fits_dtype(vals, valid, np.dtype(ds)))
+                for row in rows for (vals, valid), ds in zip(row, dtypes)):
+            return False
 
         def arg_planes_ok(arg_nbytes) -> bool:
             for r, planes in zip(plan.agg_rpns, arg_nbytes):
@@ -3509,8 +2600,8 @@ class DeviceRunner:
                         not isinstance(r.nodes[0], RpnColumnRef):
                     continue    # computed exprs use dtype widths: stable
                 ci = r.nodes[0].col_idx
-                for span in spans:
-                    vals, valid = span_pairs(span)[ci]
+                for row in rows:
+                    vals, valid = row[ci]
                     live = vals if valid is None or valid.all() \
                         else vals[valid]
                     if live.size and int_planes_needed(
@@ -3526,9 +2617,10 @@ class DeviceRunner:
                 if len(plan.key_rpns) > 1 else ((base, width),)
             if key_bounds is None:
                 return False
-            for span in spans:
-                pairs = span_pairs(span)
+            for span, row in zip(spans, rows):
                 m = len(span["handles"])
+                pairs = [(v, np.ones(m, np.bool_) if ok is None else ok)
+                         for v, ok in row]
                 for rpn, (lo, wid) in zip(plan.key_rpns, key_bounds):
                     kv, km = eval_rpn(rpn, pairs, m, np)
                     kv = np.broadcast_to(kv, (m,))
@@ -3960,7 +3052,7 @@ def _analyze_on_device_impl(runner, dag, storage, n_buckets: int):
         # distinct counts and bucket bounds)
         dt = np.dtype(np.float64) if et is EvalType.REAL \
             else _device_dtype(et, col.values)
-        n_pad = runner._pad_rows(n)
+        n_pad = runner._feeds.pad_rows(n)
         vals = np.zeros(n_pad, dtype=dt)
         vals[:n] = col.values.astype(dt, copy=False)
         valid = np.zeros(n_pad, dtype=np.bool_)

@@ -1321,10 +1321,10 @@ class DeviceStateSupervisor(Observer):
             for spec in specs:
                 parent = spec["parent_lineage"]
                 ok = False
-                if runner is not None and \
-                        hasattr(runner, "split_resident_feeds"):
+                if runner is not None and hasattr(runner, "_feeds"):
                     try:
-                        ok = runner.split_resident_feeds(spec) == "split"
+                        ok = runner._feeds.split_resident_feeds(spec) \
+                            == "split"
                     except Exception:   # noqa: BLE001 — same contract
                         ok = False
                 DEVICE_FEED_MIGRATION_COUNTER.labels(
@@ -1459,7 +1459,8 @@ class DeviceStateSupervisor(Observer):
             diverged = False
             for flat, digests, n in feeds:
                 for arr, want in zip(flat, digests):
-                    got = int(np.asarray(runner.device_digest(arr, n)))
+                    got = int(np.asarray(
+                        runner._feeds.device_digest(arr, n)))
                     out["planes"] += 1
                     if got != int(np.asarray(want)):
                         diverged = True
@@ -1536,7 +1537,8 @@ class DeviceStateSupervisor(Observer):
             diverged = False
             for flat, digests, n in feeds:
                 for arr, want in zip(flat, digests):
-                    got = int(np.asarray(runner.device_digest(arr, n)))
+                    got = int(np.asarray(
+                        runner._feeds.device_digest(arr, n)))
                     out["planes"] += 1
                     if got != int(np.asarray(want)):
                         diverged = True
@@ -1567,7 +1569,7 @@ class DeviceStateSupervisor(Observer):
                             # the injected fault: a bit flips on a
                             # resident plane (HBM corruption); this
                             # pass must catch it
-                            runner.corrupt_resident_plane(v)
+                            runner._feeds.corrupt_resident_plane(v)
                             injected = True
                         feeds.append((v["flat"], v["digests"],
                                       v.get("n_live", 0)))
