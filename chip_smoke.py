@@ -16,8 +16,10 @@ join are answered over gRPC at fresh timestamps and compared with plain
 numpy; one acknowledged ``txn_write`` must be in the next answer via the
 delta/patch path.  Every response is held to ``backend=device``, no
 ``degraded`` label, no ``host_exec`` span, and the compile class its plan
-is meant to take on a TPU.  The table sits in ONE region, as in every
-bench rig (multi-region fan-out is ROADMAP R1).
+is meant to take on a TPU.  The table sits in ONE region (a fan-out over
+several is ROADMAP W7, done: the benchmark's regions cells and the
+pre-split table of Phase A's mux check; reads under a refresh stream are
+the cell q1-refresh-lineitem-sf1-closed4).
 
 Phase B — the north-star shape at full size: 104,857,600 rows, GROUP BY
 1024 groups + COUNT/SUM through ``DeviceRunner().handle_request``

@@ -167,3 +167,42 @@ def test_the_q15_cell_asks_for_the_chunk_decoder_and_the_wide_grid():
     from tikv_tpu.device import pallas_hash
     kind = byname.load("requests", "tpch_q15")
     assert kind.kernel_max_slots() == pallas_hash.MAX_SLOTS >= kind.GRID
+
+
+def test_the_refresh_cell_asks_for_the_lock_wait_and_the_rebuild_phase():
+    """``requests/tpch_q1_refresh.py`` ``require_program``: the client's
+    ``LOCK_BACKOFF`` by ``hasattr``, and the two phases this cell's
+    metrics and labels read, by their names in the vocabulary: an older
+    program exits 1 before the cell's first write."""
+    import sys
+    assert ("tikv_tpu.server.client", "LOCK_BACKOFF") in \
+        capabilities_the_benchmark_asks_for()
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import byname
+    from tikv_tpu.server import client
+    from tikv_tpu.utils import trace_vocab
+    assert client.LOCK_BACKOFF == {"base": 0.010, "cap": 3.0}
+    assert {"feed_rebuild", "fanout_lock_wait", "feed_patch",
+            "delta_apply"} <= set(trace_vocab.SPAN_VOCABULARY)
+    assert "fanout_lock_wait" in trace_vocab.CLIENT_CLOCK
+    byname.load("requests", "tpch_q1_refresh").require_program()
+
+
+@pytest.mark.parametrize("missing", ["LOCK_BACKOFF", "feed_rebuild"])
+def test_the_refresh_cell_refuses_an_older_program(missing, monkeypatch):
+    import sys
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import byname
+    from tikv_tpu.server import client
+    from tikv_tpu.utils import trace_vocab
+    if missing == "LOCK_BACKOFF":
+        monkeypatch.delattr(client, "LOCK_BACKOFF")
+    else:
+        monkeypatch.delitem(trace_vocab.SPAN_VOCABULARY, missing)
+    with pytest.raises(SystemExit) as e:
+        byname.load("requests", "tpch_q1_refresh").require_program()
+    assert missing in str(e.value)

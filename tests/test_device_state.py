@@ -325,7 +325,7 @@ def test_patch_refreshes_digests_and_scrub_stays_clean():
             table, 300, {"c0": 1, "c1": 7})])
         resp = c.coprocessor(dag())
         assert resp["time_detail"]["labels"].get("device_feed") in \
-            ("patch", "upload")
+            ("patch", "rebuild")
         check_scrub_clean(sup)
     finally:
         _srv_rig["close"]()

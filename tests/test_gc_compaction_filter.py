@@ -204,10 +204,10 @@ def test_consistency_check_immune_to_gc_divergence():
     with eng._mu:
         for cf in filt.CF_ORDER:
             data = eng._writable(cf)
-            keys, vals = filt.filter_cf(cf, data.keys, data.vals)
-            dropped += len(data.keys) - len(keys)
-            data.keys = list(keys)
-            data.vals = list(vals)
+            live_keys, live_vals = data.flat()
+            keys, vals = filt.filter_cf(cf, live_keys, live_vals)
+            dropped += len(live_keys) - len(keys)
+            data.set_flat(list(keys), list(vals))
     assert dropped > 0      # the replica really diverged in raw bytes
     # the safe-point-pinned hash still agrees across all replicas
     c.check_consistency(region.id)

@@ -177,7 +177,8 @@ def unary_fanout(store, kind, params, name, monkeypatch):
     real = cls._run_cop_task
     monkeypatch.setattr(
         cls, "_run_cop_task", lambda self, task, env, timeout, t_entry,
-        mux=False: real(self, task, env, timeout, t_entry, False))
+        mux=False, *rest: real(self, task, env, timeout, t_entry, False,
+                               *rest))
     try:
         return read(store, kind, params, name)
     finally:

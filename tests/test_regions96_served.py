@@ -485,8 +485,11 @@ def test_a_region_under_the_row_threshold_fails_the_read(store, kind,
         kind.reference(ctx, params).tobytes()
 
 
-def test_locked_key_is_the_callers(store, kind, params):
-    """``key_is_locked`` rises to the caller as from ``coprocessor``."""
+def test_an_abandoned_lock_is_waited_out(store, kind, params):
+    """``key_is_locked`` no longer rises to the caller: the fan-out asks
+    for the transaction's status, waits while it is alive, and sends the
+    task again.  A prewrite nobody commits is rolled back when its TTL
+    (3 s) has run out, and the read answers without it."""
     from tikv_tpu.testing.fixture import encode_table_row
     ctx = store.ctxs["dense"]
     key, value = encode_table_row(ctx.table, 7, {"c0": 1, "c1": 1})
@@ -495,15 +498,15 @@ def test_locked_key_is_the_callers(store, kind, params):
     client._call_leader(key, "KvPrewrite", {
         "mutations": [{"op": "put", "key": key, "value": value}],
         "primary": key, "start_version": start_ts})
-    try:
-        with pytest.raises(wire.RemoteError) as e:
-            read(store, kind, params, "dense")
-        assert e.value.kind == "key_is_locked"
-    finally:
-        client._call_leader(key, "KvBatchRollback", {
-            "keys": [key], "start_version": start_ts})
+    t0 = time.monotonic()
     rec, _resp = read(store, kind, params, "dense")
-    assert rec["ok"]
+    assert rec["ok"] and int(rec["labels"]["lock_retries"]) >= 1
+    assert rec["phases_ms"]["fanout_lock_wait"] > 100
+    assert 1.0 < time.monotonic() - t0 < 30
+    assert kind.check(ctx, [rec], params, kind.reference(ctx, params))[0][1] \
+        == 0
+    rec, _resp = read(store, kind, params, "dense")
+    assert rec["ok"] and "lock_retries" not in rec["labels"]
 
 
 # ------------------------------------------------- the layout wait

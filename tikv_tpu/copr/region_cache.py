@@ -99,6 +99,7 @@ device-state supervisor, device/supervisor.py):
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import OrderedDict
@@ -556,6 +557,14 @@ class MvccColumnarSnapshot:
             return True
         return read_ts >= self.safe_ts and self.build_ts >= self.safe_ts
 
+    def serves(self, read_ts: int) -> bool:
+        """``valid_for`` and not yet superseded for ``read_ts``: no data
+        delta the line knows of and this view lacks (one a later
+        generation applied, or one held back for a later reader) was
+        committed at or below it."""
+        return self.valid_for(read_ts) and (
+            self.superseded_at is None or read_ts < self.superseded_at)
+
     def check_locks(self, ranges, read_ts: int, bypass_locks=()) -> None:
         """Range-scoped conflict check, matching MvccReader.scan's
         semantics: only locks inside the REQUEST's ranges can block it."""
@@ -853,6 +862,26 @@ class _LineState:
         self.n_dead = 0
         self.alive = None
 
+    def _merge_tail(self, pos: int, inserts) -> None:
+        """``inserts`` ([(handle, payload)], none of them present)
+        merged among the line's rows from ``pos`` on: written into the
+        slack, then rows [``pos``, n + k) put in handle order.  The rows
+        that move are visible to published snapshots, so the buffers
+        are copied first (as a positional update copies them)."""
+        n, k = self.n, len(inserts)
+        ins = sorted(inserts, key=lambda kv: kv[0])
+        self.handles = self.handles.copy()
+        self._cow_columns()
+        self.handles[n:n + k] = [h for h, _ in ins]
+        for i, (_h, payload) in enumerate(ins):
+            self._set_row(n + i, payload)
+        order = pos + np.argsort(self.handles[pos:n + k], kind="stable")
+        self.handles[pos:n + k] = self.handles[order]
+        for vals, valid in self.cols.values():
+            vals[pos:n + k] = vals[order]
+            valid[pos:n + k] = valid[order]
+        self.n = n + k
+
     def tombstone_ratio(self) -> float:
         return self.n_dead / self.n if self.n else 0.0
 
@@ -883,6 +912,11 @@ class RegionColumnarCache:
     back to a rebuild, which is exactly the pre-delta behavior.
     """
 
+    # inserts that land among a line's last rows this far from its end
+    # are merged in place of a repack (the largest bucket a feed patch's
+    # span is widened to: device/feed.py PATCH_BUCKETS)
+    TAIL_MERGE_ROWS = 4096
+
     def __init__(self, capacity: int = 8, delta_source=None,
                  compact_ratio: float = 0.25,
                  max_delta_rows: int = 1 << 16):
@@ -900,6 +934,8 @@ class RegionColumnarCache:
         self.deltas = 0         # data-version gaps bridged by patching
         self.rebuilds = 0       # gaps that fell back to a full rebuild
         self.compactions = 0
+        self.tail_merges = 0
+        self.deltas_held = 0
         self.invalidations = 0  # lines dropped by lifecycle events
         self.device_builds = 0  # cold builds served by device resolve
         # device-side MVCC resolution (the cold-path kill): a
@@ -957,6 +993,8 @@ class RegionColumnarCache:
         out = {"hits": self.hits, "misses": self.misses,
                "deltas": self.deltas, "rebuilds": self.rebuilds,
                "compactions": self.compactions,
+               "tail_merges": self.tail_merges,
+               "deltas_held": self.deltas_held,
                "invalidations": self.invalidations,
                "device_builds": self.device_builds,
                "splits": self.splits,
@@ -1124,7 +1162,7 @@ class RegionColumnarCache:
         if line.state is None or line.data_index is None or \
                 line.data_index > left_index:
             return None
-        if line.data_index < left_index:
+        if line.data_index < left_index or line.pending:
             try:
                 if self._bridge(line, None, left.id, left_index) is None:
                     return None
@@ -1344,8 +1382,9 @@ class RegionColumnarCache:
         or via a data delta that already retired the old snapshot)."""
         if line is None:
             return None
-        if line.data_index == data_index and \
-                line.snap.valid_for(start_ts):
+        if line.data_index == data_index and line.snap.serves(start_ts):
+            # (``serves``: the newest view is bounded too while deltas
+            # are held back for a later reader, ``_bridge``)
             return line.snap, line.snap
         # write churn: a read whose ts predates every data commit since
         # an older generation serves that generation — same visible set,
@@ -1356,9 +1395,7 @@ class RegionColumnarCache:
         # unapplied gap could hide a commit at or below the read's ts.
         if line.data_index is not None and line.data_index >= data_index:
             for old in line.history:
-                if old.valid_for(start_ts) and (
-                        old.superseded_at is None or
-                        start_ts < old.superseded_at):
+                if old.serves(start_ts):
                     return old, (line.snap if line.snap is not None
                                  else old)
         parked = line.parked.get((data_index, start_ts))
@@ -1386,11 +1423,12 @@ class RegionColumnarCache:
         # and that fallback must still count as a rebuild, not a miss
         had_state = line is not None and line.state is not None
         if had_state and line.data_index is not None and \
-                line.data_index < data_index and \
+                (line.data_index < data_index or
+                 (line.data_index == data_index and line.pending)) and \
                 self._delta_source is not None:
             with tracker.phase("delta_apply"):
                 bridged = self._bridge(line, snap, base_key[0],
-                                       data_index)
+                                       data_index, start_ts)
         if bridged is not None:
             with self._lock:
                 if base_key in self._lines:     # may have been evicted
@@ -1398,7 +1436,7 @@ class RegionColumnarCache:
                 self.deltas += 1
             self._count("delta")
             self._export_gauges(base_key[0], line)
-            if bridged.valid_for(start_ts):
+            if bridged.serves(start_ts):
                 return bridged, bridged
             # the delta landed but this request reads below the new
             # safe_ts — the generation it raced past may still serve it
@@ -1513,9 +1551,20 @@ class RegionColumnarCache:
 
     # -- the delta patch ------------------------------------------------
 
-    def _bridge(self, line, snap, region_id: int, data_index: int):
+    def _bridge(self, line, snap, region_id: int, data_index: int,
+                start_ts: Optional[int] = None):
         """Bridge ``line`` forward to ``data_index``; returns the new
         published snapshot, or None → caller falls back to rebuild.
+
+        ``start_ts``: the reader this bridge serves.  Deltas committed
+        ABOVE it are fetched and HELD BACK (``line.pending``), not
+        applied: sessions that write and read in turn race each other,
+        and a reader whose TSO lies between two commits of one batch
+        needs the first and must not see the second.  The view it gets
+        is bounded by the earliest held commit (``superseded_at``); the
+        next reader past that bound applies what is held, oldest first.
+        Without it such a reader falls to an exact-ts build of the whole
+        line.  None: apply everything (a split's bridge).
 
         The delta fetch happens INSIDE ``line.mu``: two threads bridging
         the same line toward different target versions must each replay
@@ -1525,46 +1574,90 @@ class RegionColumnarCache:
             cur = line.data_index
             if cur is None or cur > data_index:
                 return None
-            if cur == data_index:
+            rows, locks = [], []
+            if cur < data_index:
+                deltas = self._delta_source.deltas_between(
+                    region_id, cur, data_index)
+                if deltas is None:
+                    return None
+                rows, locks = deltas
+            elif not line.pending:
                 return line.snap
-            deltas = self._delta_source.deltas_between(
-                region_id, cur, data_index)
-            if deltas is None or len(deltas[0]) > self._max_delta_rows:
+            # (a key's deltas keep their apply order, which is their
+            # commit order: held ones first)
+            rows = line.pending + list(rows)
+            if len(rows) > self._max_delta_rows:
                 return None
-            try:
-                published = self._apply_deltas(line.state, snap,
-                                               *deltas)
-            except Exception:   # noqa: BLE001 — any surprise: rebuild
-                import logging
-                logging.getLogger(__name__).warning(
-                    "columnar delta apply failed; falling back to "
-                    "rebuild", exc_info=True)
-                published = None
-            if published is None:
-                # the state may be part-mutated: retire it so no later
-                # bridge replays onto it (the rebuild replaces the
-                # line), and drop its device feed with it
-                self._retire(line)
-                line.state = None
-                return None
-            published, min_data_ts = published
-            prev = line.snap
-            with self._lock:
-                if prev is not None:
-                    # the outgoing generation keeps serving reads below
-                    # the first commit that superseded it (churn path);
-                    # commit_ts order is not apply order across keys, so
-                    # EVERY older generation's bound tightens too
-                    if min_data_ts is not None:
-                        for h in (prev,) + tuple(line.history):
-                            h.superseded_at = min_data_ts if \
-                                h.superseded_at is None else \
-                                min(h.superseded_at, min_data_ts)
-                    line.history.appendleft(prev)
-                line.data_index = data_index
-                line.snap = published
-                line.parked.clear()
+            hold = [] if start_ts is None else \
+                [d for d in rows if d.commit_ts > start_ts]
+            if hold:
+                rows = [d for d in rows if d.commit_ts <= start_ts]
+                self.deltas_held += len(hold)
+            # one generation a TRANSACTION, in commit order (a stable
+            # sort: a key's own order stands): a reader that arrives
+            # later with an earlier TSO finds the view it needs in the
+            # line's history instead of building one
+            def ts(d):
+                return d.commit_ts
+            steps = [list(g) for _ts, g in
+                     itertools.groupby(sorted(rows, key=ts), key=ts)]
+            published = None
+            for i, step in enumerate(steps or [[]]):
+                # (a view between two steps is bounded by the steps to
+                # come as by what is held: a reader that looks the line
+                # up meanwhile must not take it for the newest)
+                rest = [d for later in steps[i + 1:] for d in later]
+                published = self._step(line, snap, step,
+                                       () if rest else locks,
+                                       rest + hold, data_index)
+                if published is None:
+                    return None
             return published
+
+    def _step(self, line, snap, rows, locks, hold, data_index: int):
+        """Apply one transaction's deltas to ``line`` (under its ``mu``)
+        and install the view; → it, or None (the line is retired)."""
+        try:
+            published = self._apply_deltas(line.state, snap, rows, locks)
+        except Exception:   # noqa: BLE001 — any surprise: rebuild
+            import logging
+            logging.getLogger(__name__).warning(
+                "columnar delta apply failed; falling back to "
+                "rebuild", exc_info=True)
+            published = None
+        if published is None:
+            # the state may be part-mutated: retire it so no later
+            # bridge replays onto it (the rebuild replaces the
+            # line), and drop its device feed with it
+            self._retire(line)
+            line.state = None
+            return None
+        published, min_data_ts = published
+        if hold:
+            # the new view lacks what is held; every older one lacks
+            # it too, beside what was just applied
+            published.superseded_at = min(d.commit_ts for d in hold)
+            min_data_ts = published.superseded_at \
+                if min_data_ts is None else \
+                min(min_data_ts, published.superseded_at)
+        prev = line.snap
+        with self._lock:
+            line.pending = list(hold)
+            if prev is not None:
+                # the outgoing generation keeps serving reads below
+                # the first commit that superseded it (churn path);
+                # commit_ts order is not apply order across keys, so
+                # EVERY older generation's bound tightens too
+                if min_data_ts is not None:
+                    for h in (prev,) + tuple(line.history):
+                        h.superseded_at = min_data_ts if \
+                            h.superseded_at is None else \
+                            min(h.superseded_at, min_data_ts)
+                line.history.appendleft(prev)
+            line.data_index = data_index
+            line.snap = published
+            line.parked.clear()
+        return published
 
     def _apply_deltas(self, state: _LineState, snap, rows, locks):
         """→ (published snapshot, min data commit_ts of the batch) or
@@ -1616,12 +1709,28 @@ class RegionColumnarCache:
         structural = False
 
         # 3. inserts: slack append when strictly increasing past the
-        #    current max handle, else a one-pass repack (mid-insert)
+        #    current max handle; a merge into the line's TAIL where they
+        #    land among its last rows and nothing else changed (sessions
+        #    take their rowids in one order and commit in another: the
+        #    rows that move are a span the device feed can patch); else
+        #    a one-pass repack (mid-insert)
         append_only = all(
             h > int(state.handles[n0 - 1]) for h, _ in inserts) \
             if n0 else True
-        if inserts and (not append_only or
-                        n0 + len(inserts) > state.cap):
+        merge_from = None
+        if inserts and not append_only and state.alive is None and \
+                not (updates or revives or deletes) and \
+                n0 + len(inserts) <= state.cap:
+            merge_from = int(np.searchsorted(
+                state.handles[:n0], min(h for h, _ in inserts)))
+            if n0 - merge_from > self.TAIL_MERGE_ROWS:
+                merge_from = None
+        if merge_from is not None:
+            state._merge_tail(merge_from, inserts)
+            self.tail_merges += 1
+            patch_spans.append((merge_from, state.n))
+        elif inserts and (not append_only or
+                          n0 + len(inserts) > state.cap):
             # repack folds deletes/tombstones too; positional updates
             # must land first so the gather copies patched values
             if updates or revives:
@@ -1692,8 +1801,14 @@ class RegionColumnarCache:
             else:
                 state.locks[ld.user_key] = ld.lock
 
-        # 6. journal the patch for the device feed
-        if structural or state.alive is not None:
+        # 6. journal the patch for the device feed.  A batch that
+        #    changed no row (another session's prewrite: locks alone; a
+        #    rollback) journals nothing: the view it publishes reflects
+        #    the same generation, so the feed, the request memos and the
+        #    prepared launches all still stand
+        if not (updates or revives or deletes or inserts or structural):
+            pass
+        elif structural or state.alive is not None:
             state.lineage.record({"structural": True, "n": state.n})
         else:
             spans = []
@@ -1722,7 +1837,7 @@ class RegionColumnarCache:
 
 class _Line:
     __slots__ = ("key", "data_index", "snap", "state", "parked",
-                 "history", "mu")
+                 "history", "mu", "pending")
 
     def __init__(self, key, data_index, snap, state):
         self.key = key
@@ -1730,8 +1845,11 @@ class _Line:
         self.snap = snap
         self.state = state
         self.parked: "OrderedDict" = OrderedDict()
+        # row deltas fetched up to ``data_index`` and held back: committed
+        # above the TSO of the reader that fetched them (``_bridge``)
+        self.pending: list = []
         # recently superseded generations, newest first: each serves
         # reads below its ``superseded_at`` without a rebuild
         from collections import deque
-        self.history: "deque" = deque(maxlen=4)
+        self.history: "deque" = deque(maxlen=6)
         self.mu = threading.Lock()

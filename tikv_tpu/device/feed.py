@@ -20,13 +20,16 @@ The store owns no state.  It serves through its runner's, by these
 names and no others: ``_arena``, ``_kernel_cache``, ``_single``,
 ``_mesh``, ``_row_sharding``, ``_nshards``, ``_block_local``,
 ``_chunk_override``, ``scrub_digests``, ``_dispatch_mu``,
-``_sub_runners``.  The runner, ``aggregate.py``, ``mvcc.py`` and
-``join.py`` import this module, and it imports none of them.
+``_sub_runners``, ``flight_recorder`` (its counts of patches and of
+rebuilds after a delta: /health ``device_mesh.feed``).  The runner,
+``aggregate.py``, ``mvcc.py`` and ``join.py`` import this module, and
+it imports none of them.
 """
 
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import numpy as np
 
@@ -120,6 +123,24 @@ def value_plane_index(null_flags) -> list:
         out.append(fi)
         fi += 2 if has_nulls else 1
     return out
+
+
+# The lengths, in rows, a patch's span is widened to ON THE HOST before
+# it is sent (a span longer than the last is cut into windows of that):
+# the update program compiles once a (length, plane dtype, n_pad), so a
+# line that takes appends and updates of every length (an order of 1-7
+# lineitems, two sessions' orders between two reads) runs three
+# programs, all built by its first patch, and none compiles later.  The
+# rows a span is widened by are sent as the request's snapshot holds
+# them.
+PATCH_BUCKETS = (16, 256, 4096)
+
+
+def patch_bucket(rows: int) -> int:
+    for b in PATCH_BUCKETS:
+        if rows <= b:
+            return b
+    return PATCH_BUCKETS[-1]
 
 
 def span_planes(span, used_infos, kinds):
@@ -274,6 +295,14 @@ class HostPlanes:
     def cols(self) -> list:
         return list(self.stream())
 
+    def window(self, pos: int, kind, lo: int, hi: int) -> tuple:
+        """Rows [``lo``, ``hi``) of used column ``pos`` as the request's
+        snapshot holds them → (the values of a plane of ``kind``, None
+        where a CHAR value has no code; the validity): what a patch
+        writes over a widened span."""
+        col = self.get_batch().columns[self.plan.used_cols[pos]]
+        return plane_values(kind, col.values[lo:hi]), col.validity[lo:hi]
+
 
 # -------------------------------------------------------------- the store
 
@@ -420,6 +449,9 @@ class FeedStore:
             anc = anchor(storage)
             cache = arena.bucket(anc)
         feed = cache.get(feed_key) if cache is not None else None
+        # why a resident feed that a write left behind is built again
+        # instead of patched (None: no such feed)
+        rebuild = None
         if feed is not None:
             fv = feed.get("lineage_v")
             if lineage is None or fv == req_v:
@@ -429,15 +461,18 @@ class FeedStore:
                 # an older-generation read (history serve): never
                 # downgrade the shared feed — build a private one
                 cache = None
-            elif positional and self._try_patch_feed(
-                    feed, lineage, used_infos, dtypes, n, req_v):
+            else:
                 # the snapshot moved forward under the feed: replay only
                 # the journal's dirty row spans into HBM instead of a
                 # cold re-upload — bucketed padding keeps n_pad (the
                 # compile class) stable across small deltas
-                tracker.label("device_feed", "patch")
-                self._register_digests(lineage, feed_key, feed)
-                return feed
+                rebuild = self._try_patch_feed(
+                    feed, lineage, planes, n, req_v) if positional \
+                    else "structural"
+                if rebuild is None:
+                    tracker.label("device_feed", "patch")
+                    self._register_digests(lineage, feed_key, feed)
+                    return feed
 
         def adopt(feed: dict) -> dict:
             """A feed this ladder just made, into its line."""
@@ -471,7 +506,8 @@ class FeedStore:
             # cannot bridge it falls through to the upload)
             if feed is not None and (
                     feed.get("lineage_v") == req_v or self._try_patch_feed(
-                        feed, lineage, used_infos, dtypes, n, req_v)):
+                        feed, lineage, planes, n, req_v,
+                        count=False) is None):
                 tracker.label("device_feed", "split")
                 return adopt(feed)
         # cold-path kill (device/mvcc.py): a device build left its
@@ -500,9 +536,12 @@ class FeedStore:
                 # now rather than pinning ~100 bytes/version on the
                 # lineage until a delta or teardown gets there
                 lineage.drop_cold()
-        tracker.label("device_feed", "upload")
+        tracker.label("device_feed", "rebuild" if rebuild else "upload")
         _fp_degrade("device::before_feed_upload")
-        with tracker.phase("feed_upload"):
+        if rebuild:
+            self._runner.flight_recorder.note_feed_rebuild(rebuild)
+        with tracker.phase("feed_rebuild") if rebuild \
+                else tracker.phase("feed_upload"):
             feed = self._build_flat(planes.stream(), n, planes.kinds)
         return adopt(feed)
 
@@ -548,63 +587,107 @@ class FeedStore:
 
     # ---------------------------------------------------------- the patch
 
-    def _try_patch_feed(self, feed, lineage, used_infos, dtypes,
-                        n: int, req_v) -> bool:
+    def _try_patch_feed(self, feed, lineage, planes: HostPlanes, n: int,
+                        req_v, count: bool = True) -> Optional[str]:
         """Apply the lineage's dirty row spans to the device feed in
-        place of a cold upload.  Only sound when the patch journal
-        covers the gap with pure row patches (no repack/compaction/
-        tombstones), positions map 1:1 (full-snapshot ascending feed),
-        the padded shape is unchanged, and every patched value fits the
-        feed's established device dtypes.  Sharded feeds patch too:
-        GSPMD partitions the update and ``dus`` pins the result back
-        to the row sharding."""
+        place of a cold upload → None where it did, else why it could
+        not (the keys of /health ``device_mesh.feed.rebuilds_after_
+        delta``).  Only sound when the patch journal covers the gap with
+        pure row patches (no repack/compaction/tombstones:
+        ``structural``), positions map 1:1 (full-snapshot ascending
+        feed), the padded shape is unchanged (``pad``), and every
+        patched value fits the feed's established device dtypes
+        (``dtype``) and NULL flags (``null``).
+
+        The journal says WHICH rows changed; their values are read from
+        the request's snapshot, which holds every row of the line at
+        ``req_v``: each span is widened to a bucket length
+        (``PATCH_BUCKETS``) and that window written whole, so spans of
+        every length share a few update programs, and a gap of several
+        generations writes each window once, as it stands at ``req_v``.
+        Sharded feeds patch too: GSPMD partitions the update and ``dus``
+        pins the result back to the row sharding."""
         patches = lineage.since(feed.get("lineage_v", -1), until=req_v)
         if patches is None or any(p.get("structural") for p in patches):
-            return False
+            return "structural"
         if patches and patches[-1]["n"] != n:
-            return False        # ranged feed: positions do not map 1:1
-        if self.pad_rows(max(n, 1)) != feed["n_pad"]:
-            return False        # row count crossed a pad bucket
+            return "structural"     # ranged feed: positions do not map 1:1
+        n_pad = feed["n_pad"]
+        if self.pad_rows(max(n, 1)) != n_pad:
+            return "pad"            # row count crossed a pad bucket
+        dtypes = planes.dtypes()
         plane = value_plane_index(feed["null_flags"])
         flat = list(feed["flat"])
         digests = list(feed["digests"]) \
             if self._runner.scrub_digests and \
             feed.get("digests") is not None else None
+        windows: dict = {}
+        rows = 0
+        for p in patches:
+            for span in p["spans"]:
+                lo = span["lo"]
+                m = span.get("hi", lo + len(span["handles"])) - lo
+                rows += m
+                for at in range(lo, lo + m, PATCH_BUCKETS[-1]):
+                    width = min(patch_bucket(lo + m - at), n_pad)
+                    start = max(0, min(at, n_pad - width))
+                    windows[start] = max(width, windows.get(start, 0))
         with tracker.phase("feed_patch"):
-            for p in patches:
-                for span in p["spans"]:
-                    lo = span["lo"]
-                    for ci, (vals, valid) in enumerate(span_planes(
-                            span, used_infos, feed["kinds"])):
-                        dt = np.dtype(dtypes[ci])
-                        if vals is None or \
-                                not fits_dtype(vals, valid, dt):
-                            return False
-                        if valid is not None and not valid.all() and \
-                                not feed["null_flags"][ci]:
-                            # first NULL in an all-valid column would
-                            # change the compile class: rebuild
-                            return False
-                        self._patch_plane(
-                            digests, flat, plane[ci], np.ascontiguousarray(
-                                vals.astype(dt, copy=False)), lo)
-                        if feed["null_flags"][ci]:
-                            mask = valid if valid is not None else \
-                                np.ones(len(vals), np.bool_)
-                            self._patch_plane(
-                                digests, flat, plane[ci] + 1,
-                                np.ascontiguousarray(mask), lo)
+            self._warm_patch_programs(flat)
+            for lo, width in sorted(windows.items()):
+                hi = min(lo + width, n)
+                # (the window's three device scalars, once for its planes)
+                at = (jnp.asarray(lo, jnp.int32), jnp.asarray(lo, jnp.int64),
+                      jnp.asarray(lo + width, jnp.int64))
+                for ci, kind in enumerate(feed["kinds"]):
+                    vals, valid = planes.window(ci, kind, lo, hi)
+                    dt = np.dtype(dtypes[ci])
+                    if vals is None or not fits_dtype(vals, valid, dt):
+                        return "dtype"
+                    if not valid.all() and not feed["null_flags"][ci]:
+                        # first NULL in an all-valid column would
+                        # change the compile class: rebuild
+                        return "null"
+                    # (rows past the line's end are the pad: zeros)
+                    update = np.zeros(width, dt)
+                    update[:hi - lo] = vals
+                    self._patch_plane(digests, flat, plane[ci], update, at)
+                    if feed["null_flags"][ci]:
+                        mask = np.zeros(width, np.bool_)
+                        mask[:hi - lo] = valid
+                        self._patch_plane(digests, flat, plane[ci] + 1,
+                                          mask, at)
         feed["flat"] = tuple(flat)
         feed["lineage_v"] = req_v
         if digests is not None:
             feed["digests"] = tuple(digests)
             feed["n_live"] = n
-        return True
+        if count:
+            self._runner.flight_recorder.note_feed_patch(
+                rows, list(windows.values()))
+        return None
+
+    def _warm_patch_programs(self, flat) -> None:
+        """A line's first patch runs the update program of EVERY bucket
+        once for each of its planes' classes (over the plane as it
+        stands, the result dropped), so that no later span length
+        compiles anything: the classes are (bucket, dtype, n_pad), a
+        handful a store, and what they cost is paid by the first read
+        after a line's first write."""
+        r = self._runner
+        for a in flat:
+            key = ("feed_patch_warm", str(a.dtype), a.shape[0])
+            if key not in r._kernel_cache:
+                for width in {min(b, a.shape[0]) for b in PATCH_BUCKETS}:
+                    self.dus(a, np.zeros(width, a.dtype), 0)
+                r._kernel_cache[key] = True
 
     def _patch_plane(self, digests, flat, fi: int, update: np.ndarray,
-                     lo: int) -> None:
-        """Plane ``flat[fi]``'s span patch + INCREMENTAL digest maintenance:
-        ``R' = R - H_span(old device plane) + H_span(new host data)``.
+                     at: tuple) -> None:
+        """Plane ``flat[fi]``'s window patch + INCREMENTAL digest
+        maintenance: ``R' = R - H_span(old device plane) + H_span(new
+        host data)``, ``at`` the window's (start as int32, start and end
+        as int64) on the device.
         Never re-hashes the whole plane from device state — doing so
         would launder any HBM corruption that landed since the last
         scrub into the recorded digest (the recorded value must stay
@@ -612,26 +695,28 @@ class FeedStore:
         delta survives arithmetically and the next scrub still catches
         it, wherever it sits relative to the patched span).  All device
         scalars — nothing blocks under the dispatch lock."""
+        lo32, lo_arr, hi_arr = at
         old = flat[fi]
-        new = flat[fi] = self.dus(old, update, lo)
+        new = flat[fi] = self._patch_program()(old, update, lo32)
         if digests is not None:
-            hi = lo + len(update)
             rng = self.range_digest_kernel(old.dtype, old.shape[0])
-            lo_arr = jnp.asarray(lo, jnp.int64)
-            hi_arr = jnp.asarray(hi, jnp.int64)
             d_old = rng(old, lo_arr, hi_arr)
             d_new = rng(new, lo_arr, hi_arr)
             digests[fi] = jnp.uint64(digests[fi]) - d_old + d_new
 
     def dus(self, arr, update, lo: int):
         """Jitted in-place-style slice update (dynamic_update_slice);
-        the start index is traced, so repeated single-row patches at
-        different positions share one compile class per update length.
+        the start index is traced, so repeated patches at different
+        positions share one compile class per update length.
         On a sharded feed GSPMD partitions the update and the jit's
         ``out_shardings`` pins the result to the row sharding in the
         SAME dispatch — no post-hoc device_put re-lay, so delta churn
         on a sharded feed costs one small collective-free launch per
         span, exactly like the single-device path."""
+        return self._patch_program()(arr, update,
+                                     jnp.asarray(lo, jnp.int32))
+
+    def _patch_program(self):
         r = self._runner
         fn = r._kernel_cache.get("feed_patch_fn")
         if fn is None:
@@ -640,7 +725,7 @@ class FeedStore:
             fn = r._kernel_cache["feed_patch_fn"] = \
                 jax.jit(feed_patch) if r._single else \
                 jax.jit(feed_patch, out_shardings=r._row_sharding)
-        return fn(arr, update, jnp.asarray(lo, jnp.int32))
+        return fn
 
     # -------------------------------------------------------- the digests
     #

@@ -995,7 +995,12 @@ class DeviceRunner:
         warm whole-feed Pallas launches staged from their class's
         prepared record, ``hits`` a lane, the records written
         (``builds``) and dropped, by cause (``drops``:
-        ``FlightRecorder.prepared_counts``); ``lanes``: this runner's
+        ``FlightRecorder.prepared_counts``); ``feed``: resident feeds
+        a write left behind, patched forward (``patches``, the dirty
+        ``patch_rows``, the widened windows by bucket length) or built
+        again, by cause (``rebuilds_after_delta``), and both together
+        (``after_delta``: ``FlightRecorder.feed_counts``); ``lanes``:
+        this runner's
         launches of lanes, ``DeviceAggregator.lane_stats``; all
         monotone), the resident
         bytes of the live mesh's fullest shard, and the placement
@@ -1018,6 +1023,7 @@ class DeviceRunner:
                "scalar_cache": self.flight_recorder.scalar_counts(),
                "agg_params": self.flight_recorder.agg_param_counts(),
                "prepared": self.flight_recorder.prepared_counts(),
+               "feed": self.flight_recorder.feed_counts(),
                "lanes": self.lane_stats(),
                "submesh_rebuilds": self._submesh_rebuilds,
                "feed_bytes_per_shard": max(

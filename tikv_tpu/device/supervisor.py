@@ -176,6 +176,13 @@ class FlightRecorder:
         self.prepared_hits = 0
         self.prepared_builds = 0
         self.prepared_drops = {"refresh": 0, "feed": 0, "kernel": 0}
+        # device/feed.py: resident feeds brought forward by a patch
+        # after a write, and those built again instead, by cause
+        self.feed_patches = 0
+        self.feed_patch_rows = 0
+        self.feed_patch_buckets: dict = {}
+        self.feed_rebuilds = {"structural": 0, "pad": 0, "dtype": 0,
+                              "null": 0}
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -282,6 +289,37 @@ class FlightRecorder:
             return {"hits": self.prepared_hits,
                     "builds": self.prepared_builds,
                     "drops": dict(self.prepared_drops)}
+
+    def note_feed_patch(self, rows: int, widths) -> None:
+        """A resident feed patched forward: the journal's dirty
+        ``rows``, sent as windows of these bucket ``widths``."""
+        with self._mu:
+            self.feed_patches += 1
+            self.feed_patch_rows += rows
+            for w in widths:
+                self.feed_patch_buckets[w] = \
+                    self.feed_patch_buckets.get(w, 0) + 1
+
+    def note_feed_rebuild(self, why: str) -> None:
+        """A resident feed a write left behind built again from the
+        line: ``structural`` (tombstones, a repack, a journal gap),
+        ``pad`` (the row count crossed a pad bucket), ``dtype`` / ``null``
+        (a value outside the feed's dtypes / its first NULL)."""
+        with self._mu:
+            self.feed_rebuilds[why] += 1
+
+    def feed_counts(self) -> dict:
+        """/health ``device_mesh.feed``; ``after_delta`` = ``patches`` +
+        every rebuild: what a read found a write had left behind."""
+        with self._mu:
+            rebuilds = dict(self.feed_rebuilds)
+            return {"patches": self.feed_patches,
+                    "patch_rows": self.feed_patch_rows,
+                    "patch_buckets": {str(w): c for w, c in sorted(
+                        self.feed_patch_buckets.items())},
+                    "rebuilds_after_delta": rebuilds,
+                    "after_delta": self.feed_patches +
+                    sum(rebuilds.values())}
 
     def note_scalar(self, hit: bool) -> None:
         with self._mu:

@@ -299,8 +299,7 @@ class DiskEngine(MemoryEngine):
                 off += 4
                 vals.append(body[off:off + vlen])
                 off += vlen
-            data_cf.keys = keys
-            data_cf.vals = vals
+            data_cf.set_flat(keys, vals)
         return True
 
     def _replay_wal(self, path: str) -> bool:
@@ -507,19 +506,17 @@ class DiskEngine(MemoryEngine):
                      if cf in self._cf_names]
             order += [cf for cf in self._cf_names if cf not in order]
             for cf in order:
-                keys, vals = filt.filter_cf(cf, self._cfs[cf].keys,
-                                            self._cfs[cf].vals)
-                if keys is not self._cfs[cf].keys:
+                live_keys, live_vals = self._cfs[cf].flat()
+                keys, vals = filt.filter_cf(cf, live_keys, live_vals)
+                if keys is not live_keys:
                     # respect the copy-on-write snapshot contract:
                     # pinned generations are shared with live readers
-                    data = self._writable(cf)
-                    data.keys = list(keys)
-                    data.vals = list(vals)
+                    self._writable(cf).set_flat(list(keys), list(vals))
         parts = [_CKPT_MAGIC, struct.pack(">B", len(self._cf_names))]
         for cfi, cf in enumerate(self._cf_names):
-            data = self._cfs[cf]
-            parts.append(struct.pack(">BQ", cfi, len(data.keys)))
-            for k, v in zip(data.keys, data.vals):
+            keys, vals = self._cfs[cf].flat()
+            parts.append(struct.pack(">BQ", cfi, len(keys)))
+            for k, v in zip(keys, vals):
                 parts.append(struct.pack(">I", len(k)))
                 parts.append(k)
                 parts.append(struct.pack(">I", len(v)))

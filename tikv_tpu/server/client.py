@@ -22,6 +22,12 @@ from . import wire
 from .pd_server import RemotePdClient
 
 
+# How long a cop task waits for a lock that is still alive before it is
+# sent again: client-go's BoTxnLockFast as a TiDB session configures it
+# (``tidb_backoff_lock_fast``: base 10 ms, cap 3 s, equal jitter).
+LOCK_BACKOFF = {"base": 0.010, "cap": 3.0}
+
+
 class StoreClient:
     """Raw method stub against one tikv-server."""
 
@@ -794,20 +800,28 @@ class TxnClient:
 
     # -- lock resolution (client-go resolver protocol) --
 
-    def _resolve_lock(self, key: bytes, lock: dict, caller_ts: int) -> None:
+    def _resolve_lock(self, key: bytes, lock: dict, caller_ts: int) -> str:
+        """→ what the lock's transaction turned out to be: ``committed``
+        / ``rolled_back`` / ``ttl_expired`` (the lock on ``key`` is
+        resolved to match) or ``locked`` (still alive: back off)."""
         primary = lock["primary"]
         status = self._call_leader(primary, "KvCheckTxnStatus", {
             "primary_key": primary, "lock_ts": lock["start_ts"],
             "caller_start_ts": caller_ts, "current_ts": self.tso()})
         st = status["status"]
+        # (``key_hint``: the store resolves the transaction's locks in
+        # the region that holds the key, all in one round; without it a
+        # store of several regions cannot tell where to look)
         if st == "committed":
             self._call_leader(key, "KvResolveLock", {
                 "start_version": lock["start_ts"],
-                "commit_version": status["ts"]})
+                "commit_version": status["ts"], "key_hint": key})
         elif st in ("rolled_back", "ttl_expired"):
             self._call_leader(key, "KvResolveLock", {
-                "start_version": lock["start_ts"], "commit_version": 0})
+                "start_version": lock["start_ts"], "commit_version": 0,
+                "key_hint": key})
         # "locked": still alive — caller retries / backs off
+        return st
 
     # -- coprocessor --
 
@@ -935,8 +949,13 @@ class TxnClient:
         one task of the read, its tasks travel as commands on that
         ``StoreClient``'s ONE BatchCommands stream (``call_mux``: the
         same bytes, the same reply, the same wire phases); a lone task
-        is a unary call, as ``coprocessor()``'s request is.
-        ``key_is_locked`` and anything else is the caller's, as from
+        is a unary call, as ``coprocessor()``'s request is.  A task
+        that meets a lock of a transaction started at or before the
+        read's TSO (``key_is_locked``) asks the lock's primary for the
+        transaction's status, resolves the lock where that is decided,
+        waits under ``LOCK_BACKOFF`` where it is still alive, and is
+        sent again, as client-go's copIterator does: inside the read's
+        clock and ``timeout``.  Anything else is the caller's, as from
         ``coprocessor``.
 
         → one summary shaped like a single reply, with the partial
@@ -945,13 +964,15 @@ class TxnClient:
         ``backend`` is ``"device"`` only if every task's was;
         ``time_detail.labels`` is the union of the tasks' (plus
         ``cop_tasks``, ``fanout_retries`` where a task was cut again,
-        and ``unary_resends`` where a task's stream died under it);
+        ``lock_retries`` where one was sent again after a lock, and
+        ``unary_resends`` where a task's stream died under it);
         ``phases_ms`` / ``total_rpc_wall_ms`` / ``trace_id`` are those
         of the task that returned last, the read's critical path
         (each task has a server-minted trace id of its own: the store's
         trace buffer keeps one tracker an id), with any task's
         ``host_exec`` and the client's own phases added: ``fanout_cut``,
-        ``fanout_tasks``, ``fanout_straggler``, ``fanout_task``
+        ``fanout_tasks``, ``fanout_straggler``, ``fanout_task`` and,
+        where a task waited for a lock, ``fanout_lock_wait``
         (utils/trace_vocab.py).  The critical task's path across the
         wire rides along (``StoreClient.call``: ``client_encode``,
         ``wire_request``, ``rpc_accept_wait``, ``wire_reply``,
@@ -982,7 +1003,8 @@ class TxnClient:
             to_store = collections.Counter(
                 leader.store_id for _region, leader, _ranges in tasks)
             futs = [pool.submit(self._run_cop_task, task, env, timeout,
-                                t_entry, to_store[task[1].store_id] > 1)
+                                t_entry, to_store[task[1].store_id] > 1,
+                                dag.start_ts)
                     for task in tasks]
             try:
                 ran = [f.result() for f in futs]
@@ -990,7 +1012,7 @@ class TxnClient:
                 for f in futs:
                     f.cancel()
                 raise
-            parts = [part for got, _n, _m in ran for part in got]
+            parts = [part for got, *_counts in ran for part in got]
             if not parts:
                 raise TxnError("coprocessor fan-out over no ranges")
             back = [b for _r, _s, b in parts]
@@ -1001,6 +1023,11 @@ class TxnClient:
                               max(back) - statistics.median(back))
             tracker.add_phase("fanout_task", statistics.median(
                 b - s for _r, s, b in parts))
+            lock_wait = max(w for _got, _n, _m, _l, w in ran)
+            if lock_wait:
+                # the longest any one task of the read spent on locks:
+                # status checks, resolves and backoff sleeps
+                tracker.add_phase("fanout_lock_wait", lock_wait)
         finally:
             tracker.uninstall(tok)
         replies = [r for r, _s, _b in parts]
@@ -1016,12 +1043,11 @@ class TxnClient:
             backends.add(r.get("backend"))
         phases.update(tr.time_detail()["phases_ms"])
         labels["cop_tasks"] = str(len(replies))
-        retries = sum(n for _got, n, _m in ran)
-        if retries:
-            labels["fanout_retries"] = str(retries)
-        resends = sum(m for _got, _n, m in ran)
-        if resends:
-            labels["unary_resends"] = str(resends)
+        for label, i in (("fanout_retries", 1), ("unary_resends", 2),
+                         ("lock_retries", 3)):
+            n = sum(counts[i] for counts in ran)
+            if n:
+                labels[label] = str(n)
         detail["phases_ms"], detail["labels"] = phases, labels
         return {"responses": replies, "tasks": len(replies),
                 "backend": "device" if backends == {"device"}
@@ -1077,11 +1103,14 @@ class TxnClient:
                 for region, leader, pieces in tasks]
 
     def _run_cop_task(self, task, env: dict, timeout: float,
-                      t_entry: int, mux: bool = False) -> tuple:
+                      t_entry: int, mux: bool = False,
+                      start_ts: int = 0) -> tuple:
         """One cop task to its region's leader → ([(reply, sent_ns,
         back_ns)], times it was cut again, times it was re-sent as a
-        unary call): more than one reply where the region had changed
-        under the task.  ``t_entry``: when the fan-out was entered,
+        unary call, times it was sent again after a lock, ns it spent on
+        locks): more than one reply where the region had changed under
+        the task.  ``start_ts``: the read's TSO, which a lock's status
+        check carries as its caller.  ``t_entry``: when the fan-out was entered,
         where each reply's ``client_route`` starts.  ``mux``: the task
         (and what it is cut into again) goes as a command on its store's
         BatchCommands stream (``_store_call``)."""
@@ -1089,7 +1118,8 @@ class TxnClient:
         from ..utils.failpoint import fail_point
         from ..utils.health import CircuitOpen
         bo = Backoff(base=0.02, cap=0.5, deadline_s=timeout)
-        todo, out, recuts = [task], [], 0
+        lock_bo = None
+        todo, out, recuts, locked, lock_ns = [task], [], 0, 0, 0
         resent = [0] if mux else None
         while todo:
             region, leader, ranges = todo.pop(0)
@@ -1107,6 +1137,23 @@ class TxnClient:
                     hint = e.err.get("retry_after_ms")
                     if not bo.sleep(hint_s=hint / 1000.0 if hint else None):
                         raise
+                    todo.insert(0, (region, leader, ranges))
+                    continue
+                if e.kind == "key_is_locked":
+                    # a transaction that started at or before this read
+                    # holds the key: its outcome decides what the read
+                    # sees, so find it out, then send the task again
+                    t_lock = time.perf_counter_ns()
+                    if lock_bo is None:
+                        lock_bo = Backoff(deadline_s=bo.remaining(),
+                                          **LOCK_BACKOFF)
+                    alive = self._resolve_lock(
+                        e.err["key"], e.err["lock"], start_ts) == "locked"
+                    if lock_bo.expired() or \
+                            (alive and not lock_bo.sleep()):
+                        raise
+                    locked += 1
+                    lock_ns += time.perf_counter_ns() - t_lock
                     todo.insert(0, (region, leader, ranges))
                     continue
                 if e.kind not in self._REROUTE_KINDS and \
@@ -1127,7 +1174,7 @@ class TxnClient:
             if not bo.sleep():
                 raise last
             todo[:0] = self._cut_by_region(ranges)
-        return out, recuts, resent[0] if mux else 0
+        return out, recuts, resent[0] if mux else 0, locked, lock_ns
 
     def coprocessor_replica(self, dag, key_hint: Optional[bytes] = None,
                             resource_group: str = "default",

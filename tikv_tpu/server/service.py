@@ -56,6 +56,16 @@ _slow_query_logger = logging.getLogger("tikv_tpu.slow_query")
 _TRACE_ID_RE = re.compile(r"[0-9A-Za-z_-]{1,64}")
 
 
+def _note_locked_reply(resp) -> None:
+    """Count a Coprocessor reply that says ``key_is_locked`` (/health
+    ``coprocessor.locked_replies``): the reader resolves or waits, then
+    asks again, so each is a cop task served twice."""
+    err = resp.get("error") if isinstance(resp, dict) else None
+    if isinstance(err, dict) and err.get("kind") == "key_is_locked":
+        from ..utils import metrics as m
+        m.COPR_LOCKED_REPLY_COUNTER.inc()
+
+
 class MuxStats:
     """``/health`` ``batch_commands``: what the mux has carried since
     process start.  ``commands_in`` over ``messages_in`` and
@@ -342,6 +352,7 @@ class KvService:
                         resp = d.wait() if hasattr(d, "wait") else d
                 except Exception as e:  # noqa: BLE001 — ride the wire
                     env = {"error": wire.enc_error(e)}
+                    _note_locked_reply(env)
             finally:
                 if dl is not None:
                     dl_mod.uninstall(dl_tok)
@@ -509,6 +520,8 @@ class KvService:
             time.perf_counter() - t0)
         m.GRPC_MSG_COUNTER.labels(
             method, "err" if resp.get("error") else "ok").inc()
+        if method == "Coprocessor":
+            _note_locked_reply(resp)
         return resp
 
     def _seal_traced(self, method: str, req: dict, resp: dict,
@@ -657,7 +670,8 @@ class KvService:
                 req["start_version"], req.get("commit_version", 0),
                 req["keys"]))
         return self.storage.sched_txn_command(cmds.ResolveLock(
-            req["start_version"], req.get("commit_version", 0)))
+            req["start_version"], req.get("commit_version", 0),
+            key_hint=req.get("key_hint")))
 
     def KvPessimisticLock(self, req: dict) -> dict:
         return self.storage.sched_txn_command(cmds.AcquirePessimisticLock(

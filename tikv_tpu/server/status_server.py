@@ -97,11 +97,14 @@ class StatusServer:
                     # and how the replies carried their results
                     # (wire.enc_cop_body: rows, or a chunk where the
                     # request asked for one), with the chunks' rows and
-                    # buffer bytes
+                    # buffer bytes; and how many of them were answered
+                    # key_is_locked (the reader waits and asks again)
                     from ..utils import metrics as m
                     body["coprocessor"] = {"requests_served": int(sum(
                         m.GRPC_MSG_COUNTER.labels("Coprocessor", st).value
                         for st in ("ok", "err"))),
+                        "locked_replies": int(
+                            m.COPR_LOCKED_REPLY_COUNTER.value),
                         "replies": {
                             "rows": int(m.COPR_REPLY_COUNTER.labels(
                                 "rows").value),

@@ -232,13 +232,16 @@ class ResolveLockLite(Command):
 @dataclass
 class ResolveLock(Command):
     """commands/resolve_lock.rs — scan this txn's locks in range and
-    commit/rollback them (the resolver's bulk path)."""
+    commit/rollback them (the resolver's bulk path).  ``key_hint``: a
+    key of the region to scan (the request's region context; without it
+    a store of several regions scans its first)."""
 
     start_ts: int
     commit_ts: int
     start_key: Optional[bytes] = None
     end_key: Optional[bytes] = None
     scan_limit: int = 256
+    key_hint: Optional[bytes] = None
 
     _found: list = field(default_factory=list, repr=False)
 

@@ -63,7 +63,8 @@ class TxnScheduler:
         if ctx is None:
             from ..txn_types import encode_key
             keys = cmd.write_keys()
-            ctx = SnapContext(key_hint=encode_key(keys[0]) if keys else b"")
+            hint = keys[0] if keys else getattr(cmd, "key_hint", None)
+            ctx = SnapContext(key_hint=encode_key(hint) if hint else b"")
         if isinstance(cmd, ResolveLock):
             # read phase before latching (resolve_lock.rs scan → write)
             cmd.prepare(MvccReader(self._engine.snapshot(ctx)))

@@ -291,6 +291,10 @@ COPR_REPLY_COUNTER = REGISTRY.counter(
     "coprocessor replies by how they carry their result "
     "(rows / chunk: a buffer a column, where the request asked for one)",
     labels=("encode",))
+COPR_LOCKED_REPLY_COUNTER = REGISTRY.counter(
+    "tikv_coprocessor_locked_reply_total",
+    "coprocessor requests answered key_is_locked: a lock of a "
+    "transaction started at or before the read's TSO lay in its ranges")
 COPR_CHUNK_ROWS = REGISTRY.counter(
     "tikv_coprocessor_reply_chunk_rows_total",
     "rows that left in chunk replies")

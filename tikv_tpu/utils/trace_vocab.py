@@ -64,6 +64,11 @@ SPAN_VOCABULARY: dict[str, str] = {
                         "task's: what the read waited for its slowest "
                         "region",
     "fanout_task": "median over the read's cop tasks of send → reply",
+    "fanout_lock_wait": "where a cop task met another transaction's "
+                        "lock (key_is_locked): the longest any one task "
+                        "of the read spent on status checks, resolves "
+                        "and backoff sleeps before it was sent again "
+                        "(label lock_retries counts the resends)",
     "client_route": "TxnClient.coprocessor / coprocessor_fanout entry → "
                     "StoreClient.call entry: plan encode, region "
                     "lookup, breaker, leader choice, retries (a cop "
@@ -143,7 +148,14 @@ SPAN_VOCABULARY: dict[str, str] = {
                       "mesh: one plane handed to device_put on the row "
                       "sharding, a slice to each shard (not waited for: "
                       "the first launch waits for the transfer)",
-    "feed_patch": "delta-dirty span patch of a resident feed",
+    "feed_patch": "delta-dirty span patch of a resident feed: each "
+                  "span widened on the host to a bucket length, one "
+                  "update program a (bucket, plane) class",
+    "feed_rebuild": "a resident feed the journal could not patch "
+                    "forward (tombstones, a repack, a crossed pad "
+                    "bucket, a value outside the feed's dtypes) built "
+                    "again from the line and uploaded: feed_upload's "
+                    "work, named apart because it follows a write",
     "shard_merge": "host-side merge of per-shard partial agg states",
     "mesh_rebuild": "elastic degrade: re-mint serving on a submesh",
     "feed_migrate": "ICI move of a resident feed between slices "
@@ -189,6 +201,7 @@ SPAN_VOCABULARY: dict[str, str] = {
 # total_rpc_wall_ms + wire_reply + client_decode = the caller's wall.
 CLIENT_CLOCK = frozenset({
     "fanout_cut", "fanout_tasks", "fanout_straggler", "fanout_task",
+    "fanout_lock_wait",
     "client_route", "client_encode", "wire_request", "wire_reply",
     "client_decode"})
 OUTSIDE_ROOT = CLIENT_CLOCK | {"rpc_accept_wait"}
