@@ -529,27 +529,35 @@ def test_multi_key_group_by_on_int_table_equals_the_host(runner, n_keys):
 
 
 def test_a_composite_keys_memo_survives_what_stays_inside_its_bounds(runner):
-    """``_verify_meta_consts`` holds each key to ITS bounds: a row inside
-    them keeps the memo, a row outside one key's drops it."""
+    """``feed.roll_derived``'s proof holds each key to ITS bounds: a
+    row inside them keeps the memo, a row outside one key's drops it."""
+    from tikv_tpu.device import feed
     table, names, snap = _int_keys(8960, 500, spans=(3, 3, 2))
     s = DagSelect.from_table(table, names)
     dag = s.aggregate([s.col(names[0]), s.col(names[1])],
                       [("count_star", None)]).build()
     plan = runner._analyze(dag)
     infos = [plan.scan.columns[ci] for ci in plan.used_cols]
-    meta = {"hash_bounds": (0, 9, (0,)), "key_bounds": ((-5, 3), (-10, 3))}
+    meta = {"dtypes": ("int32", "int32"), "hash_bounds": (0, 9, (0,)),
+            "key_bounds": ((-5, 3), (-10, 3))}
+
+    def disproved(*rows):
+        return feed._disproved(meta, plan, list(rows), 500,
+                               runner._limb_variant)
 
     def span(a, b):
         return {"handles": np.array([1]), "cols": {
             infos[0].col_id: (np.array([a]), np.array([True])),
             infos[1].col_id: (np.array([b]), np.array([True]))}}
 
-    assert runner._verify_meta_consts(meta, plan, infos, [span(-4, -9)])
-    assert not runner._verify_meta_consts(meta, plan, infos, [span(-4, -7)])
-    assert not runner._verify_meta_consts(meta, plan, infos, [span(-2, -9)])
+    assert not plan.lowered
+    assert disproved(span(-4, -9)) is None
+    assert disproved(span(-4, -7)) == "key"
+    assert disproved(span(-2, -9)) == "key"
     nulled = span(-4, -9)
     nulled["cols"][infos[1].col_id] = (np.array([0]), np.array([False]))
-    assert not runner._verify_meta_consts(meta, plan, infos, [nulled])
+    assert disproved(nulled) == "null_key"
+    assert disproved(span(-4, 1 << 40)) == "dtype"
 
 
 def test_key_spans_over_the_grid_leave_the_fused_kernel(monkeypatch):

@@ -313,7 +313,8 @@ def test_delta_vs_rebuild_randomized(rig, monkeypatch, seed):
     first = [int(h) for h in rng.choice(200, size=30, replace=False)]
     c.txn_write([_mut(table, h, {2: h % 7, 3: h}) for h in first])
     live.update(first)
-    _assert_parity(c, cache, table)
+    prev = _assert_parity(c, cache, table)
+    said = 0
 
     st = _storage(c)
     for _round in range(100):
@@ -354,6 +355,172 @@ def test_delta_vs_rebuild_randomized(rig, monkeypatch, seed):
             c.txn_write(muts)
         ent = _assert_parity(c, cache, table)
         assert ent.estimated_rows() == len(live)
+        # every journal entry says what its batch did (a round is one
+        # transaction: one entry, or none where no row changed)
+        if ent.feed_lineage is prev.feed_lineage:
+            for entry in ent.feed_lineage.since(prev.feed_version,
+                                                until=ent.feed_version):
+                _assert_entry_says_what_the_batch_did(
+                    _view(prev), _view(ent), entry)
+                said += 1
+        prev = ent
+    assert said >= 60
     # the overwhelming majority of rounds must ride the delta path
     assert cache.deltas >= 80, (cache.deltas, cache.misses,
                                 cache.rebuilds)
+
+
+# ------------------------------------------------- what the journal says
+
+
+def _row(pairs, i: int) -> tuple:
+    """Row ``i`` of some columns' (values, validity), as Python values."""
+    return tuple((v[i].item() if hasattr(v[i], "item") else v[i],
+                  bool(ok[i])) for v, ok in pairs)
+
+
+def _view(ent) -> dict:
+    """{handle: ((value, valid) per column)} of a published line's view,
+    in handle order (a dict keeps it)."""
+    tbl = ent._tbl
+    n = len(tbl.handles)
+    keep = np.ones(n, np.bool_) if tbl.alive is None else tbl.alive
+    pairs = [(tbl.columns[cid].values, tbl.columns[cid].validity)
+             for cid in sorted(tbl.columns)]
+    return {int(h): _row(pairs, i)
+            for i, h in enumerate(tbl.handles) if keep[i]}
+
+
+def _introduced(entry) -> dict:
+    out = {}
+    for rows in entry["introduced"]:
+        pairs = [rows["cols"][cid] for cid in sorted(rows["cols"])]
+        for i, h in enumerate(rows["handles"]):
+            out[int(h)] = _row(pairs, i)
+    return out
+
+
+def _assert_entry_says_what_the_batch_did(before: dict, after: dict,
+                                          entry: dict) -> None:
+    """One journal entry between two views of a line: every row whose
+    values the batch wrote is ``introduced`` as the line now holds it;
+    ``dead``, where present, names the rows that left by their position
+    in the view before, and every row the batch did not write is where
+    it was, less the rows that left before it."""
+    wrote = _introduced(entry)
+    for h, row in wrote.items():
+        assert after[h] == row, (h, after[h], row)
+    changed = {h for h, row in after.items() if before.get(h) != row}
+    assert changed <= set(wrote), (changed, set(wrote))
+    assert entry["live"] == len(after)
+    if entry["structural"]:
+        assert "spans" not in entry
+    else:
+        assert entry["introduced"] is entry["spans"]
+        assert entry["dead"] == ()
+    if "dead" not in entry:
+        return
+    order = list(before)
+    gone = [order[i] for i in entry["dead"]]
+    assert gone == sorted(set(before) - set(after))
+    assert list(entry["dead"]) == sorted(entry["dead"])
+    left = [h for h in order if h not in gone]
+    if not wrote:
+        assert list(after) == left
+    # the rows the batch did not write: each where it was, less the
+    # rows that left before it
+    kept = [(i, h) for i, h in enumerate(left) if h not in wrote]
+    assert kept == [(i, h) for i, h in enumerate(after) if h not in wrote]
+
+
+# per cause of ``_apply_deltas``: the line before it (handles), the
+# batch, and what its entry must say
+def _put(h, v=None):
+    return ("put", h, v if v is not None else h)
+
+
+JOURNAL_CAUSES = {
+    "tail append": (
+        range(0, 40, 2), [_put(40), _put(44)],
+        dict(structural=False, dead=(), wrote=[40, 44])),
+    "merge into the tail": (
+        range(0, 40, 2), [_put(35), _put(41)],
+        dict(structural=False, dead=(), wrote=[35, 36, 38, 41])),
+    "update": (
+        range(0, 40, 2), [_put(6, 600)],
+        dict(structural=False, dead=(), wrote=[6])),
+    "delete": (
+        range(0, 40, 2), [("delete", 4), ("delete", 30)],
+        dict(structural=True, dead=(2, 15), wrote=[])),
+    "delete on a line with tombstones": (
+        range(0, 40, 2), [("delete", 10), ("delete", 30)],
+        dict(structural=True, dead=(3, 13), wrote=[]), [("delete", 2),
+                                                       ("delete", 8)]),
+    "update beside a delete": (
+        range(0, 40, 2), [_put(6, 600), ("delete", 30)],
+        dict(structural=True, dead=(15,), wrote=[6])),
+    "append on a line with tombstones": (
+        range(0, 40, 2), [_put(50)],
+        dict(structural=True, dead=(), wrote=[50]), [("delete", 2)]),
+    "revive": (
+        range(0, 40, 2), [_put(2, 222)],
+        dict(structural=True, dead=None, wrote=[2]), [("delete", 2)]),
+    "mid insert: a repack": (
+        range(0, 40, 2), [_put(7)],
+        dict(structural=True, dead=None, wrote=[7])),
+    "a repack beside an update and a delete": (
+        range(0, 40, 2), [_put(7), _put(12, 1200), ("delete", 20)],
+        dict(structural=True, dead=None, wrote=[7, 12])),
+    "compaction at 25%": (
+        range(0, 14, 2), [("delete", 6)],
+        dict(structural=True, dead=None, wrote=[]), [("delete", 2)]),
+}
+
+
+@pytest.mark.parametrize("cause", sorted(JOURNAL_CAUSES))
+def test_every_cause_journals_what_it_introduced_and_what_left(rig, cause):
+    """copr/region_cache.py ``FeedLineage``: ``introduced`` and ``dead``
+    on every entry ``_apply_deltas`` writes, whatever made it."""
+    c, cache, table = rig["c"], rig["cache"], rig["table"]
+    cache.TAIL_MERGE_ROWS = 4       # (a mid insert further in repacks)
+    handles, batch, want, *before = JOURNAL_CAUSES[cause]
+
+    def muts(ops):
+        return [("delete", _row_key(table, op[1]), None)
+                if op[0] == "delete"
+                else _mut(table, op[1], {2: op[2] % 7, 3: op[2]})
+                for op in ops]
+
+    c.txn_write(muts([_put(h) for h in handles]))
+    ent = _assert_parity(c, cache, table)
+    if before:
+        c.txn_write(muts(before[0]))
+        ent = _assert_parity(c, cache, table)
+    v0, view0, compactions = ent.feed_version, _view(ent), cache.compactions
+    c.txn_write(muts(batch))
+    ent = _assert_parity(c, cache, table)
+    assert cache.misses == 1, "the batch must ride the delta path"
+    entry, = ent.feed_lineage.since(v0, until=ent.feed_version)
+    _assert_entry_says_what_the_batch_did(view0, _view(ent), entry)
+    assert entry["structural"] == want["structural"]
+    assert entry.get("dead") == want["dead"]
+    assert sorted(_introduced(entry)) == want["wrote"]
+    assert (cache.compactions > compactions) == (want["dead"] is None and
+                                                 cause != "revive")
+    assert cache.tail_merges == (cause == "merge into the tail")
+
+
+def test_a_lock_only_batch_still_journals_nothing(rig):
+    c, cache, table = rig["c"], rig["cache"], rig["table"]
+    c.txn_write([_mut(table, h, {2: h, 3: h}) for h in range(8)])
+    ent = _assert_parity(c, cache, table)
+    v0 = ent.feed_version
+    st = _storage(c)
+    key = _row_key(table, 2)
+    lock_ts = c.pd.tso()
+    st.sched_txn_command(cmds.Prewrite(
+        [Mutation("put", key, encode_row({2: 9, 3: 9}))], key, lock_ts))
+    st.sched_txn_command(cmds.Rollback([key], lock_ts))
+    ent = _assert_parity(c, cache, table)
+    assert cache.deltas >= 1 and cache.misses == 1
+    assert ent.feed_version == v0 and ent.feed_lineage.since(v0) == []

@@ -240,6 +240,8 @@ def test_every_read_answers_the_table_as_of_its_tso(store, kind, params):
     first = refresh_and_read(store, kind, params)       # cold: builds
     assert first["ok"], first
     feed0, cache0 = feed_counts(store), health(store)["copr_cache"]
+    memo0 = health(store)["device_mesh"]["memo"]
+    derives0 = health(store)["tracing"]["phases"]["host_derive"]["count"]
     recs = [refresh_and_read(store, kind, params) for _ in range(6)]
     assert all(r["ok"] for r in recs), [r["labels"] for r in recs]
     assert all(r["labels"]["cop_tasks"] == str(N) for r in recs)
@@ -264,7 +266,17 @@ def test_every_read_answers_the_table_as_of_its_tso(store, kind, params):
     assert cache1["deltas"] - cache0["deltas"] == 12
     assert cache1["rebuilds"] == cache0["rebuilds"]
     assert cache1["misses"] == cache0["misses"]
+    # both written lines' derived records rolled across every write:
+    # no constant derived again, the head's host planes cut to the rows
+    # its tombstones left (the tail's dropped by its first append)
+    memo1 = health(store)["device_mesh"]["memo"]
+    assert memo1["kept"] - memo0["kept"] == 12
+    assert memo1["dropped"] == memo0["dropped"]
+    assert memo1["host_planes"]["cut"] - memo0["host_planes"]["cut"] == 6
+    assert memo1["host_planes"]["dropped"] - \
+        memo0["host_planes"]["dropped"] <= 1
     phases = health(store)["tracing"]["phases"]
+    assert phases["host_derive"]["count"] == derives0
     assert phases["feed_rebuild"]["count"] >= 6
     assert phases["feed_patch"]["count"] >= 6
     assert phases["delta_apply"]["count"] >= 12

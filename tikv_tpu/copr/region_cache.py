@@ -614,6 +614,32 @@ class FeedLineage:
     any ``structural`` patch (repack, compaction, tombstones pending)
     means the feed must re-upload from the logical view instead of
     patching.
+
+    An entry (``RegionColumnarCache._apply_deltas`` step 6) says what the
+    batch did.  For the device feed: ``structural``, ``n`` (the line's
+    physical rows after it, tombstones included) and, on an entry that
+    is not structural, ``spans`` (the row spans to write over).  For
+    what the host derived from the line's rows (device/feed.py
+    ``roll_derived``), on every entry:
+
+    - ``introduced``: the rows whose VALUES the batch wrote (updates,
+      revives, inserts; a repack's inserts too), as dicts of
+      ``handles`` and ``cols`` ``{col_id: (values, validity)}`` scaled
+      as the line holds them.  A non-structural entry's ``spans`` are
+      this (the same dicts); a delete-only batch carries it empty.
+    - ``dead``: the positions the batch tombstoned, ascending, in the
+      numbering of the line's VIEW before the entry (its live rows in
+      handle order: what a scan returns, what a feed and the host
+      planes are laid out by), and ``live``, the view's rows after it.
+      Present where every row the batch did not write is where it
+      was in the view, less the rows of ``dead`` before it (a
+      delete-only entry's view is the view before it less those rows);
+      absent where the batch renumbered the view otherwise (a repack: a
+      mid insert or the slack run out; compaction; a revived
+      tombstone): then nothing positional survives.
+
+    An entry WITHOUT ``introduced`` (made by hand, or by an older
+    writer) says nothing of what it did: absent is never read as empty.
     """
 
     __slots__ = ("version", "_base", "_patches", "_max", "_mu",
@@ -797,6 +823,23 @@ class _LineState:
                 return None
             out[cid] = v
         return out
+
+    def rows_of(self, written) -> dict:
+        """``written`` ([(handle, payload)], payloads scaled) as a
+        journal entry carries rows: ``handles`` and ``cols``
+        ``{col_id: (values, validity)}`` at the line's dtypes, a NULL as
+        ``_set_row`` stores it."""
+        k = len(written)
+        cols = {cid: (np.empty(k, dtype=vals.dtype), np.empty(k, np.bool_))
+                for cid, (vals, _valid) in self.cols.items()}
+        for i, (_h, payload) in enumerate(written):
+            for cid, (v, ok) in self._payload_cols(payload).items():
+                vals, valid = cols[cid]
+                vals[i] = v if ok else \
+                    (b"" if vals.dtype == object else 0)
+                valid[i] = ok
+        return {"handles": np.fromiter((h for h, _p in written), np.int64, k),
+                "cols": cols}
 
     def _cow_columns(self) -> None:
         for cid, bufs in self.cols.items():
@@ -1707,6 +1750,15 @@ class RegionColumnarCache:
         n0 = state.n
         patch_spans: list = []
         structural = False
+        # what the journal entry says the batch did (step 6): the rows
+        # it wrote, and the view positions of the rows it tombstoned
+        # (None once the view is renumbered otherwise)
+        written = [(int(state.handles[pos]), payload)
+                   for pos, payload in updates + revives] + inserts
+        dead = None if revives else tuple(
+            pos if state.alive is None else
+            int(np.count_nonzero(state.alive[:pos]))
+            for pos in sorted(deletes))
 
         # 3. inserts: slack append when strictly increasing past the
         #    current max handle; a merge into the line's TAIL where they
@@ -1750,6 +1802,7 @@ class RegionColumnarCache:
             state._repack(inserts)
             self.compactions += 1
             structural = True
+            dead = None
         else:
             if updates or revives:
                 state._cow_columns()
@@ -1785,6 +1838,7 @@ class RegionColumnarCache:
                 state._repack([])
                 self.compactions += 1
                 structural = True
+                dead = None
 
         if state.alive is not None and state.n_dead == 0:
             # every tombstone was revived: drop the mask so scans are
@@ -1807,9 +1861,13 @@ class RegionColumnarCache:
         #    the same generation, so the feed, the request memos and the
         #    prepared launches all still stand
         if not (updates or revives or deletes or inserts or structural):
-            pass
-        elif structural or state.alive is not None:
-            state.lineage.record({"structural": True, "n": state.n})
+            return state.publish(), min_data_ts
+        entry = {"n": state.n, "live": state.n - state.n_dead}
+        if dead is not None:
+            entry["dead"] = dead
+        if structural or state.alive is not None:
+            entry.update(structural=True, introduced=[
+                state.rows_of(written)] if written else [])
         else:
             spans = []
             for lo, hi in patch_spans:
@@ -1820,8 +1878,10 @@ class RegionColumnarCache:
                                    bufs[1][lo:hi].copy())
                              for cid, bufs in state.cols.items()},
                 })
-            state.lineage.record({"structural": False, "n": state.n,
-                                  "spans": spans})
+            # (the spans ARE the rows written, a few rows of the line
+            # between two updates beside them)
+            entry.update(structural=False, spans=spans, introduced=spans)
+        state.lineage.record(entry)
         return state.publish(), min_data_ts
 
     @staticmethod

@@ -63,10 +63,9 @@ from ..ops.agg import (
 from ..parallel import ROW_AXES
 from ..utils import trace
 from . import lowering, pallas_hash
-from .feed import value_plane_index
+from .feed import arg_byte_planes, bare_int_refs, value_plane_index
 from .kernels import (
     build_layouts,
-    int_planes_needed,
     make_planes,
     matmul_supported,
     named_program,
@@ -83,7 +82,6 @@ from .request import (
     _Pending,
     _Plan,
     _Prepared,
-    _rpn_col_indices,
 )
 from .selection import _next_pow2
 
@@ -462,41 +460,17 @@ class DeviceAggregator:
             bounds.append((lo, int(kv.max()) - lo + 1 if n else 1))
         return tuple(bounds)
 
-    def _arg_nbytes(self, plan: _Plan, host_cols, n: int) -> tuple:
-        """Byte-plane count per aggregate arg for the MXU int path.
-
-        Plain column refs use the column's actual value range (host
-        min/max, vectorized); computed expressions use the device dtype
-        width (int arithmetic wraps in-dtype on device — documented
-        deviation, expr/functions.py), but a LOWERED plan's: there
-        ``lowering`` proves each argument's interval from the columns'
-        bounds (it proved the plan exact from the same), so a limb
-        product of 21 bits rides three planes, not four."""
-        proven = {}
-        if plan.lowered:
-            proven = lowering.agg_intervals(
-                plan, [(int(v.min()), int(v.max())) if v.size else (0, 0)
-                       for v, _ok in host_cols],
-                [str(v.dtype) for v, _ok in host_cols])
-        out = []
-        for j, r in enumerate(plan.agg_rpns):
-            if r is None or r.ret_type is EvalType.REAL:
-                out.append(0)
-                continue
-            nodes = r.nodes
-            if len(nodes) == 1 and isinstance(nodes[0], RpnColumnRef):
-                v, ok = host_cols[nodes[0].col_idx]
-                if v.size:
-                    out.append(int_planes_needed(int(v.min()), int(v.max())))
-                else:
-                    out.append(1)
-            elif j in proven:
-                out.append(int_planes_needed(*proven[j]))
-            else:
-                widths = [host_cols[i][0].dtype.itemsize
-                          for i in _rpn_col_indices(r)] or [4]
-                out.append(max(widths))
-        return tuple(out)
+    @staticmethod
+    def _arg_nbytes(plan: _Plan, host_cols, n: int) -> tuple:
+        """``feed.arg_byte_planes`` over the bounds of these host planes
+        (host min/max, vectorized: of a lowered plan's every column, else
+        of the columns an argument is a bare reference to)."""
+        bare = bare_int_refs(plan)
+        bounds = [None if not (plan.lowered or i in bare) else
+                  (int(v.min()), int(v.max())) if v.size else (0, 0)
+                  for i, (v, _ok) in enumerate(host_cols)]
+        return arg_byte_planes(plan, bounds,
+                               [str(v.dtype) for v, _ok in host_cols])
 
     def _arg_ok_is_mask(self, plan, feed) -> list:
         """Per-agg flag: the arg's validity provably equals the row mask

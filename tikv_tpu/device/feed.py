@@ -5,7 +5,9 @@ kept across requests (the region-cache-engine analog).
 feed under its dispatch lock; the supervisor scrubs, moves and splits
 resident feeds through the same store.  Top to bottom: the FORMAT (how
 a column becomes a plane, where its planes lie in ``flat``, the
-request's host halves: ``HostPlanes``), then ``FeedStore``: the shape,
+request's host halves: ``HostPlanes``), the DERIVED RECORD (what a
+request memo holds of a line's rows, and the one function that rolls it
+across a write: ``roll_derived``), then ``FeedStore``: the shape,
 the one constructor, the build ladder (``get``), the patch, the
 digests, the move between slices and the split of a region's line.
 
@@ -40,10 +42,12 @@ from jax import lax
 from ..copr.dag import TableScanDesc
 from ..datatype import EvalType
 from ..datatype.tile import _device_dtype, code_plane, date_plane
+from ..expr.eval import eval_rpn
+from ..expr.rpn import RpnColumnRef
 from ..utils import tracker
 from . import lowering
-from .kernels import named_program
-from .request import _FallbackToHost, _fp_degrade
+from .kernels import int_planes_needed, named_program
+from .request import _FallbackToHost, _fp_degrade, _rpn_col_indices
 
 # same-width unsigned views for bit-exact digest/corruption bitcasts
 _UINT_BY_ITEMSIZE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
@@ -113,6 +117,14 @@ def fits_dtype(vals: np.ndarray, valid, dt: np.dtype) -> bool:
         return 0 <= lo and hi < (1 << 63)
     info = np.iinfo(dt)
     return info.min <= lo and hi <= info.max
+
+
+def positional(scan) -> bool:
+    """Do a line's row positions map straight onto the rows of a feed
+    (and of the host planes) of this scan?  Only an ascending table scan
+    (index scans re-sort, desc scans reverse)."""
+    return isinstance(scan, TableScanDesc) and \
+        not getattr(scan, "desc", False)
 
 
 def value_plane_index(null_flags) -> list:
@@ -187,7 +199,8 @@ class HostPlanes:
                 memo["dtypes"] = meta["dtypes"]
                 memo["limbs"] = meta.get("limbs", ())
             else:
-                self._derive()
+                with tracker.phase("host_derive"):
+                    self._derive()
         return memo["dtypes"]
 
     @property
@@ -260,6 +273,10 @@ class HostPlanes:
         if self.fresh():
             self.meta["dtypes"] = memo["dtypes"]
             self.meta["limbs"] = limbs
+            if plan.lowered:
+                # what the proof stood on: ``roll_derived`` widens them
+                # by the rows a write introduces and proves it again
+                self.meta["bounds"] = tuple(bounds)
 
     def stream(self):
         """Yield the pairs one column at a time, building the memo
@@ -302,6 +319,226 @@ class HostPlanes:
         writes over a widened span."""
         col = self.get_batch().columns[self.plan.used_cols[pos]]
         return plane_values(kind, col.values[lo:hi]), col.validity[lo:hi]
+
+
+# ------------------------------------------------------ the derived record
+#
+# What a request memo (``DeviceRunner._request_meta``) holds of a line's
+# ROWS is one record, these keys of it: the ``bounds`` of each used
+# column's plane values (a lowered plan's: what its proofs stand on),
+# the planes' ``dtypes``, the ``limbs`` ``lowering.fit`` asks for, the
+# GROUP BY key's grid and the aggregates' byte-plane widths
+# (``hash_bounds`` = (base, span, widths), a composite key's
+# ``key_bounds``, ``simple_arg_nbytes``), and the host planes
+# (``host_cols``).  ``HostPlanes`` and the run bodies of aggregate.py
+# write them, each when it is first asked for; ``roll_derived`` alone
+# carries them across a write.
+
+DERIVED = ("bounds", "dtypes", "limbs", "hash_bounds", "key_bounds",
+           "simple_arg_nbytes", "host_cols")
+
+
+def _bare_int_ref(rpn) -> Optional[int]:
+    """The used column an aggregate's integer argument is a bare
+    reference to, else None."""
+    if rpn is None or rpn.ret_type is EvalType.REAL or \
+            len(rpn.nodes) != 1 or \
+            not isinstance(rpn.nodes[0], RpnColumnRef):
+        return None
+    return rpn.nodes[0].col_idx
+
+
+def bare_int_refs(plan) -> set:
+    """The used columns ``arg_byte_planes`` reads a bound of whatever
+    the plan: those an aggregate's argument is a bare reference to."""
+    return {c for c in map(_bare_int_ref, plan.agg_rpns) if c is not None}
+
+
+def arg_byte_planes(plan, bounds, dtypes) -> tuple:
+    """Byte-plane count per aggregate argument for the MXU int path,
+    over a feed whose used column ``i`` holds plane values inside
+    ``bounds[i]`` at ``dtypes[i]`` (a bound is read for a lowered plan's
+    columns and for a column an argument is a bare reference to).
+
+    Plain column refs use the column's value range; computed
+    expressions use the device dtype width (int arithmetic wraps
+    in-dtype on device — documented deviation, expr/functions.py), but
+    a LOWERED plan's: there ``lowering`` proves each argument's interval
+    from the columns' bounds (it proved the plan exact from the same),
+    so a limb product of 21 bits rides three planes, not four."""
+    proven = lowering.agg_intervals(plan, bounds, dtypes) \
+        if plan.lowered else {}
+    out = []
+    for j, r in enumerate(plan.agg_rpns):
+        if r is None or r.ret_type is EvalType.REAL:
+            out.append(0)
+        elif _bare_int_ref(r) is not None:
+            out.append(int_planes_needed(*bounds[_bare_int_ref(r)]))
+        elif j in proven:
+            out.append(int_planes_needed(*proven[j]))
+        else:
+            out.append(max([np.dtype(dtypes[i]).itemsize
+                            for i in _rpn_col_indices(r)] or [4]))
+    return tuple(out)
+
+
+def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
+                 recorder) -> None:
+    """Roll the derived record in ``meta`` across ``patches``, the
+    journal entries of a lineage gap (``FeedLineage.since``; None: the
+    journal no longer covers it), from the rows the entries INTRODUCED:
+    every constant the record keeps is proved again for them, and where
+    a proof fails, or an entry does not say what it did, the whole
+    record drops and the next request derives it from the line (correct,
+    and as dear as before this function: ``HostPlanes._derive``).
+
+    Deletes never enter: a bound is an upper bound and every constant is
+    valid for any data inside it, so a kept record may cut more byte
+    planes than a fresh derive of the shrunken line would, never fewer.
+    ``count_rows()`` is the request's row count after the gap (it enters
+    ``lowering.fit``'s sum proof), ``limb_variant(plan, limbs)`` the
+    plan a feed asking for limbs is served by.  Counted on ``recorder``
+    (/health ``device_mesh.memo``): kept, or dropped by its cause."""
+    if "dtypes" not in meta:
+        _drop(meta)
+        return                  # nothing was derived yet
+    if patches is None or any("introduced" not in p for p in patches) or \
+            not plan.lowered and any(p.get("structural") for p in patches):
+        # (a plan that is not lowered keeps its constants across row
+        # patches alone, as it always has)
+        cause = "unknown"
+    else:
+        n = meta["n_rows"] = count_rows()
+        cause = _disproved(meta, plan, [rows for p in patches
+                                        for rows in p["introduced"]],
+                           n, limb_variant)
+    planes, cut = meta.pop("host_cols", None), None
+    if cause is not None:
+        _drop(meta)
+    elif planes is not None:
+        # the host planes follow where the feed will be built from them
+        cut = _cut_dead(planes, plan, patches, n)
+        if cut is not None:
+            meta["host_cols"] = cut
+    recorder.note_memo(cause, planes_cut=cut is not None,
+                       planes_dropped=planes is not None and cut is None)
+
+
+def _drop(meta: dict) -> None:
+    for k in DERIVED:
+        meta.pop(k, None)
+
+
+def _disproved(meta: dict, plan, introduced, n: int,
+               limb_variant) -> Optional[str]:
+    """Which of the record's constants the ``introduced`` rows (journal
+    dicts of ``handles`` / ``cols``) leave → the /health cause, or None
+    where every one is proved again; then a lowered plan's ``bounds``
+    are widened in ``meta``."""
+    infos = [plan.scan.columns[ci] for ci in plan.used_cols]
+    kinds, dtypes = plane_kinds(plan), meta["dtypes"]
+    # the rows as the plan's rpns see them: plane values (a DATE column
+    # on the date plane shifted, a CHAR column as its codes)
+    rows = [list(span_planes(r, infos, kinds)) for r in introduced]
+    for row in rows:
+        for (vals, valid), ds in zip(row, dtypes):
+            if vals is None:
+                return "code"
+            if not fits_dtype(vals, valid, np.dtype(ds)):
+                return "dtype"
+    widths = [meta[k][-1] if k == "hash_bounds" else meta[k]
+              for k in ("hash_bounds", "simple_arg_nbytes") if k in meta]
+    if "hash_bounds" in meta:
+        # every introduced key inside its (base, span); a composite
+        # key's each inside its own, and never NULL (the grid has one
+        # NULL slot: aggregate.py ``_key_bounds``)
+        base, span, _w = meta["hash_bounds"]
+        grid = meta.get("key_bounds") if len(plan.key_rpns) > 1 \
+            else ((base, span),)
+        if grid is None:
+            return "key"
+        for row in rows:
+            m = len(row[0][0])
+            pairs = [(v, np.ones(m, np.bool_) if ok is None else ok)
+                     for v, ok in row]
+            for rpn, (lo, wid) in zip(plan.key_rpns, grid):
+                kv, km = eval_rpn(rpn, pairs, m, np)
+                kv = np.broadcast_to(kv, (m,))
+                km = np.broadcast_to(km, (m,))
+                if len(grid) > 1 and not km.all():
+                    return "null_key"
+                live = kv[km]
+                if live.size and (int(live.min()) < lo or
+                                  int(live.max()) >= lo + wid):
+                    return "key"
+    if not plan.lowered:
+        # a bare column's planes follow its values; a computed
+        # argument's its dtype width, which was held above
+        for kept in widths:
+            for r, planes in zip(plan.agg_rpns, kept):
+                if _bare_int_ref(r) is None:
+                    continue
+                for row in rows:
+                    vals, valid = row[_bare_int_ref(r)]
+                    live = vals if valid is None or valid.all() \
+                        else vals[valid]
+                    if live.size and int_planes_needed(
+                            int(live.min()), int(live.max())) > planes:
+                        return "widths"
+        return None
+    if "bounds" not in meta:
+        return "unknown"
+    bounds = list(meta["bounds"])
+    for row in rows:
+        for i, (vals, _valid) in enumerate(row):
+            if vals.size:
+                bounds[i] = (min(bounds[i][0], int(vals.min())),
+                             max(bounds[i][1], int(vals.max())))
+    # interval arithmetic over the plan, no data touched: the limb split
+    # and every byte-plane width from the widened bounds and the new n
+    limbs = lowering.fit(plan, bounds, dtypes, n)
+    if limbs != meta["limbs"]:
+        return "limbs"
+    proved = arg_byte_planes(limb_variant(plan, limbs) if limbs else plan,
+                             bounds, dtypes)
+    if any(kept != proved for kept in widths):
+        return "widths"
+    meta["bounds"] = tuple(bounds)
+    return None
+
+
+def _cut_dead(host_cols: list, plan, patches, n: int) -> Optional[list]:
+    """The host planes of the generation before ``patches`` cut to the
+    rows the entries left, where every entry is delete-only and says
+    which (``dead``): what ``FeedStore.get``'s rebuild after a tombstone
+    then streams in place of planes made again from the logical view and
+    its Python ``bytes``; None (they drop, as after any other write: a
+    patched feed reads ``HostPlanes.window`` and needs none) anywhere
+    else.  Until a tombstone is a device patch (ROADMAP S7 (a)), which
+    retires this cut and keeps ``dead``."""
+    rows = len(host_cols[0][0]) if host_cols else -1
+    if not positional(plan.scan):
+        return None
+    keep = None
+    for p in patches:
+        dead = p.get("dead")
+        # (planes of part of the line's rows are not laid out by its
+        # view's positions: a ranged request's)
+        if dead is None or p["introduced"] or \
+                rows != p["live"] + len(dead):
+            return None
+        if dead:
+            if keep is None:
+                keep = np.ones(rows, np.bool_)
+                keep[list(dead)] = False
+            else:
+                keep[np.flatnonzero(keep)[list(dead)]] = False
+        rows = p["live"]
+    if rows != n:
+        return None
+    if keep is None:
+        return host_cols
+    return [(v[keep], ok[keep]) for v, ok in host_cols]
 
 
 # -------------------------------------------------------------- the store
@@ -438,11 +675,8 @@ class FeedStore:
         scan, used_infos, dtypes = planes.plan.scan, planes.infos, \
             planes.dtypes()
         feed_key = (tuple(i.col_id for i in used_infos), dtypes, ranges)
-        # patching maps journal row positions straight onto feed
-        # rows — only sound for an ascending table scan (index
-        # scans re-sort, desc scans reverse)
-        positional = isinstance(scan, TableScanDesc) and \
-            not getattr(scan, "desc", False)
+        # (patching maps journal row positions straight onto feed rows)
+        by_position = positional(scan)
         arena = self._runner._arena
         cache = anc = None
         if hasattr(storage, "scan_columns"):
@@ -467,7 +701,7 @@ class FeedStore:
                 # cold re-upload — bucketed padding keeps n_pad (the
                 # compile class) stable across small deltas
                 rebuild = self._try_patch_feed(
-                    feed, lineage, planes, n, req_v) if positional \
+                    feed, lineage, planes, n, req_v) if by_position \
                     else "structural"
                 if rebuild is None:
                     tracker.label("device_feed", "patch")
@@ -478,7 +712,7 @@ class FeedStore:
             """A feed this ladder just made, into its line."""
             if lineage is not None:
                 feed["lineage_v"] = req_v
-            if positional:
+            if by_position:
                 # which planes carry the pk-handle column (sourced from
                 # state.handles, not state.cols): a device-side region
                 # split re-anchors child digests to host truth by it
@@ -500,7 +734,7 @@ class FeedStore:
         # stash was digest-verified against the child's host truth at
         # split time, so serving it is as safe as serving a scrubbed
         # resident feed.
-        if lineage is not None and positional and cache is not None:
+        if lineage is not None and by_position and cache is not None:
             feed = self.take_split_feed(lineage, feed_key, n)
             # (a child that moved past the stash where the journal
             # cannot bridge it falls through to the upload)
@@ -519,7 +753,7 @@ class FeedStore:
         # to the plain upload below, which is always correct.
         if lineage is not None and \
                 getattr(lineage, "cold_bundle", None) is not None:
-            if positional and cache is not None and \
+            if by_position and cache is not None and \
                     not any(planes.kinds):
                 # (the resolver gathers the columns as they lie: a date
                 # or code plane is cut from the host mirror instead)

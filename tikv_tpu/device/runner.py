@@ -80,14 +80,13 @@ from ..datatype import Column, ColumnBatch, EvalType
 from ..datatype.tile import _device_dtype, code_width
 from ..expr import build_rpn
 from ..expr.eval import eval_rpn
-from ..expr.rpn import RpnColumnRef, RpnExpression
+from ..expr.rpn import RpnExpression
 from ..ops.agg import AggSpec
 from ..parallel import ROW_AXES, make_mesh, num_shards, row_sharding
 from . import lowering, pallas_hash
 from .aggregate import DeviceAggregator
 from .feed import (
-    FeedStore, HostPlanes, anchor as feed_anchor, fits_dtype, generation,
-    plane_kinds, span_planes,
+    FeedStore, HostPlanes, anchor as feed_anchor, generation, roll_derived,
 )
 from .kernels import named_program
 from .request import (
@@ -999,7 +998,11 @@ class DeviceRunner:
         a write left behind, patched forward (``patches``, the dirty
         ``patch_rows``, the widened windows by bucket length) or built
         again, by cause (``rebuilds_after_delta``), and both together
-        (``after_delta``: ``FlightRecorder.feed_counts``); ``lanes``:
+        (``after_delta``: ``FlightRecorder.feed_counts``); ``memo``:
+        request memos whose derived record was rolled across a write,
+        ``kept`` or ``dropped`` by cause, and their ``host_planes``
+        (``FlightRecorder.memo_counts``: feed.py ``roll_derived``);
+        ``lanes``:
         this runner's
         launches of lanes, ``DeviceAggregator.lane_stats``; all
         monotone), the resident
@@ -1024,6 +1027,7 @@ class DeviceRunner:
                "agg_params": self.flight_recorder.agg_param_counts(),
                "prepared": self.flight_recorder.prepared_counts(),
                "feed": self.flight_recorder.feed_counts(),
+               "memo": self.flight_recorder.memo_counts(),
                "lanes": self.lane_stats(),
                "submesh_rebuilds": self._submesh_rebuilds,
                "feed_bytes_per_shard": max(
@@ -2252,6 +2256,26 @@ class DeviceRunner:
                 dag = dag.over_ranges(())
 
         meta = self._request_meta(storage, self._meta_key(dag, plan))
+        memo: dict = {}
+
+        def get_batch():
+            """Host ColumnBatch for this scan (built at most once; the
+            warm agg path never needs it — the feed is HBM-resident and
+            the row count is memoized)."""
+            if "batch" not in memo:
+                memo["batch"] = self._scan_batch(dag, plan, storage)
+            return memo["batch"]
+
+        def count_rows() -> int:
+            if isinstance(plan.scan, TableScanDesc) and \
+                    hasattr(storage, "count_rows") and \
+                    hasattr(storage, "scan_columns"):
+                # row count without materializing the batch — the warm
+                # delta path must not pay a full columnar gather just
+                # to re-learn n
+                return storage.count_rows(dag.ranges)
+            return get_batch().num_rows
+
         # (every shared-memo interaction pins to the request's generation)
         lineage, req_v = generation(storage)
         if lineage is not None:
@@ -2259,7 +2283,8 @@ class DeviceRunner:
             if mv < req_v:
                 # the memo lags this snapshot: carry what provably
                 # survives the gap, drop the rest
-                self._refresh_meta(meta, lineage, plan, mv, req_v)
+                self._refresh_meta(meta, lineage, plan, mv, req_v,
+                                   count_rows)
             elif mv > req_v:
                 # an older-generation read (history serve) must not
                 # consume or mutate the newer shared memo: go local
@@ -2273,31 +2298,13 @@ class DeviceRunner:
         # reflects req_v — a request (or deferred finalize) racing a
         # newer generation's refresh must not repopulate the shared
         # memo with stale data; stale results stay request-local
-        memo: dict = {}
-
         def memo_fresh() -> bool:
             return req_v is None or meta.get("lineage_v") == req_v
-
-        def get_batch():
-            """Host ColumnBatch for this scan (built at most once; the
-            warm agg path never needs it — the feed is HBM-resident and
-            the row count is memoized)."""
-            if "batch" not in memo:
-                memo["batch"] = self._scan_batch(dag, plan, storage)
-            return memo["batch"]
 
         if "n_rows" in meta and memo_fresh():
             n = meta["n_rows"]
         else:
-            if isinstance(plan.scan, TableScanDesc) and \
-                    hasattr(storage, "count_rows") and \
-                    hasattr(storage, "scan_columns"):
-                # row count without materializing the batch — the warm
-                # delta path must not pay a full columnar gather just
-                # to re-learn n
-                n = storage.count_rows(dag.ranges)
-            else:
-                n = get_batch().num_rows
+            n = count_rows()
             if memo_fresh():
                 meta["n_rows"] = n
         if n == 0:
@@ -2546,21 +2553,20 @@ class DeviceRunner:
         return per_storage.setdefault(("meta", meta_key), {})
 
     def _refresh_meta(self, meta: dict, lineage, plan, from_v: int,
-                      to_v: int) -> None:
+                      to_v: int, count_rows) -> None:
         """Roll a request memo forward across a feed-lineage gap.
 
-        Volatile fields (row count, host column copies) always drop.
-        Derived kernel constants — device dtypes, hash key bounds,
-        byte-plane widths — survive only when every dirty row provably
-        stays inside them, because each is baked into a compiled kernel
-        (capacity, plane count) or a value transform (dtype narrowing);
-        keeping a violated constant would corrupt results, dropping a
+        The row count drops (``count_rows`` re-learns it where the
+        record's proofs ask).  The derived record — device dtypes, limbs,
+        hash key bounds, byte-plane widths, the host planes — is rolled
+        by ``feed.roll_derived`` from the rows the journal says the gap
+        introduced: each constant is baked into a compiled kernel
+        (capacity, plane count) or a value transform (dtype narrowing),
+        so keeping a violated one would corrupt results while dropping a
         valid one only costs a re-derivation (still no MVCC rebuild).
         Sparse key recodes always drop: new rows have no slot ids.
         """
-        patches = lineage.since(from_v, until=to_v)
         meta.pop("n_rows", None)
-        meta.pop("host_cols", None)
         meta.pop("sparse_slots", None)
         if meta.pop("prepared", None) is not None:
             # (the record, and with it the line's launch class: both
@@ -2568,81 +2574,9 @@ class DeviceRunner:
             self.flight_recorder.note_prepared("refresh")
         meta.pop("key_dense", None)     # (likewise: run_hash)
         meta.pop("key_dense_tiled", None)
-        # (a lowered plan's dtypes stand on ``lowering.fits``'s proof
-        # over the columns' BOUNDS, which new rows may leave while
-        # still fitting the dtype: derive them again)
-        keep = patches is not None and not plan.lowered and \
-            not any(p.get("structural") for p in patches)
-        if keep:
-            used_infos = [plan.scan.columns[ci] for ci in plan.used_cols]
-            spans = [s for p in patches for s in p["spans"]]
-            keep = self._verify_meta_consts(meta, plan, used_infos,
-                                            spans)
-        if not keep:
-            meta.pop("dtypes", None)
-            meta.pop("limbs", None)
-            meta.pop("hash_bounds", None)
-            meta.pop("key_bounds", None)
-            meta.pop("simple_arg_nbytes", None)
+        roll_derived(meta, plan, lineage.since(from_v, until=to_v),
+                     count_rows, self._limb_variant, self.flight_recorder)
         meta["lineage_v"] = to_v
-
-    def _verify_meta_consts(self, meta, plan, used_infos, spans) -> bool:
-        from .kernels import int_planes_needed
-        # the spans' rows as the plan's rpns see them: plane values (a
-        # DATE column on the date plane shifted), of the kinds a feed
-        # built for this memo's plan carries (device/feed.py)
-        rows = [list(span_planes(span, used_infos, plane_kinds(plan)))
-                for span in spans]
-        dtypes = meta.get("dtypes") or (None,) * len(used_infos)
-        if not all(vals is not None and (
-                ds is None or fits_dtype(vals, valid, np.dtype(ds)))
-                for row in rows for (vals, valid), ds in zip(row, dtypes)):
-            return False
-
-        def arg_planes_ok(arg_nbytes) -> bool:
-            for r, planes in zip(plan.agg_rpns, arg_nbytes):
-                if r is None or r.ret_type is EvalType.REAL or \
-                        len(r.nodes) != 1 or \
-                        not isinstance(r.nodes[0], RpnColumnRef):
-                    continue    # computed exprs use dtype widths: stable
-                ci = r.nodes[0].col_idx
-                for row in rows:
-                    vals, valid = row[ci]
-                    live = vals if valid is None or valid.all() \
-                        else vals[valid]
-                    if live.size and int_planes_needed(
-                            int(live.min()), int(live.max())) > planes:
-                        return False
-            return True
-
-        if "hash_bounds" in meta:
-            base, width, arg_nbytes = meta["hash_bounds"]
-            # (a composite key: each key inside its own bounds, and
-            # never NULL)
-            key_bounds = meta.get("key_bounds") \
-                if len(plan.key_rpns) > 1 else ((base, width),)
-            if key_bounds is None:
-                return False
-            for span, row in zip(spans, rows):
-                m = len(span["handles"])
-                pairs = [(v, np.ones(m, np.bool_) if ok is None else ok)
-                         for v, ok in row]
-                for rpn, (lo, wid) in zip(plan.key_rpns, key_bounds):
-                    kv, km = eval_rpn(rpn, pairs, m, np)
-                    kv = np.broadcast_to(kv, (m,))
-                    km = np.broadcast_to(km, (m,))
-                    if len(key_bounds) > 1 and not km.all():
-                        return False
-                    live = kv[km]
-                    if live.size and (int(live.min()) < lo or
-                                      int(live.max()) >= lo + wid):
-                        return False
-            if not arg_planes_ok(arg_nbytes):
-                return False
-        if "simple_arg_nbytes" in meta and \
-                not arg_planes_ok(meta["simple_arg_nbytes"]):
-            return False
-        return True
 
     def _result(self, dag, schema, columns) -> "SelectResult":
         from ..executors.runner import SelectResult

@@ -176,6 +176,16 @@ class FlightRecorder:
         self.prepared_hits = 0
         self.prepared_builds = 0
         self.prepared_drops = {"refresh": 0, "feed": 0, "kernel": 0}
+        # device/feed.py ``roll_derived``: request memos whose derived
+        # record a write was rolled across, kept (every constant proved
+        # again) or dropped, by what the written rows left; and the
+        # host planes such a memo held, cut to the rows tombstones left
+        # or dropped
+        self.memo_kept = 0
+        self.memo_dropped = {"unknown": 0, "dtype": 0, "code": 0,
+                             "null_key": 0, "limbs": 0, "widths": 0,
+                             "key": 0}
+        self.memo_host_planes = {"cut": 0, "dropped": 0}
         # device/feed.py: resident feeds brought forward by a patch
         # after a write, and those built again instead, by cause
         self.feed_patches = 0
@@ -289,6 +299,29 @@ class FlightRecorder:
             return {"hits": self.prepared_hits,
                     "builds": self.prepared_builds,
                     "drops": dict(self.prepared_drops)}
+
+    def note_memo(self, cause: Optional[str], planes_cut: bool = False,
+                  planes_dropped: bool = False) -> None:
+        """A request memo's derived record rolled across a write: kept
+        (``cause`` None) or dropped because an entry did not say what
+        it did (``unknown``) or a written row left a plane's dtype
+        (``dtype``), has a CHAR value without a code (``code``), a NULL
+        in a composite key (``null_key``), moved the limb split
+        (``limbs``), a byte-plane width (``widths``) or the key grid
+        (``key``); and what became of the host planes it held."""
+        with self._mu:
+            if cause is None:
+                self.memo_kept += 1
+            else:
+                self.memo_dropped[cause] += 1
+            self.memo_host_planes["cut"] += planes_cut
+            self.memo_host_planes["dropped"] += planes_dropped
+
+    def memo_counts(self) -> dict:
+        with self._mu:
+            return {"kept": self.memo_kept,
+                    "dropped": dict(self.memo_dropped),
+                    "host_planes": dict(self.memo_host_planes)}
 
     def note_feed_patch(self, rows: int, widths) -> None:
         """A resident feed patched forward: the journal's dirty

@@ -691,7 +691,8 @@ def thread_cpu() -> tuple:
 ANNOTATED = frozenset({
     "dispatcher_idle", "group_dispatch", "d2h_wait", "host_materialize",
     "plan_decode", "snapshot", "columnar_cache", "device_dispatch",
-    "feed_patch", "feed_upload", "feed_rebuild", "delta_apply",
+    "feed_patch", "feed_upload", "feed_rebuild", "host_derive",
+    "delta_apply",
     "resp_serialize",
     "rpc_reply"})
 _ANNOTATION_NAMES = {n: f"copr:{n}" for n in ANNOTATED}

@@ -156,6 +156,11 @@ SPAN_VOCABULARY: dict[str, str] = {
                     "bucket, a value outside the feed's dtypes) built "
                     "again from the line and uploaded: feed_upload's "
                     "work, named apart because it follows a write",
+    "host_derive": "a request's device dtypes derived from the line's "
+                   "rows (feed.py HostPlanes._derive: the code and date "
+                   "planes cut, a lowered plan's bounds and its proof); "
+                   "once a line, and again where a write left what a "
+                   "memo had proved (/health device_mesh.memo dropped)",
     "shard_merge": "host-side merge of per-shard partial agg states",
     "mesh_rebuild": "elastic degrade: re-mint serving on a submesh",
     "feed_migrate": "ICI move of a resident feed between slices "
