@@ -258,6 +258,10 @@ def test_every_read_answers_the_table_as_of_its_tso(store, kind, params):
     assert feed1["patch_rows"] - feed0["patch_rows"] == sum(
         len(e[2]["l_quantity"]) for e in log[2:] if e[1] > 0)
     assert set(feed1["patch_buckets"]) == {"16"}
+    # ONE device program a window: the seven planes' updates and their
+    # digest chain together
+    assert feed1["patch_programs"] - feed0["patch_programs"] == \
+        feed1["patch_windows"] - feed0["patch_windows"] == 6
     rebuilt = {k: feed1["rebuilds_after_delta"][k] -
                feed0["rebuilds_after_delta"][k]
                for k in feed1["rebuilds_after_delta"]}

@@ -85,8 +85,8 @@ def test_single_write_serves_via_delta_path(rig):
         assert td["labels"].get("device_feed") == "patch", td["labels"]
         assert "feed_upload" not in td["phases_ms"]
         assert "feed_patch" in td["phases_ms"]
-        # only the one shared patch updater (and the marks that its
-        # bucket lengths are warm for a plane class) may appear — a
+        # only the one shared patch program (and the marks that its
+        # bucket lengths are warm for a feed's class) may appear — a
         # point write must not mint new kernel compile classes
         minted = len([k for k in device._kernel_cache
                       if not (isinstance(k, tuple) and

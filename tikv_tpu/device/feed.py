@@ -20,7 +20,7 @@ reflects), ``key`` (what its bucket holds it under), ``positional`` /
 
 The store owns no state.  It serves through its runner's, by these
 names and no others: ``_arena``, ``_kernel_cache``, ``_single``,
-``_mesh``, ``_row_sharding``, ``_nshards``, ``_block_local``,
+``_mesh``, ``_row_sharding``, ``_repl``, ``_nshards``, ``_block_local``,
 ``_chunk_override``, ``scrub_digests``, ``_dispatch_mu``,
 ``_sub_runners``, ``flight_recorder`` (its counts of patches and of
 rebuilds after a delta: /health ``device_mesh.feed``).  The runner,
@@ -52,6 +52,23 @@ from .request import _FallbackToHost, _fp_degrade, _rpn_col_indices
 # same-width unsigned views for bit-exact digest/corruption bitcasts
 _UINT_BY_ITEMSIZE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
                      8: jnp.uint64}
+
+
+def _to_bits(x):
+    """A plane's elements as the uint64 a digest sums (traced)."""
+    dt = np.dtype(x.dtype)
+    if dt == np.bool_ or (dt.kind in "iu" and dt.itemsize == 8):
+        # 64-bit ints convert, not bitcast: the wrap mod 2^64 IS the
+        # bit pattern, and the TPU compiler has no 64-bit
+        # bitcast-convert (its X64 rewrite rejects it — found on v5e,
+        # libtpu 0.0.34)
+        return x.astype(jnp.uint64)
+    # narrower ints and float32: bitcast to the same-width unsigned
+    # view, then widen.  (float64 takes this branch too and lowers on
+    # CPU only; feed planes are never float64 — datatype/tile.py
+    # _device_dtype.)
+    return lax.bitcast_convert_type(
+        x, _UINT_BY_ITEMSIZE[dt.itemsize]).astype(jnp.uint64)
 
 
 # ------------------------------------------------------------- the format
@@ -139,10 +156,10 @@ def value_plane_index(null_flags) -> list:
 
 # The lengths, in rows, a patch's span is widened to ON THE HOST before
 # it is sent (a span longer than the last is cut into windows of that):
-# the update program compiles once a (length, plane dtype, n_pad), so a
-# line that takes appends and updates of every length (an order of 1-7
-# lineitems, two sessions' orders between two reads) runs three
-# programs, all built by its first patch, and none compiles later.  The
+# the patch program compiles once a (length, the feed's planes' dtypes,
+# n_pad), so a line that takes appends and updates of every length (an
+# order of 1-7 lineitems, two sessions' orders between two reads) runs
+# three programs, all built by its first patch, and none compiles later.  The
 # rows a span is widened by are sent as the request's snapshot holds
 # them.
 PATCH_BUCKETS = (16, 256, 4096)
@@ -153,6 +170,23 @@ def patch_bucket(rows: int) -> int:
         if rows <= b:
             return b
     return PATCH_BUCKETS[-1]
+
+
+def pack_updates(updates) -> tuple:
+    """A window's per-plane updates (``flat``'s order, one length) as ONE
+    host array a dtype, its rows in that order: every host array of a
+    jitted call is an upload of its own, and each is a place where the
+    dispatcher's thread gives the GIL away."""
+    by: dict = {}
+    for u in updates:
+        by.setdefault(u.dtype, []).append(u)
+    return tuple(np.stack(rows) for rows in by.values())
+
+
+def unpack_updates(planes, packed) -> list:
+    """``pack_updates`` undone for ``planes`` (traced, or host arrays)."""
+    rows = {np.dtype(p.dtype): iter(p) for p in packed}
+    return [next(rows[np.dtype(a.dtype)]) for a in planes]
 
 
 def span_planes(span, used_infos, kinds):
@@ -622,11 +656,12 @@ class FeedStore:
         return feed
 
     def _warm_digest_kernels(self, flat) -> None:
-        """Pre-register the planes' digest kernels now (a cold path) so
-        the warm patch path's incremental digest update mints no new
-        kernel cache entries — compile classes stay churn-stable."""
+        """Pre-register the planes' digest kernels now (a cold path,
+        under the dispatch lock) so the scrubber, which hashes OUTSIDE
+        the lock, mints no kernel cache entries beside request threads
+        — compile classes stay churn-stable."""
         for a in flat:
-            self.range_digest_kernel(a.dtype, a.shape[0])
+            self.digest_kernel(a.dtype, a.shape[0])
 
     def _build_flat(self, host_cols, n: int, kinds) -> dict:
         """One flat padded array per column value; a validity array only
@@ -839,8 +874,10 @@ class FeedStore:
         (``PATCH_BUCKETS``) and that window written whole, so spans of
         every length share a few update programs, and a gap of several
         generations writes each window once, as it stands at ``req_v``.
-        Sharded feeds patch too: GSPMD partitions the update and ``dus``
-        pins the result back to the row sharding."""
+        Every window's updates are gathered first, so a refusal leaves
+        before anything is dispatched; then a window is one program over
+        all the feed's planes (``_patch_program``), a sharded feed's
+        too."""
         patches = lineage.since(feed.get("lineage_v", -1), until=req_v)
         if patches is None or any(p.get("structural") for p in patches):
             return "structural"
@@ -850,9 +887,8 @@ class FeedStore:
         if self.pad_rows(max(n, 1)) != n_pad:
             return "pad"            # row count crossed a pad bucket
         dtypes = planes.dtypes()
-        plane = value_plane_index(feed["null_flags"])
-        flat = list(feed["flat"])
-        digests = list(feed["digests"]) \
+        flat = feed["flat"]
+        digests = feed["digests"] \
             if self._runner.scrub_digests and \
             feed.get("digests") is not None else None
         windows: dict = {}
@@ -867,12 +903,13 @@ class FeedStore:
                     start = max(0, min(at, n_pad - width))
                     windows[start] = max(width, windows.get(start, 0))
         with tracker.phase("feed_patch"):
-            self._warm_patch_programs(flat)
+            # every window's updates first, in ``flat``'s order (a
+            # column's values, then its validity where it has a plane):
+            # a refusal leaves before anything is dispatched
+            sends = []
             for lo, width in sorted(windows.items()):
                 hi = min(lo + width, n)
-                # (the window's three device scalars, once for its planes)
-                at = (jnp.asarray(lo, jnp.int32), jnp.asarray(lo, jnp.int64),
-                      jnp.asarray(lo + width, jnp.int64))
+                updates = []
                 for ci, kind in enumerate(feed["kinds"]):
                     vals, valid = planes.window(ci, kind, lo, hi)
                     dt = np.dtype(dtypes[ci])
@@ -885,81 +922,116 @@ class FeedStore:
                     # (rows past the line's end are the pad: zeros)
                     update = np.zeros(width, dt)
                     update[:hi - lo] = vals
-                    self._patch_plane(digests, flat, plane[ci], update, at)
+                    updates.append(update)
                     if feed["null_flags"][ci]:
                         mask = np.zeros(width, np.bool_)
                         mask[:hi - lo] = valid
-                        self._patch_plane(digests, flat, plane[ci] + 1,
-                                          mask, at)
-        feed["flat"] = tuple(flat)
+                        updates.append(mask)
+                sends.append((pack_updates(updates), np.int32(lo)))
+            if digests is not None and \
+                    not all(isinstance(d, jax.Array) for d in digests):
+                # (a build records the host's ints, a patch leaves
+                # device scalars: the program takes the latter, so put
+                # a build's once, together, where its scalars lie)
+                digests = jax.device_put(
+                    tuple(d if isinstance(d, jax.Array) else np.uint64(d)
+                          for d in digests),
+                    None if self._runner._single else self._runner._repl)
+            self._warm_patch_programs(flat, digests)
+            program = self._patch_program()
+            for updates, lo in sends:
+                flat, digests = program(flat, updates, lo, digests)
+        feed["flat"] = flat
         feed["lineage_v"] = req_v
         if digests is not None:
-            feed["digests"] = tuple(digests)
+            feed["digests"] = digests
             feed["n_live"] = n
         if count:
             self._runner.flight_recorder.note_feed_patch(
-                rows, list(windows.values()))
+                rows, list(windows.values()), programs=len(sends))
         return None
 
-    def _warm_patch_programs(self, flat) -> None:
-        """A line's first patch runs the update program of EVERY bucket
-        once for each of its planes' classes (over the plane as it
-        stands, the result dropped), so that no later span length
-        compiles anything: the classes are (bucket, dtype, n_pad), a
+    def _warm_patch_programs(self, flat, digests) -> None:
+        """A line's first patch runs the patch program of EVERY bucket
+        once for its feed's class (over the planes as they stand, the
+        result dropped), so that no later span length compiles anything:
+        the classes are (bucket, the planes' dtypes in order, n_pad), a
         handful a store, and what they cost is paid by the first read
         after a line's first write."""
         r = self._runner
-        for a in flat:
-            key = ("feed_patch_warm", str(a.dtype), a.shape[0])
-            if key not in r._kernel_cache:
-                for width in {min(b, a.shape[0]) for b in PATCH_BUCKETS}:
-                    self.dus(a, np.zeros(width, a.dtype), 0)
-                r._kernel_cache[key] = True
-
-    def _patch_plane(self, digests, flat, fi: int, update: np.ndarray,
-                     at: tuple) -> None:
-        """Plane ``flat[fi]``'s window patch + INCREMENTAL digest
-        maintenance: ``R' = R - H_span(old device plane) + H_span(new
-        host data)``, ``at`` the window's (start as int32, start and end
-        as int64) on the device.
-        Never re-hashes the whole plane from device state — doing so
-        would launder any HBM corruption that landed since the last
-        scrub into the recorded digest (the recorded value must stay
-        anchored to the host-truth chain, so a pre-existing corruption
-        delta survives arithmetically and the next scrub still catches
-        it, wherever it sits relative to the patched span).  All device
-        scalars — nothing blocks under the dispatch lock."""
-        lo32, lo_arr, hi_arr = at
-        old = flat[fi]
-        new = flat[fi] = self._patch_program()(old, update, lo32)
-        if digests is not None:
-            rng = self.range_digest_kernel(old.dtype, old.shape[0])
-            d_old = rng(old, lo_arr, hi_arr)
-            d_new = rng(new, lo_arr, hi_arr)
-            digests[fi] = jnp.uint64(digests[fi]) - d_old + d_new
-
-    def dus(self, arr, update, lo: int):
-        """Jitted in-place-style slice update (dynamic_update_slice);
-        the start index is traced, so repeated patches at different
-        positions share one compile class per update length.
-        On a sharded feed GSPMD partitions the update and the jit's
-        ``out_shardings`` pins the result to the row sharding in the
-        SAME dispatch — no post-hoc device_put re-lay, so delta churn
-        on a sharded feed costs one small collective-free launch per
-        span, exactly like the single-device path."""
-        return self._patch_program()(arr, update,
-                                     jnp.asarray(lo, jnp.int32))
+        n_pad = flat[0].shape[0]
+        key = ("feed_patch_warm", tuple(str(a.dtype) for a in flat), n_pad)
+        if key not in r._kernel_cache:
+            program = self._patch_program()
+            for width in {min(b, n_pad) for b in PATCH_BUCKETS}:
+                program(flat, pack_updates(
+                    [np.zeros(width, a.dtype) for a in flat]),
+                    np.int32(0), digests)
+            r._kernel_cache[key] = True
 
     def _patch_program(self):
+        """THE patch: ONE jitted program a window over every plane of a
+        feed, ``(planes, their updates packed an array a dtype, the
+        window's start, their digests) → (new planes, new digests)``:
+        each plane's
+        ``dynamic_update_slice`` (the start is traced, so windows at
+        every position share one compile class a bucket length) and its
+        INCREMENTAL digest maintenance, ``R' = R - H_span(old device
+        plane) + H_span(new host data)`` mod 2^64 under the scrub's
+        GLOBAL position weights ``2i + 1`` (``digest_kernel``),
+        summed over the window's rows alone.  Never re-hashes a whole
+        plane from device state — doing so would launder any HBM
+        corruption that landed since the last scrub into the recorded
+        digest (the recorded value must stay anchored to the host-truth
+        chain, so a pre-existing corruption delta survives
+        arithmetically and the next scrub still catches it, wherever it
+        sits relative to the patched span).  All device scalars —
+        nothing blocks under the dispatch lock; ``digests`` None (a
+        store that records none): the updates alone.  The old planes
+        are not donated: launches in flight and prepared records hold
+        them.  On a sharded feed GSPMD partitions the updates and the
+        jit's ``out_shardings`` pin the planes to the row sharding in
+        the SAME dispatch (no post-hoc device_put re-lay), the digests
+        replicated."""
         r = self._runner
         fn = r._kernel_cache.get("feed_patch_fn")
         if fn is None:
-            def feed_patch(a, u, i):
-                return lax.dynamic_update_slice(a, u, (i,))
+            def feed_patch(planes, packed, lo, digests):
+                updates = unpack_updates(planes, packed)
+                new = tuple(lax.dynamic_update_slice(a, u, (lo,))
+                            for a, u in zip(planes, updates))
+                if digests is None:
+                    return new, None
+                width = updates[0].shape[0]
+                w = 2 * (lo.astype(jnp.uint64) +
+                         jnp.arange(width, dtype=jnp.uint64)) + 1
+                return new, tuple(
+                    d - jnp.sum(_to_bits(
+                        lax.dynamic_slice(a, (lo,), (width,))) * w) +
+                    jnp.sum(_to_bits(u) * w)
+                    for a, u, d in zip(planes, updates, digests))
             fn = r._kernel_cache["feed_patch_fn"] = \
                 jax.jit(feed_patch) if r._single else \
-                jax.jit(feed_patch, out_shardings=r._row_sharding)
+                jax.jit(feed_patch,
+                        out_shardings=(r._row_sharding, r._repl))
         return fn
+
+    def dus(self, arr, update, lo: int):
+        """Jitted slice update of ONE plane (dynamic_update_slice; the
+        start index is traced, so updates at different positions share
+        one compile class per update length): what the device MVCC
+        resolve (device/mvcc.py) writes its planes with.  On a sharded
+        plane the jit's ``out_shardings`` pins the result to the row
+        sharding in the same dispatch."""
+        r = self._runner
+        fn = r._kernel_cache.get("feed_dus_fn")
+        if fn is None:
+            def feed_dus(a, u, i):
+                return lax.dynamic_update_slice(a, u, (i,))
+            fn = r._kernel_cache["feed_dus_fn"] = \
+                jax.jit(feed_dus) if r._single else \
+                jax.jit(feed_dus, out_shardings=r._row_sharding)
+        return fn(arr, update, jnp.asarray(lo, jnp.int32))
 
     # -------------------------------------------------------- the digests
     #
@@ -967,42 +1039,23 @@ class FeedStore:
     # digest leaf the scrubber re-hashes resident planes with, and the
     # fault its chaos arm injects.
 
-    def range_digest_kernel(self, dtype, n_pad: int):
-        """Jitted plane digest over rows [lo, hi) with GLOBAL position
-        weights: sum bits(x[i]) * (2i+1) mod 2^64 — the device half of
-        the scrub formula (host half: supervisor.host_plane_digest;
-        the full-prefix digest is just lo=0).  Cached per (dtype,
+    def digest_kernel(self, dtype, n_pad: int):
+        """Jitted digest of a plane's live prefix, rows [0, n), with
+        GLOBAL position weights: sum bits(x[i]) * (2i+1) mod 2^64 — the
+        device half of the scrub formula (host half:
+        supervisor.host_plane_digest; a patch chains the same sum over
+        its window's rows: ``_patch_program``).  Cached per (dtype,
         n_pad) like every other kernel; on a sharded feed GSPMD
         partitions the reduction."""
-        dt = np.dtype(dtype)
-        key = ("scrubr", str(dt), n_pad)
+        key = ("scrubr", str(np.dtype(dtype)), n_pad)
         cache = self._runner._kernel_cache
         fn = cache.get(key)
         if fn is None:
-            if dt == np.bool_ or (dt.kind in "iu" and dt.itemsize == 8):
-                # 64-bit ints convert, not bitcast: the wrap mod 2^64
-                # IS the bit pattern, and the TPU compiler has no
-                # 64-bit bitcast-convert (its X64 rewrite rejects it —
-                # found on v5e, libtpu 0.0.34)
-                to_bits = lambda x: x.astype(jnp.uint64)    # noqa: E731
-            else:
-                # narrower ints and float32: bitcast to the same-width
-                # unsigned view, then widen.  (float64 takes this
-                # branch too and lowers on CPU only; feed planes are
-                # never float64 — datatype/tile.py _device_dtype.)
-                udt = _UINT_BY_ITEMSIZE[dt.itemsize]
-
-                def to_bits(x, _udt=udt):
-                    return lax.bitcast_convert_type(x, _udt) \
-                        .astype(jnp.uint64)
-
-            def feed_digest(x, lo_arr, hi_arr):
+            def feed_digest(x, n_arr):
                 iota = jnp.arange(n_pad, dtype=jnp.uint64)
-                w = 2 * iota + 1
-                sel = (iota >= lo_arr.astype(jnp.uint64)) & \
-                    (iota < hi_arr.astype(jnp.uint64))
-                return jnp.sum(jnp.where(sel, to_bits(x) * w,
-                                         jnp.uint64(0)))
+                return jnp.sum(jnp.where(
+                    iota < n_arr.astype(jnp.uint64),
+                    _to_bits(x) * (2 * iota + 1), jnp.uint64(0)))
 
             fn = cache[key] = jax.jit(feed_digest)
         return fn
@@ -1013,8 +1066,8 @@ class FeedStore:
         LRU scalar cache: the background scrubber calls this OUTSIDE
         the dispatch lock, and the OrderedDict's move_to_end/popitem
         is not safe against concurrent request threads."""
-        return self.range_digest_kernel(arr.dtype, arr.shape[0])(
-            arr, jnp.asarray(0, jnp.int64), jnp.asarray(n, jnp.int64))
+        return self.digest_kernel(arr.dtype, arr.shape[0])(
+            arr, jnp.asarray(n, jnp.int64))
 
     @staticmethod
     def corrupt_resident_plane(feed: dict) -> None:

@@ -996,7 +996,9 @@ class DeviceRunner:
         (``builds``) and dropped, by cause (``drops``:
         ``FlightRecorder.prepared_counts``); ``feed``: resident feeds
         a write left behind, patched forward (``patches``, the dirty
-        ``patch_rows``, the widened windows by bucket length) or built
+        ``patch_rows``, the widened windows by bucket length, their
+        count ``patch_windows`` and the device programs that wrote them,
+        ``patch_programs``: one a window) or built
         again, by cause (``rebuilds_after_delta``), and both together
         (``after_delta``: ``FlightRecorder.feed_counts``); ``memo``:
         request memos whose derived record was rolled across a write,

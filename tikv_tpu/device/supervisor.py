@@ -190,6 +190,8 @@ class FlightRecorder:
         # after a write, and those built again instead, by cause
         self.feed_patches = 0
         self.feed_patch_rows = 0
+        self.feed_patch_windows = 0
+        self.feed_patch_programs = 0
         self.feed_patch_buckets: dict = {}
         self.feed_rebuilds = {"structural": 0, "pad": 0, "dtype": 0,
                               "null": 0}
@@ -323,12 +325,15 @@ class FlightRecorder:
                     "dropped": dict(self.memo_dropped),
                     "host_planes": dict(self.memo_host_planes)}
 
-    def note_feed_patch(self, rows: int, widths) -> None:
+    def note_feed_patch(self, rows: int, widths, programs: int) -> None:
         """A resident feed patched forward: the journal's dirty
-        ``rows``, sent as windows of these bucket ``widths``."""
+        ``rows``, sent as windows of these bucket ``widths`` by that
+        many device ``programs`` (one a window)."""
         with self._mu:
             self.feed_patches += 1
             self.feed_patch_rows += rows
+            self.feed_patch_windows += len(widths)
+            self.feed_patch_programs += programs
             for w in widths:
                 self.feed_patch_buckets[w] = \
                     self.feed_patch_buckets.get(w, 0) + 1
@@ -348,6 +353,8 @@ class FlightRecorder:
             rebuilds = dict(self.feed_rebuilds)
             return {"patches": self.feed_patches,
                     "patch_rows": self.feed_patch_rows,
+                    "patch_windows": self.feed_patch_windows,
+                    "patch_programs": self.feed_patch_programs,
                     "patch_buckets": {str(w): c for w, c in sorted(
                         self.feed_patch_buckets.items())},
                     "rebuilds_after_delta": rebuilds,
