@@ -206,3 +206,131 @@ def test_the_refresh_cell_refuses_an_older_program(missing, monkeypatch):
     with pytest.raises(SystemExit) as e:
         byname.load("requests", "tpch_q1_refresh").require_program()
     assert missing in str(e.value)
+
+
+# ------------------------------------------------ the cell of three plans
+#
+# ``streams-lineitem-sf1-closed4`` (PR 48) is measured on its parent too,
+# traced, under this benchmark's files: a metric that read a counter the
+# parent lacks would leave its line without a declared metric, and
+# ``line.py validate`` refuses such a line (PERF.md section 7, row 1a).
+# So every metric the cell declares reads the window's records or one of
+# these, which the program had before the cell.
+
+STREAMS = "streams-lineitem-sf1-closed4"
+WHAT_A_PARENT_HAS = {
+    "health.copr_cache.misses", "health.coalescer.lane_class_mismatch",
+    "health.fastpath.hit", "health.coprocessor.requests_served",
+    "flight_recorder.launches",
+    "health.tracing.phases.group_dispatch.wall_ms",
+    "health.tracing.process.clock_ms"}
+
+
+def streams_metrics() -> list:
+    return sorted(name for name, spec in entries().items()
+                  if spec["per_layer_entry"]["workloads"] == [STREAMS])
+
+
+@pytest.mark.parametrize("name", streams_metrics())
+def test_a_streams_metric_reads_only_what_its_parent_had(name):
+    spec = entries()[name]
+    reader = os.path.join(ROOT, "benchmark", "readers",
+                          f"{spec['reader']}.py")
+    assert os.path.isfile(reader), reader
+    paths = {v for k, v in spec["args"].items()
+             if k in ("num", "den", "counter")}
+    assert paths <= WHAT_A_PARENT_HAS, (name, paths - WHAT_A_PARENT_HAS)
+    if spec["reader"] == "kind_latency_median":
+        with open(os.path.join(ROOT, "benchmark", "traffic",
+                               f"{STREAMS}.json")) as f:
+            assert spec["args"]["kind"] in json.load(f)["kinds"]
+    else:
+        assert paths, name
+    assert name.endswith(".streams")
+
+
+def test_the_streams_cell_declares_eight_metrics_and_one_waits():
+    """Eight metrics of its own, and ``cache.evictions_per_task``, which
+    reads the counter this PR brings, waits as a file without an entry
+    (PR 36's convention) until a parent has the counter."""
+    assert len(streams_metrics()) == 8
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "cache.evictions_per_task.json")) as f:
+        waiting = json.load(f)
+    assert "per_layer_entry" not in waiting
+    assert waiting["pending_entry"]["workloads"] == [STREAMS]
+    assert waiting["args"]["num"].startswith("health.copr_cache.evictions.")
+    assert "cache.evictions_per_task" not in \
+        {m["name"] for m in manifest["per_layer"]}
+    # the counters it and the hand readings of PERF.md take from /health
+    from tikv_tpu.copr.region_cache import RegionColumnarCache
+    st = RegionColumnarCache().stats()
+    assert st["evictions"] == {"region_lru": 0, "schema_bound": 0}
+    assert (st["resident_lines"], st["regions"],
+            st["schemas_per_region_max"]) == (0, 0, 0)
+
+
+def test_the_streams_cell_runs_the_manifests_files():
+    import sys
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import line
+    manifest = line.load_manifest(ROOT)
+    cell, config_file, traffic_file = line.cell_files(manifest, STREAMS,
+                                                      ROOT)
+    assert os.path.isfile(config_file) and os.path.isfile(traffic_file)
+    assert cell["chips"] == 1
+    with open(config_file) as f:
+        config = json.load(f)
+    assert os.path.isfile(os.path.join(ROOT, config["toml"]))
+    assert config["name"] == cell["config"]
+    want = line.declared(manifest, STREAMS, "per_layer")
+    assert len(want) == 18 and set(streams_metrics()) < set(want)
+    assert set(line.declared(manifest, STREAMS, "end_to_end")) == \
+        {"read_p50_ms", "read_p95_ms", "reads_per_s", "setup_s"}
+    for name in want:
+        assert os.path.isfile(os.path.join(
+            bench, "layer_metrics", f"{name}.json")), name
+
+
+def streams_kind(name: str):
+    import sys
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import byname
+    return byname.load("requests", name)
+
+
+@pytest.mark.parametrize("kind", ["tpch_q1", "tpch_q6", "tpch_q15"])
+def test_a_streams_kind_is_its_original_behind_one_question(kind):
+    """``requests/<kind>_streams.py``: the original's classes, send,
+    reference, digest and check (so its check names), its plan and walk
+    by ``__getattr__``, and a ``prepare`` of its own that asks
+    ``copr/region_cache.py`` for ``SCHEMAS_PER_REGION`` first."""
+    assert ("tikv_tpu.copr.region_cache", "SCHEMAS_PER_REGION") in \
+        capabilities_the_benchmark_asks_for()
+    mine, theirs = streams_kind(f"{kind}_streams"), streams_kind(kind)
+    assert mine.CLASSES == theirs.CLASSES == ("pallas_hash",)
+    for name in ("send", "reference", "digest", "check", "plan"):
+        assert getattr(mine, name).__code__ == getattr(theirs, name).__code__
+    assert mine.VALIDATION == theirs.VALIDATION
+    assert mine.prepare.__code__ != theirs.prepare.__code__
+    from tikv_tpu.copr import region_cache
+    assert region_cache.SCHEMAS_PER_REGION == 8
+    streams_kind("tpch_q1_streams").require_program()
+
+
+@pytest.mark.parametrize("kind", ["tpch_q1", "tpch_q6", "tpch_q15"])
+def test_a_streams_kind_refuses_a_program_that_bounds_lines(kind,
+                                                             monkeypatch):
+    """An older program exits 1 from ``prepare``, before the cell's
+    first read of any kind, and says what it lacks."""
+    from tikv_tpu.copr import region_cache
+    monkeypatch.delattr(region_cache, "SCHEMAS_PER_REGION")
+    with pytest.raises(SystemExit) as e:
+        streams_kind(f"{kind}_streams").prepare(None, None, {})
+    assert "SCHEMAS_PER_REGION" in str(e.value)

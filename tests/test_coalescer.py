@@ -775,8 +775,8 @@ def lanes_of(rig) -> dict:
     st = rig.coal.stats()
     return {k: st[k] for k in (
         "lanes_hist", "multi_lane_launches", "lanes_sum", "groups_merged",
-        "same_lane_merges", "lane_class_mismatch", "unbuilt_fallbacks",
-        "solo_degrade")}
+        "same_lane_merges", "lane_class_mismatch", "launch_classes",
+        "unbuilt_fallbacks", "solo_degrade")}
 
 
 @pytest.mark.parametrize("k,sparse", [(2, False), (3, False), (4, False),
@@ -876,6 +876,7 @@ def test_lanes_at_two_versions_of_one_line(lane_runner):
         assert [sorted(g.rows()) for g in got] == want
         st = lanes_of(rig)
         assert st["groups_merged"] == 1 and st["lanes_hist"]["2"] == 1, st
+        assert st["launch_classes"] == 1, st
     finally:
         rig.close()
 
@@ -912,6 +913,10 @@ def test_other_launch_classes_are_not_fused(lane_runner, what):
         # counted as what kept the two apart (whichever was popped
         # first saw the other behind it)
         assert st["lane_class_mismatch"] == (0 if what == "tile" else 1), st
+        # ... and the launch that left it behind left under ONE class
+        # (the second found nothing waiting and was asked for none)
+        assert st["launch_classes"] == 1 if what != "tile" else \
+            st["launch_classes"] <= 1, st
     finally:
         rig.close()
 

@@ -490,13 +490,16 @@ def test_e2e_many_classes_and_tenants_coexist(rig):
     bucket) plus one class split across two resource groups (same
     const-blind class_key, distinct templates) — all must hit
     concurrently, none may mutually evict.  The columnar cache must
-    hold every table's line at once (default capacity 8 < 10 tables —
-    an evicted line is a GENERATION change, which correctly
-    invalidates its template; that lower-layer bound is not what this
-    test measures)."""
+    hold every table's line at once (the ten tables lie in ONE region,
+    ten lines of it against ``SCHEMAS_PER_REGION`` = 8 — an evicted
+    line is a GENERATION change, which correctly invalidates its
+    template; that lower-layer bound is not what this test measures)."""
     c, node = rig["client"], rig["node"]
     cap0 = node.copr_cache._capacity
     node.copr_cache._capacity = 32
+    from tikv_tpu.copr import region_cache
+    per_region0 = region_cache.SCHEMAS_PER_REGION
+    region_cache.SCHEMAS_PER_REGION = 32
     tables = []
     for i in range(10):
         t = int_table(2, table_id=9700 + i)
@@ -522,6 +525,7 @@ def test_e2e_many_classes_and_tenants_coexist(rig):
         assert st["classes"] >= 11, st
     finally:
         node.copr_cache._capacity = cap0
+        region_cache.SCHEMAS_PER_REGION = per_region0
 
 
 # ------------------------------------------- back-to-back dispatcher

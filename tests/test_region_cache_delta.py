@@ -7,11 +7,14 @@ fallback-to-rebuild paths.
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
 from tikv_tpu.codec.keys import table_record_key
 from tikv_tpu.codec.row import encode_row
+from tikv_tpu.copr import region_cache
 from tikv_tpu.copr.delta import DeltaSink, decode_entry_ops
 from tikv_tpu.copr.region_cache import (
     RegionColumnarCache,
@@ -20,6 +23,8 @@ from tikv_tpu.copr.region_cache import (
 )
 from tikv_tpu.kv.engine import SnapContext
 from tikv_tpu.raftstore import RaftKv
+from tikv_tpu.raftstore.metapb import RegionEpoch
+from tikv_tpu.raftstore.peer import RegionSnapshot
 from tikv_tpu.storage import Storage
 from tikv_tpu.storage.mvcc.errors import KeyIsLocked
 from tikv_tpu.storage.txn import commands as cmds
@@ -524,3 +529,215 @@ def test_a_lock_only_batch_still_journals_nothing(rig):
     ent = _assert_parity(c, cache, table)
     assert cache.deltas >= 1 and cache.misses == 1
     assert ent.feed_version == v0 and ent.feed_lineage.since(v0) == []
+
+
+# ------------------------------------------------ the bound counts REGIONS
+#
+# ``RegionColumnarCache``'s bound counts REGIONS, as the option's
+# documentation tells the operator (``coprocessor.region-cache-capacity``):
+# a region's lines under different scan schemas stay and leave together,
+# the least recently used region first, every dropped line through
+# ``on_line_retired``; one region holds at most ``SCHEMAS_PER_REGION``
+# lines, its least recently used line out first; lifecycle sweeps behave
+# as before and are counted apart (``invalidations``, not ``evictions``).
+#
+# One small table in one real region; the other "regions" are the same
+# engine snapshot under another region id (the cache keys a line by the
+# snapshot's region and epoch and never asks what else the region is).
+
+BOUND_ROWS = 24
+# three scan schemas over one table, as Q1, Q6 and Q15 are over lineitem
+SCHEMAS = {"wide": ["id", "c0", "c1"], "left": ["id", "c0"],
+           "right": ["id", "c1"]}
+
+
+@pytest.fixture(scope="module")
+def bound_rig():
+    c = Cluster(n_stores=1)
+    c.bootstrap()
+    c.start()
+    table = int_table(2, table_id=7790)
+    c.txn_write([("put", table_record_key(table.table_id, h),
+                  encode_row({2: h % 5, 3: h * 10})) for h in range(BOUND_ROWS)])
+    return {"c": c, "table": table}
+
+
+class BoundedCache:
+    """A cache of ``capacity`` regions with its retirement hook
+    recorded, read as the store reads it."""
+
+    def __init__(self, rig, capacity: int):
+        self.rig = rig
+        self.cache = RegionColumnarCache(capacity=capacity)
+        self.retired: list = []
+        self.cache.on_line_retired = self.retired.append
+
+    def snap(self, region_id: int, version: int = 1):
+        real = self.rig["c"].kvs[1].snapshot(SnapContext(region_id=1))
+        region = dataclasses.replace(
+            real.region, id=region_id,
+            epoch=RegionEpoch(real.region.epoch.conf_ver, version))
+        out = RegionSnapshot(real._snap, region)
+        out.data_index = real.data_index
+        return out
+
+    def read(self, region_id: int, schema: str, version: int = 1):
+        c, table = self.rig["c"], self.rig["table"]
+        dag = DagSelect.from_table(table, SCHEMAS[schema]).build(
+            start_ts=c.pd.tso())
+        ent = self.cache.get(self.snap(region_id, version), dag)
+        assert ent.estimated_rows() == BOUND_ROWS
+        return ent
+
+    def resident(self) -> dict:
+        """{region: its lines' column counts, in LRU order}."""
+        out: dict = {}
+        for key in self.cache._lines:
+            out.setdefault(key[0], []).append(len(key[3]))
+        return out
+
+    def stats(self) -> dict:
+        return self.cache.stats()
+
+
+def test_the_bound_counts_regions_not_lines(bound_rig):
+    """Two regions under three schemas are six lines inside a bound of
+    two: nothing leaves, and a second round builds nothing."""
+    c = BoundedCache(bound_rig, capacity=2)
+    for _round in range(2):
+        for schema in SCHEMAS:
+            for region in (11, 12):
+                c.read(region, schema)
+    st = c.stats()
+    assert st["resident_lines"] == 6 and st["regions"] == 2
+    assert st["schemas_per_region_max"] == 3
+    assert st["misses"] == 6 and st["hits"] == 6
+    assert st["evictions"] == {"region_lru": 0, "schema_bound": 0}
+    assert c.retired == []
+
+
+def test_a_regions_lines_leave_together_and_each_is_retired(bound_rig):
+    """A third region evicts the least recently used region with every
+    line it has, each through ``on_line_retired`` with its own lineage;
+    the other region keeps all of its."""
+    c = BoundedCache(bound_rig, capacity=2)
+    lineages = {}
+    for region in (11, 12):
+        for schema in SCHEMAS:
+            lineages[region, schema] = c.read(region, schema).feed_lineage
+    c.read(13, "left")
+    assert c.resident() == {12: [3, 2, 2], 13: [2]}
+    assert sorted(map(id, c.retired)) == sorted(
+        id(lineages[11, s]) for s in SCHEMAS)
+    st = c.stats()
+    assert st["evictions"] == {"region_lru": 3, "schema_bound": 0}
+    assert st["invalidations"] == 0 and st["regions"] == 2
+    # the evicted region is built again when it is asked, as a miss
+    c.read(11, "wide")
+    assert c.stats()["misses"] == 8
+    assert set(c.resident()) == {13, 11}
+
+
+@pytest.mark.parametrize("touched", sorted(SCHEMAS))
+def test_a_region_is_as_recent_as_its_most_recent_line(bound_rig, touched):
+    """A hit on ANY one line of a region keeps the whole region: the
+    other region, read later but not since, is the one that leaves."""
+    c = BoundedCache(bound_rig, capacity=2)
+    for region in (11, 12):
+        for schema in SCHEMAS:
+            c.read(region, schema)
+    c.read(11, touched)                     # a hit: builds nothing
+    assert c.stats()["misses"] == 6
+    c.read(13, "wide")
+    assert set(c.resident()) == {11, 13}
+    assert len(c.resident()[11]) == 3 and len(c.retired) == 3
+
+
+def test_the_schema_bound_evicts_the_oldest_schemas_line_only(
+        bound_rig, monkeypatch):
+    """Past ``SCHEMAS_PER_REGION`` lines of ONE region its least
+    recently used line leaves, alone: the region stays, so do its other
+    lines and every other region's."""
+    monkeypatch.setattr(region_cache, "SCHEMAS_PER_REGION", 2)
+    c = BoundedCache(bound_rig, capacity=4)
+    wide = c.read(11, "wide").feed_lineage
+    c.read(11, "left")
+    c.read(12, "wide")
+    c.read(11, "wide")                      # "left" is now 11's oldest
+    left = next(ln for key, ln in c.cache._lines.items()
+                if key[0] == 11 and len(key[3]) == 2).state.lineage
+    c.read(11, "right")
+    assert c.resident() == {12: [3], 11: [3, 2]}
+    assert c.retired == [left] and left is not wide
+    st = c.stats()
+    assert st["evictions"] == {"region_lru": 0, "schema_bound": 1}
+    assert st["schemas_per_region_max"] == 2 and st["regions"] == 2
+
+
+def test_the_default_schema_bound_is_small_and_fixed():
+    assert region_cache.SCHEMAS_PER_REGION == 8
+
+
+def test_a_smaller_capacity_set_online_holds_at_the_next_build(bound_rig):
+    """``server/node.py`` sets ``_capacity`` in place; the next line
+    built brings the cache under it, whole regions at a time."""
+    c = BoundedCache(bound_rig, capacity=4)
+    for region in (11, 12, 13, 14):
+        for schema in ("wide", "left"):
+            c.read(region, schema)
+    assert c.stats()["regions"] == 4 and c.stats()["resident_lines"] == 8
+    c.cache._capacity = 2
+    c.read(14, "right")
+    assert c.resident() == {13: [3, 2], 14: [3, 2, 2]}
+    assert c.stats()["evictions"]["region_lru"] == 4
+    assert len(c.retired) == 4
+
+
+@pytest.mark.parametrize("keep_epoch", [None, 1, 2])
+def test_lifecycle_sweeps_behave_as_before(bound_rig, keep_epoch):
+    """``invalidate_region`` drops a region's lines (all of them, or
+    the superseded epochs'), retires each, and counts them as
+    ``invalidations``: the bound's evictions are another counter."""
+    c = BoundedCache(bound_rig, capacity=4)
+    for schema in SCHEMAS:
+        c.read(11, schema, version=1)
+    c.read(11, "wide", version=2)
+    c.read(12, "wide")
+    want = {None: 4, 1: 1, 2: 3}[keep_epoch]
+    assert c.cache.invalidate_region(11, keep_epoch=keep_epoch) == want
+    assert len(c.retired) == want
+    st = c.stats()
+    assert st["invalidations"] == want
+    assert st["evictions"] == {"region_lru": 0, "schema_bound": 0}
+    assert st["resident_lines"] == 5 - want
+    assert c.resident()[12] == [3]
+    if keep_epoch is None:
+        assert 11 not in c.resident() and st["regions"] == 1
+    else:
+        assert {key[1] for key in c.cache._lines if key[0] == 11} == \
+            {keep_epoch}
+    # an epoch below the floor is served and not cached, as before
+    if keep_epoch == 2:
+        c.read(11, "left", version=1)
+        assert all(key[1] == 2 for key in c.cache._lines if key[0] == 11)
+
+
+def test_two_epochs_of_one_region_are_one_region(bound_rig):
+    """A split's superseded lines beside the children's count as ONE
+    region until the sweep behind the split retires them."""
+    c = BoundedCache(bound_rig, capacity=2)
+    c.read(11, "wide", version=1)
+    c.read(11, "wide", version=2)
+    c.read(12, "wide")
+    st = c.stats()
+    assert st["regions"] == 2 and st["resident_lines"] == 3
+    assert st["evictions"] == {"region_lru": 0, "schema_bound": 0}
+
+
+def test_a_capacity_of_zero_keeps_nothing(bound_rig):
+    c = BoundedCache(bound_rig, capacity=0)
+    c.read(11, "wide")
+    c.read(11, "wide")
+    st = c.stats()
+    assert st["resident_lines"] == 0 and st["misses"] == 2
+    assert st["evictions"]["region_lru"] == 2

@@ -69,6 +69,9 @@ class CoprocessorConfig:
     # device routing crossover — rationale at
     # copr/endpoint.py Endpoint.DEFAULT_DEVICE_ROW_THRESHOLD
     device_row_threshold: int = 131072
+    # REGIONS whose columnar cache lines are kept (least recently used
+    # region out first, all its lines together); a region may hold a
+    # line a scan schema, up to copr/region_cache.py SCHEMAS_PER_REGION
     region_cache_capacity: int = 8
     # paged response budget (endpoint.rs paging)
     response_page_rows: int = 1 << 20

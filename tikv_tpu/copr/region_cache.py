@@ -102,7 +102,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from typing import Optional, Sequence
 
 import numpy as np
@@ -940,9 +940,28 @@ def _merge_spans(positions, gap: int = 32):
     return [(lo, hi) for lo, hi in spans]
 
 
+# lines one region may hold at once: its scan schemas (a table of the
+# region and the columns a plan reads), and during a split the
+# superseded epoch's beside the children's.  Small and fixed: the
+# operator's bound counts regions, and this keeps the worst case at
+# ``capacity`` times it.  (Asked for by name by the benchmark's cell of
+# three plans over one table, so that a program whose bound counts
+# lines is not run on it: benchmark/requests/tpch_q1_streams.py.)
+SCHEMAS_PER_REGION = 8
+
+
 class RegionColumnarCache:
-    """LRU of delta-maintained columnar lines keyed by
-    (region, epoch version, table, columns).
+    """Delta-maintained columnar lines keyed by (region, epoch version,
+    table, columns), bounded by REGIONS: ``capacity`` is the number of
+    regions whose lines are kept, as the option's documentation tells
+    the operator (``coprocessor.region-cache-capacity``).  A region's
+    lines under different scan schemas (another table of the region,
+    another column set: Q1's seven columns beside Q6's four) stay and
+    leave TOGETHER: a region is as recently used as its most recently
+    used line, and past the bound the least recently used region goes,
+    every line of it.  One region holds at most ``SCHEMAS_PER_REGION``
+    (the module's) lines (its least recently used line goes first), so the worst case
+    stays ``capacity`` x that.
 
     Thread-safe: coprocessor requests arrive on concurrent gRPC handler
     threads; builds AND delta patches for one (line, data version) are
@@ -980,6 +999,10 @@ class RegionColumnarCache:
         self.tail_merges = 0
         self.deltas_held = 0
         self.invalidations = 0  # lines dropped by lifecycle events
+        # lines dropped by the two bounds: ``region_lru`` with their
+        # whole region past ``capacity`` regions, ``schema_bound`` alone
+        # past SCHEMAS_PER_REGION lines of one region
+        self.evictions = {"region_lru": 0, "schema_bound": 0}
         self.device_builds = 0  # cold builds served by device resolve
         # device-side MVCC resolution (the cold-path kill): a
         # DeviceMvccResolver enables the device rung of the build
@@ -1033,15 +1056,21 @@ class RegionColumnarCache:
                 # own lock, and iterating live would race
                 **self._digest_summary(line),
             } for key, line in self._lines.items()]
+            evictions = dict(self.evictions)
+        per_region = Counter(ln["region"] for ln in lines)
         out = {"hits": self.hits, "misses": self.misses,
                "deltas": self.deltas, "rebuilds": self.rebuilds,
                "compactions": self.compactions,
                "tail_merges": self.tail_merges,
                "deltas_held": self.deltas_held,
                "invalidations": self.invalidations,
+               "evictions": evictions,
                "device_builds": self.device_builds,
                "splits": self.splits,
-               "resident_lines": len(lines), "lines": lines}
+               "resident_lines": len(lines), "regions": len(per_region),
+               "schemas_per_region_max": max(per_region.values(),
+                                             default=0),
+               "lines": lines}
         if self._delta_source is not None:
             out["delta_log"] = self._delta_source.stats()
         return out
@@ -1509,6 +1538,7 @@ class RegionColumnarCache:
                                       heat=self.region_heat(base_key[0]))
         try:
             with tracker.phase("columnar_build"):
+                tracker.annotate(schema_cols=len(scan.columns))
                 tbl, safe_ts, locks, bundle = build_region_columnar_ex(
                     snap, scan.table_id, scan.columns, start_ts,
                     device_resolver=self.device_resolver,
@@ -1574,9 +1604,7 @@ class RegionColumnarCache:
                     retired.append(prev)
                 self._lines[base_key] = new_line
             self._lines.move_to_end(base_key)
-            while len(self._lines) > self._capacity:
-                _k, evicted = self._lines.popitem(last=False)
-                retired.append(evicted)
+            retired += self._evict_locked(base_key[0])
             self._publish_lines()
         if bundle is not None:      # parked / uncached build
             bundle.release()
@@ -1585,6 +1613,25 @@ class RegionColumnarCache:
         self._count(result)
         self._export_gauges(base_key[0], self._lines.get(base_key))
         return ent, lock_src
+
+    def _evict_locked(self, region_id: int) -> list:
+        """Hold both bounds after an insert into ``region_id``; → the
+        lines that left, for the caller to retire outside the lock.
+        ``_lines`` is in the lines' LRU order, so a region's place is
+        its last line's: walking it only here, where a line was just
+        built, keeps no second structure to fall out of step."""
+        out = []
+        mine = [k for k in self._lines if k[0] == region_id]
+        for key in mine[:max(0, len(mine) - SCHEMAS_PER_REGION)]:
+            out.append(self._lines.pop(key))
+            self.evictions["schema_bound"] += 1
+        recent_first = list(dict.fromkeys(
+            key[0] for key in reversed(self._lines)))
+        for rid in recent_first[max(0, self._capacity):]:
+            for key in [k for k in self._lines if k[0] == rid]:
+                out.append(self._lines.pop(key))
+                self.evictions["region_lru"] += 1
+        return out
 
     def _export_gauges(self, region_id: int, line) -> None:
         from ..utils.metrics import COPR_TOMBSTONE_RATIO

@@ -1000,7 +1000,9 @@ class DeviceRunner:
         count ``patch_windows`` and the device programs that wrote them,
         ``patch_programs``: one a window) or built
         again, by cause (``rebuilds_after_delta``), and both together
-        (``after_delta``: ``FlightRecorder.feed_counts``); ``memo``:
+        (``after_delta``: ``FlightRecorder.feed_counts``), and the
+        feeds held now with their planes' bytes (``resident_feeds``,
+        ``resident_bytes``: ``FeedArena.feed_residency``); ``memo``:
         request memos whose derived record was rolled across a write,
         ``kept`` or ``dropped`` by cause, and their ``host_planes``
         (``FlightRecorder.memo_counts``: feed.py ``roll_derived``);
@@ -1028,7 +1030,8 @@ class DeviceRunner:
                "scalar_cache": self.flight_recorder.scalar_counts(),
                "agg_params": self.flight_recorder.agg_param_counts(),
                "prepared": self.flight_recorder.prepared_counts(),
-               "feed": self.flight_recorder.feed_counts(),
+               "feed": {**self.flight_recorder.feed_counts(),
+                        **self._feed_residency()},
                "memo": self.flight_recorder.memo_counts(),
                "lanes": self.lane_stats(),
                "submesh_rebuilds": self._submesh_rebuilds,
@@ -1038,6 +1041,16 @@ class DeviceRunner:
         if self._placer is not None:
             out["placement"] = self._placer.stats()
         return out
+
+    def _feed_residency(self) -> dict:
+        """``FeedArena.feed_residency`` of this runner and of its
+        placement slices, added up."""
+        feeds = nbytes = 0
+        for r in (self, *(self._placer.slices
+                          if self._placer is not None else ())):
+            f, b = r._arena.feed_residency()
+            feeds, nbytes = feeds + f, nbytes + b
+        return {"resident_feeds": feeds, "resident_bytes": nbytes}
 
     def lane_stats(self) -> dict:
         """``DeviceAggregator.lane_stats`` of this runner and of its

@@ -1093,6 +1093,22 @@ class FeedArena:
         with self._mu:
             return len(self._entries)
 
+    def feed_residency(self) -> tuple:
+        """→ (feeds, bytes of their planes) resident now, read from the
+        buckets: a feed is a line's planes under ONE scan schema, so
+        what two schemas of a region both read is counted twice here,
+        as it is held twice."""
+        with self._mu:
+            buckets = [e.bucket for e in self._entries.values()]
+        feeds = nbytes = 0
+        for b in buckets:
+            for v in list(b.values()):      # status threads race inserts
+                if isinstance(v, dict) and "flat" in v:
+                    feeds += 1
+                    nbytes += sum(int(getattr(a, "nbytes", 0))
+                                  for a in v["flat"])
+        return feeds, nbytes
+
     def residency_by_tenant(self) -> dict:
         """Resident bytes per owning tenant (the resource_group half
         of the ``arena::residency`` owner tags) — the enforcement

@@ -62,6 +62,7 @@ as its lanes:
   ``same_lane_merges`` (folded into a lane of their own key),
   ``closes["lanes"]`` (of both, those taken while still open),
   ``lane_class_mismatch`` (left behind: another class),
+  ``launch_classes`` (distinct classes such launches left under),
   ``unbuilt_fallbacks`` (the runner's: asks that found a kernel's lane
   programs still being built, off this thread: the groups then leave
   one by one, and no staging waits for a compile);
@@ -466,6 +467,11 @@ class RequestCoalescer:
         self.groups_merged = 0
         self.same_lane_merges = 0
         self.lane_class_mismatch = 0
+        # the distinct launch classes a launch left under while other
+        # groups waited (the class is asked only then): one a cell of
+        # one plan, three where Q1, Q6 and Q15 meet on one store.  The
+        # dispatcher alone adds; bounded by the kernel cache's classes
+        self._launch_classes: set = set()
 
     # ------------------------------------------------------------ wiring
 
@@ -729,6 +735,7 @@ class RequestCoalescer:
         klass = self._launch_class(g)
         if klass is None:
             return []
+        self._launch_classes.add(klass)
         with self._mu:
             waiting = list(self._ready) + [
                 og for og in self._open.values() if og.members]
@@ -1201,6 +1208,7 @@ class RequestCoalescer:
                 "groups_merged": self.groups_merged,
                 "same_lane_merges": self.same_lane_merges,
                 "lane_class_mismatch": self.lane_class_mismatch,
+                "launch_classes": len(self._launch_classes),
             }
         lane_stats = getattr(self._runner, "lane_stats", None)
         out["unbuilt_fallbacks"] = lane_stats()["unbuilt_fallbacks"] \

@@ -105,7 +105,10 @@ SPAN_VOCABULARY: dict[str, str] = {
     "replica_promote": "leader-gain promotion of a warm replica feed: "
                        "scrub-digest re-verify, never a "
                        "columnar_build (device/supervisor.py)",
-    "columnar_build": "full columnar line build from the MVCC snapshot",
+    "columnar_build": "full columnar line build from the MVCC snapshot "
+                      "(attrs: schema_cols, the columns of the line's "
+                      "scan schema; decimal_cols, code_cols, "
+                      "skipped_datums from the native build)",
     "delta_apply": "committed-write delta patch onto a cached line",
     "host_exec": "host (numpy) executor pipeline run",
     "host_materialize": "host finalize: fetched tree → SelectResult",
