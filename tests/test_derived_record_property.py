@@ -7,7 +7,10 @@ mode.  After every read: the answer is the host pipeline's; the
 record's bounds CONTAIN the bounds a fresh derive of the line finds; and
 its dtypes, limbs, byte-plane widths and key grid are what the proofs
 give over THOSE bounds and the line's row count (so a kept record may
-cut more planes than a fresh derive would, never fewer)."""
+cut more planes than a fresh derive would, never fewer); and where the
+memo holds host planes, whatever delete-only entries they lag by, a
+reader of them (``HostPlanes.stream``: the cut deferred, not changed)
+gets the planes of the line as it stands."""
 
 from __future__ import annotations
 
@@ -26,7 +29,8 @@ from tikv_tpu.datatype import EvalType, FieldType, FieldTypeFlag, FieldTypeTp
 from tikv_tpu.datatype.time import pack_datetime
 from tikv_tpu.device import lowering
 from tikv_tpu.device.feed import (
-    anchor, arg_byte_planes, fits_dtype, plane_kinds, plane_values,
+    HostPlanes, anchor, arg_byte_planes, fits_dtype, plane_kinds,
+    plane_values,
 )
 from tikv_tpu.executors.runner import BatchExecutorsRunner
 from tikv_tpu.expr import Expr
@@ -106,6 +110,14 @@ def assert_record_is_what_its_bounds_prove(runner, plan, dag, ent) -> bool:
             (pos, meta["bounds"][pos], int(vals.min()), int(vals.max()))
         assert fits_dtype(vals, None, np.dtype(meta["dtypes"][pos]))
         planes.append((vals, col.validity))
+    if "host_cols" in meta:
+        # (on a copy: the memo's planes stay as the roll left them)
+        held = HostPlanes(plan, dict(meta), {}, lambda: True, None, n,
+                          runner.flight_recorder).cols()
+        assert len(held) == len(planes)
+        for (v, ok), (fv, fok), ds in zip(held, planes, meta["dtypes"]):
+            assert v.dtype == np.dtype(ds) and np.array_equal(v, fv)
+            assert np.array_equal(ok, fok)
     bounds, dtypes, limbs = meta["bounds"], meta["dtypes"], meta["limbs"]
     assert lowering.fit(plan, bounds, dtypes, n) == limbs
     served_by = runner._limb_variant(plan, limbs) if limbs else plan
@@ -180,6 +192,12 @@ def test_the_rolled_record_is_what_a_derive_over_its_bounds_proves(
     assert sum(memo["dropped"].values()) >= 3, memo
     assert memo["dropped"]["widths"] >= 1, memo
     assert memo["dropped"]["unknown"] == 0, memo
-    assert memo["host_planes"]["cut"] >= 1, memo
+    # host planes a delete-only batch found in the memo stayed, with the
+    # batch noted beside them, and were cut where they were read (above)
+    assert memo["host_planes"]["deferred"] >= 1, memo
+    assert memo["host_planes"]["cut"] >= memo["host_planes"]["deferred"]
+    feeds = rec.feed_counts()
+    assert feeds["rebuild_source"]["device"] >= 1, feeds
+    assert feeds["rebuild_source"]["host"] >= 1, feeds
     assert rec.stats()["faults"] == 0
     _lane_builds_done(runner)

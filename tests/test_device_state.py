@@ -302,6 +302,68 @@ def test_corruption_before_patch_survives_patch_and_is_caught():
         rig["close"]()
 
 
+@pytest.mark.parametrize("corrupted", [True, False],
+                         ids=["corrupted", "clean"])
+def test_corruption_before_compaction_survives_the_chain(corrupted):
+    """A tombstone's rebuild compacts the resident planes and CHAINS
+    their digests (R' = R - H(old rows from the first dead one on) + H(new
+    rows from there on): device/feed.py ``_compact_program``), never
+    re-hashes them: a bit that flipped in a plane before the compaction,
+    with rows deleted before AND after it, moves up with its row, what
+    the device holds less what is recorded is unchanged by the step, and
+    the next scrub fails the feed; without the fault it passes."""
+    from test_feed_compact import DeletedLine
+    from test_feed_patch_buckets import (
+        assert_feed_is_the_cold_build, one_device, serve,
+    )
+    runner = one_device()
+    sup = DeviceStateSupervisor(runner=runner)
+    line = DeletedLine(2000)
+    how, feed = serve(runner, line.snapshot())
+    assert how == "upload"
+
+    def off_by() -> int:
+        """The first plane's device digest less the recorded one."""
+        return (int(np.asarray(runner._feeds.device_digest(
+            feed["flat"][0], feed["n_live"]))) -
+            int(np.asarray(feed["digests"][0]))) % (1 << 64)
+
+    at = 1000
+    if corrupted:
+        # an HBM fault in the first value plane (int64 handles)
+        arr = feed["flat"][0]
+        feed["flat"] = (arr.at[at].set(arr[at] ^ 1),) + feed["flat"][1:]
+    before = off_by()
+    assert (before != 0) == corrupted
+    line.delete([0, 1, 2, 400])
+    line.delete([at - 4 + 10, 1990])    # (row ``at`` is now at - 4)
+    how, compacted = serve(runner, line.snapshot())
+    assert how == "compact" and compacted is feed
+    assert off_by() == before, \
+        "the compaction laundered the corruption into the digest"
+    if corrupted:
+        # the planes are the host's but for the flipped bit, moved up
+        got = np.asarray(feed["flat"][0])[:len(line.handles)]
+        assert list(np.flatnonzero(got != line.handles)) == [at - 4]
+    else:
+        assert_feed_is_the_cold_build(line, feed)
+    out = sup.scrub()
+    assert out["lines"] == 1 and out["divergences"] == int(corrupted)
+    assert runner.hbm_stats()["quarantined"] == int(corrupted)
+    if corrupted:
+        # quarantine (the feed dropped, the host serves one read) →
+        # rebuilt from host truth: clean again
+        assert runner.hbm_stats()["resident_bytes"] == 0
+        from test_feed_patch_buckets import dag
+        want = sorted(runner.handle_request(dag(), line.view()).rows())
+        assert sorted(runner.handle_request(
+            dag(), line.snapshot()).rows()) == want
+        how, rebuilt = serve(runner, line.snapshot())
+        assert how == "upload" and rebuilt is not feed
+        assert_feed_is_the_cold_build(line, rebuilt)
+    check_scrub_clean(sup)
+
+
 def test_patch_refreshes_digests_and_scrub_stays_clean():
     """Delta-patched feeds keep their recorded digests in sync: after
     an in-place span patch the scrubber must still read clean (a stale

@@ -110,7 +110,7 @@ class Line:
         n = len(self.handles)
         more = make_cols(self.rng, k)
         self.handles = np.append(self.handles,
-                                 _HANDLE0 + n + np.arange(k))
+                                 self.handles[-1] + 1 + np.arange(k))
         self.cols = {name: (self.cols[name] + more[name] if name == "f"
                             else np.append(self.cols[name], more[name]))
                      for name in self.cols}

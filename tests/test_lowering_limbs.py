@@ -334,6 +334,13 @@ def test_code_plane_pad_space_null_and_refusals():
     # what no code gives back: a value wider than the column's bytes
     # (multi-byte UTF-8 in a CHAR(1)), the pad byte inside a value
     assert code_plane(_texts(["é".encode()]).values, 1) is None
+    # (the cast to the column's width cuts such a value; the count of the
+    # bytes kept finds it, beside shorter values too)
+    assert code_plane(_texts([b"AB", b"", b"C"]).values, 1) is None
+    assert code_plane(_texts([b"ABC", b"A", b""]).values, 2) is None
+    # what is not bytes has no code, whatever the width cuts it to
+    assert code_plane(np.array([12, 7, None], dtype=object), 1) is None
+    assert code_plane(np.array([12, 7], dtype=object), 1) is None
     assert code_plane(_texts([b"A\x00"]).values, 2) is None
     assert code_plane(_texts([b"A\x00B"]).values, 3) is None
     assert code_plane(_texts([]).values, 1).tolist() == []

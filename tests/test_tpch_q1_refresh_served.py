@@ -239,7 +239,8 @@ def test_the_cells_files_agree_on_the_layout(kind, params):
 def test_every_read_answers_the_table_as_of_its_tso(store, kind, params):
     """Inserts AND deletes between reads: each read equals the reference
     at its own TSO, the tail region's feed is patched forward and the
-    head region's is built again."""
+    head region's is built again, from the resident planes (compacted
+    on the device: no host rebuild in the window)."""
     first = refresh_and_read(store, kind, params)       # cold: builds
     assert first["ok"], first
     feed0, cache0 = feed_counts(store), health(store)["copr_cache"]
@@ -269,17 +270,28 @@ def test_every_read_answers_the_table_as_of_its_tso(store, kind, params):
                feed0["rebuilds_after_delta"][k]
                for k in feed1["rebuilds_after_delta"]}
     assert rebuilt == {"structural": 6, "pad": 0, "dtype": 0, "null": 0}
+    # ... each of them a compaction of the resident feed by one program
+    # (an RF2 order is one run at the head), none sourced from the host
+    assert feed1["rebuild_source"]["device"] - \
+        feed0["rebuild_source"]["device"] == 6
+    assert feed1["rebuild_source"]["host"] == feed0["rebuild_source"]["host"]
+    assert feed1["compact_programs"] - feed0["compact_programs"] == 6
+    assert feed1["compact_rows"] - feed0["compact_rows"] == sum(
+        len(e[2]["l_quantity"]) for e in log[2:] if e[1] < 0)
     assert feed1["after_delta"] - feed0["after_delta"] == 12
     assert cache1["deltas"] - cache0["deltas"] == 12
     assert cache1["rebuilds"] == cache0["rebuilds"]
     assert cache1["misses"] == cache0["misses"]
     # both written lines' derived records rolled across every write:
-    # no constant derived again, the head's host planes cut to the rows
-    # its tombstones left (the tail's dropped by its first append)
+    # no constant derived again, the head's host planes left as they
+    # were with its tombstones noted beside them and never cut (nobody
+    # read them: the tail's dropped by its first append)
     memo1 = health(store)["device_mesh"]["memo"]
     assert memo1["kept"] - memo0["kept"] == 12
     assert memo1["dropped"] == memo0["dropped"]
-    assert memo1["host_planes"]["cut"] - memo0["host_planes"]["cut"] == 6
+    assert memo1["host_planes"]["deferred"] - \
+        memo0["host_planes"]["deferred"] == 6
+    assert memo1["host_planes"]["cut"] == memo0["host_planes"]["cut"]
     assert memo1["host_planes"]["dropped"] - \
         memo0["host_planes"]["dropped"] <= 1
     phases = health(store)["tracing"]["phases"]

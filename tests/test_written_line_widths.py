@@ -13,7 +13,9 @@ Where the journal entry SAYS what the write did (``introduced`` /
 record is rolled across it instead (device/feed.py ``roll_derived``):
 kept where every constant is proved again from the widened bounds and
 the new row count, dropped by its cause where one is not, its host
-planes cut to the rows a delete left; counted on
+planes kept with the tombstones they lag by and cut to the rows those
+left where they are next read (``HostPlanes.stream``: the feed itself is
+compacted on the device and reads none); counted on
 ``FlightRecorder.memo_counts`` (/health ``device_mesh.memo``)."""
 
 import numpy as np
@@ -28,7 +30,8 @@ from tikv_tpu.copr.region_cache import FeedLineage
 from tikv_tpu.datatype import (
     Column, EvalType, FieldType, FieldTypeFlag, FieldTypeTp,
 )
-from tikv_tpu.device.feed import anchor
+from tikv_tpu.device import feed as feed_mod
+from tikv_tpu.device.feed import HostPlanes, anchor
 from tikv_tpu.executors.columnar import ColumnarTable
 from tikv_tpu.executors.runner import BatchExecutorsRunner
 from tikv_tpu.expr import Expr
@@ -324,7 +327,7 @@ SAID = {
                                {"key": 1, "planes_dropped": 1}, "patch",
                                "same"),
     "a delete": (dict(deletes=[0, 5, N - 1]),
-                 {"kept": 1, "planes_cut": 1}, "rebuild", "same"),
+                 {"kept": 1, "planes_deferred": 1}, "compact", "same"),
 }
 
 
@@ -358,10 +361,51 @@ def test_a_write_that_says_what_it_did_rolls_the_memo(interpret, case):
     _lane_builds_done(runner)
 
 
+def reader(runner, line: SaidLine) -> HostPlanes:
+    """A request of the line's generation as it is NOW, about to read the
+    memo's host planes; it writes the shared memo by the memo's rule
+    (runner.py ``memo_fresh``)."""
+    dag, snap, meta = line.dag(), line.snapshot(), memo_of(runner, line)
+    plan, v = runner._analyze(dag), line.v
+    return HostPlanes(plan, meta, {}, lambda: meta.get("lineage_v") == v,
+                      lambda: runner._scan_batch(dag, plan, snap),
+                      len(line.handles), runner.flight_recorder)
+
+
+def streamed(runner, line: SaidLine) -> list:
+    """The memo's host planes as a request of the line's generation
+    reads them (``HostPlanes.stream``: a host rebuild, a TopN refine)."""
+    return reader(runner, line).cols()
+
+
+def cold_memo(line: SaidLine) -> dict:
+    """What a cold build of the line as it stands derives."""
+    cold_runner = _runner(1)
+    cold = SaidLine()
+    cold.handles, cold.cols, cold.valid = line.handles, line.cols, line.valid
+    assert said(cold_runner, cold)["feed"] == "upload"
+    fresh = memo_of(cold_runner, cold)
+    _lane_builds_done(cold_runner)
+    return fresh
+
+
+def cold_planes(line: SaidLine) -> list:
+    return cold_memo(line)["host_cols"]
+
+
+def assert_planes_equal(planes, fresh) -> None:
+    assert len(planes) == len(fresh) == 3
+    for (v, ok), (fv, fok) in zip(planes, fresh):
+        assert v.dtype == fv.dtype and np.array_equal(v, fv)
+        assert ok.dtype == fok.dtype and np.array_equal(ok, fok)
+
+
 def test_a_delete_cuts_the_host_planes_to_a_fresh_derives(interpret):
-    """Delete-only entries, two in one gap: the memo is kept, its host
-    planes are the planes a cold build of what is left derives, array
-    for array, and the feed is built again from them."""
+    """Delete-only entries, two in one gap: the memo is kept, the feed
+    is compacted on the device, and the memo's host planes, left as they
+    were with the two entries noted beside them, are cut where they are
+    next read to the planes a cold build of what is left derives, array
+    for array."""
     runner = _runner(1)
     line = SaidLine()
     said(runner, line)
@@ -369,19 +413,20 @@ def test_a_delete_cuts_the_host_planes_to_a_fresh_derives(interpret):
     line.batch(deletes=[0, 1, 2, 77])
     line.batch(deletes=[0, N - 6])      # (in the view the first left)
     got = said(runner, line)
-    assert got["memo"] == {"kept": 1, "planes_cut": 1}
-    assert got["feed"] == "rebuild" and not got["derived"]
+    assert got["memo"] == {"kept": 1, "planes_deferred": 1}
+    assert got["feed"] == "compact" and not got["derived"]
     kept = memo_of(runner, line)
     assert kept["bounds"] == bounds and kept["n_rows"] == N - 6
-    cold_runner = _runner(1)
-    cold = SaidLine()
-    cold.handles, cold.cols, cold.valid = line.handles, line.cols, line.valid
-    assert said(cold_runner, cold)["feed"] == "upload"
-    fresh = memo_of(cold_runner, cold)
-    assert len(kept["host_cols"]) == len(fresh["host_cols"]) == 3
-    for (v, ok), (fv, fok) in zip(kept["host_cols"], fresh["host_cols"]):
-        assert v.dtype == fv.dtype and np.array_equal(v, fv)
-        assert ok.dtype == fok.dtype and np.array_equal(ok, fok)
+    # the cut deferred, not changed: the planes are the generation's
+    # before the gap until someone reads them
+    assert len(kept["host_cols"][0][0]) == N and len(kept["host_gap"]) == 2
+    counts0 = runner.flight_recorder.memo_counts()["host_planes"]
+    planes = streamed(runner, line)
+    assert "host_gap" not in kept and planes == kept["host_cols"]
+    counts1 = runner.flight_recorder.memo_counts()["host_planes"]
+    assert counts1 == dict(counts0, cut=counts0["cut"] + 1)
+    fresh = cold_memo(line)
+    assert_planes_equal(planes, fresh["host_cols"])
     assert kept["dtypes"] == fresh["dtypes"]
     assert kept["hash_bounds"] == fresh["hash_bounds"]
     # an update beside a delete: the planes drop, the record stays
@@ -390,7 +435,87 @@ def test_a_delete_cuts_the_host_planes_to_a_fresh_derives(interpret):
     assert got["memo"] == {"kept": 1, "planes_dropped": 1}
     assert got["feed"] == "rebuild" and not got["derived"]
     _lane_builds_done(runner)
-    _lane_builds_done(cold_runner)
+
+
+@pytest.mark.parametrize("beside", [
+    "a_roll_across_a_delete", "a_roll_across_an_update", "a_second_reader"])
+def test_a_cut_that_something_runs_beside_leaves_the_memo_to_it(
+        interpret, monkeypatch, beside):
+    """The deferred cut takes ~10 ms with the GIL released and holds no
+    lock: a newer generation's roll, or a second reader of the same
+    generation, may run INSIDE it.  The reader then keeps its cut planes
+    to itself and the memo is what the other left: never the older
+    generation's planes under the newer generation's name."""
+    runner = _runner(1)
+    line = SaidLine()
+    said(runner, line)
+    line.batch(deletes=[0, 1, 2])
+    assert said(runner, line)["memo"] == {"kept": 1, "planes_deferred": 1}
+    meta = memo_of(runner, line)
+    uncut, gap = meta["host_cols"], meta["host_gap"]
+    first, want = reader(runner, line), cold_planes(line)
+    cut_dead, inside = feed_mod._cut_dead, []
+
+    def cut_with_company(*args):
+        if not inside:
+            inside.append(True)
+            if beside == "a_second_reader":
+                inside.append(streamed(runner, line))
+            else:
+                line.batch(**(dict(deletes=[5]) if "delete" in beside
+                              else dict(writes=[(9, INSIDE)])))
+                said(runner, line)      # (rolls the memo, sets its v)
+        return cut_dead(*args)
+
+    monkeypatch.setattr(feed_mod, "_cut_dead", cut_with_company)
+    assert_planes_equal(first.cols(), want)
+    if beside == "a_second_reader":
+        # its planes were published; the first's were not (one of a pair)
+        assert_planes_equal(inside[1], want)
+        assert all(a is b for a, b in zip(meta["host_cols"], inside[1]))
+        assert "host_gap" not in meta
+    elif "delete" in beside:
+        assert meta["host_cols"] is uncut
+        assert meta["host_gap"][:-1] == gap and len(meta["host_gap"]) == 2
+    else:
+        assert "host_cols" not in meta and "host_gap" not in meta
+    # ... and a reader of the line as it stands now reads ITS planes
+    assert_planes_equal(streamed(runner, line), cold_planes(line))
+    _lane_builds_done(runner)
+
+
+def test_more_pending_entries_than_the_journal_keeps_drop_the_planes(
+        interpret):
+    """The host planes lag the record by at most the journal's own depth
+    of delete-only entries (``FeedLineage.depth``): one more and they
+    drop, as after any other write, while the record and the compacted
+    feed stand."""
+    runner = _runner(1)
+    depth = FeedLineage().depth
+    rows = N + 2 * depth                # (the deletes cross no pad bucket)
+    line = SaidLine(n=rows)
+    said(runner, line)
+    half = depth // 2
+    for _ in range(half):
+        line.batch(deletes=[0])
+    got = said(runner, line)
+    assert got["memo"] == {"kept": 1, "planes_deferred": 1}
+    assert got["feed"] == "compact"
+    for _ in range(half):
+        line.batch(deletes=[1])
+    got = said(runner, line)
+    assert got["memo"] == {"kept": 1, "planes_deferred": 1}
+    kept = memo_of(runner, line)
+    assert len(kept["host_gap"]) == depth
+    line.batch(deletes=[2])
+    got = said(runner, line)
+    assert got["memo"] == {"kept": 1, "planes_dropped": 1}
+    assert got["feed"] == "compact" and not got["derived"]
+    assert "host_cols" not in kept and "host_gap" not in kept
+    # ... and a reader of the planes makes them again from the line
+    planes = streamed(runner, line)
+    assert len(planes[0][0]) == rows - depth - 1
+    _lane_builds_done(runner)
 
 
 def test_an_append_the_row_count_alone_pushes_past_the_sum_bound():

@@ -669,6 +669,12 @@ class FeedLineage:
         # miss consumes a match instead of re-uploading from host
         self.split_stash = None
 
+    @property
+    def depth(self) -> int:
+        """The entries the journal keeps: what lags a line by more has
+        no bridge (``since``)."""
+        return self._max
+
     def stash_cold(self, bundle) -> None:
         bundle.lineage_v = self.version
         with self._mu:

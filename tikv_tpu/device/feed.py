@@ -9,21 +9,23 @@ request's host halves: ``HostPlanes``), the DERIVED RECORD (what a
 request memo holds of a line's rows, and the one function that rolls it
 across a write: ``roll_derived``), then ``FeedStore``: the shape,
 the one constructor, the build ladder (``get``), the patch, the
-digests, the move between slices and the split of a region's line.
+compaction after tombstones, the digests, the move between slices and
+the split of a region's line.
 
 A feed is a dict: ``flat`` (per used column its value plane, then its
 validity plane if the column holds a NULL), ``null_flags`` (which do),
-``n_pad``, ``kinds`` (``plane_kinds``), where the runner records
-digests ``digests`` / ``n_live``; ``lineage_v`` (the generation it
-reflects), ``key`` (what its bucket holds it under), ``positional`` /
-``pk_flags`` (what a device split needs).
+``n_pad``, ``kinds`` (``plane_kinds``), ``n_live`` (the rows it holds),
+where the runner records digests ``digests``; ``lineage_v`` (the
+generation it reflects), ``key`` (what its bucket holds it under),
+``positional`` / ``pk_flags`` (what a device split needs).
 
 The store owns no state.  It serves through its runner's, by these
 names and no others: ``_arena``, ``_kernel_cache``, ``_single``,
 ``_mesh``, ``_row_sharding``, ``_repl``, ``_nshards``, ``_block_local``,
 ``_chunk_override``, ``scrub_digests``, ``_dispatch_mu``,
-``_sub_runners``, ``flight_recorder`` (its counts of patches and of
-rebuilds after a delta: /health ``device_mesh.feed``).  The runner,
+``_sub_runners``, ``flight_recorder`` (its counts of patches, of
+rebuilds after a delta and of where their rows came from: /health
+``device_mesh.feed``).  The runner,
 ``aggregate.py``, ``mvcc.py`` and ``join.py`` import this module, and
 it imports none of them.
 """
@@ -31,6 +33,7 @@ it imports none of them.
 from __future__ import annotations
 
 import math
+import threading
 from typing import Optional
 
 import numpy as np
@@ -170,6 +173,13 @@ def patch_bucket(rows: int) -> int:
         if rows <= b:
             return b
     return PATCH_BUCKETS[-1]
+
+
+# The runs of dead rows ONE compaction removes
+# (``FeedStore._compact_program``: fewer are padded with empty runs, so
+# a feed's class (its planes' dtypes, n_pad) compiles once; a gap of
+# more is the host rebuild's).
+COMPACT_RUNS = 16
 
 
 def pack_updates(updates) -> tuple:
@@ -320,11 +330,12 @@ class HostPlanes:
         double-buffering the tail of a columnar build instead of
         serializing convert-all then upload-all."""
         memo, meta = self.memo, self.meta
+        if "host_cols" not in memo:
+            held = self._held()
+            if held is not None:
+                memo["host_cols"] = held
         if "host_cols" in memo:
             yield from memo["host_cols"]
-            return
-        if "host_cols" in meta and self.fresh():
-            yield from meta["host_cols"]
             return
         dts = self.dtypes()
         batch = self.get_batch()
@@ -340,8 +351,46 @@ class HostPlanes:
             built.append(pair)
             yield pair
         memo["host_cols"] = built
-        if self.fresh():
-            meta["host_cols"] = built
+        with _PLANES_MU:
+            if self.fresh():
+                meta["host_cols"] = built
+                meta.pop("host_gap", None)
+
+    def _held(self) -> Optional[list]:
+        """The shared memo's planes AT THIS REQUEST'S GENERATION, else
+        None (the caller builds its own): the rows of the delete-only
+        entries ``roll_derived`` noted beside them (``host_gap``) are cut
+        here, where someone reads the planes (a host rebuild, a TopN
+        refine), not at every roll.
+
+        The cut runs ~10 ms with the GIL released, and a newer
+        generation's roll (no dispatch lock: ``runner._refresh_meta``) or
+        a second reader may run beside it: so the pair is READ as one
+        (``_PLANES_MU``), cut into this request's hands, and PUBLISHED as
+        one, only where the memo still holds the very pair that was read
+        and still reflects this generation; anything else leaves the memo
+        to whoever moved it.  Planes of another row count than the
+        request's are never served (a pair read between a roll and its
+        ``lineage_v``)."""
+        meta = self.meta
+        with _PLANES_MU:
+            planes, gap = meta.get("host_cols"), meta.get("host_gap")
+        if planes is None or not self.fresh():
+            return None
+        if gap:
+            cut = _cut_dead(planes, self.plan, gap, self.n)
+            if cut is None:
+                return None
+            self.recorder.note_planes_cut()
+            with _PLANES_MU:
+                if self.fresh() and meta.get("host_cols") is planes and \
+                        meta.get("host_gap") is gap:
+                    meta["host_cols"] = cut
+                    del meta["host_gap"]
+            planes = cut
+        if planes and len(planes[0][0]) != self.n:
+            return None
+        return planes
 
     def cols(self) -> list:
         return list(self.stream())
@@ -364,12 +413,20 @@ class HostPlanes:
 # GROUP BY key's grid and the aggregates' byte-plane widths
 # (``hash_bounds`` = (base, span, widths), a composite key's
 # ``key_bounds``, ``simple_arg_nbytes``), and the host planes
-# (``host_cols``).  ``HostPlanes`` and the run bodies of aggregate.py
-# write them, each when it is first asked for; ``roll_derived`` alone
-# carries them across a write.
+# (``host_cols``, and beside them ``host_gap``: the delete-only journal
+# entries they lag the record by, cut where the planes are next read:
+# ``HostPlanes.stream``).  ``HostPlanes`` and the run bodies of
+# aggregate.py write them, each when it is first asked for;
+# ``roll_derived`` alone carries them across a write.
 
 DERIVED = ("bounds", "dtypes", "limbs", "hash_bounds", "key_bounds",
-           "simple_arg_nbytes", "host_cols")
+           "simple_arg_nbytes", "host_cols", "host_gap")
+
+# ``host_cols`` and ``host_gap`` change together: a roll notes a gap
+# beside the planes or drops both, a reader publishes the planes cut and
+# the gap gone (``HostPlanes._held``), each under this lock, which is
+# held for dict operations alone, never for a cut.
+_PLANES_MU = threading.Lock()
 
 
 def _bare_int_ref(rpn) -> Optional[int]:
@@ -417,7 +474,7 @@ def arg_byte_planes(plan, bounds, dtypes) -> tuple:
 
 
 def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
-                 recorder) -> None:
+                 recorder, journal_depth: int) -> None:
     """Roll the derived record in ``meta`` across ``patches``, the
     journal entries of a lineage gap (``FeedLineage.since``; None: the
     journal no longer covers it), from the rows the entries INTRODUCED:
@@ -431,8 +488,11 @@ def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
     planes than a fresh derive of the shrunken line would, never fewer.
     ``count_rows()`` is the request's row count after the gap (it enters
     ``lowering.fit``'s sum proof), ``limb_variant(plan, limbs)`` the
-    plan a feed asking for limbs is served by.  Counted on ``recorder``
-    (/health ``device_mesh.memo``): kept, or dropped by its cause."""
+    plan a feed asking for limbs is served by, ``journal_depth`` the
+    entries the lineage's journal keeps (``FeedLineage.depth``): the
+    host planes lag the record by no more.  Counted on ``recorder``
+    (/health ``device_mesh.memo``): kept, or dropped by its cause, and
+    what became of the host planes it held."""
     if "dtypes" not in meta:
         _drop(meta)
         return                  # nothing was derived yet
@@ -446,21 +506,37 @@ def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
         cause = _disproved(meta, plan, [rows for p in patches
                                         for rows in p["introduced"]],
                            n, limb_variant)
-    planes, cut = meta.pop("host_cols", None), None
+    fate = None
     if cause is not None:
+        fate = "dropped" if "host_cols" in meta else None
         _drop(meta)
-    elif planes is not None:
-        # the host planes follow where the feed will be built from them
-        cut = _cut_dead(planes, plan, patches, n)
-        if cut is not None:
-            meta["host_cols"] = cut
-    recorder.note_memo(cause, planes_cut=cut is not None,
-                       planes_dropped=planes is not None and cut is None)
+    else:
+        with _PLANES_MU:
+            planes = meta.get("host_cols")
+            if planes is not None:
+                # the host planes stay as they are and the gap's
+                # tombstones are noted beside them: a resident feed is
+                # compacted on the device (``FeedStore._try_compact_feed``)
+                # and reads none of them; the cut runs where they are
+                # next read (``HostPlanes._held``)
+                gap = meta.get("host_gap", ()) + tuple(patches)
+                if len(gap) <= journal_depth and positional(plan.scan) and \
+                        _delete_only(gap, len(planes[0][0]) if planes else -1,
+                                     n):
+                    meta["host_gap"], fate = gap, "deferred"
+                else:
+                    # (as after any other write: a patched feed reads
+                    # ``HostPlanes.window`` and needs none)
+                    del meta["host_cols"]
+                    meta.pop("host_gap", None)
+                    fate = "dropped"
+    recorder.note_memo(cause, fate)
 
 
 def _drop(meta: dict) -> None:
-    for k in DERIVED:
-        meta.pop(k, None)
+    with _PLANES_MU:
+        for k in DERIVED:
+            meta.pop(k, None)
 
 
 def _disproved(meta: dict, plan, introduced, n: int,
@@ -541,35 +617,68 @@ def _disproved(meta: dict, plan, introduced, n: int,
     return None
 
 
+def _delete_only(patches, rows: int, n: int) -> bool:
+    """Do ``patches`` take a view of ``rows`` rows to one of ``n`` by
+    tombstones alone, each entry saying which (``dead``)?  Then every
+    row that is left lies where it lay, less the dead rows before it.
+    Not where an entry wrote a row's values, renumbered the view (a
+    repack, a compaction, a revive: no ``dead``), does not say what it
+    did, or where ``rows`` is not the whole view's count (planes or a
+    feed of part of the line's rows are not laid out by its view's
+    positions: a ranged request's)."""
+    for p in patches:
+        dead = p.get("dead")
+        if dead is None or p.get("introduced", True) or \
+                rows != p["live"] + len(dead):
+            return False
+        rows = p["live"]
+    return rows == n
+
+
+def dead_runs(patches) -> list:
+    """The rows delete-only ``patches`` tombstoned, entry after entry
+    each in the numbering of the view before it, folded into ascending
+    disjoint runs ``[start, length]`` in the numbering of the view
+    BEFORE THE FIRST (an RF2 order is one run of 1-7 rows; the oldest
+    orders of several sessions lie side by side and fold into one)."""
+    gone = np.zeros(0, np.int64)
+    for p in patches:
+        pos = np.asarray(p["dead"], np.int64)
+        # (``gone[i] - i`` is where the first row left behind ``gone[i]``
+        # lies now: a position has as many dead rows before it as there
+        # are such at or before it)
+        pos = pos + np.searchsorted(gone - np.arange(gone.size), pos,
+                                    side="right")
+        gone = np.union1d(gone, pos)
+    if not gone.size:
+        return []
+    first = np.flatnonzero(np.diff(gone, prepend=gone[0] - 2) != 1)
+    return [[int(gone[i]), int(j - i)]
+            for i, j in zip(first, [*first[1:], gone.size])]
+
+
 def _cut_dead(host_cols: list, plan, patches, n: int) -> Optional[list]:
     """The host planes of the generation before ``patches`` cut to the
     rows the entries left, where every entry is delete-only and says
-    which (``dead``): what ``FeedStore.get``'s rebuild after a tombstone
-    then streams in place of planes made again from the logical view and
-    its Python ``bytes``; None (they drop, as after any other write: a
-    patched feed reads ``HostPlanes.window`` and needs none) anywhere
-    else.  Until a tombstone is a device patch (ROADMAP S7 (a)), which
-    retires this cut and keeps ``dead``."""
+    which (``_delete_only``): what ``FeedStore.get``'s host rebuild
+    after a tombstone then streams in place of planes made again from
+    the logical view and its Python ``bytes``; None (they drop, as after
+    any other write) anywhere else.  One boolean gather a plane, 9-10 ms
+    at 500,102 rows of seven: paid where the planes are read
+    (``HostPlanes.stream``), which a feed compacted on the device
+    (``FeedStore._try_compact_feed``) does not."""
     rows = len(host_cols[0][0]) if host_cols else -1
-    if not positional(plan.scan):
+    if not positional(plan.scan) or not _delete_only(patches, rows, n):
         return None
     keep = None
     for p in patches:
-        dead = p.get("dead")
-        # (planes of part of the line's rows are not laid out by its
-        # view's positions: a ranged request's)
-        if dead is None or p["introduced"] or \
-                rows != p["live"] + len(dead):
-            return None
+        dead = p["dead"]
         if dead:
             if keep is None:
                 keep = np.ones(rows, np.bool_)
                 keep[list(dead)] = False
             else:
                 keep[np.flatnonzero(keep)[list(dead)]] = False
-        rows = p["live"]
-    if rows != n:
-        return None
     if keep is None:
         return host_cols
     return [(v[keep], ok[keep]) for v, ok in host_cols]
@@ -642,7 +751,7 @@ class FeedStore:
         resolve, slice or gather diverges at the next scrub instead of
         laundering."""
         feed = {"flat": tuple(flat), "null_flags": tuple(null_flags),
-                "n_pad": n_pad, "kinds": tuple(kinds)}
+                "n_pad": n_pad, "kinds": tuple(kinds), "n_live": n}
         if self._runner.scrub_digests:
             from .supervisor import host_plane_digest
             digests = []
@@ -651,7 +760,6 @@ class FeedStore:
                 if has_nulls:
                     digests.append(host_plane_digest(ok, n))
             feed["digests"] = tuple(digests)
-            feed["n_live"] = n
             self._warm_digest_kernels(feed["flat"])
         return feed
 
@@ -705,8 +813,9 @@ class FeedStore:
             req_v) -> dict:
         """The feed of ``planes`` over ``ranges`` of ``storage``'s line
         at generation ``req_v``, from the cheapest rung that has it: the
-        arena's (hit), that one patched forward, a split's stash, the
-        device MVCC resolve's bundle, the upload."""
+        arena's (hit), that one patched forward, or compacted where the
+        gap is tombstones alone, a split's stash, the device MVCC
+        resolve's bundle, the upload."""
         scan, used_infos, dtypes = planes.plan.scan, planes.infos, \
             planes.dtypes()
         feed_key = (tuple(i.col_id for i in used_infos), dtypes, ranges)
@@ -740,6 +849,14 @@ class FeedStore:
                     else "structural"
                 if rebuild is None:
                     tracker.label("device_feed", "patch")
+                    self._register_digests(lineage, feed_key, feed)
+                    return feed
+                if rebuild == "structural" and \
+                        self._try_compact_feed(feed, lineage, n, req_v):
+                    # the same rebuild (every plane written anew, the
+                    # feed's positions the view's), sourced from the
+                    # resident planes and not from the host's
+                    tracker.label("device_feed", "compact")
                     self._register_digests(lineage, feed_key, feed)
                     return feed
 
@@ -928,28 +1045,32 @@ class FeedStore:
                         mask[:hi - lo] = valid
                         updates.append(mask)
                 sends.append((pack_updates(updates), np.int32(lo)))
-            if digests is not None and \
-                    not all(isinstance(d, jax.Array) for d in digests):
-                # (a build records the host's ints, a patch leaves
-                # device scalars: the program takes the latter, so put
-                # a build's once, together, where its scalars lie)
-                digests = jax.device_put(
-                    tuple(d if isinstance(d, jax.Array) else np.uint64(d)
-                          for d in digests),
-                    None if self._runner._single else self._runner._repl)
+            digests = self._device_digests(digests)
             self._warm_patch_programs(flat, digests)
             program = self._patch_program()
             for updates, lo in sends:
                 flat, digests = program(flat, updates, lo, digests)
         feed["flat"] = flat
         feed["lineage_v"] = req_v
+        feed["n_live"] = n
         if digests is not None:
             feed["digests"] = digests
-            feed["n_live"] = n
         if count:
             self._runner.flight_recorder.note_feed_patch(
                 rows, list(windows.values()), programs=len(sends))
         return None
+
+    def _device_digests(self, digests):
+        """A feed's recorded digests as the device scalars a patch or a
+        compaction chains (None: a store that records none).  A build
+        records the host's ints, either program leaves device scalars:
+        a build's are put once, together, where its scalars lie."""
+        if digests is None or all(isinstance(d, jax.Array) for d in digests):
+            return digests
+        return jax.device_put(
+            tuple(d if isinstance(d, jax.Array) else np.uint64(d)
+                  for d in digests),
+            None if self._runner._single else self._runner._repl)
 
     def _warm_patch_programs(self, flat, digests) -> None:
         """A line's first patch runs the patch program of EVERY bucket
@@ -1014,6 +1135,113 @@ class FeedStore:
                 jax.jit(feed_patch) if r._single else \
                 jax.jit(feed_patch,
                         out_shardings=(r._row_sharding, r._repl))
+        return fn
+
+    # ----------------------------------------- the compaction (tombstones)
+
+    def _try_compact_feed(self, feed, lineage, n: int, req_v) -> bool:
+        """Bring a resident feed across a gap of delete-only journal
+        entries ON THE DEVICE → whether it did; anywhere else the host
+        rung builds it again (``get``), cause and count unchanged.
+
+        Still a rebuild: every plane is written anew and the feed's
+        positions stay the view's, so later patch spans map 1:1 and the
+        kernels' inputs are what a host build would hand them; only the
+        SOURCE of the rows is the resident planes, not a host copy cut
+        (``_cut_dead``), padded and uploaded again.  Sound where the gap
+        is exactly what that cut accepts (``_delete_only``: every entry
+        says which rows it tombstoned and wrote none; the feed holds the
+        whole view), the padded shape is unchanged and the feed lies on
+        one device (a shift across shards is a collective).  The dead
+        rows are folded into ascending runs (``dead_runs``), at most
+        ``COMPACT_RUNS`` of them, and sent as ONE packed int32 array
+        (``_compact_program``)."""
+        r = self._runner
+        if not (r._single and feed.get("positional")) or \
+                self.pad_rows(max(n, 1)) != feed["n_pad"]:
+            return False
+        patches = lineage.since(feed.get("lineage_v", -1), until=req_v)
+        n_old = feed.get("n_live", -1)
+        if not patches or not _delete_only(patches, n_old, n):
+            return False
+        runs = dead_runs(patches)
+        if len(runs) > COMPACT_RUNS:
+            return False
+        digests = feed.get("digests") if r.scrub_digests else None
+        with tracker.phase("feed_rebuild"):
+            packed = np.zeros(2 * COMPACT_RUNS + 3, np.int32)
+            packed[-3:] = len(runs), n_old, n
+            gone = 0
+            for j, (start, length) in enumerate(runs):
+                # (its start in the numbering its predecessors leave)
+                packed[j], packed[COMPACT_RUNS + j] = start - gone, length
+                gone += length
+            flat, digests = self._compact_program()(
+                feed["flat"], packed, self._device_digests(digests))
+        feed["flat"] = flat
+        feed["lineage_v"] = req_v
+        feed["n_live"] = n
+        if digests is not None:
+            feed["digests"] = digests
+        r.flight_recorder.note_feed_rebuild(
+            "structural", source="device", rows=n_old - n)
+        return True
+
+    def _compact_program(self):
+        """THE compaction: ONE jitted program over every plane of a
+        feed, ``(planes, up to ``COMPACT_RUNS`` runs packed as one int32
+        array: their starts (each in the numbering its predecessors
+        leave), their lengths, their count, the rows before and after,
+        their digests) → (new planes, new digests)``.  Each plane with
+        the rows of every run removed and the rows behind them moved up
+        (a masked roll a run, ``_split_plane_kernel``'s form, in a loop
+        over the runs there are: 1.8 ms to ready for one run where one
+        ``take`` over shifted positions takes 28, PERF.md section 6,
+        PR 50; the loop's body is compiled once, a third of the code
+        and of the compile of sixteen rolls laid end to end), rows at
+        and past the new count zero (``_build_flat``'s pad).  Compiled
+        once a feed's class (its planes' dtypes, n_pad), by the line's
+        first compaction.
+
+        The digests are CHAINED, as ``_patch_program``'s are and for its
+        reason (a whole-plane re-hash would launder a corruption into
+        the record): with ``t`` the first dead row, ``R' = R - H(old
+        rows [t, n_old)) + H(new rows [t, n_new))`` mod 2^64 under the
+        scrub's weights ``2i + 1``; rows before ``t`` did not move, so
+        what the device holds less what is recorded is unchanged by the
+        step and the next scrub still finds a fault that sat anywhere in
+        the plane (the new rows past ``n_new`` are this program's
+        zeros).  ``digests`` None: the planes alone.  No donation:
+        launches in flight and prepared records hold the old planes."""
+        r = self._runner
+        fn = r._kernel_cache.get("feed_compact_fn")
+        if fn is None:
+            def feed_compact(planes, packed, digests):
+                k = COMPACT_RUNS
+                starts, lens = packed[:k], packed[k:2 * k]
+                runs, n_old, n_new = packed[-3], packed[-2], packed[-1]
+                iota = jnp.arange(planes[0].shape[0], dtype=jnp.int32)
+
+                def drop_run(i, xs):
+                    return tuple(jnp.where(iota >= starts[i],
+                                           jnp.roll(x, -lens[i]), x)
+                                 for x in xs)
+
+                new = tuple(
+                    jnp.where(iota < n_new, x, jnp.zeros((), x.dtype))
+                    for x in lax.fori_loop(0, runs, drop_run, tuple(planes)))
+                if digests is None:
+                    return new, None
+                w = 2 * iota.astype(jnp.uint64) + 1
+                moved = iota >= starts[0]
+                zero = jnp.uint64(0)
+                return new, tuple(
+                    d - jnp.sum(jnp.where(moved & (iota < n_old),
+                                          _to_bits(a) * w, zero)) +
+                    jnp.sum(jnp.where(moved, _to_bits(b) * w, zero))
+                    for a, b, d in zip(planes, new, digests))
+            fn = r._kernel_cache["feed_compact_fn"] = jax.jit(
+                named_program(feed_compact, "feed_compact"))
         return fn
 
     def dus(self, arr, update, lo: int):

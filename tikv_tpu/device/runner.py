@@ -999,13 +999,18 @@ class DeviceRunner:
         ``patch_rows``, the widened windows by bucket length, their
         count ``patch_windows`` and the device programs that wrote them,
         ``patch_programs``: one a window) or built
-        again, by cause (``rebuilds_after_delta``), and both together
+        again, by cause (``rebuilds_after_delta``) and by where the rows
+        came from (``rebuild_source``: the ``host``'s planes, or the
+        resident feed compacted on the ``device``: ``compact_rows`` dead
+        rows removed by ``compact_programs``), and both together
         (``after_delta``: ``FlightRecorder.feed_counts``), and the
         feeds held now with their planes' bytes (``resident_feeds``,
         ``resident_bytes``: ``FeedArena.feed_residency``); ``memo``:
         request memos whose derived record was rolled across a write,
         ``kept`` or ``dropped`` by cause, and their ``host_planes``
-        (``FlightRecorder.memo_counts``: feed.py ``roll_derived``);
+        (``deferred``: kept with the tombstones they lag by, ``cut``
+        where next read, or ``dropped``;
+        ``FlightRecorder.memo_counts``: feed.py ``roll_derived``);
         ``lanes``:
         this runner's
         launches of lanes, ``DeviceAggregator.lane_stats``; all
@@ -2590,7 +2595,8 @@ class DeviceRunner:
         meta.pop("key_dense", None)     # (likewise: run_hash)
         meta.pop("key_dense_tiled", None)
         roll_derived(meta, plan, lineage.since(from_v, until=to_v),
-                     count_rows, self._limb_variant, self.flight_recorder)
+                     count_rows, self._limb_variant, self.flight_recorder,
+                     lineage.depth)
         meta["lineage_v"] = to_v
 
     def _result(self, dag, schema, columns) -> "SelectResult":

@@ -179,15 +179,19 @@ class FlightRecorder:
         # device/feed.py ``roll_derived``: request memos whose derived
         # record a write was rolled across, kept (every constant proved
         # again) or dropped, by what the written rows left; and the
-        # host planes such a memo held, cut to the rows tombstones left
-        # or dropped
+        # host planes such a memo held: kept with the tombstones they
+        # lag by noted beside them (``deferred``), cut to the rows those
+        # left where someone read them (``cut``), or dropped
         self.memo_kept = 0
         self.memo_dropped = {"unknown": 0, "dtype": 0, "code": 0,
                              "null_key": 0, "limbs": 0, "widths": 0,
                              "key": 0}
-        self.memo_host_planes = {"cut": 0, "dropped": 0}
+        self.memo_host_planes = {"cut": 0, "dropped": 0, "deferred": 0}
         # device/feed.py: resident feeds brought forward by a patch
-        # after a write, and those built again instead, by cause
+        # after a write, and those built again instead, by cause and by
+        # where the rows came from (the host's planes; the resident
+        # feed itself, compacted: the dead rows it removed and the
+        # device programs that did)
         self.feed_patches = 0
         self.feed_patch_rows = 0
         self.feed_patch_windows = 0
@@ -195,6 +199,8 @@ class FlightRecorder:
         self.feed_patch_buckets: dict = {}
         self.feed_rebuilds = {"structural": 0, "pad": 0, "dtype": 0,
                               "null": 0}
+        self.feed_rebuild_source = {"device": 0, "host": 0}
+        self.feed_compact_rows = 0
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -302,22 +308,29 @@ class FlightRecorder:
                     "builds": self.prepared_builds,
                     "drops": dict(self.prepared_drops)}
 
-    def note_memo(self, cause: Optional[str], planes_cut: bool = False,
-                  planes_dropped: bool = False) -> None:
+    def note_memo(self, cause: Optional[str],
+                  planes: Optional[str] = None) -> None:
         """A request memo's derived record rolled across a write: kept
         (``cause`` None) or dropped because an entry did not say what
         it did (``unknown``) or a written row left a plane's dtype
         (``dtype``), has a CHAR value without a code (``code``), a NULL
         in a composite key (``null_key``), moved the limb split
         (``limbs``), a byte-plane width (``widths``) or the key grid
-        (``key``); and what became of the host planes it held."""
+        (``key``); and what became of the host ``planes`` it held
+        (None: it held none): ``deferred`` or ``dropped``."""
         with self._mu:
             if cause is None:
                 self.memo_kept += 1
             else:
                 self.memo_dropped[cause] += 1
-            self.memo_host_planes["cut"] += planes_cut
-            self.memo_host_planes["dropped"] += planes_dropped
+            if planes is not None:
+                self.memo_host_planes[planes] += 1
+
+    def note_planes_cut(self) -> None:
+        """A memo's host planes READ after delete-only writes (feed.py
+        ``HostPlanes._held``) and cut to the rows those left."""
+        with self._mu:
+            self.memo_host_planes["cut"] += 1
 
     def memo_counts(self) -> dict:
         with self._mu:
@@ -338,13 +351,19 @@ class FlightRecorder:
                 self.feed_patch_buckets[w] = \
                     self.feed_patch_buckets.get(w, 0) + 1
 
-    def note_feed_rebuild(self, why: str) -> None:
-        """A resident feed a write left behind built again from the
-        line: ``structural`` (tombstones, a repack, a journal gap),
-        ``pad`` (the row count crossed a pad bucket), ``dtype`` / ``null``
-        (a value outside the feed's dtypes / its first NULL)."""
+    def note_feed_rebuild(self, why: str, source: str = "host",
+                          rows: int = 0) -> None:
+        """A resident feed a write left behind built again:
+        ``structural`` (tombstones, a repack, a journal gap), ``pad``
+        (the row count crossed a pad bucket), ``dtype`` / ``null`` (a
+        value outside the feed's dtypes / its first NULL); from the
+        line's ``host`` planes, or, after tombstones alone, from the
+        resident feed itself (``device``: its dead ``rows`` removed by
+        one program)."""
         with self._mu:
             self.feed_rebuilds[why] += 1
+            self.feed_rebuild_source[source] += 1
+            self.feed_compact_rows += rows
 
     def feed_counts(self) -> dict:
         """/health ``device_mesh.feed``; ``after_delta`` = ``patches`` +
@@ -358,6 +377,10 @@ class FlightRecorder:
                     "patch_buckets": {str(w): c for w, c in sorted(
                         self.feed_patch_buckets.items())},
                     "rebuilds_after_delta": rebuilds,
+                    "rebuild_source": dict(self.feed_rebuild_source),
+                    "compact_rows": self.feed_compact_rows,
+                    # (one program a compaction)
+                    "compact_programs": self.feed_rebuild_source["device"],
                     "after_delta": self.feed_patches +
                     sum(rebuilds.values())}
 

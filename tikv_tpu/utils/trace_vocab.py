@@ -157,8 +157,11 @@ SPAN_VOCABULARY: dict[str, str] = {
     "feed_rebuild": "a resident feed the journal could not patch "
                     "forward (tombstones, a repack, a crossed pad "
                     "bucket, a value outside the feed's dtypes) built "
-                    "again from the line and uploaded: feed_upload's "
-                    "work, named apart because it follows a write",
+                    "again: from the line and uploaded (feed_upload's "
+                    "work, named apart because it follows a write) or, "
+                    "after tombstones alone, compacted on the device "
+                    "from the resident planes (feed.py "
+                    "_try_compact_feed: label device_feed=compact)",
     "host_derive": "a request's device dtypes derived from the line's "
                    "rows (feed.py HostPlanes._derive: the code and date "
                    "planes cut, a lowered plan's bounds and its proof); "
