@@ -223,7 +223,13 @@ WHAT_A_PARENT_HAS = {
     "health.fastpath.hit", "health.coprocessor.requests_served",
     "flight_recorder.launches",
     "health.tracing.phases.group_dispatch.wall_ms",
-    "health.tracing.process.clock_ms"}
+    "health.tracing.process.clock_ms",
+    # the dispatcher's phases from before PR 51 (trace_vocab.HOLD_WHOLE)
+    "health.tracing.phases.device_dispatch.wall_ms",
+    "health.tracing.phases.feed_patch.wall_ms",
+    "health.tracing.phases.feed_rebuild.wall_ms",
+    "health.tracing.phases.feed_upload.wall_ms",
+    "health.tracing.phases.host_derive.wall_ms"}
 
 
 def streams_metrics() -> list:
@@ -288,7 +294,7 @@ def test_the_streams_cell_runs_the_manifests_files():
     assert os.path.isfile(os.path.join(ROOT, config["toml"]))
     assert config["name"] == cell["config"]
     want = line.declared(manifest, STREAMS, "per_layer")
-    assert len(want) == 18 and set(streams_metrics()) < set(want)
+    assert len(want) == 19 and set(streams_metrics()) < set(want)
     assert set(line.declared(manifest, STREAMS, "end_to_end")) == \
         {"read_p50_ms", "read_p95_ms", "reads_per_s", "setup_s"}
     for name in want:
@@ -334,3 +340,97 @@ def test_a_streams_kind_refuses_a_program_that_bounds_lines(kind,
     with pytest.raises(SystemExit) as e:
         streams_kind(f"{kind}_streams").prepare(None, None, {})
     assert "SCHEMAS_PER_REGION" in str(e.value)
+
+
+# ------------------------------------------ the dispatcher's hold (PR 51)
+#
+# ``dispatcher.hold_named_share`` is declared in the PR that brings the
+# hold's own rows, so its reader has to give a true value on a program
+# that lacks them: the parent's five phases there are in its ``parts``
+# first, and a part a sample does not hold adds nothing.
+
+HOLD_METRIC = "dispatcher.hold_named_share"
+
+
+def bench_module(directory: str, name: str):
+    import sys
+    bench = os.path.join(ROOT, "benchmark")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import byname
+    return byname.load(directory, name)
+
+
+def test_the_hold_metric_reads_a_parent_and_names_the_holds_rows():
+    from tikv_tpu.utils.trace_vocab import (
+        HOLD_SELF, HOLD_WHOLE, SPAN_VOCABULARY,
+    )
+    spec = entries()[HOLD_METRIC]
+    assert spec["reader"] == "phase_cover"
+    whole, parts = spec["args"]["whole"], spec["args"]["parts"]
+    path = "health.tracing.phases.{}.wall_ms".format
+    assert path(whole) in WHAT_A_PARENT_HAS
+    assert all(path(p) in WHAT_A_PARENT_HAS for p in parts[:5])
+    # ... and then the hold's own rows, each once: what the program adds
+    # up (utils/trace.py hold) is what the metric reads
+    assert tuple(parts) == HOLD_WHOLE + HOLD_SELF
+    assert set(parts) | {whole, "dispatch_self"} <= set(SPAN_VOCABULARY)
+    assert "dispatch_self" not in parts
+    entry = spec["per_layer_entry"]
+    assert (entry["unit"], entry["better"], entry["moves"]) == \
+        ("%", "higher", "reads_per_s")
+    assert entry["workloads"] == ["q1-refresh-lineitem-sf1-closed4",
+                                  "q1-lineitem-sf1-closed4", STREAMS]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        assert json.load(f)["per_layer"][-1] == entry
+
+
+def _sample(rows: dict) -> dict:
+    return {"health": {"tracing": {"phases": {
+        name: {"count": 1, "wall_ms": wall} for name, wall in rows.items()}}}}
+
+
+PARENT_GO = {"group_dispatch": 1000.0, "device_dispatch": 100.0,
+             "feed_patch": 50.0, "feed_rebuild": 20.0, "feed_upload": 300.0,
+             "host_derive": 10.0}
+PARENT_END = {"group_dispatch": 43000.0, "device_dispatch": 7300.0,
+              "feed_patch": 5450.0, "feed_rebuild": 2320.0,
+              "feed_upload": 1500.0, "host_derive": 3910.0}
+OWN_GO = {"group_open": 5.0, "stage_plan": 40.0, "memo_roll": 7.0,
+          "stage_full": 60.0, "feed_get": 9.0, "lanes_launch": 11.0,
+          "group_complete": 30.0, "dispatch_self": 338.0}
+OWN_END = {"group_open": 905.0, "stage_plan": 4040.0, "memo_roll": 3007.0,
+           "stage_full": 9060.0, "feed_get": 1009.0, "lanes_launch": 2011.0,
+           "group_complete": 1930.0, "dispatch_self": 538.0}
+
+
+@pytest.mark.parametrize("shape, want", [
+    ("parent", 100.0 * 20000.0 / 42000.0),
+    ("change", 100.0 * (42000.0 - 200.0) / 42000.0),
+    ("change_lacking_a_row", 100.0 * (42000.0 - 200.0 - 3000.0) / 42000.0),
+    ("idle", None), ("no_such_row", None)])
+def test_phase_cover_over_a_parents_sample_and_a_changes(shape, want):
+    read = bench_module("readers", "phase_cover").read
+    args = entries()[HOLD_METRIC]["args"]
+    go, end = dict(PARENT_GO), dict(PARENT_END)
+    if shape.startswith("change"):
+        go.update(OWN_GO)
+        end.update(OWN_END)
+    if shape == "change_lacking_a_row":
+        del go["memo_roll"]         # (held by one sample alone: adds 0)
+    if shape == "idle":
+        end["group_dispatch"] = go["group_dispatch"]
+    if shape == "no_such_row":
+        del end["group_dispatch"]
+    got = read({"counters_go": _sample(go), "counters_end": _sample(end)},
+               args)
+    if want is None:
+        assert got is None
+        return
+    assert got == pytest.approx(want) and 0 < got <= 100
+    if shape == "change":
+        # the rows the program adds up: the share and dispatch_self's
+        # make the whole
+        own = 100.0 * (OWN_END["dispatch_self"] - OWN_GO["dispatch_self"]) \
+            / 42000.0
+        assert got + own == pytest.approx(100.0)

@@ -38,6 +38,9 @@ if BENCH not in sys.path:       # the table and request kinds import ``byname``
     sys.path.append(BENCH)
 
 import byname  # noqa: E402
+from pending_entries import (  # noqa: E402
+    REGIONS, finite, pending_metrics, read_pending,
+)
 
 ROWS = 20000
 SEED = 2600000027           # the driver's seeds are this large
@@ -621,38 +624,19 @@ def test_loadgen_child_runs_the_cell_end_to_end(loadgen_result):
 
 # --------------------------- the metrics that wait for their entries (PR 36)
 #
-# A counter and the metric that reads it cannot land in one PR: line.py
-# refuses a traced line that lacks a declared metric, and the driver
-# makes the traced run on the parent too, whose program has no such
-# counter (PERF.md section 7, row 1a).  So a PR that brings a source
-# brings the metric's file complete, with its manifest entry under
-# ``pending_entry``; a later benchmark PR renames the key to
-# ``per_layer_entry`` (which tests/test_benchmark_manifest.py holds
-# equal to the manifest) and adds the entry.
+# tests/pending_entries.py says why they wait.  Each file is read over a
+# child of a cell of its OWN ``workloads``: this one's, or (the files of
+# the cell that writes, PR 51) tests/test_tpch_q1_refresh_served.py's.
 
-
-def pending_metrics() -> dict:
-    import glob
-    out = {}
-    for path in sorted(glob.glob(os.path.join(BENCH, "layer_metrics",
-                                              "*.json"))):
-        with open(path) as f:
-            spec = json.load(f)
-        if "pending_entry" in spec:
-            out[os.path.basename(path)[:-len(".json")]] = spec
-    return out
-
-
-@pytest.mark.parametrize("name", sorted(pending_metrics()))
+@pytest.mark.parametrize("name", sorted(pending_metrics(REGIONS)))
 def test_a_pending_metric_reads_the_loadgen_childs_result(loadgen_result,
                                                           name):
     """Its reader, over ``data`` as ``run.py`` builds it from the load
     generator's result file, finds its source in this program and gives
     a finite number; its entry is ready for the manifest and not in it."""
-    import math
+    assert CELL == REGIONS
     _warm, result = loadgen_result
     spec = pending_metrics()[name]
-    assert set(spec) == {"what", "reader", "args", "pending_entry"}
     with open(os.path.join(BENCH, "traffic", f"{CELL}.json")) as f:
         traffic = json.load(f)
     data = {"reads": [r for r in result["records"] if r["ok"]],
@@ -662,7 +646,7 @@ def test_a_pending_metric_reads_the_loadgen_childs_result(loadgen_result,
             "stats": {"loadgen_cpu_share": result["loadgen_cpu_share"]},
             "setup": {"load_s": result["load_s"],
                       "first_read_s": result["first_read_s"]}}
-    got = byname.load("readers", spec["reader"]).read(data, spec["args"])
+    got = read_pending(name, spec, data)
     if name == "mesh.dispatch_lock_wait_ms" and got is None:
         # "did not occur": off a mesh only a launch the coalescer did
         # not stage takes the lock on a request's path
@@ -672,20 +656,7 @@ def test_a_pending_metric_reads_the_loadgen_childs_result(loadgen_result,
         assert "dispatch_lock_wait" in \
             result["counters_end"]["health"]["tracing"]["phases"]
     else:
-        assert isinstance(got, (int, float)) and math.isfinite(got) and \
-            got >= 0, (name, got)
-    entry = spec["pending_entry"]
-    assert set(entry) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"} and entry["name"] == name
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    assert set(entry["workloads"]) <= {w["name"]
-                                       for w in manifest["workloads"]}
-    reported = {m["name"]: m for m in manifest["end_to_end"]}
-    assert entry["moves"] in reported
-    assert entry["better"] in ("lower", "higher")
-    assert entry["source"] in ("program_span", "program_counter")
-    assert name not in {m["name"] for m in manifest["per_layer"]}
+        assert finite(got), (name, got)
 
 
 # ------------------------------------------- a read's tasks as lanes

@@ -288,10 +288,11 @@ def test_the_cells_files_agree_with_their_three_sources():
         assert key in config["assumed"]
     assert set(config["memory"]) >= {"reckoned", "measured"}
     # the cell reports the ten shared layer metrics and its own eight,
-    # which list it alone and stand where the manifest ended
+    # which list it alone and stand where the manifest ended at PR 48,
+    # and (PR 51) the share of the dispatcher's hold that has a name
     mine = sorted(m["name"] for m in manifest["per_layer"]
                   if CELL in m.get("workloads", ()))
-    assert len(mine) == 18
+    assert len(mine) == 19 and "dispatcher.hold_named_share" in mine
     assert [m for m in mine if m in NEW_METRICS] == NEW_METRICS
     assert sorted(m["name"] for m in manifest["per_layer"][52:60]) == \
         NEW_METRICS

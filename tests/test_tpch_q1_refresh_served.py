@@ -41,6 +41,9 @@ from tikv_tpu.device import DeviceRunner, pallas_hash
 from tikv_tpu.parallel import make_mesh
 
 import byname  # noqa: E402 — test_tpch_q1_served put benchmark/ on the path
+from pending_entries import (  # noqa: E402
+    REFRESH, finite, pending_metrics, read_pending,
+)
 
 CELL = "q1-refresh-lineitem-sf1-closed4"
 CONFIG = "tpch-sf1-lineitem-q1-refresh-regions96"
@@ -225,7 +228,8 @@ def test_the_cells_files_agree_on_the_layout(kind, params):
                    "feed.patch_share", "feed.rebuild_ms",
                    "kernel.pallas_q1_refresh_region_roofline",
                    "prepared.drops_per_task"]
-    assert len(mine) == 20
+    # (and, PR 51, the share of the dispatcher's hold that has a name)
+    assert len(mine) == 21 and "dispatcher.hold_named_share" in mine
     layers = {m["name"]: m["layer"] for m in manifest["per_layer"]}
     assert {layers[n] for n in own} == {
         "columnar cache, feed", "client fan-out", "kernel launch",
@@ -517,14 +521,16 @@ def test_a_lock_that_never_clears_is_the_callers(store, kind, params):
 # ------------------------------------------------- loadgen.py, as run.py runs it
 
 
-def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
+@pytest.fixture(scope="module")
+def loadgen_result(store, tmp_path_factory):
     """``benchmark/loadgen.py`` itself, as a child with the ``warm`` /
     ``go`` / ``done`` hand-shake of ``run.py``, over the cell's own
     traffic file (``warm_s`` apart) and its configuration (the table's
     id apart): the load, the first read after the first two writes, the
     probes, the warm rounds, a window of one second in which four
     sessions write and read, the check of every record against the
-    reference at its own TSO, and the cell's new layer metrics."""
+    reference at its own TSO.  → the result file."""
+    tmp_path = tmp_path_factory.mktemp("loadgen")
     config = load_config()
     config["table"]["table_id"] = TABLE_IDS["loadgen"]
     config_file = tmp_path / "config.json"
@@ -565,7 +571,21 @@ def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
             child.wait()
         child.stdin.close()
         child.stdout.close()
-    result = json.loads(out.read_text())
+    return json.loads(out.read_text())
+
+
+def window_data(result: dict) -> dict:
+    """``data`` as ``run.py`` hands it to a reader (a copy: a test may
+    cut rows out of its samples)."""
+    return json.loads(json.dumps({
+        "counters_go": result["counters_go"],
+        "counters_end": result["counters_end"],
+        "reads": [r for r in result["records"] if r["ok"]]}))
+
+
+def test_loadgen_child_runs_the_cell_end_to_end(loadgen_result):
+    """... and the cell's new layer metrics over that window."""
+    result = loadgen_result
     assert result["warm_failed"] == 0
     assert result["checks"] == [["tpch_q1_refresh.wrong_answers", 0, 0],
                                 ["regions.reads_off_the_layout", 0, 0],
@@ -575,9 +595,7 @@ def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
     assert all(r["ok"] for r in result["last"])
     assert all(r["labels"]["cop_tasks"] == str(N)
                for r in result["records"])
-    data = {"counters_go": result["counters_go"],
-            "counters_end": result["counters_end"],
-            "reads": result["records"]}
+    data = window_data(result)
 
     def metric(name):
         with open(os.path.join(BENCH, "layer_metrics", f"{name}.json")) as f:
@@ -604,6 +622,99 @@ def test_loadgen_child_runs_the_cell_end_to_end(store, tmp_path):
     assert metric("feed.patch_share") is None
     assert metric("feed.rebuild_ms") is None
     assert metric("cop.locked_reply_share") is None
+
+
+# ------------------- the dispatcher's hold and the writes' rows (PR 51)
+
+
+def test_the_holds_rows_add_up_over_the_childs_window(loadgen_result):
+    """Over the window in which four sessions write and read: the rows
+    of the hold's vocabulary and ``dispatch_self`` add up to
+    ``group_dispatch`` (each row is rounded to a microsecond on
+    ``/health``), every staging's row rose, a written region's read
+    rolled its memo and staged in full, and the declared share reads
+    what the program adds up."""
+    from tikv_tpu.utils.trace_vocab import HOLD_CPU, HOLD_SELF, HOLD_WHOLE
+    data = window_data(loadgen_result)
+    go, end = (data[k]["health"]["tracing"]["phases"]
+               for k in ("counters_go", "counters_end"))
+
+    def rise(name, field="wall_ms"):
+        return end[name][field] - go[name][field]
+
+    rows = HOLD_SELF + HOLD_WHOLE
+    whole = rise("group_dispatch")
+    assert whole > 0
+    assert sum(rise(n) for n in rows) + rise("dispatch_self") == \
+        pytest.approx(whole, rel=0.01, abs=0.05)
+    for name in HOLD_SELF + ("device_dispatch", "feed_patch",
+                             "feed_rebuild", "dispatch_self"):
+        assert rise(name, "count") > 0, name
+    assert rise("dispatch_self", "count") == rise("group_dispatch", "count")
+    # a write is followed by one roll and one full staging a region (a
+    # read that comes back older than its line's memo stages in full
+    # without a roll; a staging under way at either sample has closed
+    # its ladder and not yet itself)
+    assert 0 < rise("memo_roll", "count") <= rise("stage_full", "count") + 1
+    assert abs(rise("stage_full", "count") - rise("feed_get", "count")) <= 1
+    assert rise("stage_plan", "count") >= rise("stage_full", "count")
+    # on the dispatcher the jitted calls take the CPU clock every time
+    for name in HOLD_CPU:
+        assert rise(name, "cpu_samples") == rise(name, "count"), name
+    with open(os.path.join(BENCH, "layer_metrics",
+                           "dispatcher.hold_named_share.json")) as f:
+        spec = json.load(f)
+    share = byname.load("readers", spec["reader"]).read(data, spec["args"])
+    assert 0 < share <= 100
+    assert share == pytest.approx(
+        100.0 * (1 - rise("dispatch_self") / whole), abs=0.5)
+    # ... and on a program from before the rows it reads a smaller share
+    for side in (go, end):
+        for name in HOLD_SELF:
+            del side[name]
+    older = byname.load("readers", spec["reader"]).read(data, spec["args"])
+    assert 0 < older < share
+
+
+@pytest.mark.parametrize("name", sorted(pending_metrics(REFRESH)))
+def test_a_pending_metric_reads_the_writing_cells_child(loadgen_result,
+                                                        name):
+    """The files that wait for their entries and list this cell (and not
+    the regions cell, whose child reads the others:
+    tests/test_regions96_served.py), each over a window in which writes
+    happen: a finite number from this program."""
+    assert CELL == REFRESH
+    spec = pending_metrics()[name]
+    got = read_pending(name, spec, window_data(loadgen_result))
+    assert finite(got), (name, got)
+    if spec["pending_entry"]["unit"] == "%":
+        assert got <= 100
+    if name == "txn.rpcs_per_task":
+        # a session's two orders are four RPCs at least (a prewrite and
+        # a commit each) beside its read's twelve cop tasks
+        assert got >= 4 / N / 2
+
+
+def test_the_childs_writes_are_traced_on_one_clock(loadgen_result):
+    """Every write of the window has its envelope's rows and its send
+    stamp was believed (the aggregate is the process's: rises, and a
+    write in flight at either sample is counted by one row and not yet
+    by the other, one a session at most)."""
+    go, end = (loadgen_result[k]["health"]
+               for k in ("counters_go", "counters_end"))
+    rose = {m: n - go["txn"]["rpcs"][m]
+            for m, n in end["txn"]["rpcs"].items()}
+    assert rose["KvPrewrite"] > 0 and rose["KvCommit"] >= rose["KvPrewrite"]
+    assert end["txn"]["wire_clock_unshared"] == 0
+
+    def rise(name):
+        return end["tracing"]["phases"][name]["count"] - \
+            go["tracing"]["phases"][name]["count"]
+
+    sessions = 4        # (the traffic file's closed loops)
+    assert abs(rise("txn_rpc") - sum(rose.values())) <= sessions
+    for name in ("txn_wire_request", "txn_accept_wait", "txn_reply"):
+        assert abs(rise(name) - rise("txn_rpc")) <= sessions, name
 
 
 # ------------------------------------------------- readers that race writers

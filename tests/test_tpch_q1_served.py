@@ -240,10 +240,11 @@ def test_the_cells_files_agree_on_the_layout(table_kind, params, kind):
         assert ours[key] == theirs[key]
     assert ours["exactness"] != theirs["exactness"]
     assert set(theirs := q6["assumed"]) < set(config["assumed"])
-    # the cell reports the ten shared layer metrics and its own three
+    # the cell reports the ten shared layer metrics, its own three and
+    # (PR 51) the share of the dispatcher's hold that has a name
     mine = sorted(m["name"] for m in manifest["per_layer"]
                   if CELL in m.get("workloads", ()))
-    assert len(mine) == 13
+    assert len(mine) == 14 and "dispatcher.hold_named_share" in mine
     assert [m for m in mine if "q1" in m or "planes" in m or
             "composite" in m] == ["kernel.composite_key_launch_share",
                                   "kernel.pallas_q1_region_roofline",

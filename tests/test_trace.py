@@ -26,7 +26,7 @@ from tikv_tpu.utils import failpoint
 from tikv_tpu.utils import trace as trace_mod
 from tikv_tpu.utils import tracker
 from tikv_tpu.utils.trace import TraceBuffer, Tracker, to_chrome
-from tikv_tpu.utils.trace_vocab import SPAN_VOCABULARY
+from tikv_tpu.utils.trace_vocab import OUTSIDE_ROOT, SPAN_VOCABULARY
 
 
 @pytest.fixture(autouse=True)
@@ -424,6 +424,235 @@ def test_aggregate_cpu_is_over_the_spans_that_took_the_clock(
     assert trace_mod._takes_cpu(Tracker(trace_id="asked-for"))
 
 
+# ------------------------------------------- a thread's hold (PR 51)
+#
+# ``trace.hold`` opens the dispatcher's ``group_dispatch`` as a ledger:
+# the rows of trace_vocab.HOLD_ROWS that run inside it and its
+# ``dispatch_self`` add up to it, to the nanosecond under an injected
+# clock.
+
+
+class _Clock:
+    """``time`` for utils/trace.py with a hand that only the test moves."""
+
+    def __init__(self):
+        self.now = 1_000_000
+
+    def perf_counter_ns(self):
+        return self.now
+
+    def thread_time_ns(self):
+        return self.now // 2
+
+    def tick(self, ns):
+        self.now += ns
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def _one_hold(clock):
+    """One hold shaped as a staging of the refresh cell's is: a span of
+    another vocabulary nested inside a named row (never subtracted), a
+    row that turns into the next, whole-wall leaves inside self-time
+    rows.  → what each row should have gained, in ns."""
+    with tracker.hold("group_dispatch", "dispatch_self", "feedc0de"):
+        clock.tick(5)                               # nobody's
+        with tracker.held("group_open"):
+            clock.tick(7)
+        with tracker.held("stage_plan") as piece:
+            clock.tick(3)
+            with tracker.held("memo_roll") as roll:
+                clock.tick(11)
+                with tracker.span("decimal_lower"):
+                    clock.tick(2)                   # stays memo_roll's
+                roll.note(outcome="kept")
+            piece.turn("stage_full")
+            clock.tick(13)
+            with tracker.held("feed_get"):
+                clock.tick(1)
+                with tracker.phase("feed_patch"):
+                    clock.tick(17)
+                    with tracker.span("feed_host_pad"):
+                        clock.tick(4)
+                clock.tick(2)
+            with tracker.phase("host_derive"):
+                clock.tick(19)
+            with tracker.held("lanes_launch"):
+                clock.tick(2)
+                with tracker.phase("device_dispatch"):
+                    clock.tick(23)
+                clock.tick(1)
+            piece.turn(None)
+            clock.tick(6)                           # nobody's
+            piece.turn("group_complete", traced=False)
+            clock.tick(29)
+    return {"group_open": 7, "stage_plan": 3, "memo_roll": 13,
+            "stage_full": 13, "feed_get": 3, "feed_patch": 21,
+            "host_derive": 19, "lanes_launch": 3, "device_dispatch": 23,
+            "group_complete": 29, "dispatch_self": 11,
+            "group_dispatch": 145}
+
+
+@pytest.mark.parametrize("traced", ["leader", "unsampled", "nobody"])
+def test_a_holds_rows_and_its_self_time_add_up_to_it(
+        fresh_aggregate, monkeypatch, traced):
+    from tikv_tpu.utils.trace_vocab import HOLD_ROWS, HOLD_SELF, HOLD_WHOLE
+    clock = _Clock()
+    monkeypatch.setattr(trace_mod, "time", clock)
+    monkeypatch.setattr(trace_mod, "_takes_cpu", lambda tr=None: False)
+    assert HOLD_ROWS == set(HOLD_SELF) | set(HOLD_WHOLE)
+    tr = tok = None
+    if traced != "nobody":
+        tr, tok = tracker.install(sampled=traced == "leader")
+    try:
+        want = _one_hold(clock)
+        rows = fresh_aggregate._rows
+        if tr is None:
+            # a phase needs a tracker (as ever) and the hold's own rows
+            # do not: each then keeps what ran unnamed inside it
+            for outer, name in (("feed_get", "feed_patch"),
+                                ("stage_full", "host_derive"),
+                                ("lanes_launch", "device_dispatch")):
+                want[outer] += want.pop(name)
+        assert {n: rows[n][1] for n in want} == want
+        assert sum(rows[n][1] for n in HOLD_ROWS) + \
+            rows["dispatch_self"][1] == rows["group_dispatch"][1]
+        # over a window: a second hold, the rises add up as well
+        first = {n: rows[n][1] for n in rows}
+        _one_hold(clock)
+        rise = {n: rows[n][1] - first[n] for n in rows}
+        assert sum(rise[n] for n in HOLD_ROWS) + rise["dispatch_self"] == \
+            rise["group_dispatch"] == 145
+        assert rows["group_dispatch"][0] == rows["dispatch_self"][0] == 2
+    finally:
+        if tok is not None:
+            tracker.uninstall(tok)
+    if tr is None:
+        return
+    tr.finish()
+    # the leader's flat phases hold each instant once: self times
+    # (group_complete is no request's: the row and the annotation alone)
+    named = {n: ns for n, ns in want.items()
+             if n in HOLD_ROWS and n != "group_complete"}
+    assert {n: tr.phases.get(n) for n in named} == \
+        {n: 2 * ns for n, ns in named.items()}
+    assert "group_complete" not in tr.phases
+    assert sum(tr.phases.values()) <= tr.total_ns()
+    if traced == "leader":
+        by = {}
+        for sp in tr.spans:
+            by.setdefault(sp.name, []).append(sp)
+        # a span keeps its whole interval; the roll says how it ended
+        full = by["stage_full"][0]
+        assert full.t1 - full.t0 == 13 + 24 + 19 + 26
+        assert by["memo_roll"][0].attrs == {"outcome": "kept"}
+        assert len(by["stage_plan"]) == len(by["stage_full"]) == 2
+    else:
+        assert tr.spans == []
+
+
+def test_inside_a_hold_the_jitted_calls_take_the_cpu_clock_every_time(
+        fresh_aggregate, monkeypatch):
+    """``feed_patch`` / ``feed_rebuild`` / ``device_dispatch`` inside a
+    hold read the thread CPU clock each time (their offcpu_ms is a full
+    sum there); outside one, and every other name, on the sample."""
+    monkeypatch.setattr(trace_mod, "_takes_cpu", lambda tr=None: False)
+    tr, tok = tracker.install()
+    try:
+        for name in ("feed_patch", "feed_rebuild", "device_dispatch",
+                     "host_derive"):
+            with tracker.phase(name):
+                pass
+            with tracker.hold("group_dispatch", "dispatch_self"):
+                with tracker.held("stage_full"):
+                    with tracker.phase(name):
+                        time.sleep(0.002)
+    finally:
+        tracker.uninstall(tok)
+    rows = fresh_aggregate.snapshot()
+    for name in ("feed_patch", "feed_rebuild", "device_dispatch"):
+        assert (rows[name]["count"], rows[name]["cpu_samples"]) == (2, 1)
+        assert rows[name]["offcpu_ms"] >= 1.5
+    assert rows["host_derive"]["cpu_samples"] == 0
+    assert rows["stage_full"]["cpu_samples"] == 0
+
+
+def test_a_held_piece_takes_the_cpu_clock_where_its_wall_is_all_its_own(
+        fresh_aggregate, monkeypatch):
+    """On the sample a piece reads the thread CPU clock, and keeps the
+    reading where no row of the hold ran inside it (its self time is its
+    wall); a write's root span does the same, a read's never."""
+    monkeypatch.setattr(trace_mod, "_takes_cpu", lambda tr=None: True)
+    with tracker.hold("group_dispatch", "dispatch_self"):
+        with tracker.held("stage_plan") as piece:
+            time.sleep(0.002)
+            piece.turn("stage_full")
+            with tracker.held("feed_get"):
+                time.sleep(0.002)
+    for envelope in (trace_mod.READ_ENVELOPE, trace_mod.TXN_ENVELOPE):
+        tr, tok = tracker.install(envelope=envelope)
+        time.sleep(0.002)
+        tracker.uninstall(tok)
+        tr.finish()
+    rows = fresh_aggregate.snapshot()
+    assert rows["stage_plan"]["cpu_samples"] == 1
+    assert rows["stage_plan"]["offcpu_ms"] >= 1.5
+    assert rows["feed_get"]["cpu_samples"] == 1
+    assert rows["stage_full"]["cpu_samples"] == 0   # (feed_get inside)
+    assert rows["txn_rpc"]["cpu_samples"] == 1
+    assert rows["txn_rpc"]["offcpu_ms"] >= 1.5
+    assert (rows["rpc"]["count"], rows["rpc"]["cpu_samples"]) == (1, 0)
+
+
+def test_outside_a_hold_its_rows_do_not_exist(fresh_aggregate,
+                                              recorded_annotations):
+    """A launch a request's own thread stages records none of the hold's
+    rows: no phase, no span, no annotation, no aggregate row."""
+    from tikv_tpu.utils.trace_vocab import HOLD_SELF
+    tr, tok = tracker.install(trace_id="0ff0ff")
+    try:
+        for name in HOLD_SELF:
+            with tracker.held(name) as piece:
+                piece.note(outcome="kept")
+                piece.turn("stage_full")
+                with tracker.phase("device_dispatch"):
+                    pass
+    finally:
+        tracker.uninstall(tok)
+    tr.finish()
+    assert set(tr.phases) == {"device_dispatch"}
+    assert {sp.name for sp in tr.spans} == {"rpc", "device_dispatch"}
+    assert {n for n, _kw in recorded_annotations} == \
+        {"copr:device_dispatch"}
+    rows = fresh_aggregate.snapshot()
+    assert all(rows.get(n, {"count": 0})["count"] == 0 for n in HOLD_SELF)
+    assert set(HOLD_SELF) <= trace_mod.ANNOTATED
+    assert "dispatch_self" not in trace_mod.ANNOTATED
+
+
+def test_a_hold_inside_a_hold_is_the_outer_ones_child(fresh_aggregate,
+                                                      monkeypatch):
+    """A shutdown's inline dispatch opens a hold on a thread that has
+    one open: the inner one keeps its own ledger and is not the outer
+    one's self time."""
+    clock = _Clock()
+    monkeypatch.setattr(trace_mod, "time", clock)
+    with tracker.hold("group_dispatch", "dispatch_self"):
+        clock.tick(3)
+        with tracker.hold("group_dispatch", "dispatch_self"):
+            with tracker.held("group_open"):
+                clock.tick(5)
+            clock.tick(2)
+        with tracker.held("group_complete"):
+            clock.tick(7)
+    rows = fresh_aggregate._rows
+    assert rows["group_dispatch"][:2] == [2, 7 + 17]
+    assert rows["dispatch_self"][:2] == [2, 2 + 3]
+    assert (rows["group_open"][1], rows["group_complete"][1]) == (5, 7)
+    assert trace_mod._held.frames is None
+
+
 def test_gc_pause_counts_and_reenters_the_aggregate(fresh_aggregate):
     import gc
     trace_mod.watch_gc()
@@ -544,14 +773,19 @@ def test_span_vocabulary_inventory():
     pat = re.compile(
         r'(?:\bphase|\badd_phase|\bspan|\badd_span|\btimed'
         r'|\bbegin|\blink_from|_new_span|AGGREGATE\.add|_annotation'
-        r'|\bclient_phase)'
+        r'|\bclient_phase|\bheld|\bhold|\.turn)'
         r'\(\s*\n?\s*"([a-z0-9_]+)"')
+    # (a hold names two rows: its own and the one its self time goes to)
+    hold_self = re.compile(r'\bhold\(\s*"[a-z0-9_]+",\s*"([a-z0-9_]+)"')
     used = set()
     for p in root.rglob("*.py"):
-        used |= set(pat.findall(p.read_text()))
+        text = p.read_text()
+        used |= set(pat.findall(text)) | set(hold_self.findall(text))
     # names minted through module constants (the root span + the
-    # synthesized residual)
+    # synthesized residual; an RPC's envelope rows, a read's and a
+    # write's)
     used |= {trace_mod.ROOT_SPAN_NAME, trace_mod.UNTRACKED_NAME}
+    used |= set(trace_mod.READ_ENVELOPE) | set(trace_mod.TXN_ENVELOPE)
     assert len(used) >= 20, f"span scan found only {sorted(used)}"
     unknown = used - set(SPAN_VOCABULARY)
     assert not unknown, \
@@ -1050,6 +1284,159 @@ def test_e2e_rpc_envelope_outside_the_root_span(rig):
     assert "rpc_reply" not in resp["time_detail"]["phases_ms"]
     assert not {"rpc_accept_wait", "rpc_reply"} & \
         {s["name"] for s in doc["spans"]}
+
+
+# ------------------------------------------------ a write has a trace (PR 51)
+
+
+def _rows(rig_d, names):
+    phases = _health_tracing(rig_d)["phases"]
+    return {n: (phases[n]["count"], phases[n]["wall_ms"]) for n in names}
+
+
+_READ_ROWS = ("rpc", "rpc_accept_wait", "rpc_reply")
+_TXN_ROWS = ("txn_rpc", "txn_accept_wait", "txn_reply", "txn_wire_request",
+             "sched_latch_wait", "sched_snapshot", "sched_process",
+             "raft_write_wait", "raft_apply_wait", "raft_wake_wait")
+
+
+def _health(rig_d):
+    return json.load(urllib.request.urlopen(rig_d["base_url"] + "/health"))
+
+
+def test_e2e_a_write_rpc_is_traced_under_rows_of_its_own(rig):
+    """A transaction's RPCs, as ``txn_write`` sends them: every reply
+    carries ``time_detail`` with the seven stamps of its path in order
+    and the scheduler's and raft's phases; the store's rows for it are
+    ``txn_*`` / ``sched_*`` / ``raft_*``, and neither the reads' envelope
+    rows nor ``coprocessor.requests_served`` move across a write."""
+    from tikv_tpu.testing.fixture import encode_table_row
+    c = rig["client"]
+    key, value = encode_table_row(rig["table"], 900_001,
+                                  {"c0": 1, "c1": 2})
+    before = _health(rig)
+    rows0 = _rows(rig, _READ_ROWS + _TXN_ROWS)
+    start_ts = c.tso()
+    replies = [
+        c._call_leader(key, "KvPrewrite", {
+            "mutations": [{"op": "put", "key": key, "value": value}],
+            "primary": key, "start_version": start_ts,
+            "trace_id": "7e57ab1e00000001"}),
+        c._call_leader(key, "KvCommit", {
+            "keys": [key], "start_version": start_ts,
+            "commit_version": c.tso()})]
+    c.txn_write([("delete", key, None)])        # two more, unseen
+    after = _health(rig)
+    rows1 = _rows(rig, _READ_ROWS + _TXN_ROWS)
+    for resp in replies:
+        td = resp["time_detail"]
+        ck = td["clock_ns"]
+        assert ck["call"] <= ck["sent"] <= ck["accept"] <= ck["t0"] <= \
+            ck["t1"] <= ck["bytes_in"] <= ck["decoded"]
+        assert "wire_clock" not in td.get("labels", {})
+        phases = td["phases_ms"]
+        assert {"sched_latch_wait", "sched_snapshot", "sched_process",
+                "raft_write_wait", "raft_wake_wait", "client_encode", "wire_request",
+                "rpc_accept_wait", "wire_reply", "client_decode"} <= \
+            set(phases)
+        inside = sum(v for k, v in phases.items()
+                     if k not in OUTSIDE_ROOT)
+        assert inside <= td["total_rpc_wall_ms"] + 0.01
+        # the whole path adds up to the caller's wall, as a read's does
+        whole = sum(phases[k] for k in OUTSIDE_ROOT if k in phases) \
+            + td["total_rpc_wall_ms"]
+        assert whole == pytest.approx(
+            (ck["decoded"] - ck["call"]) / 1e6, abs=0.01)
+    assert replies[0]["trace_id"] == "7e57ab1e00000001"
+    # a span tree for whoever asked by id; the commit, which did not,
+    # has its phases and no tree
+    assert rig["node"].trace_buffer.get(replies[1]["trace_id"]) is None
+    doc = _fetch_trace(rig, "7e57ab1e00000001")
+    names = [s["name"] for s in doc["spans"]]
+    assert names[0] == "txn_rpc" and "rpc" not in names
+    wait = next(s for s in doc["spans"] if s["name"] == "raft_write_wait")
+    kids = [s for s in doc["spans"] if s["parent_id"] == wait["span_id"]]
+    assert [s["name"] for s in kids] == ["raft_propose_wait",
+                                         "raft_apply_wait"]
+    assert sum(s["dur_us"] for s in kids) == \
+        pytest.approx(wait["dur_us"], abs=0.3)
+    for name in _TXN_ROWS:
+        assert rows1[name][0] - rows0[name][0] == 4, name
+    assert rows1["txn_rpc"][1] > rows0["txn_rpc"][1]
+    # the reads' rows, and the count of cop tasks, do not hold a write
+    assert {n: rows1[n] for n in _READ_ROWS} == \
+        {n: rows0[n] for n in _READ_ROWS}
+    assert after["coprocessor"] == before["coprocessor"]
+    rose = {m: n - before["txn"]["rpcs"][m]
+            for m, n in after["txn"]["rpcs"].items()}
+    assert rose == {"KvPrewrite": 2, "KvCommit": 2, "KvBatchRollback": 0,
+                    "KvCleanup": 0, "KvCheckTxnStatus": 0,
+                    "KvResolveLock": 0, "KvPessimisticLock": 0}
+    assert after["txn"]["wire_clock_unshared"] == \
+        before["txn"]["wire_clock_unshared"]
+
+
+@pytest.mark.parametrize("sent", ["absent", "ahead", "stale", "garbage",
+                                  "shared"])
+def test_e2e_a_send_stamp_is_believed_only_on_one_clock(rig, sent):
+    """``txn_wire_request`` gains ``accept - sent`` where the request's
+    ``clock_ns.sent`` is this machine's clock and not ahead of the
+    store's accept stamp; anything else adds nothing and is counted."""
+    from tikv_tpu.server import wire
+    from tikv_tpu.testing.fixture import encode_table_row
+    c = rig["client"]
+    key, value = encode_table_row(rig["table"], 900_002,
+                                  {"c0": 3, "c1": 4})
+    client, _region = c._leader_client(key)
+    req = {"mutations": [{"op": "put", "key": key, "value": value}],
+           "primary": key, "start_version": c.tso()}
+    now = time.perf_counter_ns()
+    stamp = {"ahead": now + 10_000_000_000,
+             "stale": now - 61_000_000_000, "garbage": "soon",
+             "shared": now}.get(sent)
+    if sent != "absent":
+        req["clock_ns"] = {"sent": stamp}
+    before = _health(rig)["txn"]
+    row0 = _rows(rig, ("txn_wire_request", "txn_rpc"))
+    # (below StoreClient.call, which would stamp its own)
+    resp = client._chan.unary_unary(
+        "/tikv.Tikv/KvPrewrite", request_serializer=wire.pack,
+        response_deserializer=wire.unpack)(req, timeout=10)
+    assert not resp.get("error"), resp
+    after = _health(rig)["txn"]
+    row1 = _rows(rig, ("txn_wire_request", "txn_rpc"))
+    believed = sent == "shared"
+    assert row1["txn_wire_request"][0] - row0["txn_wire_request"][0] == \
+        int(believed)
+    if believed:
+        assert 0 < row1["txn_wire_request"][1] - \
+            row0["txn_wire_request"][1] < 10_000
+    else:
+        assert row1["txn_wire_request"][1] == row0["txn_wire_request"][1]
+    assert after["wire_clock_unshared"] - before["wire_clock_unshared"] \
+        == int(not believed)
+    assert row1["txn_rpc"][0] - row0["txn_rpc"][0] == 1
+    assert resp["time_detail"]["clock_ns"].keys() == {"accept", "t0", "t1"}
+    c._call_leader(key, "KvBatchRollback", {
+        "keys": [key], "start_version": req["start_version"]})
+
+
+def test_a_write_whose_trace_fails_is_still_answered(rig, monkeypatch):
+    """The trace watches the write: a seal that raises costs the reply
+    its ``time_detail``, never its answer."""
+    from tikv_tpu.server.service import KvService
+    from tikv_tpu.testing.fixture import encode_table_row
+    c = rig["client"]
+    key, value = encode_table_row(rig["table"], 900_003,
+                                  {"c0": 5, "c1": 6})
+
+    def boom(self, *a, **kw):
+        raise RuntimeError("seal broke")
+    monkeypatch.setattr(KvService, "_seal_traced", boom)
+    ts = c.txn_write([("put", key, value)])
+    monkeypatch.undo()
+    assert c.get(key, version=c.tso()) == value and ts > 0
+    c.txn_write([("delete", key, None)])
 
 
 def test_e2e_wait_children_split_where_it_happens(rig):

@@ -769,8 +769,14 @@ class DeviceAggregator:
 
         → the lanes whose launch FAILED (unbound; the caller releases
         their pins and their members retry solo; a failed launch of
-        ONE lane is the kernel's own: ``_pallas_failed``).
+        ONE lane is the kernel's own: ``_pallas_failed``).  On the
+        dispatcher the launch's own time around ``device_dispatch`` is
+        the hold's row ``lanes_launch``.
         """
+        with trace.held("lanes_launch"):
+            return self._launch_lanes(lanes)
+
+    def _launch_lanes(self, lanes: list) -> list:
         runner = self._runner
         by_key: dict = {}
         for p in lanes:

@@ -474,7 +474,7 @@ def arg_byte_planes(plan, bounds, dtypes) -> tuple:
 
 
 def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
-                 recorder, journal_depth: int) -> None:
+                 recorder, journal_depth: int) -> str:
     """Roll the derived record in ``meta`` across ``patches``, the
     journal entries of a lineage gap (``FeedLineage.since``; None: the
     journal no longer covers it), from the rows the entries INTRODUCED:
@@ -492,10 +492,12 @@ def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
     entries the lineage's journal keeps (``FeedLineage.depth``): the
     host planes lag the record by no more.  Counted on ``recorder``
     (/health ``device_mesh.memo``): kept, or dropped by its cause, and
-    what became of the host planes it held."""
+    what became of the host planes it held.  → the same as a word for
+    the ``memo_roll`` span: ``kept`` / ``dropped:<cause>``
+    (``underived``: there was no record)."""
     if "dtypes" not in meta:
         _drop(meta)
-        return                  # nothing was derived yet
+        return "underived"      # nothing was derived yet
     if patches is None or any("introduced" not in p for p in patches) or \
             not plan.lowered and any(p.get("structural") for p in patches):
         # (a plan that is not lowered keeps its constants across row
@@ -531,6 +533,7 @@ def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
                     meta.pop("host_gap", None)
                     fate = "dropped"
     recorder.note_memo(cause, fate)
+    return "kept" if cause is None else f"dropped:{cause}"
 
 
 def _drop(meta: dict) -> None:
@@ -815,7 +818,13 @@ class FeedStore:
         at generation ``req_v``, from the cheapest rung that has it: the
         arena's (hit), that one patched forward, or compacted where the
         gap is tombstones alone, a split's stash, the device MVCC
-        resolve's bundle, the upload."""
+        resolve's bundle, the upload.  On the dispatcher the ladder's
+        own time between its rungs is the hold's row ``feed_get``."""
+        with tracker.held("feed_get"):
+            return self._get(storage, planes, ranges, n, lineage, req_v)
+
+    def _get(self, storage, planes: HostPlanes, ranges, n: int, lineage,
+             req_v) -> dict:
         scan, used_infos, dtypes = planes.plan.scan, planes.infos, \
             planes.dtypes()
         feed_key = (tuple(i.col_id for i in used_infos), dtypes, ranges)

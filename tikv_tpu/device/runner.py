@@ -2225,7 +2225,18 @@ class DeviceRunner:
         ``handle_lanes``, which holds the dispatch lock and launches
         the lanes' kernels itself; a lane that would be served on the
         host raises ``_BatchUnavailable`` instead, as a stacked group
-        does."""
+        does.
+
+        On the coalescer's dispatcher (``trace.hold``) the staging is
+        two rows of the hold: ``stage_plan`` up to the branch (all of a
+        hit), then ``stage_full``."""
+        from ..utils import tracker
+        with tracker.held("stage_plan") as piece:
+            return self._stage_local(dag, storage, deferred, _stack,
+                                     _lanes, piece)
+
+    def _stage_local(self, dag: DAGRequest, storage, deferred: bool,
+                     _stack, _lanes: bool, piece):
         plan = self._analyze(dag)
         if plan is None:
             raise RuntimeError("plan not supported by device backend")
@@ -2361,6 +2372,7 @@ class DeviceRunner:
                         pin_anchor = None
             # the full staging: the used columns' host halves, each
             # derived at most once
+            piece.turn("stage_full")
             planes = HostPlanes(plan, meta, memo, memo_fresh, get_batch, n,
                                 self.flight_recorder)
             dtypes = planes.dtypes()
@@ -2594,9 +2606,11 @@ class DeviceRunner:
             self.flight_recorder.note_prepared("refresh")
         meta.pop("key_dense", None)     # (likewise: run_hash)
         meta.pop("key_dense_tiled", None)
-        roll_derived(meta, plan, lineage.since(from_v, until=to_v),
-                     count_rows, self._limb_variant, self.flight_recorder,
-                     lineage.depth)
+        from ..utils import tracker
+        with tracker.held("memo_roll") as roll:
+            roll.note(outcome=roll_derived(
+                meta, plan, lineage.since(from_v, until=to_v), count_rows,
+                self._limb_variant, self.flight_recorder, lineage.depth))
         meta["lineage_v"] = to_v
 
     def _result(self, dag, schema, columns) -> "SelectResult":

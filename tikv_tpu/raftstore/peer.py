@@ -269,6 +269,11 @@ class RaftPeer:
                 raise KeyNotInRegion(op.key, region)
 
     def propose(self, cmd: RaftCmd, cb: Callable) -> int:
+        # (a traced write's callback keeps the instant the thread that
+        # proposes took the command: raftkv.py _WriteCallback)
+        stamp = getattr(cb, "proposed", None)
+        if stamp is not None:
+            stamp()
         with self.mu:
             self.wake()
             return self._propose_locked(cmd, cb)

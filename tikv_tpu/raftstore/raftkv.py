@@ -10,12 +10,35 @@ reference blocking on the apply callback.
 
 from __future__ import annotations
 
+import time
 from typing import Callable, Optional
 
 from ..kv.engine import SnapContext, WriteData
+from ..utils import tracker
 from .cmd import RaftCmd, WriteOp
 from .metapb import NotLeaderError
 from .store import RaftStore
+
+
+class _WriteCallback:
+    """A proposed write's apply callback, which also stamps the command's
+    way WHERE it happens: ``proposed()`` on the thread that proposes it
+    (``Peer.propose``: the peer's poller on a pooled store), the call
+    itself on the thread that applied it.  ``RaftKv.write`` reads the
+    stamps once its wait is over."""
+
+    __slots__ = ("box", "t_proposed", "t_applied")
+
+    def __init__(self):
+        self.box: dict = {}
+        self.t_proposed = self.t_applied = None
+
+    def proposed(self) -> None:
+        self.t_proposed = time.perf_counter_ns()
+
+    def __call__(self, result) -> None:
+        self.t_applied = time.perf_counter_ns()
+        self.box["result"] = result
 
 
 class RaftKv:
@@ -120,24 +143,42 @@ class RaftKv:
             else:
                 ops.append(WriteOp("delete", cf, key))
         cmd = RaftCmd(peer.region.id, peer.region.epoch, tuple(ops))
-        import time as _time
-        t0 = _time.perf_counter()
-        box: dict = {}
+        cb = _WriteCallback()
+        t0_ns = time.perf_counter_ns()
         if self.store.pooled():
             # proposals ride the mailbox: the peer's poller serializes
             # them with ready handling (fsm/peer.rs PeerMsg::RaftCommand)
-            if not self.store._route_peer_msg(
-                    peer.region.id,
-                    ("cmd", cmd,
-                     lambda r: box.__setitem__("result", r))):
+            if not self.store._route_peer_msg(peer.region.id,
+                                              ("cmd", cmd, cb)):
                 raise NotLeaderError(peer.region.id)    # mailbox gone
         else:
-            peer.propose(cmd, lambda r: box.__setitem__("result", r))
+            peer.propose(cmd, cb)
         try:
-            self._wait(box)
+            self._wait(cb.box)
         finally:
+            self._note_write_wait(t0_ns, cb)
             if self._latency_inspector is not None:
-                self._latency_inspector(_time.perf_counter() - t0)
+                self._latency_inspector(
+                    (time.perf_counter_ns() - t0_ns) / 1e9)
+
+    @staticmethod
+    def _note_write_wait(t0_ns: int, cb: _WriteCallback) -> None:
+        """``raft_write_wait`` on the write RPC's tracker: the command
+        handed over → its callback fired, and inside it the two stamps
+        taken where they happened (a command that never got that far has
+        none); then ``raft_wake_wait``, what the caller's wait still
+        took after the callback (the driver's poll, the node lock, the
+        GIL)."""
+        if cb.t_applied is None:
+            return
+        now = time.perf_counter_ns()
+        sp = tracker.add_phase("raft_write_wait", cb.t_applied - t0_ns,
+                               cb.t_applied)
+        if cb.t_proposed is not None:
+            tracker.add_span("raft_propose_wait", t0_ns, cb.t_proposed, sp)
+            tracker.add_span("raft_apply_wait", cb.t_proposed,
+                             cb.t_applied, sp)
+        tracker.add_phase("raft_wake_wait", now - cb.t_applied, now)
 
     def kv_engine(self):
         return self.store.engine

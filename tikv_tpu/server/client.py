@@ -50,11 +50,17 @@ class StoreClient:
         ``encode_type``) comes back with ``chunk`` decoded
         (``wire.dec_chunk``), inside ``client_decode``.  ``resend``: the
         call carries again a command whose stream died, and says so in
-        its metadata (the store counts them)."""
+        its metadata (the store counts them).  A txn write's request
+        leaves with ``clock_ns.sent``, this clock right before the pack:
+        the store places its accept stamp behind it
+        (``txn_wire_request``)."""
         t_call = time.perf_counter_ns()
         at = [0, 0, 0]      # sent, bytes_in, decoded
+        stamps = method in wire.TXN_WRITE_METHODS
 
         def pack(obj):
+            if stamps:
+                obj = dict(obj, clock_ns={"sent": time.perf_counter_ns()})
             raw = wire.pack(obj)
             at[0] = time.perf_counter_ns()
             return raw

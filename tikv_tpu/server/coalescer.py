@@ -798,11 +798,18 @@ class RequestCoalescer:
         from ..utils import tracker
         t_begin_ns = time.perf_counter_ns()
         lead = group.members[0].tracker if group.members else None
-        with tracker.timed("group_dispatch",
-                           lead.trace_id if lead is not None else None):
-            self._stage(group, t_begin_ns, merged)
+        # the hold: every row of trace_vocab.HOLD_ROWS that runs on this
+        # thread until it closes is accounted against it, and what none
+        # of them covered is its ``dispatch_self``
+        with tracker.hold("group_dispatch", "dispatch_self",
+                          lead.trace_id if lead is not None else None), \
+                tracker.held("group_open") as piece:
+            self._stage(group, t_begin_ns, merged, piece)
 
-    def _stage(self, group: _Group, t_begin_ns: int, merged=()) -> None:
+    def _stage(self, group: _Group, t_begin_ns: int, merged, piece) -> None:
+        """``piece``: the hold's open row, ``group_open`` on entry; it
+        is turned off where the runner is called (the staging's rows are
+        the runner's) and to ``group_complete`` where it returned."""
         from ..device.runner import (
             DeferredResult,
             _BatchUnavailable,
@@ -899,6 +906,7 @@ class RequestCoalescer:
             with GLOBAL_RECORDER.group_scope(meter_members):
                 if fail_point("copr::coalesce_dispatch") is not None:
                     raise _BatchUnavailable("copr::coalesce_dispatch")
+                piece.turn(None)
                 if len(lanes) > 1:
                     # closed groups of one launch class: ONE staging,
                     # one program, one fetch; a lane the runner could
@@ -921,6 +929,9 @@ class RequestCoalescer:
                     outcomes = [self._runner.handle_request(
                         members[0].dag, members[0].storage,
                         deferred=True)]
+                # (no request waits for what follows but for its own
+                # hand-over: the row and the annotation alone)
+                piece.turn("group_complete", traced=False)
                 if outcomes is not None:
                     resolvers, infos = [], []
                     for lane, d in zip(lanes, outcomes):
@@ -941,6 +952,7 @@ class RequestCoalescer:
                 tracker.uninstall(lead_tok)
                 lead_tok = None
             self.router.note_launch(time.perf_counter() - t0, size)
+            piece.turn(None)    # stagings again, each with its own rows
             self._solo_fallback(members, t_begin_ns)
             return
         finally:
@@ -970,6 +982,7 @@ class RequestCoalescer:
             self._complete(m, resolve, t_begin_ns, t_staged_ns,
                            None if leads else info, leads=leads)
         if solo:
+            piece.turn(None)
             self._solo_fallback(solo, t_begin_ns)
 
     def _note_lanes(self, infos) -> None:

@@ -47,6 +47,15 @@ _READ_METHODS = {
     "Coprocessor": "normal",
 }
 
+# the txn write RPCs get a tracker too, under an envelope of their own
+# (``txn_rpc`` / ``txn_accept_wait`` / ``txn_reply``: the reads' rows
+# and ``coprocessor.requests_served`` never hold a write)
+_WRITE_METHODS = wire.TXN_WRITE_METHODS
+
+# ``clock_ns.sent`` is believed where it is at most this far behind the
+# handler pool's stamp (the mirror of client.py ``_note_wire``)
+_WIRE_SHARED_NS = 60 * 1_000_000_000
+
 # the slow-query channel (TiKV slow_log!): one redacted line per
 # request over coprocessor.slow_log_threshold_ms
 _slow_query_logger = logging.getLogger("tikv_tpu.slow_query")
@@ -91,6 +100,27 @@ class MuxStats:
             return dict(self._n)
 
 
+class TxnStats:
+    """``/health`` ``txn``: the write RPCs this process has traced, by
+    method, and those whose ``clock_ns.sent`` could not be placed on the
+    store's clock (``txn_wire_request`` then holds nothing of them)."""
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        self._rpcs = dict.fromkeys(sorted(_WRITE_METHODS), 0)
+        self._unshared = 0
+
+    def note(self, method: str, shared: bool) -> None:
+        with self._mu:
+            self._rpcs[method] += 1
+            self._unshared += not shared
+
+    def stats(self) -> dict:
+        with self._mu:
+            return {"rpcs": dict(self._rpcs),
+                    "wire_clock_unshared": self._unshared}
+
+
 class KvService:
     """All RPC handlers over one node's Storage + raftstore."""
 
@@ -112,6 +142,9 @@ class KvService:
         # what batch_commands has carried (the status server reads it
         # off the node: /health batch_commands)
         self.mux_stats = node.mux_stats = MuxStats()
+        # (one a node: a second service over it counts into the same)
+        self.txn_stats = getattr(node, "txn_stats", None) or TxnStats()
+        node.txn_stats = self.txn_stats
 
     # ---------------------------------------------------------- helpers
 
@@ -131,7 +164,13 @@ class KvService:
         if fn is None:
             return {"error": {"kind": "unimplemented", "method": method}}
         prio = _READ_METHODS.get(method)
-        if prio is None:
+        # a txn write is traced as a read is, under an envelope of its
+        # own: a tracker, the accept stamp, ``time_detail`` with
+        # ``clock_ns`` on the reply (the client's ``_note_wire`` then
+        # places its own four stamps).  Same handler, same
+        # acknowledgement: the trace watches
+        write = prio is None and method in _WRITE_METHODS
+        if prio is None and not write:
             return self._dispatch_rpc(method, fn, req, None)
         # per-request causal trace (components/tracker + minitrace):
         # installed BEFORE admission/decode so even a shed or
@@ -153,13 +192,48 @@ class KvService:
                          "trace_sample", 1.0)
         sampled = tid is not None or sample >= 1.0 or \
             (sample > 0.0 and random.random() < sample)
-        tr, tok = tracker.install(trace_id=tid, sampled=sampled)
+        if write:
+            # a write's span TREE is built for whoever asks for it by id;
+            # its flat phases, its reply's time_detail and the aggregate's
+            # rows need none (a third of what tracing a write costs: PERF.md
+            # section 6, PR 51)
+            sampled = tid is not None
+        tr, tok = tracker.install(
+            trace_id=tid, sampled=sampled,
+            envelope=tracker.TXN_ENVELOPE if write
+            else tracker.READ_ENVELOPE)
         tracker.note_accept(tr)
+        if write:
+            self._note_txn_wire(method, req, tr)
         try:
             resp = self._dispatch_rpc(method, fn, req, prio)
         finally:
             tracker.uninstall(tok)
-        return self._seal_traced(method, req, resp, tr)
+        if not write:
+            return self._seal_traced(method, req, resp, tr)
+        try:
+            return self._seal_traced(method, req, resp, tr)
+        except Exception:       # noqa: BLE001 — the answer stands
+            # a trace that fails costs the reply its ``time_detail``,
+            # never the write its answer
+            logging.getLogger(__name__).warning(
+                "sealing a write's trace failed", exc_info=True)
+            return resp
+
+    def _note_txn_wire(self, method: str, req: dict, tr) -> None:
+        """``txn_wire_request``: the client's ``clock_ns.sent`` → the
+        handler pool's stamp, where both are on one clock (``sent`` not
+        ahead of ``accept`` and under a minute behind it); anything else
+        adds nothing and is counted (``txn.wire_clock_unshared``)."""
+        ck = req.get("clock_ns") if isinstance(req, dict) else None
+        sent = ck.get("sent") if isinstance(ck, dict) else None
+        accept = tr.accept_ns
+        shared = isinstance(sent, int) and accept is not None and \
+            0 <= accept - sent < _WIRE_SHARED_NS
+        if shared:
+            from ..utils.trace import AGGREGATE
+            AGGREGATE.add("txn_wire_request", accept - sent)
+        self.txn_stats.note(method, shared)
 
     def handle_raw(self, method: str, raw: bytes):
         """RAW-bytes entry for unary Coprocessor RPCs (server.py binds
@@ -526,7 +600,8 @@ class KvService:
 
     def _seal_traced(self, method: str, req: dict, resp: dict,
                      tr) -> dict:
-        """Completion tail for every traced read: freeze the trace,
+        """Completion tail for every traced RPC (a read; a txn write
+        under its own envelope): freeze the trace,
         echo trace_id + TimeDetail/ScanDetail on the wire (INCLUDING
         error responses — a deadline_exceeded or ServerIsBusy answer
         must be debuggable from the response alone), fire the
@@ -539,9 +614,10 @@ class KvService:
         # line and /debug/trace/<id>) answers "who paid for this" —
         # resource_group was labeled at admission, the RU total
         # accumulated across every charge site this request hit
-        from ..utils.metrics import RU_REQUEST_HISTOGRAM
-        tr.label("ru", f"{tr.ru:.4f}")
-        RU_REQUEST_HISTOGRAM.observe(tr.ru)
+        if method not in _WRITE_METHODS:    # (RU prices reads)
+            from ..utils.metrics import RU_REQUEST_HISTOGRAM
+            tr.label("ru", f"{tr.ru:.4f}")
+            RU_REQUEST_HISTOGRAM.observe(tr.ru)
         if isinstance(resp, dict):
             resp.setdefault("time_detail", tr.time_detail())
             resp.setdefault("scan_detail", tr.scan_detail())

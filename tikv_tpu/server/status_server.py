@@ -121,6 +121,13 @@ class StatusServer:
                         # over coprocessor.requests_served the share of
                         # cop tasks that came by the mux
                         body["batch_commands"] = mux.stats()
+                    txn = getattr(node, "txn_stats", None)
+                    if txn is not None:
+                        # the txn write RPCs traced, by method, and
+                        # those whose client's send stamp was not on
+                        # this clock (service.py TxnStats; their spans
+                        # are tracing.phases' txn_* / sched_* / raft_*)
+                        body["txn"] = txn.stats()
                     fp = getattr(node, "fastpath", None)
                     if fp is not None and hasattr(fp, "stats"):
                         # microsecond warm path: learned wire-template

@@ -69,6 +69,14 @@ def pack_response(obj: Any) -> bytes:
 # stream died under it (the store counts them: /health batch_commands)
 MUX_RESEND_KEY = "tikv-mux-resend"
 
+# The txn write RPCs: the store traces each as a read is traced, under
+# rows of its own (service.py ``handle``), and a client on the store's
+# machine stamps ``clock_ns.sent`` into the request (client.py
+# ``StoreClient.call``).
+TXN_WRITE_METHODS = frozenset({
+    "KvPrewrite", "KvCommit", "KvBatchRollback", "KvCleanup",
+    "KvCheckTxnStatus", "KvResolveLock", "KvPessimisticLock"})
+
 
 def mux_batches(q, stop):
     """What either end sends: blocks for one item of the queue ``q``,

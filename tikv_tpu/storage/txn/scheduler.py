@@ -60,6 +60,12 @@ class TxnScheduler:
                     raise
 
     def _run_once(self, cmd: Command, ctx: Optional[SnapContext]):
+        # (the phases are the write RPC's tracker's: service.py
+        # _handle_write; a command run without one records none)
+        from ...utils import tracker
+        from ...utils.failpoint import fail_point
+        from ...utils.metrics import SCHED_COMMANDS
+        from .commands import Commit, Prewrite
         if ctx is None:
             from ..txn_types import encode_key
             keys = cmd.write_keys()
@@ -67,14 +73,15 @@ class TxnScheduler:
             ctx = SnapContext(key_hint=encode_key(hint) if hint else b"")
         if isinstance(cmd, ResolveLock):
             # read phase before latching (resolve_lock.rs scan → write)
-            cmd.prepare(MvccReader(self._engine.snapshot(ctx)))
-        from ...utils.failpoint import fail_point
-        from ...utils.metrics import SCHED_COMMANDS
-        from .commands import Commit, Prewrite
+            with tracker.phase("sched_snapshot"):
+                snapshot = self._engine.snapshot(ctx)
+            with tracker.phase("sched_process"):
+                cmd.prepare(MvccReader(snapshot))
         SCHED_COMMANDS.labels(type(cmd).__name__).inc()
         fail_point("txn::before_latch")
         cid = self._latches.gen_cid()
-        slots = self._latches.acquire(cid, cmd.write_keys())
+        with tracker.phase("sched_latch_wait"):
+            slots = self._latches.acquire(cid, cmd.write_keys())
         fail_point("txn::after_latch")
         mem_keys = ()
         released: list = []
@@ -99,10 +106,12 @@ class TxnScheduler:
                     mem_keys,
                     [Lock(LockType.PUT, cmd.primary, cmd.start_ts,
                           ttl=cmd.lock_ttl) for _ in mem_keys])
-            snapshot = self._engine.snapshot(ctx)
-            reader = MvccReader(snapshot)
-            txn = MvccTxn(cmd.start_ts)
-            result = cmd.process_write(txn, reader)
+            with tracker.phase("sched_snapshot"):
+                snapshot = self._engine.snapshot(ctx)
+            with tracker.phase("sched_process"):
+                reader = MvccReader(snapshot)
+                txn = MvccTxn(cmd.start_ts)
+                result = cmd.process_write(txn, reader)
             fail_point("txn::before_engine_write")
             if not txn.is_empty():
                 self._engine.write(ctx, WriteData.from_txn(txn))

@@ -57,7 +57,7 @@ from collections import OrderedDict
 from contextlib import contextmanager
 from typing import Optional
 
-from .trace_vocab import SPAN_VOCABULARY
+from .trace_vocab import HOLD_CPU, HOLD_ROWS, SPAN_VOCABULARY
 
 # (trace, ambient parent span) — the span new phases nest under
 _current: contextvars.ContextVar = contextvars.ContextVar(
@@ -65,6 +65,12 @@ _current: contextvars.ContextVar = contextvars.ContextVar(
 
 ROOT_SPAN_NAME = "rpc"
 UNTRACKED_NAME = "untracked"
+# An RPC's envelope: (root span, accept wait, reply), the root span's
+# name and the aggregate rows of the two intervals outside it.  A txn
+# write's rows are its own, so that what reads the reads' rows
+# (service.accept_wait_ms, service.reply_ms) never holds a write.
+READ_ENVELOPE = (ROOT_SPAN_NAME, "rpc_accept_wait", "rpc_reply")
+TXN_ENVELOPE = ("txn_rpc", "txn_accept_wait", "txn_reply")
 
 
 def new_trace_id() -> str:
@@ -123,15 +129,20 @@ class Tracker:
                  "t1", "accept_ns", "wait_ns", "phases", "scan_rows",
                  "scan_bytes",
                  "labels", "_mu", "_next_id", "spans", "root",
-                 "meter_ctx", "ru")
+                 "meter_ctx", "ru", "envelope", "root_c0")
 
     def __init__(self, trace_id: Optional[str] = None,
-                 sampled: bool = True):
+                 sampled: bool = True, envelope: tuple = READ_ENVELOPE):
+        self.envelope = envelope
         # a trace the caller asked for by id takes the thread CPU clock
         # on every span; the others on a sample of them (_takes_cpu)
         self.cpu_all = trace_id is not None
         self.trace_id = trace_id or new_trace_id()
         self.sampled = sampled
+        # a write's root span runs on ONE thread, install to seal: on
+        # the sample, its CPU beside its wall (a read's crosses threads)
+        self.root_c0 = time.thread_time_ns() \
+            if envelope is TXN_ENVELOPE and _takes_cpu(self) else None
         self.t0 = time.perf_counter_ns()
         self.wall_t0 = time.time()
         self.t1: Optional[int] = None       # set by finish()
@@ -152,7 +163,7 @@ class Tracker:
         self.meter_ctx = None
         self.ru = 0.0
         if sampled:
-            self.root = self._new_span(ROOT_SPAN_NAME, None, self.t0)
+            self.root = self._new_span(envelope[0], None, self.t0)
 
     # -- span tree --
 
@@ -215,7 +226,9 @@ class Tracker:
         clamped so export/breakdown see a closed tree."""
         if self.t1 is None:
             self.t1 = time.perf_counter_ns()
-            AGGREGATE.add(ROOT_SPAN_NAME, self.t1 - self.t0)
+            AGGREGATE.add(self.envelope[0], self.t1 - self.t0,
+                          None if self.root_c0 is None
+                          else time.thread_time_ns() - self.root_c0)
         with self._mu:
             for sp in self.spans:
                 if sp.t1 is None:
@@ -434,6 +447,161 @@ def timed(name: str, trace_id: Optional[str] = None):
                       if c0 is None else time.thread_time_ns() - c0)
         if ann is not None:
             ann.__exit__(None, None, None)
+
+
+# ------------------------------------------------- a thread's hold
+#
+# The coalescer's dispatcher paces a cell that writes, and its
+# ``group_dispatch`` is one wall with a few phases inside: ``hold`` opens
+# it as a ledger.  While one is open on a thread, the rows of
+# trace_vocab.HOLD_ROWS that run there nest: a ``held`` scope keeps its
+# SELF time (its wall less the HOLD_ROWS scopes inside it) as its flat
+# phase and its aggregate row, so no instant is in two of them, and the
+# hold closes with ``dispatch_self``, its wall less what its outermost
+# HOLD_ROWS scopes covered.  Over any window the rows and dispatch_self
+# add up to the hold's own row.
+
+class _Held(threading.local):
+    # one int a HOLD_ROWS scope open on this thread, outermost first
+    # (the hold's own): the wall of the HOLD_ROWS scopes that closed
+    # directly inside it.  None: no hold is open here.
+    frames = None
+
+
+_held = _Held()
+
+
+class _Hold:
+    """``hold`` as a context manager (``timed``'s work, and the
+    ledger)."""
+
+    __slots__ = ("name", "self_name", "trace_id", "ann", "c0", "t0",
+                 "outer")
+
+    def __init__(self, name: str, self_name: str,
+                 trace_id: Optional[str]):
+        self.name = name
+        self.self_name = self_name
+        self.trace_id = trace_id
+
+    def __enter__(self) -> "_Hold":
+        self.ann = _annotation(self.name, self.trace_id)
+        self.c0 = time.thread_time_ns() if _takes_cpu() else None
+        self.outer = _held.frames
+        _held.frames = [0]
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *_exc) -> bool:
+        wall = time.perf_counter_ns() - self.t0
+        covered = _held.frames[0]
+        _held.frames = self.outer
+        if self.outer is not None:
+            # a hold inside a hold (a shutdown's inline dispatch): the
+            # outer one's child, whole
+            self.outer[-1] += wall
+        AGGREGATE.add(self.name, wall, None if self.c0 is None
+                      else time.thread_time_ns() - self.c0)
+        AGGREGATE.add(self.self_name, max(0, wall - covered))
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        return False
+
+
+def hold(name: str, self_name: str,
+         trace_id: Optional[str] = None) -> _Hold:
+    """:func:`timed`, and for its duration this thread's HOLD_ROWS
+    scopes are accounted against it: ``self_name``'s aggregate row gets
+    the wall none of them covered."""
+    return _Hold(name, self_name, trace_id)
+
+
+class _HeldScope:
+    """``held`` as a context manager.  Outside a hold: nothing at all
+    (one thread-local read).  Inside: an aggregate row, an annotation,
+    and on the tracker that is active when the piece CLOSES (the
+    coalescer adopts its leader inside ``group_open``) a flat phase and,
+    where it is sampled, a span beside the ambient span's children (the
+    ambient span does not move: a piece may outlive an ``adopt``).  The
+    flat phase and the row get the piece's SELF time.
+
+    ``turn(name)`` closes the piece that is open and opens the next
+    under ``name`` at the same instant: consecutive pieces of one
+    function without nesting its body; ``turn(None)`` opens none (what
+    follows is somebody else's rows, or nobody's: ``dispatch_self``).
+    ``traced=False``: the row and the annotation alone (a piece no
+    request waits for)."""
+
+    __slots__ = ("name", "traced", "frames", "t0", "c0", "ann", "attrs")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.traced = True
+        self.frames = None
+        self.attrs = None
+
+    def __enter__(self) -> "_HeldScope":
+        self.frames = _held.frames
+        if self.frames is not None:
+            self._open()
+        return self
+
+    def __exit__(self, *_exc) -> bool:
+        self.turn(None)
+        self.frames = None
+        return False
+
+    def turn(self, name: Optional[str], traced: bool = True) -> None:
+        if self.frames is None:
+            return
+        if self.name is not None:
+            self._close()
+        self.name, self.traced = name, traced
+        if name is not None:
+            self._open()
+
+    def note(self, **attrs) -> None:
+        """Attributes of the open piece's span."""
+        if self.frames is not None:
+            self.attrs = attrs
+
+    def _open(self) -> None:
+        got = _current.get()
+        self.ann = _annotation(
+            self.name, got[0].trace_id if got is not None else None)
+        self.frames.append(0)
+        self.c0 = time.thread_time_ns() if _takes_cpu() else None
+        self.t0 = time.perf_counter_ns()
+
+    def _close(self) -> None:
+        t1 = time.perf_counter_ns()
+        wall = t1 - self.t0
+        inside = self.frames.pop()
+        own = max(0, wall - inside)
+        self.frames[-1] += wall
+        # on the sample, its CPU: of a piece nothing of HOLD_ROWS ran
+        # inside (a hit's stage_plan, a roll, the hold's two ends), whose
+        # wall is all its own
+        AGGREGATE.add(self.name, own, time.thread_time_ns() - self.c0
+                      if self.c0 is not None and not inside else None)
+        got = _current.get() if self.traced else None
+        if got is not None:
+            tr, parent = got
+            tr.add(self.name, own)
+            if tr.sampled:
+                sp = tr.begin(self.name, parent, self.t0)
+                tr.end(sp, t1)
+                if self.attrs:
+                    tr.annotate_span(sp, **self.attrs)
+        self.attrs = None
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+
+
+def held(name: str) -> _HeldScope:
+    """A row of trace_vocab.HOLD_SELF: exists inside a :func:`hold`
+    alone (a launch a request's own thread stages records none)."""
+    return _HeldScope(name)
 
 
 _gc_t0 = 0
@@ -694,7 +862,11 @@ ANNOTATED = frozenset({
     "feed_patch", "feed_upload", "feed_rebuild", "host_derive",
     "delta_apply",
     "resp_serialize",
-    "rpc_reply"})
+    "rpc_reply",
+    # the hold's own rows (trace_vocab.HOLD_SELF): inside
+    # copr:group_dispatch on the dispatcher's line
+    "group_open", "stage_plan", "memo_roll", "stage_full", "feed_get",
+    "lanes_launch", "group_complete"})
 _ANNOTATION_NAMES = {n: f"copr:{n}" for n in ANNOTATED}
 _annotator = None
 
@@ -727,7 +899,7 @@ class _RpcEnvelope(threading.local):
     span: when gRPC handed it to the pool, and the sealed trace whose
     reply is still to be serialized."""
     submit_ns = None    # pool submit, consumed by the first install
-    reply = None        # (t_finish_ns, cpu0_ns | None, annotation)
+    reply = None        # (t_finish_ns, cpu0_ns | None, annotation, row)
     armed = False       # inside a pool task: a serializer will follow
 
 
@@ -749,30 +921,33 @@ def rpc_task_end() -> None:
 
 
 def note_accept(tr: Tracker) -> None:
-    """``rpc_accept_wait``: pool submit → this tracker's install (the
-    pool's queue, the message receive, the wait for the GIL).  An
-    attribute of the root span and a row of the aggregate; the root
-    span and ``total_rpc_wall_ms`` do not move."""
+    """``rpc_accept_wait`` (a write's: ``txn_accept_wait``): pool
+    submit → this tracker's install (the pool's queue, the message
+    receive, the wait for the GIL).  An attribute of the root span and
+    a row of the aggregate; the root span and ``total_rpc_wall_ms`` do
+    not move."""
     t = _rpc.submit_ns
     if t is None:
         return
     _rpc.submit_ns = None       # a streamed task's later requests: none
     tr.accept_ns = min(t, tr.t0)
     wait = max(0, tr.t0 - t)
-    AGGREGATE.add("rpc_accept_wait", wait)
+    AGGREGATE.add(tr.envelope[1], wait)
     tr.annotate_span(tr.root, rpc_accept_wait_us=round(wait / 1e3, 1))
 
 
 def reply_begin(tr: Tracker) -> None:
-    """``rpc_reply`` opens where the trace was sealed
-    (``Tracker.finish``); :func:`reply_done` closes it when the
-    response serializer returns.  Aggregate only: the reply has left."""
+    """``rpc_reply`` (a write's: ``txn_reply``) opens where the trace
+    was sealed (``Tracker.finish``); :func:`reply_done` closes it when
+    the response serializer returns.  Aggregate only: the reply has
+    left."""
     if not _rpc.armed or tr.t1 is None:
         return
     reply_done()
     _rpc.reply = (tr.t1,
                   time.thread_time_ns() if _takes_cpu(tr) else None,
-                  _annotation("rpc_reply", tr.trace_id))
+                  _annotation(tr.envelope[2], tr.trace_id),
+                  tr.envelope[2])
 
 
 def reply_done() -> None:
@@ -789,24 +964,26 @@ def reply_handoff() -> Optional[tuple]:
     if got is None:
         return None
     _rpc.reply = None
-    t_finish, c0, ann = got
+    t_finish, c0, ann, row = got
     if ann is not None:
         ann.__exit__(None, None, None)
-    return t_finish, None if c0 is None else time.thread_time_ns() - c0
+    return (t_finish, None if c0 is None else time.thread_time_ns() - c0,
+            row)
 
 
 def reply_close(handed: Optional[tuple]) -> None:
     if handed is not None:
-        AGGREGATE.add("rpc_reply", time.perf_counter_ns() - handed[0],
+        AGGREGATE.add(handed[2], time.perf_counter_ns() - handed[0],
                       handed[1])
 
 
 # ------------------------------------------------------------- context
 
-def install(trace_id: Optional[str] = None, sampled: bool = True
+def install(trace_id: Optional[str] = None, sampled: bool = True,
+            envelope: tuple = READ_ENVELOPE
             ) -> tuple[Tracker, contextvars.Token]:
     """Create + activate a tracker; pair with :func:`uninstall`."""
-    tr = Tracker(trace_id=trace_id, sampled=sampled)
+    tr = Tracker(trace_id=trace_id, sampled=sampled, envelope=envelope)
     return tr, _current.set((tr, tr.root))
 
 
@@ -844,7 +1021,7 @@ class _Scoped:
     of these under a saturated GIL."""
 
     __slots__ = ("name", "in_phases", "tr", "sp", "tok", "ann", "c0",
-                 "t0")
+                 "t0", "frames")
 
     def __init__(self, name: str, in_phases: bool):
         self.name = name
@@ -858,7 +1035,18 @@ class _Scoped:
         tr, parent = got
         self.tr = tr
         self.ann = _annotation(self.name, tr.trace_id)
-        self.c0 = time.thread_time_ns() if _takes_cpu(tr) else None
+        # inside a hold (``hold``) a row of the hold's vocabulary is a
+        # child of whatever ``held`` scope is open around it, and a
+        # jitted call's takes the CPU clock every time (HOLD_CPU)
+        frames = _held.frames
+        if frames is not None and self.name in HOLD_ROWS:
+            frames.append(0)
+            self.frames = frames
+            cpu = self.name in HOLD_CPU or _takes_cpu(tr)
+        else:
+            self.frames = None
+            cpu = _takes_cpu(tr)
+        self.c0 = time.thread_time_ns() if cpu else None
         self.t0 = time.perf_counter_ns()
         self.sp = sp = tr.begin(self.name, parent, self.t0) \
             if tr.sampled else None
@@ -879,6 +1067,11 @@ class _Scoped:
         if self.in_phases:
             tr.add(self.name, t1 - self.t0)
         AGGREGATE.add(self.name, t1 - self.t0, cpu)
+        if self.frames is not None:
+            # its whole wall is its parent's child time; what ran inside
+            # it is not subtracted again further out
+            self.frames.pop()
+            self.frames[-1] += t1 - self.t0
         if self.ann is not None:
             self.ann.__exit__(None, None, None)
         return False

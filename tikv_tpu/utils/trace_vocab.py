@@ -1,10 +1,11 @@
 """Registered span-name vocabulary for the causal tracing subsystem.
 
 Every ``tracker.phase(...)`` / ``add_phase(...)`` / ``add_span(...)`` /
-``timed(...)`` / ``begin(...)`` / ``link_from(...)`` span name used
+``timed(...)`` / ``hold(...)`` / ``held(...)`` / ``.turn(...)`` /
+``begin(...)`` / ``link_from(...)`` span name used
 anywhere in ``tikv_tpu/`` (and each ``AGGREGATE.add(...)`` row of
-utils/trace.py, each ``client_phase(...)`` of server/client.py) MUST
-appear here (tests/test_trace.py scans the source tree both ways, like the
+utils/trace.py, its two envelopes' rows, each ``client_phase(...)`` of
+server/client.py) MUST appear here (tests/test_trace.py scans the source tree both ways, like the
 failpoint inventory): a typo'd phase label fails CI instead of silently
 forking the latency breakdown into two names no dashboard ever joins.
 The descriptions double as the README's span-vocabulary table — keep
@@ -55,6 +56,40 @@ SPAN_VOCABULARY: dict[str, str] = {
                     "either serving leg (server/wire.py enc_cop_body; "
                     "aggregate row only: count = requests that asked "
                     "for a chunk)",
+    # -- a transaction's write RPC (server/service.py _WRITE_METHODS,
+    # storage/txn/scheduler.py, raftstore/raftkv.py; the names follow
+    # kvproto's WriteDetail / TimeDetailV2) --
+    "txn_rpc": "root span of a txn write RPC (KvPrewrite, KvCommit, "
+               "KvCheckTxnStatus, KvResolveLock, ...): admission to "
+               "response, as rpc is a read's; the reads' rows (rpc, "
+               "rpc_accept_wait, rpc_reply) never hold a write",
+    "txn_accept_wait": "before a write's root span: gRPC's hand-off to "
+                       "the handler pool → tracker install (aggregate "
+                       "row + root-span attribute rpc_accept_wait_us)",
+    "txn_reply": "after a write's root span: trace sealed → response "
+                 "serializer returned (aggregate row only)",
+    "txn_wire_request": "the client's stamp right before it packed a "
+                        "write's request (request's clock_ns.sent) → the "
+                        "store's handler pool was handed the call: the "
+                        "pack, both gRPC cores, loopback, _serve getting "
+                        "the GIL; one shared clock only, else /health "
+                        "txn.wire_clock_unshared (aggregate row only)",
+    "sched_latch_wait": "txn scheduler: the command's key latches "
+                        "acquired (FIFO behind conflicting commands)",
+    "sched_snapshot": "txn scheduler: the engine snapshot the command "
+                      "reads (raft lease read)",
+    "sched_process": "txn scheduler: process_write, the command's MVCC "
+                     "reads and buffered writes",
+    "raft_write_wait": "RaftKv.write: the command handed over → its "
+                       "apply callback fired",
+    "raft_wake_wait": "RaftKv.write: the apply callback fired → the "
+                      "caller's wait returned (the driver's poll or "
+                      "condition wait, the node lock, the GIL)",
+    "raft_propose_wait": "span-only child of raft_write_wait on a pooled "
+                         "store: mailbox → the peer's poller proposed it "
+                         "(stamped in peer.py propose)",
+    "raft_apply_wait": "span-only child of raft_write_wait: proposed → "
+                       "applied (stamped by the apply callback)",
     # -- the client's side (server/client.py; CLIENT_CLOCK below: on the
     # client's clock, added to the reply's phases_ms by the client) --
     "fanout_cut": "region lookup through the client's region cache and "
@@ -128,6 +163,41 @@ SPAN_VOCABULARY: dict[str, str] = {
                       "its aggregate row is the dispatcher thread busy",
     "dispatcher_idle": "dispatcher thread parked with no closed group "
                        "to stage (aggregate row only)",
+    # the hold's own vocabulary (HOLD_ROWS below): inside a hold each
+    # of these keeps its SELF time, so that with the five older rows of
+    # the hold and dispatch_self they add up to group_dispatch
+    "group_open": "the hold, before the runner is called: lanes merged, "
+                  "DWFQ selection, the group span begun, the leader "
+                  "adopted, the metering scope entered",
+    "stage_plan": "one lane's staging up to its branch: plan analysis, "
+                  "quarantine gates, the tile probe, the request memo, "
+                  "the generation check, the row count, and on a hit the "
+                  "prepared record's guards, operands and pin "
+                  "(runner.py _handle_local, _stage_prepared)",
+    "memo_roll": "a written line's derived record rolled across the "
+                 "journal's gap (runner.py _refresh_meta → feed.py "
+                 "roll_derived; attr outcome: kept | dropped:<cause>, "
+                 "as /health device_mesh.memo counts it)",
+    "stage_full": "a full staging's own time: the host planes, the run "
+                  "body's prologue (layouts, kernel lookup, the prepared "
+                  "record written), the arena's pin and admit; less "
+                  "feed_get, host_derive and the launch",
+    "feed_get": "the feed ladder's own time between its rungs "
+                "(feed.py FeedStore.get): the key, the bucket, the "
+                "journal's gap read, windows and dead runs folded, "
+                "digests registered; less feed_patch / feed_rebuild / "
+                "feed_upload",
+    "lanes_launch": "a launch's own time around device_dispatch "
+                    "(aggregate.py launch_lanes): lanes grouped by "
+                    "kernel, the lane program looked up, the output "
+                    "staged toward pinned host memory, lanes bound",
+    "group_complete": "the hold, after the runner returned: lane counts "
+                      "noted, every member's resolution handed to the "
+                      "completion pool, the follows-from links",
+    "dispatch_self": "the hold's wall that no row of HOLD_ROWS covered "
+                     "(aggregate row only, written where group_dispatch "
+                     "closes: Σ HOLD_ROWS + dispatch_self = "
+                     "group_dispatch over any window)",
     "group_fetch_wait": "member resolution joining the group's shared "
                         "(memoized) fetch",
     # -- device backend (device/runner.py) --
@@ -152,8 +222,9 @@ SPAN_VOCABULARY: dict[str, str] = {
                       "sharding, a slice to each shard (not waited for: "
                       "the first launch waits for the transfer)",
     "feed_patch": "delta-dirty span patch of a resident feed: each "
-                  "span widened on the host to a bucket length, one "
-                  "update program a (bucket, plane) class",
+                  "span widened on the host to a bucket length, every "
+                  "window's updates gathered, then ONE program a window "
+                  "over all the feed's planes, digests chained",
     "feed_rebuild": "a resident feed the journal could not patch "
                     "forward (tombstones, a repack, a crossed pad "
                     "bucket, a value outside the feed's dtypes) built "
@@ -216,3 +287,19 @@ CLIENT_CLOCK = frozenset({
     "client_route", "client_encode", "wire_request", "wire_reply",
     "client_decode"})
 OUTSIDE_ROOT = CLIENT_CLOCK | {"rpc_accept_wait"}
+
+
+# The dispatcher's hold (server/coalescer.py _dispatch: ``trace.hold``).
+# HOLD_SELF are written by ``trace.held`` and exist inside a hold alone;
+# each row keeps its SELF time (its wall less the HOLD_ROWS scopes that
+# ran inside it).  HOLD_WHOLE are older phases that run there as leaves
+# and keep their whole wall, as every metric that reads them expects.
+# Over any window Σ Δwall(HOLD_ROWS) + Δwall(dispatch_self) =
+# Δwall(group_dispatch).  HOLD_CPU: the jitted calls, which inside a hold
+# take the thread CPU clock every time, so that offcpu_ms is a full sum.
+HOLD_SELF = ("group_open", "stage_plan", "memo_roll", "stage_full",
+             "feed_get", "lanes_launch", "group_complete")
+HOLD_WHOLE = ("device_dispatch", "feed_patch", "feed_rebuild",
+              "feed_upload", "host_derive")
+HOLD_ROWS = frozenset(HOLD_SELF + HOLD_WHOLE)
+HOLD_CPU = frozenset({"device_dispatch", "feed_patch", "feed_rebuild"})

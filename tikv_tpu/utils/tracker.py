@@ -21,6 +21,8 @@ follows-from links, and the /debug/trace retention buffer.
 from __future__ import annotations
 
 from .trace import (      # noqa: F401 — re-exported compat surface
+    READ_ENVELOPE,
+    TXN_ENVELOPE,
     Span,
     TraceBuffer,
     Tracker,
@@ -32,6 +34,8 @@ from .trace import (      # noqa: F401 — re-exported compat surface
     annotate,
     current,
     current_span,
+    held,
+    hold,
     install,
     label,
     note_accept,
