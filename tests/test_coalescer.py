@@ -1038,6 +1038,171 @@ def test_an_open_group_of_the_class_leaves_with_the_launch(lane_runner):
         rig.close()
 
 
+def _prepared(runner) -> dict:
+    return runner.mesh_stats()["prepared"]
+
+
+@pytest.mark.parametrize("max_group", [8, 2])
+def test_the_take_is_what_it_was_and_a_ticket_rides_every_group_asked(
+        lane_runner, max_group):
+    """Behind a held dispatcher wait, in this order, a closed group of
+    another class, a closed group of the lead's class and an OPEN group
+    of it.  The take is what it was: oldest first, the other class left
+    behind and counted, the open group closed early (``lanes``), no more
+    keys than ``max_group``; and what the runner resolved to tell each
+    asked group's class stays with the group as its ticket, from which
+    its lane is staged, in this hold or a later one."""
+    import time
+    from tikv_tpu.server import coalescer as coal_mod
+    snaps = [lane_snapshot(0), lane_snapshot(1, n=9 * LANE_BLOCK + 7),
+             lane_snapshot(2), lane_snapshot(3)]
+    rig = LaneRig(lane_runner, snaps)
+    try:
+        rig.warm()
+        rig.wait_built()
+        rig.coal.max_group = max_group
+        seen = []
+        inner = rig.coal._take_fusable
+
+        def recording(g):
+            rig._gate.wait(30)
+            with rig.coal._mu:
+                waiting = list(rig.coal._ready) + \
+                    list(rig.coal._open.values())
+            take = inner(g)
+            seen.append((g, waiting, take))
+            return take
+
+        rig.coal._take_fusable = recording
+        before = _prepared(lane_runner)
+        rig._gate.clear()
+        out, ts = {}, []
+        t_end = time.monotonic() + 10
+
+        def send(i):
+            t = threading.Thread(
+                target=lambda: out.setdefault(i, rig.one(lane_dag(i))))
+            t.start()
+            ts.append(t)
+
+        def until(cond):
+            while not cond() and time.monotonic() < t_end:
+                time.sleep(0.002)
+            assert cond()
+
+        send(0)     # closes by its window; the dispatcher pops it, is held
+        until(lambda: rig.coal.stats()["closes"].get("window", 0) >= 1 and
+              not rig.coal._ready and not rig.coal._open)
+        for i in (1, 2):
+            send(i)
+            until(lambda: len(rig.coal._ready) == i)
+        rig.coal.configure(window_ms=1500.0)
+        send(3)
+        until(lambda: len(rig.coal._open) == 1)     # collecting
+        rig._gate.set()
+        for t in ts:
+            t.join()
+        for i in range(4):
+            assert sorted(out[i].rows()) == rig.solo(i), i
+        g, waiting, take = seen[0]
+        g1, g2, g3 = waiting
+        assert [og.members[0].dag.start_ts for og in waiting] == [2, 3, 4]
+        # oldest first, one lane a key, the lead's key among max_group
+        assert take == ([g2, g3] if max_group == 8 else [g2])
+        st = rig.coal.stats()
+        # (the group of the other class, popped next, finds the open
+        # one of the lead's class waiting where max_group left it)
+        assert st["lane_class_mismatch"] == (1 if max_group == 8 else 2), st
+        assert st["closes"].get("lanes", 0) == (max_group == 8), st
+        assert st["groups_merged"] == len(take), st
+        # every group asked carries what the runner resolved for it
+        asked = [g, g1] + take
+        for og in asked:
+            lead = og.members[0]
+            t = og.ticket
+            assert t is not None and t.klass == og.klass
+            assert t.klass == lane_runner.launch_class(
+                og.key, lead.dag, lead.storage)
+            assert t.runner is lane_runner and \
+                t.anchor is lead.storage and t.rec.key == t.klass
+        assert g1.klass != g.klass and \
+            all(og.klass == g.klass for og in take)
+        if max_group == 2:
+            # the third key was not asked in that turn; by the time it
+            # left it had been, in its own
+            assert g3 not in take and g3.ticket is not None and \
+                g3.klass is not coal_mod._UNASKED
+        # and each of the four lanes was staged from its group's ticket
+        after = _prepared(lane_runner)
+        assert after["ticket_hits"] == before["ticket_hits"] + 4
+        assert after["ticket_misses"] == before["ticket_misses"]
+        assert after["hits"] == before["hits"] + 4
+    finally:
+        rig.close()
+
+
+@pytest.mark.parametrize("what", ["drop_feed", "kernel_false"])
+def test_a_group_whose_ticket_went_stale_while_it_waited_still_launches(
+        lane_runner, what):
+    """Between the take (every group's ticket asked) and the staging,
+    what one group's ticket stood on goes: that lane is staged in full
+    inside the same hold, the others from their tickets, nobody retries
+    solo and every answer is right."""
+    snaps = [lane_snapshot(s) for s in range(3)]
+    rig = LaneRig(lane_runner, snaps)
+    try:
+        rig.warm()
+        rig.wait_built()
+        inner = rig.coal._take_fusable
+        stale = []
+
+        def then_stale(g):
+            take = inner(g)
+            if take and not stale:
+                assert all(og.ticket is not None for og in [g] + take)
+                victim = take[0]
+                stale.append(victim)
+                if what == "drop_feed":
+                    assert lane_runner.drop_feed(
+                        victim.members[0].storage) > 0
+                else:
+                    lane_runner._kernel_cache[victim.ticket.rec.key] = False
+            return take
+
+        rig.coal._take_fusable = then_stale
+        before = _prepared(lane_runner)
+        launches = lane_runner.flight_recorder.stats()["launches"]
+        got = rig.together([lane_dag(i) for i in range(3)], 3)
+        for i, g in enumerate(got):
+            assert sorted(g.rows()) == rig.solo(i), i
+            assert g.backend == "device"
+        assert len(stale) == 1
+        after = _prepared(lane_runner)
+        st = lanes_of(rig)
+        assert st["solo_degrade"] == 0 and st["groups_merged"] == 2, st
+        if what == "drop_feed":
+            # its lane: one full staging (a new feed, a new record) that
+            # left with the two ticketed lanes, in one launch
+            assert after["ticket_misses"]["feed"] == \
+                before["ticket_misses"]["feed"] + 1
+            assert after["ticket_hits"] == before["ticket_hits"] + 2
+            assert after["builds"] == before["builds"] + 1
+            assert lane_runner.flight_recorder.stats()["launches"] == \
+                launches + 1
+        else:
+            # the class's kernel gone, every lane of it misses there and
+            # the stand-in body serves each
+            assert after["ticket_misses"]["kernel"] == \
+                before["ticket_misses"]["kernel"] + 3
+            assert after["ticket_hits"] == before["ticket_hits"]
+            del lane_runner._kernel_cache[stale[0].ticket.rec.key]
+        assert sum(after["ticket_misses"].values()) + after["ticket_hits"] \
+            == sum(before["ticket_misses"].values()) + \
+            before["ticket_hits"] + 3
+    finally:
+        rig.close()
+
+
 def test_a_lone_request_on_an_idle_store_leaves_at_once(lane_runner):
     """Nothing parked, nothing in flight: the group closes ``idle``
     and is one lane; merging adds no window and no wait."""

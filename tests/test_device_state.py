@@ -204,6 +204,80 @@ def test_arena_weakref_backstop_only_for_untracked_anchors():
     assert arena.resident_lines() == 0
 
 
+def test_pin_many_and_peek_beside_drops_and_admits_balance_every_pin():
+    """A hold's lanes are pinned together (``pin_many``) and their
+    buckets looked up without the mutex (``peek``) while other threads
+    drop, re-admit and unpin the same lines: a token of a dropped entry
+    is a no-op, a bucket handed back is the entry's own, and once every
+    token is back nothing stays pinned."""
+    import sys
+    import threading
+    import time
+    arena = FeedArena()
+
+    class Anchor:
+        pass
+
+    anchors = [Anchor() for _ in range(6)]
+
+    def admit(a):
+        arena.bucket(a)["x"] = {"flat": (np.zeros(8, np.int64),)}
+        arena.admit(a)
+
+    for a in anchors:
+        admit(a)
+    stop = threading.Event()
+    errors = []
+    holds = [0]
+
+    def churn(i):
+        try:
+            while not stop.is_set():
+                a = anchors[i % len(anchors)]
+                arena.drop(a)
+                admit(a)
+                i += 1
+        except Exception as e:          # noqa: BLE001 — surfaced below
+            errors.append(e)
+
+    def stage():
+        try:
+            while not stop.is_set():
+                for a in anchors:
+                    b = arena.peek(a)
+                    assert b is None or isinstance(b, dict)
+                got = arena.pin_many(anchors)
+                assert len(got) == len(anchors)
+                for a, (bucket, token) in zip(anchors, got):
+                    assert (bucket is None) == (token is None)
+                    if token is not None:
+                        assert token[0] == id(a)
+                for _bucket, token in got:
+                    arena.unpin(token)
+                holds[0] += 1
+        except Exception as e:          # noqa: BLE001
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=churn, args=(i,)) for i in range(3)] \
+        + [threading.Thread(target=stage) for _ in range(3)]
+    try:
+        for t in threads:
+            t.start()
+        time.sleep(1.0)
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=20)
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert holds[0] > 0
+    assert arena.pinned_bytes() == 0
+    assert all(e.pins == 0 for e in arena._entries.values())
+
+
 # ----------------------------------------- scrub → quarantine → rebuild
 
 

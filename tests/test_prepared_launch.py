@@ -1,5 +1,5 @@
 """A warm launch is staged from its class's prepared record
-(device/request.py ``_Prepared``; ``DeviceRunner._stage_prepared``).
+(device/request.py ``_Prepared``; ``DeviceRunner._stage_tickets``).
 
 What a warm whole-feed Pallas launch needs is computed once a (line,
 generation, const-blind class) and left in the request memo by the
@@ -125,6 +125,10 @@ def host_rows(dag, snap):
 
 def prepared(runner) -> dict:
     return runner.mesh_stats()["prepared"]
+
+
+NO_MISSES = {"none": 0, "tile": 0, "generation": 0, "feed": 0, "kernel": 0,
+             "gate": 0}
 
 
 def record_of(runner, dag, snap):
@@ -284,7 +288,8 @@ def test_the_span_and_the_health_rollup_carry_prepared(lane_runner):
         == [0, 1]
     assert prepared(lane_runner) == {
         "hits": 1, "builds": 1,
-        "drops": {"refresh": 0, "feed": 0, "kernel": 0}}
+        "drops": {"refresh": 0, "feed": 0, "kernel": 0},
+        "ticket_hits": 1, "ticket_misses": dict(NO_MISSES, none=1)}
 
 
 # ------------------------------------------------- missed, and rebuilt
@@ -638,5 +643,334 @@ def test_a_mesh_runner_never_builds_a_record(monkeypatch):
         == {"pallas_hash"}
     assert prepared(runner) == {
         "hits": 0, "builds": 0,
-        "drops": {"refresh": 0, "feed": 0, "kernel": 0}}
+        "drops": {"refresh": 0, "feed": 0, "kernel": 0},
+        "ticket_hits": 0, "ticket_misses": dict(NO_MISSES, none=3)}
     assert record_of(runner, dense_dag(0, 5), snap) is None
+    assert runner.launch_ticket(runner.batch_class(dense_dag(0, 5), snap) or
+                                ("share",), dense_dag(0, 5), snap) is None
+
+
+# ------------------------------------------------------------ the ticket
+#
+# What ``launch_ticket`` resolved to tell a group's launch class rides
+# with the group, and its lane is staged from it (``_stage_tickets``):
+# a hold's lanes in one pass, a request alone through the same function.
+
+
+def ticket_of(runner, dag, snap):
+    return runner.launch_ticket(runner.batch_class(dag, snap), dag, snap)
+
+
+def whole_ranges(snap, table_id=8700):
+    """Ranges, as a client sends them, that cover every row of ``snap``."""
+    from tikv_tpu.codec.keys import table_record_key
+    from tikv_tpu.executors.ranges import KeyRange
+    h = snap.handles
+    return (KeyRange(table_record_key(table_id, int(h[0])),
+                     table_record_key(table_id, int(h[-1]) + 1)),)
+
+
+def rows_of(outcome):
+    got = outcome.result() if hasattr(outcome, "result") else outcome
+    return sorted(got.rows())
+
+
+def staged(runner, lanes, tickets, via):
+    """``lanes`` staged from ``tickets``: as the lanes of one hold, or
+    (one lane) as a request alone."""
+    if via == "lanes":
+        return runner.handle_lanes(lanes, tickets)
+    (dag, snap), = lanes
+    return [runner.handle_request(dag, snap, deferred=True,
+                                  _ticket=tickets[0])]
+
+
+@pytest.mark.parametrize("k", [1, 3])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_a_lane_staged_from_a_ticket_answers_as_the_full_staging_and_the_host(
+        lane_runner, monkeypatch, shape, k):
+    """The full staging writes the record; the ticket that finds it
+    carries the class ``launch_class`` tells, and k lanes staged from
+    their tickets (one alone: ``handle_request``; three: one hold) give
+    the rows the full staging gave and the host gives, each lane under
+    its own constant, with nothing of the full staging run."""
+    snap_of, dag_of = SHAPES[shape]
+    runner = lane_runner
+    snaps = [snap_of(s) for s in range(k)]
+    first = [sorted(runner.handle_request(dag_of(i, 5), snaps[i]).rows())
+             for i in range(k)]
+    spies = Spies(runner, monkeypatch)
+    for consts in ([5] * k, [7 + 31 * i for i in range(k)]):
+        dags = [dag_of(i, c) for i, c in enumerate(consts)]
+        tickets = [ticket_of(runner, d, s) for d, s in zip(dags, snaps)]
+        for t, d, s in zip(tickets, dags, snaps):
+            assert t is not None and t.rec is record_of(runner, d, s)
+            assert t.klass == runner.launch_class(
+                runner.batch_class(d, s), d, s) == t.rec.key
+        before = prepared(runner)
+        out = staged(runner, list(zip(dags, snaps)), tickets,
+                     "alone" if k == 1 else "lanes")
+        assert runner.hbm_stats()["pinned_lines"] == k
+        rows = [rows_of(o) for o in out]
+        assert rows == [host_rows(d, s) for d, s in zip(dags, snaps)]
+        if consts[0] == 5:
+            assert rows == first
+        after = prepared(runner)
+        assert after["ticket_hits"] == before["ticket_hits"] + k
+        assert after["hits"] == before["hits"] + k
+        assert after["ticket_misses"] == before["ticket_misses"]
+        assert after["builds"] == before["builds"] and \
+            after["drops"] == before["drops"]
+    assert spies.calls == {"run_hash": 0, "run_simple": 0, "admit": 0}
+    assert runner._arena.pinned_bytes() == 0
+
+
+def _two_generations(runner, how="patch"):
+    old, new, _ = _after_write(runner, how)
+    return old, new
+
+
+@pytest.mark.parametrize("via", ["lanes", "alone"])
+@pytest.mark.parametrize("how", [
+    "feed:eviction", "feed:drop_feed", "feed:patch_under_another_class",
+    "kernel:entry_false", "generation:a_write_since_the_ticket",
+    "generation:the_line_moved_on", "generation:an_older_read"])
+def test_a_ticket_misses_as_the_record_did_and_says_why(lane_runner, how,
+                                                        via):
+    """Every guard a record was held to holds its ticket: what changed
+    between the ask and the hold sends the lane to ``_stage_local``
+    inside the same staging, the answer is its own snapshot's, and
+    ``ticket_misses`` says why."""
+    runner = lane_runner
+    cause, what = how.split(":")
+    dag = dense_dag(0, 5)
+    keeps = None        # a record this staging must leave as it is
+    if what == "eviction":
+        snap, other = lane_snapshot(22), lane_snapshot(23)
+        for s in (snap, other):
+            runner.handle_request(dag, s)
+        runner.handle_request(dense_dag(0, 6), other)
+        ticket = ticket_of(runner, dag, snap)
+        one = runner.hbm_stats()["resident_bytes"] // 2
+        runner.set_hbm_budget(one + one // 2)
+        assert runner._arena.bucket(snap, create=False) is None
+        runner.set_hbm_budget(0)
+    elif what == "drop_feed":
+        snap = lane_snapshot(24)
+        runner.handle_request(dag, snap)
+        ticket = ticket_of(runner, dag, snap)
+        assert runner.drop_feed(snap) > 0
+    elif what == "patch_under_another_class":
+        snap, new = _two_generations(runner)
+        s = DagSelect.from_table(lane_table(), ["id", "k", "v"])
+        dag = _at(s.where(s.col("v") > 5).aggregate(
+            [], [("sum", s.col("k")), ("sum", s.col("v"))]).build(), 0)
+        for d in (dense_dag(0, 5), dag):
+            runner.handle_request(d, snap)
+        ticket = ticket_of(runner, dag, snap)
+        runner.handle_request(dense_dag(0, 5), new)     # patches the feed
+        assert ticket.rec.feed["lineage_v"] == 1
+    elif what == "entry_false":
+        snap = lane_snapshot(24)
+        runner.handle_request(dag, snap)
+        ticket = ticket_of(runner, dag, snap)
+        runner._kernel_cache[ticket.rec.key] = False
+    elif what == "a_write_since_the_ticket":
+        # the group was asked at the generation before its snapshot's:
+        # the memo, and the record, still stood there
+        old, snap = _two_generations(runner)
+        runner.handle_request(dag, old)
+        ticket = ticket_of(runner, dag, snap)
+        assert ticket.rec is record_of(runner, dag, old) and \
+            ticket.req_v == 1 and ticket.meta["lineage_v"] == 0
+    elif what == "the_line_moved_on":
+        # asked while its generation was the line's; a newer read has
+        # rolled the memo since
+        snap, new = _two_generations(runner)
+        runner.handle_request(dag, snap)
+        ticket = ticket_of(runner, dag, snap)
+        runner.handle_request(dag, new)
+        keeps = record_of(runner, dag, new)
+        assert keeps is not None and keeps is not ticket.rec
+    else:
+        # an older-generation read finds the NEWER record under its
+        # key (and so its class, as before): it never stages from it
+        snap, new = _two_generations(runner)
+        for s in (snap, new):
+            runner.handle_request(dag, s)
+        keeps = record_of(runner, dag, new)
+        ticket = ticket_of(runner, dag, snap)
+        assert ticket.rec is keeps and ticket.req_v == 0
+    before = prepared(runner)
+    out, = staged(runner, [(dag, snap)], [ticket], via)
+    assert rows_of(out) == host_rows(dag, snap)
+    after = prepared(runner)
+    assert after["ticket_hits"] == before["ticket_hits"]
+    assert after["hits"] == before["hits"]
+    assert after["ticket_misses"] == dict(
+        before["ticket_misses"],
+        **{cause: before["ticket_misses"][cause] + 1})
+    if keeps is not None:
+        assert record_of(runner, dag, new) is keeps
+        assert after["drops"] == before["drops"]
+        assert rows_of(runner.handle_request(dag, new)) == \
+            host_rows(dag, new) != host_rows(dag, snap)
+        assert prepared(runner)["ticket_hits"] == before["ticket_hits"] + 1
+    if what == "entry_false":
+        del runner._kernel_cache[ticket.rec.key]
+    assert runner.hbm_stats()["pinned_lines"] == 0
+    assert runner._arena.pinned_bytes() == 0
+
+
+class CountingLock:
+    """The arena's mutex, its acquires counted."""
+
+    def __init__(self, inner):
+        self.inner, self.acquires = inner, 0
+
+    def __enter__(self):
+        self.acquires += 1
+        return self.inner.__enter__()
+
+    def __exit__(self, *exc):
+        return self.inner.__exit__(*exc)
+
+    def acquire(self, *a, **kw):
+        self.acquires += 1
+        return self.inner.acquire(*a, **kw)
+
+    def release(self):
+        self.inner.release()
+
+
+@pytest.mark.parametrize("asked", ["by_the_coalescer", "at_the_staging"])
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_a_ticketed_hold_probes_no_rows_and_takes_the_arenas_mutex_once(
+        lane_runner, monkeypatch, k, asked):
+    """k lanes whose ranges cover their regions: the full staging that
+    wrote each record probed the region's rows and found no tile; a hold
+    staged from tickets asks neither ``row_slices`` nor
+    ``estimated_rows`` nor ``searchsorted`` again, takes the arena's
+    mutex ONCE for all k pins, and the k unpins balance them."""
+    runner = lane_runner
+    snaps = [lane_snapshot(s) for s in range(k)]
+
+    def ranged(i, c):
+        return dataclasses.replace(dense_dag(i, c),
+                                   ranges=whole_ranges(snaps[i]))
+
+    probes = {"row_slices": 0, "estimated_rows": 0, "searchsorted": 0}
+
+    def counting(fn, name):
+        def spy(*a, **kw):
+            probes[name] += 1
+            return fn(*a, **kw)
+        return spy
+
+    for name in ("row_slices", "estimated_rows"):
+        monkeypatch.setattr(ColumnarTable, name,
+                            counting(getattr(ColumnarTable, name), name))
+    monkeypatch.setattr(np, "searchsorted",
+                        counting(np.searchsorted, "searchsorted"))
+    for i in range(k):
+        runner.handle_request(ranged(i, 5), snaps[i])
+    assert probes["row_slices"] == probes["estimated_rows"] == k
+    assert probes["searchsorted"] >= 2 * k
+    dags = [ranged(i, 40 + i) for i in range(k)]
+    tickets = [ticket_of(runner, d, s) for d, s in zip(dags, snaps)]
+    assert None not in tickets
+    for name in probes:
+        probes[name] = 0
+    lock = CountingLock(runner._arena._mu)
+    monkeypatch.setattr(runner._arena, "_mu", lock)
+    out = runner.handle_lanes(
+        list(zip(dags, snaps)),
+        tickets if asked == "by_the_coalescer" else None)
+    assert lock.acquires == 1
+    assert probes == {"row_slices": 0, "estimated_rows": 0,
+                      "searchsorted": 0}
+    assert runner._arena.pinned_bytes() > 0
+    assert runner.hbm_stats()["pinned_lines"] == k
+    assert [rows_of(o) for o in out] == \
+        [host_rows(d, s) for d, s in zip(dags, snaps)]
+    assert runner.hbm_stats()["pinned_lines"] == 0
+    assert runner._arena.pinned_bytes() == 0
+    assert prepared(runner)["ticket_hits"] == k
+
+
+def test_a_tiled_request_gets_no_ticket_and_a_whole_one_does(lane_runner):
+    """The ticket is asked under the request's ranges AS SENT: a tiled
+    request's memo lies under the region's whole ranges, so it finds
+    none (as it finds no class) beside the whole region's record, and
+    says ``tile``; ranges that cover the region are a request of their
+    own memo, probed once, then ticketed."""
+    from tikv_tpu.codec.keys import table_record_key
+    from tikv_tpu.executors.ranges import KeyRange
+    runner = lane_runner
+    snap = lane_snapshot(1)
+    whole = dataclasses.replace(dense_dag(0, 5), ranges=())
+    runner.handle_request(whole, snap)
+    assert ticket_of(runner, whole, snap) is not None
+    tiled = dataclasses.replace(dense_dag(0, 5), ranges=(
+        KeyRange(table_record_key(8700, 100_000 + 256),
+                 table_record_key(8700, 100_000 + 9000)),))
+    for n in (1, 2):
+        assert ticket_of(runner, tiled, snap) is None
+        assert runner.launch_class(runner.batch_class(tiled, snap), tiled,
+                                   snap) is None
+        assert rows_of(runner.handle_request(tiled, snap)) == \
+            host_rows(tiled, snap)
+        assert prepared(runner)["ticket_misses"]["tile"] == n
+    covers = dataclasses.replace(dense_dag(0, 5),
+                                 ranges=whole_ranges(snap))
+    assert ticket_of(runner, covers, snap) is None
+    runner.handle_request(covers, snap)
+    assert ticket_of(runner, covers, snap).rec is \
+        record_of(runner, covers, snap)
+    got = prepared(runner)
+    assert got["ticket_misses"] == dict(NO_MISSES, none=2, tile=2)
+    assert got["ticket_hits"] == got["hits"] == 0
+
+
+@pytest.mark.parametrize("via", ["lanes", "alone"])
+@pytest.mark.parametrize("fp", ["device::before_dispatch",
+                                "device::slice_dead"])
+def test_a_dispatch_guard_fires_on_a_ticketed_hit(lane_runner, fp, via):
+    """Armed once, the dispatch site's failpoint fires in a staging from
+    tickets as it did in a staging from the record: a request alone is
+    served by the host; of a hold's three lanes, the lane
+    ``device::before_dispatch`` fired in goes solo and the others leave,
+    and ``device::slice_dead``, the runner's, fails the one dispatch
+    whole.  Nothing stays pinned and the next staging is a hit."""
+    runner = lane_runner
+    k = 1 if via == "alone" else 3
+    snaps = [lane_snapshot(30 + s) for s in range(k)]
+    for i in range(k):
+        runner.handle_request(dense_dag(i, 5), snaps[i])
+    dags = [dense_dag(i, 40 + i) for i in range(k)]
+    lanes = list(zip(dags, snaps))
+    tickets = [ticket_of(runner, d, s) for d, s in lanes]
+    launches = runner.flight_recorder.stats()["launches"]
+    before = prepared(runner)
+    failpoint.cfg(fp, "1*return")
+    out = staged(runner, lanes, tickets, via)
+    after = prepared(runner)
+    if via == "alone":
+        assert rows_of(out[0]) == host_rows(dags[0], snaps[0])
+        assert runner.flight_recorder.stats()["launches"] == launches
+        assert after == before
+    else:
+        gone = [o is None for o in out]
+        assert gone == ([True, False, False] if fp.endswith("before_dispatch")
+                        else [True] * 3)
+        assert after["ticket_hits"] == before["ticket_hits"] + \
+            gone.count(False)
+        assert after["ticket_misses"] == before["ticket_misses"]
+        for o, (d, s) in zip(out, lanes):
+            if o is not None:
+                assert rows_of(o) == host_rows(d, s)
+    assert runner.hbm_stats()["pinned_lines"] == 0
+    out = staged(runner, lanes, [ticket_of(runner, d, s) for d, s in lanes],
+                 via)
+    assert [rows_of(o) for o in out] == [host_rows(d, s) for d, s in lanes]
+    assert prepared(runner)["ticket_hits"] == after["ticket_hits"] + k

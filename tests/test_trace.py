@@ -653,6 +653,115 @@ def test_a_hold_inside_a_hold_is_the_outer_ones_child(fresh_aggregate,
     assert trace_mod._held.frames is None
 
 
+# (the rig whose dispatcher can be held, and its Pallas body in interpret
+# mode: tests/test_coalescer.py)
+from test_coalescer import (  # noqa: E402,F401 — the rig and its fixture
+    LaneRig,
+    lane_dag,
+    lane_runner,
+    lane_snapshot,
+)
+
+
+@pytest.mark.parametrize("case", ["alone", "three", "three_one_in_full"])
+def test_stage_plan_is_one_piece_a_hold_and_says_its_lanes(
+        fresh_aggregate, lane_runner, case):
+    """The served hold, on the dispatcher's own thread: k lanes staged
+    from their tickets are ONE ``stage_plan`` piece (one aggregate row
+    count, one span on the sampled leader) whose attributes say
+    ``lanes`` and ``ticket_hits``; a lane that stages in full turns the
+    piece to ``stage_full`` as before; and the rows of the hold with
+    ``dispatch_self`` still add up to ``group_dispatch``, the take of the
+    waiting groups now inside it."""
+    from tikv_tpu.utils.trace_vocab import HOLD_ROWS
+    k = 1 if case == "alone" else 3
+    snaps = [lane_snapshot(s) for s in range(k)]
+    rig = LaneRig(lane_runner, snaps)
+    try:
+        rig.warm()
+        if k > 1:
+            rig.together([lane_dag(i) for i in range(k)], k)
+            rig.wait_built()
+        hits = k
+        if case == "three_one_in_full":
+            # one group's feed goes between the take, which asked its
+            # ticket, and the staging
+            take = rig.coal._take_fusable
+
+            def then_stale(g):
+                got = take(g)
+                if got:
+                    assert lane_runner.drop_feed(
+                        got[0].members[0].storage) > 0
+                return got
+
+            rig.coal._take_fusable = then_stale
+            hits = k - 1
+        trackers = []
+        one = rig.one
+
+        def traced(dag):
+            tr, tok = tracker.install(sampled=True)
+            trackers.append(tr)
+            try:
+                return one(dag)
+            finally:
+                tr.finish()
+                tracker.uninstall(tok)
+
+        rig.one = traced
+        rows = fresh_aggregate._rows
+        first = {n: tuple(r[:2]) for n, r in rows.items()}
+        dags = [lane_dag(i) for i in range(k)]
+        if k == 1:
+            rig.one(dags[0])
+        else:
+            rig.together(dags, k)
+        t_end = time.monotonic() + 10
+        while rows["group_dispatch"][0] == first["group_dispatch"][0] and \
+                time.monotonic() < t_end:
+            time.sleep(0.002)       # (a reply can beat the hold's close)
+        count = {n: rows[n][0] - first[n][0] for n in first}
+        wall = {n: rows[n][1] - first[n][1] for n in first}
+        assert count["group_dispatch"] == count["dispatch_self"] == 1
+        assert count["group_open"] == count["lanes_launch"] == 1
+        assert count["stage_plan"] == 1                 # not one a lane
+        assert count["stage_full"] == k - hits
+        assert sum(wall[n] for n in HOLD_ROWS) + wall["dispatch_self"] == \
+            wall["group_dispatch"] > 0
+        assert wall["dispatch_self"] * 10 <= wall["group_dispatch"]
+        spans = [sp for tr in trackers for sp in tr.spans
+                 if sp.name == "stage_plan"]
+        assert len(spans) == 1, [(sp.name, sp.attrs) for sp in spans]
+        assert spans[0].attrs == {"lanes": k, "ticket_hits": hits}
+        got = lane_runner.mesh_stats()["prepared"]
+        assert got["ticket_misses"]["feed"] == k - hits
+    finally:
+        rig.close()
+
+
+def test_health_has_the_ticket_counters_from_start_up(rig):
+    """``/health`` ``device_mesh.prepared`` carries ``ticket_hits`` and
+    every cause of ``ticket_misses`` before the first read (zeros on a
+    runner that has served nothing), beside ``hits`` / ``builds`` /
+    ``drops``."""
+    import jax
+
+    from tikv_tpu.device import DeviceRunner
+    from tikv_tpu.device.supervisor import TICKET_MISSES
+    from tikv_tpu.parallel import make_mesh
+    fresh = DeviceRunner(mesh=make_mesh(jax.devices()[:1]))
+    assert fresh.mesh_stats()["prepared"] == {
+        "hits": 0, "builds": 0,
+        "drops": {"refresh": 0, "feed": 0, "kernel": 0},
+        "ticket_hits": 0, "ticket_misses": dict.fromkeys(TICKET_MISSES, 0)}
+    assert set(TICKET_MISSES) >= {"none", "generation", "feed", "kernel",
+                                  "tile"}
+    served = _health(rig)["device_mesh"]["prepared"]
+    assert isinstance(served["ticket_hits"], int)
+    assert set(served["ticket_misses"]) == set(TICKET_MISSES)
+
+
 def test_gc_pause_counts_and_reenters_the_aggregate(fresh_aggregate):
     import gc
     trace_mod.watch_gc()

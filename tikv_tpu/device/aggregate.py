@@ -552,7 +552,7 @@ class DeviceAggregator:
         single-device runner over a built kernel: the preparation is
         left in ``meta`` (the request's memo) as its ``_Prepared``
         record, from which the class's next requests are staged
-        without coming here (``DeviceRunner._stage_prepared``; its
+        without coming here (``DeviceRunner._stage_tickets``; its
         ``key`` is what ``DeviceRunner.launch_class`` tells the
         coalescer), and this request is its first lane, a
         ``_LanePending``: launched here through ``launch_lanes`` as a

@@ -944,7 +944,7 @@ class FeedStore:
     def _cache_feed(bucket: dict, feed_key, feed: dict) -> None:
         """``feed`` into its anchor's bucket.  A cached feed says what
         it is cached under (``key``): a prepared record holds it to
-        that slot of the bucket by identity (``_stage_prepared``)."""
+        that slot of the bucket by identity (``_stage_tickets``)."""
         feed["key"] = feed_key
         bucket[feed_key] = feed
 

@@ -316,7 +316,7 @@ class _Prepared:
     feed of a single-device runner; ``DeviceRunner._refresh_meta`` drops
     it with the generation, the arena's bucket with the line).  A
     request of the class then stages from it (``DeviceRunner.
-    _stage_prepared``): its guards, its own operands, its pin.
+    _stage_tickets``): its guards, its own operands, its pin.
 
     ``key`` / ``entry``: the kernel cache key (the launch class
     ``DeviceRunner.launch_class`` tells the coalescer) and the entry it
@@ -360,6 +360,34 @@ class _Prepared:
         finish, LO = self.finish, self.LO
         return _LanePending(self, self.key_scalars + tuple(pvals),
                             lambda parts: finish(dag, parts, LO), prepared)
+
+
+@dataclass(slots=True)
+class _Ticket:
+    """What ``DeviceRunner.launch_ticket`` resolved for a closed group's
+    lead, kept by the coalescer beside the group's launch class and
+    handed back with the lane: a prepared hit's ONLY look-up.
+    ``klass``: the launch class (``rec.key`` behind the slice prefix);
+    ``runner``: the runner (slice) it was resolved on; ``pvals`` /
+    ``pdts``: the operands of the request's OWN plan, in the record's
+    limb variant, and their dtypes (``pallas_hash.plan_params``);
+    ``anchor`` / ``bucket``: the line and the arena's bucket the memo
+    was found in; ``meta`` / ``rec``: the memo under the request's OWN
+    ranges and the record it held; ``lineage`` / ``req_v``: the
+    generation of the request's snapshot.  It decides nothing: a staging
+    (``DeviceRunner._stage_tickets``) holds every field to what stands
+    then, by identity."""
+
+    klass: tuple
+    runner: object
+    pvals: tuple
+    pdts: tuple
+    anchor: object
+    bucket: dict
+    meta: dict
+    rec: _Prepared
+    lineage: object
+    req_v: Optional[int]
 
 
 class _LanePending(_Pending):

@@ -97,7 +97,7 @@ from .request import (
     _LanePending,
     _Pending,
     _Plan,
-    _Prepared,
+    _Ticket,
     _remap_rpn,
     _rpn_col_indices,
     _rpn_device_safe,
@@ -212,6 +212,12 @@ class _GuardedMeta:
         return v
 
 
+_UNSET = object()       # an argument left out, where None says something
+# a lane of ``_stage_tickets`` in which a dispatch gate fired (its
+# strike is taken): it goes solo, a request alone to the host rung
+_FAULT = object()
+
+
 class DeferredResult:
     """Handle for a device request whose D2H fetch + host finalize have
     not run yet (``DeviceRunner.handle_request(..., deferred=True)``).
@@ -230,7 +236,7 @@ class DeferredResult:
                  "_launch_info")
 
     def __init__(self, runner, pending: _Pending, dag, storage,
-                 pin_anchor=None):
+                 pin_anchor=None, meter_ctx=_UNSET):
         self._runner = runner
         self._pending = pending
         self._dag = dag             # original request (host fallback)
@@ -244,8 +250,11 @@ class DeferredResult:
         # dispatch-time metering context: fetch-side charges (D2H
         # bytes) attribute to the dispatching request/share-group no
         # matter which completion worker runs the fetch
-        from .. import resource_metering as rm
-        self._meter_ctx = rm.current_context()
+        # (``meter_ctx``: a hold's lanes share the one it read)
+        if meter_ctx is _UNSET:
+            from .. import resource_metering as rm
+            meter_ctx = rm.current_context()
+        self._meter_ctx = meter_ctx
         # the mesh the launch ran on: every request that joins this
         # result (a coalesced group's members resolve on their own
         # trackers) is labelled with it; None once a rescue re-served
@@ -1220,8 +1229,21 @@ class DeviceRunner:
         kind, a mesh, a bucket-tile request (its ranges have no memo of
         their own), a cold or refreshed line take today's path.  The
         class only decides who is staged together; each lane's kernel
-        is looked up again when it is prepared, and lanes that turn
-        out to differ leave as launches of their own."""
+        is held to the cache again when it is staged, and lanes that
+        turn out to differ leave as launches of their own."""
+        ticket = self.launch_ticket(key, dag, storage)
+        return None if ticket is None else ticket.klass
+
+    def launch_ticket(self, key, dag: DAGRequest, storage):
+        """``launch_class``, and with the class everything that was
+        resolved to find it: the group's TICKET (``request._Ticket``:
+        the runner, the lead's plan and operands, the line's bucket and
+        memo, the record, the generation), which the coalescer keeps
+        beside the class and hands back with the lane
+        (``handle_lanes`` / ``handle_request``), so that a prepared
+        hit is staged without a second look-up.  None where
+        ``launch_class`` is None.  Nothing of the arena is touched or
+        locked here (``FeedArena.peek``)."""
         prefix = ()
         if key[0] == "slice":
             prefix, key = key[:2], key[2:]
@@ -1232,17 +1254,39 @@ class DeviceRunner:
             runner = self._placer.route(storage)
             if (id(runner) != prefix[1]) if prefix else runner is not self:
                 return None
-        if not runner._single:
+        ticket = runner._ticket_of(dag, storage)
+        if ticket is not None and prefix:
+            ticket.klass = prefix + ticket.klass
+        return ticket
+
+    def _ticket_of(self, dag: DAGRequest, storage) -> Optional[_Ticket]:
+        """The ticket of ONE request on this runner: the record its memo
+        holds under ITS OWN plan, ranges and line, as sent (a tiled
+        request's memo lies under the whole region's ranges, a mesh
+        builds no record, a cold or refreshed line has none: no ticket),
+        with what a staging from it needs of the request."""
+        if not self._single:
             return None
-        plan = runner._analyze(dag)
+        plan = self._analyze(dag)
         if plan is None or plan.kind not in ("hash_agg", "simple_agg"):
             return None
-        per_storage = runner._arena.bucket(feed_anchor(storage),
-                                           create=False)
-        meta = per_storage.get(("meta", runner._meta_key(dag, plan))) \
-            if per_storage is not None else None
+        anchor = feed_anchor(storage)
+        bucket = self._arena.peek(anchor)
+        meta = bucket.get(("meta", self._meta_key(dag, plan))) \
+            if bucket is not None else None
         rec = meta.get("prepared") if meta else None
-        return None if rec is None else prefix + rec.key
+        if rec is None:
+            return None
+        return self._ticket(plan, anchor, bucket, meta, rec,
+                            *generation(storage))
+
+    def _ticket(self, plan, anchor, bucket, meta, rec, lineage,
+                req_v) -> _Ticket:
+        if rec.limbs:
+            plan = self._limb_variant(plan, rec.limbs)
+        _sel, _aggs, pvals, pdts = pallas_hash.plan_params(plan)
+        return _Ticket(rec.key, self, tuple(pvals), tuple(pdts), anchor,
+                       bucket, meta, rec, lineage, req_v)
 
     def lanes_ready(self, klass, storage) -> bool:
         """Whether groups of launch class ``klass`` over DIFFERENT
@@ -1259,15 +1303,23 @@ class DeviceRunner:
                 runner = self._placer.route(storage)
         return runner._aggregator.lanes_ready(klass)
 
-    def handle_lanes(self, lanes) -> list:
+    def handle_lanes(self, lanes, tickets=None) -> list:
         """ONE staging for ``lanes``, a list of ``(dag, storage)``
-        leads of closed ``share`` groups with one ``launch_class``:
-        under one hold of the dispatch lock each lane is prepared as a
-        request of its own (``_handle_local``: its memo, its feed, its
-        row bounds, its arena pin; from its class's prepared record
-        where one stands), then the prepared kernels leave
-        together (``DeviceAggregator.launch_lanes``: one program, one
-        Pallas call a lane, one fetch).
+        leads of closed ``share`` groups with one ``launch_class``,
+        under one hold of the dispatch lock.  ``tickets``: what
+        ``launch_ticket`` resolved for each (None: it had none; the
+        argument left out: they are resolved here).  The ticketed lanes
+        are staged first and together, each from its class's prepared
+        record (``_stage_tickets``: the runner's gates once, each
+        lane's guards, operands and pin, the arena's mutex once); a lane
+        without a ticket, or whose ticket no longer stands, is then
+        prepared as a request of its own (``_stage_local``: its memo,
+        its feed, its row bounds, its arena pin); then the prepared
+        kernels leave together (``DeviceAggregator.launch_lanes``: one
+        program, one Pallas call a lane, one fetch).  On the
+        coalescer's dispatcher the staging is ONE ``stage_plan`` piece
+        of the hold, which says ``lanes`` and ``ticket_hits``, and
+        turns to ``stage_full`` where a lane stages in full.
 
         → one outcome a lane, in order: a ``DeferredResult`` (or a
         result that settled in line) for its members to share as a
@@ -1279,20 +1331,49 @@ class DeviceRunner:
         if self._placer is not None and lanes:
             target = self._placer.route(lanes[0][1])
             if target is not self:
-                return target.handle_lanes(lanes)
+                return target.handle_lanes(lanes, tickets)
         if not self._single:
             raise _BatchUnavailable("lanes need a single-device runner")
-        out = []
+        from .. import resource_metering as rm
+        from ..utils import tracker
+        out = [None] * len(lanes)
         with self._device_scope(), self._dispatch_mu:
-            for dag, storage in lanes:
-                try:
+            with tracker.held("stage_plan") as piece:
+                ticketed, full = [], {}
+                for i, (dag, storage) in enumerate(lanes):
                     if self._placer is not None and \
                             self._placer.route(storage) is not self:
-                        raise _BatchUnavailable("lane placed elsewhere")
-                    out.append(self._handle_local(dag, storage, True, None,
-                                                  _lanes=True))
-                except Exception:   # noqa: BLE001 — the lane goes solo
-                    out.append(None)
+                        continue        # placed elsewhere: it goes solo
+                    ticket = self._ticket_of(dag, storage) \
+                        if tickets is None else tickets[i]
+                    if ticket is None:
+                        full[i] = "none"
+                    else:
+                        ticketed.append((i, dag, storage, ticket))
+                hits = 0
+                if ticketed:
+                    ctx = rm.current_context()
+                    staged = self._stage_tickets(
+                        [lane[1:] for lane in ticketed], launch=False)
+                    for (i, dag, storage, _t), got in zip(ticketed,
+                                                          staged):
+                        if type(got) is tuple:
+                            out[i] = DeferredResult(
+                                self, got[0], dag, storage,
+                                pin_anchor=got[1], meter_ctx=ctx)
+                            hits += 1
+                        elif got is not _FAULT:
+                            full[i] = got
+                piece.note(lanes=len(lanes), ticket_hits=hits)
+                for i in sorted(full):
+                    dag, storage = lanes[i]
+                    if piece.name != "stage_plan":
+                        piece.turn("stage_plan")
+                    try:
+                        out[i] = self._stage_local(
+                            dag, storage, True, None, True, piece, full[i])
+                    except Exception:   # noqa: BLE001 — the lane goes solo
+                        out[i] = None
             waiting = [i for i, d in enumerate(out)
                        if isinstance(d, DeferredResult) and
                        isinstance(d._pending, _LanePending)]
@@ -2023,7 +2104,7 @@ class DeviceRunner:
         grid step takes (on the span and in the entry; counted on
         ``/health`` ``device_mesh.agg_params``); ``prepared``: the
         launch's lanes that were staged from their class's prepared
-        record alone (``_stage_prepared``; ``device_mesh.prepared``
+        record alone (``_stage_tickets``; ``device_mesh.prepared``
         ``hits``)."""
         from .. import resource_metering as rm
         from ..utils import tracker
@@ -2159,8 +2240,13 @@ class DeviceRunner:
             return BatchExecutorsRunner(dag, storage).handle_request()
 
     def handle_request(self, dag: DAGRequest, storage,
-                       deferred: bool = False, _stack=None):
+                       deferred: bool = False, _stack=None,
+                       _ticket=_UNSET):
         """Execute a supported plan on the device.
+
+        ``_ticket`` (the coalescer's singleton group): what
+        ``launch_ticket`` resolved for this request, None where it found
+        nothing; left out, the request's ticket is resolved here.
 
         ``_stack`` (handle_batched only): a tuple of per-member hoisted
         predicate parameter value tuples.  The scan_sel run then builds
@@ -2188,7 +2274,8 @@ class DeviceRunner:
             target = self._placer.route(storage)
             if target is not self:
                 return target.handle_request(dag, storage,
-                                             deferred=deferred)
+                                             deferred=deferred,
+                                             _ticket=_ticket)
         if self._board is not None:
             # elastic mesh degrade: a quarantined chip routes whole-
             # mesh plans to the largest healthy submesh (8→4→2→1; the
@@ -2204,7 +2291,8 @@ class DeviceRunner:
                                                deferred=deferred,
                                                _stack=_stack)
         with self._device_scope():
-            return self._handle_local(dag, storage, deferred, _stack)
+            return self._handle_local(dag, storage, deferred, _stack,
+                                      _ticket=_ticket)
 
     def _device_scope(self):
         """Where this runner's uploads and plain-jit launches land.  A
@@ -2219,24 +2307,68 @@ class DeviceRunner:
         return jax.default_device(self._pin_device)
 
     def _handle_local(self, dag: DAGRequest, storage, deferred: bool,
-                      _stack, _lanes: bool = False):
+                      _stack, _lanes: bool = False, _ticket=_UNSET):
         """``handle_request`` on THIS runner's devices (placement and
-        degrade routing already done).  ``_lanes``: one lane of
-        ``handle_lanes``, which holds the dispatch lock and launches
-        the lanes' kernels itself; a lane that would be served on the
-        host raises ``_BatchUnavailable`` instead, as a stacked group
-        does.
+        degrade routing already done): from the request's ticket where
+        one stands (``_stage_tickets``, the one way a prepared hit is
+        staged: a hold's lanes, a request alone), else in full
+        (``_stage_local``).  ``_lanes``: as one lane of a staging whose
+        caller holds the dispatch lock and launches the lanes' kernels
+        itself; a lane that would be served on the host raises
+        ``_BatchUnavailable`` instead, as a stacked group does.
 
         On the coalescer's dispatcher (``trace.hold``) the staging is
         two rows of the hold: ``stage_plan`` up to the branch (all of a
         hit), then ``stage_full``."""
         from ..utils import tracker
         with tracker.held("stage_plan") as piece:
+            cause = "none"
+            ticket = None
+            if _stack is None:
+                ticket = self._ticket_of(dag, storage) \
+                    if _ticket is _UNSET else _ticket
+            if ticket is not None:
+                with nullcontext() if _lanes else self._dispatch_locked():
+                    got, = self._stage_tickets([(dag, storage, ticket)],
+                                               launch=not _lanes)
+                hit = type(got) is tuple
+                piece.note(lanes=1, ticket_hits=int(hit))
+                if hit:
+                    return self._hit_result(dag, storage, got, deferred)
+                if got is _FAULT:
+                    if _lanes:
+                        raise _BatchUnavailable("degraded during batched "
+                                                "dispatch")
+                    return self._serve_on_host(dag, storage, "dispatch")
+                cause = got
             return self._stage_local(dag, storage, deferred, _stack,
-                                     _lanes, piece)
+                                     _lanes, piece, cause)
+
+    def _hit_result(self, dag, storage, staged, deferred: bool):
+        """A request alone staged from its record: its handle, or its
+        answer where the caller blocks."""
+        lane, pin = staged
+        if deferred:
+            return DeferredResult(self, lane, dag, storage, pin_anchor=pin)
+        try:
+            try:
+                result = self._finish(lane)
+            finally:
+                self._arena.unpin(pin)
+        except _FallbackToHost:
+            # (a fetch-side fault of a blocking caller: the host rung,
+            # as the full staging's own handler answers it)
+            self._note_slice_fault("dispatch")
+            return self._serve_on_host(dag, storage, "dispatch")
+        return self._apply_output_offsets(dag, result)
 
     def _stage_local(self, dag: DAGRequest, storage, deferred: bool,
-                     _stack, _lanes: bool, piece):
+                     _stack, _lanes: bool, piece, cause: str):
+        """A request staged as one of its own, in full unless its memo
+        turns out to hold a record after all (a group whose ticket was
+        asked before another lane's full staging wrote it).  ``cause``:
+        why it was not staged from a ticket (``supervisor.
+        TICKET_MISSES``), counted here, once."""
         plan = self._analyze(dag)
         if plan is None:
             raise RuntimeError("plan not supported by device backend")
@@ -2253,6 +2385,7 @@ class DeviceRunner:
             # pipeline without touching any per-slice state (a racing
             # caller that bypassed the placer's exclusion lands here)
             from ..utils import tracker
+            self.flight_recorder.note_tickets(miss="gate")
             tracker.label("device_feed", "slice_quarantined")
             return self._serve_on_host(dag, storage, "slice_quarantined")
 
@@ -2262,6 +2395,7 @@ class DeviceRunner:
             # quarantine time; serve THIS request from the host
             # pipeline, then let the next one rebuild from host truth
             from ..utils import tracker
+            self.flight_recorder.note_tickets(miss="gate")
             tracker.label("device_feed", "quarantined")
             return self._serve_on_host(dag, storage, "quarantined")
 
@@ -2282,10 +2416,13 @@ class DeviceRunner:
             covered = sum(j - i for i, j in spans) if spans else 0
             if spans and 0 < covered < n_all:
                 tile_spans = tuple(spans)
+                if cause == "none":
+                    cause = "tile"
                 # feed/meta keyed WITHOUT ranges: every tiled request
                 # over this snapshot shares one region feed
                 dag = dag.over_ranges(())
 
+        self.flight_recorder.note_tickets(miss=cause)
         meta = self._request_meta(storage, self._meta_key(dag, plan))
         memo: dict = {}
 
@@ -2343,9 +2480,10 @@ class DeviceRunner:
             return BatchExecutorsRunner(orig_dag, storage).handle_request()
 
         # what a warm whole-feed Pallas launch of this class needs was
-        # left in the memo by the last one (``_Prepared``): a request
-        # of the class stages from it (its guards, its own operands,
-        # its pin) and nothing below the guards runs
+        # left in the memo by the last one (``_Prepared``).  A request
+        # that finds one only HERE (it had no ticket, or its ticket's
+        # record was of the generation before) is staged from it all the
+        # same, by the hits' one function, the gates below already fired
         rec = meta.get("prepared") if _stack is None and \
             tile_spans is None and memo_fresh() else None
         pin_anchor = None
@@ -2356,20 +2494,16 @@ class DeviceRunner:
             # device::slice_dead names one of mine
             self._preflight_slice()
             if rec is not None:
+                anchor = feed_anchor(storage)
+                ticket = self._ticket(plan, anchor,
+                                      self._arena.peek(anchor), meta, rec,
+                                      lineage, req_v)
                 with nullcontext() if _lanes else self._dispatch_locked():
-                    staged = self._stage_prepared(rec, meta, dag, plan,
-                                                  storage, req_v, _lanes)
-                if staged is not None:
-                    result, pin_anchor = staged
-                    if deferred:
-                        return DeferredResult(self, result, dag, storage,
-                                              pin_anchor=pin_anchor)
-                    try:
-                        return self._apply_output_offsets(
-                            dag, self._finish(result))
-                    finally:
-                        self._arena.unpin(pin_anchor)
-                        pin_anchor = None
+                    staged, = self._stage_tickets(
+                        [(dag, storage, ticket)], launch=not _lanes,
+                        gated=True)
+                if type(staged) is tuple:
+                    return self._hit_result(dag, storage, staged, deferred)
             # the full staging: the used columns' host halves, each
             # derived at most once
             piece.turn("stage_full")
@@ -2473,60 +2607,133 @@ class DeviceRunner:
                                   pin_anchor=pin_anchor)
         return self._apply_output_offsets(orig_dag, result)
 
-    def _stage_prepared(self, rec: _Prepared, meta: dict, dag, plan,
-                        storage, req_v, lanes: bool):
-        """One request staged from its class's prepared record, under
-        the dispatch lock → ``(its lane, its arena pin)``, launched
-        unless the caller launches its ``lanes`` together; or None where
-        the record no longer stands, and the caller stages in full
-        (which writes the next record).  The caller has held the memo
-        to the request's generation (a write is followed by one full
-        staging) and fired the dispatch's guards; what is checked here,
-        on every request: the arena's bucket still holds THAT feed
-        under its key, planes untouched and at this generation (so a
-        budget eviction, ``drop_feed``, a scrub quarantine, a split's
-        or a move's take, a patch or a re-upload all miss), and the
-        kernel cache still holds THAT entry (a failed launch's
-        ``False`` misses).  Then the request's own: its operands from
-        ITS plan, its pin.  No ``arena.admit``: a hit caches no new
-        device state (it stays on every miss, where a feed or a slot
-        column may have been added)."""
+    def _stage_tickets(self, lanes, launch: bool,
+                       gated: bool = False) -> list:
+        """THE way a prepared hit is staged: ``lanes``, ``(dag, storage,
+        ticket)`` each, from their class's prepared records in one pass
+        under the caller's hold of the dispatch lock → one outcome a
+        lane, in order: ``(its lane, its arena pin)``, launched where
+        ``launch`` says so (a request alone; a hold launches its lanes
+        together); or the cause it was not (``supervisor.
+        TICKET_MISSES``), and the caller stages it in full, which writes
+        the next record; or ``_FAULT`` where a dispatch gate fired in it.
+
+        Once a hold, the runner's own gates (``gated``: the caller has
+        fired them): a quarantined slice stages nothing from a record,
+        and ``device::slice_dead`` naming one of mine fails every lane
+        of the hold as the chip would, one strike for the one dispatch.
+        Then a lane's, none of which touches the arena: the ticket is
+        this runner's; the memo still stands at the request's
+        generation and holds THAT record (a write since, or an
+        older-generation read, goes local, and ``_stage_local`` rolls
+        or shields the memo as it always did); the memo is not forced
+        to the host and the line not quarantined by the scrub;
+        ``device::before_dispatch``.  Then the arena, ONCE for all of
+        them (``FeedArena.pin_many``: each line touched, settled and
+        pinned, its bucket handed back), and each lane's record held to
+        it by identity: THAT bucket still holds THAT feed under its
+        key, planes untouched and at this generation (so a budget
+        eviction, ``drop_feed``, a scrub quarantine, a split's or a
+        move's take, a patch or a re-upload all miss), and the kernel
+        cache still holds THAT entry for these operand dtypes (a failed
+        launch's ``False`` misses).  The lane's operands are ITS plan's
+        (the ticket's).  That the request covers its whole region is
+        the record's knowledge: it was written for these ranges at this
+        generation by a staging that found no tile.  No ``arena.admit``:
+        a hit caches no new device state (it stays on every miss, where
+        a feed or a slot column may have been added)."""
         from ..utils import tracker
-        anchor = feed_anchor(storage)
-        bucket = self._arena.bucket(anchor, create=False)
-        feed = rec.feed
-        if rec.limbs:
-            plan = self._limb_variant(plan, rec.limbs)
-        _sel, _aggs, pvals, pdts = pallas_hash.plan_params(plan)
-        cause = None
-        if bucket is None or bucket.get(rec.feed_key) is not feed or \
-                feed["flat"] is not rec.flat or \
-                feed.get("lineage_v") != req_v:
-            cause = "feed"
-        elif self._kernel_cache.get(rec.key) is not rec.entry or \
-                tuple(pdts) != rec.param_dts:
-            # (a memo is a const-blind class's, and so are the
-            # operands' dtypes the kernel was built for: held, not
-            # assumed)
-            cause = "kernel"
-        if cause is not None:
-            if meta.get("prepared") is rec:
-                del meta["prepared"]
-                self.flight_recorder.note_prepared(cause)
-            return None
-        tracker.label("device_feed", "hit")
-        lane = rec.lane(dag, pvals, prepared=True)
-        if self._health is not None and self._health.quarantined():
-            # (the invariant counter chaos audits: ``_handle_local``)
-            self._health.launched_quarantined += 1
-        # pin the line for the in-flight dispatch, as every launch does
-        pin = self._arena.pin(anchor)
-        if not lanes and self._aggregator.launch_lanes([lane]):
-            # the launch failed (struck): the full staging decides what
-            # serves this request
-            self._arena.unpin(pin)
-            return None
-        return lane, pin
+        out = [None] * len(lanes)
+        if not gated:
+            if self._health is not None and self._health.quarantined():
+                # (``_stage_local`` refuses each, and counts it)
+                return ["gate"] * len(lanes)
+            try:
+                self._preflight_slice()
+            except _FallbackToHost:
+                self._note_slice_fault("dispatch")
+                return [_FAULT] * len(lanes)
+        live = []
+        for i, (_dag, _storage, t) in enumerate(lanes):
+            meta = t.meta
+            if t.runner is not self:
+                out[i] = "none"
+            elif meta.get("prepared") is not t.rec or (
+                    t.lineage is not None and
+                    meta.get("lineage_v") != t.req_v):
+                out[i] = "generation"
+            elif meta.get("force_host") or (
+                    self._quarantined and
+                    id(t.anchor) in self._quarantined):
+                out[i] = "gate"
+            else:
+                if not gated:
+                    try:
+                        _fp_degrade("device::before_dispatch")
+                    except _FallbackToHost:
+                        self._note_slice_fault("dispatch")
+                        out[i] = _FAULT
+                        continue
+                live.append(i)
+        if not live:
+            return out
+        pinned = self._arena.pin_many([lanes[i][2].anchor for i in live])
+        # lane -> its pin, while this call answers for it
+        held = {i: pin for i, (_bucket, pin) in zip(live, pinned)}
+        hits = []
+        try:
+            for i, (bucket, pin) in zip(live, pinned):
+                dag, _storage, t = lanes[i]
+                rec = t.rec
+                feed = rec.feed
+                cause = None
+                if bucket is None or bucket is not t.bucket or \
+                        bucket.get(rec.feed_key) is not feed or \
+                        feed["flat"] is not rec.flat or \
+                        feed.get("lineage_v") != t.req_v:
+                    cause = "feed"
+                elif self._kernel_cache.get(rec.key) is not rec.entry or \
+                        t.pdts != rec.param_dts:
+                    # (a memo is a const-blind class's, and so are the
+                    # operands' dtypes the kernel was built for: held,
+                    # not assumed)
+                    cause = "kernel"
+                if cause is not None:
+                    self._arena.unpin(held.pop(i))
+                    if t.meta.get("prepared") is rec:
+                        del t.meta["prepared"]
+                        self.flight_recorder.note_prepared(cause)
+                    out[i] = cause
+                    continue
+                out[i] = (rec.lane(dag, t.pvals, prepared=True), pin)
+                hits.append(i)
+            if launch and hits:
+                # a launch from the record that failed (struck): the full
+                # staging decides what serves the request, and writes
+                # the next record
+                gone = {id(p) for p in self._aggregator.launch_lanes(
+                    [out[i][0] for i in hits])}
+                for i in [i for i in hits if id(out[i][0]) in gone]:
+                    hits.remove(i)
+                    self._arena.unpin(held.pop(i))
+                    t = lanes[i][2]
+                    if t.meta.get("prepared") is t.rec:
+                        del t.meta["prepared"]
+                    out[i] = "kernel"
+        except BaseException:
+            for pin in held.values():
+                self._arena.unpin(pin)
+            raise
+        if hits:
+            tracker.label("device_feed", "hit")
+            if self._health is not None and self._health.quarantined():
+                # (the invariant counter chaos audits: ``_stage_local``)
+                self._health.launched_quarantined += len(hits)
+            if not gated:
+                # (a record found only at the staging, ``gated``, was
+                # no ticket's: ``prepared.hits`` alone counts that one)
+                self.flight_recorder.note_tickets(hits=len(hits))
+        return out
 
     def _finish(self, pending: _Pending):
         """Blocking fetch + host finalize for a dispatched request."""

@@ -110,6 +110,18 @@ def _bucket_nbytes(bucket: dict) -> int:
 
 DEFAULT_FLIGHT_RECORDER_DEPTH = 256
 
+# Why a staging was not a lane staged from its group's ticket
+# (``FlightRecorder.note_tickets``; /health device_mesh.prepared
+# ``ticket_misses``): ``none``: it had no ticket (no record under its own
+# plan, ranges and line: a cold or refreshed line, another plan kind, a
+# mesh); ``tile``: none, and its ranges cover part of the region;
+# ``generation``: the memo no longer stands at the request's generation
+# with THAT record (a write since, or an older-generation read);
+# ``feed`` / ``kernel``: the record's guards, as ``prepared.drops``
+# names them (``kernel`` also a launch from the record that failed);
+# ``gate``: a quarantined slice or line, or a memo forced to the host.
+TICKET_MISSES = ("none", "tile", "generation", "feed", "kernel", "gate")
+
 
 class FlightRecorder:
     """Bounded ring of recent device launches — the black box an
@@ -176,6 +188,10 @@ class FlightRecorder:
         self.prepared_hits = 0
         self.prepared_builds = 0
         self.prepared_drops = {"refresh": 0, "feed": 0, "kernel": 0}
+        # lanes staged from their group's ticket (runner.py
+        # _stage_tickets), and stagings that were not, by cause
+        self.ticket_hits = 0
+        self.ticket_misses = dict.fromkeys(TICKET_MISSES, 0)
         # device/feed.py ``roll_derived``: request memos whose derived
         # record a write was rolled across, kept (every constant proved
         # again) or dropped, by what the written rows left; and the
@@ -302,11 +318,21 @@ class FlightRecorder:
             else:
                 self.prepared_drops[event] += 1
 
+    def note_tickets(self, hits: int = 0, miss: Optional[str] = None) -> None:
+        """Lanes staged from a ticket (once a hold), or one staging
+        that was not, by its cause (``TICKET_MISSES``)."""
+        with self._mu:
+            self.ticket_hits += hits
+            if miss is not None:
+                self.ticket_misses[miss] += 1
+
     def prepared_counts(self) -> dict:
         with self._mu:
             return {"hits": self.prepared_hits,
                     "builds": self.prepared_builds,
-                    "drops": dict(self.prepared_drops)}
+                    "drops": dict(self.prepared_drops),
+                    "ticket_hits": self.ticket_hits,
+                    "ticket_misses": dict(self.ticket_misses)}
 
     def note_memo(self, cause: Optional[str],
                   planes: Optional[str] = None) -> None:
@@ -782,10 +808,7 @@ class FeedArena:
         with self._mu:
             ent = self._entries.get(key)
             if ent is not None:
-                self._tick += 1
-                ent.hits += 1
-                ent.tick = self._tick
-                self._own_locked(ent, ctx, anchor)
+                self._touch_locked(ent, ctx, anchor)
                 return ent.bucket
             if not create:
                 return None
@@ -794,25 +817,42 @@ class FeedArena:
                                   lambda _r, k=key: self._gc_drop(k))
             except TypeError:
                 return None
-            self._tick += 1
             self._gen += 1
             ent = _ArenaEntry(ref, self._gen)
-            ent.hits = 1
-            ent.tick = self._tick
-            self._own_locked(ent, ctx, anchor)
+            self._touch_locked(ent, ctx, anchor)
             self._entries[key] = ent
             return ent.bucket
 
+    def _touch_locked(self, ent: _ArenaEntry, ctx, anchor,
+                      now: Optional[float] = None) -> None:
+        """One use of the entry: its frequency and recency (the
+        eviction order) and its residency's owner."""
+        self._tick += 1
+        ent.hits += 1
+        ent.tick = self._tick
+        self._own_locked(ent, ctx, anchor, now)
+
+    def peek(self, anchor) -> Optional[dict]:
+        """The anchor's bucket where it is resident, else None: one dict
+        read, no mutex, and nothing of the entry touched (recency,
+        frequency, ownership).  For a look-up that decides nothing by
+        itself (``DeviceRunner.launch_ticket``): whoever stages from
+        what it found holds it to :meth:`pin_many`'s answer."""
+        ent = self._entries.get(id(anchor))
+        return None if ent is None else ent.bucket
+
     # -- residency metering -------------------------------------------
 
-    def _own_locked(self, ent: _ArenaEntry, ctx, anchor) -> None:
+    def _own_locked(self, ent: _ArenaEntry, ctx, anchor,
+                    now: Optional[float] = None) -> None:
         """A tagged toucher takes ownership of the anchor's residency;
         accrual up to now settles to the PREVIOUS owner first (the
         tag that parked the bytes pays for the parking)."""
         if ctx is None or ctx.tag is None:
             return
         if ent.owner_tag != ctx.tag:
-            self._settle_entry_locked(ent, time.monotonic())
+            self._settle_entry_locked(
+                ent, time.monotonic() if now is None else now)
             ent.owner_tag = ctx.tag
         region = ctx.region if ctx.region is not None else \
             getattr(anchor, "region_hint", None)
@@ -833,6 +873,10 @@ class FeedArena:
             if not self._pending_res:
                 return
             pending, self._pending_res = self._pending_res, []
+        self._charge_residency(pending)
+
+    @staticmethod
+    def _charge_residency(pending: list) -> None:
         from .. import resource_metering as _rm
         for tag, region, byte_s in pending:
             _rm.GLOBAL_RECORDER.charge(
@@ -890,6 +934,37 @@ class FeedArena:
             token = (id(anchor), ent.gen)
         self._flush_residency()
         return token
+
+    def pin_many(self, anchors) -> list:
+        """A hold's lanes, each over its line: what :meth:`bucket` and
+        :meth:`pin` do for one (the entry touched and owned, its
+        residency settled, its pin) for all of them under ONE acquire of
+        the mutex → ``(bucket, token)`` a lane in order, ``(None,
+        None)`` where the anchor is not resident.  Residency is settled
+        at one instant and flushed once, outside the mutex; a token is
+        :meth:`pin`'s and :meth:`unpin` takes it."""
+        from .. import resource_metering as _rm
+        ctx = _rm.current_context()
+        out = []
+        pending = None
+        with self._mu:
+            now = time.monotonic()
+            for anchor in anchors:
+                ent = self._entries.get(id(anchor))
+                if ent is None:
+                    out.append((None, None))
+                    continue
+                self._touch_locked(ent, ctx, anchor, now)
+                self._settle_entry_locked(ent, now)
+                if ent.pins == 0:
+                    self._pinned += ent.nbytes
+                ent.pins += 1
+                out.append((ent.bucket, (id(anchor), ent.gen)))
+            if self._pending_res:
+                pending, self._pending_res = self._pending_res, []
+        if pending:
+            self._charge_residency(pending)
+        return out
 
     def unpin(self, token) -> None:
         if token is None:
@@ -1108,9 +1183,9 @@ class FeedArena:
     def pinned_bytes(self) -> int:
         """Bytes held by entries pinned by in-flight dispatches (the
         flight recorder stamps this per launch — O(1) running total,
-        maintained at pin/unpin/re-account/drop)."""
-        with self._mu:
-            return self._pinned
+        maintained at pin/unpin/re-account/drop; one int read, so no
+        mutex: a launch on the dispatcher must not park for a gauge)."""
+        return self._pinned
 
     def resident_lines(self) -> int:
         with self._mu:

@@ -48,7 +48,12 @@ as its lanes:
   staging under one hold of the dispatch lock, one program that runs
   the kernel once a lane over that lane's feed alone, one fetch), each
   lane still its own answer for its own snapshot; a group of a key
-  already among them joins that lane.  Nothing is held back for it and
+  already among them joins that lane.  What the runner resolved to tell
+  a group's class it hands over with it (``DeviceRunner.launch_ticket``
+  → ``_Group.ticket``: the plan, the line's memo, the prepared record,
+  the generation), and the group's lane is staged from that ticket: the
+  ask is a prepared hit's only look-up, inside the hold
+  (``group_open``).  Nothing is held back for it and
   nothing waits longer: a closed group waits for nothing but the
   dispatcher thread, and an open one is closed early (``lanes``), the
   launch its window was waiting to amortize being this one; so there
@@ -365,7 +370,7 @@ _UNASKED = object()
 
 class _Group:
     __slots__ = ("key", "members", "close_at", "window_close_at",
-                 "closed", "t_closed_ns", "klass")
+                 "closed", "t_closed_ns", "klass", "ticket")
 
     def __init__(self, key, close_at: float):
         self.key = key
@@ -377,8 +382,13 @@ class _Group:
         # shutdown-time group dispatched inline, never queued)
         self.t_closed_ns = 0
         # the runner's launch class of the group's launch, asked once
-        # (_launch_class; None: it leaves alone)
+        # (_launch_class; None: it leaves alone), and what the runner
+        # resolved to find it, the group's TICKET: handed back with the
+        # lane, so that a prepared hit is staged without a second
+        # look-up (DeviceRunner.launch_ticket; None: no class, or a
+        # runner that gives none)
         self.klass = _UNASKED
+        self.ticket = None
 
 
 class RequestCoalescer:
@@ -693,8 +703,10 @@ class RequestCoalescer:
             g = None
             # the launcher's two states, accounted in the aggregate:
             # dispatcher_idle (here, nothing ready) and group_dispatch
-            # (_dispatch).  Idle most of a window, the device starves
-            # because requests are elsewhere; busy, staging is the queue
+            # (_dispatch, from the group popped: the take of what leaves
+            # with it is the hold's first work).  Idle most of a window,
+            # the device starves because requests are elsewhere; busy,
+            # staging is the queue
             with tracker.timed("dispatcher_idle"), self._cv:
                 while not self._ready:
                     if self._shutdown:
@@ -712,7 +724,7 @@ class RequestCoalescer:
                 if self._ready:
                     g = self._ready.popleft()
             if g is not None:
-                self._dispatch(g, self._take_fusable(g))
+                self._dispatch(g, fuse=True)
 
     def _take_fusable(self, g: _Group) -> list:
         """The other groups that leave with ``g``: every group that
@@ -723,7 +735,9 @@ class RequestCoalescer:
         lane; groups over other feeds need the kernel's lane programs
         built (``lanes_ready``) and leave alone until they are.
 
-        Nothing is held back for this and nothing waits longer.  A
+        Each group asked keeps what the runner resolved for it
+        (``_launch_class``: its ticket rides to its staging, now or in a
+        later turn).  Nothing is held back for this and nothing waits longer.  A
         closed group waits for nothing but this thread; an open one is
         closed EARLY (reason ``lanes``), as the pipeline close does
         for a device that ran dry: its window was for gathering
@@ -770,18 +784,21 @@ class RequestCoalescer:
         return take
 
     def _launch_class(self, g: _Group):
+        """The group's launch class, asked of the runner once; what the
+        runner resolved to answer stays with the group (``g.ticket``)."""
         if g.klass is not _UNASKED:
             return g.klass
         lead = g.members[0] if g.members else None
-        klass = None
+        ticket = None
         if lead is not None and not self._shutdown:
             try:
-                klass = self._runner.launch_class(g.key, lead.dag,
-                                                  lead.storage)
+                ticket = self._runner.launch_ticket(g.key, lead.dag,
+                                                    lead.storage)
             except Exception:   # noqa: BLE001 — it leaves alone
                 pass
-        g.klass = klass
-        return klass
+        g.ticket = ticket
+        g.klass = None if ticket is None else ticket.klass
+        return g.klass
 
     def _lanes_ready(self, g: _Group, klass) -> bool:
         try:
@@ -791,19 +808,25 @@ class RequestCoalescer:
 
     # ---------------------------------------------------------- dispatch
 
-    def _dispatch(self, group: _Group, merged=()) -> None:
-        """Stage one closed group's launch, and with it the ``merged``
-        groups of its launch class, on whichever thread runs it (the
-        dispatcher; a submitter or close() at shutdown)."""
+    def _dispatch(self, group: _Group, fuse: bool = False) -> None:
+        """Stage one closed group's launch on whichever thread runs it:
+        the dispatcher, which takes with it (``fuse``) the waiting
+        groups of its launch class, or a submitter or close() at
+        shutdown."""
         from ..utils import tracker
         t_begin_ns = time.perf_counter_ns()
         lead = group.members[0].tracker if group.members else None
         # the hold: every row of trace_vocab.HOLD_ROWS that runs on this
         # thread until it closes is accounted against it, and what none
-        # of them covered is its ``dispatch_self``
+        # of them covered is its ``dispatch_self``.  It opens where the
+        # group was popped: the take and the look-ups it asks of the
+        # runner (each group's class and ticket, a hit's only look-up)
+        # are ``group_open``'s
         with tracker.hold("group_dispatch", "dispatch_self",
                           lead.trace_id if lead is not None else None), \
                 tracker.held("group_open") as piece:
+            merged = self._take_fusable(group) if fuse else ()
+            self._launch_class(group)       # (asked, where nothing waited)
             self._stage(group, t_begin_ns, merged, piece)
 
     def _stage(self, group: _Group, t_begin_ns: int, merged, piece) -> None:
@@ -819,16 +842,20 @@ class RequestCoalescer:
         # (a merged group of a key already there joins that lane: its
         # members share the lane's one result, as if they had closed
         # together).  One lane, no merge: today's path.
-        lanes = None
+        lanes = tickets = None
         if merged:
             by_key = {group.key: members}
+            leads = {group.key: group}
             for og in merged:
                 lane = by_key.get(og.key)
                 if lane is None:
                     by_key[og.key] = list(og.members)
+                    leads[og.key] = og
                 else:
                     lane.extend(og.members)
             lanes = list(by_key.values())
+            # a lane's ticket is its lead's, the first group of its key
+            tickets = [g.ticket for g in leads.values()]
             members = [m for lane in lanes for m in lane]
             with self._mu:
                 self.groups_merged += len(lanes) - 1
@@ -913,7 +940,8 @@ class RequestCoalescer:
                     # not launch comes back None and its members
                     # retry solo below
                     outcomes = self._runner.handle_lanes(
-                        [(lane[0].dag, lane[0].storage) for lane in lanes])
+                        [(lane[0].dag, lane[0].storage) for lane in lanes],
+                        tickets)
                 elif group.key[0] == "stack" and size > 1:
                     handle = self._runner.handle_batched(
                         [(m.dag, m.storage) for m in members])
@@ -926,9 +954,12 @@ class RequestCoalescer:
                     # singleton / identical-plan share: one solo
                     # dispatch, its (memoized, thread-safe) fetch
                     # serves every member
+                    # (the ticket rides only where there is one: a
+                    # runner that gives none takes no such argument)
                     outcomes = [self._runner.handle_request(
-                        members[0].dag, members[0].storage,
-                        deferred=True)]
+                        members[0].dag, members[0].storage, deferred=True,
+                        **({} if group.ticket is None
+                           else {"_ticket": group.ticket}))]
                 # (no request waits for what follows but for its own
                 # hand-over: the row and the annotation alone)
                 piece.turn("group_complete", traced=False)
@@ -1114,6 +1145,8 @@ class RequestCoalescer:
                                        t_waited_ns)
                 tracker.add_span("coalesce_window", m.t_submit_ns,
                                  t_closed_ns, sp)
+                # (0 for a group this very turn closed, ``lanes``,
+                # after its staging began: add_span clamps)
                 tracker.add_span("dispatch_queue_wait", t_closed_ns,
                                  t_begin_ns, sp)
                 if launch is not None and sp is not None:

@@ -166,14 +166,21 @@ SPAN_VOCABULARY: dict[str, str] = {
     # the hold's own vocabulary (HOLD_ROWS below): inside a hold each
     # of these keeps its SELF time, so that with the five older rows of
     # the hold and dispatch_self they add up to group_dispatch
-    "group_open": "the hold, before the runner is called: lanes merged, "
-                  "DWFQ selection, the group span begun, the leader "
-                  "adopted, the metering scope entered",
-    "stage_plan": "one lane's staging up to its branch: plan analysis, "
+    "group_open": "the hold, before the runner is called: the waiting "
+                  "groups of the launch class taken (_take_fusable) and "
+                  "each one's class and ticket asked of the runner "
+                  "(runner.py launch_ticket: a hit's only look-up), "
+                  "lanes merged, DWFQ selection, the group span begun, "
+                  "the leader adopted, the metering scope entered",
+    "stage_plan": "a hold's lanes staged up to their branch, ONE piece "
+                  "a hold (attrs lanes, ticket_hits): every ticketed "
+                  "lane from its prepared record in one pass "
+                  "(runner.py _stage_tickets: the runner's gates once, "
+                  "each lane's guards and operands, the arena's mutex "
+                  "once for every pin), then, of a lane that stages as "
+                  "a request of its own (_stage_local), plan analysis, "
                   "quarantine gates, the tile probe, the request memo, "
-                  "the generation check, the row count, and on a hit the "
-                  "prepared record's guards, operands and pin "
-                  "(runner.py _handle_local, _stage_prepared)",
+                  "the generation check, the row count",
     "memo_roll": "a written line's derived record rolled across the "
                  "journal's gap (runner.py _refresh_meta → feed.py "
                  "roll_derived; attr outcome: kept | dropped:<cause>, "
