@@ -1,7 +1,8 @@
 """The metric files that wait for their manifest entries (PR 36's
 convention), for the tests that run each reader over a load generator
-child's result: tests/test_regions96_served.py, and for the files whose
-cells write, tests/test_tpch_q1_refresh_served.py.
+child's result: tests/test_regions96_served.py, for the files whose
+cells write, tests/test_tpch_q1_refresh_served.py, and for those of the
+cell under an HBM budget, tests/test_streams_hbm_served.py.
 
 A counter and the metric that reads it cannot land in one PR: line.py
 refuses a traced line that lacks a declared metric, and the driver makes
@@ -21,13 +22,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 REGIONS = "agg-regions96-closed4"
 REFRESH = "q1-refresh-lineitem-sf1-closed4"
+HBM = "streams-hbm164-lineitem-sf1-closed4"
 
 
 def pending_metrics(cell: str = None) -> dict:
     """{name: the file}; with ``cell``, the files that child's result is
     the one to read over: a file is taken on a cell of its OWN
-    ``workloads``, the regions cell's where it lists it (or neither of
-    the two: the read-only child reads them as it always has)."""
+    ``workloads``, the regions cell's where it lists it (or none of
+    the three: the read-only child reads them as it always has)."""
     out = {}
     for path in sorted(glob.glob(os.path.join(BENCH, "layer_metrics",
                                               "*.json"))):
@@ -36,8 +38,8 @@ def pending_metrics(cell: str = None) -> dict:
         if "pending_entry" not in spec:
             continue
         cells = spec["pending_entry"]["workloads"]
-        home = REFRESH if REFRESH in cells and REGIONS not in cells \
-            else REGIONS
+        home = next((c for c in (REFRESH, HBM)
+                     if c in cells and REGIONS not in cells), REGIONS)
         if cell in (None, home):
             out[os.path.basename(path)[:-len(".json")]] = spec
     return out

@@ -302,6 +302,102 @@ def test_the_streams_cell_runs_the_manifests_files():
             bench, "layer_metrics", f"{name}.json")), name
 
 
+# The cell under an HBM budget (PR 53): each of its nine reads the
+# window's records or what the program had before the PR (the arena's
+# counters since PR 6), so a parent's line under these files is whole: it
+# was measured so (PERF.md section 6).  The driver found that parent too
+# unsteady to admit a cell against (two states a process keeps, 22% of
+# spread), so the cell's Q1 module now asks one more question and the
+# parent exits 1 at its first read, as a program before PR 48 does on the
+# streams cell; the readers stay as they were.
+
+HBM = "streams-hbm164-lineitem-sf1-closed4"
+WHAT_A_PARENT_OF_PR_53_HAS = WHAT_A_PARENT_HAS | {
+    "health.tracing.phases.feed_upload.count",
+    "health.device_state.hbm.evictions",
+    "health.device_state.hbm.rejections"}
+
+
+def hbm_metrics() -> list:
+    return sorted(name for name, spec in entries().items()
+                  if spec["per_layer_entry"]["workloads"] == [HBM])
+
+
+@pytest.mark.parametrize("name", hbm_metrics())
+def test_an_hbm_metric_reads_only_what_its_parent_had(name):
+    assert len(hbm_metrics()) == 9 and name.endswith(".hbm")
+    spec = entries()[name]
+    assert os.path.isfile(os.path.join(
+        ROOT, "benchmark", "readers", f"{spec['reader']}.py"))
+    paths = {v for k, v in spec["args"].items() if k in ("num", "den")}
+    assert paths <= WHAT_A_PARENT_OF_PR_53_HAS, name
+    if spec["reader"] == "kind_latency_median":
+        assert spec["args"]["kind"] in hbm_traffic()[0]["kinds"]
+    else:
+        assert spec["reader"] == "counter_ratio" and len(paths) == 2
+    # what the PR adds to /health is read by files that WAIT
+    from tikv_tpu.device.supervisor import FeedArena, FlightRecorder
+    assert {"evicted_bytes", "memos_kept", "evictions", "rejections"} <= \
+        set(FeedArena().stats())
+    assert {"gets", "uploads"} <= set(FlightRecorder().feed_counts())
+
+
+def hbm_traffic() -> tuple:
+    """(the cell's traffic file, the streams cell's)."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cell, = [w for w in json.load(f)["workloads"] if w["name"] == HBM]
+    assert cell["traffic"] == HBM
+    out = []
+    for name in (HBM, STREAMS):
+        with open(os.path.join(ROOT, "benchmark", "traffic",
+                               f"{name}.json")) as f:
+            out.append(json.load(f))
+    return tuple(out)
+
+
+def test_the_hbm_cells_traffic_is_the_streams_cells_but_for_one_module():
+    """A file of its own for one word: Q1's module, which asks the
+    program one more question; every parameter is the streams file's."""
+    mine, theirs = hbm_traffic()
+    assert mine.pop("what") != theirs.pop("what")
+    assert mine["kinds"]["tpch_q1"].pop("module") == "tpch_q1_streams_hbm"
+    assert theirs["kinds"]["tpch_q1"].pop("module") == "tpch_q1_streams"
+    assert mine == theirs
+
+
+def test_the_hbm_cells_q1_is_the_streams_kind_behind_one_more_question():
+    """``requests/tpch_q1_streams_hbm.py``: ``tpch_q1``'s classes, send,
+    reference, digest and check, the streams kind's question asked
+    first, then ``arena_evict`` of the span vocabulary."""
+    mine, theirs = streams_kind("tpch_q1_streams_hbm"), streams_kind("tpch_q1")
+    assert mine.CLASSES == theirs.CLASSES == ("pallas_hash",)
+    for name in ("send", "reference", "digest", "check", "plan"):
+        assert getattr(mine, name).__code__ == getattr(theirs, name).__code__
+    assert mine.DELTAS == theirs.DELTAS
+    assert mine.prepare.__code__ != theirs.prepare.__code__
+    from tikv_tpu.utils import trace_vocab
+    assert "arena_evict" in trace_vocab.SPAN_VOCABULARY
+    assert "arena_evict" in trace_vocab.HOLD_ROWS
+    mine.require_program()
+
+
+@pytest.mark.parametrize("missing", ["arena_evict", "SCHEMAS_PER_REGION"])
+def test_the_hbm_cell_refuses_a_program_whose_budget_takes_the_memo(
+        missing, monkeypatch):
+    """The parent of PR 53 (no ``arena_evict``) and a program before
+    PR 48 exit 1 from ``prepare``, before the cell's first read, and
+    say what they lack."""
+    from tikv_tpu.copr import region_cache
+    from tikv_tpu.utils import trace_vocab
+    if missing == "arena_evict":
+        monkeypatch.delitem(trace_vocab.SPAN_VOCABULARY, missing)
+    else:
+        monkeypatch.delattr(region_cache, missing)
+    with pytest.raises(SystemExit) as e:
+        streams_kind("tpch_q1_streams_hbm").prepare(None, None, {})
+    assert missing in str(e.value)
+
+
 def streams_kind(name: str):
     import sys
     bench = os.path.join(ROOT, "benchmark")
@@ -373,7 +469,10 @@ def test_the_hold_metric_reads_a_parent_and_names_the_holds_rows():
     assert all(path(p) in WHAT_A_PARENT_HAS for p in parts[:5])
     # ... and then the hold's own rows, each once: what the program adds
     # up (utils/trace.py hold) is what the metric reads
-    assert tuple(parts) == HOLD_WHOLE + HOLD_SELF
+    # (arena_evict, PR 53's leaf, is not in the accepted file: on the
+    # cells it lists the budget is 0 and no sweep evicts)
+    assert tuple(parts) == tuple(
+        r for r in HOLD_WHOLE if r != "arena_evict") + HOLD_SELF
     assert set(parts) | {whole, "dispatch_self"} <= set(SPAN_VOCABULARY)
     assert "dispatch_self" not in parts
     entry = spec["per_layer_entry"]
@@ -382,7 +481,8 @@ def test_the_hold_metric_reads_a_parent_and_names_the_holds_rows():
     assert entry["workloads"] == ["q1-refresh-lineitem-sf1-closed4",
                                   "q1-lineitem-sf1-closed4", STREAMS]
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        assert json.load(f)["per_layer"][-1] == entry
+        # (where the manifest ended at PR 52)
+        assert json.load(f)["per_layer"][60] == entry
 
 
 def _sample(rows: dict) -> dict:

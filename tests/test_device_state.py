@@ -173,7 +173,158 @@ def test_pinned_inflight_deferred_dispatch_is_never_evicted():
     assert int(deferred.result().rows()[0][0]) == want0
     assert runner.hbm_stats()["pinned_lines"] == 0
     assert int(runner.handle_request(dag1, snap1).rows()[0][0]) == want1
-    assert runner._arena.bucket(snap0, create=False) is None
+    # ... and is evicted: its planes go, its host memo stays (PR 53)
+    kept = runner._arena.bucket(snap0, create=False)
+    assert kept and not any("flat" in v for v in kept.values())
+    assert runner.hbm_stats()["resident_lines"] == 1
+
+
+@pytest.mark.parametrize("how", ["evict", "drop"])
+def test_the_budget_keeps_a_lines_host_memo_and_a_drop_does_not(
+        how, monkeypatch):
+    """What the HBM budget takes from a line (here a squeeze under
+    ``device::hbm_oom``) is its device state: the next read of the line
+    pays ``feed_upload`` (``after_eviction``) and derives nothing on the
+    host, nor hashes the host planes again (their scrub digests stay in
+    the memo beside them).  ``drop_feed`` (lifecycle, quarantine) takes
+    the memo too, as it always did: the next read derives and hashes
+    again.  Both count where /health shows them."""
+    from tikv_tpu.device import supervisor
+    from tikv_tpu.utils import tracker
+    runner = _runner()
+    assert runner.scrub_digests
+    hashed = []
+    digest = supervisor.host_plane_digest
+    monkeypatch.setattr(supervisor, "host_plane_digest",
+                        lambda arr, n: hashed.append(n) or digest(arr, n))
+    snap, dag, want = _snap(8450, seed=6)
+    snap1, dag1, want1 = _snap(8451, seed=7)
+
+    def serve(snap, dag):
+        tr, tok = tracker.install(sampled=True)
+        try:
+            rows = runner.handle_request(dag, snap).rows()
+        finally:
+            tracker.uninstall(tok)
+        return int(rows[0][0]), {s.name: s.attrs for s in tr.spans}
+
+    got, spans = serve(snap, dag)
+    assert got == want and "host_derive" in spans
+    assert spans["feed_upload"] == {"bytes": 16384, "planes": 1,
+                                    "after_eviction": False}
+    per_feed = runner.hbm_stats()["resident_bytes"]
+    assert per_feed == 16384 and len(hashed) == 1
+    cold, = (v["digests"] for v in
+             runner._arena.bucket(snap, create=False).values()
+             if "flat" in v)
+    if how == "evict":
+        # room for one feed: admitting the second line's evicts the first
+        failpoint.cfg("device::hbm_oom", f"return({per_feed})")
+        got, spans = serve(snap1, dag1)
+        failpoint.remove("device::hbm_oom")
+        assert got == want1
+        assert spans["arena_evict"] == {"victims": 1, "bytes": per_feed}
+        st = runner.hbm_stats()
+        assert (st["evictions"], st["evicted_bytes"], st["memos_kept"]) \
+            == (1, per_feed, 1)
+        assert (st["resident_bytes"], st["resident_lines"]) == (per_feed, 1)
+        bucket = runner._arena.bucket(snap, create=False)
+        memo, = bucket.values()
+        assert "dtypes" in memo and "prepared" not in memo
+        assert memo["host_digests"][0] is memo["host_cols"]
+        hashed.clear()      # (the other line's upload hashed its own)
+    else:
+        assert runner.drop_feed(snap) == per_feed
+        assert runner._arena.bucket(snap, create=False) is None
+        assert runner.hbm_stats()["memos_kept"] == 0
+    got, spans = serve(snap, dag)
+    assert got == want
+    assert ("host_derive" in spans) == (how == "drop")
+    assert spans["feed_upload"]["after_eviction"] == (how == "evict")
+    # the same truth, the same digests; hashed again after a drop alone
+    assert len(hashed) == (0 if how == "evict" else 2)
+    again, = (v["digests"] for v in
+              runner._arena.bucket(snap, create=False).values()
+              if "flat" in v)
+    assert again == cold
+    feed = runner.mesh_stats()["feed"]
+    assert feed["gets"]["upload"] == sum(feed["gets"].values()) == \
+        feed["uploads"]["cold"] + feed["uploads"]["evicted"]
+    assert feed["uploads"]["evicted"] == (how == "evict")
+    assert feed["uploads"]["bytes"] == per_feed * feed["gets"]["upload"]
+    # a warm read is a hit, and the memo it reads is the kept one
+    got, spans = serve(snap, dag)
+    assert got == want and "feed_upload" not in spans
+    assert runner.mesh_stats()["feed"]["gets"]["hit"] == 1
+    check_hbm_within_budget(runner)
+
+
+@pytest.mark.parametrize("shards", [1, 8])
+def test_an_upload_puts_the_padded_buffer_kept_with_the_host_plane(
+        shards, monkeypatch):
+    """A plane that is cast on its way into the memo is cast INTO a
+    zeroed buffer of the feed's padded length, the memo's plane its head
+    (``HostPlanes.stream`` / ``padded``): the cold upload and the one
+    that brings back a feed the budget took put THAT buffer, and make no
+    padded copy.  Planes that replace the memo's are never taken for
+    them (identity), and pad a copy as before."""
+    import jax
+    from tikv_tpu.device import feed as feed_mod
+    from tikv_tpu.parallel import make_mesh
+    runner = _runner(mesh=make_mesh(jax.devices()[:shards]))
+    assert runner._single == (shards == 1)
+    n, n_pad = 4000, 4096
+    snap, dag, want = _snap(8460, n=n, seed=8)
+    snap1, dag1, want1 = _snap(8461, n=n, seed=9)
+    put = []
+
+    class Puts:
+        """``jnp`` / ``jax`` as feed.py sees them, the host arrays it
+        puts noted (the tests' mesh has eight shards: ``device_put``)."""
+
+        def __init__(self, mod):
+            self._mod = mod
+
+        def __getattr__(self, name):
+            return getattr(self._mod, name)
+
+        def asarray(self, a, *args, **kw):
+            put.append(a)
+            return self._mod.asarray(a, *args, **kw)
+
+        def device_put(self, a, *args, **kw):
+            put.append(a)
+            return self._mod.device_put(a, *args, **kw)
+
+    monkeypatch.setattr(feed_mod, "jnp", Puts(feed_mod.jnp))
+    monkeypatch.setattr(feed_mod, "jax", Puts(feed_mod.jax))
+    assert int(runner.handle_request(dag, snap).rows()[0][0]) == want
+    memo, = (v for v in runner._arena.bucket(snap, create=False).values()
+             if "flat" not in v)
+    (plane, _ok), = memo["host_cols"]
+    pad, = memo["host_pads"]
+    assert pad.shape == (n_pad,) and pad.dtype == plane.dtype == np.int32
+    assert plane.base is pad and plane.shape == (n,)
+    assert not pad[n:].any()
+    assert [a is pad for a in put] == [True]         # the cold upload too
+    per_feed = runner.hbm_stats()["resident_bytes"]
+    assert per_feed == n_pad * 4
+    failpoint.cfg("device::hbm_oom", f"return({per_feed})")
+    assert int(runner.handle_request(dag1, snap1).rows()[0][0]) == want1
+    failpoint.remove("device::hbm_oom")
+    assert runner.hbm_stats()["evictions"] == 1
+    del put[:]
+    assert int(runner.handle_request(dag, snap).rows()[0][0]) == want
+    assert [a is pad for a in put] == [True]
+    assert runner.mesh_stats()["feed"]["uploads"]["evicted"] == 1
+    # planes of another make (a cut's, a request's own) are not the
+    # buffer's head: no buffer is taken for theirs
+    planes = feed_mod.HostPlanes.__new__(feed_mod.HostPlanes)
+    planes.memo, planes.meta = {}, memo
+    assert planes.padded(0, plane, n_pad) is pad
+    assert planes.padded(0, plane.copy(), n_pad) is None
+    assert planes.padded(0, plane, 2 * n_pad) is None
+    check_hbm_within_budget(runner)
 
 
 def test_hbm_oom_failpoint_squeezes_budget():

@@ -156,7 +156,10 @@ def refresh_and_read(store, kind, params) -> dict:
 
 
 def feed_counts(store) -> dict:
-    return health(store)["device_mesh"]["feed"]
+    """What the feed ladder did to resident feeds (its answers by rung,
+    ``gets``, count every read and are left out)."""
+    feed = health(store)["device_mesh"]["feed"]
+    return {k: v for k, v in feed.items() if k != "gets"}
 
 
 # ------------------------------------------------- the files of the cell

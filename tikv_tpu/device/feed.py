@@ -209,6 +209,21 @@ def span_planes(span, used_infos, kinds):
         yield plane_values(kind, vals), valid
 
 
+def truth_digests(truth, null_flags, n: int) -> tuple:
+    """The scrub digests of a feed's HOST truth: per used column
+    ``(values, validity)`` at the planes' dtypes, one digest a plane the
+    feed holds (a validity plane where the column has NULLs), over the
+    ``n`` live rows: one host pass a plane, ~12 B of temporaries a byte
+    of plane (supervisor ``host_plane_digest``)."""
+    from .supervisor import host_plane_digest
+    digests = []
+    for (v, ok), has_nulls in zip(truth, null_flags):
+        digests.append(host_plane_digest(v, n))
+        if has_nulls:
+            digests.append(host_plane_digest(ok, n))
+    return tuple(digests)
+
+
 class HostPlanes:
     """One request's used columns as the host halves of their planes:
     device dtypes, then (values, validity) numpy pairs at those dtypes.
@@ -222,10 +237,10 @@ class HostPlanes:
     so repeat requests do not rebuild columns to re-discover it."""
 
     __slots__ = ("plan", "infos", "kinds", "meta", "memo", "fresh",
-                 "get_batch", "n", "recorder")
+                 "get_batch", "n", "recorder", "pad_rows")
 
     def __init__(self, plan, meta: dict, memo: dict, fresh, get_batch,
-                 n: int, recorder):
+                 n: int, recorder, pad_rows=None):
         self.plan = plan
         self.infos = [plan.scan.columns[ci] for ci in plan.used_cols]
         self.kinds = plane_kinds(plan)
@@ -235,6 +250,8 @@ class HostPlanes:
         self.get_batch = get_batch
         self.n = n
         self.recorder = recorder
+        # the rows a feed of ``n`` is padded to (``FeedStore.pad_rows``)
+        self.pad_rows = pad_rows
 
     def dtypes(self) -> tuple:
         memo, meta = self.memo, self.meta
@@ -328,7 +345,16 @@ class HostPlanes:
         (async) device_put as soon as that column is converted, so the
         H2D transfer of column i overlaps the astype of column i+1 —
         double-buffering the tail of a columnar build instead of
-        serializing convert-all then upload-all."""
+        serializing convert-all then upload-all.
+
+        Where the cast makes a pass over a plane anyway, it is cast INTO
+        a zeroed buffer of the length a feed of these planes is padded
+        to (``pad_rows``, whoever reads the planes first) and
+        the memo's plane is the buffer's head (a view): what an upload
+        pads is what it padded the last time, so the buffer stays with
+        the planes (``host_pads``, by position; :meth:`padded`) and an
+        upload that brings back a feed the HBM budget took puts it as
+        it lies: no fresh pages, no copy."""
         memo, meta = self.memo, self.meta
         if "host_cols" not in memo:
             held = self._held()
@@ -339,22 +365,52 @@ class HostPlanes:
             return
         dts = self.dtypes()
         batch = self.get_batch()
+        n_pad = self.n if self.pad_rows is None else self.pad_rows(self.n)
         built = []
+        # (in the request's memo as they are made: the cold upload that
+        # drives this generator puts each buffer too)
+        pads = memo["host_pads"] = []
         for pos, (ci, ds) in enumerate(zip(self.plan.used_cols, dts)):
             col = batch.columns[ci]
             vals = self._values(pos, col)
             if vals is None:
                 raise _FallbackToHost("CHAR value without a code")
-            pair = (np.ascontiguousarray(
-                vals.astype(np.dtype(ds), copy=False)),
-                np.ascontiguousarray(col.validity))
+            dt = np.dtype(ds)
+            if n_pad == self.n or (
+                    vals is col.values and vals.dtype == dt
+                    and vals.flags.c_contiguous):
+                # (a plane that IS the line's column costs no host
+                # memory today: it stays so, and its upload pads a copy)
+                pad, v = None, np.ascontiguousarray(
+                    vals.astype(dt, copy=False))
+            else:
+                pad = np.zeros(n_pad, dtype=dt)
+                v = pad[:self.n]
+                np.copyto(v, vals, casting="unsafe")    # astype's cast
+            pads.append(pad)
+            pair = (v, np.ascontiguousarray(col.validity))
             built.append(pair)
             yield pair
         memo["host_cols"] = built
         with _PLANES_MU:
             if self.fresh():
                 meta["host_cols"] = built
+                meta["host_pads"] = tuple(pads)
                 meta.pop("host_gap", None)
+                meta.pop("host_digests", None)
+
+    def padded(self, pos: int, arr, n_pad: int):
+        """The zero-padded host buffer of ``n_pad`` rows whose head the
+        plane ``arr`` of used column ``pos`` is, where :meth:`stream`
+        made one with it, else None.  Proved by identity (the only
+        views of such a buffer are its heads), so planes that replaced
+        the memo's (a cut, a request's own build) are never taken for
+        them."""
+        pads = self.memo.get("host_pads") or self.meta.get("host_pads")
+        pad = pads[pos] if pads else None
+        if pad is None or pad.shape[0] != n_pad or arr.base is not pad:
+            return None
+        return pad
 
     def _held(self) -> Optional[list]:
         """The shared memo's planes AT THIS REQUEST'S GENERATION, else
@@ -387,10 +443,31 @@ class HostPlanes:
                         meta.get("host_gap") is gap:
                     meta["host_cols"] = cut
                     del meta["host_gap"]
+                    meta.pop("host_digests", None)
+                    meta.pop("host_pads", None)
             planes = cut
         if planes and len(planes[0][0]) != self.n:
             return None
         return planes
+
+    def truth_digests(self, truth, null_flags, n: int) -> tuple:
+        """``truth_digests`` of this request's planes, computed ONCE for
+        the planes the memo holds and kept beside them
+        (``host_digests``): an upload that brings back a feed the HBM
+        budget took reads the same planes and hashes nothing again.
+        Held to THAT list of planes by identity and to the row count:
+        whatever replaces the planes (a roll, a cut, a fresh build)
+        leaves the digests behind."""
+        planes = self.memo.get("host_cols")
+        kept = self.meta.get("host_digests")
+        if kept is not None and kept[0] is planes and kept[1] == n:
+            return kept[2]
+        got = truth_digests(truth, null_flags, n)
+        with _PLANES_MU:
+            if planes is not None and self.fresh() and \
+                    self.meta.get("host_cols") is planes:
+                self.meta["host_digests"] = (planes, n, got)
+        return got
 
     def cols(self) -> list:
         return list(self.stream())
@@ -415,12 +492,17 @@ class HostPlanes:
 # ``key_bounds``, ``simple_arg_nbytes``), and the host planes
 # (``host_cols``, and beside them ``host_gap``: the delete-only journal
 # entries they lag the record by, cut where the planes are next read:
-# ``HostPlanes.stream``).  ``HostPlanes`` and the run bodies of
+# ``HostPlanes.stream``), with the scrub digests of THOSE planes
+# (``host_digests``: ``HostPlanes.truth_digests``, held to the list by
+# identity) and the padded buffers they are the heads of (``host_pads``:
+# ``HostPlanes.padded``, held to each plane by identity).  ``HostPlanes``
+# and the run bodies of
 # aggregate.py write them, each when it is first asked for;
 # ``roll_derived`` alone carries them across a write.
 
 DERIVED = ("bounds", "dtypes", "limbs", "hash_bounds", "key_bounds",
-           "simple_arg_nbytes", "host_cols", "host_gap")
+           "simple_arg_nbytes", "host_cols", "host_gap", "host_digests",
+           "host_pads")
 
 # ``host_cols`` and ``host_gap`` change together: a roll notes a gap
 # beside the planes or drops both, a reader publishes the planes cut and
@@ -531,6 +613,8 @@ def roll_derived(meta: dict, plan, patches, count_rows, limb_variant,
                     # ``HostPlanes.window`` and needs none)
                     del meta["host_cols"]
                     meta.pop("host_gap", None)
+                    meta.pop("host_digests", None)
+                    meta.pop("host_pads", None)
                     fate = "dropped"
     recorder.note_memo(cause, fate)
     return "kept" if cause is None else f"dropped:{cause}"
@@ -745,24 +829,20 @@ class FeedStore:
         return math.gcd(n_pad, desired)
 
     def make_feed(self, flat, null_flags, n_pad: int, kinds, truth,
-                  n: int) -> dict:
+                  n: int, digests=truth_digests) -> dict:
         """THE feed dict: every builder (the upload, the device MVCC
         resolve, a split's child) comes here with its planes and the
         HOST ``truth`` they hold (per used column (values, validity) at
         the planes' dtypes; read only where the runner records digests).
         The digests anchor there, never to the planes they audit: a wrong
         resolve, slice or gather diverges at the next scrub instead of
-        laundering."""
+        laundering.  ``digests(truth, null_flags, n)``: who hashes the
+        truth (the upload's: ``HostPlanes.truth_digests``, which keeps
+        what it hashed beside the planes)."""
         feed = {"flat": tuple(flat), "null_flags": tuple(null_flags),
                 "n_pad": n_pad, "kinds": tuple(kinds), "n_live": n}
         if self._runner.scrub_digests:
-            from .supervisor import host_plane_digest
-            digests = []
-            for (v, ok), has_nulls in zip(truth, feed["null_flags"]):
-                digests.append(host_plane_digest(v, n))
-                if has_nulls:
-                    digests.append(host_plane_digest(ok, n))
-            feed["digests"] = tuple(digests)
+            feed["digests"] = digests(truth, feed["null_flags"], n)
             self._warm_digest_kernels(feed["flat"])
         return feed
 
@@ -774,43 +854,57 @@ class FeedStore:
         for a in flat:
             self.digest_kernel(a.dtype, a.shape[0])
 
-    def _build_flat(self, host_cols, n: int, kinds) -> dict:
+    def _build_flat(self, host_cols, n: int, kinds,
+                    digests=truth_digests, padded=None) -> dict:
         """One flat padded array per column value; a validity array only
         for columns that actually contain NULLs — all-valid columns
         reuse the on-device row mask (synthesized from iota < n), saving
-        the HBM footprint and H2D bandwidth of an all-true mask."""
+        the HBM footprint and H2D bandwidth of an all-true mask.
+        ``padded(pos, values, n_pad)``: the zero-padded host buffer a
+        used column's value plane is the head of, where its maker kept
+        one (``HostPlanes.padded``), else None."""
         r = self._runner
         n_pad = self.pad_rows(n)
         flat, flags, pairs = [], [], []
 
-        def put_padded(arr):
+        def put_padded(arr, pad=None):
             if r._single:
                 if n_pad == n:
                     return jnp.asarray(arr)
+                if pad is not None:
+                    # the plane's own padded buffer, kept with it and
+                    # never written again: put as it lies
+                    return jnp.asarray(pad)
                 # pad on the HOST: a device-side concatenate would
                 # compile per exact n (every data version has a new row
                 # count), costing seconds per cache rebuild; a host
-                # memcpy is shape-oblivious
+                # memcpy is shape-oblivious.  (A FRESH buffer a plane: the
+                # put reads it after it returns, on the chip too.  PR 53
+                # reused one and every plane read its successor's rows.)
                 p = np.zeros(n_pad, dtype=arr.dtype)
                 p[:n] = arr
                 return jnp.asarray(p)
             # a sharded cold build, span by span: the host's padded
-            # copy, then handing one slice to each shard (the put is
-            # not waited for: the next plane's pad overlaps it, and the
-            # first launch waits for what is left)
-            with tracker.span("feed_host_pad"):
-                p = np.zeros(n_pad, dtype=arr.dtype)
-                p[:n] = arr
+            # copy (where the plane did not bring its own), then handing
+            # one slice to each shard (the put is not waited for: the
+            # next plane's pad overlaps it, and the first launch waits
+            # for what is left)
+            p = pad
+            if p is None:
+                with tracker.span("feed_host_pad"):
+                    p = np.zeros(n_pad, dtype=arr.dtype)
+                    p[:n] = arr
             with tracker.span("feed_shard_put"):
                 return jax.device_put(p, r._row_sharding)
 
-        for v, ok in host_cols:
+        for pos, (v, ok) in enumerate(host_cols):
             pairs.append((v, ok))
-            flat.append(put_padded(v))
+            flat.append(put_padded(
+                v, padded(pos, v, n_pad) if padded is not None else None))
             flags.append(not bool(ok.all()))
             if flags[-1]:
                 flat.append(put_padded(ok))
-        return self.make_feed(flat, flags, n_pad, kinds, pairs, n)
+        return self.make_feed(flat, flags, n_pad, kinds, pairs, n, digests)
 
     def get(self, storage, planes: HostPlanes, ranges, n: int, lineage,
             req_v) -> dict:
@@ -842,7 +936,7 @@ class FeedStore:
         if feed is not None:
             fv = feed.get("lineage_v")
             if lineage is None or fv == req_v:
-                tracker.label("device_feed", "hit")
+                self._answered("hit")
                 return feed
             if fv is not None and fv > req_v:
                 # an older-generation read (history serve): never
@@ -857,7 +951,7 @@ class FeedStore:
                     feed, lineage, planes, n, req_v) if by_position \
                     else "structural"
                 if rebuild is None:
-                    tracker.label("device_feed", "patch")
+                    self._answered("patch")
                     self._register_digests(lineage, feed_key, feed)
                     return feed
                 if rebuild == "structural" and \
@@ -865,7 +959,7 @@ class FeedStore:
                     # the same rebuild (every plane written anew, the
                     # feed's positions the view's), sourced from the
                     # resident planes and not from the host's
-                    tracker.label("device_feed", "compact")
+                    self._answered("compact")
                     self._register_digests(lineage, feed_key, feed)
                     return feed
 
@@ -903,7 +997,7 @@ class FeedStore:
                     feed.get("lineage_v") == req_v or self._try_patch_feed(
                         feed, lineage, planes, n, req_v,
                         count=False) is None):
-                tracker.label("device_feed", "split")
+                self._answered("split")
                 return adopt(feed)
         # cold-path kill (device/mvcc.py): a device build left its
         # resolve artifacts on the lineage — mint the feed BORN
@@ -923,7 +1017,7 @@ class FeedStore:
                     feed = bundle.mint(self, used_infos, dtypes, n,
                                        self.pad_rows(n))
                     if feed is not None:
-                        tracker.label("device_feed", "device_resolve")
+                        self._answered("device_resolve")
                         return adopt(feed)
             else:
                 # first feed build for this line cannot consume the
@@ -931,14 +1025,30 @@ class FeedStore:
                 # now rather than pinning ~100 bytes/version on the
                 # lineage until a delta or teardown gets there
                 lineage.drop_cold()
-        tracker.label("device_feed", "rebuild" if rebuild else "upload")
+        self._answered("rebuild" if rebuild else "upload")
         _fp_degrade("device::before_feed_upload")
+        recorder = self._runner.flight_recorder
         if rebuild:
-            self._runner.flight_recorder.note_feed_rebuild(rebuild)
+            recorder.note_feed_rebuild(rebuild)
+        # an upload that brings back what the budget took, or a cold one
+        after = not rebuild and cache is not None and \
+            arena.reclaimed(anc, feed_key)
         with tracker.phase("feed_rebuild") if rebuild \
                 else tracker.phase("feed_upload"):
-            feed = self._build_flat(planes.stream(), n, planes.kinds)
+            feed = self._build_flat(planes.stream(), n, planes.kinds,
+                                    planes.truth_digests, planes.padded)
+            nbytes = sum(int(a.nbytes) for a in feed["flat"])
+            tracker.annotate(bytes=nbytes, planes=len(feed["flat"]),
+                             after_eviction=after)
+        if not rebuild:
+            recorder.note_feed_upload(nbytes, after)
         return adopt(feed)
+
+    def _answered(self, rung: str) -> None:
+        """The rung of the ladder that had the feed: the request's
+        ``device_feed`` label, and /health device_mesh.feed ``gets``."""
+        tracker.label("device_feed", rung)
+        self._runner.flight_recorder.note_feed_get(rung)
 
     @staticmethod
     def _cache_feed(bucket: dict, feed_key, feed: dict) -> None:

@@ -2508,7 +2508,7 @@ class DeviceRunner:
             # derived at most once
             piece.turn("stage_full")
             planes = HostPlanes(plan, meta, memo, memo_fresh, get_batch, n,
-                                self.flight_recorder)
+                                self.flight_recorder, self._feeds.pad_rows)
             dtypes = planes.dtypes()
             if planes.limbs:
                 # this feed's bounds ask for products summed as 16-bit
@@ -2726,6 +2726,7 @@ class DeviceRunner:
             raise
         if hits:
             tracker.label("device_feed", "hit")
+            self.flight_recorder.note_feed_get("hit", len(hits))
             if self._health is not None and self._health.quarantined():
                 # (the invariant counter chaos audits: ``_stage_local``)
                 self._health.launched_quarantined += len(hits)

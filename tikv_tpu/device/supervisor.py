@@ -106,6 +106,45 @@ def _bucket_nbytes(bucket: dict) -> int:
                for a in _bucket_arrays(bucket))
 
 
+def _bucket_has_feed(bucket: dict) -> bool:
+    return any(isinstance(v, dict) and "flat" in v
+               for v in list(bucket.values()))
+
+
+# what of a request memo holds device bytes or refers to a feed's: the
+# sparse-slot column (with the host's recode it belongs to) and the
+# prepared record (device/request.py ``_Prepared``)
+_MEMO_DEVICE_FIELDS = ("sparse_slots", "prepared")
+
+
+def _bucket_release(bucket: dict) -> tuple:
+    """Take out of one line's bucket what the budget counts and what
+    refers to it: its feeds, and of each request memo the fields of
+    ``_MEMO_DEVICE_FIELDS``.  What a memo derived on the HOST stays
+    (row count, dtypes, limbs, bounds, key grid, host planes, their
+    padded buffers and digests): it costs no HBM and a re-upload needs
+    all of it.  A memo is never changed in place (a staging on the dispatcher may hold it while a
+    completion thread's unpin sweeps): a copy without those fields
+    takes its slot, and the staging keeps the old one to itself, as it
+    kept the whole bucket when an eviction popped the entry.
+    → (the feed keys released, whether a memo is left)."""
+    gone, kept = [], False
+    for k, v in list(bucket.items()):
+        if not isinstance(v, dict):
+            continue
+        if "flat" in v:
+            del bucket[k]
+            gone.append(k)
+            continue
+        if any(f in v for f in _MEMO_DEVICE_FIELDS):
+            v = dict(v)
+            for f in _MEMO_DEVICE_FIELDS:
+                v.pop(f, None)
+            bucket[k] = v
+        kept = kept or bool(v)
+    return gone, kept
+
+
 # ----------------------------------------------------- flight recorder
 
 DEFAULT_FLIGHT_RECORDER_DEPTH = 256
@@ -121,6 +160,12 @@ DEFAULT_FLIGHT_RECORDER_DEPTH = 256
 # names them (``kernel`` also a launch from the record that failed);
 # ``gate``: a quarantined slice or line, or a memo forced to the host.
 TICKET_MISSES = ("none", "tile", "generation", "feed", "kernel", "gate")
+
+# The rungs of ``FeedStore.get``'s ladder (device/feed.py), as the
+# ``device_feed`` label names them and /health device_mesh.feed ``gets``
+# counts them.
+FEED_RUNGS = ("hit", "patch", "compact", "split", "device_resolve",
+              "rebuild", "upload")
 
 
 class FlightRecorder:
@@ -217,6 +262,13 @@ class FlightRecorder:
                               "null": 0}
         self.feed_rebuild_source = {"device": 0, "host": 0}
         self.feed_compact_rows = 0
+        # device/feed.py ``FeedStore.get``'s answers by rung (the
+        # ``device_feed`` label, counted; a lane staged from its
+        # prepared record is a ``hit``), and its uploads: of a feed the
+        # arena never held (``cold``) or had held and released under
+        # its budget (``evicted``), and the bytes of their planes
+        self.feed_gets = dict.fromkeys(FEED_RUNGS, 0)
+        self.feed_uploads = {"cold": 0, "evicted": 0, "bytes": 0}
         # cumulative measured launch wall: the resource-metering
         # attribution-coverage denominator (every _dispatch_phase wall
         # lands both here and in the RU recorder — charged wall /
@@ -391,12 +443,23 @@ class FlightRecorder:
             self.feed_rebuild_source[source] += 1
             self.feed_compact_rows += rows
 
+    def note_feed_get(self, rung: str, n: int = 1) -> None:
+        with self._mu:
+            self.feed_gets[rung] += n
+
+    def note_feed_upload(self, nbytes: int, after_eviction: bool) -> None:
+        with self._mu:
+            self.feed_uploads["evicted" if after_eviction else "cold"] += 1
+            self.feed_uploads["bytes"] += nbytes
+
     def feed_counts(self) -> dict:
         """/health ``device_mesh.feed``; ``after_delta`` = ``patches`` +
         every rebuild: what a read found a write had left behind."""
         with self._mu:
             rebuilds = dict(self.feed_rebuilds)
-            return {"patches": self.feed_patches,
+            return {"gets": dict(self.feed_gets),
+                    "uploads": dict(self.feed_uploads),
+                    "patches": self.feed_patches,
                     "patch_rows": self.feed_patch_rows,
                     "patch_windows": self.feed_patch_windows,
                     "patch_programs": self.feed_patch_programs,
@@ -734,7 +797,7 @@ class SliceHealthBoard:
 
 class _ArenaEntry:
     __slots__ = ("ref", "bucket", "nbytes", "hits", "tick", "pins",
-                 "gen", "owner_tag", "owner_region", "res_t0")
+                 "gen", "owner_tag", "owner_region", "res_t0", "released")
 
     def __init__(self, ref, gen: int):
         self.ref = ref
@@ -753,6 +816,9 @@ class _ArenaEntry:
         self.owner_tag = None
         self.owner_region = None
         self.res_t0 = time.monotonic()
+        # feed keys the budget took from this line and no upload has
+        # brought back (None: never any)
+        self.released = None
 
 
 class FeedArena:
@@ -770,6 +836,22 @@ class FeedArena:
     device buffers in use; evicting its line would free HBM the
     accounting still owes).  ``budget_bytes <= 0`` disables the budget
     (accounting and gauges stay live).
+
+    An entry's bucket holds what the budget counts (a line's feeds, a
+    memo's sparse-slot column) beside what it does not: the request
+    memos' host half (dtypes, limbs, bounds, host planes in their padded
+    upload buffers and their scrub digests), which costs no HBM and
+    which a re-upload needs whole.  So the BUDGET (an eviction,
+    ``admit``'s reject, ``enforce``) releases the first and
+    what refers to it (``_bucket_release``: the feeds, the slot column,
+    the prepared record) and keeps the entry with its memos: the next
+    read of the line pays ``feed_upload`` and no ``host_derive``.  The
+    kept entry starts over in the eviction order (``hits`` 0, as the
+    entry a re-upload used to create), accounts 0 bytes and is no
+    resident line; a line without a memo leaves whole.  Everything
+    else that ends a line takes the memos with it, as it always did:
+    ``drop`` (lifecycle, quarantine, ``on_line_retired``),
+    ``drop_all``, the weakref backstop.
     """
 
     def __init__(self, budget_bytes: int = 0):
@@ -792,10 +874,17 @@ class FeedArena:
         # recorder stamps it on EVERY kernel launch, so it must be
         # O(1), not an O(entries) sum under the arena mutex
         self._pinned = 0
+        # entries that hold device bytes (a line the budget released
+        # stays an entry, with its memos, and is none of these)
+        self._lines = 0
         self.budget_bytes = int(budget_bytes)
         self.evictions = 0
         self.rejections = 0
         self.drops = 0
+        # bytes evictions released, and evictions that left a host
+        # memo behind
+        self.evicted_bytes = 0
+        self.memos_kept = 0
 
     # -- bucket access ------------------------------------------------
 
@@ -902,9 +991,7 @@ class FeedArena:
             ent = self._entries.pop(key, None)
             if ent is not None:
                 self._settle_entry_locked(ent, time.monotonic())
-                self._resident -= ent.nbytes
-                if ent.pins > 0:
-                    self._pinned = max(0, self._pinned - ent.nbytes)
+                self._account_locked(ent, 0)
         # deliberately NO residency flush here: this is a weakref GC
         # callback and may fire on a thread already inside the
         # metering recorder's lock (an allocation-triggered collection
@@ -989,24 +1076,27 @@ class FeedArena:
         """Re-account ``anchor``'s bucket and enforce the budget,
         evicting other unpinned entries (lowest frequency, then oldest
         recency) until resident bytes fit.  Returns False when the
-        entry could not fit even alone — its bucket is dropped and the
-        caller serves the request from its transient feed, uncached."""
+        entry could not fit even alone — its device state is released
+        (its memos stay) and the caller serves the request from its
+        transient feed, uncached."""
         key = id(anchor)
         from ..utils.metrics import DEVICE_FEED_EVICTION_COUNTER
         with self._mu:
             ent = self._entries.get(key)
             if ent is None:
                 return False
+            if not _bucket_has_feed(ent.bucket):
+                # a line whose feed the budget took (a reject, or a
+                # sweep that raced this staging) keeps its memos and no
+                # device state: what the staging wrote since (a record
+                # of the feed it serves from, a slot column) is the
+                # request's own
+                _bucket_release(ent.bucket)
             fresh = _bucket_nbytes(ent.bucket)
             # settle at the OLD byte count before re-accounting: each
             # residency interval is charged at the bytes actually held
             self._settle_entry_locked(ent, time.monotonic())
-            self._resident += fresh - ent.nbytes
-            if ent.pins > 0:
-                # re-accounting a pinned entry moves the pinned total
-                # with it, or the pair of counters drifts apart
-                self._pinned = max(0, self._pinned + fresh - ent.nbytes)
-            ent.nbytes = fresh
+            self._account_locked(ent, fresh)
             budget = self.budget_bytes
             fp = fail_point("device::hbm_oom")
             if fp is not None:
@@ -1031,8 +1121,7 @@ class FeedArena:
                     # its HBM is in use by a launched kernel, so
                     # dropping the entry would only falsify the
                     # accounting (and strand the pin)
-                    self._entries.pop(key, None)
-                    self._resident -= ent.nbytes
+                    self._release_locked(key, ent)
                     self.rejections += 1
                     DEVICE_FEED_EVICTION_COUNTER.labels("reject").inc()
                     admitted = False
@@ -1056,25 +1145,40 @@ class FeedArena:
         share.  Work-conserving by construction: the bias only
         engages under budget pressure, so an over-share tenant keeps
         using slack capacity until someone actually needs it."""
+        if self._total_locked() <= budget:
+            return 0
+        from ..utils import tracker
+        # (a leaf of the dispatcher's hold where an admission sweeps, a
+        # phase of the request whose unpin does)
+        with tracker.phase("arena_evict"):
+            before = self.evicted_bytes
+            evicted = self._sweep_locked(budget, protect_key)
+            tracker.annotate(victims=evicted,
+                             bytes=self.evicted_bytes - before)
+        return evicted
+
+    def _sweep_locked(self, budget: int,
+                      protect_key: Optional[int]) -> int:
+        """``_evict_until_locked``'s sweep, resident bytes over
+        ``budget`` → entries evicted."""
         from ..utils.metrics import DEVICE_FEED_EVICTION_COUNTER
+        from ..resource_control import GLOBAL_CONTROLLER
+        from ..resource_metering import ResourceTagFactory as _rtf
         evicted = 0
         rc = tenant_bytes = standing = None
-        if self._total_locked() > budget:
-            from ..resource_control import GLOBAL_CONTROLLER
-            from ..resource_metering import ResourceTagFactory as _rtf
-            if GLOBAL_CONTROLLER.enabled:
-                rc = GLOBAL_CONTROLLER
-                tenant_bytes = {}
-                for e in self._entries.values():
-                    if e.nbytes > 0:
-                        t = _rtf.tenant(e.owner_tag)
-                        tenant_bytes[t] = \
-                            tenant_bytes.get(t, 0) + e.nbytes
-                # ONE controller-lock round trip per sweep: per-tenant
-                # (byte limit, RU debt) snapshot — per-entry scoring
-                # below is pure dict math under the arena mutex, and
-                # only the victim's tenant needs bytes re-tallied
-                standing = rc.hbm_standing(tenant_bytes, budget)
+        if GLOBAL_CONTROLLER.enabled:
+            rc = GLOBAL_CONTROLLER
+            tenant_bytes = {}
+            for e in self._entries.values():
+                if e.nbytes > 0:
+                    t = _rtf.tenant(e.owner_tag)
+                    tenant_bytes[t] = \
+                        tenant_bytes.get(t, 0) + e.nbytes
+            # ONE controller-lock round trip per sweep: per-tenant
+            # (byte limit, RU debt) snapshot — per-entry scoring
+            # below is pure dict math under the arena mutex, and
+            # only the victim's tenant needs bytes re-tallied
+            standing = rc.hbm_standing(tenant_bytes, budget)
         evicted_by_tenant: dict = {}
         while self._total_locked() > budget:
             victim_key = victim = victim_rank = None
@@ -1093,17 +1197,17 @@ class FeedArena:
             if victim is None:
                 break
             self._settle_entry_locked(victim, time.monotonic())
-            self._entries.pop(victim_key, None)
-            self._resident -= victim.nbytes
-            self.evictions += 1
-            evicted += 1
-            DEVICE_FEED_EVICTION_COUNTER.labels("budget").inc()
             if standing is not None:
                 t = _rtf.tenant(victim.owner_tag)
                 tenant_bytes[t] = max(
                     0, tenant_bytes.get(t, 0) - victim.nbytes)
                 evicted_by_tenant[t] = \
                     evicted_by_tenant.get(t, 0) + 1
+            self.evicted_bytes += victim.nbytes
+            self.memos_kept += self._release_locked(victim_key, victim)
+            self.evictions += 1
+            evicted += 1
+            DEVICE_FEED_EVICTION_COUNTER.labels("budget").inc()
         if standing is not None and evicted:
             # one controller-lock round trip for the whole sweep's
             # eviction telemetry (mirrors the hbm_standing read side)
@@ -1116,6 +1220,48 @@ class FeedArena:
                     t, (float("inf"), 0.0))[0])
             rc.note_protected(protected)
         return evicted
+
+    def _release_locked(self, key: int, ent: _ArenaEntry) -> bool:
+        """The budget's way of taking a line (an eviction, a reject):
+        its device state out of the bucket and out of the accounting,
+        its memos and the entry kept, the entry where a new one would
+        stand in the eviction order.  → whether a memo is left (an
+        entry without one goes whole)."""
+        gone, kept = _bucket_release(ent.bucket)
+        self._account_locked(ent, 0)
+        if not kept:
+            # nothing of the host's to keep: the entry goes whole
+            self._entries.pop(key, None)
+            return False
+        if gone:
+            if ent.released is None:
+                ent.released = set()
+            ent.released.update(gone)
+        ent.hits = 0
+        return True
+
+    def _account_locked(self, ent: _ArenaEntry, nbytes: int) -> None:
+        """``ent`` accounts ``nbytes`` from here on: the running totals
+        move with it (a pinned entry's pinned bytes too, or the pair of
+        counters drifts apart)."""
+        delta = nbytes - ent.nbytes
+        self._resident += delta
+        if ent.pins > 0:
+            self._pinned = max(0, self._pinned + delta)
+        self._lines += (nbytes > 0) - (ent.nbytes > 0)
+        ent.nbytes = nbytes
+
+    def reclaimed(self, anchor, feed_key) -> bool:
+        """Whether the upload of ``feed_key`` now brings back a feed
+        the budget had taken from ``anchor``'s line (asked once an
+        upload: the mark goes with the answer)."""
+        with self._mu:
+            ent = self._entries.get(id(anchor))
+            if ent is None or not ent.released or \
+                    feed_key not in ent.released:
+                return False
+            ent.released.discard(feed_key)
+            return True
 
     def enforce(self) -> int:
         """Eviction sweep against the CURRENT budget with no protected
@@ -1139,9 +1285,7 @@ class FeedArena:
             freed = ent.nbytes if ent is not None else 0
             if ent is not None:
                 self._settle_entry_locked(ent, time.monotonic())
-                self._resident -= ent.nbytes
-                if ent.pins > 0:
-                    self._pinned = max(0, self._pinned - ent.nbytes)
+                self._account_locked(ent, 0)
                 self.drops += 1
                 DEVICE_FEED_EVICTION_COUNTER.labels(reason).inc()
         self._flush_residency()
@@ -1164,6 +1308,7 @@ class FeedArena:
             self._entries.clear()
             self._resident = 0
             self._pinned = 0
+            self._lines = 0
             self.drops += n
             if n:
                 DEVICE_FEED_EVICTION_COUNTER.labels(reason).inc(n)
@@ -1188,8 +1333,8 @@ class FeedArena:
         return self._pinned
 
     def resident_lines(self) -> int:
-        with self._mu:
-            return len(self._entries)
+        """Entries that hold device bytes."""
+        return self._lines
 
     def feed_residency(self) -> tuple:
         """→ (feeds, bytes of their planes) resident now, read from the
@@ -1265,14 +1410,14 @@ class FeedArena:
         )
         with self._mu:
             DEVICE_HBM_RESIDENT_BYTES.set(self._total_locked())
-            DEVICE_FEED_LINES.set(len(self._entries))
+            DEVICE_FEED_LINES.set(self._lines)
 
     def stats(self) -> dict:
         with self._mu:
             return {
                 "budget_bytes": self.budget_bytes,
                 "resident_bytes": self._total_locked(),
-                "resident_lines": len(self._entries),
+                "resident_lines": self._lines,
                 "pinned_lines": sum(1 for e in self._entries.values()
                                     if e.pins > 0),
                 # bytes the budget cannot reclaim right now (in use by
@@ -1282,6 +1427,11 @@ class FeedArena:
                 "evictions": self.evictions,
                 "rejections": self.rejections,
                 "drops": self.drops,
+                # what evictions released, and how many of them left
+                # the line's host memos behind (a re-upload then
+                # derives nothing)
+                "evicted_bytes": self.evicted_bytes,
+                "memos_kept": self.memos_kept,
             }
 
 

@@ -860,7 +860,7 @@ ANNOTATED = frozenset({
     "dispatcher_idle", "group_dispatch", "d2h_wait", "host_materialize",
     "plan_decode", "snapshot", "columnar_cache", "device_dispatch",
     "feed_patch", "feed_upload", "feed_rebuild", "host_derive",
-    "delta_apply",
+    "arena_evict", "delta_apply",
     "resp_serialize",
     "rpc_reply",
     # the hold's own rows (trace_vocab.HOLD_SELF): inside

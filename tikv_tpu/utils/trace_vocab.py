@@ -164,8 +164,8 @@ SPAN_VOCABULARY: dict[str, str] = {
     "dispatcher_idle": "dispatcher thread parked with no closed group "
                        "to stage (aggregate row only)",
     # the hold's own vocabulary (HOLD_ROWS below): inside a hold each
-    # of these keeps its SELF time, so that with the five older rows of
-    # the hold and dispatch_self they add up to group_dispatch
+    # of these keeps its SELF time, so that with the hold's leaf rows
+    # (HOLD_WHOLE) and dispatch_self they add up to group_dispatch
     "group_open": "the hold, before the runner is called: the waiting "
                   "groups of the launch class taken (_take_fusable) and "
                   "each one's class and ticket asked of the runner "
@@ -188,12 +188,12 @@ SPAN_VOCABULARY: dict[str, str] = {
     "stage_full": "a full staging's own time: the host planes, the run "
                   "body's prologue (layouts, kernel lookup, the prepared "
                   "record written), the arena's pin and admit; less "
-                  "feed_get, host_derive and the launch",
+                  "feed_get, host_derive, arena_evict and the launch",
     "feed_get": "the feed ladder's own time between its rungs "
                 "(feed.py FeedStore.get): the key, the bucket, the "
                 "journal's gap read, windows and dead runs folded, "
                 "digests registered; less feed_patch / feed_rebuild / "
-                "feed_upload",
+                "feed_upload / arena_evict",
     "lanes_launch": "a launch's own time around device_dispatch "
                     "(aggregate.py launch_lanes): lanes grouped by "
                     "kernel, the lane program looked up, the output "
@@ -221,7 +221,18 @@ SPAN_VOCABULARY: dict[str, str] = {
                    "the result leaves (the program has not finished)",
     "d2h_copy": "span-only child of d2h_wait: np.asarray of the leaves "
                 "(transfer + sync left after the program finished)",
-    "feed_upload": "cold H2D upload of the columnar feed",
+    "feed_upload": "H2D upload of the columnar feed from the line's "
+                   "host planes: casts, pad, digests, the put (attrs "
+                   "bytes, planes, after_eviction: it brings back a "
+                   "feed the HBM budget had taken, not a cold one; "
+                   "/health device_mesh.feed uploads)",
+    "arena_evict": "a sweep of the HBM budget that had to evict "
+                   "(device/supervisor.py FeedArena._evict_until_locked"
+                   ": each victim found by a scan of the entries under "
+                   "the arena's mutex, its device state released, its "
+                   "host memos kept; attrs victims, bytes); in an "
+                   "admission a leaf of the dispatcher's hold, in an "
+                   "unpin a phase of the request that completed",
     "feed_host_pad": "span-only child of feed_upload on a sharded "
                      "mesh: the host's padded copy of one plane",
     "feed_shard_put": "span-only child of feed_upload on a sharded "
@@ -306,7 +317,9 @@ OUTSIDE_ROOT = CLIENT_CLOCK | {"rpc_accept_wait"}
 # take the thread CPU clock every time, so that offcpu_ms is a full sum.
 HOLD_SELF = ("group_open", "stage_plan", "memo_roll", "stage_full",
              "feed_get", "lanes_launch", "group_complete")
+# (arena_evict: PR 53; a metric that lists the hold's rows by name reads
+# the share the older ones cover)
 HOLD_WHOLE = ("device_dispatch", "feed_patch", "feed_rebuild",
-              "feed_upload", "host_derive")
+              "feed_upload", "host_derive", "arena_evict")
 HOLD_ROWS = frozenset(HOLD_SELF + HOLD_WHOLE)
 HOLD_CPU = frozenset({"device_dispatch", "feed_patch", "feed_rebuild"})
